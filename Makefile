@@ -7,7 +7,7 @@ SHELL := /bin/bash
 
 PY ?= python
 
-.PHONY: all native test test-fast verify bench lint lint-ci trace-smoke chaos-smoke obs-smoke loadgen-smoke clean
+.PHONY: all native test test-fast verify lint lint-ci trace-smoke chaos-smoke obs-smoke loadgen-smoke clean
 
 all: native
 
@@ -107,9 +107,6 @@ verify:
 	env JAX_PLATFORMS=cpu $(PY) -m cake_tpu.obs.cluster_smoke
 	env JAX_PLATFORMS=cpu $(PY) -m cake_tpu.loadgen.smoke
 	set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=$${PIPESTATUS[0]}; echo DOTS_PASSED=$$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$$' /tmp/_t1.log | tr -cd . | wc -c); exit $$rc
-
-bench:
-	$(PY) bench.py
 
 clean:
 	rm -f cake_tpu/native/libcakecodec.so cake_tpu/native/libcakeembed.so

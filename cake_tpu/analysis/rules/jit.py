@@ -181,7 +181,6 @@ class HostSyncInJit(Rule):
 _FUSED_FAMILY_CALLS = {
     "fused_sample_tail",
     "fused_norm_matmul",
-    "fused_qkv_ingest",
     "sample_step",
     "sampled_decode_scan",
 }

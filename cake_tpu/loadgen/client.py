@@ -11,8 +11,8 @@ shed; transport failures are status 0. Stdlib only — this class runs
 from any machine with no jax installed.
 
 ``EngineTarget`` is the same interface over an in-process
-``BatchEngine`` (bench.py's frontdoor section: measuring the serving
-funnel without socket noise); it imports engine types lazily so this
+``BatchEngine`` (the serving funnel without socket noise); it imports
+engine types lazily so this
 module stays importable jax-free.
 """
 
@@ -167,8 +167,8 @@ class HttpTarget:
 
 
 class EngineTarget:
-    """Same ``chat()`` interface over an in-process BatchEngine — the
-    bench path (no sockets, no server thread). Lazy engine imports keep
+    """Same ``chat()`` interface over an in-process BatchEngine (no
+    sockets, no server thread). Lazy engine imports keep
     the module stdlib-importable."""
 
     def __init__(self, engine):

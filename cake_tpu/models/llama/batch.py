@@ -56,7 +56,6 @@ from cake_tpu.ops.attention import gqa_attention, gqa_attention_hm
 from cake_tpu.ops.fuse import resolve_fusion
 from cake_tpu.ops.pallas.chunk_prefill import chunk_prefill_attention
 from cake_tpu.ops.pallas.decode_attention import decode_attention
-from cake_tpu.ops.pallas.fused_ingest import fused_qkv_ingest
 from cake_tpu.ops.pallas.paged_attention import (
     paged_decode_attention,
     paged_decode_attention_xla,
@@ -361,13 +360,9 @@ def batched_blocks_forward(
         allow_pallas and M.resolve_attention_impl(config.attention_impl) == "pallas"
     )
     # Decode hot-path op fusion (ops/fuse.py resolve_fusion): "norm" rides
-    # inside block_qkv/block_finish, "ingest" replaces the decode branch's
-    # split/rope/cache-write below; both gate their Pallas kernels on the
-    # same allow_pallas knob as attention. Every fusion is bit-identical to
-    # the unfused arithmetic (tests/test_fused_decode.py), so enabling one
-    # never changes a stream — only which ops the step dispatches.
+    # inside block_qkv/block_finish and gates its Pallas kernel on the same
+    # allow_pallas knob as attention.
     fusion = resolve_fusion(config, allow_pallas)
-    fusions, fimpl = fusion
     b = x.shape[0]
     if row_offset is not None:
         assert decode, "row-window execution is a decode-only mode"
@@ -416,34 +411,7 @@ def batched_blocks_forward(
     def layer(carry, per_layer):
         x = carry
         lp, k_c, v_c, ok = per_layer
-        use_ingest = (
-            decode
-            and "ingest" in fusions
-            and "wqkv" in lp
-            and "q_norm" not in lp
-            and row_offset is None
-        )
-        if use_ingest:
-            # Fused decode ingest (ops/pallas/fused_ingest.py): the flat
-            # projection row goes through split + rope + cache write in one
-            # kernel (dense slot DMA, or the paged variant with the block
-            # table as scalar prefetch and paged_write_layer's UNMAPPED
-            # drop). The decode rope rows are already pre-gathered above;
-            # dual-rope layers select their plane here, exactly as
-            # block_qkv would. q_norm layer trees (Qwen3 family) and the
-            # 1F1B row-window mode keep the unfused path — bit-identical.
-            qkv = M.block_qkv_flat(lp, x, config, fusion)
-            cos_l = cos[lp["rope_sel"]] if "rope_sel" in lp else cos
-            sin_l = sin[lp["rope_sel"]] if "rope_sel" in lp else sin
-            n_q, n_kv = M.layer_head_counts(lp, config)
-            q, k_c, v_c = fused_qkv_ingest(
-                qkv, cos_l, sin_l, write_pos, k_c, v_c,
-                n_q=n_q, n_kv=n_kv,
-                block_tables=block_tables if paged else None,
-                impl=fimpl,
-            )
-            k = v = None
-        elif decode or cached_chunk:
+        if decode or cached_chunk:
             # The chunk's keys rope at the chunk's own positions (== q_pos);
             # the full-cache-grid k_pos is mask-only, exactly like decode.
             # Verify chunks never place a pad in [slot, slot+W), but the
@@ -462,11 +430,10 @@ def batched_blocks_forward(
                 fusion=fusion,
             )
         if paged:
-            if not use_ingest:
-                k_c, v_c = paged_write_layer(
-                    k_c, v_c, k, v, write_pos, block_tables,
-                    starts=write_starts,
-                )
+            k_c, v_c = paged_write_layer(
+                k_c, v_c, k, v, write_pos, block_tables,
+                starts=write_starts,
+            )
             # One eligibility rule for every paged kernel (decode AND the
             # chunk family): the page must be a whole number of lane tiles.
             # A backend that wanted pallas but lands here surfaces a
@@ -525,11 +492,10 @@ def batched_blocks_forward(
             )
             x = x_new if valid is None else jnp.where(ok, x_new, x)
             return x, (k_c, v_c)
-        if not use_ingest:
-            k_c, v_c = write_layer(
-                k_c, v_c, k, v, write_pos,
-                row=0 if row_offset is None else row_offset,
-            )
+        k_c, v_c = write_layer(
+            k_c, v_c, k, v, write_pos,
+            row=0 if row_offset is None else row_offset,
+        )
         if row_offset is not None:
             # Row-window mode: attention reads this group's rows only (the
             # same bytes the kernels were going to stream); writes above
@@ -666,7 +632,7 @@ def _decode_fn(
     ``pads`` are traced arguments (NOT closure captures), so the compiled
     entry is reused across batches; batch-size changes retrace within it.
     The jit family name carries the fusion spec so tracked_jit attributes
-    compile cost per fusion family (bench.py `fusion` section)."""
+    compile cost per fusion family."""
     fusions, fimpl = resolve_fusion(config, allow_pallas)
     tail_impl = fimpl if "tail" in fusions else None
 

@@ -109,11 +109,11 @@ class LlamaConfig:
     # elsewhere; "pallas"/"xla" force one (tests force both for parity checks).
     attention_impl: str = "auto"
     # Decode hot-path op fusion (ops/fuse.py parse_fusion_spec): "none", or
-    # "<set>[@impl]" with set ⊆ {norm, ingest, tail} (or "all") selecting
-    # which op fusions run, and impl ∈ {auto, pallas, xla} selecting the
-    # kernels vs their XLA twins ("auto" = pallas on TPU). Every fusion is
-    # bit-identical to the unfused path; like attention_impl this is a
-    # runtime knob, never an HF field.
+    # "<set>[@impl]" with set ⊆ {norm, tail} (or "all") selecting which op
+    # fusions run, and impl ∈ {auto, pallas, xla} selecting the kernels vs
+    # their XLA twins ("auto" = pallas on TPU). The twins are the unfused
+    # ops; a kernel agrees with its twin to rounding. Like attention_impl
+    # this is a runtime knob, never an HF field.
     fusion_impl: str = "none"
     # Chat-template override (--chat-template; not an HF field). None = pick
     # by model_type. Needed for Llama-2-chat checkpoints, whose config.json

@@ -218,12 +218,10 @@ def block_qkv_flat(
     """rms_1 -> FUSED QKV projection -> +bias, UNSPLIT: [b, chunk, qkv_dim].
 
     The projection half of block_qkv for layer trees carrying the prep-time
-    ``wqkv`` (ops/fuse.py). Factored out so the decode ingest fusion
-    (ops/pallas/fused_ingest.py) can take the flat row straight into its
-    split+rope+write kernel. ``fusion`` is a resolved (set, impl) pair from
+    ``wqkv`` (ops/fuse.py). ``fusion`` is a resolved (set, impl) pair from
     ops/fuse.resolve_fusion (None = resolve from the config): with "norm"
     enabled the input norm folds into the projection
-    (ops/pallas/fused_norm_matmul.py) — bit-identical either way.
+    (ops/pallas/fused_norm_matmul.py).
     """
     if fusion is None:
         fusion = resolve_fusion(config)

@@ -296,7 +296,7 @@ _HINTS = {
     "wire": "worker round trips dominate; check the per-node wire_nodes "
     "breakdown and the cluster RTT table in cake-tpu stats",
     "compute": "prefill/decode compute dominates; this is the kernel "
-    "budget — see the bench ledger (BENCH_HISTORY.jsonl / benchdiff)",
+    "budget — see PERF.md (where the time goes)",
     "shed": "admission refused the request (server saturation); see "
     "cake_shed_total and per-tenant /slo burn",
     "failover": "a live-stream migration carried (or failed) this "

@@ -67,19 +67,25 @@ _DEVICE_PEAKS: tuple[tuple[str, float, float], ...] = (
 
 
 def device_peaks() -> tuple[float, float, str] | None:
-    """(peak_tflops, peak_hbm_gbps, device_kind) for the first visible
-    accelerator, or None when the platform has no table entry (CPU)."""
-    try:
-        import jax
+    """(peak_tflops, peak_hbm_gbps, device_kind) of the first device.
 
-        kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — no jax / no devices = no peaks
+    None on the CPU only (there is no peak to compare with). An accelerator
+    whose ``device_kind`` is not in the table raises: a default would put a
+    wrong denominator under every utilization the ledger reports."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return None
-    low = kind.lower()
+    low = dev.device_kind.lower()
     for sub, tf, bw in _DEVICE_PEAKS:
         if sub in low:
-            return tf, bw, kind
-    return None
+            return tf, bw, dev.device_kind
+    raise ValueError(
+        f"no peak FLOP/s and HBM bandwidth known for device_kind "
+        f"{dev.device_kind!r}: add it to obs/efficiency._DEVICE_PEAKS with "
+        "its source, or pass --peak-tflops/--peak-hbm-gbps"
+    )
 
 
 def model_active_params(config) -> int:
@@ -255,12 +261,11 @@ class EfficiencyLedger:
                 self.peak_source = "none"
 
     def reset(self) -> None:
-        """Restart the accounting window. The bench warms engines up one
-        round so jit compiles land outside its clocks — a reset after
-        that round keeps the snapshot to steady state too (the first
-        engine to compile would otherwise book multi-second compile
-        walls as prefill/pad and skew the scheduler A/B). Prometheus
-        counters are monotonic by contract and keep running."""
+        """Restart the accounting window. A caller that warms the engine up
+        first resets after that round so the snapshot holds steady state
+        only (the first dispatches would otherwise book multi-second
+        compile walls as prefill/pad). Prometheus counters are monotonic
+        by contract and keep running."""
         with self._lock:
             self.buckets = {b: 0.0 for b in BUCKETS}
             self.tokens = {c: 0 for c in TOKEN_CLASSES}

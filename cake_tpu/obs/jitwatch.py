@@ -20,8 +20,8 @@ did. This module wraps the project's jit families in a tracker:
   * ``arm()`` declares warmup over: steady state must not trace at all.
     Tests warm the decode path, arm in fatal mode, and pin zero retraces.
   * ``install_compile_listener()`` taps ``jax.monitoring`` for process-wide
-    XLA backend-compile seconds — bench.py diffs it around each section for
-    the ``compile_s_*`` / ``retrace_count_*`` keys.
+    XLA backend-compile seconds — ``GET /stats`` reports the total and
+    ``chip_smoke.py`` prints it per phase, cold beside warm-cache.
 
 Importing this module does NOT import jax; ``tracked_jit`` does (its callers
 already have).

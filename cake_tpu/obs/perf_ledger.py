@@ -1,12 +1,9 @@
 """Perf ledger: a durable bench trajectory + noise-aware regression diffs.
 
-``bench.py`` emits one normalized JSON record per run; until now each run
-overwrote the last and the trajectory lived only in git history of the
-``BENCH_r*.json`` snapshots someone remembered to commit. This module
+A benchmark emits one normalized JSON record per run. This module
 
-  * appends every top-level bench emit to ``BENCH_HISTORY.jsonl`` (one
-    line per run, stamped with the git revision and a wall timestamp —
-    ``append_history``), and
+  * appends a record to a history file (one line per run, stamped with the
+    git revision and a wall timestamp — ``append_history``), and
   * compares two bench records with noise-aware thresholds
     (``diff_records`` behind ``cake-tpu benchdiff old.json new.json``):
     a key regresses only when it moves BOTH more than the relative
@@ -18,7 +15,7 @@ throughput/utilization keys (``*tok_s*``, ``*mfu*``, ``*util*``,
 ``*hit_rate*``, ``*goodput*``, ``vs_baseline``) are higher-better; latency/compile keys
 (``*_s``, ``*_ms``, ``*seconds*``, ``*compile*``, ``*retrace*``,
 ``*ttft*``) are lower-better; anything else is reported informationally
-and never gates. Stdlib-only (bench.py imports this before jax exists).
+and never gates. Stdlib-only.
 """
 
 from __future__ import annotations
@@ -27,8 +24,6 @@ import json
 import os
 import subprocess
 import time
-
-HISTORY_NAME = "BENCH_HISTORY.jsonl"
 
 # Absolute floors per key class: a change smaller than the floor never
 # regresses regardless of its relative size (sub-noise keys like a 0.01s

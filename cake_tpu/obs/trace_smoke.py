@@ -177,7 +177,6 @@ def main(argv: list[str] | None = None) -> int:
         }
         for op in (
             "kernel:fused_norm_matmul",
-            "kernel:fused_qkv_ingest",
             "kernel:fused_sample_tail",
         ):
             if op not in kernel:

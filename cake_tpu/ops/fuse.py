@@ -1,7 +1,7 @@
 """Prep-time weight fusion: QKV -> ONE matmul, gate/up -> ONE matmul.
 
 Why: batch-1 decode is HBM-bound, and the measured model-level utilization
-(BASELINE.md int8 note) sits at 0.72 of the isolated-matmul 0.91 because of
+(int8, 2026-07-30, before PR 1) sat at 0.72 of the isolated-matmul 0.91 because of
 per-layer FIXED cost — every op in the scanned layer body pays dispatch and
 tiling setup regardless of size. The reference dispatches q/k/v and gate/up
 as five separate matmuls per layer (cake-core/src/models/llama3/attention.rs:
@@ -55,28 +55,31 @@ FUSED_SHARED_GU = "sh_gu"
 # The weight fusions above remove per-layer DISPATCHES; the decode step still
 # round-trips activations through HBM at every XLA op boundary. The op-level
 # fusion pass (the operation-fusion study in PAPERS.md, arxiv 2502.17728)
-# closes three of those boundaries with Pallas kernels:
+# closes two of those boundaries with Pallas kernels:
 #
 #   "norm"    ops/pallas/fused_norm_matmul.py — RMSNorm folded into the
 #             projection it feeds (attn input norm -> wqkv, post-attn norm ->
 #             w_gu, final norm -> lm_head): the normalized activation never
 #             materializes in HBM.
-#   "ingest"  ops/pallas/fused_ingest.py — head split + rope + K/V cache
-#             write in one kernel (dense write_layer and paged block-table
-#             variants).
 #   "tail"    ops/pallas/fused_sample_tail.py — repeat-penalty ring +
 #             temperature + top-k mask + categorical draw in one kernel over
 #             the vocab tile grid (top-p keeps the XLA sort path behind a
 #             documented fallback).
 #
+# (A third, "ingest" — head split + rope + K/V cache write as one slot-sized
+# DMA — was removed in PR 22: the slot is the sublane-tiled dim of the
+# head-major cache, and Mosaic takes no one-row slice of it.)
+#
 # Selection rides ``LlamaConfig.fusion_impl`` (beside ``attention_impl``),
 # a ``<set>[@<impl>]`` spec parsed here — THE one grammar shared by the
-# config field, ServeConfig, and the --fusion CLI flag. Every fusion is
-# BIT-IDENTICAL to the unfused path (fp32 CPU, the PR 4/9 proof pattern):
-# the XLA twins literally reuse the unfused ops, and the kernels are pinned
-# against them in tests/test_fused_decode.py.
+# config field, ServeConfig, and the --fusion CLI flag. The XLA twins
+# literally reuse the unfused ops, so a twin stream IS the unfused stream;
+# a kernel agrees with its twin to rounding. On the CPU interpreter at f32
+# that is almost always every bit, which tests/test_fused_decode.py checks
+# on token streams; on the chip it is a tolerance
+# (ops/pallas/check.py).
 
-FUSION_NAMES = ("norm", "ingest", "tail")
+FUSION_NAMES = ("norm", "tail")
 FUSION_IMPLS = ("auto", "pallas", "xla")
 
 
@@ -84,9 +87,9 @@ def parse_fusion_spec(spec: str) -> tuple[frozenset, str]:
     """Parse a fusion spec -> (fusion set, impl).
 
     Grammar: ``none`` | ``<set>[@<impl>]`` where ``<set>`` is ``all`` or a
-    comma list drawn from {norm, ingest, tail} and ``<impl>`` is auto (the
+    comma list drawn from {norm, tail} and ``<impl>`` is auto (the
     default: Pallas on TPU, the XLA twins elsewhere), pallas, or xla.
-    Examples: ``all``, ``norm,tail``, ``all@pallas``, ``ingest@xla``.
+    Examples: ``all``, ``norm,tail``, ``all@pallas``, ``tail@xla``.
     """
     spec = (spec or "none").strip()
     if spec == "none":

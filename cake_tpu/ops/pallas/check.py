@@ -1,0 +1,430 @@
+"""Every kernel in this package against its XLA twin, at one geometry.
+
+``chip_smoke.py`` runs this on the chip at a model's real widths (phase C);
+its CPU rehearsal runs it at a tiny geometry through the interpreter. The
+unit tests pin the kernels at toy shapes in interpret mode only, and Mosaic
+accepts or refuses a kernel by its block shapes, so the shapes here are the
+ones the server dispatches: decode at batch 1 and 8, a prefill chunk and a
+whole-prompt prefill, one page size, the model's vocabulary.
+
+Each case runs the kernel with its defaults (what the server gets), then the
+twin, and compares on the host. ``recorded_interpret`` notes the
+``interpret`` argument every ``pallas_call`` was traced with, so a caller on
+the chip can refuse a run in which a kernel went through the interpreter.
+
+Tolerances. Kernel and twin do the same arithmetic in a different order, so
+they agree to rounding, not to the bit (bit-identity between them is a
+property of the CPU interpreter at f32, where both sides lower to the same
+XLA ops). The error of a case is ``max|kernel - twin| / max|twin|``:
+
+  * bf16 outputs: 2**-6. Both sides accumulate in f32 and round the result
+    to bf16 once (half an ulp, 2**-9 relative, each); the attention kernels
+    also round the softmax probabilities to bf16 before the value matmul
+    where the twin rounds them after normalising (another 2**-9 each side).
+    2**-6 is four bf16 ulps of the largest value.
+  * f32 outputs (the rehearsal): 1e-4, reassociated f32 sums over at most a
+    few thousand terms.
+  * int4 matmul: the kernel scales the weights into the activation dtype
+    before the dot, the twin scales the f32 group partials after it; with
+    bf16 activations that is one more bf16 rounding per weight, averaged
+    over the contraction: 2**-5.
+  * token ids (the sampling tail): exact.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+_BIG = np.int32(2**30)  # a key position no query reaches (batch.PAD_SENTINEL)
+
+
+@dataclasses.dataclass(frozen=True)
+class Geometry:
+    hidden: int
+    intermediate: int
+    n_q: int
+    n_kv: int
+    head_dim: int
+    vocab: int
+    window: int | None
+    page_size: int
+    max_seq: int
+    chunk: int
+    int4_group: int
+    dtype: str  # "bf16" | "f32"
+    batches: tuple[int, ...] = (1, 8)
+
+
+@contextlib.contextmanager
+def recorded_interpret():
+    """Yields a list that receives ``bool(interpret)`` for every
+    ``pallas_call`` traced inside the block."""
+    from jax.experimental import pallas as pl
+
+    seen: list[bool] = []
+    real = pl.pallas_call
+
+    def recording(*args, **kwargs):
+        seen.append(bool(kwargs.get("interpret", False)))
+        return real(*args, **kwargs)
+
+    pl.pallas_call = recording
+    try:
+        yield seen
+    finally:
+        pl.pallas_call = real
+
+
+def _rel_err(got, want) -> float:
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    if not np.isfinite(got).all():
+        return float("inf")
+    denom = float(np.max(np.abs(want))) or 1.0
+    return float(np.max(np.abs(got - want))) / denom
+
+
+def _timed(fn, *args):
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn(*args))
+    return out, time.perf_counter() - t0
+
+
+class _Cases:
+    """Runs cases one by one; a case that raises is a failed case with the
+    compiler's message, not the end of the run."""
+
+    def __init__(self, geom: Geometry):
+        self.g = geom
+        self.dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[geom.dtype]
+        self.tol = 2.0**-6 if geom.dtype == "bf16" else 1e-4
+        self.results: list[dict] = []
+        self._key = jax.random.PRNGKey(0)
+
+    def key(self):
+        self._key, sub = jax.random.split(self._key)
+        return sub
+
+    def normal(self, shape, scale=1.0, dtype=None):
+        x = jax.random.normal(self.key(), shape, jnp.float32) * scale
+        return x.astype(dtype or self.dtype)
+
+    def run(self, kernel: str, case: str, fn, tol: float | None = None):
+        """``fn() -> (got, want, first_call_seconds)``; arrays or tuples of
+        arrays. ``tol`` 0 demands equality."""
+        tol = self.tol if tol is None else tol
+        rec = {"kernel": kernel, "case": case, "tol": tol}
+        try:
+            got, want, first_s = fn()
+            gots = got if isinstance(got, tuple) else (got,)
+            wants = want if isinstance(want, tuple) else (want,)
+            if tol == 0:
+                err = 0.0 if all(
+                    np.array_equal(np.asarray(a), np.asarray(b))
+                    for a, b in zip(gots, wants)
+                ) else float("inf")
+            else:
+                err = max(_rel_err(a, b) for a, b in zip(gots, wants))
+            rec.update(
+                ok=err <= tol, max_err=err, first_call_s=round(first_s, 3)
+            )
+        except Exception as e:  # noqa: BLE001 — the message IS the finding
+            rec.update(ok=False, error=f"{type(e).__name__}: {e}"[:2000])
+        self.results.append(rec)
+
+
+def _live_k_positions(n_slots, starts, lengths):
+    slots = jnp.arange(n_slots, dtype=jnp.int32)[None, :]
+    live = (slots >= starts[:, None]) & (slots < lengths[:, None])
+    return jnp.where(live, slots, _BIG)
+
+
+def _row_bounds(c: _Cases, b: int, n_slots: int, q_len: int):
+    """Per-row (starts, lengths): left pads of differing size, live prefixes
+    ending at differing slots, room for ``q_len`` queries at the end."""
+    rng = np.random.default_rng(b * 1000 + n_slots + q_len)
+    lengths = rng.integers(max(q_len, n_slots // 2), n_slots + 1, size=b)
+    lengths[0] = n_slots  # one row at full length
+    starts = rng.integers(0, np.maximum(1, (lengths - q_len) // 2 + 1))
+    starts[0] = 0
+    return (
+        jnp.asarray(starts, jnp.int32),
+        jnp.asarray(lengths, jnp.int32),
+    )
+
+
+def _attention_cases(c: _Cases) -> None:
+    from cake_tpu.ops.attention import gqa_attention, gqa_attention_hm
+    from cake_tpu.ops.pallas.chunk_prefill import chunk_prefill_attention
+    from cake_tpu.ops.pallas.decode_attention import decode_attention
+    from cake_tpu.ops.pallas.flash_attention import flash_attention
+    from cake_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention,
+        paged_decode_attention_xla,
+    )
+    from cake_tpu.ops.pallas.paged_prefill import (
+        paged_chunk_attention,
+        paged_chunk_attention_xla,
+    )
+
+    g = c.g
+    n_p = g.max_seq // g.page_size
+    # The model's own window (at these lengths it may never cut a key) and
+    # one that does, so the mask and the front pruning both run.
+    windows = tuple(dict.fromkeys((g.window, g.max_seq // 4)))
+
+    for b in g.batches:
+        k_cache = c.normal((b, g.n_kv, g.max_seq, g.head_dim))
+        v_cache = c.normal((b, g.n_kv, g.max_seq, g.head_dim))
+        # The same bytes as a page pool with every row's pages scattered.
+        perm = np.random.default_rng(b).permutation(b * n_p)
+        tables = jnp.asarray(perm.reshape(b, n_p), jnp.int32)
+
+        def to_pool(cache):
+            pages = cache.reshape(b, g.n_kv, n_p, g.page_size, g.head_dim)
+            pages = jnp.moveaxis(pages, 2, 1).reshape(
+                b * n_p, g.n_kv, g.page_size, g.head_dim
+            )
+            return jnp.zeros_like(pages).at[tables.reshape(-1)].set(pages)
+
+        k_pool, v_pool = to_pool(k_cache), to_pool(v_cache)
+
+        for window in windows:
+            # ---- decode: one query per row at slot length-1
+            starts, lengths = _row_bounds(c, b, g.max_seq, 1)
+            q = c.normal((b, 1, g.n_q, g.head_dim))
+            q_pos = (lengths - 1)[:, None]
+            k_pos = _live_k_positions(g.max_seq, starts, lengths)
+
+            def dense_decode():
+                got, s = _timed(
+                    lambda: decode_attention(
+                        q, k_cache, v_cache, lengths, starts, window=window
+                    )
+                )
+                want = gqa_attention_hm(
+                    q, k_cache, v_cache, q_pos, k_pos, window=window
+                )
+                return got, want, s
+
+            def paged_decode():
+                got, s = _timed(
+                    lambda: paged_decode_attention(
+                        q, k_pool, v_pool, lengths, tables, starts,
+                        window=window,
+                    )
+                )
+                want = paged_decode_attention_xla(
+                    q, k_pool, v_pool, q_pos, k_pos, tables, window=window
+                )
+                return got, want, s
+
+            c.run("decode_attention", f"b={b} window={window}", dense_decode)
+            c.run(
+                "paged_attention", f"b={b} window={window}", paged_decode
+            )
+
+            # ---- a chunk of queries at the end of each row's live prefix
+            # and, for one row, a whole-prompt prefill (chunk = max_seq from
+            # slot 0; the twin's f32 score tensor at batch 8 would not fit)
+            for chunk in (g.chunk, g.max_seq)[: 2 if b == 1 else 1]:
+                if chunk == g.max_seq:
+                    starts_c = jnp.zeros((b,), jnp.int32)
+                    lengths_c = jnp.full((b,), g.max_seq, jnp.int32)
+                else:
+                    starts_c, lengths_c = _row_bounds(c, b, g.max_seq, chunk)
+                q_starts = lengths_c - chunk
+                qc = c.normal((b, chunk, g.n_q, g.head_dim))
+                q_pos_c = q_starts[:, None] + jnp.arange(
+                    chunk, dtype=jnp.int32
+                )[None, :]
+                k_pos_c = _live_k_positions(g.max_seq, starts_c, lengths_c)
+
+                def dense_chunk():
+                    got, s = _timed(
+                        lambda: chunk_prefill_attention(
+                            qc, k_cache, v_cache, q_starts, lengths_c, None,
+                            starts_c, window=window,
+                        )
+                    )
+                    want = gqa_attention_hm(
+                        qc, k_cache, v_cache, q_pos_c, k_pos_c, window=window
+                    )
+                    return got, want, s
+
+                def paged_chunk():
+                    got, s = _timed(
+                        lambda: paged_chunk_attention(
+                            qc, k_pool, v_pool, q_starts, lengths_c,
+                            starts_c, tables, window=window,
+                        )
+                    )
+                    want = paged_chunk_attention_xla(
+                        qc, k_pool, v_pool, q_pos_c, k_pos_c, tables,
+                        window=window,
+                    )
+                    return got, want, s
+
+                label = f"b={b} chunk={chunk} window={window}"
+                c.run("chunk_prefill", label, dense_chunk)
+                c.run("paged_prefill", label, paged_chunk)
+
+            # ---- fresh prefill of a whole prompt from seq-major K/V
+            if b > 1:
+                continue
+            s_len = g.max_seq
+            qf = c.normal((b, s_len, g.n_q, g.head_dim))
+            kf = c.normal((b, s_len, g.n_kv, g.head_dim))
+            vf = c.normal((b, s_len, g.n_kv, g.head_dim))
+            pos_f = jnp.broadcast_to(
+                jnp.arange(s_len, dtype=jnp.int32)[None, :], (b, s_len)
+            )
+
+            def flash():
+                got, s = _timed(
+                    lambda: flash_attention(qf, kf, vf, window=window)
+                )
+                want = gqa_attention(qf, kf, vf, pos_f, pos_f, window=window)
+                return got, want, s
+
+            c.run(
+                "flash_attention", f"b={b} seq={s_len} window={window}", flash
+            )
+
+
+def _fused_cases(c: _Cases) -> None:
+    from cake_tpu.models.llama.fused import sample_step
+    from cake_tpu.ops.pallas.fused_norm_matmul import fused_norm_matmul
+
+    g = c.g
+    qkv_dim = (g.n_q + 2 * g.n_kv) * g.head_dim
+    # The three decode sites that pair a norm with a projection.
+    sites = {
+        "wqkv": qkv_dim, "w_gu": 2 * g.intermediate, "lm_head": g.vocab,
+    }
+    weights = {
+        name: c.normal((g.hidden, out), g.hidden**-0.5)
+        for name, out in sites.items()
+    }
+    norm_w = c.normal((g.hidden,), 0.1) + 1.0
+    # Both sides run under jit, as they do inside the decode step.
+    norm_run = jax.jit(
+        lambda x, nw, w, impl: fused_norm_matmul(
+            x, nw, w, eps=1e-5, impl=impl
+        ),
+        static_argnames="impl",
+    )
+
+    def tail_run(knobs):
+        return jax.jit(
+            lambda lg, key, ring, impl: sample_step(
+                lg, key, ring, jnp.zeros((lg.shape[0],), jnp.int32),
+                top_p=None, tail_impl=impl, **knobs,
+            )[0],
+            static_argnames="impl",
+        )
+
+    tail_runs = {
+        "greedy": tail_run(
+            dict(temperature=0.0, top_k=None, repeat_penalty=1.0)),
+        "greedy+penalty": tail_run(
+            dict(temperature=0.0, top_k=None, repeat_penalty=1.1)),
+        "topk+penalty": tail_run(
+            dict(temperature=0.7, top_k=40, repeat_penalty=1.1)),
+    }
+
+    for b in g.batches:
+        x = c.normal((b, 1, g.hidden))
+        for name, w in weights.items():
+            def norm_matmul(w=w):
+                got, s = _timed(norm_run, x, norm_w, w, "pallas")
+                return got, norm_run(x, norm_w, w, "xla"), s
+
+            c.run("fused_norm_matmul", f"b={b} site={name}", norm_matmul)
+
+        # ---- sampling tail: ids must be the twin's exactly
+        logits = c.normal((b, g.vocab), 4.0, jnp.float32)
+        ring = jax.random.randint(c.key(), (b, 64), -1, g.vocab, jnp.int32)
+        keys = jax.random.split(c.key(), b)
+        for label, run in tail_runs.items():
+            def tail(run=run):
+                got, s = _timed(run, logits, keys, ring, "pallas")
+                return got, run(logits, keys, ring, "xla"), s
+
+            c.run("fused_sample_tail", f"b={b} {label}", tail, tol=0)
+
+
+def _int4_cases(c: _Cases) -> None:
+    from cake_tpu.ops.pallas.int4_matmul import int4_matmul
+    from cake_tpu.ops.quant import Quant4Weight, _qmat4, quantize4_weight
+
+    g = c.g
+    tol = 2.0**-5 if g.dtype == "bf16" else 1e-4
+    twin = jax.jit(_qmat4)
+    for name, (in_dim, out) in {
+        "w_gu": (g.hidden, 2 * g.intermediate),
+        "w_down": (g.intermediate, g.hidden),
+    }.items():
+        qw = quantize4_weight(
+            c.normal((in_dim, out), in_dim**-0.5, jnp.float32),
+            group_size=g.int4_group,
+        )
+        for rows in (*g.batches, g.chunk):
+            x = c.normal((rows, in_dim))
+
+            def int4(x=x, qw=qw):
+                got, s = _timed(lambda: int4_matmul(x, qw.w, qw.scale))
+                want = twin(x, Quant4Weight(qw.w, qw.scale))
+                return got, want, s
+
+            c.run("int4_matmul", f"rows={rows} site={name}", int4, tol=tol)
+
+
+def run_checks(geom: Geometry) -> dict:
+    """Run every case; returns ``{"results": [...], "interpret": [...]}``
+    where ``interpret`` lists what each traced ``pallas_call`` was given."""
+    c = _Cases(geom)
+    with recorded_interpret() as seen:
+        _attention_cases(c)
+        _fused_cases(c)
+        _int4_cases(c)
+    return {"results": c.results, "interpret": seen}
+
+
+def timed_matmul_chain(n: int, steps: int, repeats: int = 3) -> dict:
+    """A chain of ``steps`` dependent [n, n] bf16 matmuls, timed on the host
+    clock around ``block_until_ready``. Returns the FLOPs and the fastest
+    elapsed time; the caller holds it against the device's peak: less than
+    FLOPs / peak means the clock (or the wait) cannot be trusted."""
+    w = (
+        jax.random.normal(jax.random.PRNGKey(1), (n, n), jnp.float32) * n**-0.5
+    ).astype(jnp.bfloat16)
+    x0 = jax.random.normal(jax.random.PRNGKey(2), (n, n), jnp.bfloat16)
+
+    @jax.jit
+    def chain(x, w):
+        return jax.lax.fori_loop(
+            0, steps,
+            lambda _, x: jnp.dot(
+                x, w, preferred_element_type=jnp.float32
+            ).astype(jnp.bfloat16),
+            x,
+        )
+
+    jax.block_until_ready(chain(x0, w))  # compile + warm
+    elapsed = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(chain(x0, w))
+        elapsed.append(time.perf_counter() - t0)
+    return {
+        "flops": 2.0 * n**3 * steps,
+        "elapsed_s": min(elapsed),
+        "finite": bool(jnp.isfinite(out.astype(jnp.float32)).all()),
+    }

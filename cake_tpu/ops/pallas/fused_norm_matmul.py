@@ -129,11 +129,13 @@ def fused_norm_matmul(
     out = w.shape[-1]
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    # The largest divisor of the output dim not above the requested tile:
-    # the weight is never copied/padded, so blocks must tile it exactly.
-    block_n = min(block_n, out)
+    # The weight is never copied/padded, so blocks must tile it exactly, and
+    # Mosaic takes only whole 128-lane blocks: the largest lane multiple not
+    # above the requested tile that divides the output dim (256 for a
+    # 32000-wide head, where the plain largest divisor, 500, does not lower).
+    block_n = max(_LANES, min(block_n, out) // _LANES * _LANES)
     while out % block_n:
-        block_n -= 1
+        block_n -= _LANES
     y = _norm_matmul_pallas(
         x.reshape(b * t, hidden), norm_w.reshape(1, hidden), w,
         eps=eps, offset=offset, block_n=block_n, interpret=interpret,

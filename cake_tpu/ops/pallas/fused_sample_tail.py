@@ -49,6 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 from cake_tpu.ops.sampling import _filter, apply_repeat_penalty
 
 _LANES = 128
+_ROWS = 8  # rows per block: one f32 sublane tile
+_NO_INDEX = float(2**24)  # above any vocabulary, exact in f32
 
 
 def sample_tail_supported(vocab: int, top_p) -> bool:
@@ -71,30 +73,6 @@ def gumbel_noise(key: jax.Array, logits: jnp.ndarray) -> jnp.ndarray:
     return jax.random.gumbel(key, logits.shape, logits.dtype)
 
 
-def _kth_largest(row: jnp.ndarray, k: int) -> jnp.ndarray:
-    """k-th largest of ``row`` counting duplicates — bitwise what
-    ``jax.lax.top_k(row, k)[..., -1]`` returns — via a distinct-value
-    descent: at most k - 1 max+count sweeps, each over the VMEM-resident
-    row (the vocab never re-streams from HBM)."""
-    t0 = jnp.max(row)
-    c0 = jnp.sum((row == t0).astype(jnp.int32))
-
-    def body(state, _):
-        t, c = state
-        nxt = jnp.max(jnp.where(row < t, row, -jnp.inf))
-        take = c < k
-        t2 = jnp.where(take, nxt, t)
-        c2 = jnp.where(
-            take, c + jnp.sum((row == nxt).astype(jnp.int32)), c
-        )
-        return (t2, c2), None
-
-    if k <= 1:
-        return t0
-    (t, _), _ = jax.lax.scan(body, (t0, c0), None, length=k - 1)
-    return t
-
-
 def _tail_kernel(
     *refs,
     block_v,
@@ -104,6 +82,14 @@ def _tail_kernel(
     repeat_penalty,
     window,
 ):
+    """One [8, block_v] tile of eight rows' logits per grid step.
+
+    Everything is shaped for Mosaic: eight rows fill the sublanes, tiles are
+    whole 128-lane multiples, the resident row lives in scratch as
+    ``[n_v, 8, block_v]`` so a tile is addressed by its (untiled) leading
+    index, and every reduction over the vocabulary is an elementwise sweep
+    over the tiles followed by one lane reduction. Counts and indices ride
+    in f32 (exact below 2**24, far above any vocabulary)."""
     greedy = temperature is None or temperature <= 0.0
     penalize = repeat_penalty != 1.0 and window > 0
     if penalize:
@@ -115,47 +101,130 @@ def _tail_kernel(
         logits_ref, noise_ref, o_ref, scaled_scr, noisy_scr = refs
     bi = pl.program_id(0)
     vi = pl.program_id(1)
-    v0 = vi * block_v
-    tile = logits_ref[...]  # [1, block_v] f32
+    tile = logits_ref[...]  # [8, block_v] f32
 
     if penalize:
-        vpos = v0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_v), 1)
+        vpos = vi * block_v + jax.lax.broadcasted_iota(
+            jnp.int32, (_ROWS, block_v), 1
+        )
+        row_id = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, 1), 0)
 
         def seen_body(w, acc):
-            tok = ring_ref[bi, w]
-            return acc | ((tok >= 0) & (vpos == tok))
+            # The eight rows' ring entries at slot w as an [8, 1] column,
+            # assembled from scalar reads (the ring is a prefetch operand).
+            tok = jnp.full((_ROWS, 1), -1, jnp.int32)
+            for r in range(_ROWS):
+                tok = jnp.where(row_id == r, ring_ref[bi * _ROWS + r, w], tok)
+            return jnp.where((tok >= 0) & (vpos == tok), 1.0, acc)
 
         seen = jax.lax.fori_loop(
-            0, window, seen_body, jnp.zeros((1, block_v), jnp.bool_)
+            0, window, seen_body, jnp.zeros((_ROWS, block_v), jnp.float32)
         )
         # apply_repeat_penalty's exact select: penalize everywhere, keep
         # where unseen.
         pen = jnp.where(
             tile > 0, tile / repeat_penalty, tile * repeat_penalty
         )
-        tile = jnp.where(seen, pen, tile)
+        tile = jnp.where(seen > 0, pen, tile)
 
     if greedy:
-        scaled_scr[0, pl.ds(v0, block_v)] = tile[0]
+        scaled_scr[vi] = tile
     else:
         scaled = tile / temperature
-        scaled_scr[0, pl.ds(v0, block_v)] = scaled[0]
-        noisy_scr[0, pl.ds(v0, block_v)] = (scaled + noise_ref[...])[0]
+        scaled_scr[vi] = scaled
+        noisy_scr[vi] = scaled + noise_ref[...]
+
+    def sweep(fn, init):
+        """Lane-reduce the elementwise accumulation of fn(tile t) over t."""
+        return jax.lax.fori_loop(
+            0, n_v, lambda t, acc: fn(t, acc),
+            jnp.full((_ROWS, block_v), init, jnp.float32),
+        )
+
+    def row_max(scr, below=None):
+        def fn(t, acc):
+            x = scr[t]
+            if below is not None:
+                x = jnp.where(x < below, x, -jnp.inf)
+            return jnp.maximum(acc, x)
+
+        return jnp.max(sweep(fn, -jnp.inf), axis=1, keepdims=True)
+
+    def row_count(scr, value):
+        return jnp.sum(
+            sweep(
+                lambda t, acc: acc + jnp.where(scr[t] == value, 1.0, 0.0), 0.0
+            ),
+            axis=1, keepdims=True,
+        )
+
+    def row_argmax(scr, threshold=None):
+        """jnp.argmax of each resident row (first index of the maximum; a
+        NaN counts as the maximum), with entries whose SCALED value lies
+        under ``threshold`` masked to -inf first."""
+
+        def load(t):
+            x = scr[t]
+            if threshold is not None:
+                x = jnp.where(scaled_scr[t] < threshold, -jnp.inf, x)
+            return x
+
+        has_nan = jnp.max(  # [8, 1] f32, 1.0 where the row holds a NaN
+            sweep(lambda t, acc: jnp.where(jnp.isnan(load(t)), 1.0, acc), 0.0),
+            axis=1, keepdims=True,
+        )
+        top = jnp.max(
+            sweep(
+                lambda t, acc: jnp.maximum(
+                    acc, jnp.where(jnp.isnan(load(t)), -jnp.inf, load(t))
+                ),
+                -jnp.inf,
+            ),
+            axis=1, keepdims=True,
+        )
+        lane = jax.lax.broadcasted_iota(
+            jnp.int32, (_ROWS, block_v), 1
+        ).astype(jnp.float32)
+
+        def first(t, acc):
+            x = load(t)
+            # Masks stay f32 so that only full-shape comparisons make bools.
+            hit = jnp.where(
+                jnp.isnan(x), has_nan, jnp.where(x == top, 1.0 - has_nan, 0.0)
+            )
+            idx = lane + (t * block_v).astype(jnp.float32)
+            return jnp.minimum(acc, jnp.where(hit > 0, idx, _NO_INDEX))
+
+        return jnp.min(sweep(first, _NO_INDEX), axis=1, keepdims=True)
 
     @pl.when(vi == n_v - 1)
     def _finish():
-        row = scaled_scr[...]  # [1, V]
         if greedy:
-            o_ref[0, 0] = jnp.argmax(row[0]).astype(jnp.int32)
+            idx = row_argmax(scaled_scr)
+        elif top_k is None:
+            idx = row_argmax(noisy_scr)
         else:
-            noisy = noisy_scr[...]
-            if top_k is not None:
-                t = _kth_largest(row[0], top_k)
-                # ops/sampling._top_k_mask's strict-< threshold; masked
-                # entries are -inf both here and unfused (-inf + finite
-                # noise is -inf), so the argmax sees identical values.
-                noisy = jnp.where(row < t, -jnp.inf, noisy)
-            o_ref[0, 0] = jnp.argmax(noisy[0]).astype(jnp.int32)
+            # The k-th largest scaled value COUNTING duplicates — what
+            # ``jax.lax.top_k(row, k)[..., -1]`` returns — by a descent over
+            # distinct values: at most k - 1 max-below + count sweeps.
+            t0 = row_max(scaled_scr)
+            c0 = row_count(scaled_scr, t0)
+
+            def descend(_, state):
+                t, c = state
+                nxt = row_max(scaled_scr, below=t)
+                take = c < top_k
+                return (
+                    jnp.where(take, nxt, t),
+                    jnp.where(take, c + row_count(scaled_scr, nxt), c),
+                )
+
+            t, _ = jax.lax.fori_loop(0, top_k - 1, descend, (t0, c0))
+            # ops/sampling._top_k_mask's strict-< threshold; masked entries
+            # are -inf both here and unfused (-inf + finite noise is -inf),
+            # so the argmax sees identical values.
+            idx = row_argmax(noisy_scr, threshold=t)
+        o_ref[...] = jnp.broadcast_to(idx.astype(jnp.int32), o_ref.shape)
 
 
 def _tail_xla(logits, ring, noise, temperature, top_k, top_p, repeat_penalty):
@@ -202,35 +271,38 @@ def fused_sample_tail(
         )
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    block_v = min(block_v, vocab)
+    # Whole 128-lane tiles that divide the vocab exactly (1280 for 32000,
+    # 768 for 128256); rows pad up to whole 8-sublane blocks.
+    block_v = max(_LANES, min(block_v, vocab) // _LANES * _LANES)
     while vocab % block_v:
-        block_v -= 1
+        block_v -= _LANES
     n_v = vocab // block_v
     window = int(ring.shape[1])
     penalize = repeat_penalty != 1.0 and window > 0
+    pad = (-b) % _ROWS
 
-    def _tile(*args):
-        return (args[0], args[1])
+    def rows(x, fill=0):
+        return jnp.pad(x, ((0, pad), (0, 0)), constant_values=fill)
 
-    def _out(*args):
-        return (args[0], 0)
+    def _tile(bi, vi, *_):
+        return (bi, vi)
 
-    n_prefetch = 1 if penalize else 0
-    in_specs = [pl.BlockSpec((1, block_v), _tile)]
-    operands = [jnp.asarray(logits, jnp.float32)]
-    scratch = [pltpu.VMEM((1, vocab), jnp.float32)]
+    in_specs = [pl.BlockSpec((_ROWS, block_v), _tile)]
+    operands = [rows(jnp.asarray(logits, jnp.float32))]
+    scratch = [pltpu.VMEM((n_v, _ROWS, block_v), jnp.float32)]
     if not greedy:
-        in_specs.append(pl.BlockSpec((1, block_v), _tile))
-        operands.append(jnp.asarray(noise, jnp.float32))
-        scratch.append(pltpu.VMEM((1, vocab), jnp.float32))
+        in_specs.append(pl.BlockSpec((_ROWS, block_v), _tile))
+        operands.append(rows(jnp.asarray(noise, jnp.float32)))
+        scratch.append(pltpu.VMEM((n_v, _ROWS, block_v), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(b, n_v),
+        num_scalar_prefetch=1 if penalize else 0,
+        grid=((b + pad) // _ROWS, n_v),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), _out),
+        # Lane-dense output: every lane of a row holds its token id.
+        out_specs=pl.BlockSpec((_ROWS, _LANES), lambda bi, vi, *_: (bi, 0)),
         scratch_shapes=scratch,
     )
-    prefix = (jnp.asarray(ring, jnp.int32),) if penalize else ()
+    prefix = (rows(jnp.asarray(ring, jnp.int32), -1),) if penalize else ()
     out = pl.pallas_call(
         functools.partial(
             _tail_kernel,
@@ -238,7 +310,7 @@ def fused_sample_tail(
             top_k=top_k, repeat_penalty=repeat_penalty, window=window,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b + pad, _LANES), jnp.int32),
         interpret=interpret,
     )(*prefix, *operands)
-    return out[:, 0]
+    return out[:b, 0]

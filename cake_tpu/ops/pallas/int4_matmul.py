@@ -165,11 +165,9 @@ def int4_matmul(
         ),
         scratch_shapes=[pltpu.VMEM((row_block, block_n), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((bp, out), x.dtype),
-        # jax renamed TPUCompilerParams -> CompilerParams; this tree runs on
-        # both sides of the rename, so resolve whichever spelling exists.
-        compiler_params=getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(x2, packed, scale)
     return out_arr[:b]

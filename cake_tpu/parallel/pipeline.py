@@ -51,30 +51,35 @@ def place_stage_model(config, params, boundaries, mesh, tp: int):
     Returns (layer_specs, stage_params, valid, head_params, l_pad)."""
     from cake_tpu.ops.fuse import fuse_layer_tree
     from cake_tpu.parallel.multihost import shard_put
-    from cake_tpu.parallel.tensor import put_layer_params
+    from cake_tpu.parallel.tensor import host_staging, put_layer_params
 
     # Fuse QKV / gate|up before stacking (ops/fuse.py): concat rides the
     # leading [S, L_pad] axes, and shard-major column order composes with the
-    # tp column split exactly as in place_tp_model.
-    stacked, valid = pad_stages(fuse_layer_tree(params["layers"], tp=tp), boundaries)
-    layer_specs = layer_partition_specs(
-        (STAGE_AXIS, None), tp=tp > 1, params=stacked
-    )
-    stage_params = put_layer_params(stacked, mesh, layer_specs)
-    valid_arr = shard_put(np.asarray(valid), mesh, P(STAGE_AXIS))
-    head_params = {
-        # tree.map reaches QuantWeight leaves (quantized lm_head) too.
-        k: jax.tree.map(lambda a: shard_put(a, mesh, P()), w)
-        for k, w in {
-            "embed": params["embed"],
-            "ln_f": params["ln_f"],
-            **(
-                {}
-                if config.tie_word_embeddings
-                else {"lm_head": params["lm_head"]}
-            ),
-        }.items()
-    }
+    # tp column split exactly as in place_tp_model. In host memory, the
+    # regrouping (a second copy of every layer) and the placement alike
+    # (see place_tp_model).
+    with host_staging():
+        stacked, valid = pad_stages(
+            fuse_layer_tree(params["layers"], tp=tp), boundaries
+        )
+        layer_specs = layer_partition_specs(
+            (STAGE_AXIS, None), tp=tp > 1, params=stacked
+        )
+        stage_params = put_layer_params(stacked, mesh, layer_specs)
+        valid_arr = shard_put(np.asarray(valid), mesh, P(STAGE_AXIS))
+        head_params = {
+            # tree.map reaches QuantWeight leaves (quantized lm_head) too.
+            k: jax.tree.map(lambda a: shard_put(a, mesh, P()), w)
+            for k, w in {
+                "embed": params["embed"],
+                "ln_f": params["ln_f"],
+                **(
+                    {}
+                    if config.tie_word_embeddings
+                    else {"lm_head": params["lm_head"]}
+                ),
+            }.items()
+        }
     return layer_specs, stage_params, valid_arr, head_params, valid.shape[1]
 
 

@@ -24,10 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax import shard_map  # jax >= 0.7 canonical location
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cake_tpu.models.llama import model as M
@@ -40,13 +37,20 @@ TP_AXIS = "tp"
 
 
 def checked_shard_map(body, **specs):
-    """shard_map with replication checking off — THE one spelling of the
-    jax-version shim (>=0.7 check_vma vs older check_rep), shared by every
-    shard_map site in parallel/ and runtime/batch_backend.py."""
-    try:
-        return shard_map(body, check_vma=False, **specs)
-    except TypeError:  # pragma: no cover - pre-0.7 jax spelling
-        return shard_map(body, check_rep=False, **specs)
+    """shard_map with replication checking off, shared by every shard_map
+    site in parallel/ and runtime/batch_backend.py."""
+    return shard_map(body, check_vma=False, **specs)
+
+
+def host_staging():
+    """Context in which a model tree is built in HOST memory: arrays made
+    inside it live on JAX's CPU backend, so loading, fusing and stage-padding
+    a model that is about to be sharded never puts a whole copy on device 0
+    (where it would sit beside that chip's own shard, or not fit at all).
+    Placement then hands every chip its shard once, straight from the host.
+    Needs the CPU backend, which JAX always starts unless ``JAX_PLATFORMS``
+    names platforms without it."""
+    return jax.default_device(jax.local_devices(backend="cpu")[0])
 
 
 def place_tp_model(config: "LlamaConfig", params, mesh: Mesh):
@@ -62,21 +66,25 @@ def place_tp_model(config: "LlamaConfig", params, mesh: Mesh):
     Returns (layer_specs, layer_params, head_params)."""
     from cake_tpu.ops.fuse import fuse_layer_tree
 
-    layers = fuse_layer_tree(params["layers"], tp=mesh.shape[TP_AXIS])
-    layer_specs = layer_partition_specs(params=layers)
-    layer_params = put_layer_params(layers, mesh, layer_specs)
-    head_params = jax.device_put(
-        {
-            "embed": params["embed"],
-            "ln_f": params["ln_f"],
-            **(
-                {}
-                if config.tie_word_embeddings
-                else {"lm_head": params["lm_head"]}
-            ),
-        },
-        NamedSharding(mesh, P()),
-    )
+    # Fusing AND placing: device_put cuts an uncommitted array into shards
+    # with a jitted slice, which runs on the default device — outside this
+    # context that is chip 0, and the whole tree would pass through it.
+    with host_staging():
+        layers = fuse_layer_tree(params["layers"], tp=mesh.shape[TP_AXIS])
+        layer_specs = layer_partition_specs(params=layers)
+        layer_params = put_layer_params(layers, mesh, layer_specs)
+        head_params = jax.device_put(
+            {
+                "embed": params["embed"],
+                "ln_f": params["ln_f"],
+                **(
+                    {}
+                    if config.tie_word_embeddings
+                    else {"lm_head": params["lm_head"]}
+                ),
+            },
+            NamedSharding(mesh, P()),
+        )
     return layer_specs, layer_params, head_params
 
 # Sharding of each stacked layer weight [n_layers, in, out] (model.LAYER_WEIGHTS):

@@ -117,8 +117,27 @@ class ApiServer:
                     "--request-log needs the batch engine (--api-batch "
                     "> 1); no request records will be written"
                 )
+        # Every backend compile in the process, tracked jit or not: /stats
+        # reports the total so a caller can tell a cold start from a warm one.
+        from cake_tpu.obs import jitwatch
+
+        jitwatch.install_compile_listener()
         if self.engine is not None:
             self.engine.start()
+
+    def device_info(self) -> dict:
+        """What is serving: the device as JAX reports it and the attention
+        implementation the engine resolved. /health and the start-up log
+        carry it, so a client reads the device instead of guessing."""
+        from cake_tpu.models.llama.model import resolve_attention_impl
+        from cake_tpu.utils.device import describe_devices
+
+        backend = getattr(self.engine, "backend", None)
+        if hasattr(backend, "kernel_impl"):
+            impl = backend.kernel_impl()
+        else:
+            impl = resolve_attention_impl(self.generator.config.attention_impl)
+        return {**describe_devices(), "attention_impl": impl}
 
     # ------------------------------------------------------------- handlers
 
@@ -433,7 +452,14 @@ class ApiServer:
                 parsed = urlparse(self.path)
                 route, query = parsed.path, parse_qs(parsed.query)
                 if route == "/health":
-                    self._json(200, {"status": "ok", "model": api.model_name})
+                    self._json(
+                        200,
+                        {
+                            "status": "ok",
+                            "model": api.model_name,
+                            **api.device_info(),
+                        },
+                    )
                 elif route == "/metrics":
                     # Prometheus text exposition: the metrics registry
                     # (histograms with cumulative buckets, counters, gauges —
@@ -752,13 +778,20 @@ class ApiServer:
                     # the metrics registry snapshot (histogram percentiles,
                     # counters, gauges — what `cake-tpu stats` renders) + the
                     # batch engine's admission counters under --api-batch.
-                    from cake_tpu.obs import memwatch
+                    from cake_tpu.obs import jitwatch, memwatch
                     from cake_tpu.obs.timeline import timeline
                     from cake_tpu.utils import metrics, trace
 
+                    n_compiles, compile_s = jitwatch.compile_totals()
                     body = {
                         "model": api.model_name,
                         "uptime_s": round(time.time() - api._started, 3),
+                        # Process-wide backend compiles since start (with a
+                        # warm persistent cache: the time to fetch them).
+                        "compile": {
+                            "count": n_compiles,
+                            "seconds": round(compile_s, 3),
+                        },
                         "spans": trace.spans.snapshot(),
                         # Structured span tree aggregate (total vs SELF time
                         # per span name) over the timeline ring — what
@@ -887,6 +920,10 @@ class ApiServer:
     def serve_forever(self, host: str, port: int) -> None:
         server = self.make_server(host, port)
         log.info("API listening on http://%s:%d%s", host, port, CHAT_ROUTE)
+        log.info(
+            "serving on %s",
+            " ".join(f"{k}={v}" for k, v in self.device_info().items()),
+        )
         server.serve_forever()
 
 

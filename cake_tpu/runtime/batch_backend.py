@@ -112,7 +112,6 @@ def _note_fusion_kernels(backend, s) -> None:
     unfused path fails CI instead of shipping."""
     from cake_tpu.obs.timeline import timeline
     from cake_tpu.ops.fuse import resolve_fusion
-    from cake_tpu.ops.pallas.fused_ingest import ingest_supported
     from cake_tpu.ops.pallas.fused_sample_tail import sample_tail_supported
     from cake_tpu.utils import metrics
 
@@ -125,23 +124,16 @@ def _note_fusion_kernels(backend, s) -> None:
     # claiming impl=pallas while the twin ran would let the trace-smoke gate
     # pass on a config where no kernel can engage. Norm: the decode sites
     # need a PLAIN 128-lane-tileable projection (quantized trees keep the
-    # twin); ingest: additionally gated off for q_norm (Qwen3) trees and
-    # unfused (no wqkv) weights; tail: top_p / untileable vocab take the
-    # sort twin (fused.sample_step downgrades through the same
-    # sample_tail_supported rule, so note and dispatch cannot drift).
+    # twin); tail: top_p / untileable vocab take the sort twin
+    # (fused.sample_step downgrades through the same sample_tail_supported
+    # rule, so note and dispatch cannot drift).
     lp = getattr(backend, "params", {}).get("layers", {})
     wqkv = lp.get("wqkv")
     norm_ok = (
         isinstance(wqkv, jnp.ndarray) and wqkv.shape[-1] % 128 == 0
     )
-    ingest_ok = (
-        wqkv is not None
-        and "q_norm" not in lp
-        and ingest_supported(backend.config.head_dim)
-    )
     impls = {
         "fused_norm_matmul": ("norm", fimpl if norm_ok else "xla"),
-        "fused_qkv_ingest": ("ingest", fimpl if ingest_ok else "xla"),
         "fused_sample_tail": (
             "tail",
             fimpl
@@ -808,11 +800,14 @@ class TPBatchBackend:
             jnp.asarray(row_tokens), pads1, ends1, jnp.int32(lane),
         )
 
-    def _forward_one(self, pads):
-        """Pad-closure one-token step: shard_mapped, for the decode scan."""
+    def _forward_one(self, head, layers, pads):
+        """Pad-closure one-token step: shard_mapped, for the decode scan.
+        ``head``/``layers`` are the jitted caller's ARGUMENTS: weights a
+        jitted function closes over are baked into its program as constants
+        (gigabytes of them, minutes of compile, and jax 0.9.0 then fails to
+        pass the sharded ones at call time)."""
         cfg = self.config
         cos, sin = self._rope
-        head, layers = self.head_params, self.layer_params
 
         def body(head, layers, tok, kv, pads, slot):
             # The cache's PADDED length (SEQ_MULTIPLE rounding), not the user
@@ -853,9 +848,9 @@ class TPBatchBackend:
             fusions, fimpl = resolve_fusion(self.config)
             tail_impl = fimpl if "tail" in fusions else None
 
-            def run(kv, tok, slot, pads, keys, ring, ring_idx):
+            def run(head, layers, kv, tok, slot, pads, keys, ring, ring_idx):
                 return sampled_decode_scan(
-                    self._forward_one(pads),
+                    self._forward_one(head, layers, pads),
                     kv, tok, slot, keys, ring, ring_idx,
                     n_steps=n,
                     temperature=s.temperature,
@@ -865,10 +860,13 @@ class TPBatchBackend:
                     tail_impl=tail_impl,
                 )
 
-            return jax.jit(run, donate_argnums=(0,))
+            return jax.jit(run, donate_argnums=(2,))
 
         fn = _cache_get_or_build(self._decode_cache, knobs, build)
-        return fn(kv, tok, jnp.int32(slot), pads, keys, ring, ring_idx)
+        return fn(
+            self.head_params, self.layer_params,
+            kv, tok, jnp.int32(slot), pads, keys, ring, ring_idx,
+        )
 
     # Speculative verify over the tp mesh: one shard_mapped cached-chunk
     # forward scores every draft position (MoE forced drop-free dense under
@@ -1027,6 +1025,12 @@ class PipelineBatchBackend:
         self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1,))
         self._join_jit = jax.jit(self._join_impl, donate_argnums=(1,))
         self._decode_cache: OrderedDict = OrderedDict()
+        # Every jitted entry takes the placed weights as its FIRST ARGUMENT
+        # (stage_params, valid, head): weights a jitted function closes over
+        # are baked into its program as constants — gigabytes of them,
+        # minutes of compile — and jax 0.9.0 then fails to pass the sharded
+        # ones at call time.
+        self._weights = (self.stage_params, self.valid, self.head_params)
         # The stage walks (prefill/decode/verify modes) live outside the
         # bounded knob cache: there are at most three, reused by every entry.
         self._walk_cache: dict = {}
@@ -1139,13 +1143,14 @@ class PipelineBatchBackend:
             self._walk_cache[mode] = self._mapped_walk(mode)
         return self._walk_cache[mode]
 
-    def _prefill_impl(self, head, kv, tokens, pads, ends, seq_len):
+    def _prefill_impl(self, weights, kv, tokens, pads, ends, seq_len):
         cfg = self.config
+        stage_params, valid, head = weights
         b, l = tokens.shape
         x = M.embed_tokens(head, tokens, cfg)
         q_pos, k_pos = prefill_positions(l, pads, ends)
         x_stages, kv = self._walks("prefill")(
-            self.stage_params, self.valid, x, kv, q_pos, k_pos,
+            stage_params, valid, x, kv, q_pos, k_pos,
             pads, ends, jnp.int32(0),
         )
         x = x_stages[:b]  # the true output cycles back to stage 0's shard
@@ -1160,10 +1165,10 @@ class PipelineBatchBackend:
             else jnp.asarray(ends, jnp.int32)
         )
         return self._prefill(
-            self.head_params, kv, tokens, jnp.asarray(pads), ends, jnp.int32(l)
+            self._weights, kv, tokens, jnp.asarray(pads), ends, jnp.int32(l)
         )
 
-    def _join_impl(self, head, kv, tokens, pads1, ends1, lane):
+    def _join_impl(self, weights, kv, tokens, pads1, ends1, lane):
         kv_row = init_cache(
             self.n_stages * self.l_pad,
             1,
@@ -1179,7 +1184,9 @@ class PipelineBatchBackend:
         kv_row = jax.lax.with_sharding_constraint(
             kv_row, NamedSharding(self.mesh, self._kv_spec)
         )
-        logits, kv_row = self._prefill_body_for_join(head, kv_row, tokens, pads1, ends1)
+        logits, kv_row = self._prefill_impl(
+            weights, kv_row, tokens, pads1, ends1, ends1[0]
+        )
         k = jax.lax.dynamic_update_slice(
             kv.k, kv_row.k, (0, 0, lane, 0, 0, 0)
         )
@@ -1188,32 +1195,30 @@ class PipelineBatchBackend:
         )
         return logits, KVCache(k=k, v=v)
 
-    def _prefill_body_for_join(self, head, kv_row, tokens, pads1, ends1):
-        return self._prefill_impl(head, kv_row, tokens, pads1, ends1, ends1[0])
-
     def join(self, kv, row_tokens, pads1, ends1, lane):
         return self._join_jit(
-            self.head_params, kv, jnp.asarray(row_tokens), pads1, ends1,
+            self._weights, kv, jnp.asarray(row_tokens), pads1, ends1,
             jnp.int32(lane),
         )
 
     # Speculative verify through the pipelined stage walk: one cached-chunk
     # SPMD computation scores every row's draft; acceptance runs replicated.
 
-    def _verify_walk(self, kv, tokens, slot, pads):
+    def _verify_walk(self, weights, kv, tokens, slot, pads):
         from cake_tpu.models.llama.batch import verify_positions
 
         cfg = self.config
+        stage_params, valid, head = weights
         tokens = jnp.asarray(tokens)
         b, w = tokens.shape
         pads = jnp.asarray(pads, jnp.int32)
-        x = M.embed_tokens(self.head_params, tokens, cfg)
+        x = M.embed_tokens(head, tokens, cfg)
         max_seq = kv.k.shape[-2]
         q_pos, k_pos, lengths = verify_positions(
             w, pads, jnp.int32(slot), max_seq
         )
         x_stages, kv = self._walks("verify")(
-            self.stage_params, self.valid, x, kv, q_pos, k_pos,
+            stage_params, valid, x, kv, q_pos, k_pos,
             pads, lengths, jnp.int32(slot),
         )
         return x_stages[:b], kv
@@ -1226,15 +1231,18 @@ class PipelineBatchBackend:
 
             cfg = self.config
 
-            def run(kv, tokens, slot, pads):
-                x, kv = self._verify_walk(kv, tokens, slot, pads)
-                logits = M.head_forward_all(self.head_params, x, cfg)
+            def run(weights, kv, tokens, slot, pads):
+                x, kv = self._verify_walk(weights, kv, tokens, slot, pads)
+                logits = M.head_forward_all(weights[2], x, cfg)
                 return verify_greedy_ids(logits), kv
 
-            return jax.jit(run, donate_argnums=(0,))
+            return jax.jit(run, donate_argnums=(1,))
 
         fn = _cache_get_or_build(self._decode_cache, key, build)
-        return fn(kv, jnp.asarray(tokens), jnp.int32(slot), jnp.asarray(pads))
+        return fn(
+            self._weights, kv, jnp.asarray(tokens), jnp.int32(slot),
+            jnp.asarray(pads),
+        )
 
     def verify_sampled(self, kv, tokens, slot, pads, drafts, n_drafts, keys, s):
         key = (
@@ -1247,26 +1255,27 @@ class PipelineBatchBackend:
 
             cfg = self.config
 
-            def run(kv, tokens, slot, pads, drafts, n_drafts, keys):
-                x, kv = self._verify_walk(kv, tokens, slot, pads)
-                logits = M.head_forward_all(self.head_params, x, cfg)
+            def run(weights, kv, tokens, slot, pads, drafts, n_drafts, keys):
+                x, kv = self._verify_walk(weights, kv, tokens, slot, pads)
+                logits = M.head_forward_all(weights[2], x, cfg)
                 n_accs, nxts, keys = verify_sampled_accept(
                     logits, drafts, n_drafts, keys,
                     s.temperature, s.top_k, s.top_p,
                 )
                 return n_accs, nxts, kv, keys
 
-            return jax.jit(run, donate_argnums=(0,))
+            return jax.jit(run, donate_argnums=(1,))
 
         fn = _cache_get_or_build(self._decode_cache, key, build)
         return fn(
-            kv, jnp.asarray(tokens), jnp.int32(slot), jnp.asarray(pads),
-            jnp.asarray(drafts), jnp.asarray(n_drafts, jnp.int32), keys,
+            self._weights, kv, jnp.asarray(tokens), jnp.int32(slot),
+            jnp.asarray(pads), jnp.asarray(drafts),
+            jnp.asarray(n_drafts, jnp.int32), keys,
         )
 
-    def _forward_one(self, pads):
+    def _forward_one(self, weights, pads):
         cfg = self.config
-        head = self.head_params
+        stage_params, valid, head = weights
         walk = self._walks("decode")
 
         def forward_one(tok, kv, slot):
@@ -1275,7 +1284,7 @@ class PipelineBatchBackend:
             x = M.embed_tokens(head, tok, cfg)
             q_pos, k_pos, lengths = decode_positions(slot, pads, kv.k.shape[-2])
             x_stages, kv = walk(
-                self.stage_params, self.valid, x, kv, q_pos, k_pos,
+                stage_params, valid, x, kv, q_pos, k_pos,
                 pads, lengths, slot,
             )
             x = x_stages[:b]
@@ -1306,9 +1315,9 @@ class PipelineBatchBackend:
             fusions, fimpl = resolve_fusion(self.config)
             tail_impl = fimpl if "tail" in fusions else None
 
-            def run(kv, tok, slot, pads, keys, ring, ring_idx):
+            def run(weights, kv, tok, slot, pads, keys, ring, ring_idx):
                 return sampled_decode_scan(
-                    self._forward_one(pads),
+                    self._forward_one(weights, pads),
                     kv, tok, slot, keys, ring, ring_idx,
                     n_steps=n,
                     temperature=s.temperature,
@@ -1318,10 +1327,13 @@ class PipelineBatchBackend:
                     tail_impl=tail_impl,
                 )
 
-            return jax.jit(run, donate_argnums=(0,))
+            return jax.jit(run, donate_argnums=(1,))
 
         fn = _cache_get_or_build(self._decode_cache, knobs, build)
-        return fn(kv, tok, jnp.int32(slot), pads, keys, ring, ring_idx)
+        return fn(
+            self._weights, kv, tok, jnp.int32(slot), pads, keys, ring,
+            ring_idx,
+        )
 
     # ---- 1F1B interleaved decode (S microbatch groups in flight) ----------
 
@@ -1477,11 +1489,9 @@ class PipelineBatchBackend:
 
         def build():
             mapped = self._interleaved_body(n, window, s)
-            head, stage_params, valid = (
-                self.head_params, self.stage_params, self.valid
-            )
 
-            def run(kv, tok, slot, pads, keys, ring, ring_idx):
+            def run(weights, kv, tok, slot, pads, keys, ring, ring_idx):
+                stage_params, valid, head = weights
                 out, kv, keys_f, ring_f, ridx_f = mapped(
                     stage_params, valid, head, tok, kv, slot, pads,
                     keys, ring, ring_idx,
@@ -1489,7 +1499,7 @@ class PipelineBatchBackend:
                 last = self.n_stages - 1
                 return out[last], kv, keys_f[last], ring_f[last], ridx_f[last]
 
-            return jax.jit(run, donate_argnums=(0,))
+            return jax.jit(run, donate_argnums=(1,))
 
         fn = _cache_get_or_build(self._decode_cache, knobs, build)
         b = int(tok.shape[0])
@@ -1499,8 +1509,8 @@ class PipelineBatchBackend:
             jnp.asarray(ring_idx, jnp.int32), (b,)
         )
         return fn(
-            kv, jnp.asarray(tok, jnp.int32), jnp.int32(slot), pads,
-            keys, jnp.asarray(ring, jnp.int32), ring_idx,
+            self._weights, kv, jnp.asarray(tok, jnp.int32), jnp.int32(slot),
+            pads, keys, jnp.asarray(ring, jnp.int32), ring_idx,
         )
 
 

@@ -165,11 +165,11 @@ class ServeConfig:
     max_pages: int | None = None
     page_reserve: int = 1
     # Decode hot-path op fusion (ops/fuse.py parse_fusion_spec): "none", or
-    # "<set>[@impl]" with set ⊆ {norm, ingest, tail} (or "all") and impl ∈
+    # "<set>[@impl]" with set ⊆ {norm, tail} (or "all") and impl ∈
     # {auto, pallas, xla}. Applied to the engine's model config
     # (LlamaConfig.fusion_impl) when the engine builds its own backend; an
-    # explicit backend= keeps whatever its config says. Streams are
-    # bit-identical fused or unfused (README "Decode fusion").
+    # explicit backend= keeps whatever its config says (README "Decode
+    # fusion").
     fusion_impl: str = "none"
     # ---- failure semantics (README "Failure semantics") ----
     # Per-op wire deadline + idempotent-resend budget for TCP backends

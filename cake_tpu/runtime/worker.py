@@ -131,8 +131,8 @@ class Worker:
         )
         if fusion_impl not in (None, "none"):
             # Decode op fusion (--fusion) rides the worker's config exactly
-            # like attention_impl: the norm/ingest fusion sites live in the
-            # block forward THIS process runs.
+            # like attention_impl: the norm fusion sites live in the block
+            # forward THIS process runs.
             import dataclasses
 
             from cake_tpu.ops.fuse import parse_fusion_spec

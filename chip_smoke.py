@@ -1,0 +1,816 @@
+#!/usr/bin/env python3
+"""chip_smoke.py: does the --api-batch server start, compile and answer on
+the chip?
+
+    python3 chip_smoke.py                  # on a machine with a TPU
+    python3 chip_smoke.py --rehearse-cpu   # the same phases, tiny, on the CPU
+
+It drives the serving main path once through the entry points a user calls
+(``python -m cake_tpu.cli --model DIR --api ... --api-batch 8`` and HTTP), at
+the full width of one model the repo supports: Mistral-7B-v0.1 (hidden 4096,
+intermediate 14336, 32 q / 8 kv heads of 128, vocab 32000, sliding window
+4096, bf16), depth cut to 8 layers, seeded random weights written as a real
+sharded safetensors checkpoint and loaded as a user's would be.
+
+This process never imports jax: a chip belongs to one process at a time, so
+every phase runs in a child that has exited before the next one starts.
+
+  probe    which device JAX sees, where the compile cache is
+  native   rebuild cake_tpu/native/*.so from source (they are not in git)
+  setup    write the checkpoint
+  A        server at its defaults (dense KV, epoch scheduler, auto attention)
+  B        server as the benchmark will run it (paged KV, continuous
+           scheduler, prefix cache, Pallas attention); Bf adds --fusion
+  C        every Pallas kernel at the model's shapes against its XLA twin,
+           and a bf16 matmul chain held against the device's peak
+  D        four chips: the phase-A server under --tp 4 and as a four-stage
+           pipeline, with per-device memory (skipped below four devices)
+
+Any phase failing makes the exit code non-zero and suppresses the result
+line. Without an accelerator the probe fails: merely lacking a chip never
+selects the CPU. Timings are labelled with the device and are information,
+not a benchmark. The last line of a passing run is one JSON object naming
+the device as JAX reports it.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import threading
+import time
+import urllib.error
+import urllib.request
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+WORK = os.path.join(REPO, ".chip_smoke")  # listed in .gitignore
+PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "D")
+
+MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
+    model_type="mistral", hidden_size=4096, intermediate_size=14336,
+    num_attention_heads=32, num_key_value_heads=8, head_dim_override=128,
+    vocab_size=32000, sliding_window=4096, rope_theta=10000.0,
+    rms_norm_eps=1e-5, max_position_embeddings=32768, bos_token_id=1,
+    eos_token_ids=(2,), tie_word_embeddings=False,
+)
+PRESETS = {
+    # Prompt lengths are in characters: without a tokenizer file the byte
+    # tokenizer serves, one token a byte.
+    "full": dict(
+        name="full", model=dict(MISTRAL_7B, num_hidden_layers=8), dtype="bf16",
+        max_seq_len=2048, prompts=(32, 300, 1500), shared_prefix=1000,
+        new_tokens=64, page_size=128, chunk=256, int4_group=128,
+        batches=(1, 8), matmul=(8192, 20),
+    ),
+    # The rehearsal: same family and head layout rules (tp 4 divides the
+    # heads, a page is a whole lane tile), widths a CPU can interpret.
+    "tiny": dict(
+        name="tiny",
+        model=dict(
+            MISTRAL_7B, hidden_size=128, intermediate_size=256,
+            num_attention_heads=8, num_key_value_heads=4,
+            head_dim_override=16, vocab_size=512, num_hidden_layers=8,
+        ),
+        dtype="f32", max_seq_len=256, prompts=(12, 40, 150),
+        shared_prefix=100, new_tokens=8, page_size=128, chunk=32,
+        int4_group=64, batches=(1, 2), matmul=(256, 4),
+    ),
+}
+
+_state = {"platform": "unprobed", "procs": []}
+
+
+def say(msg: str) -> None:
+    print(f"[chip_smoke platform={_state['platform']}] {msg}", flush=True)
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+# ------------------------------------------------------------------ children
+
+
+def _child_env(cpu: bool) -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    if cpu:
+        env["JAX_PLATFORMS"] = "cpu"
+        # Four virtual devices, so the rehearsal walks phase D too.
+        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    return env
+
+
+def _kill(proc: subprocess.Popen) -> None:
+    if proc.poll() is None:
+        try:
+            os.killpg(proc.pid, signal.SIGTERM)
+            proc.wait(timeout=20)
+        except (ProcessLookupError, subprocess.TimeoutExpired):
+            pass
+    if proc.poll() is None:
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        proc.wait(timeout=20)
+
+
+def _kill_all(*_args) -> None:
+    for proc in _state["procs"]:
+        _kill(proc)
+    if _args:  # called as a signal handler
+        sys.exit(128 + _args[0])
+
+
+def run_child(name: str, args: argparse.Namespace, timeout: float) -> list:
+    """Run ``chip_smoke.py --child NAME`` to its end; returns the JSON
+    records it printed on ``RESULT`` lines. Its other output passes
+    through. A child that raises, or outlives ``timeout``, fails the phase."""
+    cmd = [sys.executable, os.path.abspath(__file__), "--child", name]
+    if args.rehearse_cpu:
+        cmd.append("--rehearse-cpu")
+    proc = subprocess.Popen(
+        cmd, cwd=REPO, env=_child_env(args.rehearse_cpu),
+        stdout=subprocess.PIPE, text=True, start_new_session=True,
+    )
+    _state["procs"].append(proc)
+    records = []
+    timer = threading.Timer(timeout, _kill, (proc,))
+    timer.start()
+    try:
+        for line in proc.stdout:
+            if line.startswith("RESULT "):
+                records.append(json.loads(line[len("RESULT "):]))
+            else:
+                say(f"  {name}| {line.rstrip()}")
+        rc = proc.wait()
+    finally:
+        timer.cancel()
+        _kill(proc)
+        _state["procs"].remove(proc)
+    if rc != 0:
+        raise PhaseFailed(f"child {name!r} exited with code {rc}")
+    return records
+
+
+def emit(record: dict) -> None:
+    print("RESULT " + json.dumps(record), flush=True)
+
+
+def child_probe(preset: dict) -> None:
+    from cake_tpu.utils.device import describe_devices, setup_compile_cache
+
+    emit({"cache_dir": setup_compile_cache(), **describe_devices()})
+
+
+def child_setup(preset: dict) -> None:
+    """Seeded random weights at the preset's widths, written shard by shard
+    with the repo's checkpoint writer. numpy only: no backend starts."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cake_tpu.io.safetensors_io import (
+        ShardedCheckpointWriter,
+        head_tensor_dict,
+        layer_tensor_dict,
+    )
+    from cake_tpu.models.llama.config import LlamaConfig
+
+    config = LlamaConfig(**preset["model"])
+    dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]]
+    model_dir = _model_dir(preset)
+    h, inter, v = config.hidden_size, config.intermediate_size, config.vocab_size
+    qd = config.num_attention_heads * config.head_dim
+    kd = config.num_key_value_heads * config.head_dim
+    shapes = {  # compute orientation [in, out], stacked over one layer
+        "wq": (h, qd), "wk": (h, kd), "wv": (h, kd), "wo": (qd, h),
+        "w_gate": (h, inter), "w_up": (h, inter), "w_down": (inter, h),
+    }
+
+    def normal(seed, shape):
+        rng = np.random.default_rng(seed)
+        x = rng.standard_normal(shape, dtype=np.float32) * 0.02
+        return x.astype(dtype)
+
+    t0 = time.perf_counter()
+    shutil.rmtree(model_dir, ignore_errors=True)
+    os.makedirs(model_dir)
+    with open(os.path.join(model_dir, "config.json"), "w") as f:
+        json.dump(config.to_hf_dict(), f, indent=2)
+    with ShardedCheckpointWriter(model_dir) as writer, ThreadPoolExecutor(
+        os.cpu_count() or 1
+    ) as pool:
+        embed, head = pool.map(normal, (1, 2), ((v, h), (h, v)))
+        writer.add(head_tensor_dict(
+            {"embed": embed, "ln_f": np.ones((h,), dtype), "lm_head": head},
+            config, dtype,
+        ))
+        for i in range(config.num_hidden_layers):
+            mats = pool.map(
+                normal, (1000 * (i + 1) + j for j in range(len(shapes))),
+                shapes.values(),
+            )
+            layer = {k: m[None] for k, m in zip(shapes, mats)}
+            layer["ln_attn"] = layer["ln_mlp"] = np.ones((1, h), dtype)
+            writer.add(layer_tensor_dict(layer, config, dtype, i, i + 1))
+        paths = writer.finish()
+    size = sum(os.path.getsize(p) for p in paths)
+    emit({"model_dir": model_dir, "bytes": size, "shards": len(paths),
+          "seconds": round(time.perf_counter() - t0, 1)})
+
+
+def child_kernels(preset: dict) -> None:
+    from cake_tpu.obs.efficiency import device_peaks
+    from cake_tpu.ops.pallas.check import (
+        Geometry,
+        run_checks,
+        timed_matmul_chain,
+    )
+    from cake_tpu.obs import jitwatch
+    from cake_tpu.utils.device import describe_devices, setup_compile_cache
+
+    setup_compile_cache()
+    jitwatch.install_compile_listener()
+    device = describe_devices()
+    m = preset["model"]
+    out = run_checks(Geometry(
+        hidden=m["hidden_size"], intermediate=m["intermediate_size"],
+        n_q=m["num_attention_heads"], n_kv=m["num_key_value_heads"],
+        head_dim=m["head_dim_override"], vocab=m["vocab_size"],
+        window=m["sliding_window"], page_size=preset["page_size"],
+        max_seq=preset["max_seq_len"], chunk=preset["chunk"],
+        int4_group=preset["int4_group"], dtype=preset["dtype"],
+        batches=tuple(preset["batches"]),
+    ))
+    for rec in out["results"]:
+        emit({"kind": "case", **rec})
+    chain = timed_matmul_chain(*preset["matmul"])
+    peaks = device_peaks()  # raises on an accelerator it has no peaks for
+    emit({
+        "kind": "summary", **device, "interpret": sorted(set(out["interpret"])),
+        "pallas_calls": len(out["interpret"]), "matmul": chain,
+        "peak_tflops": peaks[0] if peaks else None,
+        "compile": dict(zip(("count", "seconds"), jitwatch.compile_totals())),
+    })
+
+
+CHILDREN = {"probe": child_probe, "setup": child_setup, "kernels": child_kernels}
+
+
+# ------------------------------------------------------------------- traffic
+
+
+def _model_dir(preset: dict) -> str:
+    return os.path.join(WORK, f"model-{preset['name']}")
+
+
+def _get(base: str, route: str, timeout: float = 30.0) -> dict:
+    with urllib.request.urlopen(base + route, timeout=timeout) as r:
+        return json.load(r)
+
+
+def _post_chat(base: str, messages: list, max_tokens: int) -> dict:
+    """One non-streaming completion -> {status, finish_reason, body}."""
+    req = urllib.request.Request(
+        base + "/api/v1/chat/completions",
+        data=json.dumps({
+            "messages": messages, "max_tokens": max_tokens, "stream": False,
+        }).encode(),
+        headers={"Content-Type": "application/json"}, method="POST",
+    )
+    try:
+        with urllib.request.urlopen(req, timeout=900) as r:
+            body = json.load(r)
+            return {
+                "status": r.status, "body": body,
+                "finish_reason": body["choices"][0]["finish_reason"],
+                "completion_tokens": body["usage"]["completion_tokens"],
+            }
+    except urllib.error.HTTPError as e:
+        return {"status": e.code, "finish_reason": "error",
+                "body": e.read().decode("utf-8", "replace")[:500]}
+    except (OSError, ValueError, KeyError) as e:
+        return {"status": 0, "finish_reason": "error", "body": repr(e)}
+
+
+def _stream_chat(base: str, prompt: str, max_tokens: int) -> dict:
+    """One streaming completion through the load generator's own client."""
+    from cake_tpu.loadgen.client import HttpTarget
+
+    res = HttpTarget(base, timeout_s=900).chat(prompt, max_tokens)
+    return {"status": res.status, "finish_reason": res.finish_reason,
+            "completion_tokens": res.completion_tokens, "body": res.error}
+
+
+def _prompt(n: int, salt: str) -> str:
+    words = f"{salt} the quick brown fox jumps over the lazy dog and "
+    return (words * (n // len(words) + 1))[:n]
+
+
+def _comparable(body: dict) -> dict:
+    return {k: v for k, v in body.items() if k not in ("id", "created")}
+
+
+def drive_traffic(base: str, preset: dict, shared_prefix: bool) -> dict:
+    """The smoke's traffic; returns counts. Raises PhaseFailed on the first
+    response that is not a 200 finishing ``stop`` or ``length``."""
+    n_new = preset["new_tokens"]
+    short, mid, long_ = preset["prompts"]
+    results = []
+
+    def check(tag: str, r: dict) -> dict:
+        results.append(r)
+        if r["status"] != 200 or r["finish_reason"] not in ("stop", "length"):
+            raise PhaseFailed(
+                f"request {tag}: HTTP {r['status']} finish_reason="
+                f"{r['finish_reason']!r} {r.get('body')!r:.400}"
+            )
+        if not r.get("completion_tokens"):
+            raise PhaseFailed(f"request {tag}: no completion tokens")
+        return r
+
+    # One request alone, then the same greedy request again: equal bodies.
+    alone = [{"role": "user", "content": _prompt(short, "alone")}]
+    first = check("alone#1", _post_chat(base, alone, n_new))
+    again = check("alone#2", _post_chat(base, alone, n_new))
+    if _comparable(first["body"]) != _comparable(again["body"]):
+        raise PhaseFailed(
+            "the same greedy request returned two different bodies: "
+            f"{first['body']['choices']} vs {again['body']['choices']}"
+        )
+
+    def concurrently(jobs: dict) -> None:
+        out: dict = {}
+        threads = [
+            threading.Thread(target=lambda t=t, j=j: out.update({t: j()}))
+            for t, j in jobs.items()
+        ]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+        for tag in jobs:
+            if tag not in out:
+                raise PhaseFailed(f"request {tag}: client thread died")
+            check(tag, out[tag])
+
+    # Eight at once: three prompt lengths, streaming and not.
+    jobs = {}
+    for i, n in enumerate((short, mid, long_, short, mid, long_, mid, short)):
+        text = _prompt(n, f"mix{i}")
+        if i % 2:
+            jobs[f"mix{i}/stream/{n}"] = (
+                lambda text=text: _stream_chat(base, text, n_new)
+            )
+        else:
+            jobs[f"mix{i}/plain/{n}"] = lambda text=text: _post_chat(
+                base, [{"role": "user", "content": text}], n_new
+            )
+    concurrently(jobs)
+
+    if shared_prefix:
+        # Three requests behind one long system prompt, one after another:
+        # the first fills the prefix cache, the other two must hit it. A
+        # cached chain serves lanes of its own alignment class only (left
+        # pad modulo the page), so the questions are of one length and each
+        # request starts its own epoch: equal prompts lengths, equal pads.
+        system = {"role": "system",
+                  "content": _prompt(preset["shared_prefix"], "system")}
+        for i, q in enumerate(("question one?", "question two?",
+                               "question six?"), start=1):
+            check(f"prefix#{i}", _post_chat(
+                base, [system, {"role": "user", "content": q}], n_new
+            ))
+    return {"requests": len(results),
+            "tokens": sum(r["completion_tokens"] for r in results)}
+
+
+# -------------------------------------------------------------- server phases
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _tail(path: str, n: int = 60) -> str:
+    try:
+        with open(path, errors="replace") as f:
+            return "".join(f.readlines()[-n:])
+    except OSError as e:
+        return f"(no log: {e})"
+
+
+def serve_phase(
+    name: str, args: argparse.Namespace, preset: dict, extra: list,
+    *, expect_impl: str, shared_prefix: bool = False, memory_check=None,
+) -> dict:
+    """Start the server as a user would, wait for /health, drive the
+    traffic, read /stats and /events, stop the server. Returns the phase's
+    figures; raises PhaseFailed with the server log's tail."""
+    port = _free_port()
+    base = f"http://127.0.0.1:{port}"
+    cmd = [
+        sys.executable, "-m", "cake_tpu.cli", "--model", _model_dir(preset),
+        "--api", f"127.0.0.1:{port}", "--api-batch", "8",
+        "--max-seq-len", str(preset["max_seq_len"]),
+        "--temperature", "0", "--repeat-penalty", "1.0", *extra,
+    ]
+    if args.rehearse_cpu:
+        cmd += ["--cpu", "--dtype", preset["dtype"]]
+    os.makedirs(os.path.join(WORK, "logs"), exist_ok=True)
+    log_path = os.path.join(WORK, "logs", f"{name}.log")
+    say(f"phase={name} starting: {' '.join(cmd[1:])}")
+    with open(log_path, "w") as log:
+        proc = subprocess.Popen(
+            cmd, cwd=REPO, env=_child_env(args.rehearse_cpu), stdout=log,
+            stderr=subprocess.STDOUT, start_new_session=True,
+        )
+    _state["procs"].append(proc)
+    try:
+        deadline = time.monotonic() + 900
+        health = None
+        while health is None:
+            if proc.poll() is not None:
+                raise PhaseFailed(
+                    f"server exited with code {proc.returncode} before "
+                    "/health answered"
+                )
+            if time.monotonic() > deadline:
+                raise PhaseFailed("server did not answer /health in 900 s")
+            try:
+                health = _get(base, "/health", timeout=5)
+            except (OSError, ValueError):
+                time.sleep(1.0)
+        if health.get("platform") != _state["platform"]:
+            raise PhaseFailed(
+                f"/health says platform={health.get('platform')!r}, the "
+                f"probe saw {_state['platform']!r}"
+            )
+        if health.get("attention_impl") != expect_impl:
+            raise PhaseFailed(
+                f"/health says attention_impl="
+                f"{health.get('attention_impl')!r}, expected {expect_impl!r}"
+            )
+        loaded = _get(base, "/stats")["memwatch"]["devices"]
+        counts = drive_traffic(base, preset, shared_prefix)
+        stats = _get(base, "/stats")
+        events = _get(base, "/events")["events"]
+        engine = stats["engine"]
+        if engine.get("stream_errors"):
+            raise PhaseFailed(
+                f"/stats engine.stream_errors = {engine['stream_errors']}"
+            )
+        fallbacks = [e for e in events if e.get("event") == "kernel-fallback"]
+        if fallbacks:
+            raise PhaseFailed(f"kernel-fallback events: {fallbacks}")
+        if shared_prefix and engine.get("prefix_hits", 0) < 2:
+            raise PhaseFailed(
+                f"prefix_hits = {engine.get('prefix_hits')} after three "
+                "requests behind one system prompt, expected >= 2"
+            )
+        if proc.poll() is not None:
+            raise PhaseFailed(f"server died (code {proc.returncode})")
+        devices = stats["memwatch"]["devices"]
+        if memory_check is not None:
+            # The peak after load is where a whole copy passing through the
+            # first chip shows; in-use, where one that stayed does.
+            memory_check(loaded, "peak_bytes_in_use", "at load")
+            memory_check(loaded, "bytes_in_use", "after load")
+            memory_check(devices, "bytes_in_use", "after traffic")
+        return {
+            **{k: health[k] for k in
+               ("platform", "device_kind", "device_count", "jax_version")},
+            "attention_impl": health["attention_impl"], **counts,
+            "compile_s": stats["compile"]["seconds"],
+            "compiles": stats["compile"]["count"],
+            "prefix_hits": engine.get("prefix_hits"),
+            "loaded_bytes_in_use": [d.get("bytes_in_use") for d in loaded],
+            "loaded_peak_bytes": [d.get("peak_bytes_in_use") for d in loaded],
+            "bytes_in_use": [d.get("bytes_in_use") for d in devices],
+            "peak_bytes_in_use": [d.get("peak_bytes_in_use") for d in devices],
+        }
+    except PhaseFailed as e:
+        raise PhaseFailed(
+            f"{e}\n--- last lines of {log_path} ---\n{_tail(log_path)}"
+        ) from None
+    finally:
+        _kill(proc)  # the chip is free again once it has exited
+        _state["procs"].remove(proc)
+
+
+def _spread_check(tolerance: float):
+    """--tp: the chips hold the same, within ``tolerance`` of the fullest."""
+
+    def check(devices: list, key: str, when: str) -> None:
+        used = [d[key] for d in devices if key in d]
+        if used and (max(used) - min(used)) > tolerance * max(used):
+            raise PhaseFailed(
+                f"{when}: {key} per device {used} differ by more than "
+                f"{tolerance:.0%}: the model is not spread evenly"
+            )
+
+    return check
+
+
+def _stage_check(preset: dict, n_stages: int):
+    """Pipeline: no chip holds more than its stage plus embedding and head
+    (a quarter over, plus the KV strips of eight lanes and the serialized
+    generator's one)."""
+    m = preset["model"]
+    item = 2 if preset["dtype"] == "bf16" else 4
+    h, inter = m["hidden_size"], m["intermediate_size"]
+    qd = m["num_attention_heads"] * m["head_dim_override"]
+    kd = m["num_key_value_heads"] * m["head_dim_override"]
+    layer = (2 * h * qd + 2 * h * kd + 3 * h * inter + 2 * h) * item
+    per_stage = m["num_hidden_layers"] // n_stages
+    head = (2 * m["vocab_size"] * h + h) * item
+    kv = 2 * per_stage * 9 * kd * preset["max_seq_len"] * item
+    bound = int(1.25 * (per_stage * layer + head)) + kv
+
+    def check(devices: list, key: str, when: str) -> None:
+        used = [d[key] for d in devices if key in d]
+        if used and max(used) > bound:
+            raise PhaseFailed(
+                f"{when}: {key} per device {used}: a chip holds more than "
+                f"its stage plus embedding/head ({bound} bytes)"
+            )
+
+    return check
+
+
+# ---------------------------------------------------------------------- main
+
+
+def phase_native(args, preset) -> dict:
+    """The .so files are not in git; whatever sits on disk is stale by
+    definition. Rebuild from codec.cpp / embed.c, or serve without."""
+    native = os.path.join(REPO, "cake_tpu", "native")
+    for f in os.listdir(native):
+        if f.endswith(".so"):
+            os.remove(os.path.join(native, f))
+    proc = subprocess.run(
+        [sys.executable, "-m", "cake_tpu.native.build"], cwd=REPO,
+        env=_child_env(True), capture_output=True, text=True, timeout=300,
+    )
+    built = sorted(f for f in os.listdir(native) if f.endswith(".so"))
+    if proc.returncode != 0 or not built:
+        say("native build did not produce the libraries; the Python codec "
+            f"serves. {proc.stderr.strip()[-300:]}")
+    return {"built": built}
+
+
+def phase_kernels(args, preset) -> dict:
+    records = run_child("kernels", args, timeout=900)
+    cases = [r for r in records if r["kind"] == "case"]
+    summary = next(r for r in records if r["kind"] == "summary")
+    by_kernel: dict = {}
+    for c in cases:
+        k = by_kernel.setdefault(
+            c["kernel"], {"cases": 0, "failed": [], "max_err": 0.0, "s": 0.0}
+        )
+        k["cases"] += 1
+        k["s"] += c.get("first_call_s", 0.0)
+        k["max_err"] = max(k["max_err"], c.get("max_err", 0.0))
+        if not c["ok"]:
+            k["failed"].append(c)
+    for name, k in by_kernel.items():
+        say(f"phase=C kernel={name} cases={k['cases']} "
+            f"failed={len(k['failed'])} max_err_vs_twin={k['max_err']:.3g} "
+            f"first_calls_s={k['s']:.1f}")
+        for c in k["failed"]:
+            say(f"phase=C   FAILED {name} {c['case']}: "
+                f"{c.get('error') or 'max_err %.3g > tol %.3g' % (c['max_err'], c['tol'])}")
+    chain = summary["matmul"]
+    if summary["peak_tflops"]:  # a rate is a device's; the cpu gets none
+        say(f"phase=C bf16 matmul chain on {summary['device_kind']}: "
+            f"{chain['flops'] / 1e12:.1f} TFLOP in "
+            f"{chain['elapsed_s'] * 1e3:.1f} ms = "
+            f"{chain['flops'] / chain['elapsed_s'] / 1e12:.1f} TFLOP/s of "
+            f"{summary['peak_tflops']} peak")
+    problems = [f"{len(k['failed'])} case(s) of {n} failed"
+                for n, k in by_kernel.items() if k["failed"]]
+    want_interpret = [args.rehearse_cpu]
+    if summary["interpret"] != want_interpret:
+        problems.append(
+            f"pallas_call was traced with interpret={summary['interpret']}, "
+            f"expected {want_interpret}"
+        )
+    if not chain["finite"]:
+        problems.append("the matmul chain produced non-finite values")
+    if summary["peak_tflops"]:
+        floor = chain["flops"] / (summary["peak_tflops"] * 1e12)
+        if chain["elapsed_s"] < floor:
+            problems.append(
+                f"matmul chain took {chain['elapsed_s']:.4f} s, less than "
+                f"FLOPs/peak = {floor:.4f} s: the host clock around "
+                "block_until_ready cannot be trusted here"
+            )
+    else:
+        say("phase=C no peak table entry on the cpu: clock check not run")
+    if problems:
+        raise PhaseFailed("; ".join(problems))
+    return {
+        **{k: summary[k] for k in
+           ("platform", "device_kind", "device_count", "jax_version")},
+        "compile_s": round(summary["compile"]["seconds"], 1),
+        "compiles": summary["compile"]["count"],
+        "kernels": len(by_kernel), "cases": len(cases),
+    }
+
+
+def phase_four_chips(args, preset) -> dict:
+    cpu = args.rehearse_cpu
+    out = {}
+    out["tp4"] = serve_phase(
+        "D-tp4", args, preset, ["--tp", "4"],
+        expect_impl="xla" if cpu else "pallas",
+        memory_check=_spread_check(0.10),
+    )
+    n_layers = preset["model"]["num_hidden_layers"]
+    per = n_layers // 4
+    topology = os.path.join(WORK, "topology-4.yml")
+    with open(topology, "w") as f:
+        for i in range(4):
+            lo, hi = i * per, (i + 1) * per - 1
+            f.write(f'stage{i}:\n  host: "127.0.0.1:{20000 + i}"\n'
+                    f'  layers:\n    - "model.layers.{lo}-{hi}"\n')
+    out["mesh4"] = serve_phase(
+        "D-mesh4", args, preset,
+        ["--backend", "mesh", "--topology", topology],
+        expect_impl="xla" if cpu else "pallas",
+        memory_check=_stage_check(preset, 4),
+    )
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument(
+        "--rehearse-cpu", action="store_true",
+        help="run the same phases at a tiny size on the CPU (a rehearsal "
+        "of this script, never a result: no JSON line is a device's)",
+    )
+    ap.add_argument(
+        "--phases", default=",".join(PHASES),
+        help=f"comma list from {','.join(PHASES)} (default: all; probe "
+        "always runs)",
+    )
+    ap.add_argument(
+        "--seed-failure", default=None, metavar="PHASE",
+        help="make PHASE fail on purpose (the rehearsal's proof that a "
+        "failing phase turns the exit code non-zero)",
+    )
+    ap.add_argument("--child", default=None, help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    preset = PRESETS["tiny" if args.rehearse_cpu else "full"]
+
+    if not os.path.isdir(os.path.join(REPO, "cake_tpu")):
+        print("chip_smoke.py: no cake_tpu package beside this script; it "
+              "checks that repository and is nothing without it",
+              file=sys.stderr)
+        return 2
+    if args.child:
+        CHILDREN[args.child](preset)
+        return 0
+
+    wanted = [p.strip() for p in args.phases.split(",") if p.strip()]
+    unknown = [p for p in wanted if p not in PHASES]
+    if unknown:
+        ap.error(f"unknown phase(s) {unknown}")
+    signal.signal(signal.SIGTERM, _kill_all)
+    signal.signal(signal.SIGINT, _kill_all)
+    os.makedirs(WORK, exist_ok=True)
+    last_path = os.path.join(WORK, "last_run.json")
+    try:
+        with open(last_path) as f:
+            records = json.load(f)
+    except (OSError, ValueError):
+        records = {}
+
+    cpu = args.rehearse_cpu
+    paged = ["--kv-mode", "paged", "--page-size", str(preset["page_size"]),
+             "--scheduler", "continuous", "--prefix-cache", "on",
+             "--attention-impl", "pallas"]
+    plan = {
+        "native": lambda: phase_native(args, preset),
+        "setup": lambda: run_child("setup", args, timeout=900)[0],
+        "A": lambda: serve_phase(
+            "A", args, preset, [], expect_impl="xla" if cpu else "pallas"),
+        "B": lambda: serve_phase(
+            "B", args, preset, paged, expect_impl="pallas",
+            shared_prefix=True),
+        "Bf": lambda: serve_phase(
+            "Bf", args, preset, [*paged, "--fusion", "all@pallas"],
+            expect_impl="pallas", shared_prefix=True),
+        "C": lambda: phase_kernels(args, preset),
+        "D": lambda: phase_four_chips(args, preset),
+    }
+
+    failed = []
+    report = {}
+    device = None
+    t_start = time.perf_counter()
+    try:
+        # ---- probe: always, first. No accelerator, no run.
+        try:
+            device = run_child("probe", args, timeout=300)[0]
+        except PhaseFailed as e:
+            say(f"phase=probe FAILED: {e}")
+            return 1
+        _state["platform"] = device["platform"]
+        # A record is comparable with a run on the same kind of device only.
+        run_key = f"{device['device_kind']} x{device['device_count']}"
+        previous = records.get(run_key, {})
+        cache_dir = device["cache_dir"]  # None: no cache on the cpu
+        n_cached = (
+            len(os.listdir(cache_dir))
+            if cache_dir and os.path.isdir(cache_dir) else 0
+        )
+        say(f"phase=probe ok device_kind={device['device_kind']!r} "
+            f"devices={device['device_count']} jax={device['jax_version']} "
+            f"compile_cache={cache_dir} entries={n_cached} "
+            f"({'populated' if n_cached else 'empty'})")
+        want = "cpu" if cpu else "tpu"
+        if device["platform"] != want:
+            say(f"phase=probe FAILED: JAX sees platform="
+                f"{device['platform']!r}, this run needs {want!r}"
+                + ("" if cpu else " (no accelerator: nothing to smoke; "
+                   "--rehearse-cpu rehearses the script, on purpose)"))
+            return 1
+
+        for name in PHASES[1:]:
+            if name not in wanted:
+                continue
+            if name == "D" and device["device_count"] < 4:
+                say(f"phase=D not run: {device['device_count']} device(s), "
+                    "it needs four")
+                continue
+            t0 = time.perf_counter()
+            try:
+                if args.seed_failure == name:
+                    raise PhaseFailed("seeded failure (--seed-failure)")
+                result = plan[name]()
+            except PhaseFailed as e:
+                failed.append(name)
+                say(f"phase={name} FAILED after "
+                    f"{time.perf_counter() - t0:.1f} s: {e}")
+                continue
+            wall = round(time.perf_counter() - t0, 1)
+            report[name] = {"wall_s": wall, **result}
+            parts = (
+                {f"D-{k}": v for k, v in result.items()} if name == "D"
+                else {name: result}
+            )
+            for part, figures in parts.items():
+                fields = " ".join(f"{k}={v}" for k, v in figures.items())
+                say(f"phase={part} ok wall_s={wall} {fields}")
+            before = previous.get(name, {})
+            if "compile_s" in result and "compile_s" in before:
+                say(f"phase={name} compile_s={result['compile_s']} now, "
+                    f"{before['compile_s']} on the previous run in this "
+                    f"checkout (cache entries at start: {n_cached})")
+    finally:
+        _kill_all()
+        # The weights are bulk, the cache and the record are what a second
+        # run in this checkout reads.
+        for name in os.listdir(WORK):
+            if name.startswith("model-"):
+                shutil.rmtree(os.path.join(WORK, name), ignore_errors=True)
+        if device is not None:
+            records[run_key] = {**previous, **report}
+            with open(last_path, "w") as f:
+                json.dump(records, f, indent=1)
+
+    say(f"total wall_s={time.perf_counter() - t_start:.1f}")
+    if "jax" in sys.modules:
+        say("FAILED: the parent imported jax")
+        return 1
+    if failed:
+        say(f"FAILED phases: {','.join(failed)}")
+        return 1
+    if cpu:
+        say("rehearsal passed (a CPU rehearsal of the script, not a result)")
+        return 0
+    if set(PHASES) - set(wanted) - {"probe"}:
+        say(f"partial run passed (phases={','.join(wanted)}): no result line")
+        return 0
+    print(json.dumps({"ok": True, "device": {
+        "platform": device["platform"], "kind": device["device_kind"],
+        "count": device["device_count"],
+    }}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

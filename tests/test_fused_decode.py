@@ -1,6 +1,6 @@
 """Fused multi-token decode (models/llama/fused.py): parity with per-step
 path — and the decode hot-path OP fusions (ISSUE 13): fused_norm_matmul /
-fused_qkv_ingest / fused_sample_tail streams bit-identical to unfused, with
+fused_sample_tail streams identical to unfused on the CPU interpreter, with
 kernel-vs-XLA-twin oracles."""
 
 import dataclasses
@@ -252,7 +252,7 @@ def test_fused_per_fusion_opt_in_bit_identical(fmodel):
     """Each fusion opts in independently and alone preserves the stream."""
     cfg, params = fmodel
     base = _engine_streams(cfg, params, "none", sampling=SAMPLED)
-    for spec in ("norm", "ingest", "tail", "norm,tail"):
+    for spec in ("norm", "tail", "norm,tail"):
         assert _engine_streams(cfg, params, spec, sampling=SAMPLED) == base
 
 
@@ -281,9 +281,12 @@ def test_fused_spec_verify_round_unaffected(fmodel):
 # ----------------------------------------------------- kernel-vs-twin oracles
 
 
-def test_norm_matmul_kernel_matches_unfused_bits():
-    """fused_norm_matmul (interpret) == rms_norm + qmat, bitwise, across
-    out-tile counts and the Gemma (1 + w) offset."""
+def test_norm_matmul_kernel_matches_unfused():
+    """fused_norm_matmul (interpret) == rms_norm + qmat across out-tile
+    counts and the Gemma (1 + w) offset, to f32 rounding: the kernel's
+    reciprocal-sqrt and the 96-term dot are the same arithmetic in another
+    order, so the two differ in the last ulp (2**-23 relative) of a few
+    outputs. 1e-6 of the largest value is eight ulps."""
     from cake_tpu.ops.norm import rms_norm
     from cake_tpu.ops.pallas.fused_norm_matmul import fused_norm_matmul
     from cake_tpu.ops.quant import qmat
@@ -300,7 +303,8 @@ def test_norm_matmul_kernel_matches_unfused_bits():
             )
             want = qmat(rms_norm(x, nw, 1e-5, offset), w)
             assert got.dtype == want.dtype
-            assert jnp.array_equal(got, want), (offset, block_n)
+            err = jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))
+            assert err <= 1e-6, (offset, block_n, err)
 
 
 def test_norm_matmul_untiled_out_dim_takes_twin():
@@ -319,115 +323,6 @@ def test_norm_matmul_untiled_out_dim_takes_twin():
     assert not norm_matmul_supported(w)
     got = fused_norm_matmul(x, nw, w, eps=1e-5, impl="pallas")
     assert jnp.array_equal(got, qmat(rms_norm(x, nw, 1e-5, False), w))
-
-
-def _rand_qkv(key, b, n_q, n_kv, hd):
-    qkv_dim = (n_q + 2 * n_kv) * hd
-    ks = jax.random.split(key, 3)
-    qkv = jax.random.normal(ks[0], (b, 1, qkv_dim), jnp.float32)
-    cos = jax.random.normal(ks[1], (b, 1, hd // 2), jnp.float32)
-    sin = jax.random.normal(ks[2], (b, 1, hd // 2), jnp.float32)
-    return qkv, cos, sin
-
-
-def _jit_ingest(n_q, n_kv, impl, paged):
-    """Both oracle sides run UNDER jit, as they do in the decode scan: the
-    bit-identity contract is between compiled paths (an eager evaluation
-    re-associates the rope multiply-adds differently than XLA's fused
-    graph — not a divergence any serving path can observe)."""
-    import functools
-
-    from cake_tpu.ops.pallas.fused_ingest import fused_qkv_ingest
-
-    if paged:
-        def run(qkv, cos, sin, pos, k, v, tables):
-            return fused_qkv_ingest(
-                qkv, cos, sin, pos, k, v, n_q=n_q, n_kv=n_kv,
-                block_tables=tables, impl=impl, interpret=True,
-            )
-    else:
-        def run(qkv, cos, sin, pos, k, v):
-            return fused_qkv_ingest(
-                qkv, cos, sin, pos, k, v, n_q=n_q, n_kv=n_kv,
-                impl=impl, interpret=True,
-            )
-    return jax.jit(run)
-
-
-def test_ingest_kernel_dense_matches_twin_bits():
-    """Dense fused_qkv_ingest (interpret): roped q and the slot write are
-    bitwise the twin's (apply_rope + write_layer); every other cache byte
-    is untouched."""
-    b, n_q, n_kv, hd, max_seq = 3, 4, 2, 16, 64
-    qkv, cos, sin = _rand_qkv(jax.random.PRNGKey(3), b, n_q, n_kv, hd)
-    base = jax.random.normal(
-        jax.random.PRNGKey(4), (b, n_kv, max_seq, hd), jnp.float32
-    )
-    pos = jnp.int32(17)
-    q_t, k_t, v_t = _jit_ingest(n_q, n_kv, "xla", False)(
-        qkv, cos, sin, pos, base, base + 1.0
-    )
-    q_p, k_p, v_p = _jit_ingest(n_q, n_kv, "pallas", False)(
-        qkv, cos, sin, pos, base, base + 1.0
-    )
-    assert jnp.array_equal(q_p, q_t)
-    assert jnp.array_equal(k_p, k_t)
-    assert jnp.array_equal(v_p, v_t)
-    # The slot changed; everything else is byte-stable.
-    assert not jnp.array_equal(k_p[:, :, 17], base[:, :, 17])
-    mask = jnp.arange(max_seq) != 17
-    assert jnp.array_equal(k_p[:, :, mask], base[:, :, mask])
-
-
-def test_ingest_kernel_paged_scattered_pages_and_unmapped_drop():
-    """Paged fused_qkv_ingest with SCATTERED physical pages: the write
-    resolves through the block table (ignored indirection fails loudly on
-    non-uniform pages), an UNMAPPED lane's write DROPS (paged_write_layer
-    semantics), and untouched pool pages stay byte-stable."""
-    b, n_q, n_kv, hd, ps, n_pages = 3, 4, 2, 16, 8, 7
-    qkv, cos, sin = _rand_qkv(jax.random.PRNGKey(5), b, n_q, n_kv, hd)
-    pool = jax.random.normal(
-        jax.random.PRNGKey(6), (n_pages, n_kv, ps, hd), jnp.float32
-    )
-    # Row 0 -> physical 5, row 1 -> physical 2 (scattered), row 2 UNMAPPED.
-    tables = jnp.asarray(
-        [[3, 5, -1], [6, 2, -1], [-1, -1, -1]], jnp.int32
-    )
-    pos = jnp.int32(11)  # logical page 1, offset 3
-    q_t, k_t, v_t = _jit_ingest(n_q, n_kv, "xla", True)(
-        qkv, cos, sin, pos, pool, pool + 1.0, tables
-    )
-    q_p, k_p, v_p = _jit_ingest(n_q, n_kv, "pallas", True)(
-        qkv, cos, sin, pos, pool, pool + 1.0, tables
-    )
-    assert jnp.array_equal(q_p, q_t)
-    assert jnp.array_equal(k_p, k_t)
-    assert jnp.array_equal(v_p, v_t)
-    # The two mapped rows landed at their scattered physical pages...
-    assert not jnp.array_equal(k_p[5, :, 3], pool[5, :, 3])
-    assert not jnp.array_equal(k_p[2, :, 3], pool[2, :, 3])
-    # ...the unmapped row dropped, and untouched pages are byte-stable.
-    for page in (0, 1, 3, 4, 6):
-        assert jnp.array_equal(k_p[page], pool[page])
-
-
-def test_ingest_kernel_paged_out_of_table_slot_drops():
-    """A slot past the table's logical pages drops (the logical-before-
-    physical clamp): no write, no crash — both impls."""
-    from cake_tpu.ops.pallas.fused_ingest import fused_qkv_ingest
-
-    b, n_q, n_kv, hd, ps, n_pages = 1, 2, 1, 16, 8, 3
-    qkv, cos, sin = _rand_qkv(jax.random.PRNGKey(8), b, n_q, n_kv, hd)
-    pool = jnp.zeros((n_pages, n_kv, ps, hd), jnp.float32)
-    tables = jnp.asarray([[1]], jnp.int32)  # one logical page: slots [0, 8)
-    pos = jnp.int32(9)  # logical page 1: past the table
-    for impl in ("xla", "pallas"):
-        _, k_o, v_o = fused_qkv_ingest(
-            qkv, cos, sin, pos, pool, pool, n_q=n_q, n_kv=n_kv,
-            block_tables=tables, impl=impl, interpret=True,
-        )
-        assert jnp.array_equal(k_o, pool), impl
-        assert jnp.array_equal(v_o, pool), impl
 
 
 def _tail_ref(logits, ring, key, s):

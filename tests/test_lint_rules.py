@@ -2152,7 +2152,7 @@ class TestFusedFamilyKernelShapes:
     """ISSUE 13 convention (mirrors the ISSUE 9 pins): the new fused-kernel
     family shapes keep traced-block-dim and prefetch-ref-unused ENGAGED —
     positive and negative for each, on snippets shaped like the real
-    kernels (ops/pallas/fused_sample_tail.py / fused_ingest.py)."""
+    kernels (ops/pallas/fused_sample_tail.py and a slot-DMA cache write)."""
 
     # The fused sampling tail's shape: ring as ONE scalar-prefetch operand,
     # a (b, n_v) grid over vocab tiles, block_v as a static knob.

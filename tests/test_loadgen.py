@@ -4,7 +4,7 @@ runner/report, and capture->replay planning (cake_tpu/loadgen/*).
 Everything here is stdlib-only and fast — no jax, no sockets: the
 targets are fakes with the ``chat()`` interface. The live end-to-end
 path (real --api master, real engine) is the ``make loadgen-smoke``
-gate; the in-proc path is the bench's ``frontdoor`` section.
+gate; the in-proc path is ``client.EngineTarget``.
 """
 
 import random

@@ -1,5 +1,5 @@
-"""Perf ledger (obs/perf_ledger.py): the BENCH_HISTORY.jsonl trajectory and
-the noise-aware `cake-tpu benchdiff` regression gate."""
+"""Perf ledger (obs/perf_ledger.py): the history-file trajectory and the
+noise-aware `cake-tpu benchdiff` regression gate."""
 
 import json
 import os
@@ -23,26 +23,19 @@ def test_append_history_stamps_rev_and_ts(tmp_path):
     assert pl.git_rev(os.path.dirname(os.path.abspath(__file__))) is not None
 
 
-def test_bench_emit_appends_history(tmp_path, monkeypatch, capsys):
-    """The satellite contract: bench.py's _emit funnel writes the ledger
-    line for top-level (non-section-child) emits."""
-    import bench
-
-    monkeypatch.setenv("BENCH_JSON_PATH", str(tmp_path / "bench.json"))
-    monkeypatch.setenv("BENCH_HISTORY_PATH", str(tmp_path / "hist.jsonl"))
-    monkeypatch.delenv("BENCH_SECTIONS", raising=False)
-    bench._emit(42.0, {"batch8_tok_s": 800.0})
-    capsys.readouterr()
-    rows = (tmp_path / "hist.jsonl").read_text().splitlines()
-    assert len(rows) == 1
-    rec = json.loads(rows[0])["record"]
-    assert rec["value"] == 42.0
-    assert rec["batch8_tok_s"] == 800.0
-    # A section child must NOT append (it rolls up into the orchestrator).
-    monkeypatch.setenv("BENCH_SECTIONS", "main")
-    bench._emit(1.0, {})
-    capsys.readouterr()
-    assert len((tmp_path / "hist.jsonl").read_text().splitlines()) == 1
+def test_append_history_keeps_the_record_whole_and_never_raises(tmp_path):
+    """An emitter's contract with the ledger: the record lands verbatim
+    (nested sections included, with the caller's timestamp), and a path
+    that cannot be written costs the line, never the run."""
+    path = tmp_path / "hist.jsonl"
+    rec = {"value": 42.0, "sections": {"batch8_tok_s": 800.0}, "ok": True}
+    line = pl.append_history(rec, str(path), ts=123.4567)
+    assert line["ts"] == 123.457 and line["record"] == rec
+    (row,) = path.read_text().splitlines()
+    assert json.loads(row) == line
+    assert pl.load_record(str(path))["sections"]["batch8_tok_s"] == 800.0
+    lost = pl.append_history(rec, str(tmp_path / "no" / "such" / "dir.jsonl"))
+    assert lost["record"] == rec
 
 
 def test_diff_flags_20pct_regression():

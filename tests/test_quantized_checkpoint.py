@@ -33,9 +33,6 @@ GREEDY = SamplingConfig(temperature=0.0, repeat_penalty=1.0)
 
 
 def _trees_equal(a, b) -> bool:
-    # jax.tree.leaves_with_path does not exist on this jax (0.4.37: the
-    # jax.tree alias module predates the with_path members); the tree_util
-    # spelling is the stable one across the versions this repo supports.
     la = jax.tree_util.tree_leaves_with_path(a)
     lb = dict(jax.tree_util.tree_leaves_with_path(b))
     if len(la) != len(lb):
