@@ -39,6 +39,7 @@ from __future__ import annotations
 import argparse
 import logging
 import sys
+import time
 
 from cake_tpu.utils import parse_address
 
@@ -550,8 +551,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--trace-dir",
         default=None,
-        help="write a JAX/XLA profiler trace (xplane, for TensorBoard/XProf) "
-        "of the generation to this directory",
+        help="directory for JAX/XLA profiler traces (xplane, for TensorBoard/XProf): "
+        "the one-shot CLI and a worker trace their whole run; an --api "
+        "server records one window per POST /profile?seconds=S (S <= 30)",
     )
     p.add_argument(
         "--events-jsonl",
@@ -1500,6 +1502,8 @@ def _benchdiff_main(argv: list[str]) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    # Where start-up goes (GET /stats ``startup``): filled as it happens.
+    startup: dict = {"t_main": time.perf_counter()}
     if argv is None:
         argv = sys.argv[1:]
     if argv and argv[0] == "stats":
@@ -1706,7 +1710,12 @@ def main(argv: list[str] | None = None) -> int:
         import dataclasses
 
         config = dataclasses.replace(config, chat_template=args.chat_template)
-    step = _build_master_step(args, config, topology, dtype, kv_dtype)
+    t_load = time.perf_counter()
+    startup["load"] = {}
+    step = _build_master_step(
+        args, config, topology, dtype, kv_dtype, startup["load"]
+    )
+    startup["load_s"] = round(time.perf_counter() - t_load, 3)
     if dist is not None:
         from cake_tpu.parallel.multihost import MultiHostStep
 
@@ -1730,10 +1739,12 @@ def main(argv: list[str] | None = None) -> int:
         # tokenizer/model errors, Ctrl-C — must release the followers, or
         # they stay parked in the broadcast collective. stop() is idempotent.
         try:
-            return _run_leader(args, step, config, sampling, dtype, kv_dtype)
+            return _run_leader(
+                args, step, config, sampling, dtype, kv_dtype, startup
+            )
         finally:
             step.stop()
-    return _run_leader(args, step, config, sampling, dtype, kv_dtype)
+    return _run_leader(args, step, config, sampling, dtype, kv_dtype, startup)
 
 
 def _resolve_kv_dtype(args, dtype):
@@ -1749,7 +1760,9 @@ def _resolve_kv_dtype(args, dtype):
     }[args.kv_dtype]
 
 
-def _run_leader(args, step, config, sampling, dtype, kv_dtype) -> int:
+def _run_leader(
+    args, step, config, sampling, dtype, kv_dtype, startup: dict
+) -> int:
     """The master-side tail of main(): generator + API server or one-shot."""
     from cake_tpu.models.llama.generator import LlamaGenerator
     from cake_tpu.models.llama.tokenizer import load_tokenizer
@@ -1813,7 +1826,6 @@ def _run_leader(args, step, config, sampling, dtype, kv_dtype) -> int:
     if args.api:
         from cake_tpu.models.llama.generator import LocalForwardStep
         from cake_tpu.runtime.api import ApiServer
-        from cake_tpu.utils import trace as _trace
 
         engine = None
         if args.api_batch > 1:
@@ -1918,6 +1930,7 @@ def _run_leader(args, step, config, sampling, dtype, kv_dtype) -> int:
                 peak_tflops=args.peak_tflops,
                 peak_hbm_gbps=args.peak_hbm_gbps,
             )
+            t_engine = time.perf_counter()
             engine = BatchEngine(
                 config,
                 engine_params,
@@ -1928,6 +1941,9 @@ def _run_leader(args, step, config, sampling, dtype, kv_dtype) -> int:
                 speculative_k=args.speculative_k,
                 proposer_factory=proposer_factory,
                 serve=serve_cfg,
+            )
+            startup["engine_init_s"] = round(
+                time.perf_counter() - t_engine, 3
             )
             if args.speculative_k and not hasattr(
                 engine.backend, "verify_greedy"
@@ -1955,11 +1971,13 @@ def _run_leader(args, step, config, sampling, dtype, kv_dtype) -> int:
                     deadline_s=args.heartbeat_deadline,
                 ).start()
         host, port = parse_address(args.api)
-        with _trace.jax_profile(args.trace_dir):
-            ApiServer(
-                generator, engine=engine, events_jsonl=args.events_jsonl,
-                trace_jsonl=args.trace_jsonl, request_log=args.request_log,
-            ).serve_forever(host, port)
+        # --trace-dir here is where POST /profile?seconds=S records its
+        # windows; a server's whole life is not a window anyone can read.
+        ApiServer(
+            generator, engine=engine, events_jsonl=args.events_jsonl,
+            trace_jsonl=args.trace_jsonl, request_log=args.request_log,
+            startup=startup, profile_dir=args.trace_dir,
+        ).serve_forever(host, port)
         return 0
 
     from cake_tpu.models.llama.chat import Message
@@ -1983,17 +2001,18 @@ def _run_leader(args, step, config, sampling, dtype, kv_dtype) -> int:
     return 0
 
 
-def _load_params(args, config, dtype, *, host: bool):
+def _load_params(args, config, dtype, *, host: bool, times: dict):
     """The checkpoint as a param tree, quantized if asked: on the default
     device, or with ``host`` in host memory (parallel.tensor.host_staging)
-    for a runner that shards it."""
+    for a runner that shards it. ``times`` receives the load's stages
+    (io/safetensors_io.load_params)."""
     import contextlib
 
     from cake_tpu.io.safetensors_io import load_params
     from cake_tpu.parallel.tensor import host_staging
 
     with host_staging() if host else contextlib.nullcontext():
-        params = load_params(args.model, config, dtype)
+        params = load_params(args.model, config, dtype, times=times)
         if args.quantize:
             from cake_tpu.ops.quant import quantize_params
 
@@ -2001,8 +2020,14 @@ def _load_params(args, config, dtype, *, host: bool):
     return params
 
 
-def _build_master_step(args, config, topology, dtype, kv_dtype):
-    """Pick mesh / tcp / local execution for the master."""
+def _build_master_step(
+    args, config, topology, dtype, kv_dtype, load: dict | None = None
+):
+    """Pick mesh / tcp / local execution for the master. ``load``, when
+    given, receives the seconds of the load's stages (where this process
+    loads weights)."""
+    if load is None:
+        load = {}
     import jax
 
     from cake_tpu.models.llama.generator import LocalForwardStep
@@ -2019,7 +2044,7 @@ def _build_master_step(args, config, topology, dtype, kv_dtype):
         # A tree that TensorParallelRunner is about to shard is built in
         # host memory, and the runner places each chip's shard once.
         params = _load_params(
-            args, config, dtype, host=args.tp > 1 and args.sp <= 1
+            args, config, dtype, host=args.tp > 1 and args.sp <= 1, times=load
         )
         if args.sp > 1:
             from cake_tpu.parallel.sequence import SequenceParallelRunner
@@ -2052,10 +2077,15 @@ def _build_master_step(args, config, topology, dtype, kv_dtype):
             and not args.speculative_k
         ):
             rolling_budget = max(args.prefill_chunk, args.decode_chunk)
-        return LocalForwardStep(
+        t_fuse = time.perf_counter()
+        step = LocalForwardStep(
             config, params, max_seq_len=args.max_seq_len, cache_dtype=kv_dtype,
             rolling_budget=rolling_budget,
         )
+        # The step's constructor builds the fused QKV and gate|up copies.
+        jax.block_until_ready(step.params)
+        load["fuse_s"] = round(time.perf_counter() - t_fuse, 3)
+        return step
 
     if args.sp > 1:
         raise SystemExit("--sp requires local execution (no topology backend)")
@@ -2086,7 +2116,7 @@ def _build_master_step(args, config, topology, dtype, kv_dtype):
         # own chip, and nothing whole ever sits on the first.
         return PipelineRunner(
             config,
-            _load_params(args, config, dtype, host=True),
+            _load_params(args, config, dtype, host=True, times=load),
             [(s.lo, s.hi) for s in plan],
             tp=args.tp,
             max_seq_len=args.max_seq_len,

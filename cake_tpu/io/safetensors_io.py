@@ -16,6 +16,7 @@ TPU-first differences:
 from __future__ import annotations
 
 import json
+import time
 from pathlib import Path
 from typing import Iterator
 
@@ -122,6 +123,8 @@ class SafetensorsReader:
     def __init__(self, paths: list[Path]):
         self._entries: dict[str, tuple[np.memmap, dict]] = {}
         self._mmaps: list[np.memmap] = []
+        # Seconds ``jax()`` spent paging tensors in from the files.
+        self.read_s = 0.0
         for path in paths:
             with open(path, "rb") as f:
                 header_len = int.from_bytes(f.read(8), "little")
@@ -163,7 +166,12 @@ class SafetensorsReader:
 
     def jax(self, name: str, dtype: jnp.dtype, transpose: bool = False) -> jnp.ndarray:
         mm, meta = self._entries[name]
+        t0 = time.perf_counter()
         arr = self.numpy(name)
+        # Touch one byte a page: the file system's part of the load ends
+        # here, and the transfer below reads memory that is mapped.
+        arr.reshape(-1).view(np.uint8)[::4096].max(initial=0)
+        self.read_s += time.perf_counter() - t0
         if meta["dtype"] == "BF16":
             x = jnp.asarray(arr).view(jnp.bfloat16)
         else:
@@ -399,12 +407,19 @@ def load_params(
     config: LlamaConfig,
     dtype: jnp.dtype = jnp.bfloat16,
     layer_range: tuple[int, int] | None = None,
+    times: dict | None = None,
 ) -> Params:
     """Load a full param pytree (or, for a worker, just a block range's layers).
 
     With ``layer_range`` set, only the stacked layer shard is returned — embedding,
     final norm, and lm_head stay on the master (llama.rs:178-196 vs worker.rs:95-108).
+
+    ``times``, when given, receives ``read_s`` (opening the checkpoint and
+    paging its tensors in) and ``put_s`` (moving them to the device in the
+    model's layout: transfer, transpose, cast, stack), the second ended by
+    ``block_until_ready`` on the whole tree.
     """
+    t0 = time.perf_counter()
     reader = open_checkpoint(model_dir)
     if layer_range is not None:
         lo, hi = layer_range
@@ -418,6 +433,12 @@ def load_params(
     }
     if not config.tie_word_embeddings:
         params["lm_head"] = read_weight(reader, "lm_head.weight", dtype, True)
+    if times is not None:
+        jax.block_until_ready(params)
+        times["read_s"] = round(reader.read_s, 3)
+        times["put_s"] = round(
+            time.perf_counter() - t0 - reader.read_s, 3
+        )
     return params
 
 

@@ -129,14 +129,14 @@ def first_sample(
 
     Returns (first [B] np.int32, carried key(s), ring, ring_idx).
     """
-    penalized = apply_repeat_penalty(logits, s.repeat_penalty, jnp.asarray(ring))
-    if row_keys is None:
-        key, sub = jax.random.split(jax.random.PRNGKey(s.seed))
-        first = sample(penalized, sub, s.temperature, s.top_k, s.top_p)
-    else:
-        pair = jax.vmap(jax.random.split)(row_keys)
-        key, sub = pair[:, 0], pair[:, 1]
-        first = sample_per_row(penalized, sub, s.temperature, s.top_k, s.top_p)
+    per_row = row_keys is not None
+    fn = _first_sample_fn(
+        s.temperature, s.top_k, s.top_p, s.repeat_penalty, per_row
+    )
+    first, key = fn(
+        logits, jnp.asarray(ring),
+        row_keys if per_row else jax.random.PRNGKey(s.seed),
+    )
     first = np.asarray(first).astype(np.int32)
     window = ring.shape[1]
     if window > 0:
@@ -144,6 +144,31 @@ def first_sample(
         ring[np.arange(b), ring_idx] = first
         ring_idx = (ring_idx + 1) % window
     return first, key, ring, ring_idx
+
+
+@functools.lru_cache(maxsize=16)
+def _first_sample_fn(temperature, top_k, top_p, repeat_penalty, per_row):
+    """Jit the device half of ``first_sample``: one program where the eager
+    form dispatched (and, per shape, compiled) a dozen."""
+
+    def run(logits, ring, keys):
+        penalized = apply_repeat_penalty(logits, repeat_penalty, ring)
+        if per_row:
+            pair = jax.vmap(jax.random.split)(keys)
+            key, sub = pair[:, 0], pair[:, 1]
+            first = sample_per_row(penalized, sub, temperature, top_k, top_p)
+        else:
+            key, sub = jax.random.split(keys)
+            first = sample(penalized, sub, temperature, top_k, top_p)
+        return first, key
+
+    return _tracked_jit(
+        run,
+        name=(
+            f"batch.first_sample[t={temperature},k={top_k},p={top_p},"
+            f"rp={repeat_penalty},rows={per_row}]"
+        ),
+    )
 
 
 def seed_rings(
@@ -665,13 +690,19 @@ def _decode_fn(
             f"batch.decode[n={n_steps},t={temperature},k={top_k},"
             f"p={top_p},rp={repeat_penalty}{fu}]"
         ),
+        module="decode_chunk_dense",
         donate_argnums=(1,),
     )
 
 
+# Module names (what a device trace shows, ``jit_<module>``): every prefill
+# starts ``prefill_``, every join ``prefill_join_``, every decode chunk
+# ``decode_chunk_``. The benchmark's readers select programs by these
+# (bench/layer_metrics/*.json); a rename there is a change of yardstick.
 _prefill_jit = _tracked_jit(
     batched_prefill,
     name="batch.prefill",
+    module="prefill_dense",
     static_argnames=("config",),
     donate_argnames=("kv",),
 )
@@ -804,6 +835,7 @@ def _paged_decode_fn(
             f"batch.paged_decode[n={n_steps},t={temperature},k={top_k},"
             f"p={top_p},rp={repeat_penalty}{fu}]"
         ),
+        module="decode_chunk_paged",
         donate_argnums=(1,),
     )
 
@@ -811,6 +843,7 @@ def _paged_decode_fn(
 _paged_prefill_jit = _tracked_jit(
     paged_prefill,
     name="batch.paged_prefill",
+    module="prefill_paged",
     static_argnames=("config", "allow_pallas"),
     donate_argnames=("kv",),
 )
@@ -859,6 +892,18 @@ def paged_suffix_prefill(
 _paged_suffix_jit = _tracked_jit(
     paged_suffix_prefill,
     name="batch.paged_suffix",
+    module="prefill_paged_suffix",
+    static_argnames=("config", "allow_pallas"),
+    donate_argnames=("kv",),
+)
+
+# The same arithmetic for one joining (or restored) row: its own jit, so
+# that a join is a program of its own name. Its shapes (one row, one lane's
+# table) never met the batch prefill's in one cache anyway.
+_paged_suffix_join_jit = _tracked_jit(
+    paged_suffix_prefill,
+    name="batch.paged_suffix_join",
+    module="prefill_join_paged_suffix",
     static_argnames=("config", "allow_pallas"),
     donate_argnames=("kv",),
 )

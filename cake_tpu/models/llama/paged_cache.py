@@ -37,6 +37,7 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from cake_tpu.obs.jitwatch import tracked_jit
 from cake_tpu.utils import metrics
 
 UNMAPPED = np.int32(-1)  # block-table sentinel: no physical page mapped
@@ -83,7 +84,17 @@ def init_paged_cache(
     exercise many-page layouts cheaply.
     """
     shape = (n_layers, n_pages, n_kv_heads, page_size, head_dim)
+    return _zero_pool(shape, jnp.dtype(dtype))
+
+
+def _zero_pool_impl(shape, dtype) -> PagedKVCache:
     return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+
+_zero_pool = tracked_jit(
+    _zero_pool_impl, name="paged.init_cache",
+    static_argnames=("shape", "dtype"),
+)
 
 
 def paged_write_layer(
@@ -157,12 +168,21 @@ def copy_pages(
     (src, dst) pairs host-side; this moves the bytes so the forked lane's
     private page starts as an exact copy of the shared one.
     """
-    src = jnp.asarray(src, jnp.int32)
-    dst = jnp.asarray(dst, jnp.int32)
+    return _copy_pages(
+        cache, jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32)
+    )
+
+
+def _copy_pages_impl(cache, src, dst):
     return PagedKVCache(
         k=cache.k.at[:, dst].set(cache.k[:, src]),
         v=cache.v.at[:, dst].set(cache.v[:, src]),
     )
+
+
+# Not donated: a caller that fails between the copy and its use of the
+# result still holds a valid pool.
+_copy_pages = tracked_jit(_copy_pages_impl, name="paged.copy_pages")
 
 
 class PageExhausted(RuntimeError):

@@ -19,6 +19,11 @@ did. This module wraps the project's jit families in a tracker:
     and (opt-in ``CAKE_RETRACE_FATAL=1``, for tests) raises RetraceError.
   * ``arm()`` declares warmup over: steady state must not trace at all.
     Tests warm the decode path, arm in fatal mode, and pin zero retraces.
+  * The XLA module of a tracked function is named for its FAMILY (the label
+    without its bracketed key, or ``module=``): a device trace then tells a
+    decode chunk from a join by name. The call that traced records a
+    ``compile`` span (label and argument shapes) on the timeline's engine
+    track, so it also lands in an open profiler window.
   * ``install_compile_listener()`` taps ``jax.monitoring`` for process-wide
     XLA backend-compile seconds — ``GET /stats`` reports the total and
     ``chip_smoke.py`` prints it per phase, cold beside warm-cache.
@@ -32,9 +37,11 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
+import re
 import threading
 import time
 
+from cake_tpu.obs.timeline import PROFILED_TRACK, timeline
 from cake_tpu.utils import metrics
 
 
@@ -137,6 +144,23 @@ class JitWatch:
                 for n in sorted(names)
             }
 
+    def by_family(self) -> dict[str, dict]:
+        """{family: {count, seconds}}: traces and the wall of the calls that
+        traced, summed over a family's keys (``batch.paged_join[w=64]`` and
+        ``[w=128]`` are one family)."""
+        out: dict[str, dict] = {}
+        with self._lock:
+            for label in set(self._traces) | set(self._compile_s):
+                fam = out.setdefault(
+                    family(label), {"count": 0, "seconds": 0.0}
+                )
+                fam["count"] += self._traces.get(label, 0)
+                fam["seconds"] += self._compile_s.get(label, 0.0)
+        return {
+            name: {"count": f["count"], "seconds": round(f["seconds"], 6)}
+            for name, f in sorted(out.items())
+        }
+
     def clear(self) -> None:
         with self._lock:
             self._traces.clear()
@@ -174,13 +198,46 @@ def _abstract_sig(args: tuple, kwargs: dict):
     return (str(treedef), tuple(parts))
 
 
-def tracked_jit(fn, *, name: str | None = None, **jit_kwargs):
+def family(label: str) -> str:
+    """A label without its bracketed key: ``batch.join[w=64]`` -> ``batch.join``."""
+    return label.split("[", 1)[0]
+
+
+def _array_shapes(args: tuple) -> list[str]:
+    """``dtype[shape]`` of a call's array arguments; pytree arguments (the
+    weights, the cache) are left out: their shapes never tell two compiles
+    of one family apart."""
+    return [
+        f"{a.dtype}{list(a.shape)}" for a in args
+        if hasattr(a, "shape") and hasattr(a, "dtype")
+    ]
+
+
+# One slot per tracked call in flight on this thread (a tracked function
+# traced inside another nests): None until the call's trace opens its
+# ``compile`` span. A trace outside any call (``_jitted.lower``) opens none.
+_in_flight = threading.local()
+
+
+def _slots() -> list:
+    try:
+        return _in_flight.slots
+    except AttributeError:
+        _in_flight.slots = []
+        return _in_flight.slots
+
+
+def tracked_jit(
+    fn, *, name: str | None = None, module: str | None = None, **jit_kwargs
+):
     """``jax.jit`` with the watchdog attached; same call surface/donation.
 
     ``name`` labels the metrics series — include the builder's cache key for
     per-cached-entry functions (``batch.decode[n=8,t=0.0,...]``) so a rebuilt
     lru entry retracing its old signature is flagged, while two entries that
-    legitimately share shapes are not.
+    legitimately share shapes are not. ``module`` names the XLA module
+    (``jit_<module>``, what a device trace shows); by default the label's
+    family as an identifier. A module name carries no shape.
     """
     import jax
 
@@ -190,23 +247,40 @@ def tracked_jit(fn, *, name: str | None = None, **jit_kwargs):
     def traced(*args, **kwargs):
         # Runs ONLY while jax traces (a compile-cache hit never enters
         # Python), so this is the exact trace count.
+        slots = _slots()
+        if slots and slots[-1] is None:
+            # Entered here, exited by ``call``: only a trace knows that the
+            # call compiles, and only the call knows when that is over.
+            slots[-1] = timeline.span(
+                "compile", track=PROFILED_TRACK,
+                args={"fn": label, "shapes": _array_shapes(args)},
+            )
+            slots[-1].__enter__()
         watch.note_trace(label, _abstract_sig(args, kwargs))
         return fn(*args, **kwargs)
 
+    traced.__name__ = traced.__qualname__ = module or re.sub(
+        r"\W", "_", family(label)
+    )
     jitted = jax.jit(traced, **jit_kwargs)
 
     @functools.wraps(fn)
     def call(*args, **kwargs):
-        before = watch.trace_count(label)
+        slots = _slots()
+        slots.append(None)
         t0 = time.perf_counter()
-        out = jitted(*args, **kwargs)
-        if watch.trace_count(label) > before:
-            # This call traced: the wall delta is trace+lower+compile plus
-            # one async dispatch — compile dominates, and that is the number
-            # a serving operator needs ("what stalled the epoch").
-            # cake-lint: disable-next-line=unblocked-timing
-            watch.note_compile(label, time.perf_counter() - t0)
-        return out
+        try:
+            return jitted(*args, **kwargs)
+        finally:
+            span = slots.pop()
+            if span is not None:
+                # This call traced: the wall delta is trace+lower+compile
+                # plus one async dispatch — compile dominates, and that is
+                # the number a serving operator needs ("what stalled the
+                # epoch").
+                # cake-lint: disable-next-line=unblocked-timing
+                watch.note_compile(label, time.perf_counter() - t0)
+                span.__exit__(None, None, None)
 
     call._jitted = jitted  # escape hatch (lower/compile introspection)
     call._watch_name = label
@@ -250,3 +324,23 @@ def compile_totals() -> tuple[int, float]:
     """(backend compiles seen, total seconds) since the listener went in."""
     with _listener_lock:
         return _compile_events, _compile_total_s
+
+
+def compile_stats() -> dict:
+    """The ``compile`` block of ``GET /stats``: every backend compile in the
+    process (``count``, ``seconds``; with a warm persistent cache the time to
+    fetch them), the wall the tracked calls that traced spent doing so
+    (``stall_seconds``: trace + lower + compile or fetch, what the calling
+    thread waited), the same by family, and ``untracked``: compiles no
+    tracked family accounts for (eager operations, plain ``jax.jit``)."""
+    count, seconds = compile_totals()
+    families = watch.by_family()
+    return {
+        "count": count,
+        "seconds": round(seconds, 3),
+        "stall_seconds": round(
+            sum(f["seconds"] for f in families.values()), 6
+        ),
+        "by_family": families,
+        "untracked": count - sum(f["count"] for f in families.values()),
+    }

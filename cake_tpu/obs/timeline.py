@@ -35,6 +35,12 @@ an ``epoch`` span; the continuous scheduler roots a ``segment`` span and
 nests one ``step`` span per scheduler iteration (restores + budgeted joins),
 with ``preempted``/``restored`` instants on the lane tracks — obs/critpath.py
 attributes ``restore`` spans to their own phase.
+
+One primitive, two sinks: a span on the ``engine`` track (``span()`` or
+``begin()/end()``) also enters a ``jax.profiler.TraceAnnotation`` of the same
+name, so it lands on the host plane of whatever profiler window is open, on
+the device trace's clock. With no window open that is one flag check. jax
+is imported when the first such span opens, never by importing this module.
 """
 
 from __future__ import annotations
@@ -68,6 +74,25 @@ def _clocks() -> tuple[float, float]:
     return time.time(), time.perf_counter()
 
 
+# The track whose spans are bridged to the profiler: the thread that drives
+# the device. Lane tracks hold ``request`` spans that cross iterations and
+# cannot nest on a thread's annotation stack.
+PROFILED_TRACK = "engine"
+_annotation_cls = None
+
+
+def _annotation(name: str, args: dict | None):
+    """An entered ``TraceAnnotation`` carrying ``args`` as its metadata."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    ann = _annotation_cls(name, **(args or {}))
+    ann.__enter__()
+    return ann
+
+
 class Timeline:
     """Bounded ring of profiling events + the Perfetto exporter over it."""
 
@@ -76,6 +101,8 @@ class Timeline:
         self._ring: deque[dict] = deque(maxlen=int(capacity))
         self._jsonl_path: str | None = None
         self.node = node  # default pid label; per-event ``node=`` overrides
+        # begin() spans on the profiled track: span id -> open annotation.
+        self._annotations: dict[int, Any] = {}
 
     @property
     def capacity(self) -> int:
@@ -156,12 +183,15 @@ class Timeline:
         span id so the body can parent flight events / flow arrows to it."""
         sid = next(_ids)
         parent = current_span_id()
+        ann = _annotation(name, args) if track == PROFILED_TRACK else None
         wall, mono = _clocks()
         token = _CURRENT.set((self, sid))
         try:
             yield sid
         finally:
             _CURRENT.reset(token)
+            if ann is not None:
+                ann.__exit__(None, None, None)
             self._event(
                 "X", name, sid=sid, parent=parent, rid=rid, node=node,
                 track=track, args=args, wall=wall, mono=mono,
@@ -184,6 +214,8 @@ class Timeline:
         span, which outlives the engine spans that happen to be open when it
         is admitted — parenting it there would double-count their self time)."""
         sid = next(_ids)
+        if track == PROFILED_TRACK:
+            self._annotations[sid] = _annotation(name, args)
         self._event(
             "B", name, sid=sid,
             parent=current_span_id() if parent == "auto" else parent,
@@ -195,6 +227,9 @@ class Timeline:
         """Close a ``begin()`` span. The name/track ride the B side; the
         exporter pairs by id. Unknown/evicted ids still record honestly (the
         exporter drops unpaired ends)."""
+        ann = self._annotations.pop(sid, None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
         self._event("E", "", sid=sid, args=args)
 
     def instant(self, name: str, **kw) -> None:

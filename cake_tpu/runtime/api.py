@@ -44,9 +44,11 @@ runs with zero third-party server dependencies.
 from __future__ import annotations
 
 import dataclasses
+import glob
 import json
 import logging
 import math
+import os
 import threading
 import time
 import uuid
@@ -60,6 +62,8 @@ log = logging.getLogger("cake_tpu.api")
 
 CHAT_ROUTE = "/api/v1/chat/completions"
 CANCEL_ROUTE = "/api/v1/cancel"
+PROFILE_ROUTE = "/profile"
+MAX_PROFILE_SECONDS = 30.0
 
 # Tenant ids key metric labels, quota buckets, and fair-queue subqueues;
 # bounding their length keeps a hostile header from being a label-
@@ -96,9 +100,19 @@ class ApiServer:
     # JSON line; the bounded ring stays live at GET /requests either way,
     # and the file IS the loadgen replay trace (cake_tpu/loadgen/replay.py).
     request_log: "str | None" = None
+    # Where start-up went, as the entry point measured it (cli.main):
+    # ``t_main`` (perf_counter at its entry), ``load_s`` with ``load`` by
+    # stage, ``engine_init_s``. ``serve_forever`` adds ``main_to_ready_s``
+    # when the socket listens; GET /stats carries the block as ``startup``.
+    startup: "dict | None" = None
+    # Where ``POST /profile?seconds=S`` writes its profiler windows
+    # (--trace-dir); None = the route answers 404.
+    profile_dir: "str | None" = None
 
     def __post_init__(self) -> None:
         self._lock = threading.Lock()
+        with self._lock:
+            self._profiling = False  # a POST /profile window is open
         self._started = int(time.time())
         if self.events_jsonl:
             from cake_tpu.utils import metrics
@@ -124,6 +138,49 @@ class ApiServer:
         jitwatch.install_compile_listener()
         if self.engine is not None:
             self.engine.start()
+
+    def startup_stats(self) -> dict:
+        """The ``startup`` block of GET /stats (empty when the server was
+        not started through the CLI)."""
+        return {
+            k: v for k, v in (self.startup or {}).items() if k != "t_main"
+        }
+
+    def profile(self, seconds: float) -> dict:
+        """Record one profiler window of ``seconds`` into ``profile_dir``
+        and return where it went. The window holds the device's operations
+        and, on the host plane, the engine's spans (obs/timeline.py) and
+        jit dispatches. Python's own tracer stays off: it slows the host it
+        is meant to watch. One window at a time (409 meanwhile): the
+        profiler is process-wide."""
+        if self.profile_dir is None:
+            raise ApiError(404, "profiling needs --trace-dir DIR at start-up")
+        if not 0 < seconds <= MAX_PROFILE_SECONDS:
+            raise ApiError(
+                400, f"seconds must be in (0, {MAX_PROFILE_SECONDS:g}]"
+            )
+        with self._lock:
+            if self._profiling:
+                raise ApiError(409, "a profiler window is already open")
+            self._profiling = True
+        try:
+            from cake_tpu.utils import trace
+
+            with trace.jax_profile(self.profile_dir, host_python=False):
+                t0 = time.perf_counter()
+                time.sleep(seconds)
+                t1 = time.perf_counter()
+            files = glob.glob(
+                f"{self.profile_dir}/plugins/profile/*/*.xplane.pb"
+            )
+            return {
+                "path": max(files, key=os.path.getmtime) if files else None,
+                "seconds": round(t1 - t0, 3),
+                "close_seconds": round(time.perf_counter() - t1, 3),
+            }
+        finally:
+            with self._lock:
+                self._profiling = False
 
     def device_info(self) -> dict:
         """What is serving: the device as JAX reports it and the attention
@@ -782,16 +839,16 @@ class ApiServer:
                     from cake_tpu.obs.timeline import timeline
                     from cake_tpu.utils import metrics, trace
 
-                    n_compiles, compile_s = jitwatch.compile_totals()
                     body = {
                         "model": api.model_name,
                         "uptime_s": round(time.time() - api._started, 3),
                         # Process-wide backend compiles since start (with a
-                        # warm persistent cache: the time to fetch them).
-                        "compile": {
-                            "count": n_compiles,
-                            "seconds": round(compile_s, 3),
-                        },
+                        # warm persistent cache: the time to fetch them),
+                        # what they stalled, and which tracked family paid.
+                        "compile": jitwatch.compile_stats(),
+                        # Where start-up went (cli.main: load by stage,
+                        # engine construction, entry to listening).
+                        "startup": api.startup_stats(),
                         "spans": trace.spans.snapshot(),
                         # Structured span tree aggregate (total vs SELF time
                         # per span name) over the timeline ring — what
@@ -819,6 +876,11 @@ class ApiServer:
                         body["cluster"] = cluster.snapshot()
                     if api.engine is not None:
                         body["engine"] = dict(api.engine.stats)
+                        periods = getattr(api.engine, "periods", None)
+                        if periods is not None:
+                            # The step loop's cumulative account
+                            # (obs/period.py): ``period`` and ``segment``.
+                            body["engine"].update(periods.snapshot())
                         # Which scheduler shape is serving (README
                         # "Continuous scheduling") plus the spill table's
                         # current depth — preempted lanes parked host-side
@@ -869,6 +931,22 @@ class ApiServer:
                     self._json(404, {"error": "not found"})
 
             def do_POST(self):
+                from urllib.parse import parse_qs, urlparse
+
+                parsed = urlparse(self.path)
+                if parsed.path == PROFILE_ROUTE:
+                    try:
+                        seconds = float(
+                            parse_qs(parsed.query).get("seconds", [""])[0]
+                        )
+                        self._json(200, api.profile(seconds))
+                    except ValueError:
+                        self._json(
+                            400, {"error": "POST /profile?seconds=S"}
+                        )
+                    except ApiError as e:
+                        self._json(e.code, {"error": str(e)})
+                    return
                 if self.path not in (CHAT_ROUTE, CANCEL_ROUTE):
                     # Reference returns a default 404 for everything else
                     # (api/mod.rs:105-107).
@@ -919,6 +997,10 @@ class ApiServer:
 
     def serve_forever(self, host: str, port: int) -> None:
         server = self.make_server(host, port)
+        if self.startup and "t_main" in self.startup:
+            self.startup["main_to_ready_s"] = round(
+                time.perf_counter() - self.startup["t_main"], 3
+            )
         log.info("API listening on http://%s:%d%s", host, port, CHAT_ROUTE)
         log.info(
             "serving on %s",

@@ -200,7 +200,8 @@ def _local_join_fn(config, width, max_seq_len, cache_dtype):
     from cake_tpu.obs.jitwatch import tracked_jit
 
     return tracked_jit(
-        run, name=f"batch.join[w={width}]", donate_argnums=(1,)
+        run, name=f"batch.join[w={width}]", module="prefill_join_dense",
+        donate_argnums=(1,),
     )
 
 
@@ -306,7 +307,8 @@ def _paged_join_fn(config, width, allow_pallas=True):
     from cake_tpu.obs.jitwatch import tracked_jit
 
     return tracked_jit(
-        run, name=f"batch.paged_join[w={width}]", donate_argnums=(1,)
+        run, name=f"batch.paged_join[w={width}]",
+        module="prefill_join_paged", donate_argnums=(1,),
     )
 
 
@@ -561,13 +563,13 @@ class PagedLocalBackend:
         threshold drop) or computed fresh. The lane table is sliced to the
         SAME epoch capacity as every other dispatch (the one-capacity rule,
         class docstring)."""
-        from cake_tpu.models.llama.batch import _paged_suffix_jit
+        from cake_tpu.models.llama.batch import _paged_suffix_join_jit
 
         self._kernel_note("suffix_join")
         self._check_write_bound(
             "suffix_join", int(start) + int(jnp.shape(row_tokens)[1])
         )
-        return _paged_suffix_jit(
+        return _paged_suffix_join_jit(
             self.params, jnp.asarray(row_tokens), kv,
             jnp.asarray(pads1, jnp.int32),
             jnp.asarray(write_starts1, jnp.int32),

@@ -58,10 +58,12 @@ BackendWorkerError path a dead worker takes.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import threading
 import time
+import types
 from collections import deque
 from typing import Iterator
 
@@ -76,7 +78,9 @@ from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.models.llama.generator import SamplingConfig, Token, decode_delta
 from cake_tpu.models.llama.tokenizer import Tokenizer
 from cake_tpu.obs import memwatch
-from cake_tpu.obs.timeline import timeline
+from cake_tpu.obs.jitwatch import tracked_jit
+from cake_tpu.obs.period import PeriodAccount
+from cake_tpu.obs.timeline import PROFILED_TRACK, timeline
 from cake_tpu.runtime import faults
 from cake_tpu.runtime.admission import (
     DEFAULT_TENANT,
@@ -102,6 +106,16 @@ _DONE = "__done__"
 # width bucketing bounds join/suffix windows (coarser here — capacity feeds
 # whole kernel grids, not one window operand).
 _CAPACITY_BUCKET = 256
+
+
+def _set_lane_rows(arrays, lane, values):
+    return tuple(a.at[lane].set(v) for a, v in zip(arrays, values))
+
+
+# Write one lane's row of each per-lane state array (token, PRNG key, pad,
+# penalty ring): a join or a restore updates them together, in one program
+# where eager ``.at[lane].set`` took five dispatches an array.
+_set_lane = tracked_jit(_set_lane_rows, name="engine.set_lane")
 
 
 class EngineOverloaded(RuntimeError):
@@ -784,6 +798,11 @@ class BatchEngine:
             # lanes re-attached (bit-identical resume).
             "preemptions": 0, "restores": 0,
         }
+        # The step loop's cumulative account (obs/period.py): periods by
+        # phase, joins, lane-seconds, segments — ``GET /stats`` carries its
+        # snapshot under ``engine.period`` / ``engine.segment``.
+        self.periods = PeriodAccount(self.max_batch)
+        self._segment_args: dict = {}
         # Latency attribution (README "Latency attribution & black-box
         # diagnostics"): live per-phase accounting — the engine knows each
         # dispatch's wall time and how many of its tokens every row
@@ -1695,6 +1714,7 @@ class BatchEngine:
                     self._queue.clear()
                     self._fail_spilled_locked("engine stopped")
                     return
+            self.periods.work_seen()
             # Admission window: let a burst of concurrent submissions land so
             # they batch together instead of trickling into 1-row batches.
             # The continuous scheduler skips it — requests admit the moment
@@ -1802,7 +1822,7 @@ class BatchEngine:
 
             raise BackendWorkerError("<fault-plan>", op)
 
-    def _dispatch(self, op: str, fn):
+    def _dispatch(self, op: str, fn, readback=None):
         """Run one backend dispatch (fault checkpoint included) under the
         stuck-epoch watchdog. With ``epoch_stall_s`` off this is exactly
         the old inline guard+call; with it on, the dispatch runs on the
@@ -1824,19 +1844,63 @@ class BatchEngine:
         own per-op deadlines/retries (``op_deadline_s``) already convert a
         hung worker into BackendWorkerError without the watchdog, so the
         guard is the local/device half of the same bound, not a substitute
-        for wire deadlines."""
+        for wire deadlines.
+
+        ``readback`` is the host's wait for the result (the chunk's
+        ``np.asarray``); with it the call returns ``(out, readback(out))``.
+        The enqueue is a ``dispatch`` span and the wait a ``readback`` span;
+        under the watchdog the engine thread only waits, so the whole call
+        is ``readback``."""
         if self._guard is None:
             self._backend_guard(op)
-            return fn()
+            with self._phase("dispatch"):
+                out = fn()
+            if readback is None:
+                return out
+            with self._phase("readback"):
+                return out, readback(out)
 
         def job():
             self._backend_guard(op)
             # Block on EVERY output leaf while still on the watchdog
             # thread: dispatch-accepted-but-readback-hung is the wedged-
             # device shape the watchdog exists for.
-            return jax.block_until_ready(fn())
+            out = jax.block_until_ready(fn())
+            return out if readback is None else (out, readback(out))
 
-        return self._guard.call(job, op=op)
+        with self._phase("readback"):
+            return self._guard.call(job, op=op)
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, *, rid: str | None = None,
+               args: dict | None = None):
+        """One span of the step loop, recorded twice from one clock: on the
+        timeline's engine track (and so in an open profiler window) and, as
+        self time by name, in the cumulative period account. Yields the
+        span; ``seconds`` is its duration once it has closed."""
+        span = types.SimpleNamespace(seconds=0.0)
+        with timeline.span(name, rid=rid, track=PROFILED_TRACK, args=args):
+            self.periods.push(name)
+            try:
+                yield span
+            finally:
+                span.seconds = self.periods.pop()
+
+    @contextlib.contextmanager
+    def _period(self, slot: int):
+        """Root span of one iteration of the step loop. The body sets
+        ``dispatched`` and ``live`` on the yielded arguments once it has
+        dispatched a chunk or a round; an iteration that did not is no
+        period (obs/period.py)."""
+        with self._cv:
+            queued = bool(self._queue) or bool(self._spilled)
+        args = {"slot": int(slot), "queued": queued, "dispatched": False}
+        self.periods.begin(queued)
+        with timeline.span("period", track=PROFILED_TRACK, args=args):
+            try:
+                yield args
+            finally:
+                self.periods.end(args["live"] if args["dispatched"] else None)
 
     # ------------------------------------------------- replica failover
     # Transparent recovery (README "Failover"): when a worker dies after
@@ -2315,6 +2379,28 @@ class BatchEngine:
             )
         self._epoch_head_rid = head_rid
         self._epoch_stalled = False
+        # The root span's arguments are filled as the segment learns them
+        # (_run_epoch: lanes, prompt bucket, capacity, its prefill's seconds,
+        # why it ended) and serialize when it closes.
+        with self._cv:
+            queue_depth = len(self._queue)
+        self._segment_args = seg_args = {
+            "rows": len(batch),
+            "queue_depth": queue_depth,
+            "kv_mode": self.kv_mode,
+            "scheduler": self.scheduler,
+            # Kernel vs fallback choice, resolved exactly as the batched
+            # forward resolves it at trace time — so a trace captured on
+            # CPU says "xla" and one on TPU says "pallas" without reading
+            # configs.
+            "attention_impl": M.resolve_attention_impl(
+                self.config.attention_impl
+            ),
+            "fusion_impl": self.config.fusion_impl,
+            "prefill_s": 0.0,
+            "ended": "error",
+        }
+        t_segment = time.perf_counter()
         try:
             # The epoch span roots this epoch's timeline tree: prefill /
             # decode-chunk / join / page-extend spans nest under it, lane
@@ -2325,20 +2411,7 @@ class BatchEngine:
             # scheduler iteration inside it.
             with timeline.span(
                 "epoch" if self.scheduler != "continuous" else "segment",
-                rid=head_rid, track="engine",
-                args={
-                    "rows": len(batch),
-                    "kv_mode": self.kv_mode,
-                    "scheduler": self.scheduler,
-                    # Kernel vs fallback choice, resolved exactly as the
-                    # batched forward resolves it at trace time — so a trace
-                    # captured on CPU says "xla" and one on TPU says
-                    # "pallas" without reading configs.
-                    "attention_impl": M.resolve_attention_impl(
-                        self.config.attention_impl
-                    ),
-                    "fusion_impl": self.config.fusion_impl,
-                },
+                rid=head_rid, track="engine", args=seg_args,
             ):
                 self._run_epoch(batch, rows)
         except BackendWorkerError as e:
@@ -2369,6 +2442,9 @@ class BatchEngine:
             # _loop's handler covers rows that never made it into `rows`.
             raise
         finally:
+            self.periods.segment_done(
+                time.perf_counter() - t_segment, seg_args["prefill_s"]
+            )
             # Paged: the epoch is over — EVERY lane's pages go back to the
             # pool (also on the error path, so _admit always sees the whole
             # pool free at the next epoch start). A CLEAN epoch end first
@@ -2418,6 +2494,7 @@ class BatchEngine:
             # requests with the same knobs join per step as usual.
             seed_spills = self._pop_spill_seed()
             if not seed_spills:
+                self._segment_args["ended"] = "empty"
                 return
             self._note_batch_started(len(seed_spills))
             head = seed_spills[0].row.req
@@ -2588,6 +2665,10 @@ class BatchEngine:
         # positions for every lane — a lane's own share scales with its
         # prompt, the rest is convoy (the padding half of the lockstep tax).
         dt_prefill = time.perf_counter() - t_prefill
+        self._segment_args.update(
+            lanes=B, bucket=int(bucket), capacity=int(cap),
+            prefill_s=round(dt_prefill, 6),
+        )
         own_tok = 0
         for row in rows:
             if row is not None:
@@ -2658,6 +2739,7 @@ class BatchEngine:
         # capacity — which covers every admitted row's full budget, so the
         # clamp never truncates a stream below what max_seq_len would give.
 
+        ended = "capacity"  # the loop's own end: the slot reached the cap
         while slot < cap - 1:
             if self._stop:
                 # stop() must not wait out a long epoch: close every live
@@ -2670,206 +2752,237 @@ class BatchEngine:
                         row.close_span(error="engine stopped")
                         self._row_finished(row.req.rid)
                         rows[lane] = None
+                self._segment_args["ended"] = "stopped"
                 return
-            # Cancellation + deadline sweeps at the chunk boundary: flagged
-            # rows finish "cancelled" and over-deadline rows finish
-            # "deadline" NOW — their pages return to the pool (release just
-            # below) and their lanes are joinable this very round; queued
-            # requests past their deadline expire without ever admitting.
-            self._apply_cancels(rows)
-            self._apply_deadlines(rows)
-            self._release_finished(rows)
-            # Per-step scheduling (continuous): grant this step's prefill
-            # budget (SLO-aware, runtime/admission.StepBudget), restore
-            # spilled lanes FIRST (previously admitted work beats new
-            # admissions), then admit queued joins the moment lanes and
-            # pages are free. Epoch mode keeps the unbudgeted join path.
-            # A join failure must not strand the popped requests: anything
-            # not yet admitted into `rows` gets the error directly (rows
-            # themselves are covered by _run_batch).
-            budget = None
-            step_span = None
-            join_args: list = []
-            if self.scheduler == "continuous":
-                # A segment under sustained joins may never drain, so the
-                # SLO feedback (fair-queue weights, shed scales — and the
-                # burning signal the step budget reads) must apply HERE,
-                # not only between segments. Rate-limited internally to
-                # ~1/s; epoch mode keeps its between-epoch cadence.
-                self._apply_slo_feedback()
-                budget = {"left": self._grant_step_budget(rows)}
-                step_span = timeline.begin(
-                    "step", track="engine",
-                    args={
+            with self._period(slot) as period:
+                budget = None
+                with self._phase("sweep"):
+                    # Cancellation + deadline sweeps at the chunk boundary:
+                    # flagged rows finish "cancelled" and over-deadline rows
+                    # finish "deadline" NOW — their pages return to the pool
+                    # (release just below) and their lanes are joinable this
+                    # very round; queued requests past their deadline expire
+                    # without ever admitting.
+                    self._apply_cancels(rows)
+                    self._apply_deadlines(rows)
+                    self._release_finished(rows)
+                    if self.scheduler == "continuous":
+                        # A segment under sustained joins may never drain,
+                        # so the SLO feedback (fair-queue weights, shed
+                        # scales — and the burning signal the step budget
+                        # reads) must apply HERE, not only between
+                        # segments. Rate-limited internally to ~1/s; epoch
+                        # mode keeps its between-epoch cadence.
+                        self._apply_slo_feedback()
+                        budget = {"left": self._grant_step_budget(rows)}
+                # Per-step scheduling (continuous): this step's prefill
+                # budget (SLO-aware, runtime/admission.StepBudget) was
+                # granted above; restore spilled lanes FIRST (previously
+                # admitted work beats new admissions), then admit queued
+                # joins the moment lanes and pages are free. Epoch mode
+                # keeps the unbudgeted join path. A join failure must not
+                # strand the popped requests: anything not yet admitted
+                # into `rows` gets the error directly (rows themselves are
+                # covered by _run_batch).
+                join_args: list = []
+                step_args: dict = {}
+                if budget is not None:
+                    step_args = {
                         "slot": int(slot),
                         "live": sum(r is not None for r in rows),
                         "budget": budget["left"],
-                    },
-                )
-            try:
-                if budget is not None:
-                    (
-                        tok, kv, keys, ring_j, ring_idx_j, pads_j
-                    ) = self._take_restores(
-                        knobs, rows, slot, cap, budget, tok, kv, keys,
-                        ring_j, ring_idx_j, pads_j, s,
-                    )
-                join_args = self._take_joins(knobs, rows, slot, cap, budget)
-                joined: set[int] = set()
-                try:
-                    for lane, req in join_args:
-                        while True:
-                            try:
-                                tok, kv, keys, ring_j, ring_idx_j = self._join(
-                                    req, lane, rows, slot, tok, kv, keys,
-                                    ring_j, ring_idx_j, s,
-                                )
-                                break
-                            except BackendWorkerError as e:
-                                # A join prefill lost its worker: migrate the
-                                # epoch's live rows to the new route, then
-                                # retry the join there (the joiner saw no side
-                                # effects — its first token samples only after
-                                # backend.join returns).
-                                self._failover_or_raise(e)
-                                kv = self._migrate_kv(rows, B, slot)
-                        joined.add(id(req))
-                        pads_j = pads_j.at[lane].set(
-                            slot - len(req.prompt_ids)
-                        )
-                except Exception as e:
-                    for _, req2 in join_args:
-                        if id(req2) not in joined:
-                            if isinstance(e, BackendWorkerError):
-                                # Same isolation as admitted rows: a graceful
-                                # "error" finish, not a raised exception.
-                                _fail_request(req2, str(e), engine=self)
-                            else:
-                                req2.handle._emit(e)
-                                req2.handle._emit(_DONE)
-                            # Popped-but-never-joined: finish() never runs
-                            # for these, so deregister here or cancel()
-                            # would claim them live forever.
-                            self._row_finished(req2.rid)
-                    raise
-            finally:
-                if step_span is not None:
-                    timeline.end(
-                        step_span, args={"joins": len(join_args)}
-                    )
-            live = sum(r is not None for r in rows)
-            metrics.registry.gauge(
-                "cake_batch_occupancy",
-                "Live lockstep lanes at the current chunk boundary.",
-            ).set(live)
-            if not live:
-                break
-            if self._spec_applicable(s, slot, cap):
-                # The verify chunk WRITES slots [slot, slot + K + 1) through
-                # the block table — map those pages first (an unmapped slot
-                # silently drops the chunk's KV). Dense backends skip this;
-                # a page-truncated row degrades exactly like the decode path.
-                if self._alloc is not None and not self._extend_pages(
-                    rows, slot, self.speculative_k + 1,
-                    spill_ctx=(keys, ring_j, ring_idx_j),
+                    }
+                with (
+                    self._phase("step", args=step_args)
+                    if budget is not None
+                    else contextlib.nullcontext()
                 ):
+                    try:
+                        if budget is not None:
+                            (
+                                tok, kv, keys, ring_j, ring_idx_j, pads_j
+                            ) = self._take_restores(
+                                knobs, rows, slot, cap, budget, tok, kv,
+                                keys, ring_j, ring_idx_j, pads_j, s,
+                            )
+                        join_args = self._take_joins(
+                            knobs, rows, slot, cap, budget
+                        )
+                        joined: set[int] = set()
+                        try:
+                            for lane, req in join_args:
+                                while True:
+                                    try:
+                                        (
+                                            tok, kv, keys, ring_j, ring_idx_j
+                                        ) = self._join(
+                                            req, lane, rows, slot, tok, kv,
+                                            keys, ring_j, ring_idx_j, s,
+                                        )
+                                        break
+                                    except BackendWorkerError as e:
+                                        # A join prefill lost its worker:
+                                        # migrate the epoch's live rows to
+                                        # the new route, then retry the
+                                        # join there (the joiner saw no
+                                        # side effects — its first token
+                                        # samples only after backend.join
+                                        # returns).
+                                        self._failover_or_raise(e)
+                                        kv = self._migrate_kv(rows, B, slot)
+                                joined.add(id(req))
+                                (pads_j,) = _set_lane(
+                                    (pads_j,), lane,
+                                    (slot - len(req.prompt_ids),),
+                                )
+                        except Exception as e:
+                            for _, req2 in join_args:
+                                if id(req2) not in joined:
+                                    if isinstance(e, BackendWorkerError):
+                                        # Same isolation as admitted rows:
+                                        # a graceful "error" finish, not a
+                                        # raised exception.
+                                        _fail_request(
+                                            req2, str(e), engine=self
+                                        )
+                                    else:
+                                        req2.handle._emit(e)
+                                        req2.handle._emit(_DONE)
+                                    # Popped-but-never-joined: finish()
+                                    # never runs for these, so deregister
+                                    # here or cancel() would claim them
+                                    # live forever.
+                                    self._row_finished(req2.rid)
+                            raise
+                    finally:
+                        step_args["joins"] = len(join_args)
+                live = sum(r is not None for r in rows)
+                metrics.registry.gauge(
+                    "cake_batch_occupancy",
+                    "Live lockstep lanes at the current chunk boundary.",
+                ).set(live)
+                if not live:
+                    ended = "drained"
+                    break
+                self.periods.first_dispatch()
+                if self._spec_applicable(s, slot, cap):
+                    # The verify chunk WRITES slots [slot, slot + K + 1)
+                    # through the block table — map those pages first (an
+                    # unmapped slot silently drops the chunk's KV). Dense
+                    # backends skip this; a page-truncated row degrades
+                    # exactly like the decode path.
+                    if self._alloc is not None and not self._extend_pages(
+                        rows, slot, self.speculative_k + 1,
+                        spill_ctx=(keys, ring_j, ring_idx_j),
+                    ):
+                        ended = "pages"
+                        break  # every remaining row truncated or spilled
+                    try:
+                        # Mutable span args: _spec_round stamps the round's
+                        # accepted advance + K before the span serializes
+                        # at exit, so /explain can split accepted vs
+                        # wasted time.
+                        sargs = {"slot": int(slot)}
+                        with self._phase("spec-round", args=sargs):
+                            res = self._spec_round(
+                                rows, kv, tok, slot, pads_j, keys, s,
+                                span_args=sargs,
+                            )
+                    except BackendWorkerError as e:
+                        # Verify-round worker death: migrate the live
+                        # streams, then take this round as a plain decode
+                        # chunk (the half-written verify tail on the dead
+                        # route is gone with it; sampling state never
+                        # advanced).
+                        self._failover_or_raise(e)
+                        kv = self._migrate_kv(rows, B, slot)
+                        res = None
+                    if res is not None:
+                        tok, kv, keys, slot = res
+                        period.update(dispatched=True, live=live)
+                        continue
+                n = min(self.decode_chunk_size, cap - 1 - slot)
+                if self._alloc is not None and not self._extend_pages(
+                    rows, slot, n, spill_ctx=(keys, ring_j, ring_idx_j)
+                ):
+                    ended = "pages"
                     break  # every remaining row was truncated or spilled
                 try:
-                    # Mutable span args: _spec_round stamps the round's
-                    # accepted advance + K before the span serializes at
-                    # exit, so /explain can split accepted vs wasted time.
-                    sargs = {"slot": int(slot)}
-                    with timeline.span(
-                        "spec-round", track="engine", args=sargs
-                    ):
-                        res = self._spec_round(
-                            rows, kv, tok, slot, pads_j, keys, s,
-                            span_args=sargs,
-                        )
-                except BackendWorkerError as e:
-                    # Verify-round worker death: migrate the live streams,
-                    # then take this round as a plain decode chunk (the
-                    # half-written verify tail on the dead route is gone
-                    # with it; sampling state never advanced).
-                    self._failover_or_raise(e)
-                    kv = self._migrate_kv(rows, B, slot)
-                    res = None
-                if res is not None:
-                    tok, kv, keys, slot = res
-                    continue
-            n = min(self.decode_chunk_size, cap - 1 - slot)
-            if self._alloc is not None and not self._extend_pages(
-                rows, slot, n, spill_ctx=(keys, ring_j, ring_idx_j)
-            ):
-                break  # every remaining row was truncated or spilled
-            # The np.asarray readback inside the span blocks on the device,
-            # so the slice is real chunk compute, not dispatch time.
-            t_chunk = time.perf_counter()
-            try:
-                with timeline.span(
-                    "decode-chunk", track="engine",
-                    args={"slot": int(slot), "n": int(n), "live": live},
-                ):
-
-                    def _chunk():
-                        out = self.backend.decode(
-                            kv, tok, slot, pads_j, keys, ring_j,
-                            ring_idx_j, n, s,
-                        )
+                    # The program's key rides the span: lanes x capacity x
+                    # n name the compiled decode program, slot and live
+                    # what it was run on.
+                    with self._phase(
+                        "decode-chunk",
+                        args={
+                            "lanes": B, "capacity": int(cap),
+                            "slot": int(slot), "n": int(n), "live": live,
+                        },
+                    ) as chunk:
                         # The readback rides the watchdog too: a device-
                         # level hang surfaces here, not just a stuck
-                        # dispatch.
-                        return out, np.asarray(out[0])
-
-                    (
-                        (toks, kv, keys, ring_j, ring_idx_j), toks_np
-                    ) = self._dispatch("decode", _chunk)
-            except BackendWorkerError as e:
-                # Transparent recovery: a worker died and a healthy replica
-                # exists — rebuild every live stream's KV on the new route
-                # and REDO this chunk. The failed chunk's partial steps are
-                # discarded with the dead route; tok/keys/rings still hold
-                # the pre-chunk state, so the redone chunk samples the
-                # exact same tokens (greedy streams stay bit-identical).
-                self._failover_or_raise(e)
-                kv = self._migrate_kv(rows, B, slot)
-                continue
-            dt_chunk = time.perf_counter() - t_chunk
-            # Feed the step-budget clock (continuous): deadline slack is
-            # measured in recent chunk walls.
-            self._step_budget.observe_chunk(dt_chunk)
-            live_rows = [
-                (lane, row) for lane, row in enumerate(rows)
-                if row is not None
-            ]
-            consumed = {
-                lane: row.peek_consumed(toks_np[lane])
-                for lane, row in live_rows
-            }
-            # Hardware ledger: the chunk computed B x n positions —
-            # consumed ones are decode goodput, live-but-unconsumed tails
-            # are convoy, dead lanes are pad. Noted BEFORE the pushes for
-            # the same flush-ordering reason as account_decode below.
-            self.efficiency.note_decode(
-                dt_chunk, len(rows), n, len(live_rows),
-                sum(consumed.values()), slot=slot,
-            )
-            for lane, row in live_rows:
-                # Account BEFORE pushing: a row that finishes mid-chunk
-                # flushes its attribution from inside push() -> finish(),
-                # so the final chunk's decode share (and its unconsumed-
-                # tail convoy — the very number the convoy meter exists
-                # for) must already be on the row by then.
-                row.account_decode(dt_chunk, n, consumed[lane])
-                for t in toks_np[lane]:
-                    row.push(int(t))
-                    if row.done:
-                        rows[lane] = None
-                        break
-            self._release_finished(rows)
-            memwatch.sample("decode", min_interval_s=0.05)
-            tok = toks[:, -1]
-            slot += n
+                        # dispatch. It blocks on the device, so the span is
+                        # real chunk compute, not dispatch time.
+                        (
+                            (toks, kv, keys, ring_j, ring_idx_j), toks_np
+                        ) = self._dispatch(
+                            "decode",
+                            lambda: self.backend.decode(
+                                kv, tok, slot, pads_j, keys, ring_j,
+                                ring_idx_j, n, s,
+                            ),
+                            readback=lambda out: np.asarray(out[0]),
+                        )
+                except BackendWorkerError as e:
+                    # Transparent recovery: a worker died and a healthy
+                    # replica exists — rebuild every live stream's KV on
+                    # the new route and REDO this chunk. The failed chunk's
+                    # partial steps are discarded with the dead route;
+                    # tok/keys/rings still hold the pre-chunk state, so the
+                    # redone chunk samples the exact same tokens (greedy
+                    # streams stay bit-identical).
+                    self._failover_or_raise(e)
+                    kv = self._migrate_kv(rows, B, slot)
+                    continue
+                dt_chunk = chunk.seconds
+                with self._phase("emit"):
+                    # Feed the step-budget clock (continuous): deadline
+                    # slack is measured in recent chunk walls.
+                    self._step_budget.observe_chunk(dt_chunk)
+                    live_rows = [
+                        (lane, row) for lane, row in enumerate(rows)
+                        if row is not None
+                    ]
+                    consumed = {
+                        lane: row.peek_consumed(toks_np[lane])
+                        for lane, row in live_rows
+                    }
+                    # Hardware ledger: the chunk computed B x n positions —
+                    # consumed ones are decode goodput, live-but-unconsumed
+                    # tails are convoy, dead lanes are pad. Noted BEFORE
+                    # the pushes for the same flush-ordering reason as
+                    # account_decode below.
+                    self.efficiency.note_decode(
+                        dt_chunk, len(rows), n, len(live_rows),
+                        sum(consumed.values()), slot=slot,
+                    )
+                    for lane, row in live_rows:
+                        # Account BEFORE pushing: a row that finishes
+                        # mid-chunk flushes its attribution from inside
+                        # push() -> finish(), so the final chunk's decode
+                        # share (and its unconsumed-tail convoy — the very
+                        # number the convoy meter exists for) must already
+                        # be on the row by then.
+                        row.account_decode(dt_chunk, n, consumed[lane])
+                        for t in toks_np[lane]:
+                            row.push(int(t))
+                            if row.done:
+                                rows[lane] = None
+                                break
+                    self._release_finished(rows)
+                    tok = toks[:, -1]
+                    slot += n
+                period.update(dispatched=True, live=live)
+        self._segment_args["ended"] = ended
 
         for row in rows:
             if row is not None:
@@ -2950,9 +3063,7 @@ class BatchEngine:
 
         any_live = grew = False
         free0 = self._alloc.pages_free
-        with timeline.span(
-            "page-extend", track="engine", args={"slot": int(slot), "n": int(n)}
-        ):
+        with self._phase("page-extend", args={"slot": int(slot), "n": int(n)}):
             for lane, row in enumerate(rows):
                 if row is None:
                     continue
@@ -3368,8 +3479,8 @@ class BatchEngine:
         row.open_span(slot=slot)
         t0 = time.perf_counter()
         try:
-            with timeline.span(
-                "restore", rid=req.rid, track="engine",
+            with self._phase(
+                "restore", rid=req.rid,
                 args={"lane": lane, "slot": int(slot), "tokens": len(hist)},
             ):
                 if self._alloc is not None and self._prefix is not None:
@@ -3418,11 +3529,13 @@ class BatchEngine:
         )
         window = int(ring_j.shape[1]) if ring_j.ndim == 2 else 0
         if window > 0 and sp.ring is not None:
-            ring_j = ring_j.at[lane].set(jnp.asarray(sp.ring))
-            ring_idx_j = ring_idx_j.at[lane].set(int(sp.ring_idx))
-        keys = keys.at[lane].set(jnp.asarray(sp.key))
-        tok = tok.at[lane].set(int(row.history[-1]))
-        pads_j = pads_j.at[lane].set(pad)
+            ring_j, ring_idx_j = _set_lane(
+                (ring_j, ring_idx_j), lane, (sp.ring, int(sp.ring_idx))
+            )
+        keys, tok, pads_j = _set_lane(
+            (keys, tok, pads_j), lane,
+            (sp.key, int(row.history[-1]), pad),
+        )
         rows[lane] = row
         row.n_at_restore = row.n
         if self._alloc is not None:
@@ -3582,23 +3695,23 @@ class BatchEngine:
 
         sampled = s.temperature is not None and s.temperature > 0.0
         if sampled:
-            n_accs, nxts, kv, keys = self._dispatch(
+            (n_accs, nxts, kv, keys), (n_accs, nxts) = self._dispatch(
                 "verify",
                 lambda: self.backend.verify_sampled(
                     kv, tokens, slot, pads_j, drafts, n_drafts, keys, s
                 ),
+                readback=lambda out: (np.asarray(out[0]), np.asarray(out[1])),
             )
-            n_accs, nxts = np.asarray(n_accs), np.asarray(nxts)
             cand = [
                 [*drafts[l, : n_accs[l]].tolist(), int(nxts[l])]
                 for l in range(B)
             ]
         else:
-            ids, kv = self._dispatch(
+            (_, kv), ids = self._dispatch(
                 "verify",
                 lambda: self.backend.verify_greedy(kv, tokens, slot, pads_j),
+                readback=lambda out: np.asarray(out[0]),
             )
-            ids = np.asarray(ids)
             cand = []
             for l in range(B):
                 n, nxt = greedy_accept(drafts[l], ids[l])
@@ -3611,33 +3724,34 @@ class BatchEngine:
         if span_args is not None:
             span_args["accepted"] = int(a)
             span_args["k"] = int(K)
-        live_rows = [
-            (lane, row) for lane, row in enumerate(rows) if row is not None
-        ]
-        used_map = {
-            lane: row.peek_consumed(cand[lane][:a]) for lane, row in live_rows
-        }
-        # Hardware ledger: the verify chunk computed B x (K+1) positions;
-        # accepted ones are spec goodput, the live remainder is the wasted
-        # half of the speculative split, dead lanes are pad.
-        self.efficiency.note_spec(
-            dt_round, B, K, len(live_rows), sum(used_map.values()),
-            slot=int(slot),
-        )
-        for lane, row in live_rows:
-            # The verify chunk computed K+1 positions; the row consumes
-            # `used` of them — the accepted/wasted split of the round.
-            # Accounted BEFORE the pushes (a finishing row flushes its
-            # attribution from inside push() -> finish()).
-            row.account_spec(dt_round, K, used_map[lane])
-            for t in cand[lane][:a]:
-                row.push(int(t))
-                if row.done:
-                    rows[lane] = None
-                    break
-        new_tok = np.asarray(
-            [c[a - 1] if len(c) >= a else 0 for c in cand], np.int32
-        )
+        with self._phase("emit"):
+            live_rows = [
+                (lane, row) for lane, row in enumerate(rows) if row is not None
+            ]
+            used_map = {
+                lane: row.peek_consumed(cand[lane][:a]) for lane, row in live_rows
+            }
+            # Hardware ledger: the verify chunk computed B x (K+1) positions;
+            # accepted ones are spec goodput, the live remainder is the wasted
+            # half of the speculative split, dead lanes are pad.
+            self.efficiency.note_spec(
+                dt_round, B, K, len(live_rows), sum(used_map.values()),
+                slot=int(slot),
+            )
+            for lane, row in live_rows:
+                # The verify chunk computed K+1 positions; the row consumes
+                # `used` of them — the accepted/wasted split of the round.
+                # Accounted BEFORE the pushes (a finishing row flushes its
+                # attribution from inside push() -> finish()).
+                row.account_spec(dt_round, K, used_map[lane])
+                for t in cand[lane][:a]:
+                    row.push(int(t))
+                    if row.done:
+                        rows[lane] = None
+                        break
+            new_tok = np.asarray(
+                [c[a - 1] if len(c) >= a else 0 for c in cand], np.int32
+            )
         self.stats["spec_rounds"] += 1
         self.stats["spec_tokens"] += a
         return jnp.asarray(new_tok), kv, keys, slot + a
@@ -3793,10 +3907,9 @@ class BatchEngine:
         from cake_tpu.models.llama.batch import first_sample, seed_rings
 
         ids = req.prompt_ids
-        with timeline.span(
-            "join", rid=req.rid, track="engine",
-            args={"lane": lane, "slot": int(slot)},
-        ):
+        with self._phase(
+            "join", rid=req.rid, args={"lane": lane, "slot": int(slot)}
+        ) as join:
             pad = slot - len(ids)
             if self._alloc is not None and self._prefix is not None:
                 from cake_tpu.models.llama.paged_cache import PageExhausted
@@ -3808,9 +3921,8 @@ class BatchEngine:
                 # byte-stable, and a warm join is bit-identical to a cold
                 # one because hit and miss walk one arithmetic.
                 try:
-                    with timeline.span(
-                        "prefix-fork", track="engine",
-                        args={"lane": lane, "slot": int(slot)},
+                    with self._phase(
+                        "prefix-fork", args={"lane": lane, "slot": int(slot)}
                     ):
                         fresh, pair = self._fork_lane(lane, req, pad, slot)
                 except PageExhausted:
@@ -3874,15 +3986,20 @@ class BatchEngine:
             window = s.repeat_last_n
             row_ring, row_ring_idx = seed_rings([ids], window)
             key0 = jax.random.PRNGKey(req.sampling.seed)
-            first_arr, key_next, row_ring, row_ring_idx = first_sample(
-                logits, s, row_ring, row_ring_idx, key0[None]
-            )
-            first = int(first_arr[0])
-        if window > 0:
-            ring_j = ring_j.at[lane].set(jnp.asarray(row_ring[0]))
-            ring_idx_j = ring_idx_j.at[lane].set(int(row_ring_idx[0]))
-        keys = keys.at[lane].set(key_next[0])
-        tok = tok.at[lane].set(first)
+            # The first token's sample waits for the join's prefill: this
+            # is where the host blocks on the device.
+            with self._phase("readback") as readback:
+                first_arr, key_next, row_ring, row_ring_idx = first_sample(
+                    logits, s, row_ring, row_ring_idx, key0[None]
+                )
+                first = int(first_arr[0])
+            if window > 0:
+                ring_j, ring_idx_j = _set_lane(
+                    (ring_j, ring_idx_j), lane,
+                    (row_ring[0], int(row_ring_idx[0])),
+                )
+            keys, tok = _set_lane((keys, tok), lane, (key_next[0], first))
+        self.periods.note_join(join.seconds, readback.seconds)
 
         dt_join = time.perf_counter() - t_join
         row.account_join(dt_join)

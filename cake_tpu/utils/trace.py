@@ -123,18 +123,25 @@ span = spans.span  # module-level convenience: `with trace.span("hop.w0"): ...`
 
 
 @contextlib.contextmanager
-def jax_profile(trace_dir: str | None):
+def jax_profile(trace_dir: str | None, host_python: bool = True):
     """Capture a JAX/XLA profiler trace (xplane) into ``trace_dir``.
 
     No-op when trace_dir is falsy, so callers can thread a CLI flag straight
-    through. View with TensorBoard's profile plugin or xprof.
+    through. View with TensorBoard's profile plugin or xprof. With
+    ``host_python`` off, Python's own tracer stays off (it slows the host it
+    watches): the host plane keeps jit dispatches and the engine's spans
+    (obs/timeline.py) — a window of a running server.
     """
     if not trace_dir:
         yield
         return
     import jax
 
-    jax.profiler.start_trace(trace_dir)
+    opts = jax.profiler.ProfileOptions()
+    if not host_python:
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
         yield
     finally:

@@ -2,6 +2,7 @@
 
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -895,3 +896,59 @@ def test_requests_and_timeseries_routes_gate_on_engine(server, stub_server):
         urllib.request.urlopen(url + "/timeseries", timeout=30).read()
     )
     assert ts["points"] and ts["points"][-1]["finished"] == 1
+
+
+# ------------------------------------------------------------ POST /profile
+
+
+@pytest.fixture()
+def profiled_server(tmp_path):
+    cfg = LlamaConfig.tiny(num_hidden_layers=2)
+    params = M.init_params(cfg, jax.random.PRNGKey(5), jnp.float32)
+    step = LocalForwardStep(cfg, params, max_seq_len=64, cache_dtype=jnp.float32)
+    gen = LlamaGenerator(
+        cfg, step, ByteTokenizer(),
+        SamplingConfig(temperature=0.0, repeat_penalty=1.0),
+    )
+    api = ApiServer(gen, model_name="tiny-test", profile_dir=str(tmp_path))
+    httpd = api.make_server("127.0.0.1", 0)
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    yield f"http://127.0.0.1:{httpd.server_address[1]}", tmp_path
+    httpd.shutdown()
+
+
+def _post_profile(url, query):
+    req = urllib.request.Request(f"{url}/profile{query}", data=b"", method="POST")
+    try:
+        with urllib.request.urlopen(req, timeout=60) as r:
+            return r.status, json.loads(r.read())
+    except urllib.error.HTTPError as e:
+        return e.code, json.loads(e.read())
+
+
+def test_profile_route_writes_one_window(profiled_server, server):
+    url, trace_dir = profiled_server
+    status, out = _post_profile(url, "?seconds=0.2")
+    assert status == 200 and out["seconds"] >= 0.2
+    assert out["path"].endswith(".xplane.pb")
+    assert out["path"].startswith(str(trace_dir))
+    assert _post_profile(url, "?seconds=31")[0] == 400
+    assert _post_profile(url, "")[0] == 400
+    assert _post_profile(server, "?seconds=1")[0] == 404  # no --trace-dir
+
+
+def test_profile_route_answers_409_while_a_window_is_open(profiled_server):
+    url, _ = profiled_server
+    first: list = []
+    t = threading.Thread(
+        target=lambda: first.append(_post_profile(url, "?seconds=2")[0])
+    )
+    t.start()
+    time.sleep(0.5)  # the first request is in: its window is open
+    second, _ = _post_profile(url, "?seconds=1")
+    t.join(60)
+    assert not t.is_alive()
+    # two overlapping requests: one records, the other is refused (which of
+    # them arrived first is the machine's business)
+    assert sorted([first[0], second]) == [200, 409]
+    assert _post_profile(url, "?seconds=0.05")[0] == 200  # and it is free again

@@ -477,3 +477,73 @@ def test_continuous_steady_state_never_retraces():
     assert _jw.retrace_total() == r0
     assert got == want
     eng.stop()
+
+
+# ------------------------------------------------------ the period account
+
+
+def test_period_account_closes_on_its_spans():
+    """``engine.period`` (obs/period.py) and the timeline's spans are one
+    set of clock reads: the phases' self times sum to the periods' seconds
+    with next to nothing unnamed, the count is the number of dispatching
+    ``period`` spans, and each such span is covered by its children."""
+    from cake_tpu.obs.timeline import timeline
+
+    cfg, params = setup()
+    timeline.clear()
+    eng = make_engine(
+        cfg, params, scheduler="continuous", kv_mode="paged", page_size=16,
+        prefix_cache=True,
+    )
+    handles = [eng.submit([Message.user(p)], 24, GREEDY) for p in MIXED]
+    time.sleep(0.3)  # the segment is under way: these two join it
+    handles += [
+        eng.submit([Message.user("joiner " + p)], 8, GREEDY) for p in MIXED[:2]
+    ]
+    for h in handles:
+        collect(h)
+    deadline = time.monotonic() + 10  # the segment closes on its own
+    while time.monotonic() < deadline and not eng.periods.snapshot()["segment"]["count"]:
+        time.sleep(0.01)
+    eng.stop()
+    snap = eng.periods.snapshot()
+    period, segment = snap["period"], snap["segment"]
+
+    assert period["count"] >= 6 and segment["count"] >= 1
+    phases = period["phase_seconds"]
+    assert sum(phases.values()) == pytest.approx(period["seconds"], rel=1e-9)
+    # under 1% unnamed; on this tiny model a period is a few milliseconds, so
+    # allow the loop's fixed unspanned cost (a gauge, two counts) its floor
+    assert phases["other"] < max(0.01 * period["seconds"], 5e-4 * period["count"])
+    assert phases["dispatch"] > 0 and phases["readback"] > 0 and phases["emit"] > 0
+    assert sum(period["hist"]["counts"]) == period["count"]
+    assert len(period["hist"]["counts"]) == len(period["hist"]["edges_s"]) + 1
+    assert 1 <= period["with_join"]["count"] <= period["joins"] == eng.stats["joins"]
+    assert period["with_join"]["seconds"] <= period["seconds"]
+    assert 0 < period["join_readback_seconds"] <= period["join_seconds"]
+    lanes = period["lane_seconds"]
+    assert 0 < lanes["live"] <= lanes["offered"] == pytest.approx(
+        eng.max_batch * period["seconds"]
+    )
+    assert 0 <= lanes["idle_queued"] <= lanes["offered"] - lanes["live"] + 1e-9
+    assert segment["prefill_seconds"] < segment["seconds"]
+    assert 0 < segment["between_seconds"]
+
+    events = timeline.snapshot()
+    spans = [e for e in events if e.get("ph") == "X"]
+    periods = [s for s in spans if s["name"] == "period"]
+    assert sum(s["args"]["dispatched"] for s in periods) == period["count"]
+    assert len(periods) == period["count"] + period["undispatched"]["count"]
+    for root in periods:
+        if not root["args"]["dispatched"]:
+            continue
+        kids = [s for s in spans if s.get("parent") == root["id"]]
+        assert {"sweep", "step", "decode-chunk", "emit"} <= {k["name"] for k in kids}
+        assert root["dur"] - sum(k["dur"] for k in kids) < 1e-3
+    chunk = next(s for s in spans if s["name"] == "decode-chunk")
+    assert set(chunk["args"]) == {"lanes", "capacity", "slot", "n", "live"}
+    inside = {s["name"] for s in spans if s.get("parent") == chunk["id"]}
+    assert inside == {"dispatch", "readback"}
+    seg = next(s for s in spans if s["name"] == "segment")
+    assert seg["args"]["ended"] in ("capacity", "drained")
+    assert {"lanes", "bucket", "capacity", "queue_depth"} <= set(seg["args"])

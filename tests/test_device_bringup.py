@@ -103,7 +103,9 @@ def test_health_names_the_device_and_the_attention_impl():
     assert health["device_count"] == len(jax.devices())
     assert health["jax_version"] == jax.__version__
     assert health["attention_impl"] == "xla"  # what "auto" resolves to here
-    assert set(stats["compile"]) == {"count", "seconds"}
+    assert set(stats["compile"]) == {
+        "count", "seconds", "stall_seconds", "by_family", "untracked",
+    }
 
 
 def test_device_peaks_raises_on_an_unknown_accelerator(monkeypatch):

@@ -232,3 +232,54 @@ def test_compile_listener_accumulates():
     jax.jit(lambda x: x * 3 + 1)(jnp.ones(8)).block_until_ready()
     n1, s1 = jitwatch.compile_totals()
     assert n1 > n0 and s1 > s0
+
+
+# ----------------------------------------------------------- program names
+
+
+def test_module_is_named_for_the_family_and_the_label_keeps_its_key():
+    """The XLA module of a tracked function carries the label's family and
+    no key (a device trace tells programs apart by it); the key stays in the
+    label and so in the metrics; ``module=`` overrides the default."""
+    from cake_tpu.obs.timeline import timeline
+
+    def run(x):
+        return x * 2
+
+    fn = jitwatch.tracked_jit(run, name="test.family[w=64,t=0.0]")
+    named = jitwatch.tracked_jit(
+        run, name="test.family2[w=64]", module="prefill_join_test"
+    )
+    x = jnp.ones((4, 3))
+    assert "module @jit_test_family " in fn._jitted.lower(x).as_text()
+    assert "module @jit_prefill_join_test " in named._jitted.lower(x).as_text()
+    timeline.clear()
+    fn(jnp.ones((5, 3)))
+    assert fn._watch_name == "test.family[w=64,t=0.0]"
+    assert jitwatch.watch.trace_count("test.family[w=64,t=0.0]") >= 1
+    assert jitwatch.family("test.family[w=64,t=0.0]") == "test.family"
+    # the call that traced left a ``compile`` span: label and argument shapes
+    (span,) = [e for e in timeline.snapshot() if e["name"] == "compile"]
+    assert span["args"] == {
+        "fn": "test.family[w=64,t=0.0]", "shapes": ["float32[5, 3]"],
+    }
+    fn(jnp.ones((5, 3)))  # a cache hit compiles nothing and records nothing
+    assert len([e for e in timeline.snapshot() if e["name"] == "compile"]) == 1
+
+
+def test_compile_stats_by_family_and_untracked_sum_to_the_count():
+    assert jitwatch.install_compile_listener()
+    before = jitwatch.compile_stats()
+    a = jitwatch.tracked_jit(lambda x: x + 1, name="test.stats[w=1]")
+    b = jitwatch.tracked_jit(lambda x: x + 2, name="test.stats[w=2]")
+    a(jnp.ones(7)).block_until_ready()
+    b(jnp.ones(7)).block_until_ready()
+    jax.jit(lambda x: x * 5 - 1)(jnp.ones(9)).block_until_ready()  # untracked
+    after = jitwatch.compile_stats()
+    fam = after["by_family"]["test.stats"]
+    assert fam["count"] == 2 and fam["seconds"] > 0
+    assert after["untracked"] + sum(
+        f["count"] for f in after["by_family"].values()
+    ) == after["count"]
+    assert after["untracked"] > before["untracked"]
+    assert after["stall_seconds"] >= before["stall_seconds"] + fam["seconds"] - 1e-6

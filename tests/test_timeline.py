@@ -358,3 +358,61 @@ def test_flight_events_carry_mono_and_span_id():
     assert "mono" in ev and "ts" in ev
     outside = metrics.flight.record("finished", "req-x")
     assert "span" not in outside
+
+
+# ------------------------------------------- the profiler as a second sink
+
+
+def test_engine_track_spans_land_on_the_profilers_host_plane(tmp_path):
+    """A span on the engine track, lexical or begin()/end(), opened while a
+    profiler window is open is an event of the same name on the trace's
+    ``/host:CPU`` plane; a lane-track span and a span that began before the
+    window are not."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from cake_tpu.obs.timeline import Timeline
+
+    tl = Timeline()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # as bench/child.py opens its window
+    opts.host_tracer_level = 1
+    with tl.span("before-the-window", track="engine"):
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with tl.span("period", track="engine", args={"slot": 3}):
+                with tl.span("sweep", track="engine"):
+                    pass
+                sid = tl.begin("step", track="engine", args={"live": 2})
+                tl.end(sid, args={"joins": 0})
+            lane = tl.begin("request", rid="r1", track="lane0", parent=None)
+            tl.end(lane)
+        finally:
+            jax.profiler.stop_trace()
+    (trace,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    host = [p for p in ProfileData.from_file(trace).planes if p.name == "/host:CPU"]
+    names = {e.name for p in host for line in p.lines for e in line.events}
+    assert {"period", "sweep", "step"} <= names
+    assert not {"request", "before-the-window"} & names
+    # the ring holds them all, whatever the profiler kept
+    assert {e["name"] for e in tl.snapshot()} >= {
+        "period", "sweep", "step", "request", "before-the-window",
+    }
+
+
+def test_obs_imports_and_plain_spans_cost_no_jax_import():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from cake_tpu import obs\n"
+        "with obs.timeline.span('x', track='lane0', args={'a': 1}):\n"
+        "    pass\n"
+        "obs.timeline.end(obs.timeline.begin('y', track='wire'))\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+    )
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True)
+    assert r.returncode == 0, r.stderr
