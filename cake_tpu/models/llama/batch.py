@@ -45,7 +45,7 @@ from cake_tpu.models.llama.cache import KVCache, init_cache, write_layer
 from cake_tpu.obs.jitwatch import tracked_jit as _tracked_jit
 from cake_tpu.models.llama.paged_cache import (
     PagedKVCache,
-    paged_write_layer,
+    paged_write_pool,
 )
 from cake_tpu.models.llama.chat import Message, encode_dialog
 from cake_tpu.models.llama.config import LlamaConfig
@@ -365,7 +365,13 @@ def batched_blocks_forward(
         FULL cache grid and per-row ``lengths`` = write_pos + width.
       block_tables: optional [B, max_pages_per_seq] int32 — PAGED mode: ``kv``
         is then a PagedKVCache (models/llama/paged_cache.py) and every K/V
-        write scatters through the table (unmapped entries drop). Decode reads
+        write scatters through the table (unmapped entries drop). The pool
+        is the layer scan's CARRY: each layer writes its rows in place at
+        ``[layer, page, :, offset, :]`` and the kernels read through a layer
+        index, so no program slices, stacks or copies the pool (the dense
+        cache below still goes in as a scanned input and comes back as a
+        stacked output; tests/test_paged_pool_carry.py guards this
+        branch). Decode reads
         dispatch to the ragged paged kernel (ops/pallas/paged_attention.py) or
         its gather fallback; fresh prefill attends over the FRESH chunk
         (identical arithmetic to the dense fresh-chunk path — prefill never
@@ -433,9 +439,7 @@ def batched_blocks_forward(
         else jnp.zeros((b,), jnp.int32)
     )
 
-    def layer(carry, per_layer):
-        x = carry
-        lp, k_c, v_c, ok = per_layer
+    def qkv(lp, x):
         if decode or cached_chunk:
             # The chunk's keys rope at the chunk's own positions (== q_pos);
             # the full-cache-grid k_pos is mask-only, exactly like decode.
@@ -448,75 +452,79 @@ def batched_blocks_forward(
             # m_safe) zero the outputs — a LOAD-BEARING contract for that
             # caller; their sub-pad KV writes land at sub-pad slots that
             # stay sentinel-masked forever.
-            q, k, v = M.block_qkv(lp, x, cos, sin, q_pos, config, fusion=fusion)
-        else:
-            q, k, v = M.block_qkv(
-                lp, x, cos, sin, q_pos, config, k_positions=k_pos,
-                fusion=fusion,
-            )
-        if paged:
-            k_c, v_c = paged_write_layer(
-                k_c, v_c, k, v, write_pos, block_tables,
-                starts=write_starts,
-            )
-            # One eligibility rule for every paged kernel (decode AND the
-            # chunk family): the page must be a whole number of lane tiles.
-            # A backend that wanted pallas but lands here surfaces a
-            # one-time `kernel-fallback` flight event host-side
-            # (runtime/batch_backend.PagedLocalBackend._kernel_note).
-            kernel_ok = use_pallas and paged_kernel_supported(k_c.shape[2])
-            if decode:
-                if kernel_ok:
-                    attn = paged_decode_attention(
-                        q, k_c, v_c, lengths, block_tables, pads,
-                        lp.get("win_flag"), **attn_kw,
-                    )
-                else:
-                    attn = paged_decode_attention_xla(
-                        q, k_c, v_c, q_pos, k_pos, block_tables,
-                        window_flag=lp.get("win_flag"), **attn_kw,
-                    )
-            elif cached_chunk:
-                # Cached chunk at slot ``write_pos`` — the prefix-cache
-                # suffix prefill AND the paged speculative verify: the
-                # chunk's queries attend the LIVE POOL PREFIX (cached/
-                # earlier pages plus the chunk's own writes just scattered
-                # above). Pallas: the ragged page-resolving chunk kernel
-                # (ops/pallas/paged_prefill.py) streams only live pages;
-                # XLA: the gathered dense view, the multi-query form of
-                # the paged decode fallback (bit-identical arithmetic).
-                if kernel_ok:
-                    attn = paged_chunk_attention(
-                        q, k_c, v_c, q_starts, lengths, pads, block_tables,
-                        lp.get("win_flag"), **attn_kw,
-                    )
-                else:
-                    attn = paged_chunk_attention_xla(
-                        q, k_c, v_c, q_pos, k_pos, block_tables,
-                        window_flag=lp.get("win_flag"), **attn_kw,
-                    )
-            elif kernel_ok:
-                # Fresh paged prefill under pallas: the chunk kernel reads
-                # the pool prefix its own writes just produced (q_starts =
-                # 0, so causal pruning touches exactly the live pages) —
-                # no [chunk, chunk] score tensor, O(live) HBM bytes.
-                attn = paged_chunk_attention(
-                    q, k_c, v_c, q_starts, lengths, pads, block_tables,
-                    lp.get("win_flag"), **attn_kw,
+            return M.block_qkv(lp, x, cos, sin, q_pos, config, fusion=fusion)
+        return M.block_qkv(
+            lp, x, cos, sin, q_pos, config, k_positions=k_pos, fusion=fusion,
+        )
+
+    def paged_layer(carry, per_layer):
+        # The pool rides in the carry and is only ever written in place and
+        # read through ``li``: nothing here may slice a layer out of it.
+        x, k_pool, v_pool = carry
+        lp, ok, li = per_layer
+        q, k, v = qkv(lp, x)
+        # An inert (``valid``-gated) layer still writes its own layer's
+        # rows, as the scanned form did; only ``x`` is gated.
+        k_pool, v_pool = paged_write_pool(
+            k_pool, v_pool, li, k, v, write_pos, block_tables,
+            starts=write_starts,
+        )
+        # One eligibility rule for every paged kernel (decode AND the
+        # chunk family): the page must be a whole number of lane tiles.
+        # A backend that wanted pallas but lands here surfaces a
+        # one-time `kernel-fallback` flight event host-side
+        # (runtime/batch_backend.PagedLocalBackend._kernel_note).
+        kernel_ok = use_pallas and paged_kernel_supported(kv.page_size)
+        if decode:
+            if kernel_ok:
+                attn = paged_decode_attention(
+                    q, k_pool, v_pool, lengths, block_tables, pads,
+                    lp.get("win_flag"), layer=li, **attn_kw,
                 )
             else:
-                # Prefill attends over the chunk it just computed — the
-                # dense fresh-chunk arithmetic, no cache read, no gather.
-                attn = gqa_attention(
-                    q, k, v, q_pos, k_pos,
-                    window_flag=lp.get("win_flag"), **attn_kw,
+                attn = paged_decode_attention_xla(
+                    q, k_pool, v_pool, q_pos, k_pos, block_tables,
+                    window_flag=lp.get("win_flag"), layer=li, **attn_kw,
                 )
-            x_new = M.block_finish(
-                lp, x, attn, config, tp_axis=tp_axis, moe_valid=moe_valid,
-                moe_dispatch=moe_dispatch, fusion=fusion,
+        elif kernel_ok:
+            # Every paged prefill under pallas is one call. A cached chunk
+            # at slot ``write_pos`` — the prefix-cache suffix prefill AND
+            # the paged speculative verify — attends the LIVE POOL PREFIX
+            # (cached/earlier pages plus the chunk's own writes just
+            # scattered above); a fresh prefill reads the pool prefix its
+            # own writes just produced (q_starts = 0, so causal pruning
+            # touches exactly the live pages). The ragged page-resolving
+            # chunk kernel (ops/pallas/paged_prefill.py) streams only live
+            # pages: no [chunk, chunk] score tensor, O(live) HBM bytes.
+            attn = paged_chunk_attention(
+                q, k_pool, v_pool, q_starts, lengths, pads, block_tables,
+                lp.get("win_flag"), layer=li, **attn_kw,
             )
-            x = x_new if valid is None else jnp.where(ok, x_new, x)
-            return x, (k_c, v_c)
+        elif cached_chunk:
+            # XLA: the gathered dense view, the multi-query form of the
+            # paged decode fallback (bit-identical arithmetic).
+            attn = paged_chunk_attention_xla(
+                q, k_pool, v_pool, q_pos, k_pos, block_tables,
+                window_flag=lp.get("win_flag"), layer=li, **attn_kw,
+            )
+        else:
+            # Prefill attends over the chunk it just computed — the
+            # dense fresh-chunk arithmetic, no cache read, no gather.
+            attn = gqa_attention(
+                q, k, v, q_pos, k_pos,
+                window_flag=lp.get("win_flag"), **attn_kw,
+            )
+        x_new = M.block_finish(
+            lp, x, attn, config, tp_axis=tp_axis, moe_valid=moe_valid,
+            moe_dispatch=moe_dispatch, fusion=fusion,
+        )
+        x = x_new if valid is None else jnp.where(ok, x_new, x)
+        return (x, k_pool, v_pool), None
+
+    def layer(carry, per_layer):
+        x = carry
+        lp, k_c, v_c, ok = per_layer
+        q, k, v = qkv(lp, x)
         k_c, v_c = write_layer(
             k_c, v_c, k, v, write_pos,
             row=0 if row_offset is None else row_offset,
@@ -566,9 +574,14 @@ def batched_blocks_forward(
         return x, (k_c, v_c)
 
     ok = jnp.ones((kv.k.shape[0],), bool) if valid is None else valid
+    if paged:
+        li = jnp.arange(kv.n_layers, dtype=jnp.int32)
+        (x, k_out, v_out), _ = jax.lax.scan(
+            paged_layer, (x, kv.k, kv.v), (layers, ok, li)
+        )
+        return x, PagedKVCache(k=k_out, v=v_out)
     x, (k_out, v_out) = jax.lax.scan(layer, x, (layers, kv.k, kv.v, ok))
-    cls = PagedKVCache if paged else KVCache
-    return x, cls(k=k_out, v=v_out)
+    return x, KVCache(k=k_out, v=v_out)
 
 
 def batched_prefill(

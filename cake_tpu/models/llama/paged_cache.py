@@ -50,7 +50,15 @@ _C_FAIL = "cake_kv_page_alloc_failures_total"
 
 
 class PagedKVCache(NamedTuple):
-    """Page-pool KV storage for a contiguous run of layers."""
+    """Page-pool KV storage for a contiguous run of layers.
+
+    A program that is given the pool gives the SAME buffers back: the
+    model's layer scan carries ``k`` and ``v`` whole, writes them in place
+    (``paged_write_pool``) and reads them through a layer index
+    (ops/pallas/paged_attention.py, paged_prefill.py). No layer is sliced
+    out of the pool and none is stacked back into it; with the jits'
+    donation a served program holds one pool, not two.
+    """
 
     k: jnp.ndarray  # [n_layers, n_pages, n_kv_heads, page_size, head_dim]
     v: jnp.ndarray
@@ -97,24 +105,29 @@ _zero_pool = tracked_jit(
 )
 
 
-def paged_write_layer(
-    k_pages: jnp.ndarray,
-    v_pages: jnp.ndarray,
+def paged_write_pool(
+    k_pool: jnp.ndarray,
+    v_pool: jnp.ndarray,
+    layer: jnp.ndarray,
     k_new: jnp.ndarray,
     v_new: jnp.ndarray,
     pos: jnp.ndarray,
     block_tables: jnp.ndarray,
     starts: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Write a [batch, chunk, n_kv, head_dim] chunk at sequence offset ``pos``.
+    """Write a [batch, chunk, n_kv, head_dim] chunk at sequence offset ``pos``
+    into layer ``layer`` of the WHOLE pool, in place.
 
-    The paged sibling of cache.write_layer: operates on ONE layer's
-    [n_pages, n_kv, page_size, head_dim] pool slice (the layer axis is scanned
-    over in the model), scattering token ``pos + j`` of row ``b`` into physical
-    page ``block_tables[b, (pos + j) // page_size]`` at offset
-    ``(pos + j) % page_size``. UNMAPPED entries (and logical pages beyond the
-    table) become out-of-bounds scatter indices and are dropped — the caller's
-    allocator decides what holds storage, the write path cannot corrupt it.
+    The paged sibling of cache.write_layer, in the form the model's layer
+    scan uses: the pool [n_layers, n_pages, n_kv, page_size, head_dim] is the
+    scan's CARRY and this is its only write — one scatter of token
+    ``pos + j`` of row ``b`` to ``[layer, block_tables[b, (pos + j) //
+    page_size], :, (pos + j) % page_size, :]``. No layer is ever sliced out
+    of the pool or stacked back into it, so the buffer a program was given
+    is the buffer it returns. UNMAPPED entries (and logical pages beyond the
+    table) become out-of-bounds scatter indices and are dropped — the
+    caller's allocator decides what holds storage, the write path cannot
+    corrupt it.
 
     ``starts`` (optional [B] int32) drops row ``b``'s writes at slots below
     ``starts[b]`` even when those slots ARE mapped: a suffix prefill over a
@@ -122,7 +135,7 @@ def paged_write_layer(
     tokens inside its window but must never scribble the shared pages that
     already hold their KV.
     """
-    n_pages, _, page_size, _ = k_pages.shape
+    n_pages, page_size = k_pool.shape[1], k_pool.shape[3]
     b, chunk = k_new.shape[0], k_new.shape[1]
     slots = pos + jnp.arange(chunk, dtype=jnp.int32)  # [chunk] absolute
     logical = jnp.broadcast_to(slots // page_size, (b, chunk))
@@ -134,15 +147,44 @@ def paged_write_layer(
     phys = jnp.where(phys < 0, n_pages, phys)
     if starts is not None:
         phys = jnp.where(slots[None, :] < starts[:, None], n_pages, phys)
-    k_new = k_new.astype(k_pages.dtype)
-    v_new = v_new.astype(v_pages.dtype)
-    k_pages = k_pages.at[phys, :, offs, :].set(k_new, mode="drop")
-    v_pages = v_pages.at[phys, :, offs, :].set(v_new, mode="drop")
-    return k_pages, v_pages
+    k_new = k_new.astype(k_pool.dtype)
+    v_new = v_new.astype(v_pool.dtype)
+    # The KV head is an index of the scatter too, not a window dimension:
+    # each update is then one [head_dim] row, contiguous in the pool's own
+    # (row-major, head-major) layout, which is the layout the kernels read.
+    # With the head in the window XLA lays the pool out token-major for the
+    # scatter and converts the WHOLE pool back for every kernel call.
+    heads = jnp.arange(k_pool.shape[2], dtype=jnp.int32)
+    at = (layer, phys[:, :, None], heads, offs[:, :, None])
+    k_pool = k_pool.at[at].set(k_new, mode="drop")
+    v_pool = v_pool.at[at].set(v_new, mode="drop")
+    return k_pool, v_pool
+
+
+def paged_write_layer(
+    k_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,
+    k_new: jnp.ndarray,
+    v_new: jnp.ndarray,
+    pos: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    starts: jnp.ndarray | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``paged_write_pool`` for ONE layer held on its own, [n_pages, n_kv,
+    page_size, head_dim]: a pool of one layer. The model does not call this
+    (its scan carries the whole pool); the per-layer oracles of
+    tests/test_paged_pool_carry.py and callers with a single layer do."""
+    k_pool, v_pool = paged_write_pool(
+        k_pages[None], v_pages[None], 0, k_new, v_new, pos, block_tables,
+        starts=starts,
+    )
+    return k_pool[0], v_pool[0]
 
 
 def gather_pages(
-    pages: jnp.ndarray, block_tables: jnp.ndarray
+    pages: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Dense head-major view of each row's pages: [b, n_kv, n_p * ps, hd].
 
@@ -150,11 +192,16 @@ def gather_pages(
     kernel is pinned against): gathering a row's pages in logical order
     reconstructs exactly the dense cache layout at every mapped slot; UNMAPPED
     pages read zeros, which the callers' position masks exclude anyway.
+    ``pages`` is one layer [n_pages, n_kv, ps, hd], or the whole pool with
+    ``layer`` naming the one to read: the gather indexes it, no layer is
+    sliced out first.
     """
-    n_pages = pages.shape[0]
+    if pages.ndim == 4:
+        pages, layer = pages[None], 0
+    n_pages = pages.shape[1]
     bt = jnp.where(block_tables < 0, n_pages, block_tables)
     # [b, n_p, n_kv, ps, hd], OOB -> 0 fill
-    g = jnp.take(pages, bt, axis=0, mode="fill", fill_value=0)
+    g = pages.at[layer, bt].get(mode="fill", fill_value=0)
     b, n_p, n_kv, ps, hd = g.shape
     return jnp.moveaxis(g, 2, 1).reshape(b, n_kv, n_p * ps, hd)
 

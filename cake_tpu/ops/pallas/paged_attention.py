@@ -2,10 +2,12 @@
 
 The paged sibling of ops/pallas/decode_attention.py: one decode query per
 sequence attends over that sequence's live prefix, but KV bytes live in a
-shared page pool ([n_pages, n_kv, page_size, head_dim], the
+shared page pool ([n_layers, n_pages, n_kv, page_size, head_dim], the
 models/llama/paged_cache.py layout) and each sequence's pages are scattered —
 the kernel walks them in logical order through a block table delivered as a
-scalar-prefetch operand.
+scalar-prefetch operand. The LAYER is one more scalar-prefetch operand: the
+model's layer scan carries the whole pool and the index maps pick the layer,
+so no layer is ever sliced out of the pool to be read.
 
 What carries over from the dense kernel, because it is the same bandwidth
 argument:
@@ -50,6 +52,7 @@ def _paged_decode_kernel(
     lens_ref,
     starts_ref,
     tables_ref,
+    layer_ref,
     q_ref,
     k_ref,
     v_ref,
@@ -123,6 +126,19 @@ def _apply_window(starts, lengths, window, window_flag):
     return jnp.where(window_flag, w_start, starts)
 
 
+def as_pool(k_pages, v_pages, layer):
+    """(k_pool, v_pool, layer [1] int32) for the paged kernels' operands: a
+    5-D pool with its traced layer index, or a 4-D single layer viewed as a
+    pool of one (a reshape, no bytes move)."""
+    if k_pages.ndim == 4:
+        if layer is not None:
+            raise ValueError("a 4-D k_pages is one layer; it takes no `layer`")
+        return k_pages[None], v_pages[None], jnp.zeros((1,), jnp.int32)
+    if layer is None:
+        raise ValueError("a 5-D pool needs the `layer` to read")
+    return k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("window", "scale", "softcap", "interpret"),
@@ -136,6 +152,7 @@ def paged_decode_attention(
     starts: jnp.ndarray | None = None,
     window_flag: jnp.ndarray | None = None,
     *,
+    layer: jnp.ndarray | None = None,
     window: int | None = None,
     scale: float | None = None,
     softcap: float | None = None,
@@ -145,9 +162,12 @@ def paged_decode_attention(
 
     Args:
       q: [batch, 1, n_q_heads, head_dim] — the current token's queries.
-      k_pages/v_pages: [n_pages, n_kv_heads, page_size, head_dim] — one
-        layer's pool slice (models/llama/paged_cache.py). ``page_size`` must
-        be a multiple of the 128-lane tile so each page is a full-width block.
+      k_pages/v_pages: the whole pool [n_layers, n_pages, n_kv_heads,
+        page_size, head_dim] (models/llama/paged_cache.py), read at ``layer``
+        through the index maps; or one layer on its own, [n_pages, ...]: a
+        pool of one layer, ``layer`` not given. ``page_size`` must be a
+        multiple of the 128-lane tile so each page is a full-width block.
+      layer: int32 scalar (traced), the layer of a 5-D pool to attend over.
       lengths: [batch] int32 live prefix length per sequence (current pos + 1;
         the token at pos must already be written through the block table).
       block_tables: [batch, max_pages_per_seq] int32 physical page per logical
@@ -164,7 +184,8 @@ def paged_decode_attention(
         raise ValueError(
             f"paged_decode_attention takes one position, got q_len={q_len}"
         )
-    n_kv, page_size = k_pages.shape[1], k_pages.shape[2]
+    k_pages, v_pages, layer = as_pool(k_pages, v_pages, layer)
+    n_kv, page_size = k_pages.shape[2], k_pages.shape[3]
     if page_size % _LANES:
         raise ValueError(
             f"page_size {page_size} is not a multiple of the {_LANES}-lane "
@@ -195,28 +216,27 @@ def paged_decode_attention(
     # same physical page and Mosaic skips the repeated fetch (the dense
     # kernel's clamp, with one extra indirection). Unmapped entries clamp to
     # physical page 0 — finite garbage for lanes whose output nobody reads.
-    def _kv_index(bi, hi, pi, lens, st, tables):
+    def _kv_index(bi, hi, pi, lens, st, tables, lyr):
         first_live = st[bi] // page_size
         last_live = jnp.maximum(
             (lens[bi] + page_size - 1) // page_size - 1, 0
         )
         phys = tables[bi, jnp.clip(pi, first_live, last_live)]
-        return (jnp.maximum(phys, 0), hi, 0, 0)
+        return (lyr[0], jnp.maximum(phys, 0), hi, 0, 0)
+
+    def _q_index(bi, hi, pi, lens, st, tables, lyr):
+        return (bi, hi, 0, 0)
 
     grid = (b, n_kv, n_p)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(
-                (1, 1, rows, d), lambda bi, hi, pi, lens, st, tables: (bi, hi, 0, 0)
-            ),
-            pl.BlockSpec((1, 1, page_size, d), _kv_index),
-            pl.BlockSpec((1, 1, page_size, d), _kv_index),
+            pl.BlockSpec((1, 1, rows, d), _q_index),
+            pl.BlockSpec((None, 1, 1, page_size, d), _kv_index),
+            pl.BlockSpec((None, 1, 1, page_size, d), _kv_index),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, rows, d), lambda bi, hi, pi, lens, st, tables: (bi, hi, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, 1, rows, d), _q_index),
         scratch_shapes=[
             pltpu.VMEM((rows, d), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
@@ -233,7 +253,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, rows, d), q.dtype),
         interpret=interpret,
-    )(lengths, starts, block_tables, qg, k_pages, v_pages)
+    )(lengths, starts, block_tables, layer, qg, k_pages, v_pages)
     return out[:, :, :group, :].reshape(b, 1, n_q, d)
 
 
@@ -248,9 +268,11 @@ def paged_decode_attention_xla(
     window_flag: jnp.ndarray | None = None,
     scale: float | None = None,
     softcap: float | None = None,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Gather-based fallback: the dense XLA decode arithmetic over a gathered
-    view of each row's pages.
+    view of each row's pages (of layer ``layer`` where ``k_pages`` is the
+    whole 5-D pool).
 
     ``q_positions``/``k_positions`` are the left-padded position grids the
     dense path feeds gqa_attention_hm (models/llama/batch.decode_positions) —
@@ -259,8 +281,8 @@ def paged_decode_attention_xla(
     position masks exclude everything else, this is bit-identical to the
     dense XLA decode path on equal token histories.
     """
-    k = gather_pages(k_pages, block_tables)
-    v = gather_pages(v_pages, block_tables)
+    k = gather_pages(k_pages, block_tables, layer)
+    v = gather_pages(v_pages, block_tables, layer)
     return gqa_attention_hm(
         q, k, v, q_positions, k_positions,
         window=window, window_flag=window_flag, scale=scale, softcap=softcap,

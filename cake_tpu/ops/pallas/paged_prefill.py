@@ -46,6 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from cake_tpu.models.llama.paged_cache import gather_pages
 from cake_tpu.ops.attention import gqa_attention_hm, widen_qkv
+from cake_tpu.ops.pallas.paged_attention import as_pool
 
 _LANES = 128
 
@@ -64,6 +65,7 @@ def _paged_chunk_kernel(
     ks_ref,
     tables_ref,
     flag_ref,
+    layer_ref,
     q_ref,
     k_ref,
     v_ref,
@@ -180,6 +182,7 @@ def paged_chunk_attention(
     block_tables: jnp.ndarray,
     window_flag: jnp.ndarray | None = None,
     *,
+    layer: jnp.ndarray | None = None,
     window: int | None = None,
     scale: float | None = None,
     softcap: float | None = None,
@@ -192,9 +195,12 @@ def paged_chunk_attention(
       q: [batch, chunk, n_q_heads, head_dim] — row r's token i sits at
         absolute slot ``q_starts[r] + i``; the chunk's own keys must already
         be written through the block table.
-      k_pages/v_pages: [n_pages, n_kv_heads, page_size, head_dim] — one
-        layer's pool slice (models/llama/paged_cache.py). ``page_size`` must
-        be a multiple of the 128-lane tile (``paged_kernel_supported``).
+      k_pages/v_pages: the whole pool [n_layers, n_pages, n_kv_heads,
+        page_size, head_dim] (models/llama/paged_cache.py), read at ``layer``
+        through the index maps; or one layer on its own, [n_pages, ...]: a
+        pool of one layer, ``layer`` not given. ``page_size`` must be a
+        multiple of the 128-lane tile (``paged_kernel_supported``).
+      layer: int32 scalar (traced), the layer of a 5-D pool to attend over.
       q_starts: [batch] int32 absolute slot of each row's first query —
         zeros for a cold chunked prefill, the window start for a suffix
         prefill, the epoch's shared slot for a speculative verify chunk.
@@ -214,7 +220,8 @@ def paged_chunk_attention(
     Returns [batch, chunk, n_q_heads, head_dim] in q's dtype.
     """
     b, chunk, n_q, d = q.shape
-    n_kv, page_size = k_pages.shape[1], k_pages.shape[2]
+    k_pages, v_pages, layer = as_pool(k_pages, v_pages, layer)
+    n_kv, page_size = k_pages.shape[2], k_pages.shape[3]
     if not paged_kernel_supported(page_size):
         raise ValueError(
             f"page_size {page_size} is not a multiple of the {_LANES}-lane "
@@ -247,7 +254,7 @@ def paged_chunk_attention(
     # to the same resident physical page and Mosaic skips the repeated
     # fetch — the paged decode kernel's re-mapping with the chunk kernel's
     # causal/window bounds.
-    def _kv_index(bi, hi, qi, ki, qs, lens, ks, tables, fl):
+    def _kv_index(bi, hi, qi, ki, qs, lens, ks, tables, fl, lyr):
         q0 = qs[bi] + qi * block_q
         last_live = jnp.maximum(
             (lens[bi] + page_size - 1) // page_size - 1, 0
@@ -261,24 +268,21 @@ def paged_chunk_attention(
             )
         first_needed = jnp.minimum(first_needed, last_needed)
         phys = tables[bi, jnp.clip(ki, first_needed, last_needed)]
-        return (jnp.maximum(phys, 0), hi // group, 0, 0)
+        return (lyr[0], jnp.maximum(phys, 0), hi // group, 0, 0)
+
+    def _q_index(bi, hi, qi, ki, qs, lens, ks, tables, fl, lyr):
+        return (bi, hi, qi, 0)
 
     grid = (b, n_q, sq // block_q, n_p)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(
-                (1, 1, block_q, d),
-                lambda bi, hi, qi, ki, qs, lens, ks, tables, fl: (bi, hi, qi, 0),
-            ),
-            pl.BlockSpec((1, 1, page_size, d), _kv_index),
-            pl.BlockSpec((1, 1, page_size, d), _kv_index),
+            pl.BlockSpec((1, 1, block_q, d), _q_index),
+            pl.BlockSpec((None, 1, 1, page_size, d), _kv_index),
+            pl.BlockSpec((None, 1, 1, page_size, d), _kv_index),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, d),
-            lambda bi, hi, qi, ki, qs, lens, ks, tables, fl: (bi, hi, qi, 0),
-        ),
+        out_specs=pl.BlockSpec((1, 1, block_q, d), _q_index),
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -303,6 +307,7 @@ def paged_chunk_attention(
         jnp.asarray(k_starts, jnp.int32),
         jnp.asarray(block_tables, jnp.int32),
         flag,
+        layer,
         qh,
         k_pages,
         v_pages,
@@ -321,9 +326,11 @@ def paged_chunk_attention_xla(
     window_flag: jnp.ndarray | None = None,
     scale: float | None = None,
     softcap: float | None = None,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Gather-based twin: the dense XLA cached-chunk arithmetic over a
-    gathered view of each row's pages — the multi-query sibling of
+    gathered view of each row's pages (of layer ``layer`` where ``k_pages``
+    is the whole 5-D pool) — the multi-query sibling of
     paged_attention.paged_decode_attention_xla, and the kernel's numerics
     oracle.
 
@@ -337,8 +344,8 @@ def paged_chunk_attention_xla(
     on the SAME live keys is NOT guaranteed (reduction shapes change), which
     is why the serving engine threads ONE capacity per epoch
     (runtime/serving.py)."""
-    k = gather_pages(k_pages, block_tables)
-    v = gather_pages(v_pages, block_tables)
+    k = gather_pages(k_pages, block_tables, layer)
+    v = gather_pages(v_pages, block_tables, layer)
     return gqa_attention_hm(
         q, k, v, q_positions, k_positions,
         window=window, window_flag=window_flag, scale=scale, softcap=softcap,

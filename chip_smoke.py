@@ -23,6 +23,10 @@ every phase runs in a child that has exited before the next one starts.
            scheduler, prefix cache, Pallas attention); Bf adds --fusion
   C        every Pallas kernel at the model's shapes against its XLA twin,
            and a bf16 matmul chain held against the device's peak
+  P        the paged decode chunk and a suffix join compiled (not run, no
+           weights) at the benchmark cell's geometry: no copy, slice or
+           update-slice of the KV pool or of a layer of it in the compiled
+           program, and less than one pool of temporaries
   D        four chips: the phase-A server under --tp 4 and as a four-stage
            pipeline, with per-device memory (skipped below four devices)
 
@@ -50,7 +54,7 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, ".chip_smoke")  # listed in .gitignore
-PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "D")
+PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "D")
 
 MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
     model_type="mistral", hidden_size=4096, intermediate_size=14336,
@@ -67,6 +71,9 @@ PRESETS = {
         max_seq_len=2048, prompts=(32, 300, 1500), shared_prefix=1000,
         new_tokens=64, page_size=128, chunk=256, int4_group=128,
         batches=(1, 8), matmul=(8192, 20),
+        # mistral7b-chat-closed (bench/configs/mistral-7b-v0.1-d16.json)
+        pool=dict(layers=16, pages=256, lanes=8, table_pages=8, steps=8,
+                  join_width=256),
     ),
     # The rehearsal: same family and head layout rules (tp 4 divides the
     # heads, a page is a whole lane tile), widths a CPU can interpret.
@@ -80,6 +87,8 @@ PRESETS = {
         dtype="f32", max_seq_len=256, prompts=(12, 40, 150),
         shared_prefix=100, new_tokens=8, page_size=128, chunk=32,
         int4_group=64, batches=(1, 2), matmul=(256, 4),
+        pool=dict(layers=3, pages=64, lanes=2, table_pages=2, steps=4,
+                  join_width=64),
     ),
 }
 
@@ -263,7 +272,37 @@ def child_kernels(preset: dict) -> None:
     })
 
 
-CHILDREN = {"probe": child_probe, "setup": child_setup, "kernels": child_kernels}
+def child_pool(preset: dict) -> None:
+    """Compile, for the device this process holds, the two programs a
+    saturated paged server runs, from shapes alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import pool_audit
+    from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.utils.device import describe_devices
+
+    device = describe_devices()
+    g = preset["pool"]
+    config = LlamaConfig(**dict(
+        preset["model"], num_hidden_layers=g["layers"],
+        attention_impl="pallas",
+    ))
+    # The interpreter (the rehearsal's) stages a kernel's operands through
+    # copies of its own; there the XLA twins stand in for the kernels.
+    reports = pool_audit.audit_paged_programs(
+        config, n_pages=g["pages"], page_size=preset["page_size"],
+        lanes=g["lanes"], table_pages=g["table_pages"], n_steps=g["steps"],
+        join_width=g["join_width"],
+        dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]],
+        allow_pallas=jax.default_backend() != "cpu",
+    )
+    for name, report in reports.items():
+        emit({"program": name, **device, **report})
+
+
+CHILDREN = {"probe": child_probe, "setup": child_setup,
+            "kernels": child_kernels, "pool": child_pool}
 
 
 # ------------------------------------------------------------------- traffic
@@ -629,6 +668,31 @@ def phase_kernels(args, preset) -> dict:
     }
 
 
+def phase_pool(args, preset) -> dict:
+    out, problems = {}, []
+    for r in run_child("pool", args, timeout=900):
+        moved = r["scans"] + r["pool_ops"]
+        say(f"phase=P program={r['program']} temp_bytes={r['temp_bytes']} "
+            f"pool_bytes={r['pool_bytes']} pool_moving_ops={len(moved)} "
+            f"compile_s={r['seconds']}")
+        for m in moved:
+            say(f"phase=P   {r['program']} moves the pool: {m}")
+        if moved:
+            problems.append(f"{r['program']}: {len(moved)} op(s) move the pool")
+        if r["temp_bytes"] is None:
+            say(f"phase=P   {r['platform']} gives no memory analysis")
+        elif r["temp_bytes"] >= r["pool_bytes"]:
+            problems.append(
+                f"{r['program']}: {r['temp_bytes']} B of temporaries, one "
+                f"pool is {r['pool_bytes']} B"
+            )
+        out[f"{r['program']}_temp_bytes"] = r["temp_bytes"]
+        out[f"{r['program']}_pool_ops"] = len(moved)
+    if problems:
+        raise PhaseFailed("; ".join(problems))
+    return out
+
+
 def phase_four_chips(args, preset) -> dict:
     cpu = args.rehearse_cpu
     out = {}
@@ -714,6 +778,7 @@ def main() -> int:
             "Bf", args, preset, [*paged, "--fusion", "all@pallas"],
             expect_impl="pallas", shared_prefix=True),
         "C": lambda: phase_kernels(args, preset),
+        "P": lambda: phase_pool(args, preset),
         "D": lambda: phase_four_chips(args, preset),
     }
 

@@ -145,6 +145,22 @@ def test_chip_smoke_seeded_failure_turns_the_exit_code():
     assert not any(ln.startswith("{") for ln in lines)
 
 
+def test_chip_smoke_pool_phase_rehearses():
+    """Phase P at the tiny preset: both paged programs compile from shapes
+    alone and neither moves the pool (the XLA twins here; the kernels'
+    program is compiled for the chip there)."""
+    proc = _smoke("--rehearse-cpu", "--phases", "P", timeout=300)
+    assert proc.returncode == 0, proc.stdout[-4000:] + proc.stderr[-4000:]
+    lines = proc.stdout.strip().splitlines()
+    assert all("platform=cpu" in ln for ln in lines), proc.stdout
+    for program in ("decode", "suffix_join"):
+        assert any(
+            f"program={program} " in ln and "pool_moving_ops=0" in ln
+            for ln in lines
+        ), proc.stdout
+    assert lines[-1].endswith("not a result)")
+
+
 def test_chip_smoke_is_nothing_without_the_repo(tmp_path):
     """Alone in a directory it exits non-zero and prints no result."""
     import shutil
@@ -161,15 +177,15 @@ def test_chip_smoke_is_nothing_without_the_repo(tmp_path):
 @pytest.mark.slow
 def test_chip_smoke_cpu_rehearsal_passes_every_phase():
     """The whole script at the tiny preset on the CPU: servers A, B, Bf,
-    the kernels in interpret mode, tp 4 and a four-stage mesh over four
-    virtual devices. ~2 minutes; merely lacking a chip never selects it
+    the kernels in interpret mode, the pool audit, tp 4 and a four-stage
+    mesh over four virtual devices. ~2 minutes; merely lacking a chip never selects it
     (the default run on this machine fails at the probe)."""
     default = _smoke(timeout=300)
     assert default.returncode == 1 and "needs 'tpu'" in default.stdout
     assert not any(
         ln.startswith("{") for ln in default.stdout.splitlines()
     )
-    proc = _smoke("--rehearse-cpu", "--phases", "setup,A,B,Bf,C,D",
+    proc = _smoke("--rehearse-cpu", "--phases", "setup,A,B,Bf,C,P,D",
                   timeout=1500)
     assert proc.returncode == 0, proc.stdout[-4000:] + proc.stderr[-4000:]
     lines = proc.stdout.strip().splitlines()

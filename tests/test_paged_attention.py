@@ -162,3 +162,51 @@ def test_unmapped_tail_pages_are_harmless():
     q_pos, k_pos = xla_grids(lengths, pads)
     want = paged_decode_attention_xla(q, kp, vp, q_pos, k_pos, bt)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+def test_stacked_pool_reads_the_indexed_layer(layer):
+    """The whole pool [n_layers, n_pages, ...] plus a layer index reads
+    exactly what the same kernel reads from that layer sliced out; the
+    other layers hold different bytes, so layer 0 read by mistake shows."""
+    q, kp, vp, _, _, bt, lengths, pads = setup(seed=6)
+    rng = np.random.default_rng(60)
+    k_pool = jnp.asarray(rng.normal(size=(3,) + kp.shape), jnp.float32)
+    v_pool = jnp.asarray(rng.normal(size=(3,) + vp.shape), jnp.float32)
+    got = paged_decode_attention(
+        q, k_pool, v_pool, lengths, bt, pads, layer=jnp.int32(layer),
+        window=64, interpret=True,
+    )
+    want = paged_decode_attention(
+        q, k_pool[layer], v_pool[layer], lengths, bt, pads, window=64,
+        interpret=True,
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    other = paged_decode_attention(
+        q, k_pool[0], v_pool[0], lengths, bt, pads, window=64, interpret=True
+    )
+    assert float(jnp.abs(got - other).max()) > 1e-3
+    # The gather twin indexes the layer the same way.
+    q_pos, k_pos = xla_grids(lengths, pads)
+    twin = paged_decode_attention_xla(
+        q, k_pool, v_pool, q_pos, k_pos, bt, window=64,
+        layer=jnp.int32(layer),
+    )
+    np.testing.assert_array_equal(
+        np.asarray(twin),
+        np.asarray(paged_decode_attention_xla(
+            q, k_pool[layer], v_pool[layer], q_pos, k_pos, bt, window=64
+        )),
+    )
+
+
+def test_layer_argument_must_match_the_rank():
+    q, kp, vp, _, _, bt, lengths, pads = setup()
+    with pytest.raises(ValueError, match="takes no `layer`"):
+        paged_decode_attention(
+            q, kp, vp, lengths, bt, pads, layer=jnp.int32(0), interpret=True
+        )
+    with pytest.raises(ValueError, match="needs the `layer`"):
+        paged_decode_attention(
+            q, kp[None], vp[None], lengths, bt, pads, interpret=True
+        )

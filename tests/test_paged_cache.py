@@ -286,3 +286,48 @@ def test_cow_fork_write_isolation_end_to_end():
     )
     assert float(jnp.abs(g1[0, :, 20] - 7.0).max()) == 0.0
     assert float(jnp.abs(g0[0, :, 20] - 7.0).min()) > 0.0
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_pool_write_touches_one_layer_and_matches_the_layer_form(layer):
+    """paged_write_pool on the whole pool == paged_write_layer on that layer
+    sliced out, byte for byte; every other layer keeps its bytes; unmapped
+    entries and slots below ``starts`` drop in both."""
+    from cake_tpu.models.llama.paged_cache import paged_write_pool
+
+    rng = np.random.default_rng(11)
+    n_layers, n_pages, n_kv, ps, hd = 3, 6, 2, 16, 8
+    a = PageAllocator(n_pages, ps, batch=3, max_pages_per_seq=3)
+    a.map_range(0, 20, 48)  # page 0 (slots 0..15) stays unmapped
+    a.map_range(1, 0, 40)
+    bt = jnp.asarray(a.block_tables)  # row 2: nothing mapped
+    shape = (n_layers, n_pages, n_kv, ps, hd)
+    k0 = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    v0 = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    k_new = jnp.asarray(rng.normal(size=(3, 30, n_kv, hd)), jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(3, 30, n_kv, hd)), jnp.float32)
+    starts = jnp.asarray([0, 25, 0], jnp.int32)
+    k1, v1 = jax.jit(paged_write_pool)(
+        k0, v0, jnp.int32(layer), k_new, v_new, jnp.int32(10), bt, starts
+    )
+    k_want, v_want = paged_write_layer(
+        k0[layer], v0[layer], k_new, v_new, jnp.int32(10), bt, starts
+    )
+    for got, want, before in ((k1, k_want, k0), (v1, v_want, v0)):
+        got, before = np.asarray(got), np.asarray(before)
+        np.testing.assert_array_equal(got[layer], np.asarray(want))
+        rest = [i for i in range(n_layers) if i != layer]
+        np.testing.assert_array_equal(got[rest], before[rest])
+    # Row 0's tokens at slots 10..15 fell on its unmapped page and row 1's
+    # below slot 25 under its threshold: the dense view reads the old bytes.
+    view = np.asarray(gather_pages(k1, bt, jnp.int32(layer)))
+    old = np.asarray(gather_pages(k0, bt, jnp.int32(layer)))
+    np.testing.assert_array_equal(view[1, :, 10:25], old[1, :, 10:25])
+    np.testing.assert_array_equal(
+        view[1, :, 25:40], np.moveaxis(np.asarray(k_new[1, 15:30]), 0, 1)
+    )
+    np.testing.assert_array_equal(view[0, :, :16], 0.0)
+    np.testing.assert_array_equal(
+        view[0, :, 20:40], np.moveaxis(np.asarray(k_new[0, 10:30]), 0, 1)
+    )
+    np.testing.assert_array_equal(view[2], 0.0)

@@ -511,3 +511,41 @@ def test_pallas_paged_engine_cold_warm_identical(model):
         eng.stop()
     assert warm == cold
     assert eng.stats["prefix_hits"] > 0
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+def test_stacked_pool_reads_the_indexed_layer(layer):
+    """The whole pool [n_layers, n_pages, ...] plus a layer index reads
+    exactly what the same kernel reads from that layer sliced out (cold
+    chunk, windowed); layer 0 read by mistake shows."""
+    q, kp, vp, bt, lengths, pads, w = cold_setup(seed=8)
+    rng = np.random.default_rng(80)
+    k_pool = jnp.asarray(rng.normal(size=(3,) + kp.shape), jnp.float32)
+    v_pool = jnp.asarray(rng.normal(size=(3,) + vp.shape), jnp.float32)
+    zeros = jnp.zeros((B,), jnp.int32)
+    kw = dict(window_flag=jnp.ones((), bool), window=48, interpret=True)
+    got = paged_chunk_attention(
+        q, k_pool, v_pool, zeros, lengths, pads, bt,
+        layer=jnp.int32(layer), **kw,
+    )
+    want = paged_chunk_attention(
+        q, k_pool[layer], v_pool[layer], zeros, lengths, pads, bt, **kw
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    other = paged_chunk_attention(
+        q, k_pool[0], v_pool[0], zeros, lengths, pads, bt, **kw
+    )
+    assert float(jnp.abs(got - other).max()) > 1e-3
+    # The gather twin indexes the layer the same way.
+    q_pos, k_pos = prefill_positions(PER_SEQ * PS, pads, ends=lengths)
+    twin = paged_chunk_attention_xla(
+        q, k_pool, v_pool, q_pos[:, :w], k_pos, bt, window=48,
+        layer=jnp.int32(layer),
+    )
+    np.testing.assert_array_equal(
+        np.asarray(twin),
+        np.asarray(paged_chunk_attention_xla(
+            q, k_pool[layer], v_pool[layer], q_pos[:, :w], k_pos, bt,
+            window=48,
+        )),
+    )
