@@ -4,7 +4,9 @@
 The program loads only from a checkpoint directory, so the weights have to
 be on disk. They are drawn on the device, one jitted call a layer, in the
 type they are served in, and written once per checkout and configuration
-(``READY`` marks a finished directory). The reader is the reference's: it
+(``READY`` marks a finished directory). Which tensors there are is the
+architecture's to say (``bench/architectures``): this file keeps the format,
+the split into files, the seeded draw and the reader. The reader is the reference's: it
 maps the files and hands out one tensor at a time. Independent of
 ``cake_tpu/io``; imports JAX only inside ``write_checkpoint``.
 """
@@ -23,23 +25,13 @@ READY = "READY"
 _ST_DTYPE = {"bfloat16": "BF16", "float32": "F32"}
 
 
-def layer_shapes(cfg: dict) -> dict[str, tuple[int, int]]:
-    """HF tensor names (after ``model.layers.<i>.``) -> [out, in] shapes."""
-    h, inter = cfg["hidden_size"], cfg["intermediate_size"]
-    head_dim = cfg.get("head_dim") or h // cfg["num_attention_heads"]
-    q, kv = cfg["num_attention_heads"] * head_dim, cfg["num_key_value_heads"] * head_dim
-    return {
-        "self_attn.q_proj.weight": (q, h),
-        "self_attn.k_proj.weight": (kv, h),
-        "self_attn.v_proj.weight": (kv, h),
-        "self_attn.o_proj.weight": (h, q),
-        "mlp.gate_proj.weight": (inter, h),
-        "mlp.up_proj.weight": (inter, h),
-        "mlp.down_proj.weight": (h, inter),
-    }
-
-
-NORMS = ("input_layernorm.weight", "post_attention_layernorm.weight")
+# How an architecture's tensor table says a tensor is drawn: normal at the
+# configuration's ``initializer_range``; constant; or ``head``, normal with
+# the rows of the special ids zero: their logits are 0 and never the largest
+# of a random model's, so no answer stops on an end-of-sequence token that
+# the words of one seed happened to draw. Every answer then runs to its
+# ``max_tokens``, whatever the seed.
+DRAWS = ("normal", "ones", "zeros", "head")
 
 
 def _write_safetensors(path: Path, tensors: dict[str, np.ndarray]) -> None:
@@ -58,38 +50,47 @@ def _write_safetensors(path: Path, tensors: dict[str, np.ndarray]) -> None:
             f.write(np.ascontiguousarray(arr).view(np.uint8).data)
 
 
-def write_checkpoint(model_dir: Path, cfg: dict, dtype: str, seed: int) -> dict:
-    """Draw and write the weights; returns {bytes, seconds}. Runs in the
-    process that holds the chip (or on the CPU in a rehearsal)."""
+def write_checkpoint(model_dir: Path, cfg: dict, dtype: str, seed: int, arch) -> dict:
+    """Draw and write the tensors of ``arch``'s table (see
+    ``bench/architectures``); returns {bytes, seconds}. Runs in the process
+    that holds the chip (or on the CPU in a rehearsal). The top's drawn
+    tensors take the seed's keys 0, 1, ... in the table's order, one call
+    each, and its file keeps that order. Layer ``i`` splits the next key but
+    ``i`` among its drawn tensors in the table's order, in one call; its file
+    holds them by name (as JAX hands back a dict), then its constants."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
-    from bench.tokens import FIRST_WORD_ID, write_tokenizer
+    from bench.tokens import Vocabulary
 
     t0 = time.perf_counter()
     shutil.rmtree(model_dir, ignore_errors=True)
     model_dir.mkdir(parents=True)
     jdtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
     std = cfg.get("initializer_range", 0.02)
-    shapes = layer_shapes(cfg)
-    h, vocab = cfg["hidden_size"], cfg["vocab_size"]
+    vocab = Vocabulary(arch, cfg)
+    special_ids = np.asarray(vocab.special_ids)
 
-    def normal(key, shape):
-        return (jax.random.normal(key, shape, jnp.float32) * std).astype(jdtype)
+    def drawn(key, shape, draw):
+        x = (jax.random.normal(key, shape, jnp.float32) * std).astype(jdtype)
+        return x.at[special_ids].set(0) if draw == "head" else x
 
-    @jax.jit
-    def draw_layer(key):
-        keys = jax.random.split(key, len(shapes))
-        return {n: normal(k, s) for k, (n, s) in zip(keys, shapes.items())}
+    @functools.cache
+    def draw_together(spec):  # one jitted call for all the drawn tensors of a layer
+        return jax.jit(lambda key: [
+            drawn(k, shape, draw) for k, (shape, draw) in zip(jax.random.split(key, len(spec)), spec)
+        ])
 
-    draw_table = jax.jit(lambda key: normal(key, (vocab, h)))
-    # The head's rows of the special ids are zero: their logits are 0 and
-    # never the largest of a random model's, so no answer stops on an
-    # end-of-sequence token that the words of one seed happened to draw.
-    # Every answer then runs to its ``max_tokens``, whatever the seed.
-    draw_head = jax.jit(lambda key: normal(key, (vocab, h)).at[:FIRST_WORD_ID].set(0))
+    def constants(table: dict) -> dict:
+        for name, (_, draw) in table.items():
+            if draw not in DRAWS:
+                raise ValueError(f"tensor {name!r}: unknown draw {draw!r} (has: {DRAWS})")
+        return {name: (np.ones if draw == "ones" else np.zeros)(shape, np.dtype(jdtype))
+                for name, (shape, draw) in table.items() if draw in ("ones", "zeros")}
+
     root = jax.random.key(seed)
-    ones = np.ones((h,), np.dtype(jdtype))
     n_layers = cfg["num_hidden_layers"]
     weight_map, total = {}, 0
 
@@ -100,21 +101,26 @@ def write_checkpoint(model_dir: Path, cfg: dict, dtype: str, seed: int) -> dict:
         weight_map.update(dict.fromkeys(tensors, fname))
         total += sum(a.nbytes for a in tensors.values())
 
-    emit(0, {
-        "model.embed_tokens.weight": np.asarray(draw_table(jax.random.fold_in(root, 0))),
-        "model.norm.weight": ones,
-        "lm_head.weight": np.asarray(draw_head(jax.random.fold_in(root, 1))),
-    })
+    top = arch.top_tensors(cfg)
+    tensors = constants(top)
+    top_drawn = [n for n in top if n not in tensors]
+    for k, name in enumerate(top_drawn):
+        one = jax.jit(functools.partial(drawn, shape=top[name][0], draw=top[name][1]))
+        tensors[name] = np.asarray(one(jax.random.fold_in(root, k)))
+    emit(0, {name: tensors[name] for name in top})
     for i in range(n_layers):
-        drawn = jax.device_get(draw_layer(jax.random.fold_in(root, 2 + i)))
-        layer = {f"model.layers.{i}.{n}": a for n, a in drawn.items()}
-        layer.update({f"model.layers.{i}.{n}": ones for n in NORMS})
-        emit(i + 1, layer)
+        table = arch.layer_tensors(cfg, i)
+        fixed = constants(table)
+        names = [n for n in table if n not in fixed]
+        arrays = draw_together(tuple(table[n] for n in names))(
+            jax.random.fold_in(root, len(top_drawn) + i))
+        by_name = sorted(zip(names, jax.device_get(arrays)), key=lambda kv: kv[0])
+        emit(i + 1, {**dict(by_name), **fixed})
     with open(model_dir / "model.safetensors.index.json", "w") as f:
         json.dump({"metadata": {"total_size": total}, "weight_map": weight_map}, f)
     with open(model_dir / "config.json", "w") as f:
         json.dump(cfg, f, indent=1)
-    write_tokenizer(model_dir / "tokenizer.json", vocab)
+    vocab.write_tokenizer(model_dir / "tokenizer.json")
     (model_dir / READY).write_text(f"{seed}\n")
     return {"bytes": total, "seconds": time.perf_counter() - t0}
 

@@ -43,10 +43,10 @@ def known_flags(flags: list[str]) -> tuple[list[str], list[str]]:
 
 
 class Control:
-    def __init__(self, port: int, config: dict, model_dir: Path):
+    def __init__(self, port: int, config: dict, arch, model_dir: Path):
         self.sock = socket.create_connection(("127.0.0.1", port))
         self.lines = self.sock.makefile("r")
-        self.config, self.model_dir = config, model_dir
+        self.config, self.arch, self.model_dir = config, arch, model_dir
         self.trace_dir: str | None = None
 
     def send(self, obj: dict) -> None:
@@ -86,9 +86,13 @@ class Control:
         if not files:
             return {"error": f"no .xplane.pb under {self.trace_dir}"}
         planes = xplane.load(files[0])
+        # a pattern with ``op`` asks for one named kernel, else for whole programs
+        by_op = {k: p for k, p in msg["patterns"].items() if "op" in p}
         out = {
             "summary": xplane.summary(planes),
-            "programs": {k: xplane.programs(planes, p) for k, p in msg["patterns"].items()},
+            "programs": {k: xplane.programs(planes, p) for k, p in msg["patterns"].items()
+                         if k not in by_op},
+            "ops": {k: xplane.op_times(planes, p) for k, p in by_op.items()},
             "inventory": xplane.inventory(planes),
         }
         if msg.get("keep_events"):
@@ -107,7 +111,8 @@ class Control:
         tolerance = self.config["judge"]["rehearsal_tolerance" if msg["rehearsal"]
                                          else "tolerance"]
         out = reference.judge(
-            Reader(self.model_dir), model_config(self.config), tolerance, msg["probes"],
+            self.arch, Reader(self.model_dir), model_config(self.config), tolerance,
+            msg["probes"],
         )
         out["seconds"] = time.perf_counter() - t0
         return out
@@ -124,7 +129,11 @@ def main() -> int:
     with open(args.config) as f:
         config = json.load(f)
     model_dir = Path(args.model_dir)
-    control = Control(args.control, config, model_dir)
+    from bench.manifest import architecture, model_config
+
+    # the one place this process learns the architecture: checkpoint and judge
+    arch = architecture(Path(__file__).resolve().parents[1], config)
+    control = Control(args.control, config, arch, model_dir)
 
     from cake_tpu.utils.device import describe_devices, setup_compile_cache
 
@@ -134,13 +143,12 @@ def main() -> int:
         print(f"bench.child: no TPU, JAX is on {device}", file=sys.stderr)
         return 3
     from bench.checkpoint import READY, write_checkpoint
-    from bench.manifest import model_config
 
     wrote = None
     if not (model_dir / READY).exists():
         dtype = "f32" if args.rehearse_cpu else config["served_dtype"]
         wrote = write_checkpoint(model_dir, model_config(config), dtype,
-                                 config["weights_seed"])
+                                 config["weights_seed"], arch)
     flags, dropped = known_flags(config["server_flags"])
     if args.rehearse_cpu:
         flags += ["--cpu", "--dtype", "f32"]

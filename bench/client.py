@@ -20,7 +20,7 @@ import time
 from collections.abc import Iterator
 from urllib.parse import urlparse
 
-from bench.tokens import chat_ids, ids_from_text, prompt_text
+from bench.tokens import Vocabulary
 from bench.traffic import Request
 
 CHAT_ROUTE = "/api/v1/chat/completions"
@@ -30,6 +30,7 @@ CHAT_ROUTE = "/api/v1/chat/completions"
 class Outcome:
     request: Request
     due: float  # perf_counter at which the request was due
+    vocab: Vocabulary  # the cell's words and template
     sent: float = 0.0
     status: int = 0  # HTTP status; 0 = transport error or never answered
     finish: str | None = None
@@ -51,7 +52,7 @@ class Outcome:
             return f"status {self.status}"
         if self.finish not in ("stop", "length"):
             return f"finish_reason {self.finish!r}"
-        want = len(chat_ids(list(self.request.prompt_ids)))
+        want = len(self.vocab.chat_ids(self.request.prompt_ids))
         if not self.usage or self.usage.get("prompt_tokens") != want:
             return f"usage {self.usage} for a prompt of {want} tokens"
         # An end-of-sequence token is counted and not streamed.
@@ -61,7 +62,7 @@ class Outcome:
         return None
 
     def served_ids(self) -> list[int]:
-        return ids_from_text(self.text)
+        return self.vocab.ids_from_text(self.text)
 
 
 class Load:
@@ -69,8 +70,9 @@ class Load:
     collects their outcomes. One thread a request in flight: each spends its
     life blocked on a socket."""
 
-    def __init__(self, base_url: str, timeout_s: float = 300.0):
+    def __init__(self, base_url: str, vocab: Vocabulary, timeout_s: float = 300.0):
         url = urlparse(base_url)
+        self._vocab = vocab
         self._addr = (url.hostname, url.port)
         self._timeout = timeout_s
         self._lock = threading.Lock()
@@ -86,7 +88,7 @@ class Load:
             "model": "bench", "stream": True, "max_tokens": out.request.max_tokens,
             "stream_options": {"include_usage": True},
             "messages": [{"role": "user",
-                          "content": prompt_text(list(out.request.prompt_ids))}],
+                          "content": self._vocab.prompt_text(list(out.request.prompt_ids))}],
         })
         conn = http.client.HTTPConnection(*self._addr, timeout=self._timeout)
         try:
@@ -141,7 +143,7 @@ class Load:
             conn.close()
 
     def _start(self, req: Request, due: float) -> Outcome:
-        out = Outcome(req, due)
+        out = Outcome(req, due, self._vocab)
         th = threading.Thread(target=self._stream, args=(out,), daemon=True)
         with self._lock:
             self.outcomes.append(out)
@@ -179,7 +181,7 @@ class Load:
                     wait = last_send[0] + min_send_gap_s - time.perf_counter()
                     if wait > 0:
                         time.sleep(wait)
-                    out = Outcome(next(stream), ready)
+                    out = Outcome(next(stream), ready, self._vocab)
                     last_send[0] = time.perf_counter()
                     with self._lock:
                         self.outcomes.append(out)
@@ -198,7 +200,7 @@ class Load:
         cancels the request: warm-up wants the programs, not the answers."""
         outs = []
         for req in requests:
-            out = Outcome(req, time.perf_counter())
+            out = Outcome(req, time.perf_counter(), self._vocab)
             self._stream(out, cut_after)
             outs.append(out)
         return outs

@@ -1,14 +1,13 @@
-"""What a step has to move, from the configuration's shapes alone, and the
-table of peaks it is held against. Stdlib only."""
+"""The table of peaks that a step's bytes and operations are held against.
+What a step has to move is counted from the configuration's shapes by its
+architecture's cost functions (``bench/architectures``). Stdlib only."""
 
 from __future__ import annotations
 
 import json
 from pathlib import Path
 
-from bench.checkpoint import layer_shapes
-
-ITEMSIZE = {"bf16": 2, "f32": 4}
+from bench.manifest import architecture
 
 
 def peaks(device_kind: str) -> dict:
@@ -23,12 +22,7 @@ def peaks(device_kind: str) -> dict:
 
 
 def decode_weight_bytes(cfg: dict, dtype: str) -> int:
-    """Bytes of weights a chip that holds the whole model must read to decode
-    one token for any batch: every layer's seven matrices and two norms, the
-    final norm and the output head. The embedding is a lookup of one row a
-    lane and the KV cache depends on the contexts: neither is counted, so
-    the share of peak bandwidth made from this is a floor on the traffic."""
-    h = cfg["hidden_size"]
-    per_layer = sum(o * i for o, i in layer_shapes(cfg).values()) + 2 * h
-    total = cfg["num_hidden_layers"] * per_layer + h + cfg["vocab_size"] * h
-    return total * ITEMSIZE[dtype]
+    """Bytes of weights a chip must read to decode one token for any batch,
+    as the configuration's architecture counts them."""
+    root = Path(__file__).resolve().parents[1]
+    return architecture(root, cfg).decode_weight_bytes(cfg, dtype)

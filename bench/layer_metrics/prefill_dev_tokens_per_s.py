@@ -4,8 +4,6 @@ token reached the client inside the traced window (a prefill ends a few
 milliseconds before it), so a request astride an edge of the window is
 counted whole or not at all."""
 
-from bench.tokens import TEMPLATE_TOKENS
-
 
 def read(facts, spec):
     trace = facts["trace"] or {}
@@ -14,7 +12,7 @@ def read(facts, spec):
         return None
     lo, hi = trace["t_start"], trace["t_stop"]
     tokens = sum(
-        len(o.request.prompt_ids) + TEMPLATE_TOKENS for o in facts["outcomes"]
+        len(o.vocab.chat_ids(o.request.prompt_ids)) for o in facts["outcomes"]
         if o.arrivals and lo <= o.arrivals[0] < hi
     )
     return tokens / sum(runs) if tokens else None

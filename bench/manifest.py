@@ -1,12 +1,16 @@
 """BENCHMARK.json and the data files it names, loaded and cross-checked.
 
-A cell, a configuration, a traffic mix or a per-layer metric is added by
-adding an entry to BENCHMARK.json and files under ``bench/``; nothing here or
-in ``run.py`` names one. Stdlib only.
+A cell, a configuration, a traffic mix, a per-layer metric or an
+architecture is added by adding an entry to BENCHMARK.json and files under
+``bench/``; nothing here or in ``run.py`` names one. What depends on a model's
+architecture (tensors, plain reference, template, costs) is in
+``bench/architectures/<model_type>.py``, found by the configuration's own
+``model_type`` key. Stdlib only.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
 from pathlib import Path
 
@@ -36,6 +40,22 @@ def _json(path: Path) -> dict:
         raise ManifestError(f"{path}: not JSON: {e}") from e
 
 
+def architecture(root: Path, config: dict):
+    """The module of the configuration's ``model_type``, loaded from its
+    file under ``root`` (what it gives: ``bench/architectures/__init__.py``)."""
+    model_type = config.get("model_type")
+    path = Path(root) / "bench" / "architectures" / f"{model_type}.py"
+    if not path.is_file():
+        raise ManifestError(
+            f"no architecture file for model_type {model_type!r}: add {path.relative_to(root)} "
+            "(bench/architectures/__init__.py says what it gives)"
+        )
+    spec = importlib.util.spec_from_file_location(f"bench_architecture_{model_type}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def _in_cell(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
@@ -49,7 +69,8 @@ class Manifest:
 
     def cell(self, name: str) -> dict:
         """Everything one run needs: the cell's entry and file, its
-        configuration's file, its mix, and the metrics it reports."""
+        configuration's file and architecture, its mix, and the metrics it
+        reports."""
         if name not in self.cells:
             raise ManifestError(
                 f"no workload {name!r} in BENCHMARK.json (has: {sorted(self.cells)})"
@@ -65,6 +86,7 @@ class Manifest:
         if entry["config"] not in self.configs:
             raise ManifestError(f"workload {name!r}: unknown config {entry['config']!r}")
         config = _json(self.root / self.configs[entry["config"]]["file"])
+        arch = architecture(self.root, config)
         mix = _json(self.root / "bench" / "traffic" / f"{entry['traffic']}.json")
         if config["deployment"]["chips"] != entry["chips"]:
             raise ManifestError(
@@ -88,12 +110,13 @@ class Manifest:
         return {
             "name": name, "entry": entry, "file": cell_file, "config": config,
             "config_file": self.configs[entry["config"]]["file"],
-            "config_name": entry["config"], "mix": mix,
+            "config_name": entry["config"], "architecture": arch, "mix": mix,
             "end_to_end": end_to_end, "per_layer": per_layer,
         }
 
     def check(self) -> None:
-        """Every cell loads, and every configuration is used."""
+        """Every cell loads (so its configuration's architecture file is
+        there), and every configuration is used."""
         for name in self.cells:
             self.cell(name)
         unused = set(self.configs) - {w["config"] for w in self.cells.values()}

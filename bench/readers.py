@@ -49,8 +49,18 @@ def program_mean_ms(facts: dict, spec: dict):
     return fmean(runs) * 1e3 if runs else None
 
 
+def op_mean_us(facts: dict, spec: dict):
+    """Mean own device time, in microseconds a call, of the operations
+    ``pattern`` picks by name (``{"op": regex}``, optionally inside
+    ``module``): one kernel's time. A ``python`` reader divides its
+    architecture's bytes or operations for the call by the same
+    ``facts["trace"]["ops"][metric]`` for a roofline share."""
+    got = (facts["trace"] or {}).get("ops", {}).get(facts["metric"])
+    return got["seconds"] / got["count"] * 1e6 if got and got["count"] else None
+
+
 KINDS = {f.__name__: f for f in
-         (stats_delta, gauge_mean, requestlog_percentile, program_mean_ms)}
+         (stats_delta, gauge_mean, requestlog_percentile, program_mean_ms, op_mean_us)}
 
 
 def load_spec(root: Path, name: str) -> dict:

@@ -24,10 +24,11 @@ from pathlib import Path
 
 T_START = time.perf_counter()
 
-from bench import readers, stats, tokens, traffic  # noqa: E402
+from bench import readers, stats, traffic  # noqa: E402
 from bench.client import Load  # noqa: E402
 from bench.manifest import Manifest, ManifestError  # noqa: E402
 from bench.server import Server, ServerFailed  # noqa: E402
+from bench.tokens import Vocabulary  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 WORK = ROOT / ".bench_work"  # listed in .gitignore; checkpoints, logs, traces
@@ -102,7 +103,7 @@ class GaugeSampler(threading.Thread):
         return self.samples
 
 
-def offer(load: Load, mix: dict, seed: int, seconds: float, vocab: int,
+def offer(load: Load, mix: dict, seed: int, seconds: float, vocab: Vocabulary,
           at_t0=lambda: None) -> float:
     """Offer the mix for ``seconds``; returns the window's start, t0. An
     open loop starts at t0. A closed loop starts ``lead_in_s`` before it, so
@@ -161,6 +162,7 @@ def main(argv: list[str] | None = None) -> int:
     except ManifestError as e:
         print(f"bench.run: {e}", file=sys.stderr)
         return 2
+    cell["vocab"] = Vocabulary(cell["architecture"], cell["config"])
     if not (ROOT / "cake_tpu" / "cli.py").exists():
         print("bench.run: no cake_tpu package beside bench/: the benchmark "
               "measures that program and is nothing without it", file=sys.stderr)
@@ -199,11 +201,11 @@ def cold_pass(args, cell: dict, server: Server, platform: str) -> None:
     window that compiles runs at half the speed and so through half the
     requests; the run after it would meet the other half cold. A closed loop
     is offered ``cold_pass_factor`` times as long, since compiling slows it."""
-    mix, vocab = cell["mix"], cell["config"]["vocab_size"]
+    mix, vocab = cell["mix"], cell["vocab"]
     server.wait_started(timeout_s=900)
     server.wait_health(timeout_s=900)
     t = time.perf_counter()
-    load = Load(server.base)
+    load = Load(server.base, vocab)
     if mix["loop"] == "open":
         offer(load, mix, args.seed, args.seconds, vocab)
         load.finish(mix["drain_s"])
@@ -217,8 +219,7 @@ def cold_pass(args, cell: dict, server: Server, platform: str) -> None:
 
 
 def measure(args, cell: dict, server: Server, platform: str) -> int:
-    config, mix = cell["config"], cell["mix"]
-    vocab = config["vocab_size"]
+    config, mix, vocab = cell["config"], cell["mix"], cell["vocab"]
     started = server.wait_started(timeout_s=900)
     t_written = time.perf_counter()
     health = server.wait_health(timeout_s=900)
@@ -238,7 +239,7 @@ def measure(args, cell: dict, server: Server, platform: str) -> int:
     # Warm-up, the same in every run: quantiles of the prompt lengths alone,
     # then (open loops) the mix itself for a few seconds, cut short. A closed
     # loop's lead-in is its warm-up.
-    load = Load(server.base)
+    load = Load(server.base, vocab)
     alone = load.run_each(traffic.warmup_requests(mix, vocab), cut_after=WARMUP_CUT)
     bad = [o for o in alone if o.status != 200 or not o.arrivals]
     if bad:
@@ -267,7 +268,7 @@ def measure(args, cell: dict, server: Server, platform: str) -> int:
             target=take_trace, daemon=True,
             args=(server, TRACE_AT * args.seconds, min(seconds, 0.5 * args.seconds), trace),
         )
-    load = Load(server.base)
+    load = Load(server.base, vocab)
     t0 = offer(load, mix, args.seed, args.seconds, vocab, at_t0)
     setup_s = t0 - T_START
     drained = load.finish(mix["drain_s"] if mix["loop"] == "open" else 0.0)
@@ -287,12 +288,12 @@ def measure(args, cell: dict, server: Server, platform: str) -> int:
     t_probe = time.perf_counter()
     probe_reqs = traffic.probe_requests(cell["file"]["probe_prompt_tokens"],
                                         PROBE_NEW_TOKENS, args.seed, vocab)
-    probes = Load(server.base).run_each(probe_reqs)
+    probes = Load(server.base, vocab).run_each(probe_reqs)
     bad = [o.failure() for o in probes if o.failure()]
     if bad:
         raise ServerFailed(f"probe request failed: {bad[0]}")
     verdict = server.call("judge", rehearsal=args.rehearse_cpu, probes=[
-        {"context": tokens.chat_ids(list(o.request.prompt_ids)), "served": o.served_ids()}
+        {"context": vocab.chat_ids(o.request.prompt_ids), "served": o.served_ids()}
         for o in probes
     ])
     stats_end = server.get("/stats")
@@ -339,7 +340,8 @@ def measure(args, cell: dict, server: Server, platform: str) -> int:
     else:
         reduced = reduce_trace(server, cell, trace, args.keep_events)
         facts = {
-            "cell": cell["name"], "config": config, "device": health,
+            "cell": cell["name"], "config": config, "architecture": cell["architecture"],
+            "device": health,
             "outcomes": load.outcomes, "late_s": e2e["late_s"],
             "requests": {r["request_id"]: r for r in log},
             "stats_before": stats_before, "stats_after": stats_after,

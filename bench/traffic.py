@@ -21,7 +21,8 @@ The arrival processes are copied from ``cake_tpu/loadgen/arrivals.py``, the
 length distributions from ``cake_tpu/loadgen/workload.py``. Its prompts
 (``"cake " * n``) are not: each would be a prefix of every longer one, so
 with the prefix cache on the cache would serve them all. Here every request
-has its own seeded words, unless the mix declares ``sharing``. Stdlib only.
+has its own seeded words, unless the mix declares ``sharing``. ``vocab`` is the
+cell's ``tokens.Vocabulary``: it draws the ordinary words. Stdlib only.
 """
 
 from __future__ import annotations
@@ -33,7 +34,7 @@ import random
 from collections.abc import Iterator
 from statistics import NormalDist
 
-from bench.tokens import FIRST_WORD_ID
+from bench.tokens import Vocabulary
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,10 +104,6 @@ def arrival_offsets(spec: dict, seconds: float, rng: random.Random) -> list[floa
     return out
 
 
-def _words(rng: random.Random, n: int, vocab: int) -> list[int]:
-    return [rng.randrange(FIRST_WORD_ID, vocab) for _ in range(n)]
-
-
 def _lengths_in_order(mix: dict, n: int, order: random.Random) -> tuple[list[int], list[int]]:
     prompts = length_set(mix["prompt_tokens"], n)
     outputs = length_set(mix["output_tokens"], n)
@@ -115,23 +112,23 @@ def _lengths_in_order(mix: dict, n: int, order: random.Random) -> tuple[list[int
     return prompts, outputs
 
 
-def _request_maker(mix: dict, seed: int, vocab: int):
+def _request_maker(mix: dict, seed: int, vocab: Vocabulary):
     """-> make(index, due_s, prompt_tokens, max_tokens), words from ``seed``."""
     rng = random.Random(seed)
     share = mix.get("sharing") or {}
     prefixes = [
-        _words(rng, share["prefix_tokens"], vocab) for _ in range(share.get("groups", 0))
+        vocab.draw(rng, share["prefix_tokens"]) for _ in range(share.get("groups", 0))
     ]
 
     def make(index: int, due_s: float, n_prompt: int, n_new: int) -> Request:
         head = rng.choice(prefixes) if prefixes else []
-        ids = head + _words(rng, max(1, n_prompt - len(head)), vocab)
+        ids = head + vocab.draw(rng, max(1, n_prompt - len(head)))
         return Request(index, due_s, tuple(ids), n_new)
 
     return make
 
 
-def open_requests(mix: dict, seed: int, seconds: float, vocab: int) -> list[Request]:
+def open_requests(mix: dict, seed: int, seconds: float, vocab: Vocabulary) -> list[Request]:
     """An open loop's requests: one per arrival, due ``due_s`` after the
     window's start whatever happened to the ones before."""
     order = random.Random(mix["order_seed"])
@@ -141,7 +138,7 @@ def open_requests(mix: dict, seed: int, seconds: float, vocab: int) -> list[Requ
     return [make(i, due[i], prompts[i], outputs[i]) for i in range(len(due))]
 
 
-def closed_requests(mix: dict, seed: int, vocab: int) -> Iterator[Request]:
+def closed_requests(mix: dict, seed: int, vocab: Vocabulary) -> Iterator[Request]:
     """A closed loop's requests, without end: the callers take them in
     order. The mix's ``pool`` lengths come round again; the words never do,
     so a request is never the prefix cache's repeat of an earlier one."""
@@ -152,7 +149,7 @@ def closed_requests(mix: dict, seed: int, vocab: int) -> Iterator[Request]:
         yield make(k, 0.0, prompts[k % n], outputs[k % n])
 
 
-def warmup_requests(mix: dict, vocab: int) -> list[Request]:
+def warmup_requests(mix: dict, vocab: Vocabulary) -> list[Request]:
     """``warmup.alone_points`` requests whose prompts are that many quantiles
     of the mix's lengths, each with the mix's longest answer, because the
     program sizes an epoch's attention by prompt plus answer. Sent one at a
@@ -164,13 +161,13 @@ def warmup_requests(mix: dict, vocab: int) -> list[Request]:
     rng = random.Random(mix["order_seed"])
     longest = max(length_set(mix["output_tokens"], n))
     return [
-        Request(i, 0.0, tuple(_words(rng, m, vocab)), longest)
+        Request(i, 0.0, tuple(vocab.draw(rng, m)), longest)
         for i, m in enumerate(length_set(mix["prompt_tokens"], n))
     ]
 
 
-def probe_requests(lengths: list[int], n_new: int, seed: int, vocab: int) -> list[Request]:
+def probe_requests(lengths: list[int], n_new: int, seed: int, vocab: Vocabulary) -> list[Request]:
     """The probes the reference judges: fixed lengths, words from the seed."""
     rng = random.Random(seed + 0x9E3779B9)
-    return [Request(i, 0.0, tuple(_words(rng, m, vocab)), n_new)
+    return [Request(i, 0.0, tuple(vocab.draw(rng, m)), n_new)
             for i, m in enumerate(lengths)]

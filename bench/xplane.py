@@ -83,23 +83,31 @@ def gaps(intervals: list[tuple[float, float]], lo: float, hi: float) -> list[tup
     return sorted(out, key=lambda g: g[0] - g[1])
 
 
-def self_times(events: list[Event]) -> dict[str, float]:
-    """Own seconds by name: each event's interval less its children's."""
-    total: dict[str, float] = {}
-    stack: list[list] = []  # [name, end, own]
+def own_events(events: list[Event]) -> list[tuple]:
+    """(name, start_s, end_s, own_s) of each event: its interval less its
+    children's."""
+    out: list[tuple] = []
+    stack: list[list] = []  # [name, start, end, own]
 
     def close() -> None:
-        name, _, own = stack.pop()
-        total[name] = total.get(name, 0.0) + own
+        out.append(tuple(stack.pop()))
 
     for name, a, b in sorted(events, key=lambda e: (e[1], -e[2])):
-        while stack and stack[-1][1] <= a:
+        while stack and stack[-1][2] <= a:
             close()
         if stack:
-            stack[-1][2] -= min(b, stack[-1][1]) - a
-        stack.append([name, b, b - a])
+            stack[-1][3] -= min(b, stack[-1][2]) - a
+        stack.append([name, a, b, b - a])
     while stack:
         close()
+    return out
+
+
+def self_times(events: list[Event]) -> dict[str, float]:
+    """Own seconds by name."""
+    total: dict[str, float] = {}
+    for name, _, _, own in own_events(events):
+        total[name] = total.get(name, 0.0) + own
     return total
 
 
@@ -115,27 +123,36 @@ def window(planes: dict) -> tuple[float, float]:
     return (min(a for a, _ in spans), max(b for _, b in spans))
 
 
+def _whole_runs(lines: dict, module: str | None) -> tuple[list[Event], list[Event]]:
+    """The first chip's operations by start, and the runs of the programs
+    whose module's name matches ``module`` (every program's without it). A
+    run that touches the first or the last operation of the trace was cut by
+    the window's edge and is left out."""
+    ops = sorted(lines.get(OPS, ()), key=lambda e: e[1])
+    edge_lo, edge_hi = (ops[0][1], max(e[2] for e in ops)) if ops else (0.0, 0.0)
+    wanted = re.compile(module) if module else None
+    runs = [
+        (name, a, b) for name, a, b in lines.get(MODULES, ())
+        if (wanted is None or wanted.search(name)) and a > edge_lo and b < edge_hi
+    ]
+    return ops, runs
+
+
 def programs(planes: dict, pattern: dict) -> list[float]:
     """Device seconds of each run of the programs a pattern picks on the
     first chip: ``module`` is a regex on the module's name; ``has_op`` and
     ``lacks_op`` are regexes of which some operation inside its interval
     must, or none may, match (two programs jitted from functions of one
-    name differ only by what is inside). A run that touches the first or the
-    last operation of the trace was cut by the window's edge and is left out."""
+    name differ only by what is inside). Cut runs are left out."""
     chips = device_planes(planes)
     if not chips:
         return []
-    lines = planes[chips[0]]
-    ops = sorted(lines.get(OPS, ()), key=lambda e: e[1])
+    ops, runs = _whole_runs(planes[chips[0]], pattern["module"])
     starts = [e[1] for e in ops]
-    module = re.compile(pattern["module"])
     has = re.compile(pattern["has_op"]) if pattern.get("has_op") else None
     lacks = re.compile(pattern["lacks_op"]) if pattern.get("lacks_op") else None
     out = []
-    edge_lo, edge_hi = (ops[0][1], max(e[2] for e in ops)) if ops else (0.0, 0.0)
-    for name, a, b in lines.get(MODULES, ()):
-        if not module.search(name) or a <= edge_lo or b >= edge_hi:
-            continue
+    for _, a, b in runs:
         inside = ops[bisect.bisect_left(starts, a):bisect.bisect_left(starts, b)]
         if has and not any(has.search(n) for n, _, _ in inside):
             continue
@@ -143,6 +160,29 @@ def programs(planes: dict, pattern: dict) -> list[float]:
             continue
         out.append(b - a)
     return out
+
+
+def op_times(planes: dict, pattern: dict) -> dict:
+    """One named kernel's device time: ``{"seconds", "count"}`` of the
+    operations on the first chip whose name matches the regex ``op``, own
+    time (an operation's interval less its children's), inside the whole
+    runs of the programs ``module`` picks (of every program without it).
+    Operations of a run the window's edge cut are left out, as ``programs``
+    leaves the run out, so seconds over count is the time of a call."""
+    chips = device_planes(planes)
+    if not chips:
+        return {"seconds": 0.0, "count": 0}
+    ops, runs = _whole_runs(planes[chips[0]], pattern.get("module"))
+    runs.sort(key=lambda r: r[1])
+    starts = [a for _, a, _ in runs]
+    wanted = re.compile(pattern["op"])
+    seconds, count = 0.0, 0
+    for name, a, b, own in own_events(ops):
+        k = bisect.bisect_right(starts, a) - 1
+        if k >= 0 and b <= runs[k][2] and wanted.search(name):
+            seconds += own
+            count += 1
+    return {"seconds": seconds, "count": count}
 
 
 def host_spans(planes: dict) -> list[Event]:

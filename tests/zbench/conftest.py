@@ -1,9 +1,17 @@
 """A temporary copy of the benchmark with a tiny configuration, a mix and a
 cell added as a later PR would add them: new files and new entries, no edit
-of a file that is there. The rehearsals run ``bench.run`` from that copy."""
+of a file that is there. The rehearsals run ``bench.run`` from that copy.
+
+That holds for a new architecture too: ``TINY_MOE_MODEL`` is of a
+``model_type`` the committed benchmark has no file for, and
+``add_architecture`` copies its file (``tests/zbench/architectures``) into
+the copy's ``bench/architectures/``, where the configuration's
+``model_type`` finds it. ``bench/architectures/__init__.py`` says what such
+a file gives."""
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import shutil
@@ -26,9 +34,27 @@ TINY_MODEL = {
 }
 
 
-def tiny_config(chips: int, flags: list[str]) -> dict:
+# Sparse experts with a shared one behind a gate, q/k/v biases, ChatML, and
+# special ids that are not the lowest of the vocabulary. Weights of 0.1 and
+# not 0.02: at this width an MLP of 0.02 adds little to the residual, and a
+# reference with a faulty expert block would change few of the largest logits.
+TINY_MOE_MODEL = {
+    "architectures": ["Qwen2MoeForCausalLM"], "model_type": "qwen2_moe",
+    "bos_token_id": 480, "eos_token_id": 482, "hidden_act": "silu",
+    "hidden_size": 128, "intermediate_size": 256, "initializer_range": 0.1,
+    "num_attention_heads": 8, "num_key_value_heads": 4,
+    "num_hidden_layers": 2, "max_position_embeddings": 512,
+    "num_experts": 8, "num_experts_per_tok": 2, "norm_topk_prob": False,
+    "moe_intermediate_size": 64, "shared_expert_intermediate_size": 96,
+    "decoder_sparse_step": 1, "mlp_only_layers": [], "use_sliding_window": False,
+    "rms_norm_eps": 1e-06, "rope_theta": 1000000.0,
+    "tie_word_embeddings": False, "vocab_size": 512,
+}
+
+
+def tiny_config(chips: int, flags: list[str], model: dict = TINY_MODEL) -> dict:
     return {
-        **TINY_MODEL, "source": "a test's own", "reduced": [], "assumed": [],
+        **model, "source": "a test's own", "reduced": [], "assumed": [],
         "deployment": {"chips": chips, "layout": "test"},
         "served_dtype": "bf16", "weights_seed": 3, "server_flags": flags,
         "judge": {"tolerance": 0.25, "rehearsal_tolerance": 0.005, "why": "test"},
@@ -78,6 +104,25 @@ def add_cell(root: Path, name: str, config_name: str, config: dict,
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
 
+def add_architecture(root: Path, model_type: str) -> None:
+    """What a later PR does for a ``model_type`` the benchmark has not met."""
+    shutil.copy(REPO / f"tests/zbench/architectures/{model_type}.py",
+                root / f"bench/architectures/{model_type}.py")
+
+
+def file_hashes(root: Path) -> dict[str, str]:
+    return {str(p.relative_to(root)): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in sorted(root.rglob("*")) if p.is_file() and "__pycache__" not in p.parts}
+
+
+def vocabulary(config: dict, root: Path = REPO):
+    """The words and the template of ``config`` as a cell of ``root`` has them."""
+    from bench.manifest import architecture
+    from bench.tokens import Vocabulary
+
+    return Vocabulary(architecture(root, config), config)
+
+
 def copy_benchmark(dst: Path) -> Path:
     shutil.copy(REPO / "BENCHMARK.json", dst / "BENCHMARK.json")
     shutil.copytree(REPO / "bench", dst / "bench",
@@ -92,6 +137,11 @@ def run_bench(root: Path, *args: str, timeout: float = 300) -> subprocess.Comple
         [sys.executable, "-m", "bench.run", *args], cwd=root, env=env,
         capture_output=True, text=True, timeout=timeout,
     )
+
+
+def last_json(stdout: str) -> dict:
+    """The result line of a run."""
+    return json.loads(stdout.strip().splitlines()[-1])
 
 
 @pytest.fixture(scope="module")

@@ -11,8 +11,12 @@ import pytest
 
 from bench import stats
 from bench.client import Load
-from bench.tokens import TEMPLATE_TOKENS, word
 from bench.traffic import Request
+
+from conftest import TINY_MODEL, vocabulary
+
+VOCAB = vocabulary(TINY_MODEL)
+word, TEMPLATE_TOKENS = VOCAB.word, len(VOCAB.chat_ids([]))
 
 
 class Scripted(BaseHTTPRequestHandler):
@@ -77,7 +81,7 @@ def req(i, due, first_word, n_new=4):
 
 def test_open_loop_times_from_due_and_keeps_every_gap(base):
     url, _ = base
-    load = Load(url)
+    load = Load(url, VOCAB)
     t0 = time.perf_counter()
     load.run_open([req(0, 0.0, 20), req(1, 0.2, 21), req(2, 0.25, 13)], t0)
     assert load.finish(drain_s=5.0) < 5.0
@@ -97,7 +101,7 @@ def test_open_loop_times_from_due_and_keeps_every_gap(base):
 
 def test_what_is_not_finished_by_the_drains_end_is_cut_and_failed(base):
     url, srv = base
-    load = Load(url)
+    load = Load(url, VOCAB)
     t0 = time.perf_counter()
     load.run_open([req(0, 0.0, 14), req(1, 0.0, 22)], t0)
     waited = load.finish(drain_s=1.0)
@@ -109,7 +113,7 @@ def test_what_is_not_finished_by_the_drains_end_is_cut_and_failed(base):
 
 def test_closed_loop_sends_the_next_when_the_last_ends(base):
     url, _ = base
-    load = Load(url)
+    load = Load(url, VOCAB)
     stream = (req(i, 0.0, 30 + i, n_new=2) for i in range(1000))
     t0 = time.perf_counter() + 0.3                # the loop leads in for 0.3 s
     load.run_closed(stream, clients=2, t_end=t0 + 0.9, min_send_gap_s=0.05)
@@ -129,7 +133,7 @@ def test_closed_loop_sends_the_next_when_the_last_ends(base):
 
 def test_warm_up_hangs_up_after_the_tokens_it_wants(base):
     url, srv = base
-    out, = Load(url).run_each([req(0, 0.0, 40, n_new=50)], cut_after=3)
+    out, = Load(url, VOCAB).run_each([req(0, 0.0, 40, n_new=50)], cut_after=3)
     assert len(out.arrivals) == 3 and out.finish == "cut" and out.status == 200
     deadline = time.time() + 5
     while not srv.hung_up and time.time() < deadline:

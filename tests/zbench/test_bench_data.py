@@ -3,6 +3,7 @@ bytes, readers, trace reduction. No server, no JAX."""
 
 from __future__ import annotations
 
+import hashlib
 import json
 import random
 import re
@@ -11,16 +12,19 @@ import pytest
 
 from bench import costs, readers, stats, traffic, xplane
 from bench.client import Outcome
-from bench.manifest import BENCH_KEYS, Manifest, ManifestError, model_config
-from bench.tokens import chat_ids, ids_from_text, prompt_text
+from bench.manifest import BENCH_KEYS, Manifest, ManifestError, architecture, model_config
 
-from conftest import (CLOSED_LOOP, ONE_CHIP_FLAGS, OPEN_LOOP, REPO, add_cell, copy_benchmark,
-                      tiny_config, tiny_mix)
+from conftest import (CLOSED_LOOP, ONE_CHIP_FLAGS, OPEN_LOOP, REPO, TINY_MODEL, TINY_MOE_MODEL,
+                      add_architecture, add_cell, copy_benchmark, tiny_config, tiny_mix,
+                      vocabulary)
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
 MIXES = sorted(p.stem for p in (REPO / "bench/traffic").glob("*.json"))
+COMMITTED = json.loads((REPO / "bench/configs/mistral-7b-v0.1-d16.json").read_text())
+VOCAB = vocabulary(COMMITTED)  # 32000 words, the committed configuration's template
+TINY_VOCAB = vocabulary(TINY_MODEL)
 
 
 def test_manifest_cross_references():
@@ -73,6 +77,21 @@ def test_added_files_are_enough_for_the_manifest(tmp_path):
     assert Manifest(root).cell("tiny-open")["config"]["hidden_size"] == 128
 
 
+def test_an_unknown_model_type_names_the_file_to_add(tmp_path):
+    root = copy_benchmark(tmp_path)
+    config = tiny_config(1, ONE_CHIP_FLAGS, TINY_MOE_MODEL)
+    add_cell(root, "tiny-moe", "tiny-moe", config, "tiny-open", tiny_mix(OPEN_LOOP))
+    with pytest.raises(ManifestError, match=r"add bench/architectures/qwen2_moe\.py"):
+        Manifest(root).cell("tiny-moe")
+    with pytest.raises(ManifestError, match="model_type 'qwen2_moe'"):
+        Manifest(root).check()
+    Manifest(root).cell("mistral7b-chat-closed")  # the other cells still load
+    add_architecture(root, "qwen2_moe")  # a new file, and the cell loads
+    Manifest(root).check()
+    cell = Manifest(root).cell("tiny-moe")
+    assert cell["architecture"].__file__ == str(root / "bench/architectures/qwen2_moe.py")
+
+
 # ------------------------------------------------------------------ generator
 
 
@@ -84,8 +103,8 @@ def _mix(name: str) -> dict:
 
 def _requests(mix: dict, seed: int) -> list:
     if mix["loop"] == "open":
-        return traffic.open_requests(mix, seed, 30.0, 32000)
-    stream = traffic.closed_requests(mix, seed, 32000)
+        return traffic.open_requests(mix, seed, 30.0, VOCAB)
+    stream = traffic.closed_requests(mix, seed, VOCAB)
     return [next(stream) for _ in range(2 * mix["pool"])]
 
 
@@ -119,12 +138,12 @@ def test_lengths_keep_the_tail_and_sharing_shares():
     assert drawn == sorted(drawn) and 290 <= drawn[250] <= 310
     mix = tiny_mix(CLOSED_LOOP)
     mix["sharing"] = {"prefix_tokens": 20, "groups": 2}
-    stream = traffic.closed_requests(mix, 1, 512)
+    stream = traffic.closed_requests(mix, 1, TINY_VOCAB)
     reqs = [next(stream) for _ in range(12)]
     assert len({r.prompt_ids[:20] for r in reqs}) == 2
     assert len({r.prompt_ids for r in reqs}) == len(reqs)
-    warm = traffic.warmup_requests(tiny_mix(OPEN_LOOP), 512)
-    assert len(warm) == 3 and traffic.warmup_requests(mix, 512) == []
+    warm = traffic.warmup_requests(tiny_mix(OPEN_LOOP), TINY_VOCAB)
+    assert len(warm) == 3 and traffic.warmup_requests(mix, TINY_VOCAB) == []
 
 
 def test_bursty_train_is_the_mixes_own():
@@ -139,10 +158,65 @@ def test_bursty_train_is_the_mixes_own():
 
 def test_words_round_trip():
     ids = [5, 31999, 77]
-    assert ids_from_text(prompt_text(ids)) == ids
-    assert chat_ids(ids) == [1, 3, 5, 31999, 77, 4]
+    assert VOCAB.ids_from_text(VOCAB.prompt_text(ids)) == ids
+    assert VOCAB.chat_ids(ids) == [1, 3, 5, 31999, 77, 4]
     with pytest.raises(ValueError):
-        ids_from_text("w5 cake")
+        VOCAB.ids_from_text("w5 cake")
+
+
+def test_the_seam_gives_what_the_code_before_it_gave():
+    """Values of commit 5e06de7, where the template, the ids traffic never
+    draws (``FIRST_WORD_ID`` 5) and the bytes were written into bench/*.py."""
+    arch = architecture(REPO, COMMITTED)
+    assert arch.special_words(COMMITTED) == {
+        0: "<unk>", 1: "<s>", 2: "</s>", 3: "[INST]", 4: "[/INST]"}
+    assert VOCAB.special_ids == [0, 1, 2, 3, 4] and len(VOCAB.chat_ids([])) == 3
+    assert VOCAB.chat_text("w7 w9") == "<s>[INST] w7 w9 [/INST]"
+    assert [VOCAB.word(i) for i in (0, 2, 4, 5)] == ["<unk>", "</s>", "[/INST]", "w5"]
+    assert arch.decode_weight_bytes(COMMITTED, "bf16") == 7241736192
+    assert costs.decode_weight_bytes(COMMITTED, "bf16") == 7241736192
+    # the same words for the same seed: randrange(5, vocab) then, the
+    # randrange(vocab - 5)-th ordinary id now
+    mix = _mix("chat-closed-16")
+    stream = traffic.closed_requests(mix, 2**31 + 11, VOCAB)
+    reqs = [next(stream) for _ in range(64)]
+    assert reqs[0].prompt_ids[:5] == (19379, 21195, 7272, 15655, 26698)
+    assert min(min(r.prompt_ids) for r in reqs) == 5
+    listed = [[r.index, r.due_s, list(r.prompt_ids), r.max_tokens] for r in reqs]
+    assert hashlib.sha256(json.dumps(listed).encode()).hexdigest() == (
+        "6e60db48ecd622f0f345f69bc705e4afec4c716ce6c9dce4197e81bdb4676fc2")
+    probes = traffic.probe_requests([12, 60], 32, 77, TINY_VOCAB)
+    assert probes[0].prompt_ids[:4] == (57, 238, 499, 196)
+
+
+def test_special_ids_anywhere_are_never_drawn(tmp_path):
+    """A template's words at the configuration's own ids (bos 480, eos 482),
+    not below a first word: traffic draws every other id and none of them."""
+    root = copy_benchmark(tmp_path)
+    add_architecture(root, "qwen2_moe")
+    vocab = vocabulary(TINY_MOE_MODEL, root)
+    assert vocab.specials[480] == "<|endoftext|>" and vocab.specials[482] == "<|im_end|>"
+    assert vocab.special_ids == [480, 482, 483, 484, 485, 486, 487]
+
+    class Counting:  # every k once, in order
+        def __init__(self):
+            self.k = -1
+
+        def randrange(self, n):
+            assert n == 512 - 7
+            self.k += 1
+            return self.k
+
+    assert vocab.draw(Counting(), 505) == [i for i in range(512) if i not in vocab.specials]
+    ids = vocab.chat_ids([7, 481])
+    assert ids == [483, 484, 487, 482, 483, 485, 7, 481, 482, 483, 486]
+    assert vocab.ids_from_text("w7 <|im_end|> w481") == [7, 482, 481]
+    vocab.write_tokenizer(tmp_path / "tokenizer.json")  # raises unless it encodes chat_ids
+    from tokenizers import Tokenizer
+
+    tok = Tokenizer.from_file(str(tmp_path / "tokenizer.json"))
+    assert tok.encode(vocab.chat_text("w7 w481"), add_special_tokens=False).ids == ids
+    assert tok.decode([7, 481], skip_special_tokens=False) == "w7 w481"
 
 
 # ---------------------------------------------------------------- percentiles
@@ -165,7 +239,7 @@ def _outcome(i, due, arrivals, **kw):
     usage = {"prompt_tokens": 6, "completion_tokens": len(arrivals)}
     base = dict(sent=due + 0.001, status=200, finish="length", usage=usage, done=True,
                 ended=arrivals[-1] + 0.01)
-    return Outcome(req, due, arrivals=arrivals, **{**base, **kw})
+    return Outcome(req, due, VOCAB, arrivals=arrivals, **{**base, **kw})
 
 
 def test_end_to_end_times_from_due_and_counts_failures():
@@ -280,6 +354,36 @@ def test_reduction_of_the_recorded_trace(recorded):
     runs = xplane.programs(recorded, decode["pattern"])
     assert runs == [pytest.approx(0.26232, abs=1e-4)]
     assert xplane.programs(recorded, {"module": "^jit_no_such"}) == []
+
+
+def test_one_named_kernels_time_from_the_recorded_trace(recorded, tmp_path):
+    """130 ``paged_decode_attention.8`` calls are in the trace; 2 lie in the
+    decode program the window's edge cut, 128 (16 layers x 8 steps) in the
+    whole one."""
+    ops = recorded["/device:TPU:0"][xplane.OPS]
+    assert sum("decode_attention.8" in name for name, _, _ in ops) == 130
+    got = xplane.op_times(recorded, {"op": r"decode_attention\.8"})
+    assert got["count"] == 128
+    assert got["seconds"] == pytest.approx(2.775e-3, rel=1e-3)
+    assert got == xplane.op_times(recorded, {"op": "decode_attention", "module": "^jit_run"})
+    # a kernel has no children: its own time is its events' time
+    whole = [(a, b) for name, a, b in ops if "decode_attention" in name and a > 0.0686]
+    assert got["seconds"] == pytest.approx(sum(b - a for a, b in whole))
+    # a loop's own time is what its children leave of it
+    loops = xplane.op_times(recorded, {"op": r"^%while"})
+    assert 0 < loops["seconds"] < 0.01 * xplane.programs(recorded, {"module": "^jit_run"})[0]
+    assert xplane.op_times(recorded, {"op": "decode_attention", "module": "^jit_less"}) == {
+        "seconds": 0.0, "count": 0}
+    assert xplane.op_times({}, {"op": "x"}) == {"seconds": 0.0, "count": 0}
+    # as data: a declarative reader a later PR adds, mean microseconds a call
+    root = copy_benchmark(tmp_path)
+    (root / "bench/layer_metrics/decode_attention_us.json").write_text(json.dumps(
+        {"kind": "op_mean_us", "pattern": {"op": "decode_attention"}}))
+    facts = {"trace": {"ops": {"decode_attention_us": got}}}
+    assert readers.read_metric(root, "decode_attention_us", facts) == pytest.approx(21.68, abs=0.01)
+    assert readers.read_metric(root, "decode_attention_us", {"trace": None}) is None
+    none = {"trace": {"ops": {"decode_attention_us": {"seconds": 0.0, "count": 0}}}}
+    assert readers.read_metric(root, "decode_attention_us", none) is None
 
 
 def test_device_readers_on_the_recorded_trace(recorded):

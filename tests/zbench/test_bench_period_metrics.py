@@ -6,16 +6,14 @@ closed-loop rehearsal that prints them from a real server on the CPU."""
 from __future__ import annotations
 
 import copy
-import json
 import math
-from pathlib import Path
 
 import pytest
 
 from bench import period_stats, readers
 from bench.manifest import Manifest
-from conftest import (CLOSED_LOOP, ONE_CHIP_FLAGS, REPO, add_cell, run_bench, tiny_config,
-                      tiny_mix)
+from conftest import (CLOSED_LOOP, ONE_CHIP_FLAGS, REPO, add_cell, last_json, run_bench,
+                      tiny_config, tiny_mix)
 
 COUNTERS = ["period_p90_ms", "host_ms_per_period", "join_ms_per_join",
             "join_period_share_pct", "lanes_live_mean", "lanes_idle_queued_pct",
@@ -136,31 +134,27 @@ def test_manifest_holds_the_new_entries():
     cell = manifest.cell("mistral7b-chat-closed")
     by_name = {m["name"]: m for m in cell["per_layer"]}
     for name in COUNTERS + ["join_prefill_dev_ms"]:
-        assert by_name[name]["workloads"] == ["mistral7b-chat-closed"]
+        # every cell's: they read what every --api-batch server has
+        assert "workloads" not in by_name[name]
         assert (REPO / f"bench/layer_metrics/{name}.json").exists()
     assert by_name["load_s"]["moves"] == "setup_s"
     assert {by_name[n]["moves"] for n in COUNTERS[:7]} == {"gap_p95_ms"}
 
 
 def test_traced_rehearsal_prints_the_counters(tiny_root):
-    """A tiny closed cell whose name the copy appends to the new entries'
-    ``workloads``: the served path on the CPU fills every counter the
-    readers need, and the phases close on the periods' seconds."""
+    """A tiny closed cell, added as files and entries: the period metrics
+    are every cell's, so also its own. The served path on the CPU fills
+    every counter the readers need, and the phases close on the periods'
+    seconds."""
     add_cell(tiny_root, "tiny-period", "tiny-p", tiny_config(1, ONE_CHIP_FLAGS),
              "tiny-period", tiny_mix(CLOSED_LOOP))
-    bench_file = Path(tiny_root) / "BENCHMARK.json"
-    bench = json.loads(bench_file.read_text())
-    for m in bench["per_layer"]:
-        if m["name"] in COUNTERS:
-            m["workloads"].append("tiny-period")
-    bench_file.write_text(json.dumps(bench))
     (tiny_root / ".bench_work/cold_pass").mkdir(parents=True, exist_ok=True)
     (tiny_root / ".bench_work/cold_pass/tiny-period.3").touch()
 
     r = run_bench(tiny_root, "--workload", "tiny-period", "--seed", "77", "--seconds", "3",
                   "--trace", "1", "--rehearse-cpu")
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
+    out = last_json(r.stdout)
     metrics = {k: v["value"] for k, v in out["metrics"].items()}
     assert set(COUNTERS) <= set(metrics)
     assert "join_prefill_dev_ms" not in metrics  # no device trace on the CPU

@@ -1,5 +1,5 @@
 """``bench.run`` end to end on the CPU, from a temporary copy to which a
-configuration, two mixes, two cells and two per-layer metrics were added as
+configuration, two mixes, two cells and three per-layer metrics were added as
 files and entries only. Also: what it does with no TPU, and with no program."""
 
 from __future__ import annotations
@@ -9,7 +9,7 @@ import json
 import pytest
 
 from conftest import (CLOSED_LOOP, ONE_CHIP_FLAGS, OPEN_LOOP, add_cell, copy_benchmark,
-                      run_bench, tiny_config, tiny_mix)
+                      last_json, run_bench, tiny_config, tiny_mix)
 
 # A reader of a later PR's own, for its open cell alone.
 GEN_LATE = '''\
@@ -30,12 +30,17 @@ def root(tiny_root):
     metrics = tiny_root / "bench/layer_metrics"
     (metrics / "joins_in_window.json").write_text(
         json.dumps({"kind": "stats_delta", "path": "engine.joins"}))
+    # one named kernel's device time, as data; a CPU has no device trace to give it
+    (metrics / "decode_attention_us.json").write_text(json.dumps(
+        {"kind": "op_mean_us", "pattern": {"op": "decode_attention", "module": "^jit_"}}))
     (metrics / "gen_late_p95_ms.json").write_text(json.dumps({"kind": "python"}))
     (metrics / "gen_late_p95_ms.py").write_text(GEN_LATE)
     bench = json.loads((tiny_root / "BENCHMARK.json").read_text())
     bench["per_layer"] += [
         {"name": "joins_in_window", "unit": "count", "better": "higher",
          "source": "program_counter", "layer": "engine", "moves": "gap_p95_ms"},
+        {"name": "decode_attention_us", "unit": "us", "better": "lower",
+         "source": "device_trace", "layer": "kernels", "moves": "gap_p95_ms"},
         {"name": "gen_late_p95_ms", "unit": "ms", "better": "lower",
          "source": "host_clock", "layer": "load generator", "moves": "gap_p95_ms",
          "workloads": ["tiny-open"]},
@@ -45,10 +50,6 @@ def root(tiny_root):
     (tiny_root / ".bench_work/cold_pass").mkdir(parents=True)
     (tiny_root / ".bench_work/cold_pass/tiny-open.2").touch()
     return tiny_root
-
-
-def last_json(stdout: str) -> dict:
-    return json.loads(stdout.strip().splitlines()[-1])
 
 
 # The committed cell's loop with and without the trace; an open loop once (each
@@ -81,10 +82,15 @@ def test_rehearsal_through_the_served_path(root, loop, trace):
         assert names == {"gap_p95_ms", "setup_s"}
         assert all(m["value"] > 0 for m in out["metrics"].values())
     else:
-        # host metrics and counts, the added ones among them; nothing of a device
+        # host metrics and counts, the added ones and the engine's own account of
+        # its periods among them (every cell's); nothing of a device
         want = {"tpot_p50_ms", "batch_occupancy_mean", "compiles_in_window",
-                "joins_in_window"} | ({"gen_late_p95_ms"} if loop == "open" else set())
-        assert names == want
+                "joins_in_window", "period_p90_ms", "host_ms_per_period",
+                "join_ms_per_join", "join_period_share_pct", "lanes_live_mean",
+                "lanes_idle_queued_pct", "compile_stall_s_in_window", "load_s",
+                } | ({"gen_late_p95_ms"} if loop == "open" else set())
+        # a window in which no request happened to join has no join to time
+        assert want - {"join_ms_per_join"} <= names <= want
         assert "busy_s" not in out["device"] and "breakdown" not in out
 
 
