@@ -1692,6 +1692,32 @@ def main(argv: list[str] | None = None) -> int:
     config = LlamaConfig.from_model_dir(
         args.model, attention_impl=args.attention_impl
     )
+    if config.has_state_layers:
+        # THE one capability check for models with state layers, before any
+        # weight is read and any backend chosen (models/llama/hybrid.py).
+        from cake_tpu.models.llama.hybrid import (
+            UnsupportedWithStateLayers,
+            refuse_unsupported,
+        )
+
+        try:
+            refuse_unsupported(config, {
+                "the single-stream generator (no --api with --api-batch > 1)":
+                    not (args.api and args.api_batch > 1),
+                "--kv-mode dense": args.kv_mode != "paged",
+                "--prefix-cache on": args.prefix_cache == "on",
+                "--draft-model": args.draft_model is not None,
+                "--speculative-k": bool(args.speculative_k),
+                "--tp": args.tp > 1,
+                "--sp": args.sp > 1,
+                "--topology (pipeline and distributed backends)":
+                    topology is not None or args.backend is not None,
+                "--distributed": bool(args.distributed),
+                "--quantize": bool(args.quantize),
+            })
+        except UnsupportedWithStateLayers as e:
+            print(f"cake-tpu: {e}", file=sys.stderr)
+            return 2
     if args.fusion != "none":
         import dataclasses
 
@@ -1768,7 +1794,9 @@ def _run_leader(
     from cake_tpu.models.llama.tokenizer import load_tokenizer
 
     if args.prefix_cache == "auto":
-        prefix_cache = bool(args.api)
+        # On for --api, but for a model with state layers: a reused prefix
+        # restores K and V only ("on" is refused outright, cli.main).
+        prefix_cache = bool(args.api) and not config.has_state_layers
     else:
         prefix_cache = args.prefix_cache == "on"
     # With a batch engine attached, the API path bypasses the generator for
@@ -1885,6 +1913,7 @@ def _run_leader(
                 )
             engine_prefix_cache = (
                 args.kv_mode == "paged" and args.prefix_cache != "off"
+                and not config.has_state_layers  # "auto" only: see above
             )
             from cake_tpu.runtime.serving import ServeConfig
 
@@ -1945,6 +1974,15 @@ def _run_leader(
             startup["engine_init_s"] = round(
                 time.perf_counter() - t_engine, 3
             )
+            from cake_tpu.utils.device import cpu_requested
+
+            if getattr(engine.backend, "hybrid", False) and not cpu_requested():
+                # A hybrid model's programs are a closed set: run each once
+                # now, so that none is traced while streams are live
+                # (PagedLocalBackend.warm_programs; ``GET /stats`` startup).
+                startup["warm"] = engine.backend.warm_programs(
+                    args.api_batch, sampling, args.decode_chunk
+                )
             if args.speculative_k and not hasattr(
                 engine.backend, "verify_greedy"
             ):

@@ -402,6 +402,90 @@ def load_layer_params(
     return out
 
 
+# Hybrid stacks (models/llama/hybrid.py): HF names as transformers'
+# JambaForCausalLM writes them -> (key in the run's tree, how the tensor is
+# turned into the layout the model holds). "T" = [out, in] -> [in, out].
+_JAMBA_FFN = {
+    "w_gate": ("feed_forward.gate_proj.weight", "T"),
+    "w_up": ("feed_forward.up_proj.weight", "T"),
+    "w_down": ("feed_forward.down_proj.weight", "T"),
+    "ln_attn": ("input_layernorm.weight", None),
+    "ln_mlp": ("pre_ff_layernorm.weight", None),
+}
+_JAMBA_TEMPLATES = {
+    "attention": {
+        "wq": ("self_attn.q_proj.weight", "T"),
+        "wk": ("self_attn.k_proj.weight", "T"),
+        "wv": ("self_attn.v_proj.weight", "T"),
+        "wo": ("self_attn.o_proj.weight", "T"),
+        **_JAMBA_FFN,
+    },
+    "state": {
+        "in_proj": ("mamba.in_proj.weight", "T"),
+        # [d_inner, 1, d_conv] -> [d_conv, d_inner]
+        "conv_w": ("mamba.conv1d.weight", "conv"),
+        "conv_b": ("mamba.conv1d.bias", None),
+        "x_proj": ("mamba.x_proj.weight", "T"),
+        "dt_ln": ("mamba.dt_layernorm.weight", None),
+        "b_ln": ("mamba.b_layernorm.weight", None),
+        "c_ln": ("mamba.c_layernorm.weight", None),
+        "dt_proj": ("mamba.dt_proj.weight", "T"),
+        "dt_bias": ("mamba.dt_proj.bias", None),
+        # [d_inner, d_state] -> [d_state, d_inner]
+        "A_log": ("mamba.A_log", "T"),
+        "D": ("mamba.D", None),
+        "wo": ("mamba.out_proj.weight", "T"),
+        **_JAMBA_FFN,
+    },
+}
+_JAMBA_FINAL_NORM = "model.final_layernorm.weight"
+
+
+def _jamba_read(reader: SafetensorsReader, name: str, how, dtype):
+    if how == "conv":
+        return reader.jax(name, dtype)[:, 0, :].T
+    return reader.jax(name, dtype, transpose=how == "T")
+
+
+def load_hybrid_layers(
+    reader: SafetensorsReader, config: LlamaConfig, dtype
+) -> list[Params]:
+    """One stacked tree a run of layers of one kind, in the model's order
+    (``config.layer_runs``), from the per-kind HF names."""
+    return [
+        {
+            key: jnp.stack([
+                _jamba_read(reader, f"model.layers.{i}.{name}", how, dtype)
+                for i in config.layers_of(kind)[lo:hi]
+            ])
+            for key, (name, how) in _JAMBA_TEMPLATES[kind].items()
+        }
+        for kind, lo, hi in config.layer_runs
+    ]
+
+
+def hybrid_tensor_dict(
+    params: Params, config: LlamaConfig, dtype
+) -> dict[str, np.ndarray]:
+    """THE inverse of ``load_hybrid_layers`` (fixtures and round trips)."""
+    tensors = {
+        "model.embed_tokens.weight": np.asarray(params["embed"].astype(dtype)),
+        _JAMBA_FINAL_NORM: np.asarray(params["ln_f"].astype(dtype)),
+    }
+    if not config.tie_word_embeddings:
+        _emit_tensor(tensors, "lm_head.weight", params["lm_head"], True, dtype)
+    for run, (kind, lo, hi) in zip(params["layers"], config.layer_runs):
+        for key, (name, how) in _JAMBA_TEMPLATES[kind].items():
+            for k, i in enumerate(config.layers_of(kind)[lo:hi]):
+                a = np.asarray(run[key][k].astype(dtype))
+                if how == "conv":
+                    a = a.T[:, None, :]
+                elif how == "T":
+                    a = a.T
+                tensors[f"model.layers.{i}.{name}"] = a.copy()
+    return tensors
+
+
 def load_params(
     model_dir: str | Path,
     config: LlamaConfig,
@@ -421,16 +505,22 @@ def load_params(
     """
     t0 = time.perf_counter()
     reader = open_checkpoint(model_dir)
-    if layer_range is not None:
+    if config.has_state_layers:
+        from cake_tpu.models.llama.hybrid import refuse_unsupported
+
+        refuse_unsupported(
+            config, {"a worker's layer range (--topology)": layer_range is not None}
+        )
+        params = {
+            "embed": reader.jax("model.embed_tokens.weight", dtype),
+            "layers": load_hybrid_layers(reader, config, dtype),
+            "ln_f": reader.jax(_JAMBA_FINAL_NORM, dtype),
+        }
+    elif layer_range is not None:
         lo, hi = layer_range
         return {"layers": load_layer_params(reader, lo, hi, dtype, config)}
-    params: Params = {
-        "embed": reader.jax("model.embed_tokens.weight", dtype),
-        "layers": load_layer_params(
-            reader, 0, config.num_hidden_layers, dtype, config
-        ),
-        "ln_f": reader.jax("model.norm.weight", dtype),
-    }
+    else:
+        params = _load_llama_params(reader, config, dtype)
     if not config.tie_word_embeddings:
         params["lm_head"] = read_weight(reader, "lm_head.weight", dtype, True)
     if times is not None:
@@ -440,6 +530,16 @@ def load_params(
             time.perf_counter() - t0 - reader.read_s, 3
         )
     return params
+
+
+def _load_llama_params(reader, config: LlamaConfig, dtype) -> Params:
+    return {
+        "embed": reader.jax("model.embed_tokens.weight", dtype),
+        "layers": load_layer_params(
+            reader, 0, config.num_hidden_layers, dtype, config
+        ),
+        "ln_f": reader.jax("model.norm.weight", dtype),
+    }
 
 
 def hf_tensor_dict(
@@ -465,6 +565,8 @@ def hf_tensor_dict(
 
     load_layer_params reconstructs the exact QuantWeight/Quant4Weight leaves
     (bit-identical round trip, tests/test_quantized_checkpoint.py)."""
+    if config.has_state_layers:
+        return hybrid_tensor_dict(params, config, dtype)
     tensors = head_tensor_dict(params, config, dtype)
     tensors.update(
         layer_tensor_dict(

@@ -67,6 +67,13 @@ def split_model(
     model_dir = Path(model_dir)
     output_dir = Path(output_dir)
     topology = Topology.from_path(topology_path)
+    if (model_dir / "config.json").exists():
+        from cake_tpu.models.llama.config import LlamaConfig
+        from cake_tpu.models.llama.hybrid import refuse_unsupported
+
+        refuse_unsupported(
+            LlamaConfig.from_model_dir(model_dir), {"cake-split-model": True}
+        )
     reader = open_checkpoint(model_dir)
 
     bundles: list[Path] = []

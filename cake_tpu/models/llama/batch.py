@@ -331,6 +331,7 @@ def batched_blocks_forward(
     moe_dispatch: str = "auto",
     block_tables: jnp.ndarray | None = None,
     write_starts: jnp.ndarray | None = None,
+    layer_base: int = 0,
 ) -> tuple[jnp.ndarray, KVCache]:
     """THE pad-aware stacked-layer scan for left-padded batches.
 
@@ -386,6 +387,10 @@ def batched_blocks_forward(
         at slots below ``write_starts[b]`` DROP even where pages are mapped:
         a suffix prefill's window re-embeds prefix tokens whose KV already
         lives in forked shared pages, and must never scribble them.
+      layer_base: STATIC (PAGED only) — ``layers`` is one RUN of a stack
+        that other kinds of layer interleave (models/llama/hybrid.py): its
+        k-th layer reads and writes pool layer ``layer_base + k``, and the
+        pool may hold more layers than the run.
     """
     use_pallas = (
         allow_pallas and M.resolve_attention_impl(config.attention_impl) == "pallas"
@@ -416,7 +421,7 @@ def batched_blocks_forward(
         # pages).
         moe_valid = q_pos >= 0
         q_pos = jnp.maximum(q_pos, 0)
-    if decode:
+    if decode and config.use_rope:
         # Decode ropes q and its one new key at the same q_pos (k_pos only
         # feeds the XLA mask): gather the rope rows once per step, not once
         # per layer inside the scan (apply_rope's 3-D form). Prefill keeps
@@ -573,13 +578,15 @@ def batched_blocks_forward(
         x = x_new if valid is None else jnp.where(ok, x_new, x)
         return x, (k_c, v_c)
 
-    ok = jnp.ones((kv.k.shape[0],), bool) if valid is None else valid
     if paged:
-        li = jnp.arange(kv.n_layers, dtype=jnp.int32)
+        n_run = jax.tree.leaves(layers)[0].shape[0]
+        ok = jnp.ones((n_run,), bool) if valid is None else valid
+        li = layer_base + jnp.arange(n_run, dtype=jnp.int32)
         (x, k_out, v_out), _ = jax.lax.scan(
             paged_layer, (x, kv.k, kv.v), (layers, ok, li)
         )
         return x, PagedKVCache(k=k_out, v=v_out)
+    ok = jnp.ones((kv.k.shape[0],), bool) if valid is None else valid
     x, (k_out, v_out) = jax.lax.scan(layer, x, (layers, kv.k, kv.v, ok))
     return x, KVCache(k=k_out, v=v_out)
 

@@ -264,6 +264,23 @@ def encode_dialog_phi3(messages: list[Message]) -> str:
     return "".join(parts)
 
 
+def encode_dialog_jamba(messages: list[Message]) -> str:
+    """Jamba-1.5 family template (written from memory of AI21's published
+    chat template; the catalog row carries none):
+
+        <|startoftext|><|bom|><|system|> {sys}<|eom|><|bom|><|user|> {u}<|eom|><|bom|><|assistant|> 
+
+    Every message is one ``<|bom|><|role|> text<|eom|>`` frame; the prompt
+    ends with an open assistant frame.
+    """
+    parts = ["<|startoftext|>"]
+    parts += [
+        f"<|bom|><|{m.role.value}|> {m.content.strip()}<|eom|>" for m in messages
+    ]
+    parts.append("<|bom|><|assistant|> ")
+    return "".join(parts)
+
+
 # Template key -> dialog encoder. The generator picks by
 # config.dialog_template (the model family, or the --chat-template override);
 # the Llama-3 encoder is the reference-parity surface (history.rs), the
@@ -283,6 +300,7 @@ DIALOG_ENCODERS = {
     "gemma2": encode_dialog_gemma,
     "gemma3_text": encode_dialog_gemma,
     "phi3": encode_dialog_phi3,
+    "jamba": encode_dialog_jamba,
 }
 
 

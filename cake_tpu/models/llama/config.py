@@ -17,6 +17,17 @@ from pathlib import Path
 from typing import Any
 
 
+# Every HF ``model_type`` from_hf_dict takes. The "unsupported" message is
+# built from this tuple, so a family cannot be in one and not the other.
+SUPPORTED_MODEL_TYPES = (
+    "llama", "qwen2", "mistral", "mixtral", "qwen2_moe",
+    "gemma", "gemma2", "phi3", "qwen3", "qwen3_moe", "gemma3_text", "jamba",
+)
+
+# The two kinds a decoder layer's token mixer can be (``layer_kinds``).
+ATTENTION, STATE = "attention", "state"
+
+
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
     """Llama 3.1-style rope frequency scaling (absent => plain RoPE)."""
@@ -115,6 +126,19 @@ class LlamaConfig:
     # ops; a kernel agrees with its twin to rounding. Like attention_impl
     # this is a runtime knob, never an HF field.
     fusion_impl: str = "none"
+    # Hybrid stacks (Jamba): layer i mixes tokens by attention when
+    # ``i % attn_layer_period == attn_layer_offset`` and by a Mamba-1
+    # state-space mixer (ops/ssm.py) otherwise. Period 0 = every layer is
+    # attention (every other family). Readers ask ``layer_kinds``.
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    # False = attention carries no positional term at all (Jamba: the state
+    # layers carry the order).
+    use_rope: bool = True
     # Chat-template override (--chat-template; not an HF field). None = pick
     # by model_type. Needed for Llama-2-chat checkpoints, whose config.json
     # is indistinguishable from base Llama (chat.DIALOG_ENCODERS keys).
@@ -131,6 +155,53 @@ class LlamaConfig:
         if self.query_pre_attn_scalar is None:
             return None
         return float(self.query_pre_attn_scalar) ** -0.5
+
+    @property
+    def layer_kinds(self) -> tuple[str, ...]:
+        """The token mixer of every layer, in the model's order: THE one
+        per-layer fact model, cache, loader and backend ask."""
+        p = self.attn_layer_period
+        return tuple(
+            ATTENTION if not p or i % p == self.attn_layer_offset else STATE
+            for i in range(self.num_hidden_layers)
+        )
+
+    @property
+    def has_state_layers(self) -> bool:
+        return STATE in self.layer_kinds
+
+    def layers_of(self, kind: str) -> tuple[int, ...]:
+        """Absolute indices of the layers of one kind."""
+        return tuple(i for i, k in enumerate(self.layer_kinds) if k == kind)
+
+    @property
+    def layer_runs(self) -> tuple[tuple[str, int, int], ...]:
+        """Maximal runs of one kind in the model's order, as (kind, lo, hi)
+        over that KIND's own stack: jamba2-3b walks state[0:7],
+        attention[0:1], state[7:20], attention[1:2], state[20:26]."""
+        runs: list[tuple[str, int, int]] = []
+        seen = {ATTENTION: 0, STATE: 0}
+        for k in self.layer_kinds:
+            if runs and runs[-1][0] == k:
+                runs[-1] = (k, runs[-1][1], runs[-1][2] + 1)
+            else:
+                runs.append((k, seen[k], seen[k] + 1))
+            seen[k] += 1
+        return tuple(runs)
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def state_bytes_per_lane(self) -> int:
+        """Recurrent state one lane holds over all state layers: the scan's
+        float32 accumulator [d_inner, d_state] and the convolution's last
+        d_conv - 1 inputs (counted at 2 bytes, the served type)."""
+        per_layer = self.mamba_d_inner * (
+            4 * self.mamba_d_state + 2 * (self.mamba_d_conv - 1)
+        )
+        return per_layer * len(self.layers_of(STATE))
 
     @property
     def head_dim(self) -> int:
@@ -189,10 +260,7 @@ class LlamaConfig:
                 ),
             )
         model_type = str(d.get("model_type", "llama"))
-        if model_type not in (
-            "llama", "qwen2", "mistral", "mixtral", "qwen2_moe",
-            "gemma", "gemma2", "phi3", "qwen3", "qwen3_moe", "gemma3_text",
-        ):
+        if model_type not in SUPPORTED_MODEL_TYPES:
             if model_type == "gemma3":
                 raise ValueError(
                     "model_type 'gemma3' is the MULTIMODAL wrapper config; "
@@ -200,10 +268,11 @@ class LlamaConfig:
                     "its fields live under the wrapper's text_config"
                 )
             raise ValueError(
-                f"unsupported model_type {model_type!r} (supported: llama, "
-                "qwen2, mistral, mixtral, qwen2_moe, gemma, gemma2, phi3, "
-                "qwen3, qwen3_moe, gemma3_text)"
+                f"unsupported model_type {model_type!r} "
+                f"(supported: {', '.join(SUPPORTED_MODEL_TYPES)})"
             )
+        if model_type == "jamba":
+            return cls._jamba_from_hf_dict(d, eos_ids)
         if model_type == "phi3" and d.get("rope_scaling"):
             # Phi-3 128k variants use longrope (per-dim su-scaled factors);
             # only the base-rope variants (4k/8k) are supported.
@@ -377,6 +446,55 @@ class LlamaConfig:
         )
 
     @classmethod
+    def _jamba_from_hf_dict(
+        cls, d: dict[str, Any], eos_ids: tuple[int, ...]
+    ) -> "LlamaConfig":
+        """``model_type: jamba`` (HF JambaConfig): Mamba-1 mixers beside
+        attention without a positional term, dense SwiGLU everywhere. Its own
+        parser, so the other families' parsing above stays as it was."""
+        if int(d.get("num_experts", 16)) > 1:
+            raise ValueError(
+                f"jamba with num_experts={d.get('num_experts', 16)} needs "
+                "Jamba's MoE feed-forward, which this framework does not "
+                "bring (dense num_experts=1 checkpoints only)"
+            )
+        if d.get("sliding_window") is not None:
+            raise ValueError("jamba with a sliding_window is not supported")
+        if d.get("mamba_proj_bias", False):
+            raise ValueError(
+                "jamba with mamba_proj_bias=true is not supported (the "
+                "mixer's in/out projections are read without a bias)"
+            )
+        hidden = int(d.get("hidden_size", 4096))
+        heads = int(d.get("num_attention_heads", 32))
+        dt_rank = d.get("mamba_dt_rank", "auto")
+        if dt_rank == "auto":
+            dt_rank = -(-hidden // 16)
+        return cls(
+            hidden_size=hidden,
+            intermediate_size=int(d.get("intermediate_size", 14336)),
+            vocab_size=int(d.get("vocab_size", 65536)),
+            num_hidden_layers=int(d.get("num_hidden_layers", 32)),
+            num_attention_heads=heads,
+            num_key_value_heads=int(d.get("num_key_value_heads", 8)),
+            rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+            max_position_embeddings=int(
+                d.get("max_position_embeddings", 262144)
+            ),
+            bos_token_id=int(d.get("bos_token_id", 1)),
+            eos_token_ids=eos_ids,
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            model_type="jamba",
+            attn_layer_period=int(d.get("attn_layer_period", 8)),
+            attn_layer_offset=int(d.get("attn_layer_offset", 4)),
+            mamba_d_state=int(d.get("mamba_d_state", 16)),
+            mamba_d_conv=int(d.get("mamba_d_conv", 4)),
+            mamba_expand=int(d.get("mamba_expand", 2)),
+            mamba_dt_rank=int(dt_rank),
+            use_rope=False,
+        )
+
+    @classmethod
     def from_model_dir(
         cls, model_dir: str | Path, *, attention_impl: str | None = None
     ) -> "LlamaConfig":
@@ -447,6 +565,7 @@ class LlamaConfig:
             "phi3": "Phi3ForCausalLM",
             "qwen3": "Qwen3ForCausalLM",
             "qwen3_moe": "Qwen3MoeForCausalLM",
+            "jamba": "JambaForCausalLM",
         }[self.model_type]
         d: dict[str, Any] = {
             "architectures": [arch],
@@ -507,6 +626,20 @@ class LlamaConfig:
                     "sliding_attention" if f else "full_attention"
                     for f in self.sliding_pattern
                 ]
+        if self.model_type == "jamba":
+            del d["rope_theta"], d["attention_bias"]
+            d.update(
+                attn_layer_period=self.attn_layer_period,
+                attn_layer_offset=self.attn_layer_offset,
+                mamba_d_state=self.mamba_d_state,
+                mamba_d_conv=self.mamba_d_conv,
+                mamba_expand=self.mamba_expand,
+                mamba_dt_rank=self.mamba_dt_rank,
+                mamba_conv_bias=True,
+                mamba_proj_bias=False,
+                num_experts=1,
+                num_experts_per_tok=1,
+            )
         if self.rope_scaling is not None and self.rope_scaling.rope_type == "linear":
             d["rope_scaling"] = {
                 "rope_type": "linear",

@@ -191,6 +191,11 @@ class LocalForwardStep(FusedDecodeCapability):
         return self._max_seq
 
     def reset(self) -> None:
+        if self.config.has_state_layers:
+            # The weights' holder for the batch engine only: this step's
+            # dense cache and M.forward know nothing of a recurrent state.
+            self._kv = None
+            return
         self._kv = init_cache(
             self.config.num_hidden_layers,
             self._batch,
@@ -201,6 +206,12 @@ class LocalForwardStep(FusedDecodeCapability):
         )
 
     def __call__(self, tokens: np.ndarray, pos: int, seq_len: int) -> np.ndarray:
+        if self._kv is None:
+            from cake_tpu.models.llama.hybrid import refuse_unsupported
+
+            refuse_unsupported(
+                self.config, {"the single-stream generator": True}
+            )
         if self.rolling:
             room = self._kv.max_seq_len - self.config.sliding_window
             if tokens.shape[1] > room:

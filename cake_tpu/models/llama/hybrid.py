@@ -1,0 +1,373 @@
+"""Hybrid stacks: state-space layers beside attention (``model_type: jamba``).
+
+Every other family is a stack of identical attention blocks: one stacked
+tree, one ``lax.scan`` (models/llama/batch.batched_blocks_forward), K and V
+the only per-lane device state. A hybrid model has layers of two KINDS
+(``config.layer_kinds``) with different weight trees and a second kind of
+per-lane state, so here:
+
+  * ``params["layers"]`` is a LIST of stacked trees, one a maximal run of
+    layers of one kind in the model's order (``config.layer_runs``; Jamba2-3B:
+    7 state, 1 attention, 13 state, 1 attention, 6 state). A run is one
+    ``lax.scan``. The attention runs go through the paged branch of
+    ``batched_blocks_forward`` itself (same kernels, same write), told which
+    pool layers they own; a state run scans ``ops/ssm.mixer_forward``. Both
+    kinds share the block's tail (residual, pre-feed-forward norm, SwiGLU:
+    ``model.block_finish``; a state layer's out-projection is its ``wo``).
+  * ``HybridCache`` is the one cache value: a ``PagedKVCache`` that holds
+    the ATTENTION layers only, and the lane state ``ssm`` / ``conv`` of the
+    state layers, indexed by lane and not by page. It is passed wherever the
+    paged backend passes ``kv``, donated, and carried through every scan: a
+    layer reads and writes its slice in place (PR 26's rule, extended to the
+    state: ``pool_audit.audit_hybrid_programs``).
+  * Left pads, a join window's dead tail, and lanes that are not live in a
+    decode dispatch are all one mask, ``live`` [b, L]: where it is false the
+    recurrence passes its state through (``ops/ssm.py``).
+
+What cannot run over a recurrent state is refused at start-up, in one place
+(``refuse_unsupported``): everything that restores, shares, rewinds or
+shards K and V only.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from cake_tpu.models.llama import model as M
+from cake_tpu.models.llama.config import ATTENTION, STATE, LlamaConfig
+from cake_tpu.models.llama.paged_cache import PagedKVCache, init_paged_cache
+from cake_tpu.obs.jitwatch import tracked_jit as _tracked_jit
+from cake_tpu.ops import ssm as S
+from cake_tpu.ops.fuse import resolve_fusion
+from cake_tpu.ops.norm import rms_norm
+
+
+class HybridCache(NamedTuple):
+    """Per-lane device state of a hybrid model. ``ssm`` and ``conv`` keep
+    ``d_inner`` (a multiple of 128) as the minor axis so that no TPU tile is
+    padded; ``ssm`` is float32 (an accumulator), ``conv`` the served type."""
+
+    kv: PagedKVCache  # the attention layers' page pool, [n_attention, ...]
+    ssm: jnp.ndarray  # [n_state, lanes, d_state, d_inner] float32
+    conv: jnp.ndarray  # [n_state, d_conv - 1, lanes, d_inner]
+
+
+def init_hybrid_cache(
+    config: LlamaConfig, lanes: int, n_pages: int, page_size: int, dtype
+) -> HybridCache:
+    """Zeroed: a lane's recurrence starts from s = 0 and a window of zeros."""
+    n_state = len(config.layers_of(STATE))
+    d = config.mamba_d_inner
+    return HybridCache(
+        kv=init_paged_cache(
+            len(config.layers_of(ATTENTION)), n_pages,
+            config.num_key_value_heads, page_size, config.head_dim, dtype,
+        ),
+        ssm=jnp.zeros((n_state, lanes, config.mamba_d_state, d), jnp.float32),
+        conv=jnp.zeros((n_state, config.mamba_d_conv - 1, lanes, d), dtype),
+    )
+
+
+# ------------------------------------------------------------------ params
+
+def run_shapes(config: LlamaConfig, kind: str) -> dict[str, tuple[int, ...]]:
+    """Per-layer shapes of one kind's tree (stacked over its run), as this
+    module holds them. Matrices are [in, out] like every other weight here;
+    ``A_log`` is stored [d_state, d_inner] and ``conv_w`` [d_conv, d_inner]
+    (io/safetensors_io.py transposes both); a state layer's out-projection
+    is its ``wo``."""
+    h, inter = config.hidden_size, config.intermediate_size
+    ffn = {
+        "w_gate": (h, inter), "w_up": (h, inter), "w_down": (inter, h),
+        "ln_attn": (h,), "ln_mlp": (h,),
+    }
+    if kind == ATTENTION:
+        hd = config.head_dim
+        q, kv = config.num_attention_heads * hd, config.num_key_value_heads * hd
+        return {"wq": (h, q), "wk": (h, kv), "wv": (h, kv), "wo": (q, h), **ffn}
+    d, n, r = config.mamba_d_inner, config.mamba_d_state, config.mamba_dt_rank
+    return {
+        "in_proj": (h, 2 * d), "conv_w": (config.mamba_d_conv, d),
+        "conv_b": (d,), "x_proj": (d, r + 2 * n), "dt_ln": (r,),
+        "b_ln": (n,), "c_ln": (n,), "dt_proj": (r, d), "dt_bias": (d,),
+        "A_log": (n, d), "D": (d,), "wo": (d, h), **ffn,
+    }
+
+
+def init_params(config: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> M.Params:
+    """Random-init params in the by-run layout (tests and compile checks)."""
+    std = 0.02
+
+    def draw(k, name, shape):
+        if name in ("D", "dt_ln", "b_ln", "c_ln", "ln_attn", "ln_mlp"):
+            return jnp.ones(shape, dtype)
+        scale = 0.2 if name in ("conv_w", "conv_b", "A_log", "dt_bias") else std
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+
+    runs = []
+    for r, (kind, lo, hi) in enumerate(config.layer_runs):
+        shapes = run_shapes(config, kind)
+        keys = jax.random.split(jax.random.fold_in(key, r), len(shapes))
+        runs.append({
+            name: draw(k, name, (hi - lo, *shape))
+            for k, (name, shape) in zip(keys, shapes.items())
+        })
+    k_embed, k_head = jax.random.split(jax.random.fold_in(key, len(runs)))
+    v, h = config.vocab_size, config.hidden_size
+    params = {
+        "embed": draw(k_embed, "embed", (v, h)),
+        "layers": runs,
+        "ln_f": jnp.ones((h,), dtype),
+    }
+    if not config.tie_word_embeddings:
+        params["lm_head"] = draw(k_head, "lm_head", (h, v))
+    return params
+
+
+# ----------------------------------------------------------------- forward
+
+
+def hybrid_blocks_forward(
+    runs: list,
+    x: jnp.ndarray,
+    cache: HybridCache,
+    q_pos: jnp.ndarray,
+    k_pos: jnp.ndarray,
+    config: LlamaConfig,
+    *,
+    decode: bool,
+    pads: jnp.ndarray,
+    lengths: jnp.ndarray,
+    write_pos: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    live: jnp.ndarray,  # [b, L] bool: positions that are tokens of the row
+    ends: jnp.ndarray | None,  # [b] one past the last live position
+    lane: jnp.ndarray | None = None,
+    cached_chunk: bool = False,
+    write_starts: jnp.ndarray | None = None,
+    allow_pallas: bool = True,
+) -> tuple[jnp.ndarray, HybridCache]:
+    """The model's layers in order, run by run. With ``lane`` (a traced
+    scalar; every prefill) the rows of ``x`` are NEW tenants of lanes
+    ``lane``, ``lane + 1``, ...: their recurrence starts from zero and their
+    final state OVERWRITES those lanes' (never continues the last tenant's).
+    Without it (decode) row r of ``x`` continues lane r's state."""
+    from cake_tpu.models.llama.batch import batched_blocks_forward
+
+    fusion = resolve_fusion(config, allow_pallas)
+    kv, ssm, conv = cache
+    eps = config.rms_norm_eps
+    rows = x.shape[0]
+    if lane is not None:
+        lanes = jnp.arange(conv.shape[2], dtype=jnp.int32)
+        mine = (lanes >= lane) & (lanes < lane + rows)
+
+    def state_layer(carry, per_layer):
+        # The lane state rides in the carry like the page pool: a layer
+        # takes its own slice and puts it back in place.
+        x, ssm, conv = carry
+        lp, li = per_layer
+        c_old = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
+        if lane is None:
+            s_l = jax.lax.dynamic_index_in_dim(ssm, li, 0, keepdims=False)
+            c_l = c_old
+        else:
+            s_l = jnp.zeros((rows, *ssm.shape[2:]), ssm.dtype)
+            c_l = jnp.zeros((conv.shape[1], rows, conv.shape[3]), conv.dtype)
+        h = rms_norm(x, lp["ln_attn"], eps)
+        gated, s_l, c_l = S.mixer_forward(lp, h, s_l, c_l, live, ends, eps)
+        if lane is None:
+            ssm = jax.lax.dynamic_update_index_in_dim(ssm, s_l, li, 0)
+        else:
+            zero = jnp.int32(0)
+            ssm = jax.lax.dynamic_update_slice(
+                ssm, s_l[None], (li, lane, zero, zero)
+            )
+            # The window's lane axis is a tiled one ([.., lanes, d_inner]):
+            # an update-slice at a lane there makes the TPU compiler re-lay
+            # the whole array out and back (two copies of it, seen compiling
+            # for a described v5e). The rows are placed in a buffer of the
+            # layer's own size, 1 MB, by a gather, and selected in.
+            placed = jnp.take(c_l, jnp.clip(lanes - lane, 0, rows - 1), axis=1)
+            c_l = jnp.where(mine[None, :, None], placed, c_old)
+        conv = jax.lax.dynamic_update_index_in_dim(conv, c_l, li, 0)
+        x = M.block_finish(lp, x, gated, config, fusion=fusion)
+        return (x, ssm, conv), None
+
+    for lp, (kind, lo, hi) in zip(runs, config.layer_runs, strict=True):
+        if kind == ATTENTION:
+            x, kv = batched_blocks_forward(
+                lp, x, kv, None, None, q_pos, k_pos, config,
+                decode=decode, pads=pads, lengths=lengths,
+                write_pos=write_pos, allow_pallas=allow_pallas,
+                block_tables=block_tables, layer_base=lo,
+                cached_chunk=cached_chunk, write_starts=write_starts,
+            )
+        else:
+            li = jnp.arange(lo, hi, dtype=jnp.int32)
+            (x, ssm, conv), _ = jax.lax.scan(
+                state_layer, (x, ssm, conv), (lp, li)
+            )
+    return x, HybridCache(kv=kv, ssm=ssm, conv=conv)
+
+
+def hybrid_prefill(
+    params: M.Params,
+    tokens: jnp.ndarray,  # [b, W]: absolute slots [start, start + W)
+    cache: HybridCache,
+    pads: jnp.ndarray,  # [b] each row's first slot (absolute)
+    ends: jnp.ndarray,  # [b] one past each row's last slot (absolute)
+    block_tables: jnp.ndarray,
+    config: LlamaConfig,
+    start: jnp.ndarray | int = 0,
+    lane: jnp.ndarray | int = 0,
+    allow_pallas: bool = True,
+) -> tuple[jnp.ndarray, HybridCache]:
+    """Every prefill of a hybrid model: the rows are NEW tenants of lanes
+    ``lane``... (``block_tables`` holds those lanes' rows), each row's
+    tokens sit at slots [pads, ends) of a window that starts at ``start``.
+    An epoch's prefill starts at 0 and ends every row at the shared slot; a
+    joiner's window is only as wide as its prompt and ENDS at the shared
+    slot — neither kind of layer needs the slots before it (the state
+    layers start from zero, and the attention layers carry no positional
+    term and read only this row's keys), so a join costs its prompt, not
+    the batch's slot.
+
+    The attention layers run the paged cached-chunk arithmetic (the window's
+    K and V are written through the table, then read back with the pool's
+    prefix: ``batch.paged_suffix_prefill``'s grids); the state layers'
+    recurrence stands still wherever the window is not the row's. Logits
+    are the first row's last slot's, ``ends[0] - 1``: the shared slot."""
+    from cake_tpu.models.llama.batch import paged_seq_len, verify_positions
+
+    b, w = tokens.shape
+    start = jnp.asarray(start, jnp.int32)
+    x = M.embed_tokens(params, tokens, config)
+    capacity = paged_seq_len(cache.kv, block_tables)
+    q_pos, k_pos, _ = verify_positions(w, pads, start, capacity)
+    grid = start + jnp.arange(w, dtype=jnp.int32)[None, :]
+    live = (grid >= pads[:, None]) & (grid < ends[:, None])
+    x, cache = hybrid_blocks_forward(
+        params["layers"], x, cache, q_pos, k_pos, config,
+        decode=False, cached_chunk=True, pads=pads, lengths=ends,
+        write_pos=start, write_starts=pads, block_tables=block_tables,
+        live=live, ends=ends - start, lane=jnp.asarray(lane, jnp.int32),
+        allow_pallas=allow_pallas,
+    )
+    return M.head_forward(params, x, ends[0] - start, config), cache
+
+
+_hybrid_prefill_jit = _tracked_jit(
+    hybrid_prefill,
+    name="batch.hybrid_prefill",
+    module="prefill_paged_hybrid",
+    static_argnames=("config", "allow_pallas"),
+    donate_argnames=("cache",),
+)
+
+
+@functools.lru_cache(maxsize=32)
+def _hybrid_join_fn(config: LlamaConfig, width: int, allow_pallas: bool = True):
+    """One joining (or restored) row's prefill into lane ``lane``: the same
+    arithmetic as the epoch's, one row, its own jit so that a join is a
+    program of its own name. One compile per window width."""
+
+    def run(params, cache, tokens, pads1, ends1, lane_table, start, lane):
+        return hybrid_prefill(
+            params, tokens, cache, pads1, ends1, lane_table, config,
+            start=start, lane=lane, allow_pallas=allow_pallas,
+        )
+
+    return _tracked_jit(
+        run, name=f"batch.hybrid_join[w={width}]",
+        module="prefill_join_paged_hybrid", donate_argnums=(1,),
+    )
+
+
+@functools.lru_cache(maxsize=16)
+def _hybrid_decode_fn(
+    config: LlamaConfig,
+    padded_seq: int,
+    n_steps: int,
+    temperature: float,
+    top_k,
+    top_p,
+    repeat_penalty: float,
+    allow_pallas: bool = True,
+):
+    """``batch._paged_decode_fn`` for a hybrid model: the fused sampled
+    decode scan with the whole ``HybridCache`` as its carried, donated
+    cache, plus ``valid`` [b]: lanes that are not live keep their state."""
+    from cake_tpu.models.llama.batch import decode_positions
+    from cake_tpu.models.llama.fused import sampled_decode_scan
+
+    fusions, fimpl = resolve_fusion(config, allow_pallas)
+    tail_impl = fimpl if "tail" in fusions else None
+
+    def run(params, cache, tok, slot, pads, block_tables, valid, key, ring, ring_idx):
+        live = valid[:, None]
+
+        def forward_one(tok, cache, slot):
+            x = M.embed_tokens(params, tok, config)
+            q_pos, k_pos, lengths = decode_positions(slot, pads, padded_seq)
+            x, cache = hybrid_blocks_forward(
+                params["layers"], x, cache, q_pos, k_pos, config,
+                decode=True, pads=pads, lengths=lengths, write_pos=slot,
+                block_tables=block_tables, live=live, ends=None,
+                allow_pallas=allow_pallas,
+            )
+            logits = M.head_forward(
+                params, x, jnp.int32(1), config, fusion=(fusions, fimpl)
+            )
+            return logits, cache
+
+        return sampled_decode_scan(
+            forward_one, cache, tok, slot, key, ring, ring_idx,
+            n_steps=n_steps, temperature=temperature, top_k=top_k,
+            top_p=top_p, repeat_penalty=repeat_penalty, tail_impl=tail_impl,
+        )
+
+    return _tracked_jit(
+        run,
+        name=(
+            f"batch.hybrid_decode[n={n_steps},t={temperature},k={top_k},"
+            f"p={top_p},rp={repeat_penalty}]"
+        ),
+        module="decode_chunk_paged_hybrid",
+        donate_argnums=(1,),
+    )
+
+
+# ----------------------------------------------------------------- refusal
+
+
+class UnsupportedWithStateLayers(ValueError):
+    """A feature was asked for that cannot run over a recurrent state."""
+
+
+def refuse_unsupported(config: LlamaConfig, asked: dict[str, bool]) -> None:
+    """THE one capability check for models with state layers, called where
+    the backend is chosen (cli.main, BatchEngine.__init__, the single-stream
+    step, cake-split-model). ``asked`` maps a feature's name as the user
+    wrote it to whether it was asked for; the first that was raises. Every
+    one of them restores, shares, rewinds, shards or re-packs K and V only:
+    over a recurrent state it would serve wrong tokens silently, and there
+    is no fallback to serve instead."""
+    if not config.has_state_layers:
+        return
+    for feature, on in asked.items():
+        if on:
+            raise UnsupportedWithStateLayers(
+                f"{feature} is not supported for model_type "
+                f"{config.model_type!r}: "
+                f"{len(config.layers_of(STATE))} of its "
+                f"{config.num_hidden_layers} layers keep a recurrent state "
+                "per lane, and this feature restores, rewinds, shares or "
+                "shards K and V only. Serve it with --api HOST:PORT "
+                "--api-batch N (N > 1) --kv-mode paged --prefix-cache off "
+                "on one chip, unquantized."
+            )

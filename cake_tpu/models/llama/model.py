@@ -267,14 +267,16 @@ def block_qkv(
     b, chunk, _ = x.shape
     hd = config.head_dim
     n_q, n_kv = layer_head_counts(lp, config)
-    if "rope_sel" in lp:
+    if "rope_sel" in lp and config.use_rope:
         # Dual-rope families (Gemma-3): plane 0 = global rope, 1 = local.
         # The SAME leading-axis select serves stacked tables [2, seq, hd/2]
         # and stacked pre-gathered rows [2, b, s, hd/2], so both the
         # per-layer and once-per-step gather paths stay family-agnostic.
         cos = cos[lp["rope_sel"]]
         sin = sin[lp["rope_sel"]]
-    assert not (cos.ndim == 3 and k_positions is not None), (
+    assert not (
+        config.use_rope and cos.ndim == 3 and k_positions is not None
+    ), (
         "pre-gathered rope rows cannot serve distinct k_positions"
     )
     if "wqkv" in lp:
@@ -303,6 +305,10 @@ def block_qkv(
         # head sharding replicates it untouched.
         q = rms_norm(q, lp["q_norm"], config.rms_norm_eps, config.rmsnorm_offset)
         k = rms_norm(k, lp["k_norm"], config.rms_norm_eps, config.rmsnorm_offset)
+    if not config.use_rope:
+        # No positional term at all (Jamba's attention: the state layers
+        # around it carry the order); ``cos``/``sin`` may be None.
+        return q, k, v
     return (
         apply_rope(q, cos, sin, positions),
         apply_rope(k, cos, sin, positions if k_positions is None else k_positions),

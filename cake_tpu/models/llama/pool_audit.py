@@ -127,6 +127,112 @@ def audit_paged_programs(
     return reports
 
 
+def audit_hybrid_programs(
+    config: LlamaConfig,
+    *,
+    n_pages: int,
+    page_size: int,
+    lanes: int,
+    table_pages: int,
+    n_steps: int,
+    join_width: int,
+    prefill_rows: int = 0,
+    dtype=jnp.bfloat16,
+    allow_pallas: bool = True,
+    sharding=None,
+) -> dict[str, dict]:
+    """``audit_paged_programs`` for a model with state layers
+    (models/llama/hybrid.py): {"decode": report, "join": report} for a decode
+    chunk over ``lanes`` rows and one joining row's prefill. Beside the page
+    pool's readings a report holds the lane state's: ``state_scans`` (a scan
+    that takes ``ssm`` or ``conv``, or a layer of one, as a scanned input or
+    gives one back stacked) and ``state_copies`` (a compiled ``copy`` of a
+    whole state array). A layer reads and writes its own slice of the state
+    in place, so slices and update-slices of ONE layer are the work itself
+    and are not counted; ``temp_bytes`` beside ``state_bytes`` says whether
+    a second state exists. With ``prefill_rows`` a third program, "prefill":
+    one group of that many rows of an epoch's prefill, ``join_width`` wide,
+    written at a lane offset."""
+    from cake_tpu.models.llama import hybrid as H
+    from cake_tpu.ops.fuse import fuse_params
+
+    def spec(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    params = abstract(jax.eval_shape(lambda: fuse_params(
+        H.init_params(config, jax.random.PRNGKey(0), dtype)
+    )))
+    cache = abstract(jax.eval_shape(
+        lambda: H.init_hybrid_cache(config, lanes, n_pages, page_size, dtype)
+    ))
+    kv_shape = tuple(cache.kv.k.shape)
+    weight_dims = {
+        ",".join(map(str, a.shape)) for a in jax.tree.leaves(params["layers"])
+    }
+    state = {"ssm": (tuple(cache.ssm.shape), jnp.float32),
+             "conv": (tuple(cache.conv.shape), dtype)}
+    decode = H._hybrid_decode_fn(
+        config, table_pages * page_size, n_steps, 0.0, None, None, 1.0,
+        allow_pallas=allow_pallas,
+    )
+    join = H._hybrid_join_fn(config, join_width, allow_pallas)
+    programs = {
+        "decode": lambda: decode._jitted.trace(
+            params, cache, spec((lanes,)), spec(()), spec((lanes,)),
+            spec((lanes, table_pages)), spec((lanes,), jnp.bool_),
+            spec((lanes, 2), jnp.uint32), spec((lanes, 0)), spec((lanes,)),
+        ),
+        "join": lambda: join._jitted.trace(
+            params, cache, spec((1, join_width)), spec((1,)), spec((1,)),
+            spec((1, table_pages)), spec(()), spec(()),
+        ),
+    }
+    if prefill_rows:
+        g = prefill_rows
+        programs["prefill"] = lambda: H._hybrid_prefill_jit._jitted.trace(
+            params, spec((g, join_width)), cache, spec((g,)), spec((g,)),
+            spec((g, table_pages)), config, spec(()), spec(()),
+            allow_pallas=allow_pallas,
+        )
+    reports = {}
+    for name, trace in programs.items():
+        t0 = time.perf_counter()
+        traced = trace()
+        compiled = traced.lower().compile()
+        hlo = compiled.as_text()
+        mem = compiled.memory_analysis()
+        state_scans, state_copies = [], []
+        for shape, dt in state.values():
+            # A run's stacked weights are scanned inputs by design; at a
+            # tiny size one may have the shape of a layer of the state.
+            state_scans += [
+                found for found in scans_moving_pool(traced.jaxpr, shape)
+                if not any(f"[{dims}]" in found for dims in weight_dims)
+            ]
+            state_copies += [
+                op for op in pool_ops_in_hlo(hlo, shape, dt)
+                if op.startswith("copy") and f"[{','.join(map(str, shape))}]" in op
+            ]
+        reports[name] = {
+            "scans": scans_moving_pool(traced.jaxpr, kv_shape),
+            "pool_ops": pool_ops_in_hlo(hlo, kv_shape, dtype),
+            "state_scans": state_scans,
+            "state_copies": state_copies,
+            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
+            "pool_bytes": math.prod(kv_shape) * jnp.dtype(dtype).itemsize,
+            "state_bytes": sum(
+                math.prod(shape) * jnp.dtype(dt).itemsize
+                for shape, dt in state.values()
+            ),
+            "kernels": hlo.count("tpu_custom_call"),
+            "seconds": round(time.perf_counter() - t0, 1),
+        }
+    return reports
+
+
 def scans_moving_pool(jaxpr, kv_shape: tuple[int, ...]) -> list[str]:
     """Every ``scan`` in ``jaxpr`` (nested ones included) that takes the
     pool, or a layer of it, as a scanned input or gives one back as a

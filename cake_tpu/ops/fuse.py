@@ -162,12 +162,19 @@ def _concat_out(ws: list, tp: int):
     return jnp.concatenate(parts, axis=-1)
 
 
-def is_fused(layers: dict) -> bool:
-    return FUSED_QKV in layers
+def is_fused(layers) -> bool:
+    if isinstance(layers, (list, tuple)):  # one tree a run (hybrid stacks)
+        return all(is_fused(run) for run in layers)
+    return FUSED_QKV in layers or FUSED_GU in layers
 
 
-def fuse_layer_tree(layers: dict, tp: int = 1) -> dict:
-    """Fuse a stacked layer tree (any leading axes). Idempotent."""
+def fuse_layer_tree(layers, tp: int = 1):
+    """Fuse a stacked layer tree (any leading axes). Idempotent. A hybrid
+    stack (models/llama/hybrid.py) is a list of such trees, one a run of
+    layers of one kind: each is fused on its own (a state layer has no
+    q/k/v, and its feed-forward fuses like any dense one)."""
+    if isinstance(layers, (list, tuple)):
+        return [fuse_layer_tree(run, tp) for run in layers]
     if is_fused(layers):
         return layers
     out = dict(layers)

@@ -61,6 +61,26 @@ class Geometry:
     batches: tuple[int, ...] = (1, 8)
 
 
+@dataclasses.dataclass(frozen=True)
+class HybridGeometry:
+    """A model with state layers (models/llama/hybrid.py): its attention
+    layers' head layout for the paged kernels, its mixer's sizes."""
+    hidden: int
+    n_q: int
+    n_kv: int
+    head_dim: int
+    d_inner: int
+    d_state: int
+    d_conv: int
+    dt_rank: int
+    page_size: int
+    max_seq: int
+    chunk: int
+    prompt: int  # a mixer prefill's length; not a multiple of the scan's chunk
+    dtype: str
+    batches: tuple[int, ...] = (1, 8)
+
+
 @contextlib.contextmanager
 def recorded_interpret():
     """Yields a list that receives ``bool(interpret)`` for every
@@ -384,6 +404,188 @@ def _int4_cases(c: _Cases) -> None:
                 return got, want, s
 
             c.run("int4_matmul", f"rows={rows} site={name}", int4, tol=tol)
+
+
+def _paged_head_cases(c: _Cases, n_q: int, n_kv: int) -> None:
+    """The paged decode and chunk kernels at another head layout than the
+    geometry's (one KV head under a group of 20: a block of 20 query rows)."""
+    from cake_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention,
+        paged_decode_attention_xla,
+    )
+    from cake_tpu.ops.pallas.paged_prefill import (
+        paged_chunk_attention,
+        paged_chunk_attention_xla,
+    )
+
+    g = c.g
+    n_p = g.max_seq // g.page_size
+    for b in g.batches:
+        perm = np.random.default_rng(b).permutation(b * n_p)
+        tables = jnp.asarray(perm.reshape(b, n_p), jnp.int32)
+        k_pool = c.normal((b * n_p, n_kv, g.page_size, g.head_dim))
+        v_pool = c.normal((b * n_p, n_kv, g.page_size, g.head_dim))
+        starts, lengths = _row_bounds(c, b, g.max_seq, 1)
+        q = c.normal((b, 1, n_q, g.head_dim))
+        q_pos = (lengths - 1)[:, None]
+        k_pos = _live_k_positions(g.max_seq, starts, lengths)
+
+        def paged_decode():
+            got, s = _timed(lambda: paged_decode_attention(
+                q, k_pool, v_pool, lengths, tables, starts))
+            want = paged_decode_attention_xla(
+                q, k_pool, v_pool, q_pos, k_pos, tables)
+            return got, want, s
+
+        c.run("paged_attention", f"b={b} heads={n_q}/{n_kv}", paged_decode)
+        starts_c, lengths_c = _row_bounds(c, b, g.max_seq, g.chunk)
+        q_starts = lengths_c - g.chunk
+        qc = c.normal((b, g.chunk, n_q, g.head_dim))
+        q_pos_c = q_starts[:, None] + jnp.arange(g.chunk, dtype=jnp.int32)[None]
+        k_pos_c = _live_k_positions(g.max_seq, starts_c, lengths_c)
+
+        def paged_chunk():
+            got, s = _timed(lambda: paged_chunk_attention(
+                qc, k_pool, v_pool, q_starts, lengths_c, starts_c, tables))
+            want = paged_chunk_attention_xla(
+                qc, k_pool, v_pool, q_pos_c, k_pos_c, tables)
+            return got, want, s
+
+        c.run("paged_prefill", f"b={b} chunk={g.chunk} heads={n_q}/{n_kv}",
+              paged_chunk)
+
+
+def _mixer_reference(lp, h, s0, window, eps):
+    """The mixer as published, float32 at matmul precision ``highest``, one
+    step of the recurrence a step of ``lax.scan`` (what ops/ssm.py's chunked
+    scan and one-token update are held against). Returns (y * silu(z), s
+    after the last token, the convolution's last inputs)."""
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    lp = {k: f32(v) for k, v in lp.items()}
+    h, s0, window = f32(h), f32(s0), f32(window)
+    rms = lambda x, w: x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+    with jax.default_matmul_precision("highest"):
+        uz = h @ lp["in_proj"]
+        d = uz.shape[-1] // 2
+        u_in, z = uz[..., :d], uz[..., d:]
+        k = lp["conv_w"].shape[0]
+        padded = jnp.concatenate([jnp.moveaxis(window, 0, 1), u_in], axis=1)
+        length = h.shape[1]
+        u = lp["conv_b"] + sum(
+            lp["conv_w"][j] * padded[:, j:j + length] for j in range(k))
+        u = jax.nn.silu(u)
+        dbc = u @ lp["x_proj"]
+        n = s0.shape[-2]
+        r = dbc.shape[-1] - 2 * n
+        dt = jax.nn.softplus(
+            rms(dbc[..., :r], lp["dt_ln"]) @ lp["dt_proj"] + lp["dt_bias"])
+        b_in = rms(dbc[..., r:r + n], lp["b_ln"])
+        c_out = rms(dbc[..., r + n:], lp["c_ln"])
+        a = -jnp.exp(lp["A_log"])  # [n, d]
+
+        def step(s, xs):
+            dt_t, u_t, b_t, c_t = xs  # [b, d], [b, d], [b, n], [b, n]
+            s = jnp.exp(dt_t[:, None, :] * a) * s + (dt_t * u_t)[:, None, :] * b_t[:, :, None]
+            return s, jnp.einsum("bnd,bn->bd", s, c_t)
+
+        t_major = lambda x: jnp.moveaxis(x, 1, 0)
+        s, y = jax.lax.scan(
+            step, s0, (t_major(dt), t_major(u), t_major(b_in), t_major(c_out)))
+        y = t_major(y) + lp["D"] * u
+        return y * jax.nn.silu(z), s, jnp.moveaxis(padded[:, -(k - 1):], 1, 0)
+
+
+def _mixer_cases(c: _Cases, g: HybridGeometry) -> None:
+    from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.models.llama.hybrid import run_shapes
+    from cake_tpu.ops import ssm
+
+    config = LlamaConfig(
+        model_type="jamba", hidden_size=g.hidden, mamba_d_state=g.d_state,
+        mamba_d_conv=g.d_conv, mamba_expand=g.d_inner // g.hidden,
+        mamba_dt_rank=g.dt_rank, attn_layer_period=2, attn_layer_offset=1,
+        num_attention_heads=g.n_q, num_key_value_heads=g.n_kv,
+        head_dim_override=g.head_dim,
+    )
+    eps = 1e-6
+    shapes = run_shapes(config, "state")
+    ones = ("D", "dt_ln", "b_ln", "c_ln")
+    lp = {
+        name: jnp.ones(shape, c.dtype) if name in ones
+        else c.normal(shape, 0.0 if name == "dt_bias" else 0.02)
+        for name, shape in shapes.items()
+        if name not in ("wo", "w_gate", "w_up", "w_down", "ln_attn", "ln_mlp")
+    }
+    # arrays as arguments: a closure would bake 100 MB of weights into the
+    # program (a 3-minute compile at 8 rows on the chip)
+    prefill_fn = jax.jit(lambda lp, h, s0, w0, live, ends: ssm.mixer_forward(
+        lp, h, s0, w0, live, ends, eps))
+    step_fn = jax.jit(lambda lp, h1, s1, w1, alive: ssm.mixer_forward(
+        lp, h1, s1, w1, alive[:, None], None, eps))
+    for b in g.batches:
+        # ---- prefill: rows left-padded by differing amounts, from zero
+        length = g.prompt
+        pads = jnp.asarray(
+            np.random.default_rng(b).integers(0, length // 3, size=b), jnp.int32)
+        h = c.normal((b, length, g.hidden))
+        grid = jnp.arange(length, dtype=jnp.int32)[None, :]
+        live = grid >= pads[:, None]
+        s0 = jnp.zeros((b, g.d_state, g.d_inner), jnp.float32)
+        w0 = jnp.zeros((g.d_conv - 1, b, g.d_inner), c.dtype)
+        ends = jnp.full((b,), length, jnp.int32)
+
+        def prefill():
+            (gated, s, w), first = _timed(prefill_fn, lp, h, s0, w0, live, ends)
+            # the reference sees each row without its pads
+            wants = [
+                _mixer_reference(lp, h[r:r + 1, int(pads[r]):], s0[r:r + 1],
+                                 w0[:, r:r + 1], eps)
+                for r in range(b)
+            ]
+            got = (
+                jnp.concatenate([gated[r, int(pads[r]):] for r in range(b)]),
+                s, w,
+            )
+            want = (
+                jnp.concatenate([x[0][0] for x in wants]),
+                jnp.concatenate([x[1] for x in wants]),
+                jnp.concatenate([x[2] for x in wants], axis=1),
+            )
+            return got, want, first
+
+        c.run("ssm_mixer", f"prefill b={b} L={length}", prefill)
+
+        # ---- one token from a state that is there, one lane not live
+        s1 = jnp.abs(c.normal((b, g.d_state, g.d_inner), 0.5, jnp.float32))
+        w1 = c.normal((g.d_conv - 1, b, g.d_inner), 0.5)
+        h1 = c.normal((b, 1, g.hidden))
+        alive = jnp.arange(b) != b - 1 if b > 1 else jnp.ones((1,), bool)
+
+        def step():
+            (gated, s, w), first = _timed(step_fn, lp, h1, s1, w1, alive)
+            want_g, want_s, want_w = _mixer_reference(lp, h1, s1, w1, eps)
+            keep = alive[:, None, None]
+            want_s = jnp.where(keep, want_s, s1)
+            want_w = jnp.where(alive[None, :, None], want_w, w1.astype(jnp.float32))
+            return (gated[alive], s, w), (want_g[alive], want_s, want_w), first
+
+        c.run("ssm_mixer", f"step b={b}", step)
+
+
+def run_hybrid_checks(geom: HybridGeometry) -> dict:
+    """``run_checks`` for a model with state layers: the paged kernels at
+    its attention layers' head layout, and the state-space mixer's prefill
+    (chunked scan) and one-token step against the stepwise float32 scan."""
+    c = _Cases(Geometry(
+        hidden=geom.hidden, intermediate=0, n_q=geom.n_q, n_kv=geom.n_kv,
+        head_dim=geom.head_dim, vocab=0, window=None,
+        page_size=geom.page_size, max_seq=geom.max_seq, chunk=geom.chunk,
+        int4_group=0, dtype=geom.dtype, batches=geom.batches,
+    ))
+    with recorded_interpret() as seen:
+        _paged_head_cases(c, geom.n_q, geom.n_kv)
+        _mixer_cases(c, geom)
+    return {"results": c.results, "interpret": seen}
 
 
 def run_checks(geom: Geometry) -> dict:

@@ -885,6 +885,16 @@ class ApiServer:
                         # "Continuous scheduling") plus the spill table's
                         # current depth — preempted lanes parked host-side
                         # awaiting a restore.
+                        state_facts = getattr(
+                            getattr(api.engine, "backend", None),
+                            "state_facts", None,
+                        )
+                        if state_facts is not None:
+                            # The recurrent state beside the page pool
+                            # (models/llama/hybrid.py): cumulative
+                            # ``lane_writes`` like ``period``; zeros for a
+                            # model without state layers.
+                            body["engine"]["state"] = state_facts()
                         body["engine"]["scheduler"] = getattr(
                             api.engine, "scheduler", "epoch"
                         )

@@ -312,6 +312,38 @@ def _paged_join_fn(config, width, allow_pallas=True):
     )
 
 
+# Window widths and decode capacities a model with state layers is compiled
+# for, as shares of a lane's table. The attention-only path keys its programs
+# by every 16-multiple of a prompt bucket, every 64-multiple of a join's slot
+# and every 256-multiple of a capacity, and a server meets new ones for as
+# long as it runs (PERF.md: 40% of Mistral's window is compile stall). A
+# hybrid program is five scans and compiles in 3 to 10 s, so its windows come
+# in eleven widths (an epoch's right-padded with a dead tail that ``ends``
+# marks: the recurrence stands still there and the scan skips it; a joiner's
+# as wide as its prompt) and read the lane's WHOLE table row (a dead page is a
+# grid step the attention kernel skips), and its decode programs in three
+# capacities (``set_epoch_capacity``): a few dozen programs in all, and in
+# the steady state eleven joins and a decode. Closing the set for every model
+# is a ``perf_opt`` of its own (ROADMAP S2); these shares are where it starts.
+HYBRID_WIDTH_64THS = (1, 2, 4, 8, 12, 16, 24, 32, 40, 48, 64)
+HYBRID_CAPACITY_QUARTERS = (1, 2, 4)
+
+
+def hybrid_shape_sets(
+    page_size: int, pages_per_seq: int
+) -> tuple[tuple[int, ...], tuple[int, ...]]:
+    """(window widths in slots, decode capacities in pages) for a table of
+    ``pages_per_seq`` pages: the shares above of the table, widths rounded up
+    to the engine's join bucket of 64. At 32 pages of 128 (``--max-seq-len
+    4096``) they are 64, 128, 256, 512, 768, 1024, 1536, 2048, 2560, 3072,
+    4096 slots and 8, 16, 32 pages: the sets ``jamba2-3b-chat-closed`` was
+    measured with. At any other geometry the set is as closed."""
+    slots = page_size * pages_per_seq
+    widths = {min(slots, -(-slots * f // (64 * 64)) * 64) for f in HYBRID_WIDTH_64THS}
+    pages = {max(1, -(-pages_per_seq * q // 4)) for q in HYBRID_CAPACITY_QUARTERS}
+    return tuple(sorted(widths)), tuple(sorted(pages))
+
+
 class PagedLocalBackend:
     """Single-device batch ops over the paged KV pool (``kv_mode="paged"``).
 
@@ -393,6 +425,20 @@ class PagedLocalBackend:
         # Epoch-bounded table capacity in PAGES (None = full table).
         self._cap_pages: int | None = None
         self._fallback_noted = False
+        # A model with state layers (config.layer_kinds; models/llama/
+        # hybrid.py): the cache value is a HybridCache — the page pool of
+        # its attention layers and the lane state of the others — and the
+        # four operations run the by-run walk. Same engine, same allocator,
+        # same kernels; the suffix/verify/copy-on-write operations are not
+        # defined for it (hybrid.refuse_unsupported keeps callers away).
+        self.hybrid = config.has_state_layers
+        self.hybrid_widths, self.hybrid_capacity_pages = hybrid_shape_sets(
+            page_size, self.pages_per_seq
+        )
+        # Lanes whose recurrent state a prefill or a join wrote, cumulative
+        # (``GET /stats`` engine.state.lane_writes).
+        self.state_lane_writes = 0
+        self._state_lanes = 0
 
     # --------------------------------------------------- kernel dispatch
 
@@ -448,6 +494,14 @@ class PagedLocalBackend:
             self._cap_pages = None
             return
         pages = -(-int(capacity_slots) // self.page_size)
+        if self.hybrid:
+            # One of three capacities. (Not one: the capacity is also what
+            # ends a segment, and a segment that began with one request has
+            # two lanes for as long as it lives — with the whole table as
+            # every epoch's capacity the cell served 140 tokens/s, not 500.)
+            pages = next(
+                (p for p in self.hybrid_capacity_pages if p >= pages), pages
+            )
         self._cap_pages = max(1, min(pages, self.pages_per_seq))
 
     def capacity_slots(self) -> int:
@@ -482,7 +536,24 @@ class PagedLocalBackend:
     def attach_prefix_cache(self, cache) -> None:
         """Switch the pool to PERSISTENT mode for the engine's prefix cache
         (runtime/prefix_cache.py): epochs stop zeroing it."""
+        from cake_tpu.models.llama.hybrid import refuse_unsupported
+
+        refuse_unsupported(self.config, {"--prefix-cache on": True})
         self.prefix_cache = cache
+
+    def state_facts(self) -> dict:
+        """``GET /stats`` engine.state: the recurrent state beside the page
+        pool (zeros for a model without state layers). ``bytes`` is what
+        the current epoch's lanes hold; ``lane_writes`` is cumulative."""
+        from cake_tpu.models.llama.config import STATE
+
+        per_lane = self.config.state_bytes_per_lane if self.hybrid else 0
+        return {
+            "layers": len(self.config.layers_of(STATE)),
+            "bytes_per_lane": per_lane,
+            "bytes": per_lane * self._state_lanes,
+            "lane_writes": self.state_lane_writes,
+        }
 
     def retain_kv(self, kv) -> None:
         """Epoch end (persistent mode): keep the final pool buffer so the
@@ -508,6 +579,19 @@ class PagedLocalBackend:
                 return kv
         else:
             self.allocator.reset(batch=b)
+        if self.hybrid:
+            from cake_tpu.models.llama.hybrid import init_hybrid_cache
+            from cake_tpu.utils import metrics
+
+            self._state_lanes = b
+            metrics.registry.gauge(
+                "cake_state_bytes",
+                "Recurrent state the epoch's lanes hold beside the KV pool.",
+            ).set(b * self.config.state_bytes_per_lane)
+            return init_hybrid_cache(
+                self.config, b, self.max_pages, self.page_size,
+                self.cache_dtype,
+            )
         return init_paged_cache(
             self.config.num_hidden_layers,
             self.max_pages,
@@ -526,11 +610,120 @@ class PagedLocalBackend:
             kw = {"ends": ends, "seq_len": ends[0]}
         self._kernel_note("prefill")
         self._check_write_bound("prefill", int(jnp.shape(tokens)[1]))
+        if self.hybrid:
+            return self._hybrid_prefill(tokens, kv, pads, ends)
         return _paged_prefill_jit(
             self.params, jnp.asarray(tokens), kv, jnp.asarray(pads),
             self._tables(), self.config,
             allow_pallas=self.allow_pallas, **kw,
         )
+
+    # Tokens one prefill program of a model with state layers may hold: the
+    # mixer's float32 intermediates are [rows, width, d_inner] several times
+    # over, so a whole epoch of 32 lanes x 2080 slots would need 8.6 GB of
+    # temporaries beside 6.5 GB of arguments (compiled for a described
+    # v5e); 16k tokens need 2.1 to 2.7 GB.
+    HYBRID_PREFILL_TOKENS = 16384
+
+    def _hybrid_width(self, width: int) -> int:
+        """The narrowest of ``hybrid_widths`` that holds ``width`` slots."""
+        return next((w for w in self.hybrid_widths if w >= width), width)
+
+    def _hybrid_prefill(self, tokens, kv, pads, ends=None):
+        """An epoch's prefill in groups of rows (a power of two each), every
+        group one program that writes its own lanes' K, V and state."""
+        from cake_tpu.models.llama.hybrid import _hybrid_prefill_jit
+
+        tokens = np.asarray(tokens)
+        b, width = tokens.shape
+        ends = jnp.asarray(
+            np.full((b,), width, np.int32) if ends is None else ends, jnp.int32
+        )
+        tokens = jnp.asarray(
+            np.pad(tokens, ((0, 0), (0, self._hybrid_width(width) - width)))
+        )
+        pads = jnp.asarray(pads)
+        group = b
+        while group > 1 and group * tokens.shape[1] > self.HYBRID_PREFILL_TOKENS:
+            group //= 2
+        tables = jnp.asarray(self.allocator.block_tables)  # uncut: see above
+        self.state_lane_writes += b
+        logits = []
+        for lo in range(0, b, group):
+            rows = slice(lo, lo + group)
+            out, kv = _hybrid_prefill_jit(
+                self.params, tokens[rows], kv, pads[rows], ends[rows],
+                tables[rows], self.config, lane=lo,
+                allow_pallas=self.allow_pallas,
+            )
+            logits.append(out)
+        return (logits[0] if len(logits) == 1 else jnp.concatenate(logits)), kv
+
+    def _hybrid_join(self, kv, row_tokens, pads1, ends1, lane):
+        """The engine hands a joiner's row left-padded from slot 0 to the
+        batch's shared slot; a hybrid model's join computes only a window
+        as wide as the PROMPT (one of ``hybrid_widths``) that ends at the slot
+        (``hybrid.hybrid_prefill``). A prompt longer than the slot's reach
+        starts at 0 and leaves a dead tail."""
+        from cake_tpu.models.llama.hybrid import _hybrid_join_fn
+
+        row_tokens = np.asarray(row_tokens)
+        pad, slot = int(np.asarray(pads1)[0]), int(np.asarray(ends1)[0])
+        width = self._hybrid_width(slot - pad)
+        start = max(0, slot - width)
+        window = np.zeros((1, width), np.int32)
+        window[0, : slot - start] = row_tokens[0, start:slot]
+        self.state_lane_writes += 1
+        fn = _hybrid_join_fn(self.config, width, self.allow_pallas)
+        return fn(
+            self.params, kv, jnp.asarray(window),
+            jnp.asarray([pad], jnp.int32), jnp.asarray([slot], jnp.int32),
+            jnp.asarray(self.allocator.block_tables[lane : lane + 1]),
+            jnp.int32(start), jnp.int32(lane),
+        )
+
+    def warm_programs(self, lanes: int, sampling, n_steps: int) -> dict:
+        """Run once, on a scratch cache, every program a saturated server of
+        a hybrid model dispatches at ``lanes`` lanes: an epoch's prefill and
+        a join at each window width, a decode chunk at each capacity. The
+        set is closed (``hybrid_shape_sets``), so a server
+        that did this at start-up traces and loads none of them while it
+        serves: each is a stall of every live stream otherwise (0.4 s from
+        the persistent cache, 3 to 10 s without). Nothing is mapped, so no
+        K or V is written; the scratch cache is dropped. {programs, seconds}."""
+        import time
+
+        t0 = time.perf_counter()
+        widths = self.hybrid_widths
+        cache = self.init_kv(lanes)
+        zeros = np.zeros((lanes,), np.int32)
+        for width in widths:
+            _, cache = self.prefill(
+                np.zeros((lanes, width), np.int32), cache, zeros
+            )
+            _, cache = self.join(
+                cache, np.zeros((1, width), np.int32),
+                jnp.zeros((1,), jnp.int32), jnp.asarray([width], jnp.int32), 0,
+            )
+        keys = jnp.stack([jax.random.PRNGKey(0)] * lanes)
+        window = sampling.repeat_last_n
+        capacities = self.hybrid_capacity_pages
+        for pages in capacities:
+            self.set_epoch_capacity(pages * self.page_size)
+            out = self.decode(
+                cache, jnp.asarray(zeros), 0, jnp.asarray(zeros), keys,
+                jnp.zeros((lanes, window), jnp.int32), jnp.asarray(zeros),
+                n_steps, sampling,
+            )
+            cache = out[1]
+        jax.block_until_ready(cache)
+        self.set_epoch_capacity(None)
+        self.allocator.reset(batch=1)
+        self.state_lane_writes = 0
+        return {
+            "programs": 2 * len(widths) + len(capacities),
+            "seconds": round(time.perf_counter() - t0, 3),
+        }
 
     def suffix_prefill(self, tokens, kv, pads, write_starts, start):
         """Prefix-cache prefill: compute only the window [start, start + W)
@@ -592,6 +785,24 @@ class PagedLocalBackend:
         self._kernel_note("decode")
         _note_fusion_kernels(self, s)
         self._check_write_bound("decode", int(slot) + n)
+        if self.hybrid:
+            from cake_tpu.models.llama.hybrid import _hybrid_decode_fn
+
+            fn = _hybrid_decode_fn(
+                self.config, self.capacity_slots(), n,
+                s.temperature, s.top_k, s.top_p, s.repeat_penalty,
+                allow_pallas=self.allow_pallas,
+            )
+            # A lane is live while it holds pages: the engine releases a
+            # finished row's pages at once and maps a joiner's before its
+            # prefill, and spare lanes never hold any. The same fact that
+            # drops a dead lane's K/V writes keeps its state.
+            b = int(jnp.shape(tok)[0])
+            valid = (self.allocator.block_tables[:b] >= 0).any(axis=1)
+            return fn(
+                self.params, kv, tok, jnp.int32(slot), pads, self._tables(),
+                jnp.asarray(valid), keys, ring, ring_idx,
+            )
         # Position grids size to the epoch capacity, not the padded max_seq
         # — the decode twin of the bounded gather view (one compile per
         # capacity bucket; steady state within an epoch never retraces).
@@ -608,6 +819,8 @@ class PagedLocalBackend:
     def join(self, kv, row_tokens, pads1, ends1, lane):
         self._kernel_note("join")
         self._check_write_bound("join", int(np.asarray(ends1).max()))
+        if self.hybrid:
+            return self._hybrid_join(kv, row_tokens, pads1, ends1, lane)
         fn = _paged_join_fn(
             self.config, row_tokens.shape[1], self.allow_pallas
         )

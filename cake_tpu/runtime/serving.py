@@ -590,6 +590,20 @@ class BatchEngine:
         # Per-epoch failover accounting (engine thread only; reset per epoch).
         self._fo_count = 0
         self._fo_spent_s = 0.0
+        if config.has_state_layers:
+            # The one capability check (models/llama/hybrid.py), again where
+            # a programmatic engine chooses its backend.
+            from cake_tpu.models.llama.hybrid import refuse_unsupported
+
+            refuse_unsupported(config, {
+                "--kv-mode dense": kv_mode != "paged",
+                "--prefix-cache on": bool(serve and serve.prefix_cache),
+                "--speculative-k": bool(speculative_k),
+                "--draft-model": proposer_factory is not None,
+                "a backend other than the local paged one (--tp, pipeline, "
+                "distributed)": backend is not None
+                and not getattr(backend, "hybrid", False),
+            })
         if backend is None:
             if params is None:
                 # Fail here, not later inside a jitted prefill with an opaque
@@ -657,6 +671,9 @@ class BatchEngine:
         # drives admission, page growth, and release; None = dense lanes.
         self._alloc = getattr(backend, "allocator", None)
         self.kv_mode = getattr(backend, "kv_mode", "dense")
+        # Layers whose recurrent state a join overwrites (0 = none: every
+        # family but the hybrid ones); rides the ``join`` span.
+        self._state_layers = len(config.layers_of("state"))
         # Persistent prefix cache (runtime/prefix_cache.py): fork shared
         # prompt-prefix page chains at admission, prefill only the uncached
         # suffix, insert/refresh chains on finish. Paged local backend only
@@ -3908,7 +3925,11 @@ class BatchEngine:
 
         ids = req.prompt_ids
         with self._phase(
-            "join", rid=req.rid, args={"lane": lane, "slot": int(slot)}
+            "join", rid=req.rid,
+            args={
+                "lane": lane, "slot": int(slot),
+                "state_layers": self._state_layers,
+            },
         ) as join:
             pad = slot - len(ids)
             if self._alloc is not None and self._prefix is not None:

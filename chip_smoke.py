@@ -27,6 +27,10 @@ every phase runs in a child that has exited before the next one starts.
            weights) at the benchmark cell's geometry: no copy, slice or
            update-slice of the KV pool or of a layer of it in the compiled
            program, and less than one pool of temporaries
+  H        a model with state layers at jamba2-3b-chat-closed's geometry:
+           the paged kernels at one KV head under a group of 20, the
+           state-space mixer (ops/ssm.py) against the stepwise scan, and its
+           decode chunk and join compiled: no copy of the pool or the state
   D        four chips: the phase-A server under --tp 4 and as a four-stage
            pipeline, with per-device memory (skipped below four devices)
 
@@ -54,7 +58,7 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, ".chip_smoke")  # listed in .gitignore
-PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "D")
+PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "D")
 
 MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
     model_type="mistral", hidden_size=4096, intermediate_size=14336,
@@ -63,6 +67,17 @@ MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
     rms_norm_eps=1e-5, max_position_embeddings=32768, bos_token_id=1,
     eos_token_ids=(2,), tie_word_embeddings=False,
 )
+def _benchmark_model(name: str) -> dict:
+    """The model's own keys of ``bench/configs/<name>.json`` (what the
+    benchmark writes as ``config.json``), so that this file holds no copy
+    of a catalog row's widths. ``bench.manifest`` imports no JAX."""
+    from bench.manifest import model_config
+
+    with open(os.path.join(REPO, "bench", "configs", name + ".json")) as f:
+        return model_config(json.load(f))
+
+
+JAMBA2_3B = _benchmark_model("ai21-jamba2-3b")
 PRESETS = {
     # Prompt lengths are in characters: without a tokenizer file the byte
     # tokenizer serves, one token a byte.
@@ -74,6 +89,11 @@ PRESETS = {
         # mistral7b-chat-closed (bench/configs/mistral-7b-v0.1-d16.json)
         pool=dict(layers=16, pages=256, lanes=8, table_pages=8, steps=8,
                   join_width=256),
+        # jamba2-3b-chat-closed (bench/configs/ai21-jamba2-3b.json)
+        hybrid=dict(
+            model=dict(JAMBA2_3B), prompt=300, pages=1024, lanes=32,
+            table_pages=8, steps=8, join_width=512,
+        ),
     ),
     # The rehearsal: same family and head layout rules (tp 4 divides the
     # heads, a page is a whole lane tile), widths a CPU can interpret.
@@ -89,6 +109,18 @@ PRESETS = {
         int4_group=64, batches=(1, 2), matmul=(256, 4),
         pool=dict(layers=3, pages=64, lanes=2, table_pages=2, steps=4,
                   join_width=64),
+        hybrid=dict(
+            model=dict(
+                JAMBA2_3B, hidden_size=64, intermediate_size=128,
+                num_attention_heads=4, vocab_size=512, num_hidden_layers=8,
+                attn_layer_period=4, attn_layer_offset=2, mamba_d_state=4,
+                mamba_dt_rank=4,
+            ),
+            # 4 lanes: with 2 a run's stacked A_log [2, 4, 128] has the
+            # shape of one layer of the state, and the audit names it.
+            prompt=37, pages=64, lanes=4, table_pages=2, steps=4,
+            join_width=64,
+        ),
     ),
 }
 
@@ -301,8 +333,55 @@ def child_pool(preset: dict) -> None:
         emit({"program": name, **device, **report})
 
 
+def child_hybrid(preset: dict) -> None:
+    """A model with state layers at the benchmark cell's geometry: the paged
+    kernels at its head layout (one KV head under a group of 20) and the
+    state-space mixer against the stepwise scan, then its decode chunk and
+    join compiled for the device this process holds, from shapes alone."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import pool_audit
+    from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.ops.pallas.check import HybridGeometry, run_hybrid_checks
+    from cake_tpu.utils.device import describe_devices, setup_compile_cache
+
+    setup_compile_cache()
+    device = describe_devices()
+    g = preset["hybrid"]
+    config = dataclasses.replace(
+        LlamaConfig.from_hf_dict(g["model"]), attention_impl="pallas"
+    )
+    out = run_hybrid_checks(HybridGeometry(
+        hidden=config.hidden_size, n_q=config.num_attention_heads,
+        n_kv=config.num_key_value_heads, head_dim=config.head_dim,
+        d_inner=config.mamba_d_inner, d_state=config.mamba_d_state,
+        d_conv=config.mamba_d_conv, dt_rank=config.mamba_dt_rank,
+        page_size=preset["page_size"], max_seq=preset["max_seq_len"],
+        chunk=preset["chunk"], prompt=g["prompt"], dtype=preset["dtype"],
+        batches=tuple(preset["batches"]),
+    ))
+    for rec in out["results"]:
+        emit({"kind": "case", **rec})
+    emit({"kind": "summary", **device,
+          "interpret": sorted(set(out["interpret"])),
+          "pallas_calls": len(out["interpret"])})
+    reports = pool_audit.audit_hybrid_programs(
+        config, n_pages=g["pages"], page_size=preset["page_size"],
+        lanes=g["lanes"], table_pages=g["table_pages"], n_steps=g["steps"],
+        join_width=g["join_width"],
+        dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]],
+        allow_pallas=jax.default_backend() != "cpu",
+    )
+    for name, report in reports.items():
+        emit({"kind": "program", "program": name, **report})
+
+
 CHILDREN = {"probe": child_probe, "setup": child_setup,
-            "kernels": child_kernels, "pool": child_pool}
+            "kernels": child_kernels, "pool": child_pool,
+            "hybrid": child_hybrid}
 
 
 # ------------------------------------------------------------------- traffic
@@ -315,6 +394,24 @@ def _model_dir(preset: dict) -> str:
 def _get(base: str, route: str, timeout: float = 30.0) -> dict:
     with urllib.request.urlopen(base + route, timeout=timeout) as r:
         return json.load(r)
+
+
+def _wait_quiet(base: str, timeout: float = 20.0) -> None:
+    """Until the engine's loop stands still: two reads of ``GET /stats``
+    0.3 s apart (two dispatch periods on the chip) count the same periods
+    and segments. A server without the account is not waited for."""
+    def counts():
+        engine = _get(base, "/stats").get("engine") or {}
+        return [(engine.get(k) or {}).get("count") for k in ("period", "segment")]
+
+    deadline = time.monotonic() + timeout
+    last = counts()
+    while last != [None, None] and time.monotonic() < deadline:
+        time.sleep(0.3)
+        now = counts()
+        if now == last:
+            return
+        last = now
 
 
 def _post_chat(base: str, messages: list, max_tokens: int) -> dict:
@@ -426,6 +523,10 @@ def drive_traffic(base: str, preset: dict, shared_prefix: bool) -> dict:
                   "content": _prompt(preset["shared_prefix"], "system")}
         for i, q in enumerate(("question one?", "question two?",
                                "question six?"), start=1):
+            # An answer is back before the engine has closed the segment
+            # that served it; a request sent in that instant JOINS the
+            # segment at another pad and misses (seen here on an idle CPU).
+            _wait_quiet(base)
             check(f"prefix#{i}", _post_chat(
                 base, [system, {"role": "user", "content": q}], n_new
             ))
@@ -693,6 +794,47 @@ def phase_pool(args, preset) -> dict:
     return out
 
 
+def phase_hybrid(args, preset) -> dict:
+    """Phase H: a model with state layers at the benchmark cell's geometry
+    (jamba2-3b-chat-closed): kernel cases, then the compiled programs."""
+    records = run_child("hybrid", args, timeout=1200)
+    summary = next(r for r in records if r["kind"] == "summary")
+    problems = []
+    for c in (r for r in records if r["kind"] == "case"):
+        say(f"phase=H kernel={c['kernel']} {c['case']}: "
+            + (f"max_err={c['max_err']:.3g} of tol {c['tol']:.3g} "
+               f"first_call_s={c['first_call_s']}" if "max_err" in c
+               else f"FAILED {c.get('error')}"))
+        if not c["ok"]:
+            problems.append(f"{c['kernel']} {c['case']}")
+    if summary["interpret"] != [args.rehearse_cpu]:
+        problems.append(
+            f"pallas_call was traced with interpret={summary['interpret']}")
+    out = {"cases": sum(r["kind"] == "case" for r in records)}
+    for r in (r for r in records if r["kind"] == "program"):
+        # What the CPU's compiler copies says nothing of the chip's layouts:
+        # the rehearsal holds the programs to their jaxprs alone.
+        compiled = [] if args.rehearse_cpu else r["pool_ops"] + r["state_copies"]
+        moved = r["scans"] + r["state_scans"] + compiled
+        say(f"phase=H program={r['program']} temp_bytes={r['temp_bytes']} "
+            f"pool_bytes={r['pool_bytes']} state_bytes={r['state_bytes']} "
+            f"kernels={r['kernels']} moving_ops={len(moved)} "
+            f"compile_s={r['seconds']}")
+        for m in moved:
+            say(f"phase=H   {r['program']} moves the pool or the state: {m}")
+        if moved:
+            problems.append(f"{r['program']}: {len(moved)} op(s) move the "
+                            "pool or the state")
+        if (not args.rehearse_cpu and r["temp_bytes"] is not None
+                and r["temp_bytes"] >= r["state_bytes"]):
+            problems.append(f"{r['program']}: {r['temp_bytes']} B of "
+                            f"temporaries, the state is {r['state_bytes']} B")
+        out[f"{r['program']}_temp_bytes"] = r["temp_bytes"]
+    if problems:
+        raise PhaseFailed("; ".join(problems))
+    return out
+
+
 def phase_four_chips(args, preset) -> dict:
     cpu = args.rehearse_cpu
     out = {}
@@ -779,6 +921,7 @@ def main() -> int:
             expect_impl="pallas", shared_prefix=True),
         "C": lambda: phase_kernels(args, preset),
         "P": lambda: phase_pool(args, preset),
+        "H": lambda: phase_hybrid(args, preset),
         "D": lambda: phase_four_chips(args, preset),
     }
 

@@ -495,8 +495,13 @@ def test_period_account_closes_on_its_spans():
         cfg, params, scheduler="continuous", kv_mode="paged", page_size=16,
         prefix_cache=True,
     )
-    handles = [eng.submit([Message.user(p)], 24, GREEDY) for p in MIXED]
-    time.sleep(0.3)  # the segment is under way: these two join it
+    handles = [eng.submit([Message.user(p)], 64, GREEDY) for p in MIXED]
+    # Once the segment is seen under way, these two join it. (A fixed sleep
+    # of 0.3 s missed it whenever the programs were already compiled in
+    # this process and the machine was quick: the segment was over.)
+    deadline = time.monotonic() + 30
+    while handles[0].completion_tokens < 2 and time.monotonic() < deadline:
+        time.sleep(0.002)
     handles += [
         eng.submit([Message.user("joiner " + p)], 8, GREEDY) for p in MIXED[:2]
     ]

@@ -425,3 +425,53 @@ def test_cell_geometry_compiles_for_v5e_without_pool_copies(
     report = cell_reports[program]
     assert report["scans"] == [] and report["pool_ops"] == [], report
     assert report["temp_bytes"] < report["pool_bytes"] // 8, report
+
+
+# ------------- a model with state layers, at its cell's geometry, for a v5e
+#
+# jamba2-3b-chat-closed (bench/configs/ai21-jamba2-3b.json): the page pool of
+# the 2 attention layers AND the lane state of the 26 state layers ride the
+# scans' carries (models/llama/hybrid.py). The same compile also shows that
+# Mosaic takes the paged kernels at one KV head under a group of 20 query
+# heads (a block of 20 rows): both are in each program.
+
+
+@pytest.fixture(scope="module")
+def hybrid_reports(one_chip):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench/configs/ai21-jamba2-3b.json")) as f:
+        config = dataclasses.replace(
+            LlamaConfig.from_hf_dict(json.load(f)), attention_impl="pallas"
+        )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        with jax.default_matmul_precision("default"):
+            return pool_audit.audit_hybrid_programs(
+                config, n_pages=1024, page_size=128, lanes=32, table_pages=8,
+                n_steps=8, join_width=512, sharding=one_chip,
+            )
+
+
+@pytest.mark.parametrize("program", ["decode", "join"])
+def test_hybrid_cell_compiles_for_v5e_without_pool_or_state_copies(
+    program, hybrid_reports
+):
+    report = hybrid_reports[program]
+    assert report["scans"] == [] and report["pool_ops"] == [], report
+    assert report["state_scans"] == [] and report["state_copies"] == [], report
+    assert report["kernels"] == 2, report  # one a layer of attention
+    # 298 MB of state, 134 MB of pool: a second copy of either would show
+    assert report["state_bytes"] == 32 * 9_318_400
+    assert report["temp_bytes"] < report["state_bytes"] // 2, report
+
+
+def test_hybrid_audit_names_a_state_that_is_scanned():
+    """The audit's own reading, on a walk that does it wrong: the state as
+    a scanned input and a stacked output."""
+    ssm = jnp.zeros((6, 4, 4, 128), jnp.float32)
+
+    def wrong(ssm):
+        return jax.lax.scan(lambda c, s: (c, s + 1.0), 0.0, ssm)[1]
+
+    found = pool_audit.scans_moving_pool(jax.make_jaxpr(wrong)(ssm), ssm.shape)
+    assert len(found) == 2 and "scanned input" in found[0]
