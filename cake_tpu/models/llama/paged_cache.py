@@ -12,8 +12,9 @@ a pool of fixed-size pages shared by every lane:
 
 The layout is **head-major inside a page** (n_kv before page_size), exactly the
 dense cache's stride order, so one page is one contiguous
-``page_size * head_dim`` strip per KV head and the paged decode kernel
-(ops/pallas/paged_attention.py) streams it as a single block DMA.
+``page_size * head_dim`` strip per KV head, and all KV heads of a page one
+contiguous block that the paged decode kernel (ops/pallas/paged_attention.py)
+copies with a single DMA.
 
 HBM committed = pages actually holding live tokens (rounded up to the page),
 not ``batch * max_seq`` — a pool sized well below the dense footprint admits

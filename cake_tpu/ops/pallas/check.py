@@ -599,6 +599,69 @@ def run_checks(geom: Geometry) -> dict:
     return {"results": c.results, "interpret": seen}
 
 
+def timed_paged_decode(
+    n_q: int,
+    n_kv: int,
+    head_dim: int,
+    page_size: int,
+    lanes: int,
+    layers: int,
+    dtype: str = "bf16",
+    table_pages: tuple[int, ...] = (4, 8, 16, 32),
+    live_tokens: int = 450,
+    calls: int = 256,
+    repeats: int = 3,
+) -> list[dict]:
+    """``paged_decode_attention`` alone, as a decode step calls it: ``lanes``
+    rows against a 5-D pool of ``layers`` layers (the layer changes from call
+    to call), at each table width once with every page of the table live
+    (``full_us``) and once with ``live_tokens`` live slots a row
+    (``live_us``), in microseconds a call. ``calls`` dependent calls make one
+    program (each call's output is the next one's query), timed on the host
+    clock around ``block_until_ready``; the fastest of ``repeats``. A kernel
+    that does a row's live pages reads flat in ``table_pages`` at
+    ``live_us``."""
+    from cake_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    n_pages = lanes * max(table_pages)
+    pool = (layers, n_pages, n_kv, page_size, head_dim)
+    k_pool = jax.random.normal(jax.random.PRNGKey(3), pool, dt)
+    v_pool = jax.random.normal(jax.random.PRNGKey(4), pool, dt)
+    q0 = jax.random.normal(jax.random.PRNGKey(5), (lanes, 1, n_q, head_dim), dt)
+    perm = np.random.default_rng(0).permutation(n_pages)
+
+    @jax.jit
+    def chain(q, k_pool, v_pool, lengths, tables):
+        return jax.lax.fori_loop(
+            0, calls,
+            lambda i, q: paged_decode_attention(
+                q, k_pool, v_pool, lengths, tables, layer=i % layers
+            ),
+            q,
+        )
+
+    def us_a_call(tables, length):
+        args = (q0, k_pool, v_pool, jnp.full((lanes,), length, jnp.int32), tables)
+        _timed(chain, *args)  # compile + warm
+        fastest = min(_timed(chain, *args)[1] for _ in range(repeats))
+        return round(fastest / calls * 1e6, 1)
+
+    rows = []
+    for n_p in table_pages:
+        tables = jnp.asarray(
+            perm[: lanes * n_p].reshape(lanes, n_p), jnp.int32
+        )
+        slots = n_p * page_size
+        live = min(live_tokens, slots)
+        rows.append({
+            "table_pages": n_p, "live_tokens": live,
+            "full_us": us_a_call(tables, slots),
+            "live_us": us_a_call(tables, live),
+        })
+    return rows
+
+
 def timed_matmul_chain(n: int, steps: int, repeats: int = 3) -> dict:
     """A chain of ``steps`` dependent [n, n] bf16 matmuls, timed on the host
     clock around ``block_until_ready``. Returns the FLOPs and the fastest

@@ -3,27 +3,38 @@
 The paged sibling of ops/pallas/decode_attention.py: one decode query per
 sequence attends over that sequence's live prefix, but KV bytes live in a
 shared page pool ([n_layers, n_pages, n_kv, page_size, head_dim], the
-models/llama/paged_cache.py layout) and each sequence's pages are scattered —
-the kernel walks them in logical order through a block table delivered as a
-scalar-prefetch operand. The LAYER is one more scalar-prefetch operand: the
-model's layer scan carries the whole pool and the index maps pick the layer,
-so no layer is ever sliced out of the pool to be read.
+models/llama/paged_cache.py layout) and each sequence's pages are scattered.
 
-What carries over from the dense kernel, because it is the same bandwidth
-argument:
+**The grid is the rows of the batch** (times blocks of KV heads only where
+one page of all heads overruns the kernel's VMEM budget: no model served
+today). K and V pools stay where they are (``pl.ANY``: no block of them is
+pipelined by Pallas), and one grid step does one row: it reads the row's
+live window ``[start, length)`` and its block table from the scalar-prefetch
+operands, and walks the row's LIVE pages in logical order in a ``fori_loop``,
+copying each page by hand (``pltpu.make_async_copy``) into a ring of VMEM
+page buffers, the next pages in flight while this one is scored. So:
 
-  * **Length pruning.** Per-sequence lengths arrive via scalar prefetch; grid
-    steps for logical pages outside the live [start, length) window clamp
-    their K/V index maps into the live page range, so Mosaic's pipeline skips
-    the repeated fetch — a sequence at position p costs O(p) HBM bytes, not
-    O(max_pages * page_size).
-  * **Grouped streaming.** All ``group`` query heads sharing a KV head score
-    in one [group, page_size] matmul per page: each KV byte is read once.
+  * **No step for a dead page.** A row costs its own live pages, whatever
+    the width of the table the backend ships: a sequence at position p costs
+    O(p) HBM bytes and O(p) work, not O(max_pages * page_size) grid steps.
+  * **All KV heads of a page at once.** A page's copy is
+    ``pool[layer, page]``: ``[n_kv, page_size, head_dim]``, one descriptor
+    for K and one for V, scored as one product batched over the KV heads
+    (all ``group`` query heads of a KV head in one ``[rows, page_size]``
+    matmul: each KV byte is read once).
+  * **The ring's depth follows the shapes**: as many page buffers as
+    ``_KV_BUFFER_BYTES`` holds of this call's pages (``n_kv * page_size *
+    head_dim`` of the pool's dtype, K and V), at least two, at most the
+    table's width; a pool whose page is larger than half the budget is
+    walked a block of KV heads at a time, the largest divisor of ``n_kv``
+    that fits. Nothing names a model and no caller chooses.
 
-What is new: the K/V index maps read ``block_tables[seq, page]`` — the
-physical page — instead of the logical block index. An UNMAPPED entry (< 0,
-possible only for garbage lanes whose output nobody reads) clamps to page 0:
-finite garbage, no OOB DMA.
+The LAYER is one more scalar-prefetch operand: the model's layer scan carries
+the whole pool and the copies pick the layer, so no layer is ever sliced out
+of the pool to be read. An UNMAPPED table entry (< 0, possible only for
+garbage lanes whose output nobody reads) clamps to page 0: finite garbage, no
+OOB DMA. Scores and accumulators are float32; the softmax runs online over
+the pages in logical order.
 
 ``paged_decode_attention_xla`` is the gather-based fallback (interpret/CPU and
 the numerical oracle): it reconstructs each row's dense head-major view via
@@ -38,6 +49,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -45,7 +57,15 @@ from cake_tpu.models.llama.paged_cache import gather_pages
 from cake_tpu.ops.attention import gqa_attention_hm, widen_qkv
 
 _LANES = 128
-_MIN_ROWS = 8  # pad the query-group dim up to a full sublane tile
+_SUBLANES = 8  # the query-group dim is padded up to whole sublane tiles
+# VMEM one call's ring of K and V page buffers may take, of the 16 MiB a
+# v5e core gives a kernel by default (q, the scores and the accumulators of
+# one page are well under 1 MiB beside it).
+_KV_BUFFER_BYTES = 4 * 1024 * 1024
+# A key outside [start, length) scores this, not -inf: exp() of it is an
+# exact 0 beside any live key, and a row with no live key at all (a garbage
+# lane) still comes out finite.
+_MASKED = -0.7 * float(np.finfo(np.float32).max)
 
 
 def _paged_decode_kernel(
@@ -54,65 +74,93 @@ def _paged_decode_kernel(
     tables_ref,
     layer_ref,
     q_ref,
-    k_ref,
-    v_ref,
+    k_hbm,
+    v_hbm,
     o_ref,
-    acc_ref,
-    m_ref,
-    l_ref,
+    k_buf,
+    v_buf,
+    sems,
     *,
     scale,
-    page_size,
     softcap,
 ):
     bi = pl.program_id(0)
-    pi = pl.program_id(2)  # LOGICAL page index; k_ref holds the physical page
+    n_slots, n_heads, page_size, _ = k_buf.shape
+    heads = pl.ds(pl.program_id(1) * n_heads, n_heads)  # this step's KV heads
+    n_p = tables_ref.shape[1]
     length = lens_ref[bi]
     start = starts_ref[bi]
-    k_start = pi * page_size
+    layer = layer_ref[0]
+    # The row's live pages [first, last], held inside the table whatever a
+    # garbage lane's bounds say.
+    last = jnp.minimum(jnp.maximum(length - 1, start) // page_size, n_p - 1)
+    first = jnp.minimum(start // page_size, last)
+    n_live = last - first + 1
 
-    # The first live page (start // page_size) always contains position
-    # ``start`` (callers guarantee start < length), so scratch init happens
-    # exactly once, before any executed update.
-    @pl.when(pi == start // page_size)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def page_copies(i):
+        """The K and V copies of the row's i-th live page into its slot."""
+        slot = jax.lax.rem(i, n_slots)
+        page = jnp.maximum(tables_ref[bi, first + i], 0)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[layer, page, heads], k_buf.at[slot], sems.at[0, slot]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, page, heads], v_buf.at[slot], sems.at[1, slot]
+            ),
+        )
 
-    # Skip pages entirely outside [start, length): the bandwidth win.
-    @pl.when((k_start < length) & (k_start + page_size > start))
-    def _update():
-        q, k, v = widen_qkv(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0])
-        rows = q.shape[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+    def start_page(i, _=None):
+        for copy in page_copies(i):
+            copy.start()
+
+    # Fill the ring but for one slot; each page scored frees the slot of the
+    # page before it for the page n_slots - 1 ahead.
+    jax.lax.fori_loop(0, jnp.minimum(n_live, n_slots - 1), start_page, None)
+
+    def score_page(i, carry):
+        m_prev, l_prev, acc = carry
+
+        @pl.when(i + n_slots - 1 < n_live)
+        def _():
+            start_page(i + n_slots - 1)
+
+        slot = jax.lax.rem(i, n_slots)
+        for copy in page_copies(i):
+            copy.wait()
+        q, k, v = widen_qkv(q_ref[...], k_buf[slot], v_buf[slot])
+        s = jax.lax.dot_general(  # [n_kv, rows, page_size]
+            q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         s = s * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
-        kpos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1
+        kpos = (first + i) * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2
         )
-        s = jnp.where((kpos >= start) & (kpos < length), s, -jnp.inf)
+        s = jnp.where((kpos >= start) & (kpos < length), s, _MASKED)
 
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        pv = jax.lax.dot_general(  # [n_kv, rows, head_dim]
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        # The first live page always executes, so writing the running result
-        # on every live page leaves the final value in the output block.
-        o_ref[0, 0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+        return m_new, l_new, acc * alpha + pv
+
+    stat = q_ref.shape[:2] + (1,)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_live, score_page,
+        (
+            jnp.full(stat, _MASKED, jnp.float32),
+            jnp.zeros(stat, jnp.float32),
+            jnp.zeros(q_ref.shape, jnp.float32),
+        ),
+    )
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
 def _apply_window(starts, lengths, window, window_flag):
@@ -193,7 +241,7 @@ def paged_decode_attention(
         )
     n_p = block_tables.shape[1]
     group = n_q // n_kv
-    rows = max(group, _MIN_ROWS)
+    rows = -(-group // _SUBLANES) * _SUBLANES
     if scale is None:
         scale = d**-0.5
     if interpret is None:
@@ -211,45 +259,37 @@ def paged_decode_attention(
     starts = _apply_window(starts, lengths, window, window_flag)
     block_tables = jnp.asarray(block_tables, jnp.int32)
 
-    # Dead grid steps must not cost DMA: clamp the LOGICAL page into the live
-    # range before the table lookup, so consecutive dead steps resolve to the
-    # same physical page and Mosaic skips the repeated fetch (the dense
-    # kernel's clamp, with one extra indirection). Unmapped entries clamp to
-    # physical page 0 — finite garbage for lanes whose output nobody reads.
-    def _kv_index(bi, hi, pi, lens, st, tables, lyr):
-        first_live = st[bi] // page_size
-        last_live = jnp.maximum(
-            (lens[bi] + page_size - 1) // page_size - 1, 0
-        )
-        phys = tables[bi, jnp.clip(pi, first_live, last_live)]
-        return (lyr[0], jnp.maximum(phys, 0), hi, 0, 0)
-
-    def _q_index(bi, hi, pi, lens, st, tables, lyr):
-        return (bi, hi, 0, 0)
-
-    grid = (b, n_kv, n_p)
+    # One page of all KV heads, K and V, is the unit the kernel copies and
+    # scores; the ring holds as many as the budget takes of THIS pool's pages.
+    # Only where two such pages overrun the budget (many KV heads, a long or
+    # wide page) a step takes a block of the heads, the largest that fits.
+    head_bytes = 2 * page_size * d * k_pages.dtype.itemsize
+    n_heads = max(
+        h for h in range(1, n_kv + 1)
+        if n_kv % h == 0 and (h == 1 or 2 * h * head_bytes <= _KV_BUFFER_BYTES)
+    )
+    n_slots = int(np.clip(
+        _KV_BUFFER_BYTES // (n_heads * head_bytes), 2, max(n_p, 2)
+    ))
+    page_buf = pltpu.VMEM((n_slots, n_heads, page_size, d), k_pages.dtype)
+    q_spec = pl.BlockSpec(
+        (None, n_heads, rows, d), lambda bi, hi, *_: (bi, hi, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=grid,
+        grid=(b, n_kv // n_heads),
         in_specs=[
-            pl.BlockSpec((1, 1, rows, d), _q_index),
-            pl.BlockSpec((None, 1, 1, page_size, d), _kv_index),
-            pl.BlockSpec((None, 1, 1, page_size, d), _kv_index),
+            q_spec,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, rows, d), _q_index),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
+            page_buf, page_buf, pltpu.SemaphoreType.DMA((2, n_slots)),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel,
-            scale=scale,
-            page_size=page_size,
-            softcap=softcap,
-        ),
+        functools.partial(_paged_decode_kernel, scale=scale, softcap=softcap),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, rows, d), q.dtype),
         interpret=interpret,

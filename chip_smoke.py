@@ -107,6 +107,7 @@ PRESETS = {
         dtype="f32", max_seq_len=256, prompts=(12, 40, 150),
         shared_prefix=100, new_tokens=8, page_size=128, chunk=32,
         int4_group=64, batches=(1, 2), matmul=(256, 4),
+        timed_decode=dict(table_pages=(2, 4), calls=2, repeats=1),
         pool=dict(layers=3, pages=64, lanes=2, table_pages=2, steps=4,
                   join_width=64),
         hybrid=dict(
@@ -275,6 +276,7 @@ def child_kernels(preset: dict) -> None:
         Geometry,
         run_checks,
         timed_matmul_chain,
+        timed_paged_decode,
     )
     from cake_tpu.obs import jitwatch
     from cake_tpu.utils.device import describe_devices, setup_compile_cache
@@ -294,6 +296,12 @@ def child_kernels(preset: dict) -> None:
     ))
     for rec in out["results"]:
         emit({"kind": "case", **rec})
+    emit({"kind": "timed", "rows": timed_paged_decode(
+        m["num_attention_heads"], m["num_key_value_heads"],
+        m["head_dim_override"], preset["page_size"], preset["pool"]["lanes"],
+        preset["pool"]["layers"], preset["dtype"],
+        **preset.get("timed_decode", {}),
+    )})
     chain = timed_matmul_chain(*preset["matmul"])
     peaks = device_peaks()  # raises on an accelerator it has no peaks for
     emit({
@@ -345,7 +353,11 @@ def child_hybrid(preset: dict) -> None:
 
     from cake_tpu.models.llama import pool_audit
     from cake_tpu.models.llama.config import LlamaConfig
-    from cake_tpu.ops.pallas.check import HybridGeometry, run_hybrid_checks
+    from cake_tpu.ops.pallas.check import (
+        HybridGeometry,
+        run_hybrid_checks,
+        timed_paged_decode,
+    )
     from cake_tpu.utils.device import describe_devices, setup_compile_cache
 
     setup_compile_cache()
@@ -368,6 +380,12 @@ def child_hybrid(preset: dict) -> None:
     emit({"kind": "summary", **device,
           "interpret": sorted(set(out["interpret"])),
           "pallas_calls": len(out["interpret"])})
+    emit({"kind": "timed", "rows": timed_paged_decode(
+        config.num_attention_heads, config.num_key_value_heads,
+        config.head_dim, preset["page_size"], g["lanes"],
+        len(config.layers_of("attention")), preset["dtype"],
+        **preset.get("timed_decode", {}),
+    )})
     reports = pool_audit.audit_hybrid_programs(
         config, n_pages=g["pages"], page_size=preset["page_size"],
         lanes=g["lanes"], table_pages=g["table_pages"], n_steps=g["steps"],
@@ -710,6 +728,18 @@ def phase_native(args, preset) -> dict:
     return {"built": built}
 
 
+def _say_timed_decode(phase: str, records: list, args) -> None:
+    """``check.timed_paged_decode``'s rows; a time is a device's, so the
+    rehearsal walks the path and prints none."""
+    if args.rehearse_cpu:
+        return
+    for r in next(r for r in records if r["kind"] == "timed")["rows"]:
+        say(f"phase={phase} paged_decode_attention alone, "
+            f"table_pages={r['table_pages']}: {r['full_us']} us a call with "
+            f"every page live, {r['live_us']} us at {r['live_tokens']} live "
+            "tokens a lane")
+
+
 def phase_kernels(args, preset) -> dict:
     records = run_child("kernels", args, timeout=900)
     cases = [r for r in records if r["kind"] == "case"]
@@ -731,6 +761,7 @@ def phase_kernels(args, preset) -> dict:
         for c in k["failed"]:
             say(f"phase=C   FAILED {name} {c['case']}: "
                 f"{c.get('error') or 'max_err %.3g > tol %.3g' % (c['max_err'], c['tol'])}")
+    _say_timed_decode("C", records, args)
     chain = summary["matmul"]
     if summary["peak_tflops"]:  # a rate is a device's; the cpu gets none
         say(f"phase=C bf16 matmul chain on {summary['device_kind']}: "
@@ -810,6 +841,7 @@ def phase_hybrid(args, preset) -> dict:
     if summary["interpret"] != [args.rehearse_cpu]:
         problems.append(
             f"pallas_call was traced with interpret={summary['interpret']}")
+    _say_timed_decode("H", records, args)
     out = {"cases": sum(r["kind"] == "case" for r in records)}
     for r in (r for r in records if r["kind"] == "program"):
         # What the CPU's compiler copies says nothing of the chip's layouts:

@@ -210,3 +210,109 @@ def test_layer_argument_must_match_the_rank():
         paged_decode_attention(
             q, kp[None], vp[None], lengths, bt, pads, interpret=True
         )
+
+
+_BIG = np.int32(2**30)  # a key position no query reaches
+
+
+def ragged_rows(n_q, n_kv, n_p, padded, pool_rank, seed, hd=32):
+    """Four rows on one table of ``n_p`` pages: one live token, the full
+    table, one token into the second page, a random length; with ``padded``
+    the live windows start past slot 0 (the full row's past its first
+    page). Only live pages are mapped, in a scattered physical order; the
+    pool is one layer (rank 4) or three with layer 2 the one to read."""
+    rng = np.random.default_rng(seed)
+    full = n_p * PS
+    lengths = np.asarray([1, full, PS + 1, rng.integers(2, full)], np.int32)
+    starts = np.zeros(4, np.int32)
+    if padded:
+        starts = np.asarray(
+            [0, PS + 3, 5, rng.integers(0, lengths[3])], np.int32
+        )
+    first, last = starts // PS, (lengths - 1) // PS
+    n_live = int((last - first + 1).sum())
+    phys = iter(rng.permutation(n_live + 3))
+    tables = np.full((4, n_p), -1, np.int32)
+    for r in range(4):
+        for p in range(first[r], last[r] + 1):
+            tables[r, p] = next(phys)
+    shape = (n_live + 3, n_kv, PS, hd)
+    layer = None
+    if pool_rank == 5:
+        shape, layer = (3,) + shape, jnp.int32(2)
+    kp = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(4, 1, n_q, hd)), jnp.float32)
+    return (q, kp, vp, jnp.asarray(lengths), jnp.asarray(tables),
+            jnp.asarray(starts), layer)
+
+
+@pytest.mark.parametrize("pool_rank", [4, 5])
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("padded", [False, True])
+@pytest.mark.parametrize("n_p", [4, 13, 32])
+@pytest.mark.parametrize("n_q,n_kv", [(32, 8), (20, 1), (8, 8)])
+def test_live_pages_walk_matches_gather_fallback(
+    n_q, n_kv, n_p, padded, window, pool_rank
+):
+    """The kernel walks each row's own live pages, all KV heads of a page
+    at once: the head layouts the served models have (a group of 4 padded to
+    8 rows, one KV head under 20, no grouping), tables narrower and wider
+    than its ring of page buffers, rows from one live token to the whole
+    table, against the gather twin."""
+    q, kp, vp, lengths, tables, starts, layer = ragged_rows(
+        n_q, n_kv, n_p, padded, pool_rank, seed=n_p + n_q
+    )
+    got = paged_decode_attention(
+        q, kp, vp, lengths, tables, starts, layer=layer, window=window,
+        interpret=True,
+    )
+    slots = jnp.arange(n_p * PS, dtype=jnp.int32)[None, :]
+    live = (slots >= starts[:, None]) & (slots < lengths[:, None])
+    want = paged_decode_attention_xla(
+        q, kp, vp, (lengths - 1)[:, None], jnp.where(live, slots, _BIG),
+        tables, window=window, layer=layer,
+    )
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_a_wider_table_with_an_unmapped_tail_changes_no_bit():
+    """A row's work is its live window, not the table's width: the same
+    rows on a table twice as wide, the new half unmapped, give the same
+    bits (a dead page read or scored would show in the rounding)."""
+    q, kp, vp, lengths, tables, starts, _ = ragged_rows(
+        8, 2, 6, padded=True, pool_rank=4, seed=11
+    )
+    wide = jnp.concatenate([tables, jnp.full_like(tables, -1)], axis=1)
+    narrow_out, wide_out = (
+        paged_decode_attention(
+            q, kp, vp, lengths, t, starts, window=300, interpret=True
+        )
+        for t in (tables, wide)
+    )
+    assert np.isfinite(np.asarray(wide_out)).all()
+    np.testing.assert_array_equal(
+        np.asarray(narrow_out), np.asarray(wide_out)
+    )
+
+
+def test_a_page_too_large_for_the_buffers_is_walked_in_head_blocks(monkeypatch):
+    """Where two pages of all KV heads overrun the kernel's VMEM budget, a
+    step takes a block of the heads (here 2 of 6, a ring of two slots under
+    rows of up to five live pages) and nothing else changes."""
+    from cake_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_KV_BUFFER_BYTES", 128 * 1024)
+    q, kp, vp, lengths, tables, starts, _ = ragged_rows(
+        12, 6, 5, padded=True, pool_rank=4, seed=17
+    )
+    got = paged_decode_attention(
+        q, kp, vp, lengths, tables, starts, interpret=True
+    )
+    slots = jnp.arange(5 * PS, dtype=jnp.int32)[None, :]
+    live = (slots >= starts[:, None]) & (slots < lengths[:, None])
+    want = paged_decode_attention_xla(
+        q, kp, vp, (lengths - 1)[:, None], jnp.where(live, slots, _BIG),
+        tables,
+    )
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
