@@ -1693,28 +1693,27 @@ def main(argv: list[str] | None = None) -> int:
         args.model, attention_impl=args.attention_impl
     )
     if config.has_state_layers:
-        # THE one capability check for models with state layers, before any
-        # weight is read and any backend chosen (models/llama/hybrid.py).
+        # What a model with state layers refuses (``hybrid.REFUSED``), asked
+        # before any weight is read and any backend chosen.
         from cake_tpu.models.llama.hybrid import (
             UnsupportedWithStateLayers,
             refuse_unsupported,
         )
 
         try:
-            refuse_unsupported(config, {
-                "the single-stream generator (no --api with --api-batch > 1)":
-                    not (args.api and args.api_batch > 1),
-                "--kv-mode dense": args.kv_mode != "paged",
-                "--prefix-cache on": args.prefix_cache == "on",
-                "--draft-model": args.draft_model is not None,
-                "--speculative-k": bool(args.speculative_k),
-                "--tp": args.tp > 1,
-                "--sp": args.sp > 1,
-                "--topology (pipeline and distributed backends)":
-                    topology is not None or args.backend is not None,
-                "--distributed": bool(args.distributed),
-                "--quantize": bool(args.quantize),
-            })
+            refuse_unsupported(
+                config,
+                single_stream=not (args.api and args.api_batch > 1),
+                kv_mode_dense=args.kv_mode != "paged",
+                prefix_cache=args.prefix_cache == "on",
+                draft_model=args.draft_model is not None,
+                speculative_k=bool(args.speculative_k),
+                tp=args.tp > 1,
+                sp=args.sp > 1,
+                topology=topology is not None or args.backend is not None,
+                distributed=bool(args.distributed),
+                quantize=bool(args.quantize),
+            )
         except UnsupportedWithStateLayers as e:
             print(f"cake-tpu: {e}", file=sys.stderr)
             return 2
@@ -1976,10 +1975,13 @@ def _run_leader(
             )
             from cake_tpu.utils.device import cpu_requested
 
-            if getattr(engine.backend, "hybrid", False) and not cpu_requested():
-                # A hybrid model's programs are a closed set: run each once
-                # now, so that none is traced while streams are live
-                # (PagedLocalBackend.warm_programs; ``GET /stats`` startup).
+            if (
+                engine.backend.shapes.programs(args.api_batch)
+                and not cpu_requested()
+            ):
+                # The backend's programs are a closed set (runtime/shapes.py):
+                # run each once now, so that none is traced while streams are
+                # live (``warm_programs``; ``GET /stats`` startup).
                 startup["warm"] = engine.backend.warm_programs(
                     args.api_batch, sampling, args.decode_chunk
                 )

@@ -508,9 +508,7 @@ def load_params(
     if config.has_state_layers:
         from cake_tpu.models.llama.hybrid import refuse_unsupported
 
-        refuse_unsupported(
-            config, {"a worker's layer range (--topology)": layer_range is not None}
-        )
+        refuse_unsupported(config, layer_range=layer_range is not None)
         params = {
             "embed": reader.jax("model.embed_tokens.weight", dtype),
             "layers": load_hybrid_layers(reader, config, dtype),

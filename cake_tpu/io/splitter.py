@@ -72,7 +72,7 @@ def split_model(
         from cake_tpu.models.llama.hybrid import refuse_unsupported
 
         refuse_unsupported(
-            LlamaConfig.from_model_dir(model_dir), {"cake-split-model": True}
+            LlamaConfig.from_model_dir(model_dir), split_model=True
         )
     reader = open_checkpoint(model_dir)
 

@@ -209,9 +209,7 @@ class LocalForwardStep(FusedDecodeCapability):
         if self._kv is None:
             from cake_tpu.models.llama.hybrid import refuse_unsupported
 
-            refuse_unsupported(
-                self.config, {"the single-stream generator": True}
-            )
+            refuse_unsupported(self.config, single_stream=True)
         if self.rolling:
             room = self._kv.max_seq_len - self.config.sliding_window
             if tokens.shape[1] > room:

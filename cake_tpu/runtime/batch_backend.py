@@ -73,6 +73,7 @@ from cake_tpu.parallel.tensor import (
     place_tp_model,
     validate_tp,
 )
+from cake_tpu.runtime.shapes import ProgramShapes
 
 # Compiled fused-decode scans per (n_steps, sampling knobs): bounded like the
 # local path's lru_cache'd _decode_fn — per-request sampling overrides on a
@@ -178,8 +179,8 @@ def _cache_get_or_build(cache: OrderedDict, key, build):
 def _local_join_fn(config, width, max_seq_len, cache_dtype):
     """Jit one continuous-batching join: single-row prefill whose prompt ends
     at the epoch's shared slot, scattered wholesale into the free lane's KV
-    row (stale lane contents are fully replaced). One compile per 64-bucketed
-    window width."""
+    row (stale lane contents are fully replaced). One compile per window width
+    (``shapes.window``)."""
 
     def run(params, kv, tokens, pads1, ends1, lane):
         kv_row = init_cache(
@@ -207,6 +208,8 @@ def _local_join_fn(config, width, max_seq_len, cache_dtype):
 
 class LocalBatchBackend:
     """Single-device batch ops: the engine's default."""
+
+    shapes = ProgramShapes()  # dense backends: the open instance
 
     def __init__(
         self,
@@ -256,7 +259,8 @@ class LocalBatchBackend:
             self.params, kv, tok, jnp.int32(slot), pads, keys, ring, ring_idx
         )
 
-    def join(self, kv, row_tokens, pads1, ends1, lane):
+    def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
+        assert start == 0, "a dense join computes its row from slot 0"
         fn = _local_join_fn(
             self.config, row_tokens.shape[1], self.max_seq_len, self.cache_dtype
         )
@@ -295,7 +299,7 @@ def _paged_join_fn(config, width, allow_pallas=True):
     """Jit one PAGED continuous-batching join: the single-row prefill writes
     straight through the joining lane's block-table row into the shared pool
     (no detached row cache, no wholesale scatter — the lane's freshly mapped
-    pages ARE the destination). One compile per 64-bucketed window width."""
+    pages ARE the destination). One compile per window width."""
     from cake_tpu.models.llama.batch import paged_prefill
 
     def run(params, kv, tokens, pads1, ends1, lane_table):
@@ -312,40 +316,11 @@ def _paged_join_fn(config, width, allow_pallas=True):
     )
 
 
-# Window widths and decode capacities a model with state layers is compiled
-# for, as shares of a lane's table. The attention-only path keys its programs
-# by every 16-multiple of a prompt bucket, every 64-multiple of a join's slot
-# and every 256-multiple of a capacity, and a server meets new ones for as
-# long as it runs (PERF.md: 40% of Mistral's window is compile stall). A
-# hybrid program is five scans and compiles in 3 to 10 s, so its windows come
-# in eleven widths (an epoch's right-padded with a dead tail that ``ends``
-# marks: the recurrence stands still there and the scan skips it; a joiner's
-# as wide as its prompt) and read the lane's WHOLE table row (a dead page is a
-# grid step the attention kernel skips), and its decode programs in three
-# capacities (``set_epoch_capacity``): a few dozen programs in all, and in
-# the steady state eleven joins and a decode. Closing the set for every model
-# is a ``perf_opt`` of its own (ROADMAP S2); these shares are where it starts.
-HYBRID_WIDTH_64THS = (1, 2, 4, 8, 12, 16, 24, 32, 40, 48, 64)
-HYBRID_CAPACITY_QUARTERS = (1, 2, 4)
-
-
-def hybrid_shape_sets(
-    page_size: int, pages_per_seq: int
-) -> tuple[tuple[int, ...], tuple[int, ...]]:
-    """(window widths in slots, decode capacities in pages) for a table of
-    ``pages_per_seq`` pages: the shares above of the table, widths rounded up
-    to the engine's join bucket of 64. At 32 pages of 128 (``--max-seq-len
-    4096``) they are 64, 128, 256, 512, 768, 1024, 1536, 2048, 2560, 3072,
-    4096 slots and 8, 16, 32 pages: the sets ``jamba2-3b-chat-closed`` was
-    measured with. At any other geometry the set is as closed."""
-    slots = page_size * pages_per_seq
-    widths = {min(slots, -(-slots * f // (64 * 64)) * 64) for f in HYBRID_WIDTH_64THS}
-    pages = {max(1, -(-pages_per_seq * q // 4)) for q in HYBRID_CAPACITY_QUARTERS}
-    return tuple(sorted(widths)), tuple(sorted(pages))
-
-
-class PagedLocalBackend:
-    """Single-device batch ops over the paged KV pool (``kv_mode="paged"``).
+class _PagedBackend:
+    """Single-device batch ops over the paged KV pool (``kv_mode="paged"``):
+    what every leaf keeps, whatever else its cache holds. A leaf per cache
+    kind adds the programs (``init_kv`` / ``prefill`` / ``decode`` /
+    ``join``, and what else its cache can do); ``paged_backend`` picks it.
 
     Same four-operation seam as LocalBatchBackend, with storage routed
     through a page pool + host-side PageAllocator (models/llama/paged_cache):
@@ -354,33 +329,22 @@ class PagedLocalBackend:
     admits by free pages (runtime/serving.py). The engine owns the allocation
     protocol (map at layout/join, extend at page boundaries, release on
     finish); this backend reads ``self.allocator.block_tables`` at each
-    dispatch and ships it as a small traced int32 operand.
-
-    With a prefix cache attached (``attach_prefix_cache``,
-    runtime/prefix_cache.py) the pool becomes PERSISTENT: ``init_kv`` keeps
-    the retained device pool (``retain_kv`` at epoch end) and releases only
-    the lane mappings, so cached chains' pages — and their bytes — survive
-    across epochs; ``suffix_prefill`` computes just a prompt's uncached tail
-    over forked chains, and ``cow_copy`` is the device half of the
-    make-private split.
-
-    Speculative verify runs through the paged cached-chunk arithmetic
-    (batch.paged_verify_logits — the same grids as ``suffix_prefill``), so
-    the engine's capability gate no longer auto-disables speculation under
-    ``kv_mode="paged"``.
+    dispatch and ships it as a small traced int32 operand. Which programs
+    get compiled is ``self.shapes`` (runtime/shapes.py): the backend owns
+    its instance, the engine reads it from here.
 
     **Bounded capacity** (``set_epoch_capacity``): the serving engine
-    computes ONE bucketed live capacity per epoch — enough slots for every
-    admitted row's maximum reach plus a chunk of slack — and every dispatch
-    slices the block-table operand to it. Attention grids, position masks,
-    and the XLA gather view then cover the live capacity instead of the
-    padded ``max_seq`` table width. The capacity is deliberately backend
-    STATE set once per epoch, not a per-op argument: every cache-enabled
-    prefill (epoch suffix prefill, joins, failover re-prefills) MUST run
-    under the same capacity or the bit-identity chain across joins and
-    failover breaks at the ulp level on real hardware (reduction shapes
-    change with the gather width) — and a per-op "local" capacity smaller
-    than the epoch's silently truncates live keys
+    computes ONE live capacity per epoch (``shapes.capacity``) — enough
+    slots for every admitted row's maximum reach plus a chunk of slack — and
+    every dispatch slices the block-table operand to it. Attention grids,
+    position masks, and the XLA gather view then cover the live capacity
+    instead of the padded ``max_seq`` table width. The capacity is
+    deliberately backend STATE set once per epoch, not a per-op argument:
+    every cache-enabled prefill (epoch suffix prefill, joins, failover
+    re-prefills) MUST run under the same capacity or the bit-identity chain
+    across joins and failover breaks at the ulp level on real hardware
+    (reduction shapes change with the gather width) — and a per-op "local"
+    capacity smaller than the epoch's silently truncates live keys
     (tests/test_paged_prefill.py pins the trap). None = the full table.
     """
 
@@ -419,21 +383,12 @@ class PagedLocalBackend:
             max_pages_per_seq=self.pages_per_seq,
             reserve_pages=page_reserve,
         )
-        self.prefix_cache = None
-        self._retained_kv = None
         self.allow_pallas = allow_pallas
         # Epoch-bounded table capacity in PAGES (None = full table).
         self._cap_pages: int | None = None
         self._fallback_noted = False
-        # A model with state layers (config.layer_kinds; models/llama/
-        # hybrid.py): the cache value is a HybridCache — the page pool of
-        # its attention layers and the lane state of the others — and the
-        # four operations run the by-run walk. Same engine, same allocator,
-        # same kernels; the suffix/verify/copy-on-write operations are not
-        # defined for it (hybrid.refuse_unsupported keeps callers away).
-        self.hybrid = config.has_state_layers
-        self.hybrid_widths, self.hybrid_capacity_pages = hybrid_shape_sets(
-            page_size, self.pages_per_seq
+        self.shapes = ProgramShapes.for_model(
+            config, page_size, self.pages_per_seq
         )
         # Lanes whose recurrent state a prefill or a join wrote, cumulative
         # (``GET /stats`` engine.state.lane_writes).
@@ -459,15 +414,24 @@ class PagedLocalBackend:
             return "fallback"
         return "pallas"
 
-    def _kernel_note(self, op: str) -> None:
-        """Timeline breadcrumb per paged dispatch (the trace-smoke gate
-        reads these to prove the kernel path engaged) plus a ONE-TIME
-        ``kernel-fallback`` flight event when a paged path silently
+    def _kernel_note(self, op: str, end_slot: int) -> None:
+        """Every paged dispatch starts here. A timeline breadcrumb (the
+        trace-smoke gate reads these to prove the kernel path engaged), a
+        ONE-TIME ``kernel-fallback`` flight event when a paged path silently
         downgrades to XLA (attention_impl wanted pallas, pool layout says
-        no)."""
+        no), and the write bound: a write past the sliced table would DROP
+        silently (take_along_axis fill) and corrupt the stream, so fail
+        loudly instead; the engine's capacity formula is supposed to make
+        this unreachable."""
         from cake_tpu.obs.timeline import timeline
         from cake_tpu.utils import metrics
 
+        if end_slot > self.capacity_slots():
+            raise ValueError(
+                f"paged {op} writes through slot {end_slot} but the epoch "
+                f"capacity is {self.capacity_slots()} slots — the engine's "
+                "one-capacity-per-epoch bound was violated"
+            )
         impl = self.kernel_impl()
         if impl == "fallback" and not self._fallback_noted:
             self._fallback_noted = True
@@ -484,24 +448,13 @@ class PagedLocalBackend:
 
     def set_epoch_capacity(self, capacity_slots: int | None) -> None:
         """Bound every dispatch's block-table operand to ``capacity_slots``
-        (rounded up to whole pages); None restores the full table. The
-        serving engine calls this ONCE per epoch — or once per SEGMENT
-        under the continuous scheduler, whose per-step dispatches (joins,
-        restores of spilled lanes, decode chunks) all run under the same
-        bound — see the class docstring for why the capacity must not vary
-        within one."""
+        (rounded up to whole pages); None restores the full table. ONCE per
+        epoch, or per segment under the continuous scheduler: why it must
+        not vary within one is in the class docstring."""
         if capacity_slots is None:
             self._cap_pages = None
             return
         pages = -(-int(capacity_slots) // self.page_size)
-        if self.hybrid:
-            # One of three capacities. (Not one: the capacity is also what
-            # ends a segment, and a segment that began with one request has
-            # two lanes for as long as it lives — with the whole table as
-            # every epoch's capacity the cell served 140 tokens/s, not 500.)
-            pages = next(
-                (p for p in self.hybrid_capacity_pages if p >= pages), pages
-            )
         self._cap_pages = max(1, min(pages, self.pages_per_seq))
 
     def capacity_slots(self) -> int:
@@ -510,36 +463,11 @@ class PagedLocalBackend:
             return self.padded_seq
         return self._cap_pages * self.page_size
 
-    def _check_write_bound(self, op: str, end_slot: int) -> None:
-        # A write past the sliced table would DROP silently (take_along_axis
-        # fill) and corrupt the stream — fail loudly instead: the engine's
-        # capacity formula is supposed to make this unreachable.
-        if end_slot > self.capacity_slots():
-            raise ValueError(
-                f"paged {op} writes through slot {end_slot} but the epoch "
-                f"capacity is {self.capacity_slots()} slots — the engine's "
-                "one-capacity-per-epoch bound was violated"
-            )
-
-    def _tables(self) -> jnp.ndarray:
-        tables = self.allocator.block_tables
-        if self._cap_pages is not None:
-            tables = tables[:, : self._cap_pages]
-        return jnp.asarray(tables)
-
-    def _lane_table(self, lane: int) -> jnp.ndarray:
-        tables = self.allocator.block_tables[lane : lane + 1]
-        if self._cap_pages is not None:
-            tables = tables[:, : self._cap_pages]
-        return jnp.asarray(tables)
-
-    def attach_prefix_cache(self, cache) -> None:
-        """Switch the pool to PERSISTENT mode for the engine's prefix cache
-        (runtime/prefix_cache.py): epochs stop zeroing it."""
-        from cake_tpu.models.llama.hybrid import refuse_unsupported
-
-        refuse_unsupported(self.config, {"--prefix-cache on": True})
-        self.prefix_cache = cache
+    def _tables(self, lane: int | None = None) -> jnp.ndarray:
+        """The block-table operand under the epoch's capacity: every lane's
+        row, or one lane's."""
+        rows = slice(None) if lane is None else slice(lane, lane + 1)
+        return jnp.asarray(self.allocator.block_tables[rows, : self._cap_pages])
 
     def state_facts(self) -> dict:
         """``GET /stats`` engine.state: the recurrent state beside the page
@@ -547,13 +475,69 @@ class PagedLocalBackend:
         the current epoch's lanes hold; ``lane_writes`` is cumulative."""
         from cake_tpu.models.llama.config import STATE
 
-        per_lane = self.config.state_bytes_per_lane if self.hybrid else 0
+        per_lane = self.config.state_bytes_per_lane
         return {
             "layers": len(self.config.layers_of(STATE)),
             "bytes_per_lane": per_lane,
             "bytes": per_lane * self._state_lanes,
             "lane_writes": self.state_lane_writes,
         }
+
+    def warm_programs(self, lanes: int, sampling, n_steps: int) -> dict:
+        """Run ``shapes.programs(lanes)`` once each, on a scratch cache. A
+        server that did this at start-up traces and loads none of them while
+        it serves: each is a stall of every live stream otherwise (0.4 s
+        from the persistent cache, 3 to 10 s without). Nothing is mapped, so
+        no K or V is written. {programs, seconds}."""
+        import time
+
+        t0 = time.perf_counter()
+        programs = self.shapes.programs(lanes)
+        cache = self.init_kv(lanes)
+        zeros = jnp.zeros((lanes,), jnp.int32)
+        keys = jnp.stack([jax.random.PRNGKey(0)] * lanes)
+        ring = jnp.zeros((lanes, sampling.repeat_last_n), jnp.int32)
+        for op, rows, slots in programs:
+            blank = np.zeros((rows, slots), np.int32)
+            if op == "prefill":
+                _, cache = self.prefill(blank, cache, zeros)
+            elif op == "join":
+                _, cache = self.join(
+                    cache, blank, zeros[:1], jnp.asarray([slots], jnp.int32), 0
+                )
+            else:
+                self.set_epoch_capacity(slots)
+                cache = self.decode(
+                    cache, zeros, 0, zeros, keys, ring, zeros, n_steps, sampling
+                )[1]
+        jax.block_until_ready(cache)
+        self.set_epoch_capacity(None)
+        self.allocator.reset(batch=1)
+        self.state_lane_writes = 0
+        return {
+            "programs": len(programs),
+            "seconds": round(time.perf_counter() - t0, 3),
+        }
+
+
+class PagedLocalBackend(_PagedBackend):
+    """The attention-only leaf: the cache is the page pool alone.
+
+    With a prefix cache attached (``attach_prefix_cache``,
+    runtime/prefix_cache.py) the pool becomes PERSISTENT: ``init_kv`` keeps
+    the retained device pool (``retain_kv`` at epoch end) and releases only
+    the lane mappings, so cached chains' pages — and their bytes — survive
+    across epochs; ``suffix_prefill`` computes just a prompt's uncached tail
+    over forked chains, and ``cow_copy`` is the device half of the
+    make-private split."""
+
+    prefix_cache = None
+    _retained_kv = None
+
+    def attach_prefix_cache(self, cache) -> None:
+        """Switch the pool to PERSISTENT mode for the engine's prefix cache
+        (runtime/prefix_cache.py): epochs stop zeroing it."""
+        self.prefix_cache = cache
 
     def retain_kv(self, kv) -> None:
         """Epoch end (persistent mode): keep the final pool buffer so the
@@ -579,19 +563,6 @@ class PagedLocalBackend:
                 return kv
         else:
             self.allocator.reset(batch=b)
-        if self.hybrid:
-            from cake_tpu.models.llama.hybrid import init_hybrid_cache
-            from cake_tpu.utils import metrics
-
-            self._state_lanes = b
-            metrics.registry.gauge(
-                "cake_state_bytes",
-                "Recurrent state the epoch's lanes hold beside the KV pool.",
-            ).set(b * self.config.state_bytes_per_lane)
-            return init_hybrid_cache(
-                self.config, b, self.max_pages, self.page_size,
-                self.cache_dtype,
-            )
         return init_paged_cache(
             self.config.num_hidden_layers,
             self.max_pages,
@@ -608,122 +579,12 @@ class PagedLocalBackend:
         if ends is not None:
             ends = jnp.asarray(ends, jnp.int32)
             kw = {"ends": ends, "seq_len": ends[0]}
-        self._kernel_note("prefill")
-        self._check_write_bound("prefill", int(jnp.shape(tokens)[1]))
-        if self.hybrid:
-            return self._hybrid_prefill(tokens, kv, pads, ends)
+        self._kernel_note("prefill", int(jnp.shape(tokens)[1]))
         return _paged_prefill_jit(
             self.params, jnp.asarray(tokens), kv, jnp.asarray(pads),
             self._tables(), self.config,
             allow_pallas=self.allow_pallas, **kw,
         )
-
-    # Tokens one prefill program of a model with state layers may hold: the
-    # mixer's float32 intermediates are [rows, width, d_inner] several times
-    # over, so a whole epoch of 32 lanes x 2080 slots would need 8.6 GB of
-    # temporaries beside 6.5 GB of arguments (compiled for a described
-    # v5e); 16k tokens need 2.1 to 2.7 GB.
-    HYBRID_PREFILL_TOKENS = 16384
-
-    def _hybrid_width(self, width: int) -> int:
-        """The narrowest of ``hybrid_widths`` that holds ``width`` slots."""
-        return next((w for w in self.hybrid_widths if w >= width), width)
-
-    def _hybrid_prefill(self, tokens, kv, pads, ends=None):
-        """An epoch's prefill in groups of rows (a power of two each), every
-        group one program that writes its own lanes' K, V and state."""
-        from cake_tpu.models.llama.hybrid import _hybrid_prefill_jit
-
-        tokens = np.asarray(tokens)
-        b, width = tokens.shape
-        ends = jnp.asarray(
-            np.full((b,), width, np.int32) if ends is None else ends, jnp.int32
-        )
-        tokens = jnp.asarray(
-            np.pad(tokens, ((0, 0), (0, self._hybrid_width(width) - width)))
-        )
-        pads = jnp.asarray(pads)
-        group = b
-        while group > 1 and group * tokens.shape[1] > self.HYBRID_PREFILL_TOKENS:
-            group //= 2
-        tables = jnp.asarray(self.allocator.block_tables)  # uncut: see above
-        self.state_lane_writes += b
-        logits = []
-        for lo in range(0, b, group):
-            rows = slice(lo, lo + group)
-            out, kv = _hybrid_prefill_jit(
-                self.params, tokens[rows], kv, pads[rows], ends[rows],
-                tables[rows], self.config, lane=lo,
-                allow_pallas=self.allow_pallas,
-            )
-            logits.append(out)
-        return (logits[0] if len(logits) == 1 else jnp.concatenate(logits)), kv
-
-    def _hybrid_join(self, kv, row_tokens, pads1, ends1, lane):
-        """The engine hands a joiner's row left-padded from slot 0 to the
-        batch's shared slot; a hybrid model's join computes only a window
-        as wide as the PROMPT (one of ``hybrid_widths``) that ends at the slot
-        (``hybrid.hybrid_prefill``). A prompt longer than the slot's reach
-        starts at 0 and leaves a dead tail."""
-        from cake_tpu.models.llama.hybrid import _hybrid_join_fn
-
-        row_tokens = np.asarray(row_tokens)
-        pad, slot = int(np.asarray(pads1)[0]), int(np.asarray(ends1)[0])
-        width = self._hybrid_width(slot - pad)
-        start = max(0, slot - width)
-        window = np.zeros((1, width), np.int32)
-        window[0, : slot - start] = row_tokens[0, start:slot]
-        self.state_lane_writes += 1
-        fn = _hybrid_join_fn(self.config, width, self.allow_pallas)
-        return fn(
-            self.params, kv, jnp.asarray(window),
-            jnp.asarray([pad], jnp.int32), jnp.asarray([slot], jnp.int32),
-            jnp.asarray(self.allocator.block_tables[lane : lane + 1]),
-            jnp.int32(start), jnp.int32(lane),
-        )
-
-    def warm_programs(self, lanes: int, sampling, n_steps: int) -> dict:
-        """Run once, on a scratch cache, every program a saturated server of
-        a hybrid model dispatches at ``lanes`` lanes: an epoch's prefill and
-        a join at each window width, a decode chunk at each capacity. The
-        set is closed (``hybrid_shape_sets``), so a server
-        that did this at start-up traces and loads none of them while it
-        serves: each is a stall of every live stream otherwise (0.4 s from
-        the persistent cache, 3 to 10 s without). Nothing is mapped, so no
-        K or V is written; the scratch cache is dropped. {programs, seconds}."""
-        import time
-
-        t0 = time.perf_counter()
-        widths = self.hybrid_widths
-        cache = self.init_kv(lanes)
-        zeros = np.zeros((lanes,), np.int32)
-        for width in widths:
-            _, cache = self.prefill(
-                np.zeros((lanes, width), np.int32), cache, zeros
-            )
-            _, cache = self.join(
-                cache, np.zeros((1, width), np.int32),
-                jnp.zeros((1,), jnp.int32), jnp.asarray([width], jnp.int32), 0,
-            )
-        keys = jnp.stack([jax.random.PRNGKey(0)] * lanes)
-        window = sampling.repeat_last_n
-        capacities = self.hybrid_capacity_pages
-        for pages in capacities:
-            self.set_epoch_capacity(pages * self.page_size)
-            out = self.decode(
-                cache, jnp.asarray(zeros), 0, jnp.asarray(zeros), keys,
-                jnp.zeros((lanes, window), jnp.int32), jnp.asarray(zeros),
-                n_steps, sampling,
-            )
-            cache = out[1]
-        jax.block_until_ready(cache)
-        self.set_epoch_capacity(None)
-        self.allocator.reset(batch=1)
-        self.state_lane_writes = 0
-        return {
-            "programs": 2 * len(widths) + len(capacities),
-            "seconds": round(time.perf_counter() - t0, 3),
-        }
 
     def suffix_prefill(self, tokens, kv, pads, write_starts, start):
         """Prefix-cache prefill: compute only the window [start, start + W)
@@ -732,12 +593,10 @@ class PagedLocalBackend:
         prefill routes here — cold epochs included, with start at the
         youngest pad — so warm and cold runs share ONE attention arithmetic
         and greedy streams stay bit-identical (the fresh-chunk path's
-        reduction differs at the ulp level). One compile per 64-bucketed
-        width."""
+        reduction differs at the ulp level). One compile per width."""
         from cake_tpu.models.llama.batch import _paged_suffix_jit
 
-        self._kernel_note("suffix_prefill")
-        self._check_write_bound(
+        self._kernel_note(
             "suffix_prefill", int(start) + int(jnp.shape(tokens)[1])
         )
         return _paged_suffix_jit(
@@ -758,15 +617,14 @@ class PagedLocalBackend:
         class docstring)."""
         from cake_tpu.models.llama.batch import _paged_suffix_join_jit
 
-        self._kernel_note("suffix_join")
-        self._check_write_bound(
+        self._kernel_note(
             "suffix_join", int(start) + int(jnp.shape(row_tokens)[1])
         )
         return _paged_suffix_join_jit(
             self.params, jnp.asarray(row_tokens), kv,
             jnp.asarray(pads1, jnp.int32),
             jnp.asarray(write_starts1, jnp.int32),
-            self._lane_table(lane), self.config, jnp.int32(start),
+            self._tables(lane), self.config, jnp.int32(start),
             allow_pallas=self.allow_pallas,
         )
 
@@ -782,27 +640,8 @@ class PagedLocalBackend:
     def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
         from cake_tpu.models.llama.batch import _paged_decode_fn
 
-        self._kernel_note("decode")
+        self._kernel_note("decode", int(slot) + n)
         _note_fusion_kernels(self, s)
-        self._check_write_bound("decode", int(slot) + n)
-        if self.hybrid:
-            from cake_tpu.models.llama.hybrid import _hybrid_decode_fn
-
-            fn = _hybrid_decode_fn(
-                self.config, self.capacity_slots(), n,
-                s.temperature, s.top_k, s.top_p, s.repeat_penalty,
-                allow_pallas=self.allow_pallas,
-            )
-            # A lane is live while it holds pages: the engine releases a
-            # finished row's pages at once and maps a joiner's before its
-            # prefill, and spare lanes never hold any. The same fact that
-            # drops a dead lane's K/V writes keeps its state.
-            b = int(jnp.shape(tok)[0])
-            valid = (self.allocator.block_tables[:b] >= 0).any(axis=1)
-            return fn(
-                self.params, kv, tok, jnp.int32(slot), pads, self._tables(),
-                jnp.asarray(valid), keys, ring, ring_idx,
-            )
         # Position grids size to the epoch capacity, not the padded max_seq
         # — the decode twin of the bounded gather view (one compile per
         # capacity bucket; steady state within an epoch never retraces).
@@ -816,17 +655,15 @@ class PagedLocalBackend:
             keys, ring, ring_idx,
         )
 
-    def join(self, kv, row_tokens, pads1, ends1, lane):
-        self._kernel_note("join")
-        self._check_write_bound("join", int(np.asarray(ends1).max()))
-        if self.hybrid:
-            return self._hybrid_join(kv, row_tokens, pads1, ends1, lane)
+    def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
+        assert start == 0, "the plain paged join computes its row from slot 0"
+        self._kernel_note("join", int(np.asarray(ends1).max()))
         fn = _paged_join_fn(
             self.config, row_tokens.shape[1], self.allow_pallas
         )
         return fn(
             self.params, kv, jnp.asarray(row_tokens), pads1, ends1,
-            self._lane_table(lane),
+            self._tables(lane),
         )
 
     # Speculative verify through the paged cached-chunk arithmetic — the
@@ -836,8 +673,7 @@ class PagedLocalBackend:
     def verify_greedy(self, kv, tokens, slot, pads):
         from cake_tpu.models.llama.batch import _paged_verify_greedy_fn
 
-        self._kernel_note("verify")
-        self._check_write_bound("verify", int(slot) + tokens.shape[1])
+        self._kernel_note("verify", int(slot) + tokens.shape[1])
         fn = _paged_verify_greedy_fn(
             self.config, tokens.shape[1], self.allow_pallas
         )
@@ -849,8 +685,7 @@ class PagedLocalBackend:
     def verify_sampled(self, kv, tokens, slot, pads, drafts, n_drafts, keys, s):
         from cake_tpu.models.llama.batch import _paged_verify_sampled_fn
 
-        self._kernel_note("verify")
-        self._check_write_bound("verify", int(slot) + tokens.shape[1])
+        self._kernel_note("verify", int(slot) + tokens.shape[1])
         fn = _paged_verify_sampled_fn(
             self.config, tokens.shape[1], s.temperature, s.top_k, s.top_p,
             self.allow_pallas,
@@ -860,6 +695,109 @@ class PagedLocalBackend:
             jnp.int32(slot), self._tables(), jnp.asarray(drafts),
             jnp.asarray(n_drafts, jnp.int32), keys,
         )
+
+
+class PagedHybridBackend(_PagedBackend):
+    """The same for a model with state layers (models/llama/hybrid.py): the
+    cache is a ``HybridCache``, the page pool of its attention layers and the
+    lane state of the others, and the four operations run the by-run walk.
+    It has no suffix, verify or copy-on-write operation and takes no prefix
+    cache: that absence is what the engine's capability gates read
+    (``hybrid.REFUSED`` says why). Its prefill and join read a lane's WHOLE
+    table row: a dead page is a grid step the attention kernel skips."""
+
+    hybrid = True
+
+    def init_kv(self, b: int):
+        from cake_tpu.models.llama.hybrid import init_hybrid_cache
+        from cake_tpu.utils import metrics
+
+        self.allocator.reset(batch=b)
+        self._state_lanes = b
+        metrics.registry.gauge(
+            "cake_state_bytes",
+            "Recurrent state the epoch's lanes hold beside the KV pool.",
+        ).set(b * self.config.state_bytes_per_lane)
+        return init_hybrid_cache(
+            self.config, b, self.max_pages, self.page_size, self.cache_dtype,
+        )
+
+    def prefill(self, tokens, kv, pads, ends=None):
+        """An epoch's prefill in groups of rows, every group one program
+        that writes its own lanes' K, V and state."""
+        from cake_tpu.models.llama.hybrid import _hybrid_prefill_jit
+
+        tokens = np.asarray(tokens)
+        b, width = tokens.shape
+        self._kernel_note(
+            "prefill", width if ends is None else int(np.max(ends))
+        )
+        ends = jnp.asarray(
+            np.full((b,), width, np.int32) if ends is None else ends, jnp.int32
+        )
+        tokens = jnp.asarray(np.pad(
+            tokens, ((0, 0), (0, self.shapes.program_width(width) - width))
+        ))
+        pads = jnp.asarray(pads)
+        group = self.shapes.prefill_group(b, tokens.shape[1])
+        tables = jnp.asarray(self.allocator.block_tables)
+        self.state_lane_writes += b
+        logits = []
+        for lo in range(0, b, group):
+            rows = slice(lo, lo + group)
+            out, kv = _hybrid_prefill_jit(
+                self.params, tokens[rows], kv, pads[rows], ends[rows],
+                tables[rows], self.config, lane=lo,
+                allow_pallas=self.allow_pallas,
+            )
+            logits.append(out)
+        return (logits[0] if len(logits) == 1 else jnp.concatenate(logits)), kv
+
+    def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
+        from cake_tpu.models.llama.hybrid import _hybrid_decode_fn
+
+        self._kernel_note("decode", int(slot) + n)
+        _note_fusion_kernels(self, s)
+        fn = _hybrid_decode_fn(
+            self.config, self.capacity_slots(), n,
+            s.temperature, s.top_k, s.top_p, s.repeat_penalty,
+            allow_pallas=self.allow_pallas,
+        )
+        # A lane is live while it holds pages: the engine releases a
+        # finished row's pages at once and maps a joiner's before its
+        # prefill, and spare lanes never hold any. The same fact that
+        # drops a dead lane's K/V writes keeps its state.
+        b = int(jnp.shape(tok)[0])
+        valid = (self.allocator.block_tables[:b] >= 0).any(axis=1)
+        return fn(
+            self.params, kv, tok, jnp.int32(slot), pads, self._tables(),
+            jnp.asarray(valid), keys, ring, ring_idx,
+        )
+
+    def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
+        """One row's window [start, start + width) as the engine cut it
+        (``shapes.window``) into lane ``lane``: K and V through its table
+        row, the state from zero (``hybrid.hybrid_prefill``)."""
+        from cake_tpu.models.llama.hybrid import _hybrid_join_fn
+
+        self._kernel_note("join", int(np.asarray(ends1).max()))
+        self.state_lane_writes += 1
+        fn = _hybrid_join_fn(
+            self.config, row_tokens.shape[1], self.allow_pallas
+        )
+        return fn(
+            self.params, kv, jnp.asarray(row_tokens),
+            jnp.asarray(pads1, jnp.int32), jnp.asarray(ends1, jnp.int32),
+            jnp.asarray(self.allocator.block_tables[lane : lane + 1]),
+            jnp.int32(start), jnp.int32(lane),
+        )
+
+
+def paged_backend(config: LlamaConfig, params: M.Params, **kw) -> _PagedBackend:
+    """The paged backend of this model's cache kind: the one place the kind
+    is chosen (the shapes are chosen beside it, ``ProgramShapes.for_model``)."""
+    leaf = PagedHybridBackend if config.has_state_layers else PagedLocalBackend
+    return leaf(config, params, **kw)
 
 
 class TPBatchBackend:
@@ -872,6 +810,8 @@ class TPBatchBackend:
     numerics are the local path's, shard count only changes the reduction
     order.
     """
+
+    shapes = ProgramShapes()
 
     def __init__(
         self,
@@ -1009,7 +949,8 @@ class TPBatchBackend:
 
         return jax.jit(run, donate_argnums=(2,))
 
-    def join(self, kv, row_tokens, pads1, ends1, lane):
+    def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
+        assert start == 0, "a dense join computes its row from slot 0"
         return self._join(
             self.head_params, self.layer_params, kv,
             jnp.asarray(row_tokens), pads1, ends1, jnp.int32(lane),
@@ -1187,6 +1128,8 @@ class PipelineBatchBackend:
         KV stays the shared full-batch cache: groups read/write their row
         window in place (batch.batched_blocks_forward row_offset mode).
     """
+
+    shapes = ProgramShapes()
 
     def __init__(
         self,
@@ -1410,7 +1353,8 @@ class PipelineBatchBackend:
         )
         return logits, KVCache(k=k, v=v)
 
-    def join(self, kv, row_tokens, pads1, ends1, lane):
+    def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
+        assert start == 0, "a dense join computes its row from slot 0"
         return self._join_jit(
             self._weights, kv, jnp.asarray(row_tokens), pads1, ends1,
             jnp.int32(lane),
@@ -1762,6 +1706,8 @@ class DistributedBatchBackend:
     its full-history replay on top of the same per-op machinery.
     """
 
+    shapes = ProgramShapes()
+
     def __init__(self, step, *, max_seq_len: int | None = None,
                  cache_dtype: jnp.dtype = jnp.bfloat16):
         from cake_tpu.parallel.topology import MASTER_NODE
@@ -1994,7 +1940,8 @@ class DistributedBatchBackend:
             out.append(tok)
         return jnp.stack(out, axis=1), kv, keys, ring, ring_idx
 
-    def join(self, kv, row_tokens, pads1, ends1, lane):
+    def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
+        assert start == 0, "a dense join computes its row from slot 0"
         row_tokens = jnp.asarray(row_tokens)
         pads1 = jnp.asarray(pads1, jnp.int32)
         ends1 = jnp.asarray(ends1, jnp.int32)

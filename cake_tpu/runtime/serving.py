@@ -72,7 +72,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from cake_tpu.models.llama import model as M
-from cake_tpu.models.llama.batch import prompt_bucket
 from cake_tpu.models.llama.chat import Message, encode_dialog
 from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.models.llama.generator import SamplingConfig, Token, decode_delta
@@ -100,13 +99,6 @@ __all__ = [
 log = logging.getLogger("cake_tpu.serving")
 
 _DONE = "__done__"
-
-# Epoch attention-capacity granularity (slots): the bounded paged capacity
-# rounds up to this, so compiled-shape variants stay bounded the way 64-slot
-# width bucketing bounds join/suffix windows (coarser here — capacity feeds
-# whole kernel grids, not one window operand).
-_CAPACITY_BUCKET = 256
-
 
 def _set_lane_rows(arrays, lane, values):
     return tuple(a.at[lane].set(v) for a, v in zip(arrays, values))
@@ -591,19 +583,19 @@ class BatchEngine:
         self._fo_count = 0
         self._fo_spent_s = 0.0
         if config.has_state_layers:
-            # The one capability check (models/llama/hybrid.py), again where
-            # a programmatic engine chooses its backend.
+            # ``hybrid.REFUSED`` again, with the facts of a programmatic
+            # engine (the CLI asked before it read a weight).
             from cake_tpu.models.llama.hybrid import refuse_unsupported
 
-            refuse_unsupported(config, {
-                "--kv-mode dense": kv_mode != "paged",
-                "--prefix-cache on": bool(serve and serve.prefix_cache),
-                "--speculative-k": bool(speculative_k),
-                "--draft-model": proposer_factory is not None,
-                "a backend other than the local paged one (--tp, pipeline, "
-                "distributed)": backend is not None
+            refuse_unsupported(
+                config,
+                kv_mode_dense=kv_mode != "paged",
+                prefix_cache=bool(serve and serve.prefix_cache),
+                speculative_k=bool(speculative_k),
+                draft_model=proposer_factory is not None,
+                other_backend=backend is not None
                 and not getattr(backend, "hybrid", False),
-            })
+            )
         if backend is None:
             if params is None:
                 # Fail here, not later inside a jitted prefill with an opaque
@@ -614,10 +606,10 @@ class BatchEngine:
                     "backend) or an explicit backend="
                 )
             if kv_mode == "paged":
-                from cake_tpu.runtime.batch_backend import PagedLocalBackend
+                from cake_tpu.runtime.batch_backend import paged_backend
 
                 pages_per_seq = -(-self.max_seq_len // serve.page_size)
-                backend = PagedLocalBackend(
+                backend = paged_backend(
                     config, params,
                     max_seq_len=self.max_seq_len, cache_dtype=cache_dtype,
                     page_size=serve.page_size,
@@ -639,6 +631,9 @@ class BatchEngine:
                 f"provided {type(backend).__name__} is dense"
             )
         self.backend = backend
+        # Which programs get compiled (runtime/shapes.py): the backend owns
+        # the answer, every width and capacity below is asked of it.
+        self.shapes = backend.shapes
         # Thread the wire-resilience knobs into a TCP backend's live
         # clients (ServeConfig is the ONE config surface; without this the
         # fields would validate and then silently do nothing for
@@ -1311,7 +1306,7 @@ class BatchEngine:
         # Left-pad bucket rounding can add slots ahead of the prompt; require
         # room for the bucket plus at least one generated token. Same helper
         # as the actual layout (models/llama/batch.py) so they cannot drift.
-        bucket_ceiling = prompt_bucket(len(ids), self.max_seq_len)
+        bucket_ceiling = self.shapes.prompt_width(len(ids), self.max_seq_len)
         if bucket_ceiling >= self.max_seq_len:
             raise ValueError(
                 f"prompt is {len(ids)} tokens but the context window "
@@ -1989,7 +1984,7 @@ class BatchEngine:
             capw = self.max_seq_len
             if hasattr(self.backend, "capacity_slots"):
                 capw = min(capw, self.backend.capacity_slots())
-            W = min(-(-slot // 64) * 64, capw)
+            _, W = self.shapes.window(0, slot, capw)
             tokens = np.zeros((B, W), np.int32)
             pads = np.full((B,), slot - 1, np.int32)
             # Dummy/finished lanes carry a 1-token bos window: garbage
@@ -2082,7 +2077,7 @@ class BatchEngine:
             end = (
                 end_slot
                 if end_slot is not None
-                else prompt_bucket(n, self.max_seq_len)
+                else self.shapes.prompt_width(n, self.max_seq_len)
             )
             served = self._prefix.match_tokens(
                 req.prompt_ids, (end - n) % self._alloc.page_size
@@ -2261,9 +2256,9 @@ class BatchEngine:
             # alignment (the same estimate _pages_for prices admission
             # with): requests extending the same cached chain share a key.
             n = len(r.prompt_ids)
-            align = (prompt_bucket(n, self.max_seq_len) - n) % (
-                self._alloc.page_size
-            )
+            align = (
+                self.shapes.prompt_width(n, self.max_seq_len) - n
+            ) % self._alloc.page_size
             return self._prefix.radix_key(r.prompt_ids, align)
 
         def defer(r: _Request, cause: str) -> str:
@@ -2524,15 +2519,8 @@ class BatchEngine:
             # this epoch carry the head request's id. An epoch serves many
             # rows; the head id identifies the epoch in worker-side logs.
             self.backend.trace_id = self._epoch_head_rid
-        # Lane count: next pow2 of the group size, doubled once for join
-        # headroom, capped at max_batch — light load must not pay
-        # max_batch-wide prefill/decode, but continuous joins need free
-        # lanes. Compiles stay bounded to log2 variants.
         n_seed = len(batch) or len(seed_spills)
-        B = 1
-        while B < n_seed:
-            B *= 2
-        B = min(max(B * 2, 2), self.max_batch)
+        B = self.shapes.lanes(n_seed, self.max_batch)
         window = s.repeat_last_n
 
         # Lay out the initial group over B fixed lanes; spare lanes carry a
@@ -2576,15 +2564,11 @@ class BatchEngine:
 
         tokens, pads, bucket = layout_prompts(ids_list, self.max_seq_len)
         # ONE bounded attention capacity for the whole epoch (paged backends
-        # only): enough slots for every admitted row's full token budget,
-        # bucketed so compiles stay bounded, capped at max_seq_len. Every
-        # position grid, kernel grid, and gather view of the epoch then
-        # covers the live capacity instead of the padded max_seq — the
-        # short-request TTFT win. ``cap`` (the epoch's slot ceiling) clamps
-        # to it below, so joins (_take_joins gates budgets on cap), spec
-        # verify (slot + K + 1 < cap), decode chunks, and failover
-        # re-prefills all stay inside the ONE capacity — vary it mid-epoch
-        # and the bit-identity chain breaks (PagedLocalBackend docstring).
+        # only; why, in their class docstring): enough slots for every
+        # admitted row's full token budget. ``cap`` (the epoch's slot
+        # ceiling) clamps to it below, so joins (_take_joins gates budgets on
+        # cap), spec verify (slot + K + 1 < cap), decode chunks, and failover
+        # re-prefills all stay inside the ONE capacity.
         cap = self.max_seq_len
         if self._alloc is not None and hasattr(
             self.backend, "set_epoch_capacity"
@@ -2599,10 +2583,7 @@ class BatchEngine:
                 min(t, self.max_seq_len - bucket) for t in budgets
             )
             self.backend.set_epoch_capacity(
-                min(
-                    self.max_seq_len,
-                    -(-reach // _CAPACITY_BUCKET) * _CAPACITY_BUCKET,
-                )
+                self.shapes.capacity(reach, self.max_seq_len)
             )
             cap = min(self.max_seq_len, self.backend.capacity_slots())
         t_prefill = time.perf_counter()
@@ -2640,8 +2621,8 @@ class BatchEngine:
                     if write_starts is not None:
                         # Prefix-cache path (cold epochs included): prefill
                         # ONLY the window [start, bucket) covering every
-                        # lane's uncached tail (64-bucketed width so
-                        # compiles stay bounded); writes below each lane's
+                        # lane's uncached tail (``shapes.window`` bounds
+                        # the widths); writes below each lane's
                         # threshold drop, so forked shared pages stay
                         # byte-stable. Cold lanes' thresholds are their
                         # pads — full compute through the SAME cached-chunk
@@ -2651,9 +2632,9 @@ class BatchEngine:
                         # order at the ulp level). Logits land at
                         # bucket - 1, exactly where the cold path reads
                         # them.
-                        start = bucket - min(
-                            -(-(bucket - int(write_starts.min())) // 64) * 64,
-                            bucket,
+                        start, _ = self.shapes.window(
+                            int(write_starts.min()), bucket, bucket,
+                            reads_pool=True,
                         )
                         logits, kv = self._dispatch(
                             "prefill",
@@ -2918,7 +2899,9 @@ class BatchEngine:
                         tok, kv, keys, slot = res
                         period.update(dispatched=True, live=live)
                         continue
-                n = min(self.decode_chunk_size, cap - 1 - slot)
+                n = self.shapes.decode_steps(
+                    self.decode_chunk_size, cap, slot
+                )
                 if self._alloc is not None and not self._extend_pages(
                     rows, slot, n, spill_ctx=(keys, ring_j, ring_idx_j)
                 ):
@@ -3328,7 +3311,10 @@ class BatchEngine:
                 if row.req.knobs() != knobs:
                     continue
                 hist = len(row.history) - 1
-                if prompt_bucket(hist, self.max_seq_len) >= self.max_seq_len:
+                if (
+                    self.shapes.prompt_width(hist, self.max_seq_len)
+                    >= self.max_seq_len
+                ):
                     del self._spilled[row.req.rid]
                     doomed.append(sp)
                     continue
@@ -3500,40 +3486,10 @@ class BatchEngine:
                 "restore", rid=req.rid,
                 args={"lane": lane, "slot": int(slot), "tokens": len(hist)},
             ):
-                if self._alloc is not None and self._prefix is not None:
-                    fresh, pair = self._fork_lane(
-                        lane, req, pad, slot, ids=hist
-                    )
-                    if pair is not None:
-                        kv = self.backend.cow_copy(kv, [pair[0]], [pair[1]])
-                    W = min(-(-(slot - fresh) // 64) * 64, slot)
-                    start = slot - W
-                    row_tokens = np.zeros((1, W), np.int32)
-                    lo = max(pad, start)
-                    row_tokens[0, lo - start: slot - start] = hist[lo - pad:]
-                    _, kv = self._dispatch(
-                        "join",
-                        lambda: self.backend.suffix_join(
-                            kv, row_tokens, np.asarray([pad], np.int32),
-                            np.asarray([fresh], np.int32), lane, start,
-                        ),
-                    )
-                else:
-                    # Same window arithmetic as a plain join (_join_inner):
-                    # W >= slot, pad/slot are absolute.
-                    W = min(-(-slot // 64) * 64, self.max_seq_len)
-                    row_tokens = np.zeros((1, W), np.int32)
-                    row_tokens[0, pad:slot] = hist
-                    if self._alloc is not None:
-                        self._alloc.map_range(lane, pad, slot)
-                    _, kv = self._dispatch(
-                        "join",
-                        lambda: self.backend.join(
-                            kv, row_tokens,
-                            jnp.asarray([pad], jnp.int32),
-                            jnp.asarray([slot], jnp.int32), lane,
-                        ),
-                    )
+                fork = None
+                if self._prefix is not None:
+                    fork = self._fork_lane(lane, req, pad, slot, ids=hist)
+                _, kv, W = self._row_prefill(kv, lane, hist, pad, slot, fork)
         except BaseException as e:
             row.close_span(error=str(e)[:200])
             raise
@@ -3827,7 +3783,8 @@ class BatchEngine:
             # cap - slot tokens: 1 at the join + cap - 1 - slot decoded.
             solo_budget = min(
                 req.max_tokens,
-                self.max_seq_len - prompt_bucket(n_ids, self.max_seq_len),
+                self.max_seq_len
+                - self.shapes.prompt_width(n_ids, self.max_seq_len),
             )
             fits = n_ids <= slot and cap - slot >= solo_budget
             if not fits:
@@ -3887,6 +3844,40 @@ class BatchEngine:
             req.t_admit = t_admit  # join prefill is lane time, not queue
         return out
 
+    def _row_prefill(self, kv, lane: int, ids, pad: int, slot: int, fork):
+        """Re-prefill one row so that it ends at the shared slot: ``ids`` (a
+        joiner's prompt, a restored lane's history) into slots [pad, slot)
+        of lane ``lane``, in the window ``shapes.window`` cuts. ``fork`` is
+        ``_fork_lane``'s answer under a prefix cache: only the window over
+        the uncached tail runs, hit or miss through the one cached-chunk
+        arithmetic (``suffix_join``), so a warm row is bit-identical to a
+        cold one. Without a cache (None) the lane's pages, charged by
+        ``_take_joins``, are mapped over the row and the backend's join
+        runs. Returns (logits, kv, window width)."""
+        if fork is not None:
+            bound, pair = fork  # writes below ``bound`` drop
+            if pair is not None:
+                kv = self.backend.cow_copy(kv, [pair[0]], [pair[1]])
+            start, W = self.shapes.window(bound, slot, slot, reads_pool=True)
+            run = self.backend.suffix_join
+        else:
+            bound = slot  # the row ends here
+            start, W = self.shapes.window(pad, slot, self.max_seq_len)
+            if self._alloc is not None:
+                self._alloc.map_range(lane, pad, slot)
+            run = self.backend.join
+        row_tokens = np.zeros((1, W), np.int32)
+        lo = max(pad, start)
+        row_tokens[0, lo - start : slot - start] = ids[lo - pad :]
+        logits, kv = self._dispatch(
+            "join",
+            lambda: run(
+                kv, row_tokens, np.asarray([pad], np.int32),
+                np.asarray([bound], np.int32), lane, start,
+            ),
+        )
+        return logits, kv, W
+
     def _join(self, req, lane, rows, slot, tok, kv, keys, ring_j, ring_idx_j, s):
         """Prefill one request into a free lane of the RUNNING epoch.
 
@@ -3932,20 +3923,15 @@ class BatchEngine:
             },
         ) as join:
             pad = slot - len(ids)
-            if self._alloc is not None and self._prefix is not None:
+            fork = None
+            if self._prefix is not None:
                 from cake_tpu.models.llama.paged_cache import PageExhausted
 
-                # Prefix-cache join: fork the longest cached chain, map only
-                # the tail, and prefill the window [start, slot) through the
-                # SAME cached-chunk arithmetic as suffix_prefill — writes
-                # below the fresh threshold drop, shared pages stay
-                # byte-stable, and a warm join is bit-identical to a cold
-                # one because hit and miss walk one arithmetic.
                 try:
                     with self._phase(
                         "prefix-fork", args={"lane": lane, "slot": int(slot)}
                     ):
-                        fresh, pair = self._fork_lane(lane, req, pad, slot)
+                        fork = self._fork_lane(lane, req, pad, slot)
                 except PageExhausted:
                     # _take_joins priced this join exactly, but the chain it
                     # was priced against can be reclaimed by an earlier
@@ -3966,42 +3952,7 @@ class BatchEngine:
                     row.finish()
                     self._pool_counter()
                     return tok, kv, keys, ring_j, ring_idx_j
-                if pair is not None:
-                    kv = self.backend.cow_copy(kv, [pair[0]], [pair[1]])
-                W = min(-(-(slot - fresh) // 64) * 64, slot)
-                start = slot - W
-                row_tokens = np.zeros((1, W), np.int32)
-                lo = max(pad, start)
-                row_tokens[0, lo - start : slot - start] = ids[lo - pad :]
-                logits, kv = self._dispatch(
-                    "join",
-                    lambda: self.backend.suffix_join(
-                        kv, row_tokens, np.asarray([pad], np.int32),
-                        np.asarray([fresh], np.int32), lane, start,
-                    ),
-                )
-            else:
-                # Window width bucketed to bound compiles; the prompt ends
-                # at `slot`.
-                W = min(-(-slot // 64) * 64, self.max_seq_len)
-                row_tokens = np.zeros((1, W), np.int32)
-                row_tokens[0, pad:slot] = ids
-                if self._alloc is not None:
-                    # Map the joiner's pages over its prompt window BEFORE
-                    # the join prefill writes through them (_take_joins
-                    # already charged the pool). The lane was released when
-                    # its previous row finished.
-                    self._alloc.map_range(lane, pad, slot)
-                logits, kv = self._dispatch(
-                    "join",
-                    lambda: self.backend.join(
-                        kv,
-                        row_tokens,
-                        jnp.asarray([pad], jnp.int32),
-                        jnp.asarray([slot], jnp.int32),
-                        lane,
-                    ),
-                )
+            logits, kv, W = self._row_prefill(kv, lane, ids, pad, slot, fork)
 
             # Same first-token arithmetic as every entry point (batch.py).
             window = s.repeat_last_n

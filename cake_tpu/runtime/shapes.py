@@ -1,0 +1,147 @@
+"""Which programs a server compiles: the one home of that decision.
+
+A served program's key is lanes x prompt width x capacity x one-row window x
+decode tail. One frozen value type answers for every part; the backend owns
+its instance (``backend.shapes``) and the engine reads it from there. Two
+instances that differ in data, picked by ``for_model`` from the config alone:
+
+  * OPEN (the defaults): prompt widths in 16s (``prompt_bucket``), one-row
+    windows in 64s from slot 0, capacities in 256s. A server meets new
+    programs for as long as it runs (PERF.md: 45% of Mistral's window is
+    compile stall) and runs none ahead.
+  * CLOSED, for a model with state layers, whose programs compile in 3 to 10
+    s each: eleven window widths and three capacities, shares of a lane's
+    table. An epoch's prefill is right-padded to a width (a dead tail under
+    ``ends``: the recurrence stands still there), a joiner's window is as
+    wide as its prompt and ends at the shared slot, and the few dozen
+    programs there are run once at start-up (``programs``).
+
+Closing the set for every model is ROADMAP S2: a ``perf_opt`` that changes
+the open instance's tables and is measured on both cells.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+from cake_tpu.models.llama.batch import prompt_bucket
+
+# The closed tables as shares of a lane's table: widths in 64ths of its
+# slots, capacities in quarters of its pages.
+_WIDTH_64THS = (1, 2, 4, 8, 12, 16, 24, 32, 40, 48, 64)
+_CAPACITY_QUARTERS = (1, 2, 4)
+# Tokens a prefill program of a model with state layers may hold: the mixer's
+# float32 intermediates are [rows, width, d_inner] several times over, so an
+# epoch of 32 lanes x 2080 slots would need 8.6 GB of temporaries beside 6.5
+# GB of arguments (compiled for a described v5e); 16k tokens need 2.1 to 2.7.
+_PREFILL_TOKENS = 16384
+
+
+def _ceil_to(x: int, multiple: int) -> int:
+    return -(-x // multiple) * multiple
+
+
+def _at_least(table: tuple[int, ...], x: int) -> int:
+    """The least entry of an ascending table that holds ``x``; ``x`` itself
+    when none does (an open table is empty)."""
+    return next((t for t in table if t >= x), x)
+
+
+@dataclasses.dataclass(frozen=True)
+class ProgramShapes:
+    window_multiple: int = 64
+    capacity_multiple: int = 256
+    # The closed sets, ascending, in slots; empty = open.
+    widths: tuple[int, ...] = ()
+    capacities: tuple[int, ...] = ()
+    # None: an epoch's prefill is one program, whatever it holds.
+    prefill_tokens: int | None = None
+
+    @classmethod
+    def for_model(cls, config, page_size: int = 0, pages_per_seq: int = 0):
+        """The closed instance for a model with state layers, the open one
+        otherwise (a dense backend has no table to pass). At 32 pages of 128
+        the sets are 64, 128, 256, 512, 768, 1024, 1536, 2048, 2560, 3072,
+        4096 slots and 8, 16, 32 pages (``jamba2-3b-chat-closed``'s); at any
+        other geometry they are as closed."""
+        if not config.has_state_layers:
+            return cls()
+        slots = page_size * pages_per_seq
+        widths = {
+            min(slots, -(-slots * f // (64 * 64)) * 64) for f in _WIDTH_64THS
+        }
+        pages = {max(1, -(-pages_per_seq * q // 4)) for q in _CAPACITY_QUARTERS}
+        return cls(
+            widths=tuple(sorted(widths)),
+            capacities=tuple(p * page_size for p in sorted(pages)),
+            prefill_tokens=_PREFILL_TOKENS,
+        )
+
+    def lanes(self, n_seed: int, max_batch: int) -> int:
+        """Lanes of an epoch seeded with ``n_seed`` rows: the next power of
+        two, doubled once (joins need free lanes), capped (light load must
+        not pay ``max_batch``-wide programs)."""
+        b = 1
+        while b < n_seed:
+            b *= 2
+        return min(max(b * 2, 2), max_batch)
+
+    def prompt_width(self, longest: int, max_seq_len: int) -> int:
+        """The shared left-pad bucket, and so the shared slot, of a batch
+        whose longest prompt is ``longest`` (``layout_prompts`` asks the same
+        helper)."""
+        return prompt_bucket(longest, max_seq_len)
+
+    def program_width(self, bucket: int) -> int:
+        """The width an epoch's prefill program is compiled for."""
+        return _at_least(self.widths, bucket)
+
+    def capacity(self, reach: int, max_seq_len: int) -> int:
+        """An epoch's attention capacity in slots, for rows that reach slot
+        ``reach``. (Closed: three, not one. The capacity also ends a segment,
+        and one that began with one request has two lanes while it lives:
+        with the whole table every time, Jamba's cell served 140 tokens/s,
+        not 500.)"""
+        slots = min(max_seq_len, _ceil_to(reach, self.capacity_multiple))
+        return _at_least(self.capacities, slots)
+
+    def window(
+        self, fresh: int, slot: int, limit: int, *, reads_pool: bool = False
+    ) -> tuple[int, int]:
+        """(start, width) of the program that computes a row's slots
+        [``fresh``, ``slot``) so that it ends at the shared slot. Closed: as
+        wide as that span, ending at the slot; a span the slot cannot hold
+        starts at 0 and leaves a dead tail. Open over the pool's prefix
+        (``reads_pool``: a prefix cache's suffix programs): ending at the
+        slot, never wider than it. Open otherwise: from slot 0, since the
+        plain join takes no start, at most ``limit`` wide."""
+        if self.widths:
+            width = _at_least(self.widths, slot - fresh)
+            return max(0, slot - width), width
+        if reads_pool:
+            width = min(_ceil_to(slot - fresh, self.window_multiple), slot)
+            return slot - width, width
+        return 0, min(_ceil_to(slot, self.window_multiple), limit)
+
+    def decode_steps(self, chunk: int, cap: int, slot: int) -> int:
+        """A chunk, or what is left under the epoch's slot ceiling."""
+        return min(chunk, cap - 1 - slot)
+
+    def prefill_group(self, rows: int, width: int) -> int:
+        """Rows (a power of two) one prefill program takes of an epoch's."""
+        group = rows
+        while self.prefill_tokens and group > 1 and (
+            group * width > self.prefill_tokens
+        ):
+            group //= 2
+        return group
+
+    def programs(self, lanes: int) -> tuple[tuple[str, int, int], ...]:
+        """What a saturated server dispatches at ``lanes`` lanes, as
+        (operation, rows, slots): an epoch's prefill and a join at each
+        width, a decode chunk at each capacity. Empty when open: nothing can
+        be run ahead of a set that has no end."""
+        out = []
+        for width in self.widths:
+            out += [("prefill", lanes, width), ("join", 1, width)]
+        return (*out, *(("decode", lanes, c) for c in self.capacities))

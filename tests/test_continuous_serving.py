@@ -552,3 +552,106 @@ def test_period_account_closes_on_its_spans():
     seg = next(s for s in spans if s["name"] == "segment")
     assert seg["args"]["ended"] in ("capacity", "drained")
     assert {"lanes", "bucket", "capacity", "queue_depth"} <= set(seg["args"])
+
+
+# ------------------------------------------- which programs a session compiles
+
+# (prompt, new tokens), all queued BEFORE the engine starts: the schedule is
+# then a function of the queue alone. On two lanes: an epoch of the first
+# two; the second ends at once and the third joins; the fourth, with the
+# longest prompt, joins a bucket later; the pool is short of what the first
+# and the fourth reach together, so one is preempted and restored.
+SCRIPT = [
+    ("the first stream outlives every other one of the session", 180),
+    ("short", 6),
+    ("a joiner of middling length, here", 40),
+    ("the last joiner has the longest prompt of the three that join, "
+     "longer than the first", 30),
+]
+# A config of its own each (no other test's traces are in the way) and the
+# pool that makes the preemption.
+SESSIONS = {
+    "dense": dict(eps=1.01e-5),
+    "paged": dict(eps=1.02e-5, kv_mode="paged", page_size=16, max_pages=18),
+    "prefix": dict(eps=1.03e-5, kv_mode="paged", page_size=16, max_pages=18,
+                   prefix_cache=True),
+    "hybrid": dict(eps=1.04e-6, kv_mode="paged", page_size=16, max_pages=22),
+}
+TINY_JAMBA = dict(
+    model_type="jamba", hidden_size=64, intermediate_size=128, vocab_size=512,
+    num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=1,
+    attn_layer_period=4, attn_layer_offset=2, mamba_d_state=4, mamba_d_conv=4,
+    mamba_expand=2, mamba_dt_rank=4, mamba_conv_bias=True,
+    mamba_proj_bias=False, num_experts=1, num_experts_per_tok=1,
+    tie_word_embeddings=True, bos_token_id=1, eos_token_id=2, pad_token_id=0,
+    max_position_embeddings=512, sliding_window=None,
+)
+
+
+def scripted_session(case):
+    """(jitwatch's ``compile`` spans of the served programs, in order, as
+    [fn, shapes]; the engine's stats)."""
+    import dataclasses
+
+    from cake_tpu.models.llama import hybrid as H
+    from cake_tpu.obs.timeline import timeline
+
+    kw = dict(SESSIONS[case])
+    eps = kw.pop("eps")
+    if case == "hybrid":
+        cfg = LlamaConfig.from_hf_dict({**TINY_JAMBA, "rms_norm_eps": eps})
+        params = H.init_params(cfg, jax.random.PRNGKey(5), jnp.float32)
+    else:
+        cfg = dataclasses.replace(
+            LlamaConfig.tiny(num_hidden_layers=2), rms_norm_eps=eps
+        )
+        params = M.init_params(cfg, jax.random.PRNGKey(5), jnp.float32)
+    eng = BatchEngine(
+        cfg, params, ByteTokenizer(), max_seq_len=512, cache_dtype=jnp.float32,
+        serve=ServeConfig(
+            max_batch=2, decode_chunk_size=4, admission_window=0.0,
+            scheduler="continuous", **kw,
+        ),
+    )
+    timeline.clear()
+    handles = [eng.submit([Message.user(p)], n, GREEDY) for p, n in SCRIPT]
+    eng.start()
+    try:
+        for h in handles:
+            collect(h)
+        stats = dict(eng.stats)
+    finally:
+        eng.stop()
+    events = timeline.snapshot()
+    assert len(events) < timeline.capacity  # nothing fell off the ring
+    compiles = [
+        [e["args"]["fn"], e["args"]["shapes"]] for e in events
+        if e.get("name") == "compile" and e.get("ph") in ("B", "X")
+        # the served programs; ``first_sample`` is keyed by the sampling
+        # knobs alone, so another test's trace of it would hide this one's
+        and e["args"]["fn"].startswith("batch.")
+        and not e["args"]["fn"].startswith("batch.first_sample")
+    ]
+    return compiles, stats
+
+
+@pytest.mark.parametrize("case", sorted(SESSIONS))
+def test_a_scripted_session_compiles_the_recorded_programs(case):
+    """The guard that an edit of ``runtime/shapes.py`` (or of who asks it)
+    changes which programs get compiled only on purpose: an epoch, two
+    joins of different lengths and a preemption with its restore compile
+    exactly the programs, at exactly the operand shapes, that the commit
+    before ``shapes.py`` compiled (recorded there, ``tests/data/``). When a
+    change of programs IS the purpose (ROADMAP S2), record the list again
+    and say so."""
+    import json
+    from pathlib import Path
+
+    want = json.loads(
+        (Path(__file__).parent / "data" / "served_programs_pr29.json").read_text()
+    )["programs"][case]
+    got, stats = scripted_session(case)
+    assert stats["joins"] == 2
+    if case != "dense":  # dense lanes hold no pages: nothing to preempt
+        assert stats["preemptions"] >= 1 and stats["restores"] >= 1
+    assert got == want

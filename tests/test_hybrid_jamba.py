@@ -29,7 +29,7 @@ from cake_tpu.models.llama.config import SUPPORTED_MODEL_TYPES, LlamaConfig
 from cake_tpu.models.llama.generator import SamplingConfig
 from cake_tpu.models.llama.tokenizer import ByteTokenizer
 from cake_tpu.ops import ssm as S
-from cake_tpu.runtime.batch_backend import PagedLocalBackend
+from cake_tpu.runtime.batch_backend import PagedHybridBackend, paged_backend
 from cake_tpu.runtime.serving import BatchEngine, ServeConfig
 
 REPO = Path(__file__).resolve().parents[1]
@@ -60,10 +60,18 @@ def model(tmp_path_factory):
 
 
 def backend(config, params, **kw):
-    return PagedLocalBackend(
+    be = paged_backend(
         config, params, max_seq_len=128, cache_dtype=jnp.float32,
         page_size=PAGE, max_pages=48, allow_pallas=False, **kw,
     )
+    assert type(be) is PagedHybridBackend  # picked from the config alone
+    return be
+
+
+def with_shapes(be, **fields):
+    """The backend's own instance with some of its tables replaced."""
+    be.shapes = dataclasses.replace(be.shapes, **fields)
+    return be
 
 
 def lay_out(be, prompts, lanes, bucket):
@@ -186,13 +194,12 @@ def test_a_joined_row_and_a_reused_lane_equal_the_row_alone(model):
     assert be.state_facts()["lane_writes"] == 3  # two at the prefill, one join
 
 
-def test_an_epoch_prefill_in_groups_equals_one_program(model, monkeypatch):
+def test_an_epoch_prefill_in_groups_equals_one_program(model):
     config, _, loaded, *_ = model
     rows = prompts(3, 9, 30, 17, 25)
     out = []
     for budget in (1 << 20, 256):  # one program of 4 rows; four of 1 row
-        monkeypatch.setattr(PagedLocalBackend, "HYBRID_PREFILL_TOKENS", budget)
-        be = backend(config, loaded)
+        be = with_shapes(backend(config, loaded), prefill_tokens=budget)
         cache, tokens, pads = lay_out(be, rows, 4, 32)
         logits, cache = be.prefill(tokens, cache, jnp.asarray(pads))
         out.append(jax.tree.map(np.asarray, (logits, cache)))
@@ -204,23 +211,25 @@ def test_an_epoch_prefill_in_groups_equals_one_program(model, monkeypatch):
 
 
 def test_a_join_costs_its_prompt_and_programs_come_in_few_shapes(model, monkeypatch):
-    """The engine hands the joiner left-padded from slot 0 to the shared
-    slot; the hybrid join computes a window as wide as the PROMPT's bucket
-    that ends at the slot. Widths and decode capacities are a fixed few."""
+    """The joiner's window is cut once, by the backend's shapes: as wide as
+    the PROMPT's bucket, ending at the slot, not a row from slot 0. Widths
+    and decode capacities are a fixed few."""
     config, _, loaded, *_ = model
     be = backend(config, loaded)  # max_seq_len 128: 8 pages of 16
-    assert (be.hybrid_widths, be.hybrid_capacity_pages) == ((64, 128), (2, 4, 8))
-    be.hybrid_widths = (16, 32, 64, 128)
-    assert [be._hybrid_width(n) for n in (1, 16, 17, 100, 500)] == [16, 16, 32, 128, 500]
-    be.set_epoch_capacity(40)  # 3 pages of 16 -> 4
+    assert (be.shapes.widths, be.shapes.capacities) == ((64, 128), (32, 64, 128))
+    with_shapes(be, widths=(16, 32, 64, 128), capacity_multiple=8)
+    assert [be.shapes.program_width(n) for n in (1, 16, 17, 100, 500)] == [16, 16, 32, 128, 500]
+    be.set_epoch_capacity(be.shapes.capacity(40, 128))  # 3 pages of 16 -> 4
     assert be.capacity_slots() == 64
     be.set_epoch_capacity(None)
     first, joiner = prompts(5, 60, 11)
     cache, tokens, pads = lay_out(be, [first], 2, 64)
     _, cache = be.prefill(tokens, cache, jnp.asarray(pads))
     slot = 100
-    row = np.zeros((1, 128), np.int32)
-    row[0, slot - 11:slot] = joiner
+    start, width = be.shapes.window(slot - 11, slot, 128)
+    assert (start, width) == (84, 16)
+    row = np.zeros((1, width), np.int32)
+    row[0, slot - 11 - start:slot - start] = joiner
     be.allocator.map_range(1, slot - 11, slot)
     seen = []
     real = H._hybrid_join_fn
@@ -232,9 +241,9 @@ def test_a_join_costs_its_prompt_and_programs_come_in_few_shapes(model, monkeypa
     monkeypatch.setattr(H, "_hybrid_join_fn", recording)
     j_logits, cache = be.join(
         cache, row, jnp.asarray([slot - 11], jnp.int32),
-        jnp.asarray([slot], jnp.int32), 1,
+        jnp.asarray([slot], jnp.int32), 1, start,
     )
-    assert seen == [16]  # not the 128 slots the engine's row spans
+    assert seen == [16]  # not the 128 slots a row from slot 0 would span
     alone = backend(config, loaded)
     a_cache, a_tokens, a_pads = lay_out(alone, [joiner], 2, 16)
     a_logits, a_cache = alone.prefill(a_tokens, a_cache, jnp.asarray(a_pads))
@@ -243,24 +252,11 @@ def test_a_join_costs_its_prompt_and_programs_come_in_few_shapes(model, monkeypa
     np.testing.assert_allclose(cache.ssm[:, 1], a_cache.ssm[:, 0], **near)
 
 
-def test_the_shape_sets_are_shares_of_the_table():
-    """The sets the cell was measured with, at its geometry; as closed at
-    any other page size and length."""
-    from cake_tpu.runtime.batch_backend import hybrid_shape_sets
-
-    assert hybrid_shape_sets(128, 32) == (
-        (64, 128, 256, 512, 768, 1024, 1536, 2048, 2560, 3072, 4096), (8, 16, 32))
-    for page_size, pages in ((16, 8), (64, 64), (128, 2), (256, 8), (128, 1), (128, 64)):
-        widths, capacities = hybrid_shape_sets(page_size, pages)
-        assert 1 <= len(widths) <= 11 and widths[-1] == page_size * pages
-        assert all(w % 64 == 0 or w == page_size * pages for w in widths)
-        assert 1 <= len(capacities) <= 3 and capacities[-1] == pages
-
-
 def test_warm_programs_runs_the_closed_set_and_leaves_nothing(model, monkeypatch):
     config, _, loaded, *_ = model
     be = backend(config, loaded)  # max_seq_len 128: 8 pages of 16
-    be.hybrid_widths, be.hybrid_capacity_pages = (16, 32), (2, 8)
+    with_shapes(be, widths=(16, 32), capacities=(32, 128))
+    assert len(be.shapes.programs(2)) == 2 * 2 + 2
     seen = []
     real = H._hybrid_join_fn
     monkeypatch.setattr(
@@ -275,8 +271,7 @@ def test_warm_programs_runs_the_closed_set_and_leaves_nothing(model, monkeypatch
     rows = prompts(6, 12)
     cache, tokens, pads = lay_out(be, rows, 2, 16)
     logits, _ = be.prefill(tokens, cache, jnp.asarray(pads))
-    fresh = backend(config, loaded)
-    fresh.hybrid_widths = be.hybrid_widths
+    fresh = with_shapes(backend(config, loaded), widths=be.shapes.widths)
     cache, tokens, pads = lay_out(fresh, rows, 2, 16)
     want, _ = fresh.prefill(tokens, cache, jnp.asarray(pads))
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
@@ -473,7 +468,7 @@ def test_refusals_outside_the_cli(model, tmp_path):
         BatchEngine(config, loaded, ByteTokenizer(), max_seq_len=64,
                     cache_dtype=jnp.float32, speculative_k=4,
                     serve=ServeConfig(max_batch=2, kv_mode="paged"))
-    H.refuse_unsupported(LlamaConfig.tiny(), {"--tp": True})  # no state layers: nothing
+    H.refuse_unsupported(LlamaConfig.tiny(), tp=True)  # no state layers: nothing
 
 
 @pytest.mark.parametrize("change,message", [
