@@ -342,6 +342,15 @@ class PageAllocator:
         reuse)."""
         return int((self.block_tables[lane] >= 0).sum())
 
+    def pages_missing(self, lanes: list[int], start_slot: int, end_slot: int) -> int:
+        """How many pages ``map_range`` over the same slots would allocate,
+        over all of ``lanes``."""
+        if end_slot <= start_slot or not lanes:
+            return 0
+        first = start_slot // self.page_size
+        last = -(-end_slot // self.page_size)  # exclusive
+        return int((self.block_tables[lanes, first:last] < 0).sum())
+
     def map_range(self, lane: int, start_slot: int, end_slot: int) -> None:
         """Map pages so slots [start_slot, end_slot) of ``lane`` have storage.
 

@@ -45,6 +45,15 @@ SELF_PHASE = {
     "emit": "emit",
 }
 
+# Why a dispatching period's decode chunk was NOT enqueued before the chunk
+# in front of it was read (``ahead`` counts those that were): the segment's
+# first chunk; a backend that cannot run ahead (it may have to redo a chunk
+# from pre-chunk state, or dispatches through the watchdog's thread); a
+# speculative round, whose drafts the host accepts; a restore or a pool too
+# short for the chunk, which spill and restore from settled state; a join
+# that lost its worker.
+SERIAL_WHY = ("segment-start", "backend", "spec", "restore", "pages", "join")
+
 # Histogram of the periods' durations: geometric buckets from 1 ms to 10 s
 # whose ratio is at most 1.02, so a percentile read from it is within 1% of
 # the sample's. counts[0] holds what fell below the first edge, counts[-1]
@@ -83,6 +92,7 @@ class PeriodAccount:
             "phase_seconds": dict.fromkeys(PHASES, 0.0),
             "joins": 0, "join_seconds": 0.0, "join_readback_seconds": 0.0,
             "lane_seconds": {"live": 0.0, "offered": 0.0, "idle_queued": 0.0},
+            "ahead": 0, "serial": dict.fromkeys(SERIAL_WHY, 0),
         }
         self._hist = [0] * (_N_BUCKETS + 2)
         self._segment = {
@@ -130,16 +140,25 @@ class PeriodAccount:
         self._mark = now
         return now - t_in
 
-    def note_join(self, seconds: float, readback_seconds: float) -> None:
-        """One join's whole duration and, inside it, its first-token wait."""
+    def note_join(self, seconds: float) -> None:
+        """One join's ``join`` span: the host's work to enqueue its prefill,
+        its first sample and its lane's writes. The wait for its first
+        token is no part of it (``note_join_wait``)."""
         self._joins += 1
         self._join_s += seconds
-        self._join_readback_s += readback_seconds
 
-    def end(self, live: int | None) -> None:
+    def note_join_wait(self, seconds: float) -> None:
+        """The host's wait for a joiner's first token, read with its
+        boundary's other values: what was still to run of the join when
+        the host got there."""
+        self._join_readback_s += seconds
+
+    def end(self, live: int | None, order: str = "") -> None:
         """The iteration ends. ``live``: lanes that decoded in it; None when
         it dispatched nothing (the segment's last look for work, a chunk
-        lost to a failover), which is no period."""
+        lost to a failover), which is no period. ``order``: ``"ahead"``
+        when its chunk was enqueued before the one in front of it was
+        read, else one of ``SERIAL_WHY``."""
         now = time.perf_counter()
         self._self["other"] += now - self._mark
         wall = now - self._t0
@@ -151,6 +170,10 @@ class PeriodAccount:
                 return
             p["count"] += 1
             p["seconds"] += wall
+            if order == "ahead":
+                p["ahead"] += 1
+            else:
+                p["serial"][order] += 1
             for phase, s in self._self.items():
                 p["phase_seconds"][phase] += s
             if self._joins:

@@ -329,7 +329,15 @@ class _PagedBackend:
     admits by free pages (runtime/serving.py). The engine owns the allocation
     protocol (map at layout/join, extend at page boundaries, release on
     finish); this backend reads ``self.allocator.block_tables`` at each
-    dispatch and ships it as a small traced int32 operand. Which programs
+    dispatch and ships a COPY of it as a small traced int32 operand. Every
+    operation only enqueues: nothing here waits for the device, and the
+    engine goes on mapping and releasing pages on the host while the
+    program runs, so an operand is what the tables said when the program
+    was ENQUEUED (a page released since is recycled only by a program
+    enqueued later, behind this one in device order). ``lookahead`` states
+    that to the engine: a failed chunk is never redone from pre-chunk
+    state here, so its step loop may enqueue one decode chunk ahead of the
+    tokens it has read (runtime/serving.py ``_run_epoch``). Which programs
     get compiled is ``self.shapes`` (runtime/shapes.py): the backend owns
     its instance, the engine reads it from here.
 
@@ -349,6 +357,7 @@ class _PagedBackend:
     """
 
     kv_mode = "paged"
+    lookahead = 1  # decode chunks the engine may enqueue ahead of its reads
 
     def __init__(
         self,
@@ -465,9 +474,12 @@ class _PagedBackend:
 
     def _tables(self, lane: int | None = None) -> jnp.ndarray:
         """The block-table operand under the epoch's capacity: every lane's
-        row, or one lane's."""
+        row, or one lane's. A copy: the allocator's array changes under a
+        program that is enqueued and has not run (class docstring)."""
         rows = slice(None) if lane is None else slice(lane, lane + 1)
-        return jnp.asarray(self.allocator.block_tables[rows, : self._cap_pages])
+        return jnp.asarray(
+            self.allocator.block_tables[rows, : self._cap_pages].copy()
+        )
 
     def state_facts(self) -> dict:
         """``GET /stats`` engine.state: the recurrent state beside the page
@@ -740,7 +752,7 @@ class PagedHybridBackend(_PagedBackend):
         ))
         pads = jnp.asarray(pads)
         group = self.shapes.prefill_group(b, tokens.shape[1])
-        tables = jnp.asarray(self.allocator.block_tables)
+        tables = jnp.asarray(self.allocator.block_tables.copy())
         self.state_lane_writes += b
         logits = []
         for lo in range(0, b, group):
@@ -764,9 +776,11 @@ class PagedHybridBackend(_PagedBackend):
             allow_pallas=self.allow_pallas,
         )
         # A lane is live while it holds pages: the engine releases a
-        # finished row's pages at once and maps a joiner's before its
-        # prefill, and spare lanes never hold any. The same fact that
-        # drops a dead lane's K/V writes keeps its state.
+        # finished row's pages at the boundary where it sees the end (one
+        # chunk late for an EOS id: that chunk steps the dead row's state,
+        # which nobody reads and a joiner starts from zero) and maps a
+        # joiner's before its prefill, and spare lanes never hold any. The
+        # same fact that drops a dead lane's K/V writes keeps its state.
         b = int(jnp.shape(tok)[0])
         valid = (self.allocator.block_tables[:b] >= 0).any(axis=1)
         return fn(
@@ -788,7 +802,7 @@ class PagedHybridBackend(_PagedBackend):
         return fn(
             self.params, kv, jnp.asarray(row_tokens),
             jnp.asarray(pads1, jnp.int32), jnp.asarray(ends1, jnp.int32),
-            jnp.asarray(self.allocator.block_tables[lane : lane + 1]),
+            jnp.asarray(self.allocator.block_tables[lane : lane + 1].copy()),
             jnp.int32(start), jnp.int32(lane),
         )
 

@@ -30,6 +30,27 @@ Decode FLOPs grow ~linearly with rows while weight HBM traffic stays constant,
 so on TPU a batch of B requests streams at nearly the single-request rate for
 each of them — aggregate throughput scales until the MXU saturates.
 
+The step loop keeps ONE DECODE CHUNK IN FLIGHT ahead of the host
+(``_run_epoch``): it never reads a value back from the device before it has
+enqueued the next program that does not depend on that read. With chunk k
+enqueued and unread, a boundary frees the lanes of rows whose budget ends
+inside chunk k (that much the host can count), enqueues the joins'
+prefills, first samples and lane writes and then chunk k+1 behind them, and
+only then waits for chunk k's tokens and the joiners' first tokens; emitting,
+sweeping, admission and page bookkeeping run while the device runs chunk k+1.
+Only an EOS id is seen one chunk late: that row's lane computes one more
+chunk nobody reads, over pages the row still holds. The order stays serial,
+by what the code can observe and not by a flag, on a backend that may have
+to redo a chunk from pre-chunk state or dispatches through the watchdog's
+thread (everything but the paged single-device backends, which state
+``lookahead``), in a speculative round (the host accepts the drafts), and
+at a boundary that restores a spilled lane, finds the pool too short for the
+next chunk, loses a worker or is stopped: such a boundary first reads what
+is in flight (``_settle``). Cancellation and deadlines take effect at the
+next boundary the host reaches, as before; tokens of the chunk then in
+flight are dropped. ``GET /stats`` ``engine.period`` counts ``ahead`` and,
+by reason, ``serial``.
+
 Failure semantics (README "Failure semantics"): finish reasons are
 ``stop`` / ``length`` / ``error`` / ``cancelled`` / ``deadline``. A worker
 failure that exhausts the wire retry/replay budget (BackendWorkerError)
@@ -108,6 +129,27 @@ def _set_lane_rows(arrays, lane, values):
 # penalty ring): a join or a restore updates them together, in one program
 # where eager ``.at[lane].set`` took five dispatches an array.
 _set_lane = tracked_jit(_set_lane_rows, name="engine.set_lane")
+
+
+def _seat_joiner_rows(
+    tok, keys, ring, ring_idx, lane, first, key, row_ring, row_ring_idx
+):
+    window = ring.shape[1]
+    if window > 0:
+        ring = ring.at[lane].set(
+            row_ring[0].at[row_ring_idx[0]].set(first[0])
+        )
+        ring_idx = ring_idx.at[lane].set((row_ring_idx[0] + 1) % window)
+    return (
+        tok.at[lane].set(first[0]), keys.at[lane].set(key[0]), ring, ring_idx
+    )
+
+
+# A joiner takes its lane ON THE DEVICE: its first token (``first`` [1], which
+# the host has not read yet), the key it carries on, and its penalty ring with
+# that token pushed (``batch.first_sample``'s ring arithmetic). One program,
+# no host value in it, so the next decode chunk can be enqueued behind it.
+_seat_joiner = tracked_jit(_seat_joiner_rows, name="engine.seat_joiner")
 
 
 class EngineOverloaded(RuntimeError):
@@ -815,6 +857,13 @@ class BatchEngine:
         # snapshot under ``engine.period`` / ``engine.segment``.
         self.periods = PeriodAccount(self.max_batch)
         self._segment_args: dict = {}
+        # Device values the step loop has enqueued and not read yet, oldest
+        # first (``_settle``): joiners' first tokens and at most one decode
+        # chunk behind the one the host waits for. ``_serial_why`` says why
+        # the last chunk was read before the next was enqueued.
+        self._unread: deque[_Unread] = deque()
+        self._serial_why = "segment-start"
+        self._t_chunk_read = 0.0
         # Latency attribution (README "Latency attribution & black-box
         # diagnostics"): live per-phase accounting — the engine knows each
         # dispatch's wall time and how many of its tokens every row
@@ -1858,11 +1907,16 @@ class BatchEngine:
         guard is the local/device half of the same bound, not a substitute
         for wire deadlines.
 
-        ``readback`` is the host's wait for the result (the chunk's
-        ``np.asarray``); with it the call returns ``(out, readback(out))``.
-        The enqueue is a ``dispatch`` span and the wait a ``readback`` span;
-        under the watchdog the engine thread only waits, so the whole call
-        is ``readback``."""
+        Without the watchdog the call ENQUEUES (a ``dispatch`` span) and
+        returns device values nobody has waited for: the step loop reads a
+        decode chunk's tokens and a joiner's first token later, through
+        ``_settle``, after it has enqueued the next program. ``readback``
+        is for a caller that needs the result at once (a speculative round:
+        the host accepts the drafts): the wait is a ``readback`` span and
+        the call returns ``(out, readback(out))``. Under the watchdog the
+        engine thread only waits, so the whole call is ``readback`` and
+        nothing is ever in flight behind it (``_run_epoch`` keeps such an
+        engine serial)."""
         if self._guard is None:
             self._backend_guard(op)
             with self._phase("dispatch"):
@@ -1883,6 +1937,103 @@ class BatchEngine:
         with self._phase("readback"):
             return self._guard.call(job, op=op)
 
+    def _read(self, entry: "_Unread") -> np.ndarray:
+        """The one place the step loop waits for the device: the host copy
+        of an enqueued value, a ``readback`` span. Read once; ``_settle``
+        finds the copy on the entry."""
+        if entry.host is None:
+            with self._phase("readback") as wait:
+                entry.host = np.asarray(entry.value)
+            entry.t_read = time.perf_counter()
+            if not entry.n:
+                self.periods.note_join_wait(wait.seconds)
+        return entry.host
+
+    def _settle(self, rows: list, keep: int = 0, why: str = "") -> None:
+        """Read and emit, oldest first, all that is enqueued but the newest
+        ``keep`` values (1: the chunk just enqueued stays in flight while the
+        host does the boundary's work under it; 0: a boundary that must see
+        every token first). ``why`` names that reason for the period
+        account: the next chunk will not have been enqueued ahead."""
+        if why and any(entry.n for entry in self._unread):
+            self._serial_why = why
+        while len(self._unread) > keep:
+            entry = self._unread[0]
+            host = self._read(entry)
+            self._unread.popleft()
+            with self._phase("emit"):
+                if entry.n:
+                    self._emit_chunk(rows, entry, host)
+                else:
+                    self._emit_first(rows, entry, int(host[0]))
+
+    def _emit_chunk(self, rows: list, entry: "_Unread", toks_np) -> None:
+        """A decode chunk's tokens reach their streams: the rows are the
+        chunk's own, as they stood when it was enqueued (a row that was
+        cancelled since takes nothing; one whose budget the chunk fills
+        has left ``rows`` already and finishes here)."""
+        # The chunk's wall as the host sees it: from when the device could
+        # start it (its enqueue, or the end of the chunk before it) to its
+        # tokens. Feeds the step-budget clock (continuous): deadline slack
+        # is measured in recent chunk walls.
+        dt_chunk = entry.t_read - max(entry.t0, self._t_chunk_read)
+        self._t_chunk_read = entry.t_read
+        self._step_budget.observe_chunk(dt_chunk)
+        n = entry.n
+        consumed = {
+            lane: row.peek_consumed(toks_np[lane]) for lane, row in entry.rows
+        }
+        # Hardware ledger: the chunk computed B x n positions — consumed
+        # ones are decode goodput, live-but-unconsumed tails are convoy,
+        # dead lanes are pad. Noted BEFORE the pushes for the same
+        # flush-ordering reason as account_decode below.
+        self.efficiency.note_decode(
+            dt_chunk, len(rows), n, len(entry.rows),
+            sum(consumed.values()), slot=entry.slot,
+        )
+        for lane, row in entry.rows:
+            # Account BEFORE pushing: a row that finishes mid-chunk flushes
+            # its attribution from inside push() -> finish(), so the final
+            # chunk's decode share (and its unconsumed-tail convoy — the
+            # very number the convoy meter exists for) must already be on
+            # the row by then.
+            row.inflight -= n
+            row.account_decode(dt_chunk, n, consumed[lane])
+            for t in toks_np[lane]:
+                row.push(int(t))
+                if row.done:
+                    break
+            if row.done and rows[lane] is row:
+                rows[lane] = None
+        self._release_finished(rows)
+
+    def _emit_first(self, rows: list, entry: "_Unread", first: int) -> None:
+        """A joiner's first token, read with its boundary's other values."""
+        (lane, row), = entry.rows
+        dt_join = entry.t_read - entry.t0
+        row.account_join(dt_join)
+        # Hardware ledger: one lane x W window, the prompt's share is
+        # useful prefill, the left-padding is pad.
+        self.efficiency.note_prefill(
+            dt_join, 1, entry.width,
+            min(len(row.req.prompt_ids), entry.width),
+        )
+        row.inflight -= 1
+        row.push(first)
+        if row.done and rows[lane] is row:
+            rows[lane] = None
+
+    def _open_rows(self, rows: list) -> list["_RowState"]:
+        """Every row of the epoch whose stream is still open: the lanes'
+        rows, and the rows that left their lane by count (the budget ends
+        inside a chunk not read yet) and wait in ``_unread`` for it."""
+        out = [row for row in rows if row is not None]
+        for entry in self._unread:
+            for _, row in entry.rows:
+                if not row._finished and row not in out:
+                    out.append(row)
+        return out
+
     @contextlib.contextmanager
     def _phase(self, name: str, *, rid: str | None = None,
                args: dict | None = None):
@@ -1901,7 +2052,8 @@ class BatchEngine:
     @contextlib.contextmanager
     def _period(self, slot: int):
         """Root span of one iteration of the step loop. The body sets
-        ``dispatched`` and ``live`` on the yielded arguments once it has
+        ``dispatched``, ``live`` and ``order`` (``"ahead"``, or why the
+        chunk was not enqueued ahead) on the yielded arguments once it has
         dispatched a chunk or a round; an iteration that did not is no
         period (obs/period.py)."""
         with self._cv:
@@ -1912,7 +2064,10 @@ class BatchEngine:
             try:
                 yield args
             finally:
-                self.periods.end(args["live"] if args["dispatched"] else None)
+                self.periods.end(
+                    args["live"] if args["dispatched"] else None,
+                    args.get("order", ""),
+                )
 
     # ------------------------------------------------- replica failover
     # Transparent recovery (README "Failover"): when a worker dies after
@@ -2435,17 +2590,15 @@ class BatchEngine:
                 # plain stop() mid-epoch is an operator action, not an
                 # anomaly worth a bundle.
                 self._capture("epoch-error", self._epoch_head_rid or None)
-            for lane, row in enumerate(rows):
-                if row is not None:
-                    row.fail(str(e))
-                    rows[lane] = None
+            for row in self._open_rows(rows):
+                row.fail(str(e))
+            rows[:] = [None] * len(rows)
         except Exception as e:  # noqa: BLE001 — surface to every consumer
             log.exception("epoch failed")
-            for row in rows:
-                if row is not None:
-                    row.req.handle._emit(e)
-                    row.req.handle._emit(_DONE)
-                    row.close_span(error=str(e))
+            for row in self._open_rows(rows):
+                row.req.handle._emit(e)
+                row.req.handle._emit(_DONE)
+                row.close_span(error=str(e))
             # A non-worker exception is a bug: spilled streams must not
             # retry a deterministically failing seed forever — close them
             # with the same error every other consumer sees.
@@ -2485,10 +2638,10 @@ class BatchEngine:
             with self._cv:
                 self._live_rids.difference_update(r.rid for r in batch)
                 self._cancel_ids.difference_update(r.rid for r in batch)
-                for row in rows:
-                    if row is not None:
-                        self._live_rids.discard(row.req.rid)
-                        self._cancel_ids.discard(row.req.rid)
+                for row in self._open_rows(rows):
+                    self._live_rids.discard(row.req.rid)
+                    self._cancel_ids.discard(row.req.rid)
+            self._unread.clear()  # and the device values it held
 
     def _run_epoch(self, batch: list[_Request], rows: list) -> None:
         from cake_tpu.models.llama.batch import (
@@ -2737,21 +2890,43 @@ class BatchEngine:
         # capacity — which covers every admitted row's full budget, so the
         # clamp never truncates a stream below what max_seq_len would give.
 
+        # THE ORDER OF THE LOOP. The host never reads a value back from the
+        # device before it has enqueued the next program that does not
+        # depend on that read: with chunk k enqueued and unread, an
+        # iteration does chunk k's boundary work from what it can COUNT
+        # (a budget that ends inside chunk k frees its lane now; an EOS id
+        # is seen one chunk late), enqueues the joins' prefills and chunk
+        # k+1 behind chunk k, and only then waits for chunk k's tokens and
+        # the joiners' first ones (``_settle``). One chunk of look-ahead,
+        # by what the code can observe: a backend that states it
+        # (``lookahead``: a paged single-device backend never redoes a
+        # chunk from pre-chunk state), no watchdog thread in the dispatch,
+        # no speculative round (the host accepts the drafts). A boundary
+        # that needs every token first — a restore, a pool too short for
+        # the next chunk (a spill snapshots host and device state), a lost
+        # worker, stop() — reads what is in flight and goes on serially.
+        backend_ahead = (
+            getattr(self.backend, "lookahead", 0) > 0 and self._guard is None
+        )
+        self._serial_why = "segment-start"
+        self._t_chunk_read = 0.0
         ended = "capacity"  # the loop's own end: the slot reached the cap
         while slot < cap - 1:
             if self._stop:
                 # stop() must not wait out a long epoch: close every live
                 # stream now (consumers see the error, not a hang).
                 err = RuntimeError("engine stopped")
-                for lane, row in enumerate(rows):
-                    if row is not None:
-                        row.req.handle._emit(err)
-                        row.req.handle._emit(_DONE)
-                        row.close_span(error="engine stopped")
-                        self._row_finished(row.req.rid)
-                        rows[lane] = None
+                for row in self._open_rows(rows):
+                    row.req.handle._emit(err)
+                    row.req.handle._emit(_DONE)
+                    row.close_span(error="engine stopped")
+                    self._row_finished(row.req.rid)
+                rows[:] = [None] * len(rows)
                 self._segment_args["ended"] = "stopped"
                 return
+            spec = self._spec_applicable(s, slot, cap)
+            look = int(backend_ahead and not spec)
+            serial = "" if look else ("spec" if backend_ahead else "backend")
             with self._period(slot) as period:
                 budget = None
                 with self._phase("sweep"):
@@ -2760,7 +2935,8 @@ class BatchEngine:
                     # finish "deadline" NOW — their pages return to the pool
                     # (release just below) and their lanes are joinable this
                     # very round; queued requests past their deadline expire
-                    # without ever admitting.
+                    # without ever admitting. (A chunk in flight still
+                    # computes such a row's lane: its tokens are dropped.)
                     self._apply_cancels(rows)
                     self._apply_deadlines(rows)
                     self._release_finished(rows)
@@ -2821,11 +2997,13 @@ class BatchEngine:
                                     except BackendWorkerError as e:
                                         # A join prefill lost its worker:
                                         # migrate the epoch's live rows to
-                                        # the new route, then retry the
-                                        # join there (the joiner saw no
-                                        # side effects — its first token
-                                        # samples only after backend.join
-                                        # returns).
+                                        # the new route (from their whole
+                                        # histories: what is in flight is
+                                        # read first), then retry the join
+                                        # there (the joiner saw no side
+                                        # effects — its first token samples
+                                        # only after backend.join returns).
+                                        self._settle(rows, 0, "join")
                                         self._failover_or_raise(e)
                                         kv = self._migrate_kv(rows, B, slot)
                                 joined.add(id(req))
@@ -2833,6 +3011,10 @@ class BatchEngine:
                                     (pads_j,), lane,
                                     (slot - len(req.prompt_ids),),
                                 )
+                                if not look:
+                                    # Serial: the joiner's first token is
+                                    # read before anything else is enqueued.
+                                    self._settle(rows, 0, serial)
                         except Exception as e:
                             for _, req2 in join_args:
                                 if id(req2) not in joined:
@@ -2854,6 +3036,17 @@ class BatchEngine:
                             raise
                     finally:
                         step_args["joins"] = len(join_args)
+                n = self.shapes.decode_steps(
+                    self.decode_chunk_size, cap, slot
+                )
+                if self._unread and (
+                    not look or self._pages_short(rows, slot, n)
+                ):
+                    # _extend_pages would spill or truncate (or the round
+                    # is the host's): with every token read, as the serial
+                    # order had them. An EOS in the chunk that was in
+                    # flight may free the very pages that were short.
+                    self._settle(rows, 0, serial or "pages")
                 live = sum(r is not None for r in rows)
                 metrics.registry.gauge(
                     "cake_batch_occupancy",
@@ -2863,7 +3056,7 @@ class BatchEngine:
                     ended = "drained"
                     break
                 self.periods.first_dispatch()
-                if self._spec_applicable(s, slot, cap):
+                if spec:
                     # The verify chunk WRITES slots [slot, slot + K + 1)
                     # through the block table — map those pages first (an
                     # unmapped slot silently drops the chunk's KV). Dense
@@ -2897,41 +3090,63 @@ class BatchEngine:
                         res = None
                     if res is not None:
                         tok, kv, keys, slot = res
-                        period.update(dispatched=True, live=live)
+                        period.update(dispatched=True, live=live, order="spec")
                         continue
-                n = self.shapes.decode_steps(
-                    self.decode_chunk_size, cap, slot
-                )
                 if self._alloc is not None and not self._extend_pages(
                     rows, slot, n, spill_ctx=(keys, ring_j, ring_idx_j)
                 ):
                     ended = "pages"
                     break  # every remaining row was truncated or spilled
+                ahead = any(entry.n for entry in self._unread)
+                order = "ahead" if ahead else self._serial_why
                 try:
                     # The program's key rides the span: lanes x capacity x
                     # n name the compiled decode program, slot and live
-                    # what it was run on.
+                    # what it was run on; ``ahead``: enqueued before the
+                    # chunk in front of it was read. The span holds this
+                    # chunk's enqueue and the wait for the OLDEST unread
+                    # chunk (this one, where the order is serial): one
+                    # chunk's wall either way.
                     with self._phase(
                         "decode-chunk",
                         args={
                             "lanes": B, "capacity": int(cap),
                             "slot": int(slot), "n": int(n), "live": live,
+                            "ahead": ahead,
                         },
-                    ) as chunk:
-                        # The readback rides the watchdog too: a device-
-                        # level hang surfaces here, not just a stuck
-                        # dispatch. It blocks on the device, so the span is
-                        # real chunk compute, not dispatch time.
-                        (
-                            (toks, kv, keys, ring_j, ring_idx_j), toks_np
-                        ) = self._dispatch(
+                    ):
+                        t0 = time.perf_counter()
+                        # Under the watchdog the call blocks on the device
+                        # too: a device-level hang surfaces here, not just
+                        # a stuck dispatch.
+                        toks, kv, keys, ring_j, ring_idx_j = self._dispatch(
                             "decode",
                             lambda: self.backend.decode(
                                 kv, tok, slot, pads_j, keys, ring_j,
                                 ring_idx_j, n, s,
                             ),
-                            readback=lambda out: np.asarray(out[0]),
                         )
+                        chunk = _Unread(
+                            toks,
+                            [
+                                (lane, row) for lane, row in enumerate(rows)
+                                if row is not None
+                            ],
+                            slot, n, t0,
+                        )
+                        self._unread.append(chunk)
+                        for lane, row in chunk.rows:
+                            # What the host can count does not lag: a
+                            # budget that ends inside this chunk frees the
+                            # lane at the next boundary, read or not. The
+                            # row takes its last tokens from ``_unread``.
+                            row.inflight += n
+                            if row.n + row.inflight >= row.req.max_tokens:
+                                rows[lane] = None
+                        tok = toks[:, -1]
+                        slot += n
+                        if not look or self._unread[0] is not chunk:
+                            self._read(self._unread[0])
                 except BackendWorkerError as e:
                     # Transparent recovery: a worker died and a healthy
                     # replica exists — rebuild every live stream's KV on
@@ -2940,48 +3155,13 @@ class BatchEngine:
                     # tok/keys/rings still hold the pre-chunk state, so the
                     # redone chunk samples the exact same tokens (greedy
                     # streams stay bit-identical).
+                    self._settle(rows, 0, "backend")
                     self._failover_or_raise(e)
                     kv = self._migrate_kv(rows, B, slot)
                     continue
-                dt_chunk = chunk.seconds
-                with self._phase("emit"):
-                    # Feed the step-budget clock (continuous): deadline
-                    # slack is measured in recent chunk walls.
-                    self._step_budget.observe_chunk(dt_chunk)
-                    live_rows = [
-                        (lane, row) for lane, row in enumerate(rows)
-                        if row is not None
-                    ]
-                    consumed = {
-                        lane: row.peek_consumed(toks_np[lane])
-                        for lane, row in live_rows
-                    }
-                    # Hardware ledger: the chunk computed B x n positions —
-                    # consumed ones are decode goodput, live-but-unconsumed
-                    # tails are convoy, dead lanes are pad. Noted BEFORE
-                    # the pushes for the same flush-ordering reason as
-                    # account_decode below.
-                    self.efficiency.note_decode(
-                        dt_chunk, len(rows), n, len(live_rows),
-                        sum(consumed.values()), slot=slot,
-                    )
-                    for lane, row in live_rows:
-                        # Account BEFORE pushing: a row that finishes
-                        # mid-chunk flushes its attribution from inside
-                        # push() -> finish(), so the final chunk's decode
-                        # share (and its unconsumed-tail convoy — the very
-                        # number the convoy meter exists for) must already
-                        # be on the row by then.
-                        row.account_decode(dt_chunk, n, consumed[lane])
-                        for t in toks_np[lane]:
-                            row.push(int(t))
-                            if row.done:
-                                rows[lane] = None
-                                break
-                    self._release_finished(rows)
-                    tok = toks[:, -1]
-                    slot += n
-                period.update(dispatched=True, live=live)
+                self._settle(rows, look, serial)
+                period.update(dispatched=True, live=live, order=order)
+        self._settle(rows, 0)
         self._segment_args["ended"] = ended
 
         for row in rows:
@@ -3041,6 +3221,17 @@ class BatchEngine:
             },
             track="mem",
         )
+
+    def _pages_short(self, rows: list, slot: int, n: int) -> bool:
+        """Whether the free list cannot cover the next chunk's slots for
+        every live lane: ``_extend_pages`` would have to reclaim, spill or
+        truncate, which it does from host and device state that holds
+        every token (the step loop reads what is in flight first)."""
+        if self._alloc is None:
+            return False
+        live = [lane for lane, row in enumerate(rows) if row is not None]
+        need = self._alloc.pages_missing(live, slot, slot + n)
+        return need > self._alloc.pages_free
 
     def _extend_pages(
         self, rows: list, slot: int, n: int, spill_ctx: tuple | None = None,
@@ -3224,6 +3415,7 @@ class BatchEngine:
         pool cannot supply even fully drained) — it force-finishes
         "length" instead of livelocking through zero-progress
         respill/reseed cycles."""
+        assert not self._unread, "a spill copies settled state"
         keys, ring_j, ring_idx_j = spill_ctx
         row = rows[lane]
         rid = row.req.rid
@@ -3358,6 +3550,9 @@ class BatchEngine:
             empty = not self._spilled
         if empty:
             return tok, kv, keys, ring_j, ring_idx_j, pads_j
+        # A restore puts a lane back from its whole history, and competes
+        # for the lanes and pages an EOS in flight may be about to free.
+        self._settle(rows, 0, "restore")
         free = [i for i, r in enumerate(rows) if r is None]
         if not free:
             return tok, kv, keys, ring_j, ring_idx_j, pads_j
@@ -3912,7 +4107,7 @@ class BatchEngine:
         self, req, row, lane, rows, slot, tok, kv, keys, ring_j, ring_idx_j,
         s, t_join,
     ):
-        from cake_tpu.models.llama.batch import first_sample, seed_rings
+        from cake_tpu.models.llama.batch import _first_sample_fn, seed_rings
 
         ids = req.prompt_ids
         with self._phase(
@@ -3954,37 +4149,32 @@ class BatchEngine:
                     return tok, kv, keys, ring_j, ring_idx_j
             logits, kv, W = self._row_prefill(kv, lane, ids, pad, slot, fork)
 
-            # Same first-token arithmetic as every entry point (batch.py).
-            window = s.repeat_last_n
-            row_ring, row_ring_idx = seed_rings([ids], window)
+            # Same first-token arithmetic as every entry point (batch.py's
+            # ``first_sample``), left ON THE DEVICE: the sample, the ring's
+            # update and the lane's writes are programs enqueued behind the
+            # prefill, and the token itself is read with this boundary's
+            # other values (``_settle``), after the next decode chunk has
+            # been enqueued behind them.
+            row_ring, row_ring_idx = seed_rings([ids], s.repeat_last_n)
             key0 = jax.random.PRNGKey(req.sampling.seed)
-            # The first token's sample waits for the join's prefill: this
-            # is where the host blocks on the device.
-            with self._phase("readback") as readback:
-                first_arr, key_next, row_ring, row_ring_idx = first_sample(
-                    logits, s, row_ring, row_ring_idx, key0[None]
-                )
-                first = int(first_arr[0])
-            if window > 0:
-                ring_j, ring_idx_j = _set_lane(
-                    (ring_j, ring_idx_j), lane,
-                    (row_ring[0], int(row_ring_idx[0])),
-                )
-            keys, tok = _set_lane((keys, tok), lane, (key_next[0], first))
-        self.periods.note_join(join.seconds, readback.seconds)
-
-        dt_join = time.perf_counter() - t_join
-        row.account_join(dt_join)
-        # Hardware ledger: one lane x W window, the prompt's share is
-        # useful prefill, the left-padding is pad.
-        self.efficiency.note_prefill(dt_join, 1, W, min(len(ids), W))
+            first, key_next = _first_sample_fn(
+                s.temperature, s.top_k, s.top_p, s.repeat_penalty, True
+            )(logits, jnp.asarray(row_ring), key0[None])
+            tok, keys, ring_j, ring_idx_j = _seat_joiner(
+                tok, keys, ring_j, ring_idx_j, lane, first, key_next,
+                row_ring, row_ring_idx,
+            )
+        self.periods.note_join(join.seconds)
+        row.inflight = 1
+        self._unread.append(_Unread(first, [(lane, row)], slot, 0, t_join, W))
+        # A budget of one token ends with the token in flight: the lane
+        # never becomes the row's (its pages go at the next release).
+        rows[lane] = row if req.max_tokens > 1 else None
         self._record_admissions([req], "joined", lane=lane, slot=slot)
         metrics.registry.counter(
             "cake_engine_joins_total",
             "Requests that joined a RUNNING epoch at a chunk boundary.",
         ).inc()
-        row.push(first)
-        rows[lane] = None if row.done else row
         self.stats["joins"] += 1
         self.stats["rows"] += 1
         return tok, kv, keys, ring_j, ring_idx_j
@@ -4015,6 +4205,24 @@ def _fail_request(
         )
         engine._record_request(req, finish="error")
     req.handle._emit(_DONE)
+
+
+@dataclasses.dataclass
+class _Unread:
+    """A device value the step loop has enqueued and not read yet: a decode
+    chunk's tokens ``[lanes, n]`` or, with ``n`` 0, a joiner's first token
+    ``[1]`` (``width``: its prefill's window). ``rows`` are the (lane, row)
+    pairs that take the tokens, as they stood at the enqueue at ``t0``;
+    ``slot`` is the shared slot then. ``host`` is the copy once read."""
+
+    value: jax.Array
+    rows: list
+    slot: int
+    n: int
+    t0: float
+    width: int = 0
+    host: np.ndarray | None = None
+    t_read: float = 0.0
 
 
 @dataclasses.dataclass
@@ -4050,6 +4258,9 @@ class _RowState:
         self.history: list[int] = list(req.prompt_ids)
         self._decoded_len = 0
         self.n = 0
+        # Tokens of this row that are enqueued on the device and unread:
+        # ``n + inflight`` is what the host can count of the budget.
+        self.inflight = 0
         self.done = False
         self._finished = False
         self._backpressured = False
