@@ -1638,7 +1638,7 @@ def main(argv: list[str] | None = None) -> int:
 
     import jax.numpy as jnp
 
-    from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.models.llama.config import CACHE_KV, LlamaConfig
     from cake_tpu.models.llama.generator import LlamaGenerator, SamplingConfig
     from cake_tpu.models.llama.tokenizer import load_tokenizer
     from cake_tpu.parallel.topology import Topology
@@ -1692,11 +1692,12 @@ def main(argv: list[str] | None = None) -> int:
     config = LlamaConfig.from_model_dir(
         args.model, attention_impl=args.attention_impl
     )
-    if config.has_state_layers:
-        # What a model with state layers refuses (``hybrid.REFUSED``), asked
-        # before any weight is read and any backend chosen.
-        from cake_tpu.models.llama.hybrid import (
-            UnsupportedWithStateLayers,
+    if config.cache_kind != CACHE_KV:
+        # What a cache that is not plain K and V refuses
+        # (``capability.REFUSED``), asked before any weight is read and any
+        # backend chosen.
+        from cake_tpu.models.llama.capability import (
+            UnsupportedForCacheKind,
             refuse_unsupported,
         )
 
@@ -1714,7 +1715,7 @@ def main(argv: list[str] | None = None) -> int:
                 distributed=bool(args.distributed),
                 quantize=bool(args.quantize),
             )
-        except UnsupportedWithStateLayers as e:
+        except UnsupportedForCacheKind as e:
             print(f"cake-tpu: {e}", file=sys.stderr)
             return 2
     if args.fusion != "none":
@@ -1789,13 +1790,14 @@ def _run_leader(
     args, step, config, sampling, dtype, kv_dtype, startup: dict
 ) -> int:
     """The master-side tail of main(): generator + API server or one-shot."""
+    from cake_tpu.models.llama.config import CACHE_KV
     from cake_tpu.models.llama.generator import LlamaGenerator
     from cake_tpu.models.llama.tokenizer import load_tokenizer
 
     if args.prefix_cache == "auto":
-        # On for --api, but for a model with state layers: a reused prefix
-        # restores K and V only ("on" is refused outright, cli.main).
-        prefix_cache = bool(args.api) and not config.has_state_layers
+        # On for --api, but for a cache that is not plain K and V: a reused
+        # prefix restores K and V only ("on" is refused outright, cli.main).
+        prefix_cache = bool(args.api) and config.cache_kind == CACHE_KV
     else:
         prefix_cache = args.prefix_cache == "on"
     # With a batch engine attached, the API path bypasses the generator for
@@ -1912,7 +1914,7 @@ def _run_leader(
                 )
             engine_prefix_cache = (
                 args.kv_mode == "paged" and args.prefix_cache != "off"
-                and not config.has_state_layers  # "auto" only: see above
+                and config.cache_kind == CACHE_KV  # "auto" only: see above
             )
             from cake_tpu.runtime.serving import ServeConfig
 

@@ -24,7 +24,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from cake_tpu.models.llama.config import LlamaConfig
+from cake_tpu.models.llama.capability import refuse_unsupported
+from cake_tpu.models.llama.config import (
+    CACHE_KV_STATE, CACHE_LATENT, LlamaConfig,
+)
 from cake_tpu.models.llama.model import Params
 
 INDEX_FILE = "model.safetensors.index.json"
@@ -486,6 +489,100 @@ def hybrid_tensor_dict(
     return tensors
 
 
+# Latent stacks (models/llama/latent.py, ``model_type: pangu_ultra_moe``): HF
+# names as the DeepSeek-V3 family writes them -> key in the run's tree. Every
+# matrix is [out, in] on disk and [in, out] here; ``kv_b_proj`` is held as its
+# two per-head halves; a sparse layer's experts are stacked over the experts
+# HELD (``config.expert_offset`` on: their own numbers on disk).
+_LATENT_MATRICES = {
+    "wq_a": "self_attn.q_a_proj.weight", "wq_b": "self_attn.q_b_proj.weight",
+    "wkv_a": "self_attn.kv_a_proj_with_mqa.weight",
+    "wo": "self_attn.o_proj.weight",
+}
+_LATENT_VECTORS = {
+    "q_a_ln": "self_attn.q_a_layernorm.weight",
+    "kv_a_ln": "self_attn.kv_a_layernorm.weight",
+    "ln_attn": "input_layernorm.weight",
+    "ln_post_attn": "post_attention_layernorm.weight",
+    "ln_mlp": "pre_mlp_layernorm.weight",
+    "ln_post_mlp": "post_mlp_layernorm.weight",
+}
+_LATENT_KV_B = "self_attn.kv_b_proj.weight"
+_SWIGLU = {"gate": "gate_proj.weight", "up": "up_proj.weight", "down": "down_proj.weight"}
+
+
+def _latent_layer(reader: SafetensorsReader, config: LlamaConfig, i: int, sparse: bool, dtype) -> Params:
+    p = f"model.layers.{i}."
+    out = {k: reader.jax(p + n, dtype, transpose=True) for k, n in _LATENT_MATRICES.items()}
+    out.update({k: reader.jax(p + n, dtype) for k, n in _LATENT_VECTORS.items()})
+    n, nope = config.num_attention_heads, config.qk_nope_head_dim
+    kv_b = reader.jax(p + _LATENT_KV_B, dtype).reshape(n, -1, config.kv_lora_rank)
+    out["w_uk"] = jnp.swapaxes(kv_b[:, :nope], 1, 2)  # [heads, rank, nope]
+    out["w_uv"] = jnp.swapaxes(kv_b[:, nope:], 1, 2)
+    if not sparse:
+        for k, name in _SWIGLU.items():
+            out[f"w_{k}"] = reader.jax(f"{p}mlp.{name}", dtype, transpose=True)
+        return out
+    out["router"] = reader.jax(p + "mlp.gate.weight", dtype, transpose=True)
+    held = range(config.expert_offset, config.expert_offset + config.num_local_experts)
+    for k, name in _SWIGLU.items():
+        out[f"w_{k}"] = jnp.stack([
+            reader.jax(f"{p}mlp.experts.{e}.{name}", dtype, transpose=True) for e in held
+        ])
+        if config.shared_expert_intermediate_size:
+            out[f"sh_{k}"] = reader.jax(f"{p}mlp.shared_experts.{name}", dtype, transpose=True)
+    return out
+
+
+def load_latent_layers(reader: SafetensorsReader, config: LlamaConfig, dtype) -> list[Params]:
+    """One stacked tree a run of layers of one feed-forward kind
+    (``config.ff_runs``), a key at a time so that a run's largest stack and
+    its parts are all that is held twice."""
+    from cake_tpu.models.llama.config import SPARSE
+
+    runs = []
+    for kind, lo, hi in config.ff_runs:
+        layers = [_latent_layer(reader, config, i, kind == SPARSE, dtype) for i in range(lo, hi)]
+        run = {}
+        for key in list(layers[0]):
+            run[key] = jnp.stack([layer.pop(key) for layer in layers])
+        runs.append(run)
+    return runs
+
+
+def latent_tensor_dict(params: Params, config: LlamaConfig, dtype) -> dict[str, np.ndarray]:
+    """THE inverse of ``load_latent_layers`` (fixtures and round trips)."""
+    from cake_tpu.models.llama.config import SPARSE
+
+    tensors = head_tensor_dict(params, config, dtype)
+
+    def put(name, a, transpose=False):
+        a = np.asarray(a.astype(dtype))
+        tensors[name] = (a.T if transpose else a).copy()
+
+    for run, (kind, lo, hi) in zip(params["layers"], config.ff_runs):
+        for k, i in enumerate(range(lo, hi)):
+            p = f"model.layers.{i}."
+            for key, name in _LATENT_MATRICES.items():
+                put(p + name, run[key][k], True)
+            for key, name in _LATENT_VECTORS.items():
+                put(p + name, run[key][k])
+            kv_b = jnp.concatenate([run["w_uk"][k], run["w_uv"][k]], axis=-1)  # [n, rank, nope+v]
+            put(p + _LATENT_KV_B, jnp.swapaxes(kv_b, 1, 2).reshape(-1, config.kv_lora_rank))
+            for key, name in _SWIGLU.items():
+                if kind != SPARSE:
+                    put(f"{p}mlp.{name}", run[f"w_{key}"][k], True)
+                    continue
+                for j in range(config.num_local_experts):
+                    e = config.expert_offset + j
+                    put(f"{p}mlp.experts.{e}.{name}", run[f"w_{key}"][k, j], True)
+                if f"sh_{key}" in run:
+                    put(f"{p}mlp.shared_experts.{name}", run[f"sh_{key}"][k], True)
+            if kind == SPARSE:
+                put(p + "mlp.gate.weight", run["router"][k], True)
+    return tensors
+
+
 def load_params(
     model_dir: str | Path,
     config: LlamaConfig,
@@ -505,14 +602,18 @@ def load_params(
     """
     t0 = time.perf_counter()
     reader = open_checkpoint(model_dir)
-    if config.has_state_layers:
-        from cake_tpu.models.llama.hybrid import refuse_unsupported
-
-        refuse_unsupported(config, layer_range=layer_range is not None)
+    refuse_unsupported(config, layer_range=layer_range is not None)
+    if config.cache_kind == CACHE_KV_STATE:
         params = {
             "embed": reader.jax("model.embed_tokens.weight", dtype),
             "layers": load_hybrid_layers(reader, config, dtype),
             "ln_f": reader.jax(_JAMBA_FINAL_NORM, dtype),
+        }
+    elif config.cache_kind == CACHE_LATENT:
+        params = {
+            "embed": reader.jax("model.embed_tokens.weight", dtype),
+            "layers": load_latent_layers(reader, config, dtype),
+            "ln_f": reader.jax("model.norm.weight", dtype),
         }
     elif layer_range is not None:
         lo, hi = layer_range
@@ -563,8 +664,10 @@ def hf_tensor_dict(
 
     load_layer_params reconstructs the exact QuantWeight/Quant4Weight leaves
     (bit-identical round trip, tests/test_quantized_checkpoint.py)."""
-    if config.has_state_layers:
+    if config.cache_kind == CACHE_KV_STATE:
         return hybrid_tensor_dict(params, config, dtype)
+    if config.cache_kind == CACHE_LATENT:
+        return latent_tensor_dict(params, config, dtype)
     tensors = head_tensor_dict(params, config, dtype)
     tensors.update(
         layer_tensor_dict(

@@ -69,7 +69,7 @@ def split_model(
     topology = Topology.from_path(topology_path)
     if (model_dir / "config.json").exists():
         from cake_tpu.models.llama.config import LlamaConfig
-        from cake_tpu.models.llama.hybrid import refuse_unsupported
+        from cake_tpu.models.llama.capability import refuse_unsupported
 
         refuse_unsupported(
             LlamaConfig.from_model_dir(model_dir), split_model=True

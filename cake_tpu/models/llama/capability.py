@@ -1,0 +1,79 @@
+"""What a model's cache kind cannot be served with: the one capability check.
+
+Most of the program restores, shares, rewinds, shards or re-packs K and V a
+KV head: the dense cache, the prefix cache's copy-on-write chains,
+speculative verify and its rewind, tensor / sequence / pipeline parallelism,
+the distributed workers' block ranges, weight quantisation's expert and
+projection tables, the single-stream generator. A model whose lanes keep
+something else (``config.cache_kind``: a recurrent state beside K and V, or
+one latent a token in place of them) is served by its own paged leaf
+(``runtime/batch_backend.paged_backend``) on one chip, and everything else is
+refused HERE, with one message, before a weight is read: over such a cache
+each would serve wrong tokens silently, and there is no fallback to serve
+instead. Every caller fills in the facts it knows (``cli.main``, the engine
+for programmatic use, the loader, the splitter, the single-stream step) and
+the message names the feature as a user would have written it.
+"""
+
+from __future__ import annotations
+
+from cake_tpu.models.llama.config import (
+    CACHE_KV, CACHE_KV_STATE, STATE, LlamaConfig,
+)
+
+
+class UnsupportedForCacheKind(ValueError):
+    """A feature was asked for that this model's cache kind cannot serve."""
+
+
+# Fact -> the feature as a user would have written it. THE one list.
+REFUSED = {
+    "single_stream":
+        "the single-stream generator (no --api with --api-batch > 1)",
+    "kv_mode_dense": "--kv-mode dense",
+    "prefix_cache": "--prefix-cache on",
+    "draft_model": "--draft-model",
+    "speculative_k": "--speculative-k",
+    "tp": "--tp",
+    "sp": "--sp",
+    "topology": "--topology (pipeline and distributed backends)",
+    "distributed": "--distributed",
+    "quantize": "--quantize",
+    "other_backend":
+        "a backend other than the local paged one (--tp, pipeline, distributed)",
+    "layer_range": "a worker's layer range (--topology)",
+    "split_model": "cake-split-model",
+}
+
+
+def _why(config: LlamaConfig) -> str:
+    if config.cache_kind == CACHE_KV_STATE:
+        return (
+            f"{len(config.layers_of(STATE))} of its "
+            f"{config.num_hidden_layers} layers keep a recurrent state "
+            "per lane, and this feature restores, rewinds, shares or "
+            "shards K and V only"
+        )
+    return (
+        f"its {config.num_hidden_layers} layers keep one latent of "
+        f"{config.kv_lora_rank} + {config.qk_rope_head_dim} numbers a token "
+        "in a latent page pool, and this feature restores, rewinds, shares, "
+        "shards or re-packs K and V a KV head"
+    )
+
+
+def refuse_unsupported(config: LlamaConfig, **facts: bool) -> None:
+    """``facts`` maps names of ``REFUSED`` to whether the caller was asked
+    for that feature; the first that was raises, for a model whose cache is
+    not plain K and V."""
+    if config.cache_kind == CACHE_KV:
+        return
+    for fact, on in facts.items():
+        if on:
+            raise UnsupportedForCacheKind(
+                f"{REFUSED[fact]} is not supported for model_type "
+                f"{config.model_type!r}: {_why(config)}. Serve it with --api "
+                "HOST:PORT --api-batch N (N > 1) --kv-mode paged "
+                "--prefix-cache off on one chip, unquantized."
+            )
+

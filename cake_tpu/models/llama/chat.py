@@ -281,6 +281,25 @@ def encode_dialog_jamba(messages: list[Message]) -> str:
     return "".join(parts)
 
 
+def encode_dialog_pangu(messages: list[Message]) -> str:
+    """openPangu template (written from memory of the published chat
+    template; the catalog row carries none):
+
+        <s>[unused9]系统：{sys}[unused10][unused9]用户：{u}[unused10][unused9]助手：
+
+    Every message is one ``[unused9]<role>：text[unused10]`` frame; the
+    prompt ends with an open assistant frame.
+    """
+    roles = {"system": "系统：", "user": "用户：", "assistant": "助手："}
+    parts = ["<s>"]
+    parts += [
+        f"[unused9]{roles[m.role.value]}{m.content.strip()}[unused10]"
+        for m in messages
+    ]
+    parts.append("[unused9]助手：")
+    return "".join(parts)
+
+
 # Template key -> dialog encoder. The generator picks by
 # config.dialog_template (the model family, or the --chat-template override);
 # the Llama-3 encoder is the reference-parity surface (history.rs), the
@@ -301,6 +320,7 @@ DIALOG_ENCODERS = {
     "gemma3_text": encode_dialog_gemma,
     "phi3": encode_dialog_phi3,
     "jamba": encode_dialog_jamba,
+    "pangu_ultra_moe": encode_dialog_pangu,
 }
 
 

@@ -22,10 +22,16 @@ from typing import Any
 SUPPORTED_MODEL_TYPES = (
     "llama", "qwen2", "mistral", "mixtral", "qwen2_moe",
     "gemma", "gemma2", "phi3", "qwen3", "qwen3_moe", "gemma3_text", "jamba",
+    "pangu_ultra_moe",
 )
 
 # The two kinds a decoder layer's token mixer can be (``layer_kinds``).
 ATTENTION, STATE = "attention", "state"
+# The two kinds its feed-forward can be (``ff_kinds``).
+DENSE, SPARSE = "dense", "sparse"
+# What a lane keeps on the device between programs (``cache_kind``): K and V
+# a KV head, those beside a recurrent state, or one latent a token.
+CACHE_KV, CACHE_KV_STATE, CACHE_LATENT = "kv", "kv+state", "latent"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,6 +145,31 @@ class LlamaConfig:
     # False = attention carries no positional term at all (Jamba: the state
     # layers carry the order).
     use_rope: bool = True
+    # Latent attention (MLA; ``pangu_ultra_moe``): queries and keys/values
+    # are projected through low-rank latents, a head's query and key are a
+    # no-position part beside a rotary part that all heads' keys share, and
+    # the cache holds ``kv_lora_rank + qk_rope_head_dim`` numbers a token a
+    # layer, whatever the number of heads. ``kv_lora_rank`` 0 = not latent.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Layers below this index keep the dense feed-forward in a model whose
+    # others are sparse (``ff_kinds``). 0 = every layer is of one kind.
+    first_k_dense_replace: int = 0
+    # A share of the routed experts: ``num_local_experts`` are HELD here,
+    # the router ranks ``router_experts`` (0 = the held ones: the whole
+    # model) and the first held one is ``expert_offset`` of those. What the
+    # absent experts would add to a token is left out (expert parallelism
+    # without its exchange: ops/moe.py).
+    router_experts: int = 0
+    expert_offset: int = 0
+    # Router scores: "softmax" over all experts, or "sigmoid" of each
+    # logit (then renormalised over the chosen by ``norm_topk_prob``);
+    # the combine weights are multiplied by ``routed_scaling_factor``.
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
     # Chat-template override (--chat-template; not an HF field). None = pick
     # by model_type. Needed for Llama-2-chat checkpoints, whose config.json
     # is indistinguishable from base Llama (chat.DIALOG_ENCODERS keys).
@@ -188,6 +219,48 @@ class LlamaConfig:
                 runs.append((k, seen[k], seen[k] + 1))
             seen[k] += 1
         return tuple(runs)
+
+    @property
+    def ff_kinds(self) -> tuple[str, ...]:
+        """The feed-forward of every layer: the second per-layer fact."""
+        if not self.num_local_experts:
+            return (DENSE,) * self.num_hidden_layers
+        return tuple(
+            DENSE if i < self.first_k_dense_replace else SPARSE
+            for i in range(self.num_hidden_layers)
+        )
+
+    @property
+    def ff_runs(self) -> tuple[tuple[str, int, int], ...]:
+        """Maximal runs of one feed-forward kind, as (kind, lo, hi) over the
+        model's own layer indices: a latent model's layers stack by these
+        (models/llama/latent.py), as a hybrid's by ``layer_runs``."""
+        runs: list[tuple[str, int, int]] = []
+        for i, k in enumerate(self.ff_kinds):
+            if runs and runs[-1][0] == k:
+                runs[-1] = (k, runs[-1][1], i + 1)
+            else:
+                runs.append((k, i, i + 1))
+        return tuple(runs)
+
+    @property
+    def cache_kind(self) -> str:
+        """What a lane keeps on the device: THE fact the backend's leaf, the
+        programs' shapes and the capability check are chosen by."""
+        if self.kv_lora_rank:
+            return CACHE_LATENT
+        return CACHE_KV_STATE if self.has_state_layers else CACHE_KV
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a token's latent takes in the pool: the compressed K/V and
+        the shared rotary key, padded to whole 128-lane tiles (512 + 64 ->
+        640: what is STORED; the padding is zeros)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def n_router_experts(self) -> int:
+        return self.router_experts or self.num_local_experts
 
     @property
     def mamba_d_inner(self) -> int:
@@ -273,6 +346,8 @@ class LlamaConfig:
             )
         if model_type == "jamba":
             return cls._jamba_from_hf_dict(d, eos_ids)
+        if model_type == "pangu_ultra_moe":
+            return cls._pangu_from_hf_dict(d, eos_ids)
         if model_type == "phi3" and d.get("rope_scaling"):
             # Phi-3 128k variants use longrope (per-dim su-scaled factors);
             # only the base-rope variants (4k/8k) are supported.
@@ -495,6 +570,77 @@ class LlamaConfig:
         )
 
     @classmethod
+    def _pangu_from_hf_dict(
+        cls, d: dict[str, Any], eos_ids: tuple[int, ...]
+    ) -> "LlamaConfig":
+        """``model_type: pangu_ultra_moe`` (openPangu-Ultra-MoE): latent
+        attention, leading dense layers then sparse ones with a shared
+        expert, sigmoid routing, four norms a layer. ``n_routed_experts``
+        counts the experts HELD; ``n_routed_experts_total`` (absent = the
+        same: the whole model) what the router ranks and
+        ``first_routed_expert`` where the held ones start among them."""
+        for key in ("n_group", "topk_group"):
+            if int(d.get(key) or 1) > 1:
+                raise ValueError(
+                    f"pangu_ultra_moe with {key}={d[key]} needs group-limited "
+                    "routing, which this framework does not bring"
+                )
+        if d.get("rope_scaling"):
+            raise ValueError("pangu_ultra_moe with rope_scaling is not supported")
+        if not d.get("sandwich_norm", True):
+            raise ValueError(
+                "pangu_ultra_moe without sandwich_norm is not supported"
+            )
+        held = int(d.get("n_routed_experts", 256))
+        ranked = int(d.get("n_routed_experts_total", held))
+        first = int(d.get("first_routed_expert", 0))
+        if not 0 <= first <= ranked - held:
+            raise ValueError(
+                f"experts {first}..{first + held - 1} are held of {ranked}: "
+                "first_routed_expert + n_routed_experts must not pass "
+                "n_routed_experts_total"
+            )
+        heads = int(d.get("num_attention_heads", 128))
+        moe_inter = int(d.get("moe_intermediate_size", 2048))
+        return cls(
+            hidden_size=int(d.get("hidden_size", 7680)),
+            intermediate_size=int(d.get("intermediate_size", 18432)),
+            vocab_size=int(d.get("vocab_size", 153600)),
+            num_hidden_layers=int(d.get("num_hidden_layers", 61)),
+            num_attention_heads=heads,
+            num_key_value_heads=heads,
+            rms_norm_eps=float(d.get("rms_norm_eps", 1e-5)),
+            rope_theta=float(d.get("rope_theta", 25600000.0)),
+            max_position_embeddings=int(
+                d.get("max_position_embeddings", 131072)
+            ),
+            bos_token_id=int(d.get("bos_token_id", 0)),
+            eos_token_ids=eos_ids,
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            model_type="pangu_ultra_moe",
+            head_dim_override=int(d.get("qk_nope_head_dim", 128))
+            + int(d.get("qk_rope_head_dim", 64)),
+            q_lora_rank=int(d.get("q_lora_rank", 1536)),
+            kv_lora_rank=int(d.get("kv_lora_rank", 512)),
+            qk_nope_head_dim=int(d.get("qk_nope_head_dim", 128)),
+            qk_rope_head_dim=int(d.get("qk_rope_head_dim", 64)),
+            v_head_dim=int(d.get("v_head_dim", 128)),
+            first_k_dense_replace=int(d.get("first_k_dense_replace", 3)),
+            num_local_experts=held,
+            router_experts=ranked,
+            expert_offset=first,
+            num_experts_per_tok=int(d.get("num_experts_per_tok", 8)),
+            norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+            moe_intermediate_size=moe_inter,
+            shared_expert_intermediate_size=(
+                int(d.get("n_shared_experts", 1)) * moe_inter or None
+            ),
+            moe_scoring="sigmoid",
+            routed_scaling_factor=float(d.get("routed_scaling_factor", 2.5)),
+            post_block_norms=True,
+        )
+
+    @classmethod
     def from_model_dir(
         cls, model_dir: str | Path, *, attention_impl: str | None = None
     ) -> "LlamaConfig":
@@ -566,6 +712,7 @@ class LlamaConfig:
             "qwen3": "Qwen3ForCausalLM",
             "qwen3_moe": "Qwen3MoeForCausalLM",
             "jamba": "JambaForCausalLM",
+            "pangu_ultra_moe": "PanguUltraMoEForCausalLM",
         }[self.model_type]
         d: dict[str, Any] = {
             "architectures": [arch],
@@ -597,7 +744,30 @@ class LlamaConfig:
                 d["max_window_layers"] = 0
         if self.head_dim_override is not None:
             d["head_dim"] = self.head_dim_override
-        if self.num_local_experts:
+        if self.model_type == "pangu_ultra_moe":
+            del d["num_key_value_heads"]
+            d.update(
+                num_key_value_heads=self.num_attention_heads,
+                q_lora_rank=self.q_lora_rank,
+                kv_lora_rank=self.kv_lora_rank,
+                qk_nope_head_dim=self.qk_nope_head_dim,
+                qk_rope_head_dim=self.qk_rope_head_dim,
+                v_head_dim=self.v_head_dim,
+                first_k_dense_replace=self.first_k_dense_replace,
+                n_routed_experts=self.num_local_experts,
+                n_routed_experts_total=self.n_router_experts,
+                first_routed_expert=self.expert_offset,
+                n_shared_experts=(
+                    (self.shared_expert_intermediate_size or 0)
+                    // self.moe_intermediate_size
+                ),
+                moe_intermediate_size=self.moe_intermediate_size,
+                num_experts_per_tok=self.num_experts_per_tok,
+                norm_topk_prob=self.norm_topk_prob,
+                routed_scaling_factor=self.routed_scaling_factor,
+                sandwich_norm=True,
+            )
+        elif self.num_local_experts:
             if self.model_type in ("qwen2_moe", "qwen3_moe"):
                 d["num_experts"] = self.num_local_experts
                 d["norm_topk_prob"] = self.norm_topk_prob

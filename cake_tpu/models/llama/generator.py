@@ -33,7 +33,7 @@ import numpy as np
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.cache import init_cache
 from cake_tpu.models.llama.chat import Message, encode_dialog
-from cake_tpu.models.llama.config import LlamaConfig
+from cake_tpu.models.llama.config import CACHE_KV, LlamaConfig
 from cake_tpu.models.llama.tokenizer import Tokenizer, load_tokenizer
 from cake_tpu.ops.sampling import DEFAULT_SEED, apply_repeat_penalty, sample
 from cake_tpu.utils import metrics
@@ -191,9 +191,9 @@ class LocalForwardStep(FusedDecodeCapability):
         return self._max_seq
 
     def reset(self) -> None:
-        if self.config.has_state_layers:
+        if self.config.cache_kind != CACHE_KV:
             # The weights' holder for the batch engine only: this step's
-            # dense cache and M.forward know nothing of a recurrent state.
+            # dense cache and M.forward know K and V a KV head alone.
             self._kv = None
             return
         self._kv = init_cache(
@@ -207,7 +207,7 @@ class LocalForwardStep(FusedDecodeCapability):
 
     def __call__(self, tokens: np.ndarray, pos: int, seq_len: int) -> np.ndarray:
         if self._kv is None:
-            from cake_tpu.models.llama.hybrid import refuse_unsupported
+            from cake_tpu.models.llama.capability import refuse_unsupported
 
             refuse_unsupported(self.config, single_stream=True)
         if self.rolling:

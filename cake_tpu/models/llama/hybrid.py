@@ -25,8 +25,8 @@ per-lane state, so here:
     recurrence passes its state through (``ops/ssm.py``).
 
 What cannot run over a recurrent state is refused at start-up, in one place
-(``refuse_unsupported``): everything that restores, shares, rewinds or
-shards K and V only.
+(``capability.refuse_unsupported``): everything that restores, shares,
+rewinds or shards K and V only.
 """
 
 from __future__ import annotations
@@ -340,56 +340,3 @@ def _hybrid_decode_fn(
         module="decode_chunk_paged_hybrid",
         donate_argnums=(1,),
     )
-
-
-# ----------------------------------------------------------------- refusal
-
-
-class UnsupportedWithStateLayers(ValueError):
-    """A feature was asked for that cannot run over a recurrent state."""
-
-
-# What a model with state layers refuses, by fact: each restores, shares,
-# rewinds, shards or re-packs K and V only. THE one list: every caller fills
-# in the facts it knows (``cli.main`` before a weight is read, the engine for
-# programmatic use, the loader, the splitter and the single-stream step for
-# what reaches them from outside) and the message names the feature as a user
-# would have written it.
-REFUSED = {
-    "single_stream":
-        "the single-stream generator (no --api with --api-batch > 1)",
-    "kv_mode_dense": "--kv-mode dense",
-    "prefix_cache": "--prefix-cache on",
-    "draft_model": "--draft-model",
-    "speculative_k": "--speculative-k",
-    "tp": "--tp",
-    "sp": "--sp",
-    "topology": "--topology (pipeline and distributed backends)",
-    "distributed": "--distributed",
-    "quantize": "--quantize",
-    "other_backend":
-        "a backend other than the local paged one (--tp, pipeline, distributed)",
-    "layer_range": "a worker's layer range (--topology)",
-    "split_model": "cake-split-model",
-}
-
-
-def refuse_unsupported(config: LlamaConfig, **facts: bool) -> None:
-    """The capability check for models with state layers. ``facts`` maps
-    names of ``REFUSED`` to whether the caller was asked for that feature;
-    the first that was raises. Over a recurrent state each would serve wrong
-    tokens silently, and there is no fallback to serve instead."""
-    if not config.has_state_layers:
-        return
-    for fact, on in facts.items():
-        if on:
-            raise UnsupportedWithStateLayers(
-                f"{REFUSED[fact]} is not supported for model_type "
-                f"{config.model_type!r}: "
-                f"{len(config.layers_of(STATE))} of its "
-                f"{config.num_hidden_layers} layers keep a recurrent state "
-                "per lane, and this feature restores, rewinds, shares or "
-                "shards K and V only. Serve it with --api HOST:PORT "
-                "--api-batch N (N > 1) --kv-mode paged --prefix-cache off "
-                "on one chip, unquantized."
-            )

@@ -325,7 +325,9 @@ def block_finish(
     moe_valid: jnp.ndarray | None = None,
     moe_dispatch: str = "auto",
     fusion: tuple | None = None,
-) -> jnp.ndarray:
+    moe_counts: bool = False,
+    moe_layer=None,
+):
     """Shared tail: out-projection + residual, rms_2 -> SwiGLU + residual,
     with the tensor-parallel psums at the two partial-sum points. A layer
     tree carrying a "router" runs the Mixtral MoE MLP instead of the dense
@@ -335,7 +337,11 @@ def block_finish(
     ``fusion`` (resolved (set, impl), ops/fuse.resolve_fusion; None = from
     the config): "norm" folds rms_2 into the fused gate|up projection
     (ops/pallas/fused_norm_matmul.py) on the dense ``w_gu`` path —
-    bit-identical either way."""
+    bit-identical either way. ``moe_counts`` (a tree with a "router" only):
+    return (x, ``moe.held_counts`` of the layer's dispatch) for the decode
+    program's account of its expert load. ``moe_layer``: the tree's routed
+    experts are a RUN of layers' stacks and this is the traced index of the
+    one to use (``ops/moe.moe_swiglu``'s ``layer``)."""
     b, chunk, _ = x.shape
     off = config.rmsnorm_offset
     if fusion is None:
@@ -371,18 +377,26 @@ def block_finish(
             h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
             config.num_experts_per_tok, tp_axis=tp_axis,
             norm_topk=config.norm_topk_prob, valid=moe_valid,
-            dispatch=moe_dispatch,
-        ).astype(x.dtype)
+            dispatch=moe_dispatch, scoring=config.moe_scoring,
+            scale=config.routed_scaling_factor,
+            expert_offset=config.expert_offset, with_counts=moe_counts,
+            layer=moe_layer,
+        )
+        if moe_counts:
+            mlp, counts = mlp
+        mlp = mlp.astype(x.dtype)
         if "sh_gu" in lp or "sh_gate" in lp:
-            # Qwen2-MoE always-on shared expert, scaled by a learned sigmoid
-            # gate (computed identically on every tp shard; the product
-            # distributes over the shared expert's partial sums).
+            # The always-on shared expert (computed identically on every tp
+            # shard and every rank of an expert-parallel deployment).
             if "sh_gu" in lp:  # fused gate|up (ops/fuse.py)
                 shared = swiglu_gu(h, lp["sh_gu"], lp["sh_down"])
             else:
                 shared = swiglu(h, lp["sh_gate"], lp["sh_up"], lp["sh_down"])
-            gate = jax.nn.sigmoid(qmat(h, lp["se_gate"]))
-            mlp = mlp + (shared * gate).astype(x.dtype)
+            if "se_gate" in lp:
+                # Qwen2-MoE scales it by a learned sigmoid gate (the product
+                # distributes over the shared expert's partial sums).
+                shared = shared * jax.nn.sigmoid(qmat(h, lp["se_gate"]))
+            mlp = mlp + shared.astype(x.dtype)
     elif "w_gu" in lp:  # fused gate|up (ops/fuse.py): one matmul, split after
         mlp = swiglu_gu(
             h, lp["w_gu"], lp["w_down"], activation=config.hidden_activation
@@ -396,7 +410,7 @@ def block_finish(
         mlp = jax.lax.psum(mlp, tp_axis)
     if "ln_post_mlp" in lp:
         mlp = rms_norm(mlp, lp["ln_post_mlp"], config.rms_norm_eps, off)
-    return x + mlp
+    return (x + mlp, counts) if moe_counts else x + mlp
 
 
 def block_forward(

@@ -233,6 +233,95 @@ def _copy_pages_impl(cache, src, dst):
 _copy_pages = tracked_jit(_copy_pages_impl, name="paged.copy_pages")
 
 
+# ------------------------------------------------------------ latent pool
+#
+# Latent attention (MLA, ``config.cache_kind == "latent"``) keeps ONE vector
+# a token a layer, shared by every head: the compressed K/V after its norm
+# and the rotary key after RoPE, side by side, padded with zeros to whole
+# 128-lane tiles (``config.latent_width``: 512 + 64 -> 640). K-and-V pools of
+# ``[.., n_kv, page_size, head_dim]`` cannot say that (V is a slice of K's
+# first numbers, and the row is not ``head_dim`` wide), so it is a pool of
+# its own. The allocator and the block tables are the SAME: a page is
+# ``page_size`` tokens whatever a token holds.
+
+
+class LatentPagedCache(NamedTuple):
+    """The latent page pool. Carried whole through the layer scans, written
+    in place (``latent_write_pool``) and read through a layer index
+    (ops/pallas/latent_attention.py), as ``PagedKVCache`` is."""
+
+    latent: jnp.ndarray  # [n_layers, n_pages, page_size, latent_width]
+
+    @property
+    def n_layers(self) -> int:
+        return self.latent.shape[0]
+
+    @property
+    def n_pages(self) -> int:
+        return self.latent.shape[1]
+
+    @property
+    def page_size(self) -> int:
+        return self.latent.shape[2]
+
+
+def init_latent_cache(
+    n_layers: int, n_pages: int, page_size: int, width: int,
+    dtype: jnp.dtype = jnp.bfloat16,
+) -> LatentPagedCache:
+    return _zero_latent_pool(
+        (n_layers, n_pages, page_size, width), jnp.dtype(dtype)
+    )
+
+
+_zero_latent_pool = tracked_jit(
+    lambda shape, dtype: LatentPagedCache(latent=jnp.zeros(shape, dtype)),
+    name="paged.init_latent_cache", static_argnames=("shape", "dtype"),
+)
+
+
+def latent_write_pool(
+    pool: jnp.ndarray,
+    layer: jnp.ndarray,
+    new: jnp.ndarray,  # [batch, chunk, latent_width]
+    pos: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    starts: jnp.ndarray | None = None,
+    ends: jnp.ndarray | None = None,
+) -> jnp.ndarray:
+    """``paged_write_pool`` for the latent pool: token ``pos + j`` of row
+    ``b`` goes to ``[layer, block_tables[b, (pos + j) // page_size], (pos +
+    j) % page_size, :]``, one scatter, in place. Unmapped entries, slots
+    below ``starts[b]`` and slots at or past ``ends[b]`` (a window's dead
+    tail) drop."""
+    n_pages, page_size = pool.shape[1], pool.shape[2]
+    b, chunk = new.shape[0], new.shape[1]
+    slots = pos + jnp.arange(chunk, dtype=jnp.int32)
+    logical = jnp.broadcast_to(slots // page_size, (b, chunk))
+    offs = jnp.broadcast_to(slots % page_size, (b, chunk))
+    phys = jnp.take_along_axis(
+        block_tables, logical, axis=1, mode="fill", fill_value=UNMAPPED
+    )
+    phys = jnp.where(phys < 0, n_pages, phys)
+    if starts is not None:
+        phys = jnp.where(slots[None, :] < starts[:, None], n_pages, phys)
+    if ends is not None:
+        phys = jnp.where(slots[None, :] >= ends[:, None], n_pages, phys)
+    return pool.at[layer, phys, offs].set(new.astype(pool.dtype), mode="drop")
+
+
+def gather_latent(
+    pool: jnp.ndarray, block_tables: jnp.ndarray, layer: jnp.ndarray
+) -> jnp.ndarray:
+    """Dense view of each row's pages of one layer, [b, n_p * page_size,
+    latent_width]: the XLA read path (CPU, and the kernel's oracle).
+    Unmapped pages read zeros; the callers' masks exclude them."""
+    bt = jnp.where(block_tables < 0, pool.shape[1], block_tables)
+    g = pool.at[layer, bt].get(mode="fill", fill_value=0)
+    b, n_p, ps, w = g.shape
+    return g.reshape(b, n_p * ps, w)
+
+
 class PageExhausted(RuntimeError):
     """The pool has no free page for a required mapping."""
 

@@ -233,6 +233,82 @@ def audit_hybrid_programs(
     return reports
 
 
+def audit_latent_programs(
+    config: LlamaConfig,
+    *,
+    n_pages: int,
+    page_size: int,
+    lanes: int,
+    table_pages: int,
+    n_steps: int,
+    join_width: int,
+    prefill_rows: int = 0,
+    dtype=jnp.bfloat16,
+    allow_pallas: bool = True,
+    sharding=None,
+) -> dict[str, dict]:
+    """``audit_paged_programs`` for a model with latent attention
+    (models/llama/latent.py): {"decode": report, "join": report} and, with
+    ``prefill_rows``, "prefill" (one group of an epoch's). The pool is the
+    latent one, [n_layers, n_pages, page_size, latent_width]; ``argument_bytes``
+    and ``temp_bytes`` together are what the program needs on the device."""
+    from cake_tpu.models.llama import latent as L
+    from cake_tpu.ops.fuse import fuse_params
+
+    def spec(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    params = abstract(jax.eval_shape(lambda: fuse_params(
+        L.init_params(config, jax.random.PRNGKey(0), dtype)
+    )))
+    cache = abstract(jax.eval_shape(
+        lambda: L.init_cache(config, n_pages, page_size, dtype)
+    ))
+    pool_shape = tuple(cache.latent.shape)
+    decode = L._latent_decode_fn(
+        config, n_steps, 0.0, None, None, 1.0, allow_pallas=allow_pallas
+    )
+    join = L._latent_join_fn(config, join_width, allow_pallas)
+    programs = {
+        "decode": lambda: decode._jitted.trace(
+            params, cache, spec((lanes,)), spec(()), spec((lanes,)),
+            spec((lanes, table_pages)), spec((lanes,), jnp.bool_),
+            spec((lanes, 2), jnp.uint32), spec((lanes, 0)), spec((lanes,)),
+        ),
+        "join": lambda: join._jitted.trace(
+            params, cache, spec((1, join_width)), spec((1,)), spec((1,)),
+            spec((1, table_pages)), spec(()),
+        ),
+    }
+    if prefill_rows:
+        g = prefill_rows
+        programs["prefill"] = lambda: L._latent_prefill_jit._jitted.trace(
+            params, spec((g, join_width)), cache, spec((g,)), spec((g,)),
+            spec((g, table_pages)), config, spec(()),
+            allow_pallas=allow_pallas,
+        )
+    reports = {}
+    for name, trace in programs.items():
+        t0 = time.perf_counter()
+        traced = trace()
+        compiled = traced.lower().compile()
+        hlo = compiled.as_text()
+        mem = compiled.memory_analysis()
+        reports[name] = {
+            "scans": scans_moving_pool(traced.jaxpr, pool_shape),
+            "pool_ops": pool_ops_in_hlo(hlo, pool_shape, dtype),
+            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
+            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
+            "pool_bytes": math.prod(pool_shape) * jnp.dtype(dtype).itemsize,
+            "kernels": hlo.count("tpu_custom_call"),
+            "seconds": round(time.perf_counter() - t0, 1),
+        }
+    return reports
+
+
 def scans_moving_pool(jaxpr, kv_shape: tuple[int, ...]) -> list[str]:
     """Every ``scan`` in ``jaxpr`` (nested ones included) that takes the
     pool, or a layer of it, as a scanned input or gives one back as a

@@ -93,6 +93,10 @@ class PeriodAccount:
             "joins": 0, "join_seconds": 0.0, "join_readback_seconds": 0.0,
             "lane_seconds": {"live": 0.0, "offered": 0.0, "idle_queued": 0.0},
             "ahead": 0, "serial": dict.fromkeys(SERIAL_WHY, 0),
+            # Sum over dispatching periods of the tokens their live lanes
+            # held in the cache (each lane's position at the dispatch):
+            # over ``count`` it is the mean cached tokens a decode dispatch.
+            "cached_tokens": 0,
         }
         self._hist = [0] * (_N_BUCKETS + 2)
         self._segment = {
@@ -153,7 +157,7 @@ class PeriodAccount:
         the host got there."""
         self._join_readback_s += seconds
 
-    def end(self, live: int | None, order: str = "") -> None:
+    def end(self, live: int | None, order: str = "", cached: int = 0) -> None:
         """The iteration ends. ``live``: lanes that decoded in it; None when
         it dispatched nothing (the segment's last look for work, a chunk
         lost to a failover), which is no period. ``order``: ``"ahead"``
@@ -170,6 +174,7 @@ class PeriodAccount:
                 return
             p["count"] += 1
             p["seconds"] += wall
+            p["cached_tokens"] += cached
             if order == "ahead":
                 p["ahead"] += 1
             else:

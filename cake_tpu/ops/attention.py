@@ -133,3 +133,57 @@ def gqa_attention(
         q, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2), q_positions, k_positions,
         window=window, window_flag=window_flag, scale=scale, softcap=softcap,
     )
+
+
+def mla_prefill_attention(
+    q_nope: jnp.ndarray,  # [b, t, heads, nope]
+    q_rope: jnp.ndarray,  # [b, t, heads, rope]   (after RoPE)
+    k_nope: jnp.ndarray,  # [b, t, heads, nope]   (ckv Wuk, a head)
+    k_rope: jnp.ndarray,  # [b, t, rope]          (after RoPE; all heads share it)
+    v: jnp.ndarray,  # [b, t, heads, v_dim]
+    live: jnp.ndarray,  # [b, t] bool: window positions that are the row's tokens
+    *,
+    scale: float,
+    starts: jnp.ndarray,  # [b] first live window position (may be < 0: clamped)
+    lengths: jnp.ndarray,  # [b] one past the last live window position
+    use_pallas: bool = False,
+) -> jnp.ndarray:
+    """Expanded latent attention over a window's OWN keys and values:
+    ordinary causal multi-head attention whose query and key are a
+    no-position part beside a rotary part (``s_h = (q_nope_h . k_nope_h +
+    q_rope_h . k_rope) * scale``) and whose values are narrower than its
+    keys. Causality is by window position, which left pads shift equally for
+    a row's queries and keys. The one softmax body of this module serves the
+    XLA path (values padded to the keys' width, sliced after); on the chip
+    the Pallas chunk kernel does (ops/pallas/chunk_prefill.py: every width
+    padded to whole 128-lane tiles, which changes no score and no sum).
+    Returns [b, t, heads, v_dim]."""
+    b, t, n, _ = q_nope.shape
+    v_dim = v.shape[-1]
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, t, n, k_rope.shape[-1]))],
+        axis=-1,
+    )
+    d = q.shape[-1]
+
+    def widen(x, to):
+        return jnp.pad(x, ((0, 0),) * 3 + ((0, to - x.shape[-1]),))
+
+    if use_pallas:
+        from cake_tpu.ops.pallas.chunk_prefill import chunk_prefill_attention
+
+        d_pad = -(-d // 128) * 128
+        out = chunk_prefill_attention(
+            widen(q, d_pad),
+            jnp.moveaxis(widen(k, d_pad), 2, 1),
+            jnp.moveaxis(widen(v, d_pad), 2, 1),
+            jnp.zeros((b,), jnp.int32), jnp.maximum(lengths, 1), None,
+            jnp.maximum(starts, 0), scale=scale,
+        )
+    else:
+        idx = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
+        out = gqa_attention(
+            q, k, widen(v, d), idx, jnp.where(live, idx, 2**30), scale=scale
+        )
+    return out[..., :v_dim]

@@ -1,38 +1,51 @@
-"""Sparse mixture-of-experts SwiGLU block (Mixtral family).
+"""Sparse mixture-of-experts SwiGLU block.
 
 The reference is dense-Llama-only (SURVEY.md §2.7 marks expert parallelism
-absent); this is a beyond-parity family. Routing follows HF Mixtral exactly
-(MixtralSparseMoeBlock): router logits -> FULL softmax over all experts in
-f32 -> top-k probabilities renormalized to sum 1 -> weighted sum of the
-selected experts' SwiGLU outputs. Pinned token-for-token against
-transformers in tests/test_moe.py.
+absent); this is a beyond-parity family. ONE routing definition
+(``route_topk_select``): router logits in float32 -> scores over ALL the
+experts the router ranks (a full softmax, HF Mixtral / Qwen-MoE; or a
+sigmoid of each logit, the DeepSeek-V3 family and ``pangu_ultra_moe``) ->
+top-k -> optional renormalisation over the chosen -> times a scaling
+factor. Pinned token-for-token against transformers in tests/test_moe.py.
 
-TPU-first formulation, two dispatch regimes sharing one routing definition:
+**The layer is told which experts it holds.** The stacked expert weights
+are ``e_local`` experts, the ``expert_offset``-th to the ``expert_offset +
+e_local``-th of the ``E`` the router ranks. It routes over all ``E`` and
+computes the part of the result its own experts give; an assignment to an
+absent expert adds nothing HERE. With ``e_local == E`` that is the whole
+layer; under ``--tp`` the offset is ``axis_index * e_local`` and the
+per-branch ``psum`` in block_finish adds the shards' parts; on one chip that
+serves one rank's share of an expert-parallel deployment the offset comes
+from the configuration (``config.expert_offset``) and the absent experts'
+part is left out, in the program and in the plain reference alike. Nothing
+stands in for the absent chips or their exchange.
 
-  * **Dense combine** (1-token decode, tp-sharded experts): every expert's
-    SwiGLU runs as one batched einsum and the per-token routing probability
-    (zero for unselected experts) is applied in the combine. No
-    gather/scatter, no ragged shapes. Batch-1 decode is weight-bandwidth-
-    bound (every expert's weights stream from HBM regardless of routing), so
-    the E/k extra MLP FLOPs are free there — and under expert-sharded tp the
-    masked combine IS the cross-shard protocol (see below).
-  * **Grouped dispatch** (prefill / batched chunks): token-expert
-    assignments are sorted by expert and each expert multiplies only its own
-    contiguous row group via ``jax.lax.ragged_dot`` (the TPU grouped-matmul
-    primitive), so MLP FLOPs are proportional to top_k/E of the dense
-    combine — 4x fewer for Mixtral's top-2-of-8. Shapes stay static
-    (sort + bincount + scatter-add combine); only the group boundaries are
-    data-dependent, which ragged_dot is built for.
+Three ways to compute it, ONE rule to choose (``moe_swiglu``):
 
-Expert parallelism: shard the EXPERT axis of the stacked weights over the
-``tp`` mesh axis (parallel/tensor.py). Each device computes its local
-experts' contribution — the routing mask zeroes tokens routed elsewhere —
-and the existing per-branch ``psum`` in block_finish combines partial sums.
-The router weight is replicated, so every shard computes identical full
-routing probabilities and slices its own expert block by ``axis_index``.
+  * **Grouped, drop-free** (``dispatch="auto"``, any number of tokens in the
+    dispatch ``n = batch * chunk``; ``GROUPED_MIN_TOKENS`` says where the
+    timings are): the token-expert assignments are sorted by held expert,
+    assignments to absent experts and of pad slots past the end, the rows
+    filled to whole tiles, and each expert multiplies its own contiguous
+    rows (``_ragged``: the Pallas grouped matmul on the TPU,
+    ``jax.lax.ragged_dot`` elsewhere): FLOPs follow the assignments that
+    land here, and an expert nobody chose is never read. Shapes are static
+    (sort, counts, a product with the routing-weight matrix to combine),
+    only the group boundaries are data; no assignment is ever dropped.
+  * **Dense combine** (``dispatch="dense"``, or below ``GROUPED_MIN_TOKENS``
+    when a test raises it): every held expert's SwiGLU runs on every token
+    as one batched einsum and the routing weight (zero where the expert was
+    not chosen) is applied in the combine. It reads every held expert
+    whatever the routing, which is why it lost at every size timed.
+  * **Capacity buckets** (a ``--tp``-sharded PREFILL chunk only: ``tp_axis``
+    set and ``chunk >= EP_CAPACITY_MIN_CHUNK``): a fixed row budget an
+    expert, overflow DROPS (``EP_CAPACITY_FACTOR``): the accepted trade of
+    that path, kept where it was.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -53,55 +66,132 @@ def _qeinsum(spec: str, x: jnp.ndarray, w) -> jnp.ndarray:
 
 
 def route_topk_select(
-    logits: jnp.ndarray, top_k: int, norm_topk: bool = True
+    logits: jnp.ndarray,
+    top_k: int,
+    norm_topk: bool = True,
+    scoring: str = "softmax",
+    scale: float = 1.0,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """HF routing: full softmax (f32) -> top-k -> optional renormalize.
+    """Scores (f32) over every ranked expert -> top-k -> optional
+    renormalise -> scale.
 
-    Mixtral always renormalizes the selected probabilities to sum 1;
-    Qwen2-MoE gates this with ``norm_topk_prob`` (usually off). THE one
-    routing definition — both the dense combine and the grouped dispatch
-    build on these (values [..., k], expert indices [..., k])."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    topv, topi = jax.lax.top_k(probs, top_k)
+    ``softmax``: Mixtral always renormalises the selected probabilities to
+    sum 1; Qwen2-MoE gates this with ``norm_topk_prob`` (usually off).
+    ``sigmoid``: each logit on its own, the chosen renormalised by their sum
+    (+1e-20) and multiplied by ``scale`` (``routed_scaling_factor``). THE one
+    routing definition: every dispatch builds on these (values [..., k],
+    expert indices [..., k])."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown MoE scoring {scoring!r}")
+    topv, topi = jax.lax.top_k(scores, top_k)
     if norm_topk:
-        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        topv = topv * scale
     return topv, topi
 
 
-def route_topk(
-    logits: jnp.ndarray, top_k: int, n_experts: int, norm_topk: bool = True
-) -> jnp.ndarray:
-    """Dense combine weights [..., n_experts], zero for unselected experts."""
-    topv, topi = route_topk_select(logits, top_k, norm_topk)
-    onehot = jax.nn.one_hot(topi, n_experts, dtype=jnp.float32)
-    return jnp.einsum("...k,...ke->...e", topv, onehot)
+def router_logits(x: jnp.ndarray, router_w: jnp.ndarray) -> jnp.ndarray:
+    """[..., E] float32 logits: the activations widened, the product in
+    float32 at the highest matmul precision (the router is a sliver of the
+    layer, and a near-tie turned by bf16 picks another expert)."""
+    return jnp.einsum(
+        "...h,he->...e", x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
 
-# Below this many tokens the dense combine wins: the sort/gather/scatter
-# fixed cost exceeds the saved matmul work, and 1-token decode is
-# weight-bandwidth-bound anyway (all experts stream from HBM regardless).
+# Tokens in a dispatch (batch * chunk) from which the grouped path serves:
+# from the first. Timed against the dense combine on the chip at
+# ``pangu-ultra-ep16-chat-closed``'s sparse layer (16 held experts of 256, 8
+# a token; ms a layer, the run's stacks with a layer index; PERF.md section
+# 6, PR 32), the dense combine reads all 16 experts whatever the routing and
+# takes 2.19 to 2.30 at any of these sizes, the grouped path reads the
+# experts somebody chose: 0.18 at 1 token, 0.25 at 2, 0.40 at 4, 0.67 at 8,
+# 0.96 at 16, 1.38 at 32, 2.14 at 64. So ``dispatch="auto"`` never takes the
+# dense combine at the default; it stays for ``dispatch="dense"`` (a ``--tp``
+# verify chunk) and as the reference the tests hold the grouped path to.
 #
 # ACCEPTED NUMERICS SEAM: the two paths reduce expert contributions in
-# different orders, so the same sequence can emit different low-precision
-# token streams depending on chunk length (prefill chunk >= threshold takes
-# the grouped path, decode takes the dense one). This is chunk-size-dependent
-# stream divergence by design, not a bug; parity tests compare within
-# tolerance. To force ONE path process-wide (e.g. bitwise-reproducibility
-# runs), set this to 0 (always grouped when ungated) or a huge value
+# different orders. Parity tests compare within tolerance. To force ONE path
+# process-wide, set this to 0 (always grouped when ungated) or a huge value
 # (always dense) before tracing.
-GROUPED_MIN_TOKENS = 8
+GROUPED_MIN_TOKENS = 1
 
 
-def _ragged(xs: jnp.ndarray, w, group_sizes: jnp.ndarray, eids: jnp.ndarray):
-    """``ragged_dot`` against stacked expert weights, plain or int8-quantized.
+_TILE = 128  # rows, and every matrix dimension, the TPU's grouped kernel tiles by
+
+
+def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """(rows, contraction, columns) of one step of the grouped kernel. The
+    kernel visits every (tile of rows, expert with rows in it) pair and reads
+    that expert's whole matrix for it, so a visit costs the larger of the
+    matrix's read and the tile's product: at 256 rows the two are level on a
+    v5e (7680 x 2048 bf16: 38 us to read, 41 to multiply), at 512 the
+    product doubles (measured: 4.7 ms for 1024 rows, 2.4 predicted at 256;
+    PERF.md, PR 32). The weights go through in the largest slabs that divide
+    the dimension (multiples of the lane tile, at most 1280 x 1024 or 1024 x
+    1280: 2.6 MB, double-buffered well inside a core's VMEM)."""
+    def slab(x, most):
+        return max(t for t in range(_TILE, most + 1, _TILE) if x % t == 0)
+
+    tm = 2 * _TILE if m % (2 * _TILE) == 0 else _TILE
+    tk, tn = (slab(k, 1280), slab(n, 1024)) if k >= n else (slab(k, 1024), slab(n, 1280))
+    return tm, tk, tn
+
+
+def _of_layer(w, layer):
+    """One layer's expert stack out of a run's (``layer`` None: ``w`` is one
+    layer's already). Inside an XLA product the index fuses into the read."""
+    return w if layer is None else w[layer]
+
+
+def _ragged(
+    xs: jnp.ndarray, w, group_sizes: jnp.ndarray, eids: jnp.ndarray, layer=None
+):
+    """Each expert's contiguous rows of ``xs`` times that expert's matrix,
+    against stacked expert weights [e, k, n] (with ``layer``: a run of
+    layers' stacks [n_layers, e, k, n] and the traced index of the one to
+    use), plain or int8-quantized. On the TPU the Pallas grouped matmul JAX
+    ships (``megablox.gmm``: a step is a tile of rows of ONE expert against a
+    slab of its weights, an expert without rows is never read); elsewhere,
+    and for shapes it does not tile, ``jax.lax.ragged_dot``. Rows past the
+    last group are left as they come.
+
+    **A kernel's operand is a whole array**: handed one layer's slice of a
+    run's stack, XLA copies the slice out first (1.5 GB a layer at
+    ``pangu-ultra-ep16-chat-closed``'s sizes, three times the product's own
+    time: PERF.md, PR 32). So with ``layer`` the kernel is given the whole
+    run's experts as one group list [n_layers * e, k, n] and this layer's
+    group sizes at their place in it, zeros elsewhere: what PR 26 did for the
+    page pool.
 
     The QuantWeight scale is per-expert per-output-channel [E, 1, out]; each
     sorted row multiplies its own expert's scale row (gathered by ``eids``)."""
     if isinstance(w, QuantWeight):
+        w = jax.tree.map(lambda a: _of_layer(a, layer), w)
         out = jax.lax.ragged_dot(xs, w.w.astype(xs.dtype), group_sizes)
         e, _, o = w.scale.shape
         return out * w.scale.reshape(e, o)[eids].astype(xs.dtype)
-    return jax.lax.ragged_dot(xs, w, group_sizes)
+    m, (e, k, n) = xs.shape[0], w.shape[-3:]
+    if jax.default_backend() == "tpu" and not (m % _TILE or k % _TILE or n % _TILE):
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        if layer is not None:
+            group_sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((w.shape[0] * e,), jnp.int32), group_sizes, (layer * e,)
+            )
+            w = w.reshape(-1, k, n)
+        return gmm(
+            xs, w, group_sizes, preferred_element_type=xs.dtype,
+            tiling=_gmm_tiling(m, k, n),
+        )
+    return jax.lax.ragged_dot(xs, _of_layer(w, layer), group_sizes)
 
 
 # Expert-capacity dispatch (tp-sharded prefill): per-LOCAL-expert row budget
@@ -113,16 +203,20 @@ def _ragged(xs: jnp.ndarray, w, group_sizes: jnp.ndarray, eids: jnp.ndarray):
 # construction, which is what lets tp-sharded prefill run FLOPs ∝ k/tp
 # instead of the dense all-experts combine.
 EP_CAPACITY_FACTOR = 2.0
+# The chunk width from which a ``--tp`` chunk is a PREFILL chunk and takes
+# the buckets (what ``GROUPED_MIN_TOKENS`` was when it decided this too): a
+# decode step, one token a lane, must not drop.
+EP_CAPACITY_MIN_CHUNK = 8
 
 
 def _capacity_dispatch(
     x: jnp.ndarray,  # [b, t, h]
-    logits: jnp.ndarray,  # [b, t, E_total]
+    topv: jnp.ndarray,  # [b, t, k] combine weights of the chosen
+    topi: jnp.ndarray,  # [b, t, k] the chosen, among the ranked
+    n_ranked: int,
     w_gate, w_up, w_down,  # [e_local, ...]
-    top_k: int,
     e_local: int,
-    tp_axis: str,
-    norm_topk: bool,
+    offset: jnp.ndarray,  # the first held expert among the ranked
     valid: jnp.ndarray | None = None,  # [b, t] bool; False = pad slot
 ) -> jnp.ndarray:
     """Capacity-bucketed expert dispatch for tp-sharded prefill.
@@ -135,12 +229,11 @@ def _capacity_dispatch(
     — ∝ k/tp, where the dense combine pays n * E/tp (E/(k*cf)x more).
     """
     b, t, h = x.shape
+    top_k = topi.shape[-1]
     n = b * t
     nk = n * top_k
-    cap = max(1, -(-int(EP_CAPACITY_FACTOR * nk) // logits.shape[-1]))
-    topv, topi = route_topk_select(logits, top_k, norm_topk)
+    cap = max(1, -(-int(EP_CAPACITY_FACTOR * nk) // n_ranked))
 
-    offset = jax.lax.axis_index(tp_axis) * e_local
     eid = topi.reshape(nk) - offset  # local expert id; out of [0, e_local) = remote
     tok = jnp.repeat(jnp.arange(n, dtype=jnp.int32), top_k)
     wts = topv.reshape(nk)
@@ -178,6 +271,88 @@ def _capacity_dispatch(
     return out.reshape(b, t, h).astype(x.dtype)
 
 
+def _row_budget(nk: int, e_local: int, n_ranked: int) -> int:
+    """Sorted rows the grouped products and the combine are given, of ``nk``
+    assignments. A share sees ``e_local / n_ranked`` of them on average, and
+    gather, products and combine pay for every row they are given (the
+    combine is rows x tokens x hidden): with fewer experts held than ranked
+    the rows are cut to four times that mean, in whole tiles, and a dispatch
+    whose held assignments overrun it (a skewed router) takes all rows
+    instead. Either way every held assignment is computed."""
+    return -(-4 * nk * e_local // (n_ranked * _TILE)) * _TILE
+
+
+def _grouped_dispatch(
+    x: jnp.ndarray,  # [b, t, h]
+    topv: jnp.ndarray,  # [b, t, k]
+    topi: jnp.ndarray,  # [b, t, k]
+    n_ranked: int,
+    w_gate, w_up, w_down,  # [e_local, ...]
+    e_local: int,
+    offset,  # the first held expert among the ranked (int or traced)
+    valid: jnp.ndarray | None = None,  # [b, t] bool; False = not a token
+    layer=None,  # the weights are a run's stacks: the layer to use
+) -> jnp.ndarray:
+    """The drop-free grouped path over the HELD experts: every assignment
+    that lands on one of them is a row of its group; the others (absent
+    experts, pad slots, dead lanes) sort past the last group, multiply
+    nothing and add nothing."""
+    b, t, h = x.shape
+    top_k = topi.shape[-1]
+    n, nk = b * t, b * t * top_k
+    eid = topi.reshape(nk) - offset
+    held = (eid >= 0) & (eid < e_local)
+    if valid is not None:
+        held &= jnp.repeat(valid.reshape(n), top_k)
+    key = jnp.where(held, eid, e_local).astype(jnp.int32)
+    # Whole row tiles, so that any number of tokens takes the TPU's grouped
+    # kernel: the filling belongs to no expert, like an absent expert's rows
+    # (its token index lies past the last token: what a gather returns there
+    # is selected away below, and the combine's one-hot row is zero).
+    rows_all = -(-nk // _TILE) * _TILE
+    key = jnp.concatenate([key, jnp.full((rows_all - nk,), e_local, jnp.int32)])
+    order = jnp.argsort(key, stable=True)
+    key_s = key[order]
+    tok_s = (order // top_k).astype(jnp.int32)
+    wts_s = jnp.where(key_s < e_local, topv.reshape(nk)[order], 0.0)
+    eid_s = jnp.minimum(key_s, e_local - 1)  # a quantised scale's row
+    group_sizes = jnp.bincount(key_s, length=e_local + 1)[:e_local].astype(
+        jnp.int32
+    )
+    x_flat = x.reshape(n, h)
+
+    def experts(rows: int) -> jnp.ndarray:
+        """The first ``rows`` sorted assignments through their experts
+        (every held assignment is among them), summed into their tokens."""
+        xs = x_flat[tok_s[:rows]]  # [rows, hidden], sorted by held expert
+        with jax.named_scope("moe_experts_grouped"):
+            g = jax.nn.silu(_ragged(xs, w_gate, group_sizes, eid_s[:rows], layer))
+            u = _ragged(xs, w_up, group_sizes, eid_s[:rows], layer)
+            y = _ragged(g * u, w_down, group_sizes, eid_s[:rows], layer)
+        # Rows past the last group belong to no expert: whatever the grouped
+        # product left there is selected away, not multiplied by zero.
+        y = jnp.where((key_s[:rows] < e_local)[:, None], y, 0)
+        # The combine is a product with the [rows, tokens] matrix of routing
+        # weights, not a scatter-add: the TPU scatters a row at a time (26 ms
+        # for 4096 rows of 7680 where this takes 3.5; PERF.md, PR 32).
+        place = jax.nn.one_hot(tok_s[:rows], n, dtype=y.dtype) * (
+            wts_s[:rows, None].astype(y.dtype)
+        )
+        return jnp.einsum(
+            "rn,rh->nh", place, y, preferred_element_type=jnp.float32
+        ).astype(y.dtype)
+
+    budget = _row_budget(nk, e_local, n_ranked)
+    if budget >= rows_all:
+        out = experts(rows_all)
+    else:
+        out = jax.lax.cond(
+            jnp.sum(group_sizes) <= budget,
+            lambda: experts(budget), lambda: experts(rows_all),
+        )
+    return out.reshape(b, t, h).astype(x.dtype)
+
+
 def moe_swiglu(
     x: jnp.ndarray,
     router_w: jnp.ndarray,
@@ -189,39 +364,46 @@ def moe_swiglu(
     norm_topk: bool = True,
     valid: jnp.ndarray | None = None,
     dispatch: str = "auto",
-) -> jnp.ndarray:
-    """Routed SwiGLU over stacked experts.
+    scoring: str = "softmax",
+    scale: float = 1.0,
+    expert_offset: int = 0,
+    with_counts: bool = False,
+    layer=None,
+):
+    """Routed SwiGLU over the stacked experts held here.
 
     Args:
       x: [batch, chunk, hidden] (post-norm activations).
-      router_w: [hidden, n_experts_total] — REPLICATED under tp.
-      w_gate/w_up: [n_local_experts, hidden, inter]; w_down:
-        [n_local_experts, inter, hidden] — the expert axis is the tp shard
-        axis, so n_local_experts = n_experts_total / tp.
+      router_w: [hidden, E]: every expert the router ranks; REPLICATED
+        under tp.
+      w_gate/w_up: [e_local, hidden, inter]; w_down: [e_local, inter,
+        hidden]: the experts held here, ``e_local <= E`` (the tp shard axis,
+        or one rank's share of an expert-parallel deployment).
       top_k: experts combined per token (config.num_experts_per_tok).
       tp_axis: mesh axis name when running inside shard_map with sharded
-        experts; the result is then a PARTIAL sum (caller psums, matching
-        the dense-MLP row-parallel convention in block_finish). Decode keeps
-        the dense combine under tp (the zero-masked combine is the
-        cross-shard protocol, and 1-token decode is weight-bandwidth-bound
-        anyway); PREFILL chunks >= GROUPED_MIN_TOKENS take the
-        expert-CAPACITY dispatch (_capacity_dispatch): a fixed per-local-
-        expert row budget keeps shapes static while shard MLP FLOPs drop to
-        ∝ k/tp — overflow assignments drop per EP_CAPACITY_FACTOR.
-      norm_topk: renormalize the selected probabilities (Mixtral yes,
-        Qwen2-MoE usually no).
-      valid: optional [batch, chunk] bool — False marks PAD slots
-        (left-padded lockstep batches) whose assignments must not consume
-        expert capacity; their own outputs are garbage nobody reads.
+        experts: the held experts then start at ``axis_index * e_local``
+        and the result is a PARTIAL sum (caller psums, matching the
+        dense-MLP row-parallel convention in block_finish).
+      layer: None, or a traced index: ``w_gate``/``w_up``/``w_down`` are then
+        the stacks of a RUN of layers [n_layers, e_local, ...] and this is the
+        one to use. A model whose layer scan would hand a Pallas kernel one
+        layer's slice passes the run whole (``_ragged`` says why).
+      expert_offset: where the held experts start among the ranked when
+        ``tp_axis`` is None (config.expert_offset; 0 with the whole model).
+      norm_topk / scoring / scale: ``route_topk_select``'s.
+      valid: optional [batch, chunk] bool. False marks slots that are no
+        token (left pads, dead lanes): their assignments take no expert's
+        rows or capacity; their own outputs are garbage nobody reads.
 
-    ``dispatch`` = "dense" forces the drop-free dense combine regardless of
-    chunk width — REQUIRED for speculative verify chunks under tp (the
-    capacity path may drop expert contributions, and greedy speculation
-    promises byte-exact streams; runtime/batch_backend.py's tp verify ops
-    set this). "auto" (default) picks by width/tp as documented above;
-    chunked prefill's capacity drops are the accepted trade.
+    ``dispatch`` = "dense" forces the drop-free dense combine whatever the
+    tokens: REQUIRED for speculative verify chunks under tp (the capacity
+    path may drop expert contributions, and greedy speculation promises
+    byte-exact streams; runtime/batch_backend.py's tp verify ops set this).
+    "auto" (default) is the module docstring's rule.
 
-    Returns [batch, chunk, hidden] in x's dtype (partial under tp).
+    Returns [batch, chunk, hidden] in x's dtype (partial under tp, or of a
+    share); with ``with_counts`` a pair of that and ``held_counts``' int32
+    [4] of this call.
     """
     if dispatch not in ("auto", "dense"):
         raise ValueError(f"unknown MoE dispatch {dispatch!r}")
@@ -237,45 +419,55 @@ def moe_swiglu(
             "quantize_layer_tree(mode='int4') which keeps experts int8"
         )
     e_local = (
-        w_gate.w.shape[0]
-        if isinstance(w_gate, (QuantWeight, Quant4Weight))
-        else w_gate.shape[0]
-    )
-    logits = x @ router_w.astype(x.dtype)  # [b, t, E_total]
+        w_gate.w if isinstance(w_gate, (QuantWeight, Quant4Weight)) else w_gate
+    ).shape[-3]
+    logits = router_logits(x, router_w)  # [b, t, E] float32
     b, t, h = x.shape
-    # "dense" must skip BOTH grouped branches explicitly (a width sentinel
-    # would break under the documented GROUPED_MIN_TOKENS=0 forcing knob).
-    grouped_ok = dispatch != "dense" and t >= GROUPED_MIN_TOKENS
-    if tp_axis is not None and grouped_ok:
-        return _capacity_dispatch(
-            x, logits, w_gate, w_up, w_down, top_k, e_local, tp_axis,
-            norm_topk, valid=valid,
+    n_ranked = logits.shape[-1]
+    topv, topi = route_topk_select(logits, top_k, norm_topk, scoring, scale)
+    offset = (
+        expert_offset if tp_axis is None
+        else jax.lax.axis_index(tp_axis) * e_local
+    )
+    # THE rule (module docstring): by the tokens in the dispatch.
+    if dispatch != "dense" and b * t >= GROUPED_MIN_TOKENS:
+        path = (
+            _capacity_dispatch  # (--tp prefill: one layer's stacks)
+            if tp_axis is not None and t >= EP_CAPACITY_MIN_CHUNK
+            else functools.partial(_grouped_dispatch, layer=layer)
         )
-    if tp_axis is None and grouped_ok:
-        # Grouped dispatch (prefill / batched chunks): FLOPs ∝ top_k/E.
-        topv, topi = route_topk_select(logits, top_k, norm_topk)
-        n = b * t
-        eids = topi.reshape(n * top_k)
-        tok = jnp.repeat(jnp.arange(n, dtype=jnp.int32), top_k)
-        wts = topv.reshape(n * top_k)
-        order = jnp.argsort(eids)
-        eids_s = eids[order]
-        tok_s = tok[order]
-        wts_s = wts[order]
-        xs = x.reshape(n, h)[tok_s]  # [n*k, hidden], expert-sorted
-        group_sizes = jnp.bincount(eids_s, length=e_local).astype(jnp.int32)
-        g = jax.nn.silu(_ragged(xs, w_gate, group_sizes, eids_s))
-        u = _ragged(xs, w_up, group_sizes, eids_s)
-        y = _ragged(g * u, w_down, group_sizes, eids_s)  # [n*k, hidden]
-        y = y * wts_s[:, None].astype(y.dtype)
-        out = jnp.zeros((n, h), y.dtype).at[tok_s].add(y)
-        return out.reshape(b, t, h).astype(x.dtype)
+        out = path(
+            x, topv, topi, n_ranked, w_gate, w_up, w_down, e_local, offset,
+            valid=valid,
+        )
+    else:
+        onehot = jax.nn.one_hot(topi - offset, e_local, dtype=jnp.float32)
+        weights = jnp.einsum("...k,...ke->...e", topv, onehot)
+        with jax.named_scope("moe_experts_dense"):
+            g = jax.nn.silu(_qeinsum("bth,ehi->btei", x, _of_layer(w_gate, layer)))
+            u = _qeinsum("bth,ehi->btei", x, _of_layer(w_up, layer))
+            y = _qeinsum("btei,eih->bteh", g * u, _of_layer(w_down, layer))
+        out = jnp.einsum(
+            "bteh,bte->bth", y, weights.astype(y.dtype)
+        ).astype(x.dtype)
+    if with_counts:
+        return out, held_counts(topi, e_local, offset, valid)
+    return out
 
-    weights = route_topk(logits, top_k, logits.shape[-1], norm_topk)
-    if tp_axis is not None:
-        offset = jax.lax.axis_index(tp_axis) * e_local
-        weights = jax.lax.dynamic_slice_in_dim(weights, offset, e_local, axis=-1)
-    g = jax.nn.silu(_qeinsum("bth,ehi->btei", x, w_gate))
-    u = _qeinsum("bth,ehi->btei", x, w_up)
-    y = _qeinsum("btei,eih->bteh", g * u, w_down)
-    return jnp.einsum("bteh,bte->bth", y, weights.astype(y.dtype)).astype(x.dtype)
+
+def held_counts(
+    topi: jnp.ndarray, e_local: int, offset, valid: jnp.ndarray | None
+) -> jnp.ndarray:
+    """int32 [4] of one routed dispatch: assignments routed (of tokens that
+    are tokens), assignments to HELD experts, held experts with at least one
+    (what the step had to read), the largest load of one held expert."""
+    live = (
+        jnp.ones(topi.shape[:-1], bool) if valid is None
+        else valid.reshape(topi.shape[:-1])
+    )
+    onehot = jax.nn.one_hot(topi - offset, e_local, dtype=jnp.int32)
+    load = jnp.sum(onehot * live[..., None, None], axis=tuple(range(topi.ndim)))
+    routed = jnp.sum(live) * topi.shape[-1]
+    return jnp.stack([
+        routed.astype(jnp.int32), jnp.sum(load), jnp.sum(load > 0), jnp.max(load),
+    ]).astype(jnp.int32)

@@ -693,3 +693,173 @@ def timed_matmul_chain(n: int, steps: int, repeats: int = 3) -> dict:
         "elapsed_s": min(elapsed),
         "finite": bool(jnp.isfinite(out.astype(jnp.float32)).all()),
     }
+
+
+# ------------------------------------------------- latent attention, experts
+
+
+def run_latent_checks(
+    n_heads: int, rank: int, rope: int, page_size: int, lanes: int,
+    dtype: str = "bf16", table_pages: int = 8,
+) -> dict:
+    """``latent_decode_attention`` against its XLA twin at a model's widths:
+    every page live, rows that start past slot 0 (left pads) and end inside
+    a page, one row with a single live token. ``{"results", "interpret"}``
+    as ``run_checks`` gives them."""
+    from cake_tpu.ops.pallas.latent_attention import (
+        latent_decode_attention, latent_decode_attention_xla,
+    )
+
+    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    tol = 2.0**-6 if dtype == "bf16" else 1e-4
+    width = -(-(rank + rope) // 128) * 128
+    layers, n_pages = 2, lanes * table_pages
+    pool = jax.random.normal(
+        jax.random.PRNGKey(3), (layers, n_pages, page_size, width), dt
+    )
+    q = jax.random.normal(jax.random.PRNGKey(5), (lanes, n_heads, width), dt)
+    tables = jnp.asarray(
+        np.random.default_rng(0).permutation(n_pages).reshape(lanes, table_pages),
+        jnp.int32,
+    )
+    slots = table_pages * page_size
+    rows = np.arange(lanes)
+    cases = {
+        "every page live": (np.zeros(lanes, np.int32), np.full(lanes, slots, np.int32)),
+        "left pads, ragged ends": (
+            (rows * 37) % (slots // 2), slots // 2 + 1 + (rows * 53) % (slots // 2)),
+        "one live token": (np.full(lanes, slots - 1, np.int32), np.full(lanes, slots, np.int32)),
+    }
+    results = []
+    with recorded_interpret() as seen:
+        for name, (starts, lengths) in cases.items():
+            rec = {"kernel": "latent_decode_attention", "case": name, "tol": tol}
+            try:
+                args = (q, pool, jnp.asarray(lengths, jnp.int32), tables,
+                        jnp.asarray(starts, jnp.int32))
+                kw = dict(layer=jnp.int32(1), rank=rank, scale=(rank // 4 + rope) ** -0.5)
+                got, first = _timed(lambda: latent_decode_attention(*args, **kw))
+                want = latent_decode_attention_xla(*args, **kw)
+                rec.update(max_err=_rel_err(got, want), first_call_s=round(first, 2))
+                rec["ok"] = rec["max_err"] <= tol
+            except Exception as e:  # noqa: BLE001 - the compiler's message is the result
+                rec.update(ok=False, error=f"{type(e).__name__}: {str(e)[:300]}")
+            results.append(rec)
+    return {"results": results, "interpret": seen}
+
+
+def timed_latent_decode(
+    n_heads: int, rank: int, rope: int, page_size: int, lanes: int, layers: int,
+    dtype: str = "bf16", table_pages: tuple[int, ...] = (8, 16, 32),
+    live_tokens: int = 450, calls: int = 128, repeats: int = 3,
+) -> list[dict]:
+    """``latent_decode_attention`` alone, as ``timed_paged_decode`` times its
+    kernel: microseconds a call with every page of the table live and with
+    ``live_tokens`` live a row."""
+    from cake_tpu.ops.pallas.latent_attention import latent_decode_attention
+
+    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    width = -(-(rank + rope) // 128) * 128
+    n_pages = lanes * max(table_pages)
+    pool = jax.random.normal(
+        jax.random.PRNGKey(3), (layers, n_pages, page_size, width), dt
+    ) * 0.1
+    q0 = jax.random.normal(jax.random.PRNGKey(5), (lanes, n_heads, width), dt)
+    perm = np.random.default_rng(0).permutation(n_pages)
+    pad = jnp.zeros((lanes, n_heads, width - rank), dt)
+
+    @jax.jit
+    def chain(q, pool, lengths, tables):
+        def call(i, q):
+            c = latent_decode_attention(
+                q, pool, lengths, tables, layer=i % layers, rank=rank,
+                scale=(rank // 4 + rope) ** -0.5,
+            )
+            return jnp.concatenate([c, pad], axis=-1)  # the next call's query
+
+        return jax.lax.fori_loop(0, calls, call, q)
+
+    def us_a_call(tables, length):
+        args = (q0, pool, jnp.full((lanes,), length, jnp.int32), tables)
+        _timed(chain, *args)
+        fastest = min(_timed(chain, *args)[1] for _ in range(repeats))
+        return round(fastest / calls * 1e6, 1)
+
+    rows = []
+    for n_p in table_pages:
+        tables = jnp.asarray(perm[: lanes * n_p].reshape(lanes, n_p), jnp.int32)
+        slots = n_p * page_size
+        live = min(live_tokens, slots)
+        rows.append({
+            "table_pages": n_p, "live_tokens": live,
+            "full_us": us_a_call(tables, slots),
+            "live_us": us_a_call(tables, live),
+        })
+    return rows
+
+
+def timed_expert_layer(
+    hidden: int, inter: int, held: int, ranked: int, top_k: int,
+    tokens: tuple[int, ...], dtype: str = "bf16", layers: int = 4,
+    calls: int = 16, repeats: int = 3,
+) -> list[dict]:
+    """One sparse layer's routed experts at a model's widths, ``held`` of
+    ``ranked`` experts here (ops/moe.moe_swiglu, sigmoid routing): at each
+    number of tokens in a dispatch the DENSE COMBINE against the GROUPED
+    path, milliseconds a call, and the largest difference of the two results
+    of ONE call (in units of the result's spread). ``calls`` dependent calls over
+    ``layers`` different layers' weights make one program, so a call streams
+    its weights from HBM as a decode step does."""
+    from cake_tpu.ops import moe
+
+    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    std = 0.02
+    router = jax.random.normal(keys[0], (layers, hidden, ranked), dt) * std
+    w_gate = jax.random.normal(keys[1], (layers, held, hidden, inter), dt) * std
+    w_up = jax.random.normal(keys[2], (layers, held, hidden, inter), dt) * std
+    w_down = jax.random.normal(keys[3], (layers, held, inter, hidden), dt) * std
+    kw = dict(top_k=top_k, scoring="sigmoid", scale=2.5, norm_topk=True)
+
+    def chain(dispatch):
+        @jax.jit
+        def run(x, router, w_gate, w_up, w_down):
+            def call(i, x):
+                li = i % layers
+                y = moe.moe_swiglu(  # the run's stacks and the layer, as the model passes them
+                    x, router[li], w_gate, w_up, w_down, layer=li,
+                    dispatch=dispatch, **kw,
+                )
+                x = (x + y).astype(jnp.float32)  # the next call's input
+                return (x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True))).astype(dt)
+
+            return jax.lax.fori_loop(0, calls, call, x)
+
+        return run
+
+    def once(dispatch):
+        return jax.jit(lambda x, r, g, u, d: moe.moe_swiglu(
+            x, r[0], g, u, d, layer=jnp.int32(0), dispatch=dispatch, **kw
+        ))
+
+    rows = []
+    for n in tokens:
+        x = jax.random.normal(keys[4], (n, 1, hidden), dt)
+        args = (x, router, w_gate, w_up, w_down)
+        rec = {"tokens": n}
+        outs = {}
+        for dispatch, name in (("dense", "dense_ms"), ("auto", "grouped_ms")):
+            if dispatch == "dense" and n * held * inter * 4 > 2**31:
+                continue  # the dense combine's [tokens, held, inter] is too large
+            fn = chain(dispatch)
+            _timed(fn, *args)  # compile + warm
+            fastest = min(_timed(fn, *args)[1] for _ in range(repeats))
+            outs[dispatch] = np.asarray(once(dispatch)(*args), np.float32)
+            rec[name] = round(fastest / calls * 1e3, 3)
+        if len(outs) == 2:
+            # ONE call's results (a chain re-routes on its own rounding)
+            rec["max_diff_in_stds"] = float(
+                np.abs(outs["dense"] - outs["auto"]).max() / max(outs["dense"].std(), 1e-9)
+            )
+        rows.append(rec)
+    return rows

@@ -885,10 +885,15 @@ class ApiServer:
                         # "Continuous scheduling") plus the spill table's
                         # current depth — preempted lanes parked host-side
                         # awaiting a restore.
-                        state_facts = getattr(
-                            getattr(api.engine, "backend", None),
-                            "state_facts", None,
-                        )
+                        backend = getattr(api.engine, "backend", None)
+                        for key in ("cache", "moe"):
+                            # What the lanes' pages hold and cost; the
+                            # decode programs' account of the expert layer
+                            # (a latent model's: runtime/batch_backend.py).
+                            facts = getattr(backend, f"{key}_facts", None)
+                            if facts is not None:
+                                body["engine"][key] = facts()
+                        state_facts = getattr(backend, "state_facts", None)
                         if state_facts is not None:
                             # The recurrent state beside the page pool
                             # (models/llama/hybrid.py): cumulative

@@ -63,7 +63,9 @@ from cake_tpu.models.llama.paged_cache import (
     PageAllocator,
     init_paged_cache,
 )
-from cake_tpu.models.llama.config import LlamaConfig
+from cake_tpu.models.llama.config import (
+    CACHE_KV, CACHE_KV_STATE, CACHE_LATENT, LlamaConfig,
+)
 from cake_tpu.models.llama.fused import sample_step, sampled_decode_scan
 from cake_tpu.ops.rope import model_rope_tables
 from cake_tpu.parallel.pipeline import STAGE_AXIS, place_stage_model
@@ -357,6 +359,7 @@ class _PagedBackend:
     """
 
     kv_mode = "paged"
+    cache_kind = CACHE_KV  # what the leaf's cache holds (config.cache_kind)
     lookahead = 1  # decode chunks the engine may enqueue ahead of its reads
 
     def __init__(
@@ -403,6 +406,7 @@ class _PagedBackend:
         # (``GET /stats`` engine.state.lane_writes).
         self.state_lane_writes = 0
         self._state_lanes = 0
+        self._note_cache()
 
     # --------------------------------------------------- kernel dispatch
 
@@ -481,6 +485,43 @@ class _PagedBackend:
             self.allocator.block_tables[rows, : self._cap_pages].copy()
         )
 
+    def _cache_token_bytes(self) -> tuple[int, int]:
+        """(needed, stored) bytes a cached token takes over all layers."""
+        from cake_tpu.models.llama.config import ATTENTION
+
+        c = self.config
+        per = 2 * c.num_key_value_heads * c.head_dim * len(c.layers_of(ATTENTION))
+        per *= jnp.dtype(self.cache_dtype).itemsize
+        return per, per
+
+    def cache_facts(self) -> dict:
+        """``GET /stats`` engine.cache: what kind of cache the lanes' pages
+        hold and what it costs. ``bytes_per_token`` is what a cached token
+        takes in the pool over all layers (``_needed``: without the pool's
+        padding to whole tiles), ``bytes`` the whole pool's."""
+        needed, stored = self._cache_token_bytes()
+        return {
+            "kind": self.config.cache_kind,
+            "bytes_per_token": stored,
+            "bytes_per_token_needed": needed,
+            "page_size": self.page_size,
+            "pages": self.max_pages,
+            "bytes": stored * self.page_size * self.max_pages,
+        }
+
+    def _note_cache(self) -> None:
+        """The gauges beside ``cake_kv_pages_*``: facts of the construction."""
+        from cake_tpu.utils import metrics
+
+        facts = self.cache_facts()
+        metrics.registry.gauge(
+            "cake_kv_bytes_per_token",
+            "Bytes one cached token takes in the page pool, all layers.",
+        ).set(facts["bytes_per_token"])
+        metrics.registry.gauge(
+            "cake_kv_pool_bytes", "Bytes of the whole page pool.",
+        ).set(facts["bytes"])
+
     def state_facts(self) -> dict:
         """``GET /stats`` engine.state: the recurrent state beside the page
         pool (zeros for a model without state layers). ``bytes`` is what
@@ -494,6 +535,28 @@ class _PagedBackend:
             "bytes": per_lane * self._state_lanes,
             "lane_writes": self.state_lane_writes,
         }
+
+    def _epoch_groups(self, tokens, pads, ends):
+        """An epoch's prefill as the closed shapes cut it: the tokens
+        right-padded to a program width (a dead tail under ``ends``), and the
+        rows in groups of ``shapes.prefill_group``, every group one program
+        over its own lanes' table rows. (tokens, pads, ends, tables, the
+        groups' row slices)."""
+        tokens = np.asarray(tokens)
+        b, width = tokens.shape
+        self._kernel_note(
+            "prefill", width if ends is None else int(np.max(ends))
+        )
+        ends = jnp.asarray(
+            np.full((b,), width, np.int32) if ends is None else ends, jnp.int32
+        )
+        tokens = jnp.asarray(np.pad(
+            tokens, ((0, 0), (0, self.shapes.program_width(width) - width))
+        ))
+        group = self.shapes.prefill_group(b, tokens.shape[1])
+        tables = jnp.asarray(self.allocator.block_tables.copy())
+        groups = [slice(lo, lo + group) for lo in range(0, b, group)]
+        return tokens, jnp.asarray(pads), ends, tables, groups
 
     def warm_programs(self, lanes: int, sampling, n_steps: int) -> dict:
         """Run ``shapes.programs(lanes)`` once each, on a scratch cache. A
@@ -715,10 +778,10 @@ class PagedHybridBackend(_PagedBackend):
     lane state of the others, and the four operations run the by-run walk.
     It has no suffix, verify or copy-on-write operation and takes no prefix
     cache: that absence is what the engine's capability gates read
-    (``hybrid.REFUSED`` says why). Its prefill and join read a lane's WHOLE
+    (``capability.REFUSED`` says why). Its prefill and join read a lane's WHOLE
     table row: a dead page is a grid step the attention kernel skips."""
 
-    hybrid = True
+    cache_kind = CACHE_KV_STATE
 
     def init_kv(self, b: int):
         from cake_tpu.models.llama.hybrid import init_hybrid_cache
@@ -739,27 +802,13 @@ class PagedHybridBackend(_PagedBackend):
         that writes its own lanes' K, V and state."""
         from cake_tpu.models.llama.hybrid import _hybrid_prefill_jit
 
-        tokens = np.asarray(tokens)
-        b, width = tokens.shape
-        self._kernel_note(
-            "prefill", width if ends is None else int(np.max(ends))
-        )
-        ends = jnp.asarray(
-            np.full((b,), width, np.int32) if ends is None else ends, jnp.int32
-        )
-        tokens = jnp.asarray(np.pad(
-            tokens, ((0, 0), (0, self.shapes.program_width(width) - width))
-        ))
-        pads = jnp.asarray(pads)
-        group = self.shapes.prefill_group(b, tokens.shape[1])
-        tables = jnp.asarray(self.allocator.block_tables.copy())
-        self.state_lane_writes += b
+        tokens, pads, ends, tables, groups = self._epoch_groups(tokens, pads, ends)
+        self.state_lane_writes += tokens.shape[0]
         logits = []
-        for lo in range(0, b, group):
-            rows = slice(lo, lo + group)
+        for rows in groups:
             out, kv = _hybrid_prefill_jit(
                 self.params, tokens[rows], kv, pads[rows], ends[rows],
-                tables[rows], self.config, lane=lo,
+                tables[rows], self.config, lane=rows.start,
                 allow_pallas=self.allow_pallas,
             )
             logits.append(out)
@@ -807,11 +856,147 @@ class PagedHybridBackend(_PagedBackend):
         )
 
 
+class PagedLatentBackend(_PagedBackend):
+    """The same for a model with latent attention (models/llama/latent.py):
+    the cache is a ``LatentPagedCache``, one latent a token a layer in the
+    same pages the same allocator hands out. An epoch's prefill and a join
+    compute the window's own K and V from its latents and write the latents;
+    decode reads them back absorbed. Like the hybrid leaf it has no suffix,
+    verify or copy-on-write operation and takes no prefix cache (the
+    engine's capability gates read that absence; ``capability.REFUSED``).
+
+    The decode program also returns its account of the expert layer
+    (``latent.MOE_COUNTS``). ``decode`` keeps it on the device beside the
+    chunk's tokens; the engine takes it with them (``take_chunk_counters``)
+    and hands it back when it READS the chunk (``absorb_chunk_counters``), so
+    the account is read at the same boundary as the tokens and never makes
+    the host wait for a chunk of its own."""
+
+    cache_kind = CACHE_LATENT
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        from cake_tpu.models.llama.latent import MOE_COUNTS
+
+        self.moe_counts = dict.fromkeys(MOE_COUNTS, 0)
+        self.moe_join_counts = {"joins": 0, "routed": 0, "held": 0}
+        self._chunk_counters = None
+
+    def _cache_token_bytes(self) -> tuple[int, int]:
+        from cake_tpu.models.llama.latent import cache_bytes_per_token
+
+        per = cache_bytes_per_token(self.config, self.cache_dtype)
+        return per["needed"], per["stored"]
+
+    def moe_facts(self) -> dict:
+        """``GET /stats`` engine.moe, cumulative over decode chunks READ:
+        ``dispatches`` decode steps x sparse layers, ``routed`` assignments
+        of live lanes' tokens, ``held`` those to experts held here,
+        ``touched`` held experts with an assignment summed over dispatches
+        (what the steps had to read), ``max_load`` the largest load of one
+        held expert in one dispatch; ``join``: the joins' windows read, the
+        assignments of their tokens and those to held experts."""
+        c = self.config
+        return {
+            **self.moe_counts, "join": dict(self.moe_join_counts),
+            "experts_held": c.num_local_experts,
+            "experts_ranked": c.n_router_experts,
+            "first_held": c.expert_offset, "top_k": c.num_experts_per_tok,
+        }
+
+    def take_chunk_counters(self):
+        counters, self._chunk_counters = self._chunk_counters, None
+        return counters
+
+    def absorb_chunk_counters(self, counters, decode: bool = True) -> dict:
+        """A read program's account into the cumulative one (a decode
+        chunk's, or a join's window); returns it as a dict (the timeline's
+        span arguments)."""
+        from cake_tpu.models.llama.latent import MOE_COUNTS
+
+        got = dict(zip(MOE_COUNTS, (int(v) for v in np.asarray(counters))))
+        if not decode:
+            self.moe_join_counts["joins"] += 1
+            for key in ("routed", "held"):
+                self.moe_join_counts[key] += got[key]
+            return got
+        for key, v in got.items():
+            self.moe_counts[key] = (
+                max(self.moe_counts[key], v) if key == "max_load"
+                else self.moe_counts[key] + v
+            )
+        return got
+
+    def init_kv(self, b: int):
+        from cake_tpu.models.llama.latent import init_cache
+
+        self.allocator.reset(batch=b)
+        return init_cache(
+            self.config, self.max_pages, self.page_size, self.cache_dtype
+        )
+
+    def prefill(self, tokens, kv, pads, ends=None):
+        """An epoch's prefill in groups of rows (``shapes.prefill_group``),
+        every group one program that writes its own lanes' latents."""
+        from cake_tpu.models.llama.latent import _latent_prefill_jit
+
+        tokens, pads, ends, tables, groups = self._epoch_groups(tokens, pads, ends)
+        logits = []
+        for rows in groups:
+            out, kv, _ = _latent_prefill_jit(
+                self.params, tokens[rows], kv, pads[rows], ends[rows],
+                tables[rows], self.config, allow_pallas=self.allow_pallas,
+            )
+            logits.append(out)
+        return (logits[0] if len(logits) == 1 else jnp.concatenate(logits)), kv
+
+    def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
+        from cake_tpu.models.llama.latent import _latent_decode_fn
+
+        self._kernel_note("decode", int(slot) + n)
+        fn = _latent_decode_fn(
+            self.config, n, s.temperature, s.top_k, s.top_p,
+            s.repeat_penalty, allow_pallas=self.allow_pallas,
+        )
+        # A lane is live while it holds pages (``PagedHybridBackend.decode``
+        # says when): a dead lane's token takes no expert's rows.
+        b = int(jnp.shape(tok)[0])
+        valid = (self.allocator.block_tables[:b] >= 0).any(axis=1)
+        *out, self._chunk_counters = fn(
+            self.params, kv, tok, jnp.int32(slot), pads, self._tables(),
+            jnp.asarray(valid), keys, ring, ring_idx,
+        )
+        return tuple(out)
+
+    def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
+        """One row's window [start, start + width) as the engine cut it
+        (``shapes.window``) into lane ``lane``."""
+        from cake_tpu.models.llama.latent import _latent_join_fn
+
+        self._kernel_note("join", int(np.asarray(ends1).max()))
+        fn = _latent_join_fn(
+            self.config, row_tokens.shape[1], self.allow_pallas
+        )
+        logits, kv, self._chunk_counters = fn(
+            self.params, kv, jnp.asarray(row_tokens),
+            jnp.asarray(pads1, jnp.int32), jnp.asarray(ends1, jnp.int32),
+            jnp.asarray(self.allocator.block_tables[lane : lane + 1].copy()),
+            jnp.int32(start),
+        )
+        return logits, kv
+
+
+_PAGED_LEAVES = {
+    CACHE_KV: PagedLocalBackend,
+    CACHE_KV_STATE: PagedHybridBackend,
+    CACHE_LATENT: PagedLatentBackend,
+}
+
+
 def paged_backend(config: LlamaConfig, params: M.Params, **kw) -> _PagedBackend:
     """The paged backend of this model's cache kind: the one place the kind
     is chosen (the shapes are chosen beside it, ``ProgramShapes.for_model``)."""
-    leaf = PagedHybridBackend if config.has_state_layers else PagedLocalBackend
-    return leaf(config, params, **kw)
+    return _PAGED_LEAVES[config.cache_kind](config, params, **kw)
 
 
 class TPBatchBackend:

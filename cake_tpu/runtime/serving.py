@@ -94,7 +94,7 @@ import numpy as np
 
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.chat import Message, encode_dialog
-from cake_tpu.models.llama.config import LlamaConfig
+from cake_tpu.models.llama.config import CACHE_KV, LlamaConfig
 from cake_tpu.models.llama.generator import SamplingConfig, Token, decode_delta
 from cake_tpu.models.llama.tokenizer import Tokenizer
 from cake_tpu.obs import memwatch
@@ -624,10 +624,10 @@ class BatchEngine:
         # Per-epoch failover accounting (engine thread only; reset per epoch).
         self._fo_count = 0
         self._fo_spent_s = 0.0
-        if config.has_state_layers:
-            # ``hybrid.REFUSED`` again, with the facts of a programmatic
+        if config.cache_kind != CACHE_KV:
+            # ``capability.REFUSED`` again, with the facts of a programmatic
             # engine (the CLI asked before it read a weight).
-            from cake_tpu.models.llama.hybrid import refuse_unsupported
+            from cake_tpu.models.llama.capability import refuse_unsupported
 
             refuse_unsupported(
                 config,
@@ -636,7 +636,7 @@ class BatchEngine:
                 speculative_k=bool(speculative_k),
                 draft_model=proposer_factory is not None,
                 other_backend=backend is not None
-                and not getattr(backend, "hybrid", False),
+                and getattr(backend, "cache_kind", "kv") != config.cache_kind,
             )
         if backend is None:
             if params is None:
@@ -1942,12 +1942,25 @@ class BatchEngine:
         of an enqueued value, a ``readback`` span. Read once; ``_settle``
         finds the copy on the entry."""
         if entry.host is None:
-            with self._phase("readback") as wait:
+            args: dict = {}
+            with self._phase("readback", args=args) as wait:
                 entry.host = np.asarray(entry.value)
+                if entry.counters is not None:
+                    # The same program's: ready when its tokens are.
+                    args.update(self.backend.absorb_chunk_counters(
+                        entry.counters, decode=bool(entry.n)
+                    ))
             entry.t_read = time.perf_counter()
             if not entry.n:
                 self.periods.note_join_wait(wait.seconds)
         return entry.host
+
+    def _take_counters(self):
+        """What the program just enqueued returned beside its tokens, still
+        on the device (a latent model's account of its expert layer; None
+        from every other backend). Read with the tokens, in ``_read``."""
+        take = getattr(self.backend, "take_chunk_counters", None)
+        return take() if take is not None else None
 
     def _settle(self, rows: list, keep: int = 0, why: str = "") -> None:
         """Read and emit, oldest first, all that is enqueued but the newest
@@ -2066,7 +2079,7 @@ class BatchEngine:
             finally:
                 self.periods.end(
                     args["live"] if args["dispatched"] else None,
-                    args.get("order", ""),
+                    args.get("order", ""), args.get("cached", 0),
                 )
 
     # ------------------------------------------------- replica failover
@@ -3133,8 +3146,15 @@ class BatchEngine:
                                 if row is not None
                             ],
                             slot, n, t0,
+                            counters=self._take_counters(),
                         )
                         self._unread.append(chunk)
+                        # Tokens the live lanes hold in the cache at this
+                        # dispatch (each lane's position).
+                        cached = sum(
+                            len(row.history) - 1 + row.inflight
+                            for _, row in chunk.rows
+                        )
                         for lane, row in chunk.rows:
                             # What the host can count does not lag: a
                             # budget that ends inside this chunk frees the
@@ -3160,7 +3180,9 @@ class BatchEngine:
                     kv = self._migrate_kv(rows, B, slot)
                     continue
                 self._settle(rows, look, serial)
-                period.update(dispatched=True, live=live, order=order)
+                period.update(
+                    dispatched=True, live=live, order=order, cached=cached
+                )
         self._settle(rows, 0)
         self._segment_args["ended"] = ended
 
@@ -4166,7 +4188,10 @@ class BatchEngine:
             )
         self.periods.note_join(join.seconds)
         row.inflight = 1
-        self._unread.append(_Unread(first, [(lane, row)], slot, 0, t_join, W))
+        self._unread.append(_Unread(
+            first, [(lane, row)], slot, 0, t_join, W,
+            counters=self._take_counters(),
+        ))
         # A budget of one token ends with the token in flight: the lane
         # never becomes the row's (its pages go at the next release).
         rows[lane] = row if req.max_tokens > 1 else None
@@ -4213,7 +4238,9 @@ class _Unread:
     chunk's tokens ``[lanes, n]`` or, with ``n`` 0, a joiner's first token
     ``[1]`` (``width``: its prefill's window). ``rows`` are the (lane, row)
     pairs that take the tokens, as they stood at the enqueue at ``t0``;
-    ``slot`` is the shared slot then. ``host`` is the copy once read."""
+    ``slot`` is the shared slot then. ``host`` is the copy once read.
+    ``counters``: what the backend's decode program returned beside the
+    tokens (``take_chunk_counters``; None from most), read with them."""
 
     value: jax.Array
     rows: list
@@ -4223,6 +4250,7 @@ class _Unread:
     width: int = 0
     host: np.ndarray | None = None
     t_read: float = 0.0
+    counters: jax.Array | None = None
 
 
 @dataclasses.dataclass

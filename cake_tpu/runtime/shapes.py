@@ -9,9 +9,10 @@ instances that differ in data, picked by ``for_model`` from the config alone:
     windows in 64s from slot 0, capacities in 256s. A server meets new
     programs for as long as it runs (PERF.md: 45% of Mistral's window is
     compile stall) and runs none ahead.
-  * CLOSED, for a model with state layers, whose programs compile in 3 to 10
-    s each: eleven window widths and three capacities, shares of a lane's
-    table. An epoch's prefill is right-padded to a width (a dead tail under
+  * CLOSED, for a model whose cache is not plain K and V (state layers:
+    programs that compile in 3 to 10 s each; a latent pool: its prefill
+    computes a window's own K and V, so a window is a whole prompt): eleven
+    window widths and three capacities, shares of a lane's table. An epoch's prefill is right-padded to a width (a dead tail under
     ``ends``: the recurrence stands still there), a joiner's window is as
     wide as its prompt and ends at the shared slot, and the few dozen
     programs there are run once at start-up (``programs``).
@@ -25,16 +26,21 @@ from __future__ import annotations
 import dataclasses
 
 from cake_tpu.models.llama.batch import prompt_bucket
+from cake_tpu.models.llama.config import CACHE_KV, CACHE_KV_STATE, CACHE_LATENT
 
 # The closed tables as shares of a lane's table: widths in 64ths of its
 # slots, capacities in quarters of its pages.
 _WIDTH_64THS = (1, 2, 4, 8, 12, 16, 24, 32, 40, 48, 64)
 _CAPACITY_QUARTERS = (1, 2, 4)
-# Tokens a prefill program of a model with state layers may hold: the mixer's
+# Tokens a prefill program may hold, by cache kind. State layers: the mixer's
 # float32 intermediates are [rows, width, d_inner] several times over, so an
 # epoch of 32 lanes x 2080 slots would need 8.6 GB of temporaries beside 6.5
 # GB of arguments (compiled for a described v5e); 16k tokens need 2.1 to 2.7.
-_PREFILL_TOKENS = 16384
+# A latent pool: the expanded K and V of a window are [tokens, heads, 256]
+# each and the grouped experts' rows [tokens * top_k, hidden]; a joiner's
+# window is one row of up to a lane's table, and an epoch's groups are held
+# to the same (PERF.md section 4 has the bytes, compiled for a described v5e).
+_PREFILL_TOKENS = {CACHE_KV_STATE: 16384, CACHE_LATENT: 4096}
 
 
 def _ceil_to(x: int, multiple: int) -> int:
@@ -59,12 +65,13 @@ class ProgramShapes:
 
     @classmethod
     def for_model(cls, config, page_size: int = 0, pages_per_seq: int = 0):
-        """The closed instance for a model with state layers, the open one
-        otherwise (a dense backend has no table to pass). At 32 pages of 128
+        """The closed instance for a model whose cache is not plain K and V
+        (``config.cache_kind``), the open one otherwise (a dense backend has
+        no table to pass). At 32 pages of 128
         the sets are 64, 128, 256, 512, 768, 1024, 1536, 2048, 2560, 3072,
         4096 slots and 8, 16, 32 pages (``jamba2-3b-chat-closed``'s); at any
         other geometry they are as closed."""
-        if not config.has_state_layers:
+        if config.cache_kind == CACHE_KV:
             return cls()
         slots = page_size * pages_per_seq
         widths = {
@@ -74,7 +81,7 @@ class ProgramShapes:
         return cls(
             widths=tuple(sorted(widths)),
             capacities=tuple(p * page_size for p in sorted(pages)),
-            prefill_tokens=_PREFILL_TOKENS,
+            prefill_tokens=_PREFILL_TOKENS[config.cache_kind],
         )
 
     def lanes(self, n_seed: int, max_batch: int) -> int:

@@ -31,6 +31,12 @@ every phase runs in a child that has exited before the next one starts.
            the paged kernels at one KV head under a group of 20, the
            state-space mixer (ops/ssm.py) against the stepwise scan, and its
            decode chunk and join compiled: no copy of the pool or the state
+  L        a model with latent attention and a share of its experts at
+           pangu-ultra-ep16-chat-closed's geometry: the absorbed decode
+           kernel over the latent pool against its XLA twin and alone on the
+           clock, one sparse layer's 16 held experts of 256 by the dense
+           combine and by the grouped path, and its decode chunk and join
+           compiled: no copy of the latent pool
   D        four chips: the phase-A server under --tp 4 and as a four-stage
            pipeline, with per-device memory (skipped below four devices)
 
@@ -58,7 +64,7 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, ".chip_smoke")  # listed in .gitignore
-PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "D")
+PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "L", "D")
 
 MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
     model_type="mistral", hidden_size=4096, intermediate_size=14336,
@@ -78,6 +84,7 @@ def _benchmark_model(name: str) -> dict:
 
 
 JAMBA2_3B = _benchmark_model("ai21-jamba2-3b")
+PANGU_EP16 = _benchmark_model("openpangu-ultra-moe-718b-ep16")
 PRESETS = {
     # Prompt lengths are in characters: without a tokenizer file the byte
     # tokenizer serves, one token a byte.
@@ -93,6 +100,12 @@ PRESETS = {
         hybrid=dict(
             model=dict(JAMBA2_3B), prompt=300, pages=1024, lanes=32,
             table_pages=8, steps=8, join_width=512,
+        ),
+        # pangu-ultra-ep16-chat-closed
+        # (bench/configs/openpangu-ultra-moe-718b-ep16.json)
+        latent=dict(
+            model=dict(PANGU_EP16), pages=2048, lanes=64, table_pages=8,
+            steps=8, join_width=512, expert_tokens=(1, 8, 64, 512, 2048),
         ),
     ),
     # The rehearsal: same family and head layout rules (tp 4 divides the
@@ -121,6 +134,19 @@ PRESETS = {
             # shape of one layer of the state, and the audit names it.
             prompt=37, pages=64, lanes=4, table_pages=2, steps=4,
             join_width=64,
+        ),
+        latent=dict(
+            model=dict(
+                PANGU_EP16, hidden_size=128, intermediate_size=256,
+                moe_intermediate_size=64, num_attention_heads=8,
+                num_key_value_heads=8, q_lora_rank=48, kv_lora_rank=128,
+                qk_nope_head_dim=32, qk_rope_head_dim=64, v_head_dim=32,
+                num_hidden_layers=3, n_routed_experts=2,
+                n_routed_experts_total=16, num_experts_per_tok=4,
+                vocab_size=512,
+            ),
+            pages=16, lanes=2, table_pages=2, steps=4, join_width=64,
+            expert_tokens=(8, 64), timed=dict(table_pages=(2,), calls=2, repeats=1),
         ),
     ),
 }
@@ -397,9 +423,67 @@ def child_hybrid(preset: dict) -> None:
         emit({"kind": "program", "program": name, **report})
 
 
+def child_latent(preset: dict) -> None:
+    """A model with latent attention and a share of its experts at the
+    benchmark cell's geometry: the absorbed decode kernel against its XLA
+    twin and alone on the clock, one sparse layer's routed experts by the
+    dense combine and by the grouped path, then its decode chunk and join
+    compiled for the device this process holds, from shapes alone."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import pool_audit
+    from cake_tpu.models.llama.config import SPARSE, LlamaConfig
+    from cake_tpu.ops.pallas.check import (
+        run_latent_checks,
+        timed_expert_layer,
+        timed_latent_decode,
+    )
+    from cake_tpu.utils.device import describe_devices, setup_compile_cache
+
+    setup_compile_cache()
+    device = describe_devices()
+    g = preset["latent"]
+    config = dataclasses.replace(
+        LlamaConfig.from_hf_dict(g["model"]), attention_impl="pallas"
+    )
+    geometry = dict(
+        n_heads=config.num_attention_heads, rank=config.kv_lora_rank,
+        rope=config.qk_rope_head_dim, page_size=preset["page_size"],
+        lanes=g["lanes"], dtype=preset["dtype"],
+    )
+    out = run_latent_checks(**geometry, table_pages=g["table_pages"])
+    for rec in out["results"]:
+        emit({"kind": "case", **rec})
+    emit({"kind": "summary", **device,
+          "interpret": sorted(set(out["interpret"])),
+          "pallas_calls": len(out["interpret"])})
+    emit({"kind": "timed", "rows": timed_latent_decode(
+        **geometry, layers=config.num_hidden_layers, **g.get("timed", {}),
+    )})
+    emit({"kind": "experts", "rows": timed_expert_layer(
+        config.hidden_size, config.moe_intermediate_size,
+        config.num_local_experts, config.n_router_experts,
+        config.num_experts_per_tok, tuple(g["expert_tokens"]),
+        dtype=preset["dtype"], layers=config.ff_kinds.count(SPARSE),
+        **({"calls": 2, "repeats": 1} if "timed" in g else {}),
+    )})
+    reports = pool_audit.audit_latent_programs(
+        config, n_pages=g["pages"], page_size=preset["page_size"],
+        lanes=g["lanes"], table_pages=g["table_pages"], n_steps=g["steps"],
+        join_width=g["join_width"],
+        dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]],
+        allow_pallas=jax.default_backend() != "cpu",
+    )
+    for name, report in reports.items():
+        emit({"kind": "program", "program": name, **report})
+
+
 CHILDREN = {"probe": child_probe, "setup": child_setup,
             "kernels": child_kernels, "pool": child_pool,
-            "hybrid": child_hybrid}
+            "hybrid": child_hybrid, "latent": child_latent}
 
 
 # ------------------------------------------------------------------- traffic
@@ -867,6 +951,52 @@ def phase_hybrid(args, preset) -> dict:
     return out
 
 
+def phase_latent(args, preset) -> dict:
+    """Phase L: a model with latent attention and a share of its experts at
+    the benchmark cell's geometry (pangu-ultra-ep16-chat-closed): the
+    absorbed decode kernel's cases and its time alone, one sparse layer by
+    the dense combine and by the grouped path, then the compiled programs."""
+    records = run_child("latent", args, timeout=1800)
+    summary = next(r for r in records if r["kind"] == "summary")
+    problems = []
+    for c in (r for r in records if r["kind"] == "case"):
+        say(f"phase=L kernel={c['kernel']} {c['case']}: "
+            + (f"max_err={c['max_err']:.3g} of tol {c['tol']:.3g} "
+               f"first_call_s={c['first_call_s']}" if "max_err" in c
+               else f"FAILED {c.get('error')}"))
+        if not c["ok"]:
+            problems.append(f"{c['kernel']} {c['case']}")
+    if summary["interpret"] != [args.rehearse_cpu]:
+        problems.append(
+            f"pallas_call was traced with interpret={summary['interpret']}")
+    where = "" if not args.rehearse_cpu else " (cpu rehearsal: no device time)"
+    for row in next(r for r in records if r["kind"] == "timed")["rows"]:
+        say(f"phase=L latent_decode_attention alone{where}, "
+            f"table_pages={row['table_pages']}: full_us={row['full_us']} "
+            f"live_us={row['live_us']} (live_tokens={row['live_tokens']})")
+    for row in next(r for r in records if r["kind"] == "experts")["rows"]:
+        say(f"phase=L routed experts{where}, tokens={row['tokens']}: "
+            f"dense_ms={row.get('dense_ms')} grouped_ms={row.get('grouped_ms')} "
+            f"max_diff_in_stds={row.get('max_diff_in_stds')}")
+        if row.get("max_diff_in_stds", 0.0) > 0.1:
+            problems.append(f"dense and grouped experts differ at {row['tokens']} tokens")
+    out = {"cases": sum(r["kind"] == "case" for r in records)}
+    for r in (r for r in records if r["kind"] == "program"):
+        moved = r["scans"] + ([] if args.rehearse_cpu else r["pool_ops"])
+        say(f"phase=L program={r['program']} temp_bytes={r['temp_bytes']} "
+            f"argument_bytes={r['argument_bytes']} pool_bytes={r['pool_bytes']} "
+            f"kernels={r['kernels']} moving_ops={len(moved)} "
+            f"compile_s={r['seconds']}")
+        for m in moved:
+            say(f"phase=L   {r['program']} moves the pool: {m}")
+        if moved:
+            problems.append(f"{r['program']}: {len(moved)} op(s) move the pool")
+        out[f"{r['program']}_temp_bytes"] = r["temp_bytes"]
+    if problems:
+        raise PhaseFailed("; ".join(problems))
+    return out
+
+
 def phase_four_chips(args, preset) -> dict:
     cpu = args.rehearse_cpu
     out = {}
@@ -954,6 +1084,7 @@ def main() -> int:
         "C": lambda: phase_kernels(args, preset),
         "P": lambda: phase_pool(args, preset),
         "H": lambda: phase_hybrid(args, preset),
+        "L": lambda: phase_latent(args, preset),
         "D": lambda: phase_four_chips(args, preset),
     }
 

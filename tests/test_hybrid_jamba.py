@@ -24,6 +24,7 @@ from bench.checkpoint import Reader
 from bench.manifest import architecture
 from cake_tpu.io.safetensors_io import load_params, save_tiny_checkpoint
 from cake_tpu.models.llama import hybrid as H
+from cake_tpu.models.llama.capability import UnsupportedForCacheKind, refuse_unsupported
 from cake_tpu.models.llama.chat import Message
 from cake_tpu.models.llama.config import SUPPORTED_MODEL_TYPES, LlamaConfig
 from cake_tpu.models.llama.generator import SamplingConfig
@@ -452,23 +453,23 @@ def test_refusals_outside_the_cli(model, tmp_path):
 
     (tmp_path / "topology.yml").write_text(
         "w0:\n  host: 127.0.0.1:1\n  layers:\n    - model.layers.0-3\n")
-    with pytest.raises(H.UnsupportedWithStateLayers, match="cake-split-model"):
+    with pytest.raises(UnsupportedForCacheKind, match="cake-split-model"):
         split_model(path, tmp_path / "topology.yml", tmp_path / "out")
-    with pytest.raises(H.UnsupportedWithStateLayers, match="layer range"):
+    with pytest.raises(UnsupportedForCacheKind, match="layer range"):
         load_params(path, config, jnp.float32, layer_range=(0, 4))
     step = LocalForwardStep(config, loaded, max_seq_len=64, cache_dtype=jnp.float32)
-    with pytest.raises(H.UnsupportedWithStateLayers, match="single-stream"):
+    with pytest.raises(UnsupportedForCacheKind, match="single-stream"):
         step(np.zeros((1, 4), np.int32), 0, 4)
     for kw, name in ((dict(kv_mode="dense"), "--kv-mode dense"),
                      (dict(kv_mode="paged", prefix_cache=True), "--prefix-cache on")):
-        with pytest.raises(H.UnsupportedWithStateLayers, match=name):
+        with pytest.raises(UnsupportedForCacheKind, match=name):
             BatchEngine(config, loaded, ByteTokenizer(), max_seq_len=64,
                         cache_dtype=jnp.float32, serve=ServeConfig(max_batch=2, **kw))
-    with pytest.raises(H.UnsupportedWithStateLayers, match="--speculative-k"):
+    with pytest.raises(UnsupportedForCacheKind, match="--speculative-k"):
         BatchEngine(config, loaded, ByteTokenizer(), max_seq_len=64,
                     cache_dtype=jnp.float32, speculative_k=4,
                     serve=ServeConfig(max_batch=2, kv_mode="paged"))
-    H.refuse_unsupported(LlamaConfig.tiny(), tp=True)  # no state layers: nothing
+    refuse_unsupported(LlamaConfig.tiny(), tp=True)  # no state layers: nothing
 
 
 @pytest.mark.parametrize("change,message", [
@@ -522,20 +523,26 @@ GOLDEN = json.loads((REPO / "tests/data/from_hf_dict_pr27.json").read_text())
 def test_the_other_families_parse_as_they_did(family):
     """``from_hf_dict`` of every family PR 27 had, field for field as PR 27's
     code gave it (tests/data/from_hf_dict_pr27.json was written by it); the
-    fields this PR adds say "every layer is attention, with RoPE"."""
+    fields PR 28 added say "every layer is attention, with RoPE", those of
+    PR 32 "K and V a KV head, the whole model's experts, softmax routing"."""
     config = LlamaConfig.from_hf_dict(GOLDEN[family]["hf"])
     got = json.loads(json.dumps(dataclasses.asdict(config)))
     old = GOLDEN[family]["parsed"]
     assert {k: got[k] for k in old} == old
     assert {k: got[k] for k in set(got) - set(old)} == {
         "attn_layer_period": 0, "attn_layer_offset": 0, "mamba_d_state": 16,
-        "mamba_d_conv": 4, "mamba_expand": 2, "mamba_dt_rank": 0, "use_rope": True}
+        "mamba_d_conv": 4, "mamba_expand": 2, "mamba_dt_rank": 0, "use_rope": True,
+        "q_lora_rank": 0, "kv_lora_rank": 0, "qk_nope_head_dim": 0, "qk_rope_head_dim": 0,
+        "v_head_dim": 0, "first_k_dense_replace": 0, "router_experts": 0, "expert_offset": 0,
+        "moe_scoring": "softmax", "routed_scaling_factor": 1.0}
     assert set(config.layer_kinds) == {"attention"} and not config.has_state_layers
+    assert config.cache_kind == "kv" and len(set(config.ff_kinds)) == 1
+    assert config.n_router_experts == config.num_local_experts
     assert config.layer_runs == (("attention", 0, config.num_hidden_layers),)
 
 
 def test_unsupported_message_is_built_from_the_tuple():
-    assert sorted([*GOLDEN, "jamba"]) == sorted(SUPPORTED_MODEL_TYPES)
+    assert sorted([*GOLDEN, "jamba", "pangu_ultra_moe"]) == sorted(SUPPORTED_MODEL_TYPES)
     with pytest.raises(ValueError) as e:
         LlamaConfig.from_hf_dict({"model_type": "mamba2"})
     assert f"(supported: {', '.join(SUPPORTED_MODEL_TYPES)})" in str(e.value)

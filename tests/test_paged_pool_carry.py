@@ -475,3 +475,40 @@ def test_hybrid_audit_names_a_state_that_is_scanned():
 
     found = pool_audit.scans_moving_pool(jax.make_jaxpr(wrong)(ssm), ssm.shape)
     assert len(found) == 2 and "scanned input" in found[0]
+
+
+# -------- a model with latent attention, at its cell's geometry, for a v5e
+#
+# pangu-ultra-ep16-chat-closed (bench/configs/openpangu-ultra-moe-718b-ep16
+# .json): the latent pool rides the carries of the two runs' scans
+# (models/llama/latent.py). The same compile shows that Mosaic takes the
+# absorbed decode kernel at 128 heads x 640 against pages of 128 x 640 and
+# the chunk kernel at heads padded to 256, and what the programs need beside
+# their 11.5 GB of arguments.
+
+
+@pytest.fixture(scope="module")
+def latent_reports(one_chip):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench/configs/openpangu-ultra-moe-718b-ep16.json")) as f:
+        config = dataclasses.replace(
+            LlamaConfig.from_hf_dict(json.load(f)), attention_impl="pallas"
+        )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        with jax.default_matmul_precision("default"):
+            return pool_audit.audit_latent_programs(
+                config, n_pages=2048, page_size=128, lanes=64, table_pages=8,
+                n_steps=8, join_width=512, sharding=one_chip,
+            )
+
+
+@pytest.mark.parametrize("program", ["decode", "join"])
+def test_latent_cell_compiles_for_v5e_without_pool_copies(program, latent_reports):
+    report = latent_reports[program]
+    assert report["scans"] == [] and report["pool_ops"] == [], report
+    assert report["kernels"] >= 2, report  # one a run of layers
+    assert report["pool_bytes"] == 5 * 2048 * 128 * 640 * 2  # 1.68 GB: what is stored
+    # weights 9.84 GB + the pool: the chip's 16 GB hold the program
+    assert report["argument_bytes"] + report["temp_bytes"] < 14.0e9, report
+    assert report["temp_bytes"] < report["pool_bytes"], report

@@ -9,14 +9,16 @@ with both cells measured.
 
 from __future__ import annotations
 
+import dataclasses
 import types
 
 import pytest
 
 from cake_tpu.runtime.shapes import ProgramShapes
 
-ATTENTION = types.SimpleNamespace(has_state_layers=False)
-STATE = types.SimpleNamespace(has_state_layers=True)
+ATTENTION = types.SimpleNamespace(cache_kind="kv")
+STATE = types.SimpleNamespace(cache_kind="kv+state")
+LATENT = types.SimpleNamespace(cache_kind="latent")
 # (page size, pages of a lane's table, --max-seq-len, --api-batch)
 MISTRAL = (128, 32, 4096, 8)
 JAMBA = (128, 32, 4096, 32)
@@ -46,6 +48,10 @@ def test_the_instance_is_picked_from_the_config_alone():
     assert ProgramShapes.for_model(ATTENTION, 128, 32) == ProgramShapes()
     assert ProgramShapes.for_model(ATTENTION) == ProgramShapes()  # dense backends
     closed = ProgramShapes.for_model(STATE, 128, 32)
+    # a latent pool takes the same tables; its prefill programs hold less
+    latent = ProgramShapes.for_model(LATENT, 128, 32)
+    assert dataclasses.replace(latent, prefill_tokens=16384) == closed
+    assert latent.prefill_tokens == 4096 and latent.prefill_group(64, 2048) == 2
     assert closed != ProgramShapes() and closed.widths and closed.capacities
     with pytest.raises(AttributeError):  # frozen: a value, not a knob
         closed.widths = ()
