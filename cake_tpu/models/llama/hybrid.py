@@ -159,6 +159,10 @@ def hybrid_blocks_forward(
     from cake_tpu.models.llama.batch import batched_blocks_forward
 
     fusion = resolve_fusion(config, allow_pallas)
+    # the prefill scan's kernel follows the attention kernels' switch
+    use_pallas = (
+        allow_pallas and M.resolve_attention_impl(config.attention_impl) == "pallas"
+    )
     kv, ssm, conv = cache
     eps = config.rms_norm_eps
     rows = x.shape[0]
@@ -179,7 +183,9 @@ def hybrid_blocks_forward(
             s_l = jnp.zeros((rows, *ssm.shape[2:]), ssm.dtype)
             c_l = jnp.zeros((conv.shape[1], rows, conv.shape[3]), conv.dtype)
         h = rms_norm(x, lp["ln_attn"], eps)
-        gated, s_l, c_l = S.mixer_forward(lp, h, s_l, c_l, live, ends, eps)
+        gated, s_l, c_l = S.mixer_forward(
+            lp, h, s_l, c_l, live, ends, eps, allow_pallas=use_pallas
+        )
         if lane is None:
             ssm = jax.lax.dynamic_update_index_in_dim(ssm, s_l, li, 0)
         else:

@@ -662,6 +662,74 @@ def timed_paged_decode(
     return rows
 
 
+def timed_selective_scan(
+    d_inner: int,
+    d_state: int,
+    windows: tuple[tuple[int, int], ...] = ((1, 512), (1, 2048), (4, 512)),
+    calls: int = 26,
+    repeats: int = 3,
+) -> list[dict]:
+    """The selective scan of a prefill window alone, the Pallas kernel and its
+    XLA twin (``ops/ssm.selective_scan``) on the same inputs: for each
+    (rows, length) of ``windows`` the kernel's error against the twin (``y``
+    and the last state, over the largest value of the twin's) and the
+    microseconds a call of both. ``calls`` dependent calls make one program
+    (a model's state layers: each call's state and output feed the next),
+    timed on the host clock around ``block_until_ready``, the fastest of
+    ``repeats``. The first eighth of a window is not live (a join's left pad):
+    neither form walks it."""
+    from cake_tpu.ops import ssm
+    from cake_tpu.ops.pallas.selective_scan import selective_scan as kernel
+
+    twin = lambda u, dt, a, b, c, s0, span: ssm.selective_scan(
+        u, dt, a, b, c, s0, span=span
+    )
+
+    def chain(scan):
+        @jax.jit
+        def run(u, dt, a, b_in, c_out, s0, lo, hi):
+            def one(_, carry):
+                u, s = carry
+                y, s = scan(u, dt, a, b_in, c_out, s, (lo, hi))
+                return u + 1e-3 * y, s
+
+            return jax.lax.fori_loop(0, calls, one, (u, s0))
+
+        return run
+
+    once = {"kernel": jax.jit(kernel), "twin": jax.jit(twin)}
+    chains = {"kernel": chain(kernel), "twin": chain(twin)}
+    rows = []
+    for b, length in windows:
+        keys = jax.random.split(jax.random.PRNGKey(b * length), 6)
+        lo = length // 8
+        live = (jnp.arange(length) >= lo)[None, :, None]
+        u = jax.random.normal(keys[0], (b, length, d_inner), jnp.float32)
+        dt = jnp.where(live, jax.nn.softplus(
+            jax.random.normal(keys[1], (b, length, d_inner), jnp.float32) - 3.0
+        ), 0.0)
+        a = -jnp.exp(jax.random.normal(keys[2], (d_state, d_inner)) * 0.5)
+        b_in = jax.random.normal(keys[3], (b, length, d_state), jnp.float32)
+        c_out = jax.random.normal(keys[4], (b, length, d_state), jnp.float32)
+        s0 = jax.random.normal(keys[5], (b, d_state, d_inner), jnp.float32)
+        args = (u, dt, a, b_in, c_out, s0, jnp.int32(lo), jnp.int32(length))
+        y_k, s_k = once["kernel"](*args[:6], args[6:])
+        y_t, s_t = once["twin"](*args[:6], args[6:])
+        rec = {
+            "rows": b, "length": length, "live": length - lo,
+            # before the span the twin's first chunk holds s0 . C, the
+            # kernel's first group zeros: nobody reads either
+            "err_y": _rel_err(y_k[:, lo:], y_t[:, lo:]),
+            "err_s": _rel_err(s_k, s_t),
+        }
+        for name, run in chains.items():
+            _timed(run, *args)  # compile + warm
+            fastest = min(_timed(run, *args)[1] for _ in range(repeats))
+            rec[f"{name}_us"] = round(fastest / calls * 1e6, 1)
+        rows.append(rec)
+    return rows
+
+
 def timed_matmul_chain(n: int, steps: int, repeats: int = 3) -> dict:
     """A chain of ``steps`` dependent [n, n] bf16 matmuls, timed on the host
     clock around ``block_until_ready``. Returns the FLOPs and the fastest

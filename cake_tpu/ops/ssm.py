@@ -25,10 +25,21 @@ other position (a left pad, the dead tail of a join window) the
 convolution's input is zero and ``dt`` is zero, so ``s`` passes through
 unchanged and the window holds zeros: a recurrence never sees a pad.
 
-No Pallas kernel here: the scan is the plain form a later kernel is held
-against. Its memory is bounded in L: time is walked in chunks of
-``SCAN_CHUNK`` steps and only one chunk's [b, T, d_state, d_inner] decay and
-input terms exist at a time.
+Which scan a window takes is read off what ``mixer_forward`` can see, and
+nothing else (no flag, field or environment variable chooses):
+
+  * ``L == 1`` (every decode step): the one-token update, in line.
+  * ``L > 1`` with ``allow_pallas`` (the switch every Pallas kernel follows,
+    handed down from ``hybrid_blocks_forward``) and widths that tile
+    (``d_inner`` in 128-lane tiles, ``d_state`` in sublane tiles):
+    ``ops/pallas/selective_scan.py``, which keeps ``s`` in VMEM over the
+    whole window and writes no per-step state to HBM.
+  * anything else (the tests' tiny models, the CPU): ``selective_scan``
+    below, the plain XLA form. It is the kernel's twin: the same
+    mathematics, the form the kernel is held against (``ops/pallas/check.py``,
+    ``tests/test_ssm_scan_kernel.py``). Its memory is bounded in L: time is
+    walked in chunks of ``SCAN_CHUNK`` steps and only one chunk's
+    [b, T, d_state, d_inner] decay, input terms and states exist at a time.
 """
 
 from __future__ import annotations
@@ -37,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from cake_tpu.ops.norm import rms_norm
+from cake_tpu.ops.pallas import selective_scan as pallas_scan
 from cake_tpu.ops.quant import qmat
 
 # Steps of the prefill scan whose decay/input terms are built at once:
@@ -146,6 +158,7 @@ def mixer_forward(
     # every row's last position is L - 1 (decode, L == 1)
     eps: float,
     chunk: int = SCAN_CHUNK,
+    allow_pallas: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One state-space mixer over a chunk of tokens continuing from
     (``ssm``, ``conv``): (y * silu(z) [b, L, d_inner] — the caller applies
@@ -181,7 +194,15 @@ def mixer_forward(
         lo = jnp.argmax(some).astype(jnp.int32)
         hi = (some.shape[0] - jnp.argmax(some[::-1])).astype(jnp.int32)
         hi = jnp.where(jnp.any(some), hi, lo)
-        y, s = selective_scan(u, dt, a, b_in, c_out, ssm, chunk, (lo, hi))
+        if allow_pallas and pallas_scan.tiles(d, n):
+            y, s = pallas_scan.selective_scan(
+                u, dt, a, b_in, c_out, ssm, (lo, hi)
+            )
+        else:
+            with jax.named_scope("selective_scan_xla"):
+                y, s = selective_scan(
+                    u, dt, a, b_in, c_out, ssm, chunk, (lo, hi)
+                )
     y = y + lp["D"].astype(jnp.float32) * u
     gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype)
     if ends is None:

@@ -134,6 +134,9 @@ PRESETS = {
             # shape of one layer of the state, and the audit names it.
             prompt=37, pages=64, lanes=4, table_pages=2, steps=4,
             join_width=64,
+            # the model's d_state 4 is no sublane tile: the twin's by shape
+            timed_scan=dict(d_state=8, windows=((1, 64), (2, 48)), calls=2,
+                            repeats=1),
         ),
         latent=dict(
             model=dict(
@@ -383,6 +386,7 @@ def child_hybrid(preset: dict) -> None:
         HybridGeometry,
         run_hybrid_checks,
         timed_paged_decode,
+        timed_selective_scan,
     )
     from cake_tpu.utils.device import describe_devices, setup_compile_cache
 
@@ -412,6 +416,10 @@ def child_hybrid(preset: dict) -> None:
         len(config.layers_of("attention")), preset["dtype"],
         **preset.get("timed_decode", {}),
     )})
+    emit({"kind": "scan", "rows": timed_selective_scan(**{
+        "d_inner": config.mamba_d_inner, "d_state": config.mamba_d_state,
+        **g.get("timed_scan", {}),
+    })})
     reports = pool_audit.audit_hybrid_programs(
         config, n_pages=g["pages"], page_size=preset["page_size"],
         lanes=g["lanes"], table_pages=g["table_pages"], n_steps=g["steps"],
@@ -911,7 +919,8 @@ def phase_pool(args, preset) -> dict:
 
 def phase_hybrid(args, preset) -> dict:
     """Phase H: a model with state layers at the benchmark cell's geometry
-    (jamba2-3b-chat-closed): kernel cases, then the compiled programs."""
+    (jamba2-3b-chat-closed): kernel cases, the prefill scan's kernel beside
+    its twin, then the compiled programs."""
     records = run_child("hybrid", args, timeout=1200)
     summary = next(r for r in records if r["kind"] == "summary")
     problems = []
@@ -926,6 +935,17 @@ def phase_hybrid(args, preset) -> dict:
         problems.append(
             f"pallas_call was traced with interpret={summary['interpret']}")
     _say_timed_decode("H", records, args)
+    for r in next(r for r in records if r["kind"] == "scan")["rows"]:
+        # a time is a device's: the rehearsal prints the errors alone
+        times = "" if args.rehearse_cpu else (
+            f": kernel {r['kernel_us']} us a call, XLA twin {r['twin_us']}")
+        say(f"phase=H selective_scan alone, {r['rows']} x {r['length']} "
+            f"({r['live']} live) err_y={r['err_y']:.3g} "
+            f"err_s={r['err_s']:.3g}{times}")
+        if max(r["err_y"], r["err_s"]) > 1e-4:
+            problems.append(
+                f"selective_scan {r['rows']} x {r['length']} differs from "
+                "its twin")
     out = {"cases": sum(r["kind"] == "case" for r in records)}
     for r in (r for r in records if r["kind"] == "program"):
         # What the CPU's compiler copies says nothing of the chip's layouts:

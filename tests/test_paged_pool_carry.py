@@ -459,7 +459,9 @@ def test_hybrid_cell_compiles_for_v5e_without_pool_or_state_copies(
     report = hybrid_reports[program]
     assert report["scans"] == [] and report["pool_ops"] == [], report
     assert report["state_scans"] == [] and report["state_copies"] == [], report
-    assert report["kernels"] == 2, report  # one a layer of attention
+    # one a layer of attention; a join's prefill scan is one more in each of
+    # the three scanned runs of state layers
+    assert report["kernels"] == (2 if program == "decode" else 5), report
     # 298 MB of state, 134 MB of pool: a second copy of either would show
     assert report["state_bytes"] == 32 * 9_318_400
     assert report["temp_bytes"] < report["state_bytes"] // 2, report
