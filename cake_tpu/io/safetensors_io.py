@@ -405,9 +405,10 @@ def load_layer_params(
     return out
 
 
-# Hybrid stacks (models/llama/hybrid.py): HF names as transformers'
-# JambaForCausalLM writes them -> (key in the run's tree, how the tensor is
-# turned into the layout the model holds). "T" = [out, in] -> [in, out].
+# Hybrid stacks (models/llama/hybrid.py), a table a model type. Jamba: HF
+# names as transformers' JambaForCausalLM writes them -> (key in the run's
+# tree, how the tensor is turned into the layout the model holds). "T" =
+# [out, in] -> [in, out].
 _JAMBA_FFN = {
     "w_gate": ("feed_forward.gate_proj.weight", "T"),
     "w_up": ("feed_forward.up_proj.weight", "T"),
@@ -441,51 +442,122 @@ _JAMBA_TEMPLATES = {
         **_JAMBA_FFN,
     },
 }
-_JAMBA_FINAL_NORM = "model.final_layernorm.weight"
+# ``model_type: olmo_hybrid``: the OLMo-2/3 names for the block, and for the
+# gated delta rule the names flash-linear-attention's GatedDeltaNet writes
+# (``bench/architectures/olmo_hybrid.py`` is their statement). A TUPLE of
+# names is joined along the tree's last axis, each turned first: q | k | v | z
+# are one ``in_proj``, a | b one ``ab_proj``, the three convolutions one.
+_OLMO_FFN = {
+    "w_gate": ("mlp.gate_proj.weight", "T"),
+    "w_up": ("mlp.up_proj.weight", "T"),
+    "w_down": ("mlp.down_proj.weight", "T"),
+    "ln_post_attn": ("post_attention_layernorm.weight", None),
+    "ln_post_mlp": ("post_feedforward_layernorm.weight", None),
+}
+_OLMO_HYBRID_TEMPLATES = {
+    "attention": {
+        "wq": ("self_attn.q_proj.weight", "T"),
+        "wk": ("self_attn.k_proj.weight", "T"),
+        "wv": ("self_attn.v_proj.weight", "T"),
+        "wo": ("self_attn.o_proj.weight", "T"),
+        "q_norm": ("self_attn.q_norm.weight", None),
+        "k_norm": ("self_attn.k_norm.weight", None),
+        **_OLMO_FFN,
+    },
+    "state": {
+        "in_proj": (
+            tuple(f"linear_attn.{n}_proj.weight" for n in "qkvg"), "T",
+        ),
+        "ab_proj": (
+            ("linear_attn.a_proj.weight", "linear_attn.b_proj.weight"), "T",
+        ),
+        # three of [channels, 1, taps] -> [taps, all channels]
+        "conv_w": (
+            tuple(f"linear_attn.{n}_conv1d.weight" for n in "qkv"), "conv",
+        ),
+        "A_log": ("linear_attn.A_log", None),
+        "dt_bias": ("linear_attn.dt_bias", None),
+        "o_norm": ("linear_attn.o_norm.weight", None),
+        "wo": ("linear_attn.o_proj.weight", "T"),
+        **_OLMO_FFN,
+    },
+}
+# model_type -> (per-kind tables, the final norm's name)
+_HYBRID_TABLES = {
+    "jamba": (_JAMBA_TEMPLATES, "model.final_layernorm.weight"),
+    "olmo_hybrid": (_OLMO_HYBRID_TEMPLATES, "model.norm.weight"),
+}
 
 
-def _jamba_read(reader: SafetensorsReader, name: str, how, dtype):
+def _hybrid_read(reader: SafetensorsReader, names, how, dtype):
+    if isinstance(names, tuple):
+        return jnp.concatenate(
+            [_hybrid_read(reader, n, how, dtype) for n in names], axis=-1
+        )
     if how == "conv":
-        return reader.jax(name, dtype)[:, 0, :].T
-    return reader.jax(name, dtype, transpose=how == "T")
+        return reader.jax(names, dtype)[:, 0, :].T
+    return reader.jax(names, dtype, transpose=how == "T")
 
 
 def load_hybrid_layers(
     reader: SafetensorsReader, config: LlamaConfig, dtype
 ) -> list[Params]:
     """One stacked tree a run of layers of one kind, in the model's order
-    (``config.layer_runs``), from the per-kind HF names."""
+    (``config.layer_runs``), from the model type's per-kind HF names."""
+    tables, _ = _HYBRID_TABLES[config.model_type]
+    at = lambda i, names: (
+        tuple(f"model.layers.{i}.{n}" for n in names)
+        if isinstance(names, tuple) else f"model.layers.{i}.{names}"
+    )
     return [
         {
             key: jnp.stack([
-                _jamba_read(reader, f"model.layers.{i}.{name}", how, dtype)
+                _hybrid_read(reader, at(i, names), how, dtype)
                 for i in config.layers_of(kind)[lo:hi]
             ])
-            for key, (name, how) in _JAMBA_TEMPLATES[kind].items()
+            for key, (names, how) in tables[kind].items()
         }
         for kind, lo, hi in config.layer_runs
     ]
+
+
+def _joined_widths(config: LlamaConfig, key: str) -> list[int]:
+    """Widths of the checkpoint's tensors that one joined key holds."""
+    heads = config.linear_num_value_heads
+    n_k = config.linear_num_key_heads * config.linear_key_head_dim
+    n_v = heads * config.linear_value_head_dim
+    return {
+        "in_proj": [n_k, n_k, n_v, n_v], "ab_proj": [heads, heads],
+        "conv_w": [n_k, n_k, n_v],
+    }[key]
 
 
 def hybrid_tensor_dict(
     params: Params, config: LlamaConfig, dtype
 ) -> dict[str, np.ndarray]:
     """THE inverse of ``load_hybrid_layers`` (fixtures and round trips)."""
+    tables, final_norm = _HYBRID_TABLES[config.model_type]
     tensors = {
         "model.embed_tokens.weight": np.asarray(params["embed"].astype(dtype)),
-        _JAMBA_FINAL_NORM: np.asarray(params["ln_f"].astype(dtype)),
+        final_norm: np.asarray(params["ln_f"].astype(dtype)),
     }
     if not config.tie_word_embeddings:
         _emit_tensor(tensors, "lm_head.weight", params["lm_head"], True, dtype)
     for run, (kind, lo, hi) in zip(params["layers"], config.layer_runs):
-        for key, (name, how) in _JAMBA_TEMPLATES[kind].items():
+        for key, (names, how) in tables[kind].items():
             for k, i in enumerate(config.layers_of(kind)[lo:hi]):
-                a = np.asarray(run[key][k].astype(dtype))
-                if how == "conv":
-                    a = a.T[:, None, :]
-                elif how == "T":
-                    a = a.T
-                tensors[f"model.layers.{i}.{name}"] = a.copy()
+                whole = np.asarray(run[key][k].astype(dtype))
+                if isinstance(names, tuple):
+                    cuts = np.cumsum(_joined_widths(config, key))[:-1]
+                    parts = zip(names, np.split(whole, cuts, axis=-1))
+                else:
+                    parts = [(names, whole)]
+                for name, a in parts:
+                    if how == "conv":
+                        a = a.T[:, None, :]
+                    elif how == "T":
+                        a = a.T
+                    tensors[f"model.layers.{i}.{name}"] = a.copy()
     return tensors
 
 
@@ -607,7 +679,7 @@ def load_params(
         params = {
             "embed": reader.jax("model.embed_tokens.weight", dtype),
             "layers": load_hybrid_layers(reader, config, dtype),
-            "ln_f": reader.jax(_JAMBA_FINAL_NORM, dtype),
+            "ln_f": reader.jax(_HYBRID_TABLES[config.model_type][1], dtype),
         }
     elif config.cache_kind == CACHE_LATENT:
         params = {

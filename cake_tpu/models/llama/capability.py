@@ -5,7 +5,8 @@ KV head: the dense cache, the prefix cache's copy-on-write chains,
 speculative verify and its rewind, tensor / sequence / pipeline parallelism,
 the distributed workers' block ranges, weight quantisation's expert and
 projection tables, the single-stream generator. A model whose lanes keep
-something else (``config.cache_kind``: a recurrent state beside K and V, or
+something else (``config.cache_kind``: a recurrent state beside K and V,
+whichever mixer keeps it (Jamba's Mamba-1, Olmo-Hybrid's gated delta rule), or
 one latent a token in place of them) is served by its own paged leaf
 (``runtime/batch_backend.paged_backend``) on one chip, and everything else is
 refused HERE, with one message, before a weight is read: over such a cache

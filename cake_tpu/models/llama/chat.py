@@ -281,6 +281,24 @@ def encode_dialog_jamba(messages: list[Message]) -> str:
     return "".join(parts)
 
 
+def encode_dialog_olmo(messages: list[Message]) -> str:
+    """OLMo-2 (Tulu) template (written from memory of allenai's published
+    chat template; the catalog row carries none):
+
+        <|endoftext|><|system|>\n{sys}\n<|user|>\n{u}\n<|assistant|>\n
+
+    Every message is one ``<|role|>\ntext\n`` frame (an assistant's turn
+    ends with ``<|endoftext|>`` before the newline); the prompt ends with an
+    open assistant frame.
+    """
+    parts = ["<|endoftext|>"]
+    for m in messages:
+        end = "<|endoftext|>" if m.role.value == "assistant" else ""
+        parts.append(f"<|{m.role.value}|>\n{m.content.strip()}{end}\n")
+    parts.append("<|assistant|>\n")
+    return "".join(parts)
+
+
 def encode_dialog_pangu(messages: list[Message]) -> str:
     """openPangu template (written from memory of the published chat
     template; the catalog row carries none):
@@ -321,6 +339,7 @@ DIALOG_ENCODERS = {
     "phi3": encode_dialog_phi3,
     "jamba": encode_dialog_jamba,
     "pangu_ultra_moe": encode_dialog_pangu,
+    "olmo_hybrid": encode_dialog_olmo,
 }
 
 

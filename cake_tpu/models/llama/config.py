@@ -22,11 +22,15 @@ from typing import Any
 SUPPORTED_MODEL_TYPES = (
     "llama", "qwen2", "mistral", "mixtral", "qwen2_moe",
     "gemma", "gemma2", "phi3", "qwen3", "qwen3_moe", "gemma3_text", "jamba",
-    "pangu_ultra_moe",
+    "pangu_ultra_moe", "olmo_hybrid",
 )
 
 # The two kinds a decoder layer's token mixer can be (``layer_kinds``).
 ATTENTION, STATE = "attention", "state"
+# Which mixer a STATE layer runs (``state_mixer``): Mamba-1's diagonal
+# selective scan over a vector state a channel (ops/ssm.py), or a gated delta
+# rule over a matrix state a head (ops/delta_rule.py).
+MAMBA, GATED_DELTA = "mamba", "gated_delta"
 # The two kinds its feed-forward can be (``ff_kinds``).
 DENSE, SPARSE = "dense", "sparse"
 # What a lane keeps on the device between programs (``cache_kind``): K and V
@@ -132,19 +136,41 @@ class LlamaConfig:
     # ops; a kernel agrees with its twin to rounding. Like attention_impl
     # this is a runtime knob, never an HF field.
     fusion_impl: str = "none"
-    # Hybrid stacks (Jamba): layer i mixes tokens by attention when
-    # ``i % attn_layer_period == attn_layer_offset`` and by a Mamba-1
-    # state-space mixer (ops/ssm.py) otherwise. Period 0 = every layer is
-    # attention (every other family). Readers ask ``layer_kinds``.
+    # Hybrid stacks: which layers mix tokens by attention and which by a
+    # recurrent state (the layer's mixer: ``state_mixer``). Two sources, one
+    # reader (``layer_kinds``): an explicit list (``layer_types``, Olmo-Hybrid)
+    # or a period (Jamba: layer i is attention when ``i % attn_layer_period ==
+    # attn_layer_offset``). Neither = every layer is attention (every other
+    # family).
+    layer_types: tuple[str, ...] | None = None
     attn_layer_period: int = 0
     attn_layer_offset: int = 0
+    state_mixer: str = MAMBA
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: int = 0
-    # False = attention carries no positional term at all (Jamba: the state
-    # layers carry the order).
+    # The gated delta rule's sizes (HF ``linear_*``): heads of
+    # ``linear_key_head_dim`` keys and ``linear_value_head_dim`` values, a
+    # causal convolution of ``linear_conv_kernel_dim`` taps over q, k and v,
+    # and whether beta reaches 2 (a negative eigenvalue of I - beta k k^T).
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False
+    # False = attention carries no positional term at all (Jamba,
+    # Olmo-Hybrid: the state layers carry the order).
     use_rope: bool = True
+    # Norm placement, as data. ``pre_block_norms``: a norm on each branch's
+    # INPUT (``ln_attn`` / ``ln_mlp``; every family but OLMo's).
+    # ``post_block_norms`` (above): one on each branch's OUTPUT before the
+    # residual add; Gemma-2 has both, OLMo-2/3's block the second INSTEAD of
+    # the first. ``qk_norm_whole``: q and k are normed over the whole
+    # projection width (OLMo), not a head (``qk_norm``).
+    pre_block_norms: bool = True
+    qk_norm_whole: bool = False
     # Latent attention (MLA; ``pangu_ultra_moe``): queries and keys/values
     # are projected through low-rank latents, a head's query and key are a
     # no-position part beside a rotary part that all heads' keys share, and
@@ -190,7 +216,10 @@ class LlamaConfig:
     @property
     def layer_kinds(self) -> tuple[str, ...]:
         """The token mixer of every layer, in the model's order: THE one
-        per-layer fact model, cache, loader and backend ask."""
+        per-layer fact model, cache, loader and backend ask, whichever way
+        the checkpoint said it (a list or a period)."""
+        if self.layer_types is not None:
+            return self.layer_types
         p = self.attn_layer_period
         return tuple(
             ATTENTION if not p or i % p == self.attn_layer_offset else STATE
@@ -267,13 +296,38 @@ class LlamaConfig:
         return self.mamba_expand * self.hidden_size
 
     @property
+    def state_shape(self) -> tuple[int, int]:
+        """One lane's float32 state in one state layer, as the cache lays it
+        out (the minor axis whole 128-lane tiles at published widths):
+        Mamba's [d_state, d_inner]; the delta rule's [dk, H * dv], head h's
+        S^T in columns h * dv .. (h + 1) * dv."""
+        if self.state_mixer == GATED_DELTA:
+            return (
+                self.linear_key_head_dim,
+                self.linear_num_value_heads * self.linear_value_head_dim,
+            )
+        return (self.mamba_d_state, self.mamba_d_inner)
+
+    @property
+    def conv_window(self) -> tuple[int, int]:
+        """(inputs kept, channels) of a state layer's causal convolution:
+        Mamba's over u; the delta rule's over q, k and v side by side."""
+        if self.state_mixer == GATED_DELTA:
+            channels = (
+                2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim
+            )
+            return (self.linear_conv_kernel_dim - 1, channels)
+        return (self.mamba_d_conv - 1, self.mamba_d_inner)
+
+    @property
     def state_bytes_per_lane(self) -> int:
-        """Recurrent state one lane holds over all state layers: the scan's
-        float32 accumulator [d_inner, d_state] and the convolution's last
-        d_conv - 1 inputs (counted at 2 bytes, the served type)."""
-        per_layer = self.mamba_d_inner * (
-            4 * self.mamba_d_state + 2 * (self.mamba_d_conv - 1)
-        )
+        """Recurrent state one lane holds over all state layers, whatever
+        their mixer: the float32 state (``state_shape``) and the
+        convolution's window (counted at 2 bytes, the served type)."""
+        rows, cols = self.state_shape
+        kept, channels = self.conv_window
+        per_layer = 4 * rows * cols + 2 * kept * channels
         return per_layer * len(self.layers_of(STATE))
 
     @property
@@ -348,6 +402,8 @@ class LlamaConfig:
             return cls._jamba_from_hf_dict(d, eos_ids)
         if model_type == "pangu_ultra_moe":
             return cls._pangu_from_hf_dict(d, eos_ids)
+        if model_type == "olmo_hybrid":
+            return cls._olmo_hybrid_from_hf_dict(d, eos_ids)
         if model_type == "phi3" and d.get("rope_scaling"):
             # Phi-3 128k variants use longrope (per-dim su-scaled factors);
             # only the base-rope variants (4k/8k) are supported.
@@ -570,6 +626,75 @@ class LlamaConfig:
         )
 
     @classmethod
+    def _olmo_hybrid_from_hf_dict(
+        cls, d: dict[str, Any], eos_ids: tuple[int, ...]
+    ) -> "LlamaConfig":
+        """``model_type: olmo_hybrid``: ``layer_types`` lists gated-delta-rule
+        layers (``linear_attention``) beside softmax attention without a
+        positional term (``full_attention``), dense SwiGLU everywhere, the
+        OLMo-2/3 block (a norm on each branch's output and none on its input;
+        q and k normed over the whole projection)."""
+        kinds = {"linear_attention": STATE, "full_attention": ATTENTION}
+        if "eos_token_id" not in d:
+            eos_ids = (100257,)  # the OLMo-2 tokenizer's <|endoftext|>
+        n_layers = int(d.get("num_hidden_layers", 32))
+        raw = d.get("layer_types")
+        if raw is None or len(raw) != n_layers or set(raw) - set(kinds):
+            raise ValueError(
+                f"olmo_hybrid needs layer_types: {n_layers} entries "
+                f"(num_hidden_layers) of {sorted(kinds)}, got {raw!r}"
+            )
+        theta = (d.get("rope_parameters") or {}).get("rope_theta", d.get("rope_theta"))
+        if theta is not None:
+            raise ValueError(
+                f"olmo_hybrid with rope_theta={theta} needs a rotary term on "
+                "its attention layers, which this framework does not bring "
+                "(rope_theta null checkpoints only)"
+            )
+        if d.get("attention_bias", False):
+            raise ValueError("olmo_hybrid with attention_bias is not supported")
+        heads = int(d.get("num_attention_heads", 30))
+        lin_k = int(d.get("linear_num_key_heads", heads))
+        lin_v = int(d.get("linear_num_value_heads", lin_k))
+        if lin_k != lin_v:
+            raise ValueError(
+                f"olmo_hybrid with linear_num_key_heads={lin_k} != "
+                f"linear_num_value_heads={lin_v} needs grouped delta-rule "
+                "heads, which this framework does not bring"
+            )
+        head_dim = d.get("head_dim")
+        hidden = int(d.get("hidden_size", 3840))
+        if head_dim is not None and int(head_dim) * heads == hidden:
+            head_dim = None
+        return cls(
+            hidden_size=hidden,
+            intermediate_size=int(d.get("intermediate_size", 11008)),
+            vocab_size=int(d.get("vocab_size", 100352)),
+            num_hidden_layers=n_layers,
+            num_attention_heads=heads,
+            num_key_value_heads=int(d.get("num_key_value_heads", heads)),
+            rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+            max_position_embeddings=int(d.get("max_position_embeddings", 65536)),
+            bos_token_id=int(d.get("bos_token_id", eos_ids[0])),
+            eos_token_ids=eos_ids,
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            model_type="olmo_hybrid",
+            head_dim_override=None if head_dim is None else int(head_dim),
+            layer_types=tuple(kinds[t] for t in raw),
+            state_mixer=GATED_DELTA,
+            linear_num_key_heads=lin_k,
+            linear_num_value_heads=lin_v,
+            linear_key_head_dim=int(d.get("linear_key_head_dim", 96)),
+            linear_value_head_dim=int(d.get("linear_value_head_dim", 192)),
+            linear_conv_kernel_dim=int(d.get("linear_conv_kernel_dim", 4)),
+            linear_allow_neg_eigval=bool(d.get("linear_allow_neg_eigval", False)),
+            use_rope=False,
+            pre_block_norms=False,
+            post_block_norms=True,
+            qk_norm_whole=True,
+        )
+
+    @classmethod
     def _pangu_from_hf_dict(
         cls, d: dict[str, Any], eos_ids: tuple[int, ...]
     ) -> "LlamaConfig":
@@ -713,6 +838,7 @@ class LlamaConfig:
             "qwen3_moe": "Qwen3MoeForCausalLM",
             "jamba": "JambaForCausalLM",
             "pangu_ultra_moe": "PanguUltraMoEForCausalLM",
+            "olmo_hybrid": "OlmoHybridForCausalLM",
         }[self.model_type]
         d: dict[str, Any] = {
             "architectures": [arch],
@@ -809,6 +935,21 @@ class LlamaConfig:
                 mamba_proj_bias=False,
                 num_experts=1,
                 num_experts_per_tok=1,
+            )
+        if self.model_type == "olmo_hybrid":
+            del d["rope_theta"]
+            d.update(
+                rope_parameters={"rope_theta": None},
+                layer_types=[
+                    "full_attention" if k == ATTENTION else "linear_attention"
+                    for k in self.layer_kinds
+                ],
+                linear_num_key_heads=self.linear_num_key_heads,
+                linear_num_value_heads=self.linear_num_value_heads,
+                linear_key_head_dim=self.linear_key_head_dim,
+                linear_value_head_dim=self.linear_value_head_dim,
+                linear_conv_kernel_dim=self.linear_conv_kernel_dim,
+                linear_allow_neg_eigval=self.linear_allow_neg_eigval,
             )
         if self.rope_scaling is not None and self.rope_scaling.rope_type == "linear":
             d["rope_scaling"] = {

@@ -1,4 +1,5 @@
-"""Hybrid stacks: state-space layers beside attention (``model_type: jamba``).
+"""Hybrid stacks: layers that keep a recurrent state beside attention
+(``model_type: jamba``: Mamba-1 mixers; ``olmo_hybrid``: a gated delta rule).
 
 Every other family is a stack of identical attention blocks: one stacked
 tree, one ``lax.scan`` (models/llama/batch.batched_blocks_forward), K and V
@@ -8,21 +9,25 @@ per-lane state, so here:
 
   * ``params["layers"]`` is a LIST of stacked trees, one a maximal run of
     layers of one kind in the model's order (``config.layer_runs``; Jamba2-3B:
-    7 state, 1 attention, 13 state, 1 attention, 6 state). A run is one
-    ``lax.scan``. The attention runs go through the paged branch of
-    ``batched_blocks_forward`` itself (same kernels, same write), told which
-    pool layers they own; a state run scans ``ops/ssm.mixer_forward``. Both
-    kinds share the block's tail (residual, pre-feed-forward norm, SwiGLU:
-    ``model.block_finish``; a state layer's out-projection is its ``wo``).
+    7 state, 1 attention, 13 state, 1 attention, 6 state; Olmo-Hybrid: 3
+    state, 1 attention, a period). A run is one ``lax.scan``. The attention
+    runs go through the paged branch of ``batched_blocks_forward`` itself
+    (same kernels, same write), told which pool layers they own; a state run
+    scans the layer's mixer, which the config names (``config.state_mixer``:
+    ``ops/ssm.mixer_forward`` or ``ops/delta_rule.mixer_forward``). Both
+    kinds share the block's tail (residual, norms where the tree has them,
+    SwiGLU: ``model.block_finish``; a state layer's out-projection is its
+    ``wo``).
   * ``HybridCache`` is the one cache value: a ``PagedKVCache`` that holds
     the ATTENTION layers only, and the lane state ``ssm`` / ``conv`` of the
-    state layers, indexed by lane and not by page. It is passed wherever the
+    state layers (``config.state_shape`` / ``conv_window``: the mixer's),
+    indexed by lane and not by page. It is passed wherever the
     paged backend passes ``kv``, donated, and carried through every scan: a
     layer reads and writes its slice in place (PR 26's rule, extended to the
     state: ``pool_audit.audit_hybrid_programs``).
   * Left pads, a join window's dead tail, and lanes that are not live in a
     decode dispatch are all one mask, ``live`` [b, L]: where it is false the
-    recurrence passes its state through (``ops/ssm.py``).
+    recurrence passes its state through (``ops/ssm.py``, ``ops/delta_rule.py``).
 
 What cannot run over a recurrent state is refused at start-up, in one place
 (``capability.refuse_unsupported``): everything that restores, shares,
@@ -38,22 +43,27 @@ import jax
 import jax.numpy as jnp
 
 from cake_tpu.models.llama import model as M
-from cake_tpu.models.llama.config import ATTENTION, STATE, LlamaConfig
+from cake_tpu.models.llama.config import (
+    ATTENTION, GATED_DELTA, STATE, LlamaConfig,
+)
 from cake_tpu.models.llama.paged_cache import PagedKVCache, init_paged_cache
 from cake_tpu.obs.jitwatch import tracked_jit as _tracked_jit
+from cake_tpu.ops import delta_rule as D
 from cake_tpu.ops import ssm as S
 from cake_tpu.ops.fuse import resolve_fusion
 from cake_tpu.ops.norm import rms_norm
 
 
 class HybridCache(NamedTuple):
-    """Per-lane device state of a hybrid model. ``ssm`` and ``conv`` keep
-    ``d_inner`` (a multiple of 128) as the minor axis so that no TPU tile is
-    padded; ``ssm`` is float32 (an accumulator), ``conv`` the served type."""
+    """Per-lane device state of a hybrid model, in the layer's mixer's
+    shapes (``config.state_shape`` / ``conv_window``). Both keep a multiple
+    of 128 as the minor axis at published widths so that no TPU tile is
+    padded (Mamba's ``d_inner``; the delta rule's ``H * dv`` and ``H (2 dk +
+    dv)``); ``ssm`` is float32 (an accumulator), ``conv`` the served type."""
 
     kv: PagedKVCache  # the attention layers' page pool, [n_attention, ...]
-    ssm: jnp.ndarray  # [n_state, lanes, d_state, d_inner] float32
-    conv: jnp.ndarray  # [n_state, d_conv - 1, lanes, d_inner]
+    ssm: jnp.ndarray  # [n_state, lanes, *state_shape] float32
+    conv: jnp.ndarray  # [n_state, taps - 1, lanes, channels]
 
 
 def init_hybrid_cache(
@@ -61,14 +71,14 @@ def init_hybrid_cache(
 ) -> HybridCache:
     """Zeroed: a lane's recurrence starts from s = 0 and a window of zeros."""
     n_state = len(config.layers_of(STATE))
-    d = config.mamba_d_inner
+    kept, channels = config.conv_window
     return HybridCache(
         kv=init_paged_cache(
             len(config.layers_of(ATTENTION)), n_pages,
             config.num_key_value_heads, page_size, config.head_dim, dtype,
         ),
-        ssm=jnp.zeros((n_state, lanes, config.mamba_d_state, d), jnp.float32),
-        conv=jnp.zeros((n_state, config.mamba_d_conv - 1, lanes, d), dtype),
+        ssm=jnp.zeros((n_state, lanes, *config.state_shape), jnp.float32),
+        conv=jnp.zeros((n_state, kept, lanes, channels), dtype),
     )
 
 
@@ -77,18 +87,31 @@ def init_hybrid_cache(
 def run_shapes(config: LlamaConfig, kind: str) -> dict[str, tuple[int, ...]]:
     """Per-layer shapes of one kind's tree (stacked over its run), as this
     module holds them. Matrices are [in, out] like every other weight here;
-    ``A_log`` is stored [d_state, d_inner] and ``conv_w`` [d_conv, d_inner]
-    (io/safetensors_io.py transposes both); a state layer's out-projection
-    is its ``wo``."""
+    Mamba's ``A_log`` is stored [d_state, d_inner] and a ``conv_w`` [taps,
+    channels] (io/safetensors_io.py transposes both); a state layer's
+    out-projection is its ``wo``. The delta rule's ``in_proj`` is q | k | v |
+    z side by side, ``ab_proj`` a | b and ``conv_w`` q's, k's and v's taps
+    (the loader joins the checkpoint's tensors). Which norms a layer has is
+    the config's (``pre_block_norms`` / ``post_block_norms``)."""
     h, inter = config.hidden_size, config.intermediate_size
-    ffn = {
-        "w_gate": (h, inter), "w_up": (h, inter), "w_down": (inter, h),
-        "ln_attn": (h,), "ln_mlp": (h,),
-    }
+    ffn = {"w_gate": (h, inter), "w_up": (h, inter), "w_down": (inter, h)}
+    if config.pre_block_norms:
+        ffn.update(ln_attn=(h,), ln_mlp=(h,))
+    if config.post_block_norms:
+        ffn.update(ln_post_attn=(h,), ln_post_mlp=(h,))
     if kind == ATTENTION:
         hd = config.head_dim
         q, kv = config.num_attention_heads * hd, config.num_key_value_heads * hd
-        return {"wq": (h, q), "wk": (h, kv), "wv": (h, kv), "wo": (q, h), **ffn}
+        qk = {"q_norm": (q,), "k_norm": (kv,)} if config.qk_norm_whole else {}
+        return {"wq": (h, q), "wk": (h, kv), "wv": (h, kv), "wo": (q, h), **qk, **ffn}
+    if config.state_mixer == GATED_DELTA:
+        heads, dv = config.linear_num_value_heads, config.linear_value_head_dim
+        kept, channels = config.conv_window
+        return {
+            "in_proj": (h, channels + heads * dv), "ab_proj": (h, 2 * heads),
+            "conv_w": (kept + 1, channels), "A_log": (heads,),
+            "dt_bias": (heads,), "o_norm": (dv,), "wo": (heads * dv, h), **ffn,
+        }
     d, n, r = config.mamba_d_inner, config.mamba_d_state, config.mamba_dt_rank
     return {
         "in_proj": (h, 2 * d), "conv_w": (config.mamba_d_conv, d),
@@ -98,14 +121,25 @@ def run_shapes(config: LlamaConfig, kind: str) -> dict[str, tuple[int, ...]]:
     }
 
 
+# How ``init_params`` draws a name: norms (and Mamba's D) are ones; the
+# convolution's taps and the gates' terms are drawn wide enough (0.2) for
+# the recurrence to be visible at a tiny width (alpha and beta spread, beta
+# on both sides of 1); everything else at 0.02.
+_ONES = frozenset((
+    "D", "dt_ln", "b_ln", "c_ln", "ln_attn", "ln_mlp", "ln_post_attn",
+    "ln_post_mlp", "q_norm", "k_norm", "o_norm",
+))
+_WIDE = frozenset(("conv_w", "conv_b", "A_log", "dt_bias", "ab_proj"))
+
+
 def init_params(config: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> M.Params:
     """Random-init params in the by-run layout (tests and compile checks)."""
     std = 0.02
 
     def draw(k, name, shape):
-        if name in ("D", "dt_ln", "b_ln", "c_ln", "ln_attn", "ln_mlp"):
+        if name in _ONES:
             return jnp.ones(shape, dtype)
-        scale = 0.2 if name in ("conv_w", "conv_b", "A_log", "dt_bias") else std
+        scale = 0.2 if name in _WIDE else std
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
     runs = []
@@ -166,6 +200,18 @@ def hybrid_blocks_forward(
     kv, ssm, conv = cache
     eps = config.rms_norm_eps
     rows = x.shape[0]
+    if config.state_mixer == GATED_DELTA:
+        mixer = functools.partial(
+            D.mixer_forward, eps=eps, neg_eigval=config.linear_allow_neg_eigval
+        )
+    else:
+        mixer = functools.partial(S.mixer_forward, eps=eps, allow_pallas=use_pallas)
+    # A decode step of a delta-rule stack updates the carry's state in place
+    # through the Pallas kernel, which takes the stack whole.
+    in_place = (
+        config.state_mixer == GATED_DELTA and lane is None and x.shape[1] == 1
+        and use_pallas and D.steps_in_place(ssm, config.linear_num_value_heads)
+    )
     if lane is not None:
         lanes = jnp.arange(conv.shape[2], dtype=jnp.int32)
         mine = (lanes >= lane) & (lanes < lane + rows)
@@ -176,24 +222,24 @@ def hybrid_blocks_forward(
         x, ssm, conv = carry
         lp, li = per_layer
         c_old = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
-        if lane is None:
+        h = rms_norm(x, lp["ln_attn"], eps) if "ln_attn" in lp else x
+        if in_place:
+            gated, ssm, c_l = D.mixer_step_stacked(
+                lp, h, ssm, li, c_old, live, eps, config.linear_allow_neg_eigval
+            )
+        elif lane is None:
             s_l = jax.lax.dynamic_index_in_dim(ssm, li, 0, keepdims=False)
-            c_l = c_old
+            gated, s_l, c_l = mixer(lp, h, s_l, c_old, live, ends)
+            ssm = jax.lax.dynamic_update_index_in_dim(ssm, s_l, li, 0)
         else:
             s_l = jnp.zeros((rows, *ssm.shape[2:]), ssm.dtype)
             c_l = jnp.zeros((conv.shape[1], rows, conv.shape[3]), conv.dtype)
-        h = rms_norm(x, lp["ln_attn"], eps)
-        gated, s_l, c_l = S.mixer_forward(
-            lp, h, s_l, c_l, live, ends, eps, allow_pallas=use_pallas
-        )
-        if lane is None:
-            ssm = jax.lax.dynamic_update_index_in_dim(ssm, s_l, li, 0)
-        else:
+            gated, s_l, c_l = mixer(lp, h, s_l, c_l, live, ends)
             zero = jnp.int32(0)
             ssm = jax.lax.dynamic_update_slice(
                 ssm, s_l[None], (li, lane, zero, zero)
             )
-            # The window's lane axis is a tiled one ([.., lanes, d_inner]):
+            # The window's lane axis is a tiled one ([.., lanes, channels]):
             # an update-slice at a lane there makes the TPU compiler re-lay
             # the whole array out and back (two copies of it, seen compiling
             # for a described v5e). The rows are placed in a buffer of the

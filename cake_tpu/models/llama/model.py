@@ -226,7 +226,10 @@ def block_qkv_flat(
     if fusion is None:
         fusion = resolve_fusion(config)
     fusions, fimpl = fusion
-    if "norm" in fusions:
+    if "ln_attn" not in lp:
+        # No norm on the branch's input (OLMo's block norms its output).
+        qkv = qmat(x, lp["wqkv"])
+    elif "norm" in fusions:
         qkv = fused_norm_matmul(
             x, lp["ln_attn"], lp["wqkv"],
             eps=config.rms_norm_eps, offset=config.rmsnorm_offset,
@@ -289,16 +292,23 @@ def block_qkv(
         k = qkv[..., qw : qw + kw]
         v = qkv[..., qw + kw :]
     else:
-        h = rms_norm(x, lp["ln_attn"], config.rms_norm_eps, config.rmsnorm_offset)
+        h = x
+        if "ln_attn" in lp:
+            h = rms_norm(x, lp["ln_attn"], config.rms_norm_eps, config.rmsnorm_offset)
         q, k, v = qmat(h, lp["wq"]), qmat(h, lp["wk"]), qmat(h, lp["wv"])
         if "bq" in lp:  # Qwen2-family QKV bias (config.attention_bias)
             q = q + lp["bq"].astype(q.dtype)
             k = k + lp["bk"].astype(k.dtype)
             v = v + lp["bv"].astype(v.dtype)
+    if config.qk_norm_whole:
+        # OLMo-2/3: one RMSNorm over the WHOLE q (and k) projection, all
+        # heads together, before the heads are told apart.
+        q = rms_norm(q, lp["q_norm"], config.rms_norm_eps, config.rmsnorm_offset)
+        k = rms_norm(k, lp["k_norm"], config.rms_norm_eps, config.rmsnorm_offset)
     q = q.reshape(b, chunk, n_q, hd)
     k = k.reshape(b, chunk, n_kv, hd)
     v = v.reshape(b, chunk, n_kv, hd)
-    if "q_norm" in lp:
+    if "q_norm" in lp and not config.qk_norm_whole:
         # Qwen3 family: head_dim-wide RMSNorm on every q/k head AFTER the
         # projection, BEFORE RoPE (HF Qwen3Attention.forward — "only on the
         # head dim"). The weight is shared across heads, so tensor-parallel
@@ -329,7 +339,10 @@ def block_finish(
     moe_layer=None,
 ):
     """Shared tail: out-projection + residual, rms_2 -> SwiGLU + residual,
-    with the tensor-parallel psums at the two partial-sum points. A layer
+    with the tensor-parallel psums at the two partial-sum points. Which norms
+    run is the tree's (placement as data: ``ln_mlp`` on the feed-forward's
+    input, ``ln_post_attn`` / ``ln_post_mlp`` on a branch's output; Gemma-2
+    has all, OLMo's block the output ones alone). A layer
     tree carrying a "router" runs the Mixtral MoE MLP instead of the dense
     SwiGLU (experts sharded over tp; same partial-sum + psum convention).
     ``moe_valid`` ([b, chunk] bool) marks pad slots whose routed assignments
@@ -356,7 +369,7 @@ def block_finish(
         # residual add.
         o = rms_norm(o, lp["ln_post_attn"], config.rms_norm_eps, off)
     x = x + o
-    if "norm" in fusions and "w_gu" in lp and "router" not in lp:
+    if "norm" in fusions and "w_gu" in lp and "router" not in lp and "ln_mlp" in lp:
         # rms_2 folded into the gate|up matmul; the epilogue is the literal
         # swiglu_gu tail, so the branch is byte-identical to the unfused one.
         gu = fused_norm_matmul(
@@ -371,7 +384,9 @@ def block_finish(
         if "ln_post_mlp" in lp:
             mlp = rms_norm(mlp, lp["ln_post_mlp"], config.rms_norm_eps, off)
         return x + mlp
-    h = rms_norm(x, lp["ln_mlp"], config.rms_norm_eps, off)
+    # No ``ln_mlp`` = no norm on the feed-forward's input (OLMo's block norms
+    # its output instead: ``ln_post_mlp`` below).
+    h = rms_norm(x, lp["ln_mlp"], config.rms_norm_eps, off) if "ln_mlp" in lp else x
     if "router" in lp:
         mlp = moe_swiglu(
             h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],

@@ -730,6 +730,268 @@ def timed_selective_scan(
     return rows
 
 
+@dataclasses.dataclass(frozen=True)
+class DeltaGeometry:
+    """A model whose state layers run the gated delta rule
+    (``ops/delta_rule.py``): its attention layers' head layout for the paged
+    kernels, its mixer's sizes."""
+    hidden: int
+    n_q: int
+    n_kv: int
+    head_dim: int
+    heads: int
+    dk: int
+    dv: int
+    taps: int
+    page_size: int
+    max_seq: int
+    chunk: int
+    prompt: int  # a mixer prefill's length; not a multiple of the rule's chunk
+    dtype: str
+    batches: tuple[int, ...] = (1, 8)
+
+
+def _delta_stepwise(q, k, v, log_alpha, beta, s0):
+    """The gated delta rule one position at a time (``lax.scan``), float32 at
+    matmul precision ``highest``: what the chunkwise form and the step kernel
+    are held against. s is S^T a head, [b, H, dk, dv]."""
+    with jax.default_matmul_precision("highest"):
+        def step(s, xs):
+            q_t, k_t, v_t, a_t, b_t = xs
+            s = jnp.exp(a_t)[..., None, None] * s
+            err = v_t - jnp.einsum("bhkv,bhk->bhv", s, k_t)
+            s = s + jnp.einsum("bhk,bhv->bhkv", k_t, b_t[..., None] * err)
+            return s, jnp.einsum("bhkv,bhk->bhv", s, q_t)
+
+        t_major = lambda x: jnp.moveaxis(x, 1, 0)
+        s, o = jax.lax.scan(
+            step, s0, tuple(map(t_major, (q, k, v, log_alpha, beta))))
+        return t_major(o), s
+
+
+def _delta_mixer_reference(lp, h, s0, window, eps, g: DeltaGeometry):
+    """The delta-rule mixer as ``bench/architectures/olmo_hybrid.py`` states
+    it, float32 at ``highest``, the rule stepwise. (rms(o) * silu(z), the
+    state after the last token in the cache's layout, the convolution's last
+    inputs.)"""
+    from cake_tpu.ops import delta_rule as D
+
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    lp = {k: f32(v) for k, v in lp.items()}
+    h, s0, window = f32(h), f32(s0), f32(window)
+    b, length = h.shape[:2]
+    n_k, n_v = g.heads * g.dk, g.heads * g.dv
+    with jax.default_matmul_precision("highest"):
+        qkvz = h @ lp["in_proj"]
+        u_in, z = qkvz[..., :2 * n_k + n_v], qkvz[..., 2 * n_k + n_v:]
+        padded = jnp.concatenate([jnp.moveaxis(window, 0, 1), u_in], axis=1)
+        u = jax.nn.silu(sum(
+            lp["conv_w"][j] * padded[:, j:j + length] for j in range(g.taps)))
+        unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+        q = unit(u[..., :n_k].reshape(b, length, g.heads, g.dk)) * g.dk ** -0.5
+        k = unit(u[..., n_k:2 * n_k].reshape(b, length, g.heads, g.dk))
+        v = u[..., 2 * n_k:].reshape(b, length, g.heads, g.dv)
+        ab = h @ lp["ab_proj"]
+        beta = 2.0 * jax.nn.sigmoid(ab[..., g.heads:])
+        log_alpha = -jnp.exp(lp["A_log"]) * jax.nn.softplus(
+            ab[..., :g.heads] + lp["dt_bias"])
+    o, s = _delta_stepwise(q, k, v, log_alpha, beta, D.to_heads(s0, g.heads))
+    y = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) * lp["o_norm"]
+    y = y * jax.nn.silu(z).reshape(b, length, g.heads, g.dv)
+    return (y.reshape(b, length, n_v), D.from_heads(s),
+            jnp.moveaxis(padded[:, -(g.taps - 1):], 1, 0))
+
+
+def _delta_mixer_cases(c: _Cases, g: DeltaGeometry) -> None:
+    from cake_tpu.ops import delta_rule as D
+
+    eps = 1e-6
+    n_k, n_v = g.heads * g.dk, g.heads * g.dv
+    channels = 2 * n_k + n_v
+    lp = {
+        "in_proj": c.normal((g.hidden, channels + n_v), 0.02),
+        # gates over their range at inputs of unit size (beta on both sides of 1)
+        "ab_proj": c.normal((g.hidden, 2 * g.heads), g.hidden ** -0.5),
+        "conv_w": c.normal((g.taps, channels), 0.5),
+        "A_log": c.normal((g.heads,), 0.5), "dt_bias": c.normal((g.heads,), 0.5),
+        "o_norm": jnp.ones((g.dv,), c.dtype),
+    }
+    prefill_fn = jax.jit(lambda lp, h, s0, w0, live, ends: D.mixer_forward(
+        lp, h, s0, w0, live, ends, eps))
+    # the decode step as the model takes it: the stack whole, in place
+    stacked = D.steps_in_place(jnp.zeros((1, 1, g.dk, n_v)), g.heads)
+    if stacked:
+        step_fn = jax.jit(lambda lp, h1, stack, w1, alive: D.mixer_step_stacked(
+            lp, h1, stack, jnp.int32(1), w1, alive[:, None], eps))
+    else:
+        def step_fn(lp, h1, stack, w1, alive):
+            gated, s, w = D.mixer_forward(
+                lp, h1, stack[1], w1, alive[:, None], None, eps)
+            return gated, stack.at[1].set(s), w
+        step_fn = jax.jit(step_fn)
+    for b in g.batches:
+        length = g.prompt
+        pads = jnp.asarray(
+            np.random.default_rng(b).integers(0, length // 3, size=b), jnp.int32)
+        h = c.normal((b, length, g.hidden))
+        live = jnp.arange(length, dtype=jnp.int32)[None, :] >= pads[:, None]
+        s0 = jnp.zeros((b, g.dk, n_v), jnp.float32)
+        w0 = jnp.zeros((g.taps - 1, b, channels), c.dtype)
+        ends = jnp.full((b,), length, jnp.int32)
+
+        def prefill():
+            (gated, s, w), first = _timed(prefill_fn, lp, h, s0, w0, live, ends)
+            wants = [
+                _delta_mixer_reference(lp, h[r:r + 1, int(pads[r]):], s0[r:r + 1],
+                                       w0[:, r:r + 1], eps, g)
+                for r in range(b)
+            ]
+            got = (jnp.concatenate([gated[r, int(pads[r]):] for r in range(b)]), s, w)
+            want = (
+                jnp.concatenate([x[0][0] for x in wants]),
+                jnp.concatenate([x[1] for x in wants]),
+                jnp.concatenate([x[2] for x in wants], axis=1),
+            )
+            return got, want, first
+
+        c.run("delta_mixer", f"prefill b={b} L={length}", prefill)
+
+        stack = c.normal((3, b, g.dk, n_v), 0.5, jnp.float32)
+        w1 = c.normal((g.taps - 1, b, channels), 0.5)
+        h1 = c.normal((b, 1, g.hidden))
+        alive = jnp.arange(b) != b - 1 if b > 1 else jnp.ones((1,), bool)
+
+        def step():
+            # the stack is not donated here: the kernel's alias copies it
+            (gated, out, w), first = _timed(step_fn, lp, h1, stack, w1, alive)
+            want_g, want_s, want_w = _delta_mixer_reference(
+                lp, h1, stack[1], w1, eps, g)
+            want_s = jnp.where(alive[:, None, None], want_s, stack[1])
+            want_w = jnp.where(alive[None, :, None], want_w, w1.astype(jnp.float32))
+            want_stack = stack.at[1].set(want_s)
+            return (gated[alive], out, w), (want_g[alive], want_stack, want_w), first
+
+        c.run("delta_mixer", f"step b={b} {'kernel' if stacked else 'xla'}", step)
+
+
+def run_delta_checks(geom: DeltaGeometry) -> dict:
+    """``run_hybrid_checks`` for a model whose state layers run the gated
+    delta rule: the paged kernels at its attention layers' head layout, and
+    the mixer's prefill (the chunkwise form) and one-token step (the Pallas
+    kernel where the widths tile) against the stepwise float32 rule."""
+    c = _Cases(Geometry(
+        hidden=geom.hidden, intermediate=0, n_q=geom.n_q, n_kv=geom.n_kv,
+        head_dim=geom.head_dim, vocab=0, window=None,
+        page_size=geom.page_size, max_seq=geom.max_seq, chunk=geom.chunk,
+        int4_group=0, dtype=geom.dtype, batches=geom.batches,
+    ))
+    with recorded_interpret() as seen:
+        _paged_head_cases(c, geom.n_q, geom.n_kv)
+        _delta_mixer_cases(c, geom)
+    return {"results": c.results, "interpret": seen}
+
+
+def timed_delta_rule(
+    heads: int,
+    dk: int,
+    dv: int,
+    windows: tuple[tuple[int, int], ...] = ((1, 512), (1, 2048), (4, 512)),
+    lanes: int = 32,
+    calls: int = 12,
+    repeats: int = 3,
+) -> list[dict]:
+    """The gated delta rule alone, ``calls`` dependent calls a program (a
+    model's state layers: each call's state and output feed the next), timed
+    on the host clock around ``block_until_ready``, the fastest of
+    ``repeats``. For each (rows, length) of ``windows``: the chunkwise form
+    (``ops/delta_rule.gated_delta_rule``) against the rule one position at a
+    time, errors over the largest value of the stepwise form's and
+    microseconds a call of both; the first eighth of a window is not live.
+    Then the one-token update at ``lanes`` rows: the Pallas kernel on a
+    stack's state in place (where the widths tile) beside its XLA twin."""
+    from cake_tpu.ops import delta_rule as D
+    from cake_tpu.ops.pallas import delta_step
+
+    def draw(key, b, length):
+        keys = jax.random.split(key, 6)
+        n = lambda k, *shape: jax.random.normal(k, shape, jnp.float32)
+        live = (jnp.arange(length) >= length // 8)[None, :, None]
+        q = D._unit(n(keys[0], b, length, heads, dk)) * dk ** -0.5
+        k = D._unit(n(keys[1], b, length, heads, dk) + n(keys[1], b, 1, heads, dk))
+        log_alpha = jnp.where(live, -jax.nn.softplus(n(keys[3], b, length, heads)), 0.0)
+        beta = jnp.where(live, 2.0 * jax.nn.sigmoid(n(keys[4], b, length, heads)), 0.0)
+        return (q, k, n(keys[2], b, length, heads, dv), log_alpha, beta,
+                n(keys[5], b, heads, dk, dv))
+
+    def chain(rule):
+        @jax.jit
+        def run(q, k, v, log_alpha, beta, s0):
+            def one(_, carry):
+                v, s = carry
+                o, s = rule(q, k, v, log_alpha, beta, s)
+                return v + 1e-3 * o, s
+
+            return jax.lax.fori_loop(0, calls, one, (v, s0))
+
+        return run
+
+    forms = {"chunkwise": D.gated_delta_rule, "stepwise": _delta_stepwise}
+    once = {name: jax.jit(rule) for name, rule in forms.items()}
+    rows = []
+    for b, length in windows:
+        args = draw(jax.random.PRNGKey(b * length), b, length)
+        o_c, s_c = once["chunkwise"](*args)
+        o_s, s_s = once["stepwise"](*args)
+        rec = {"op": "gated_delta_rule", "rows": b, "length": length,
+               "err_o": _rel_err(o_c, o_s), "err_s": _rel_err(s_c, s_s)}
+        for name, rule in forms.items():
+            run = chain(rule)
+            _timed(run, *args)  # compile + warm
+            fastest = min(_timed(run, *args)[1] for _ in range(repeats))
+            rec[f"{name}_us"] = round(fastest / calls * 1e6, 1)
+        rows.append(rec)
+
+    # the one-token update: ``calls`` layers of one stack, each stepped once
+    q, k, v, log_alpha, beta = (
+        x[:, 0] for x in draw(jax.random.PRNGKey(7), lanes, 1)[:5])
+    stack = jax.random.normal(
+        jax.random.PRNGKey(8), (calls, lanes, dk, heads * dv), jnp.float32)
+
+    def twin_chain(stack, q, k, v, log_alpha, beta):
+        def one(i, carry):
+            stack, v = carry
+            o, s = D.gated_delta_step(
+                q, k, v, log_alpha, beta, D.to_heads(stack[i], heads))
+            return stack.at[i].set(D.from_heads(s)), v + 1e-3 * o
+
+        return jax.lax.fori_loop(0, calls, one, (stack, v))
+
+    def kernel_chain(stack, q, k, v, log_alpha, beta):
+        def one(i, carry):
+            stack, v = carry
+            o, stack = delta_step.gated_delta_step(stack, i, q, k, v, log_alpha, beta)
+            return stack, v + 1e-3 * o
+
+        return jax.lax.fori_loop(0, calls, one, (stack, v))
+
+    rec = {"op": "gated_delta_step", "rows": lanes, "length": 1}
+    chains = {"twin": jax.jit(twin_chain, donate_argnums=0)}
+    if delta_step.tiles(dk, heads * dv, dv):
+        chains["kernel"] = jax.jit(kernel_chain, donate_argnums=0)
+    outs = {}
+    for name, stepped in chains.items():
+        outs[name] = jax.tree.map(np.asarray, stepped(stack + 0.0, q, k, v, log_alpha, beta))
+        fastest = min(
+            _timed(stepped, stack + 0.0, q, k, v, log_alpha, beta)[1]
+            for _ in range(repeats))
+        rec[f"{name}_us"] = round(fastest / calls * 1e6, 1)
+    if "kernel" in outs:
+        rec["err_s"] = _rel_err(outs["kernel"][0], outs["twin"][0])
+        rec["err_o"] = _rel_err(outs["kernel"][1], outs["twin"][1])
+    rows.append(rec)
+    return rows
+
+
 def timed_matmul_chain(n: int, steps: int, repeats: int = 3) -> dict:
     """A chain of ``steps`` dependent [n, n] bf16 matmuls, timed on the host
     clock around ``block_until_ready``. Returns the FLOPs and the fastest

@@ -67,14 +67,14 @@ def with_window(u: jnp.ndarray, window: jnp.ndarray) -> jnp.ndarray:
 def causal_conv(
     padded: jnp.ndarray,  # [b, K-1 + L, d] from ``with_window``
     w: jnp.ndarray,  # [K, d] taps, w[K-1] multiplies the current input
-    bias: jnp.ndarray,  # [d]
+    bias: jnp.ndarray | None,  # [d]; None = no bias
 ) -> jnp.ndarray:
     """Depthwise causal convolution over time, [b, L, d] float32."""
     k = w.shape[0]
     length = padded.shape[1] - (k - 1)
     padded = padded.astype(jnp.float32)
     wf = w.astype(jnp.float32)
-    out = bias.astype(jnp.float32)
+    out = 0.0 if bias is None else bias.astype(jnp.float32)
     for j in range(k):
         out = out + wf[j] * jax.lax.dynamic_slice_in_dim(padded, j, length, 1)
     return out

@@ -405,6 +405,8 @@ class _PagedBackend:
         # Lanes whose recurrent state a prefill or a join wrote, cumulative
         # (``GET /stats`` engine.state.lane_writes).
         self.state_lane_writes = 0
+        self.state_decode_dispatches = 0
+        self.state_decode_rows = 0
         self._state_lanes = 0
         self._note_cache()
 
@@ -529,11 +531,18 @@ class _PagedBackend:
         from cake_tpu.models.llama.config import STATE
 
         per_lane = self.config.state_bytes_per_lane
+        layers = len(self.config.layers_of(STATE))
         return {
-            "layers": len(self.config.layers_of(STATE)),
+            "layers": layers,
+            # which mixer the state layers run (``config.state_mixer``)
+            "mixer": self.config.state_mixer if layers else None,
             "bytes_per_lane": per_lane,
             "bytes": per_lane * self._state_lanes,
             "lane_writes": self.state_lane_writes,
+            # decode chunks enqueued and the rows they stepped together
+            # (every row of a dispatch is stepped, live or not): cumulative
+            "decode_dispatches": self.state_decode_dispatches,
+            "decode_rows": self.state_decode_rows,
         }
 
     def _epoch_groups(self, tokens, pads, ends):
@@ -589,6 +598,7 @@ class _PagedBackend:
         self.set_epoch_capacity(None)
         self.allocator.reset(batch=1)
         self.state_lane_writes = 0
+        self.state_decode_dispatches = self.state_decode_rows = 0
         return {
             "programs": len(programs),
             "seconds": round(time.perf_counter() - t0, 3),
@@ -832,6 +842,8 @@ class PagedHybridBackend(_PagedBackend):
         # same fact that drops a dead lane's K/V writes keeps its state.
         b = int(jnp.shape(tok)[0])
         valid = (self.allocator.block_tables[:b] >= 0).any(axis=1)
+        self.state_decode_dispatches += 1
+        self.state_decode_rows += b
         return fn(
             self.params, kv, tok, jnp.int32(slot), pads, self._tables(),
             jnp.asarray(valid), keys, ring, ring_idx,

@@ -26,7 +26,9 @@ from __future__ import annotations
 import dataclasses
 
 from cake_tpu.models.llama.batch import prompt_bucket
-from cake_tpu.models.llama.config import CACHE_KV, CACHE_KV_STATE, CACHE_LATENT
+from cake_tpu.models.llama.config import (
+    CACHE_KV, CACHE_KV_STATE, CACHE_LATENT, GATED_DELTA,
+)
 
 # The closed tables as shares of a lane's table: widths in 64ths of its
 # slots, capacities in quarters of its pages.
@@ -40,7 +42,17 @@ _CAPACITY_QUARTERS = (1, 2, 4)
 # each and the grouped experts' rows [tokens * top_k, hidden]; a joiner's
 # window is one row of up to a lane's table, and an epoch's groups are held
 # to the same (PERF.md section 4 has the bytes, compiled for a described v5e).
+# A delta-rule mixer's chunkwise form holds q, k, v, its two pseudo-value
+# products and o in float32 a head ([tokens, H, 128 or 256] each) beside the
+# chunks' triangles: 0.29 MB a token at Olmo-Hybrid's widths, 4.8 GB at 16k
+# tokens beside 11.6 GB of arguments (compiled for a described v5e), 2.4 at 8k.
 _PREFILL_TOKENS = {CACHE_KV_STATE: 16384, CACHE_LATENT: 4096}
+_PREFILL_TOKENS_BY_MIXER = {GATED_DELTA: 8192}
+
+
+def _prefill_tokens(config) -> int:
+    # only a kv+state configuration names another mixer than the default
+    return _PREFILL_TOKENS_BY_MIXER.get(config.state_mixer, _PREFILL_TOKENS[config.cache_kind])
 
 
 def _ceil_to(x: int, multiple: int) -> int:
@@ -69,8 +81,10 @@ class ProgramShapes:
         (``config.cache_kind``), the open one otherwise (a dense backend has
         no table to pass). At 32 pages of 128
         the sets are 64, 128, 256, 512, 768, 1024, 1536, 2048, 2560, 3072,
-        4096 slots and 8, 16, 32 pages (``jamba2-3b-chat-closed``'s); at any
-        other geometry they are as closed."""
+        4096 slots and 8, 16, 32 pages (``jamba2-3b-chat-closed``'s and
+        ``olmo-hybrid-7b-chat-closed``'s, and at 64 lanes
+        ``pangu-ultra-ep16-chat-closed``'s); at any other geometry they are
+        as closed."""
         if config.cache_kind == CACHE_KV:
             return cls()
         slots = page_size * pages_per_seq
@@ -81,7 +95,7 @@ class ProgramShapes:
         return cls(
             widths=tuple(sorted(widths)),
             capacities=tuple(p * page_size for p in sorted(pages)),
-            prefill_tokens=_PREFILL_TOKENS[config.cache_kind],
+            prefill_tokens=_prefill_tokens(config),
         )
 
     def lanes(self, n_seed: int, max_batch: int) -> int:

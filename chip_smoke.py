@@ -31,6 +31,12 @@ every phase runs in a child that has exited before the next one starts.
            the paged kernels at one KV head under a group of 20, the
            state-space mixer (ops/ssm.py) against the stepwise scan, and its
            decode chunk and join compiled: no copy of the pool or the state
+  O        a model whose state layers run the gated delta rule at
+           olmo-hybrid-7b-chat-closed's geometry: the paged kernels at 30 KV
+           heads, the mixer (ops/delta_rule.py: the chunkwise form, the
+           one-token kernel) against the rule one position at a time, both
+           alone on the clock, and its decode chunk and join compiled: no
+           copy of the pool or the float32 state
   L        a model with latent attention and a share of its experts at
            pangu-ultra-ep16-chat-closed's geometry: the absorbed decode
            kernel over the latent pool against its XLA twin and alone on the
@@ -64,7 +70,7 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, ".chip_smoke")  # listed in .gitignore
-PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "L", "D")
+PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "O", "L", "D")
 
 MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
     model_type="mistral", hidden_size=4096, intermediate_size=14336,
@@ -85,6 +91,7 @@ def _benchmark_model(name: str) -> dict:
 
 JAMBA2_3B = _benchmark_model("ai21-jamba2-3b")
 PANGU_EP16 = _benchmark_model("openpangu-ultra-moe-718b-ep16")
+OLMO_HYBRID_D16 = _benchmark_model("olmo-hybrid-7b-d16")
 PRESETS = {
     # Prompt lengths are in characters: without a tokenizer file the byte
     # tokenizer serves, one token a byte.
@@ -99,6 +106,11 @@ PRESETS = {
         # jamba2-3b-chat-closed (bench/configs/ai21-jamba2-3b.json)
         hybrid=dict(
             model=dict(JAMBA2_3B), prompt=300, pages=1024, lanes=32,
+            table_pages=8, steps=8, join_width=512,
+        ),
+        # olmo-hybrid-7b-chat-closed (bench/configs/olmo-hybrid-7b-d16.json)
+        olmo=dict(
+            model=dict(OLMO_HYBRID_D16), prompt=300, pages=320, lanes=32,
             table_pages=8, steps=8, join_width=512,
         ),
         # pangu-ultra-ep16-chat-closed
@@ -137,6 +149,22 @@ PRESETS = {
             # the model's d_state 4 is no sublane tile: the twin's by shape
             timed_scan=dict(d_state=8, windows=((1, 64), (2, 48)), calls=2,
                             repeats=1),
+        ),
+        olmo=dict(
+            # 3 heads of 128 values: the state tiles (the step is the
+            # kernel's, interpreted here), dk != dv, H no power of two
+            model=dict(
+                OLMO_HYBRID_D16, hidden_size=64, intermediate_size=128,
+                num_attention_heads=4, num_key_value_heads=4, vocab_size=512,
+                num_hidden_layers=4,
+                layer_types=["linear_attention"] * 3 + ["full_attention"],
+                linear_num_key_heads=3, linear_num_value_heads=3,
+                linear_key_head_dim=8, linear_value_head_dim=128,
+            ),
+            prompt=70, pages=64, lanes=4, table_pages=2, steps=4,
+            join_width=64,
+            timed_delta=dict(windows=((1, 70), (2, 48)), lanes=4, calls=2,
+                             repeats=1),
         ),
         latent=dict(
             model=dict(
@@ -431,6 +459,67 @@ def child_hybrid(preset: dict) -> None:
         emit({"kind": "program", "program": name, **report})
 
 
+def child_olmo(preset: dict) -> None:
+    """A model whose state layers run the gated delta rule, at the benchmark
+    cell's geometry: the paged kernels at its head layout (30 KV heads, no
+    grouping), the mixer against the stepwise rule, the rule alone on the
+    clock, and its decode chunk and join compiled for the device this process
+    holds, from shapes alone."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import pool_audit
+    from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.ops.pallas.check import (
+        DeltaGeometry,
+        run_delta_checks,
+        timed_delta_rule,
+        timed_paged_decode,
+    )
+    from cake_tpu.utils.device import describe_devices, setup_compile_cache
+
+    setup_compile_cache()
+    device = describe_devices()
+    g = preset["olmo"]
+    config = dataclasses.replace(
+        LlamaConfig.from_hf_dict(g["model"]), attention_impl="pallas"
+    )
+    heads, dk, dv = (config.linear_num_value_heads, config.linear_key_head_dim,
+                     config.linear_value_head_dim)
+    out = run_delta_checks(DeltaGeometry(
+        hidden=config.hidden_size, n_q=config.num_attention_heads,
+        n_kv=config.num_key_value_heads, head_dim=config.head_dim,
+        heads=heads, dk=dk, dv=dv, taps=config.linear_conv_kernel_dim,
+        page_size=preset["page_size"], max_seq=preset["max_seq_len"],
+        chunk=preset["chunk"], prompt=g["prompt"], dtype=preset["dtype"],
+        batches=tuple(preset["batches"]),
+    ))
+    for rec in out["results"]:
+        emit({"kind": "case", **rec})
+    emit({"kind": "summary", **device,
+          "interpret": sorted(set(out["interpret"])),
+          "pallas_calls": len(out["interpret"])})
+    emit({"kind": "timed", "rows": timed_paged_decode(
+        config.num_attention_heads, config.num_key_value_heads,
+        config.head_dim, preset["page_size"], g["lanes"],
+        len(config.layers_of("attention")), preset["dtype"],
+        **preset.get("timed_decode", {}),
+    )})
+    emit({"kind": "delta", "rows": timed_delta_rule(
+        heads, dk, dv, **g.get("timed_delta", {}))})
+    reports = pool_audit.audit_hybrid_programs(
+        config, n_pages=g["pages"], page_size=preset["page_size"],
+        lanes=g["lanes"], table_pages=g["table_pages"], n_steps=g["steps"],
+        join_width=g["join_width"],
+        dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]],
+        allow_pallas=jax.default_backend() != "cpu",
+    )
+    for name, report in reports.items():
+        emit({"kind": "program", "program": name, **report})
+
+
 def child_latent(preset: dict) -> None:
     """A model with latent attention and a share of its experts at the
     benchmark cell's geometry: the absorbed decode kernel against its XLA
@@ -491,7 +580,8 @@ def child_latent(preset: dict) -> None:
 
 CHILDREN = {"probe": child_probe, "setup": child_setup,
             "kernels": child_kernels, "pool": child_pool,
-            "hybrid": child_hybrid, "latent": child_latent}
+            "hybrid": child_hybrid, "olmo": child_olmo,
+            "latent": child_latent}
 
 
 # ------------------------------------------------------------------- traffic
@@ -922,18 +1012,7 @@ def phase_hybrid(args, preset) -> dict:
     (jamba2-3b-chat-closed): kernel cases, the prefill scan's kernel beside
     its twin, then the compiled programs."""
     records = run_child("hybrid", args, timeout=1200)
-    summary = next(r for r in records if r["kind"] == "summary")
-    problems = []
-    for c in (r for r in records if r["kind"] == "case"):
-        say(f"phase=H kernel={c['kernel']} {c['case']}: "
-            + (f"max_err={c['max_err']:.3g} of tol {c['tol']:.3g} "
-               f"first_call_s={c['first_call_s']}" if "max_err" in c
-               else f"FAILED {c.get('error')}"))
-        if not c["ok"]:
-            problems.append(f"{c['kernel']} {c['case']}")
-    if summary["interpret"] != [args.rehearse_cpu]:
-        problems.append(
-            f"pallas_call was traced with interpret={summary['interpret']}")
+    problems = _say_cases("H", records, args)
     _say_timed_decode("H", records, args)
     for r in next(r for r in records if r["kind"] == "scan")["rows"]:
         # a time is a device's: the rehearsal prints the errors alone
@@ -947,17 +1026,48 @@ def phase_hybrid(args, preset) -> dict:
                 f"selective_scan {r['rows']} x {r['length']} differs from "
                 "its twin")
     out = {"cases": sum(r["kind"] == "case" for r in records)}
+    out.update(_say_programs("H", records, args, problems))
+    if problems:
+        raise PhaseFailed("; ".join(problems))
+    return out
+
+
+def _say_cases(phase: str, records: list, args) -> list:
+    """A hybrid child's kernel cases, one line each; the problems found."""
+    summary = next(r for r in records if r["kind"] == "summary")
+    problems = []
+    for c in (r for r in records if r["kind"] == "case"):
+        say(f"phase={phase} kernel={c['kernel']} {c['case']}: "
+            + (f"max_err={c['max_err']:.3g} of tol {c['tol']:.3g} "
+               f"first_call_s={c['first_call_s']}" if "max_err" in c
+               else f"FAILED {c.get('error')}"))
+        if not c["ok"]:
+            problems.append(f"{c['kernel']} {c['case']}")
+    if summary["interpret"] != [args.rehearse_cpu]:
+        problems.append(
+            f"pallas_call was traced with interpret={summary['interpret']}")
+    return problems
+
+
+def _say_programs(phase: str, records: list, args, problems: list,
+                  counts=lambda op: True) -> dict:
+    """A hybrid child's compiled programs (``pool_audit.audit_hybrid_
+    programs``): what moves the pool or the state is a problem; a state copy
+    that ``counts`` does not take is said beside it (``window_copies``)."""
+    out = {}
     for r in (r for r in records if r["kind"] == "program"):
+        copies = [op for op in r["state_copies"] if counts(op)]
         # What the CPU's compiler copies says nothing of the chip's layouts:
         # the rehearsal holds the programs to their jaxprs alone.
-        compiled = [] if args.rehearse_cpu else r["pool_ops"] + r["state_copies"]
+        compiled = [] if args.rehearse_cpu else r["pool_ops"] + copies
         moved = r["scans"] + r["state_scans"] + compiled
-        say(f"phase=H program={r['program']} temp_bytes={r['temp_bytes']} "
+        say(f"phase={phase} program={r['program']} temp_bytes={r['temp_bytes']} "
             f"pool_bytes={r['pool_bytes']} state_bytes={r['state_bytes']} "
             f"kernels={r['kernels']} moving_ops={len(moved)} "
+            f"window_copies={len(r['state_copies']) - len(copies)} "
             f"compile_s={r['seconds']}")
         for m in moved:
-            say(f"phase=H   {r['program']} moves the pool or the state: {m}")
+            say(f"phase={phase}   {r['program']} moves the pool or the state: {m}")
         if moved:
             problems.append(f"{r['program']}: {len(moved)} op(s) move the "
                             "pool or the state")
@@ -966,6 +1076,33 @@ def phase_hybrid(args, preset) -> dict:
             problems.append(f"{r['program']}: {r['temp_bytes']} B of "
                             f"temporaries, the state is {r['state_bytes']} B")
         out[f"{r['program']}_temp_bytes"] = r["temp_bytes"]
+    return out
+
+
+def phase_olmo(args, preset) -> dict:
+    """Phase O: a model whose state layers run the gated delta rule at the
+    benchmark cell's geometry (olmo-hybrid-7b-chat-closed): kernel cases, the
+    rule's two forms alone on the clock, then the compiled programs."""
+    records = run_child("olmo", args, timeout=1800)
+    problems = _say_cases("O", records, args)
+    _say_timed_decode("O", records, args)
+    for r in next(r for r in records if r["kind"] == "delta")["rows"]:
+        forms = [k[:-3] for k in r if k.endswith("_us")]
+        # a time is a device's: the rehearsal prints the errors alone
+        times = "" if args.rehearse_cpu else ": " + ", ".join(
+            f"{f} {r[f + '_us']} us a call" for f in forms)
+        errs = " ".join(f"{k}={r[k]:.3g}" for k in ("err_o", "err_s") if k in r)
+        say(f"phase=O {r['op']} alone, {r['rows']} x {r['length']} {errs}{times}")
+        # float32 both sides; the chunkwise form's algebra sums in another order
+        if max(r.get("err_o", 0.0), r.get("err_s", 0.0)) > 1e-4:
+            problems.append(
+                f"{r['op']} {r['rows']} x {r['length']} differs from the "
+                "stepwise rule")
+    out = {"cases": sum(r["kind"] == "case" for r in records)}
+    # The convolution's window (bf16, 26 MB) changes layout at a decode
+    # program's two ends; the float32 state must not be copied at all.
+    out.update(_say_programs("O", records, args, problems,
+                             counts=lambda op: "f32[" in op))
     if problems:
         raise PhaseFailed("; ".join(problems))
     return out
@@ -1104,6 +1241,7 @@ def main() -> int:
         "C": lambda: phase_kernels(args, preset),
         "P": lambda: phase_pool(args, preset),
         "H": lambda: phase_hybrid(args, preset),
+        "O": lambda: phase_olmo(args, preset),
         "L": lambda: phase_latent(args, preset),
         "D": lambda: phase_four_chips(args, preset),
     }

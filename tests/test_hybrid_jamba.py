@@ -534,7 +534,13 @@ def test_the_other_families_parse_as_they_did(family):
         "mamba_d_conv": 4, "mamba_expand": 2, "mamba_dt_rank": 0, "use_rope": True,
         "q_lora_rank": 0, "kv_lora_rank": 0, "qk_nope_head_dim": 0, "qk_rope_head_dim": 0,
         "v_head_dim": 0, "first_k_dense_replace": 0, "router_experts": 0, "expert_offset": 0,
-        "moe_scoring": "softmax", "routed_scaling_factor": 1.0}
+        "moe_scoring": "softmax", "routed_scaling_factor": 1.0,
+        # PR 34: no list of layer kinds, Mamba's mixer where a layer keeps a
+        # state (none does), norms on a branch's input, q/k norms a head
+        "layer_types": None, "state_mixer": "mamba", "linear_num_key_heads": 0,
+        "linear_num_value_heads": 0, "linear_key_head_dim": 0, "linear_value_head_dim": 0,
+        "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": False,
+        "pre_block_norms": True, "qk_norm_whole": False}
     assert set(config.layer_kinds) == {"attention"} and not config.has_state_layers
     assert config.cache_kind == "kv" and len(set(config.ff_kinds)) == 1
     assert config.n_router_experts == config.num_local_experts
@@ -542,7 +548,7 @@ def test_the_other_families_parse_as_they_did(family):
 
 
 def test_unsupported_message_is_built_from_the_tuple():
-    assert sorted([*GOLDEN, "jamba", "pangu_ultra_moe"]) == sorted(SUPPORTED_MODEL_TYPES)
+    assert sorted([*GOLDEN, "jamba", "pangu_ultra_moe", "olmo_hybrid"]) == sorted(SUPPORTED_MODEL_TYPES)
     with pytest.raises(ValueError) as e:
         LlamaConfig.from_hf_dict({"model_type": "mamba2"})
     assert f"(supported: {', '.join(SUPPORTED_MODEL_TYPES)})" in str(e.value)

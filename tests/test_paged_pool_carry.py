@@ -479,6 +479,53 @@ def test_hybrid_audit_names_a_state_that_is_scanned():
     assert len(found) == 2 and "scanned input" in found[0]
 
 
+# ---- a model whose state layers run the gated delta rule, at its cell's
+# geometry, for a v5e
+#
+# olmo-hybrid-7b-chat-closed (bench/configs/olmo-hybrid-7b-d16.json): the
+# page pool of the 4 full-attention layers (30 KV heads, no grouping: the
+# heaviest K and V a layer in the benchmark) and the lane state of the 12
+# delta-rule layers ride the scans' carries. The same compile shows that
+# Mosaic takes the paged kernels at 30 KV heads and the one-token update's
+# kernel (ops/pallas/delta_step.py) at [96, 5760] a row, handed the layer
+# stack's state whole.
+
+
+@pytest.fixture(scope="module")
+def delta_reports(one_chip):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench/configs/olmo-hybrid-7b-d16.json")) as f:
+        config = dataclasses.replace(
+            LlamaConfig.from_hf_dict(json.load(f)), attention_impl="pallas"
+        )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        with jax.default_matmul_precision("default"):
+            return pool_audit.audit_hybrid_programs(
+                config, n_pages=320, page_size=128, lanes=32, table_pages=8,
+                n_steps=8, join_width=512, sharding=one_chip,
+            )
+
+
+@pytest.mark.parametrize("program", ["decode", "join"])
+def test_delta_rule_cell_compiles_for_v5e_without_pool_or_state_copies(
+    program, delta_reports
+):
+    report = delta_reports[program]
+    assert report["scans"] == [] and report["pool_ops"] == [], report
+    assert report["state_scans"] == [], report
+    # The float32 state (876 MB) is never copied. The convolution's window
+    # (bf16, 26.5 MB) changes layout once at each end of a decode program:
+    # two copies of it a chunk of 8 steps, 0.1 ms of 100.
+    assert not [op for op in report["state_copies"] if "f32[" in op], report
+    assert len(report["state_copies"]) <= 2, report
+    # decode: an attention kernel and a step kernel in each of the four
+    # periods' scans; a join: the four chunk kernels (its rule is plain XLA)
+    assert report["kernels"] == (8 if program == "decode" else 4), report
+    assert report["state_bytes"] == 32 * 27_371_520
+    assert report["temp_bytes"] < report["state_bytes"] // 2, report
+
+
 # -------- a model with latent attention, at its cell's geometry, for a v5e
 #
 # pangu-ultra-ep16-chat-closed (bench/configs/openpangu-ultra-moe-718b-ep16

@@ -17,8 +17,9 @@ import pytest
 from cake_tpu.runtime.shapes import ProgramShapes
 
 ATTENTION = types.SimpleNamespace(cache_kind="kv")
-STATE = types.SimpleNamespace(cache_kind="kv+state")
-LATENT = types.SimpleNamespace(cache_kind="latent")
+STATE = types.SimpleNamespace(cache_kind="kv+state", state_mixer="mamba")
+DELTA = types.SimpleNamespace(cache_kind="kv+state", state_mixer="gated_delta")
+LATENT = types.SimpleNamespace(cache_kind="latent", state_mixer="mamba")
 # (page size, pages of a lane's table, --max-seq-len, --api-batch)
 MISTRAL = (128, 32, 4096, 8)
 JAMBA = (128, 32, 4096, 32)
@@ -195,3 +196,17 @@ def test_closed_prefill_groups_hold_16k_tokens(rows, width, want):
     s = ProgramShapes.for_model(STATE, 128, 32)
     assert s.prefill_group(rows, width) == want
     assert want == 1 or want * width <= 16384
+
+
+@pytest.mark.parametrize("rows,width,want", [
+    (32, 256, 32), (32, 257, 16), (32, 512, 16), (32, 2048, 4), (32, 4096, 2),
+    (2, 4096, 2), (1, 4096, 1), (32, 8193, 1),
+])
+def test_a_delta_rule_mixers_groups_hold_8k_tokens(rows, width, want):
+    """The same closed sets as Mamba's, half the tokens a prefill program:
+    the chunkwise form's float32 intermediates are a head's."""
+    s = ProgramShapes.for_model(DELTA, 128, 32)
+    assert s.prefill_group(rows, width) == want
+    assert want == 1 or want * width <= 8192
+    mamba = ProgramShapes.for_model(STATE, 128, 32)
+    assert (s.widths, s.capacities) == (mamba.widths, mamba.capacities)
