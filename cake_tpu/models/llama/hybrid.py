@@ -165,6 +165,29 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> M.Pa
 # ----------------------------------------------------------------- forward
 
 
+def _kernel_switch(config: LlamaConfig, allow_pallas: bool) -> bool:
+    """The state layers' kernels follow the attention kernels' switch."""
+    return allow_pallas and M.resolve_attention_impl(config.attention_impl) == "pallas"
+
+
+def window_form(config: LlamaConfig, allow_pallas: bool) -> str | None:
+    """Which form a window (``L > 1``: every prefill and join) of the state
+    layers' recurrence takes, decided once from the config's widths and the
+    kernel switch: ``"pallas"`` (``ops/pallas/delta_rule.py`` or
+    ``selective_scan.py``) or ``"xla"`` (the twin); None without state
+    layers. ``GET /stats`` engine.state.window_form."""
+    if not config.layers_of(STATE):
+        return None
+    switch = _kernel_switch(config, allow_pallas)
+    if config.state_mixer == GATED_DELTA:
+        kernel = D.window_in_kernel(
+            config.state_shape, config.linear_num_value_heads, switch)
+    else:
+        n, d = config.state_shape
+        kernel = switch and S.pallas_scan.tiles(d, n)
+    return "pallas" if kernel else "xla"
+
+
 def hybrid_blocks_forward(
     runs: list,
     x: jnp.ndarray,
@@ -193,16 +216,14 @@ def hybrid_blocks_forward(
     from cake_tpu.models.llama.batch import batched_blocks_forward
 
     fusion = resolve_fusion(config, allow_pallas)
-    # the prefill scan's kernel follows the attention kernels' switch
-    use_pallas = (
-        allow_pallas and M.resolve_attention_impl(config.attention_impl) == "pallas"
-    )
+    use_pallas = _kernel_switch(config, allow_pallas)
     kv, ssm, conv = cache
     eps = config.rms_norm_eps
     rows = x.shape[0]
     if config.state_mixer == GATED_DELTA:
         mixer = functools.partial(
-            D.mixer_forward, eps=eps, neg_eigval=config.linear_allow_neg_eigval
+            D.mixer_forward, eps=eps, neg_eigval=config.linear_allow_neg_eigval,
+            allow_pallas=use_pallas,
         )
     else:
         mixer = functools.partial(S.mixer_forward, eps=eps, allow_pallas=use_pallas)

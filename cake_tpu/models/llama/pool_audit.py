@@ -222,6 +222,9 @@ def audit_hybrid_programs(
             "state_scans": state_scans,
             "state_copies": state_copies,
             "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
+            # the compiled program's own size: what 25 of them at start-up
+            # must fit the compile cache with (PERF.md section 7, row 17)
+            "code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
             "pool_bytes": math.prod(kv_shape) * jnp.dtype(dtype).itemsize,
             "state_bytes": sum(
                 math.prod(shape) * jnp.dtype(dt).itemsize

@@ -40,12 +40,19 @@ else (no flag, field or environment variable chooses), as ``ops/ssm.py``:
     kernel ``ops/pallas/delta_step.py``, one read and one write of ``S``,
     the operation ``gated_delta_step`` of a device trace);
     else the XLA form below.
-  * ``L > 1``: the chunkwise (WY / UT transform) form, ``gated_delta_rule``,
-    plain XLA under the scope ``gated_delta_rule``: within a chunk of
-    ``CHUNK`` = 64 positions every product is a matmul and the dependence
-    inside the chunk is one unit-triangular solve; across chunks ``S`` is
-    carried with the chunk's cumulative decay. No position is walked alone
-    over a state in HBM.
+  * ``L > 1``: the chunkwise (WY / UT transform) form under the scope
+    ``gated_delta_rule``: within a chunk of ``CHUNK`` = 64 positions every
+    product is a matmul and the dependence inside the chunk is one
+    unit-triangular solve; across chunks ``S`` is carried with the chunk's
+    cumulative decay. No position is walked alone over a state in HBM.
+    With ``allow_pallas`` and widths that tile it is the Pallas kernel
+    ``ops/pallas/delta_rule.py`` (the operation ``gated_delta_rule`` of a
+    device trace; the scope also holds the XLA that lays q, k and the gates
+    out for it): ``S`` in VMEM over the whole window in the cache's own
+    layout, a chunk's triangles made where they are used, only the chunks
+    between a row's first and last live position walked. Else its XLA twin
+    and oracle below, ``gated_delta_rule``, which builds every chunk's
+    terms at once in HBM (0.29 MB a token) and then walks the chunks.
 
 Float32 throughout the recurrence, products at ``Precision.HIGH`` (on the
 TPU a float32 product at the default precision is one bfloat16 pass, which
@@ -61,6 +68,7 @@ import jax.numpy as jnp
 
 from cake_tpu.ops import ssm as S
 from cake_tpu.ops.norm import rms_norm
+from cake_tpu.ops.pallas import delta_rule as pallas_rule
 from cake_tpu.ops.pallas import delta_step as pallas_step
 from cake_tpu.ops.quant import qmat
 
@@ -221,6 +229,7 @@ def mixer_forward(
     eps: float,
     neg_eigval: bool = True,
     chunk: int = CHUNK,
+    allow_pallas: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One gated-delta-rule mixer over a window continuing from (``ssm``,
     ``conv``): (rms(o) * silu(z) [b, L, H dv] (the caller applies the
@@ -229,17 +238,43 @@ def mixer_forward(
     q, k, v, log_alpha, beta, z, new_conv = _inputs(
         lp, h, conv, live, ends, neg_eigval
     )
-    s0 = to_heads(ssm, q.shape[2])
+    heads = q.shape[2]
     if h.shape[1] == 1:
         with jax.named_scope("gated_delta_step"):
             o, s = gated_delta_step(
-                q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0], s0
+                q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0],
+                to_heads(ssm, heads),
             )
-        o = o[:, None]
+        o, s = o[:, None], from_heads(s)
+    elif window_in_kernel(ssm.shape[-2:], heads, allow_pallas):
+        with jax.named_scope("gated_delta_rule"):
+            # The chunks before a row's first live position and after its
+            # last are not walked (a join's left pads, an epoch's dead tail).
+            length = live.shape[1]
+            lo = jnp.argmax(live, axis=1)
+            hi = length - jnp.argmax(live[:, ::-1], axis=1)
+            hi = jnp.where(jnp.any(live, axis=1), hi, lo)
+            o, s = pallas_rule.gated_delta_rule(
+                q, k, v, log_alpha, beta, ssm,
+                jnp.stack([lo, hi], axis=1).astype(jnp.int32),
+            )
     else:
         with jax.named_scope("gated_delta_rule"):
-            o, s = gated_delta_rule(q, k, v, log_alpha, beta, s0, chunk)
-    return _gated(lp, o, z, eps, h.dtype), from_heads(s), new_conv
+            o, s = gated_delta_rule(
+                q, k, v, log_alpha, beta, to_heads(ssm, heads), chunk
+            )
+            s = from_heads(s)
+    return _gated(lp, o, z, eps, h.dtype), s, new_conv
+
+
+def window_in_kernel(
+    state_shape: tuple[int, int], heads: int, allow_pallas: bool
+) -> bool:
+    """Whether a window (``L > 1``) over a state of [dk, H * dv] a row
+    (``config.state_shape``) is the Pallas kernel's (the switch, and widths
+    that tile) or its XLA twin's."""
+    dk, n = state_shape
+    return allow_pallas and pallas_rule.tiles(dk, n, n // heads)
 
 
 def steps_in_place(ssm: jnp.ndarray, heads: int) -> bool:

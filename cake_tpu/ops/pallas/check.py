@@ -895,7 +895,8 @@ def timed_delta_rule(
     heads: int,
     dk: int,
     dv: int,
-    windows: tuple[tuple[int, int], ...] = ((1, 512), (1, 2048), (4, 512)),
+    windows: tuple[tuple[int, ...], ...] = (
+        (1, 512), (1, 2048), (4, 512), (2, 2560, 1100, 1500)),
     lanes: int = 32,
     calls: int = 12,
     repeats: int = 3,
@@ -903,19 +904,26 @@ def timed_delta_rule(
     """The gated delta rule alone, ``calls`` dependent calls a program (a
     model's state layers: each call's state and output feed the next), timed
     on the host clock around ``block_until_ready``, the fastest of
-    ``repeats``. For each (rows, length) of ``windows``: the chunkwise form
-    (``ops/delta_rule.gated_delta_rule``) against the rule one position at a
-    time, errors over the largest value of the stepwise form's and
-    microseconds a call of both; the first eighth of a window is not live.
+    ``repeats``. For each (rows, length[, lo, hi]) of ``windows`` (live from
+    ``lo`` to ``hi``; without them the first eighth of a window is not live;
+    the last default is an epoch's program, 400 live positions a row of
+    2560): the window's kernel (``ops/pallas/delta_rule.py``, where the
+    widths tile, with the state in the cache's layout and the span as the
+    mixer hands them) and its XLA twin (``ops/delta_rule.gated_delta_rule``)
+    against the rule one position at a time, errors over the largest value
+    of the stepwise form's, and microseconds a call (the stepwise form's up
+    to 512 positions only: at 2048 it is most of the phase's minutes).
     Then the one-token update at ``lanes`` rows: the Pallas kernel on a
     stack's state in place (where the widths tile) beside its XLA twin."""
     from cake_tpu.ops import delta_rule as D
-    from cake_tpu.ops.pallas import delta_step
+    from cake_tpu.ops.pallas import delta_rule, delta_step
 
-    def draw(key, b, length):
+    def draw(key, b, length, lo=None, hi=None):
+        lo, hi = length // 8 if lo is None else lo, length if hi is None else hi
         keys = jax.random.split(key, 6)
         n = lambda k, *shape: jax.random.normal(k, shape, jnp.float32)
-        live = (jnp.arange(length) >= length // 8)[None, :, None]
+        at = jnp.arange(length)
+        live = ((at >= lo) & (at < hi))[None, :, None]
         q = D._unit(n(keys[0], b, length, heads, dk)) * dk ** -0.5
         k = D._unit(n(keys[1], b, length, heads, dk) + n(keys[1], b, 1, heads, dk))
         log_alpha = jnp.where(live, -jax.nn.softplus(n(keys[3], b, length, heads)), 0.0)
@@ -935,19 +943,45 @@ def timed_delta_rule(
 
         return run
 
-    forms = {"chunkwise": D.gated_delta_rule, "stepwise": _delta_stepwise}
-    once = {name: jax.jit(rule) for name, rule in forms.items()}
+    def once(rule, *args):  # the kernel's rule holds its window's span
+        return jax.jit(rule)(*args)
+
     rows = []
-    for b, length in windows:
-        args = draw(jax.random.PRNGKey(b * length), b, length)
-        o_c, s_c = once["chunkwise"](*args)
-        o_s, s_s = once["stepwise"](*args)
-        rec = {"op": "gated_delta_rule", "rows": b, "length": length,
-               "err_o": _rel_err(o_c, o_s), "err_s": _rel_err(s_c, s_s)}
-        for name, rule in forms.items():
+    for b, length, *live in windows:
+        q, k, v, log_alpha, beta, s0 = draw(jax.random.PRNGKey(b * length), b, length, *live)
+        lo, hi = live or (length // 8, length)
+        spans = jnp.tile(jnp.asarray([[lo, hi]], jnp.int32), (b, 1))
+        # name -> (the rule, its state as it takes it, that state by heads)
+        forms = {
+            "chunkwise": (D.gated_delta_rule, s0, lambda s: s),
+            "stepwise": (_delta_stepwise, s0, lambda s: s),
+        }
+        if delta_rule.tiles(dk, heads * dv, dv):
+            forms["kernel"] = (
+                lambda *a: delta_rule.gated_delta_rule(*a, spans),
+                D.from_heads(s0), lambda s: D.to_heads(s, heads))
+        outs = {}
+        for name, (rule, state, by_heads) in forms.items():
+            o, s = once(rule, q, k, v, log_alpha, beta, state)
+            outs[name] = (o, by_heads(s))
+        o_s, s_s = outs["stepwise"]
+        rec = {"op": "gated_delta_rule", "rows": b, "length": length, "live": hi - lo,
+               "err_o": _rel_err(outs["chunkwise"][0], o_s),
+               "err_s": _rel_err(outs["chunkwise"][1], s_s)}
+        if "kernel" in outs:
+            # o outside the span's chunks is nobody's: the kernel's is zero
+            first, last = lo // delta_rule.CHUNK * delta_rule.CHUNK, hi
+            rec["kernel_err_o"] = _rel_err(
+                outs["kernel"][0][:, first:last], o_s[:, first:last])
+            rec["kernel_err_s"] = _rel_err(outs["kernel"][1], s_s)
+        for name, (rule, state, _) in forms.items():
+            if name == "stepwise" and length > 512:
+                continue
             run = chain(rule)
-            _timed(run, *args)  # compile + warm
-            fastest = min(_timed(run, *args)[1] for _ in range(repeats))
+            _timed(run, q, k, v, log_alpha, beta, state)  # compile + warm
+            fastest = min(
+                _timed(run, q, k, v, log_alpha, beta, state)[1]
+                for _ in range(repeats))
             rec[f"{name}_us"] = round(fastest / calls * 1e6, 1)
         rows.append(rec)
 

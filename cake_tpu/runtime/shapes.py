@@ -42,10 +42,15 @@ _CAPACITY_QUARTERS = (1, 2, 4)
 # each and the grouped experts' rows [tokens * top_k, hidden]; a joiner's
 # window is one row of up to a lane's table, and an epoch's groups are held
 # to the same (PERF.md section 4 has the bytes, compiled for a described v5e).
-# A delta-rule mixer's chunkwise form holds q, k, v, its two pseudo-value
-# products and o in float32 a head ([tokens, H, 128 or 256] each) beside the
-# chunks' triangles: 0.29 MB a token at Olmo-Hybrid's widths, 4.8 GB at 16k
-# tokens beside 11.6 GB of arguments (compiled for a described v5e), 2.4 at 8k.
+# A delta-rule mixer's chunkwise form AS ITS XLA TWIN holds q, k, v, its two
+# pseudo-value products and o in float32 a head ([tokens, H, 128 or 256] each)
+# beside the chunks' triangles: 0.29 MB a token at Olmo-Hybrid's widths, 4.8
+# GB at 16k tokens beside 11.6 GB of arguments (compiled for a described
+# v5e), 2.4 at 8k. Since PR 35 a window at widths that tile is a kernel
+# (ops/pallas/delta_rule.py) whose triangles never leave VMEM: the 0.29 MB a
+# token is the twin's alone (an epoch's group of 2 x 2560 compiles to 0.89 GB
+# of temporaries for the twin's 1.41), and 8,192 stands until a PR of its own
+# re-measures it.
 _PREFILL_TOKENS = {CACHE_KV_STATE: 16384, CACHE_LATENT: 4096}
 _PREFILL_TOKENS_BY_MIXER = {GATED_DELTA: 8192}
 

@@ -112,6 +112,8 @@ PRESETS = {
         olmo=dict(
             model=dict(OLMO_HYBRID_D16), prompt=300, pages=320, lanes=32,
             table_pages=8, steps=8, join_width=512,
+            # one group of an epoch's prefill: rows, slots, table pages
+            epoch=(2, 2560, 20),
         ),
         # pangu-ultra-ep16-chat-closed
         # (bench/configs/openpangu-ultra-moe-718b-ep16.json)
@@ -162,9 +164,9 @@ PRESETS = {
                 linear_key_head_dim=8, linear_value_head_dim=128,
             ),
             prompt=70, pages=64, lanes=4, table_pages=2, steps=4,
-            join_width=64,
-            timed_delta=dict(windows=((1, 70), (2, 48)), lanes=4, calls=2,
-                             repeats=1),
+            join_width=64, epoch=(2, 128, 2),
+            timed_delta=dict(windows=((1, 70), (2, 200, 70, 130)), lanes=4,
+                             calls=2, repeats=1),
         ),
         latent=dict(
             model=dict(
@@ -466,12 +468,15 @@ def child_olmo(preset: dict) -> None:
     clock, and its decode chunk and join compiled for the device this process
     holds, from shapes alone."""
     import dataclasses
+    from unittest import mock
 
     import jax
     import jax.numpy as jnp
 
+    from cake_tpu.models.llama import hybrid as H
     from cake_tpu.models.llama import pool_audit
     from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.ops import delta_rule as D
     from cake_tpu.ops.pallas.check import (
         DeltaGeometry,
         run_delta_checks,
@@ -509,15 +514,41 @@ def child_olmo(preset: dict) -> None:
     )})
     emit({"kind": "delta", "rows": timed_delta_rule(
         heads, dk, dv, **g.get("timed_delta", {}))})
-    reports = pool_audit.audit_hybrid_programs(
-        config, n_pages=g["pages"], page_size=preset["page_size"],
-        lanes=g["lanes"], table_pages=g["table_pages"], n_steps=g["steps"],
-        join_width=g["join_width"],
-        dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]],
-        allow_pallas=jax.default_backend() != "cpu",
-    )
-    for name, report in reports.items():
-        emit({"kind": "program", "program": name, **report})
+
+    def audit(**kw):
+        return pool_audit.audit_hybrid_programs(
+            config, n_pages=g["pages"], page_size=preset["page_size"],
+            lanes=g["lanes"], n_steps=g["steps"],
+            dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]],
+            allow_pallas=jax.default_backend() != "cpu", **kw,
+        )
+
+    def join_and_epoch():
+        # a join at its width; one group of an epoch's prefill at its own
+        rows, width, pages = g["epoch"]
+        return {
+            **audit(table_pages=g["table_pages"], join_width=g["join_width"]),
+            "prefill": audit(
+                table_pages=pages, join_width=width, prefill_rows=rows
+            )["prefill"],
+        }
+
+    # The same programs with every window's rule as the XLA twin (what a
+    # window was before it had a kernel), for their sizes: the mixer asks
+    # ``window_in_kernel``, and a traced program is remembered.
+    with mock.patch.object(D, "window_in_kernel", lambda *a: False):
+        twin = join_and_epoch()
+    H._hybrid_join_fn.cache_clear()
+    jax.clear_caches()
+    reports = join_and_epoch()
+    for name in ("decode", "join"):
+        emit({"kind": "program", "program": name, **reports[name]})
+    emit({"kind": "sizes", "rows": [
+        {"program": name, "form": form, "code_bytes": r[name]["code_bytes"],
+         "temp_bytes": r[name]["temp_bytes"], "compile_s": r[name]["seconds"]}
+        for name in ("join", "prefill")
+        for form, r in (("twin", twin), ("kernel", reports))
+    ]})
 
 
 def child_latent(preset: dict) -> None:
@@ -1091,10 +1122,14 @@ def phase_olmo(args, preset) -> dict:
         # a time is a device's: the rehearsal prints the errors alone
         times = "" if args.rehearse_cpu else ": " + ", ".join(
             f"{f} {r[f + '_us']} us a call" for f in forms)
-        errs = " ".join(f"{k}={r[k]:.3g}" for k in ("err_o", "err_s") if k in r)
-        say(f"phase=O {r['op']} alone, {r['rows']} x {r['length']} {errs}{times}")
-        # float32 both sides; the chunkwise form's algebra sums in another order
-        if max(r.get("err_o", 0.0), r.get("err_s", 0.0)) > 1e-4:
+        kinds = ("err_o", "err_s", "kernel_err_o", "kernel_err_s")
+        errs = " ".join(f"{k}={r[k]:.3g}" for k in kinds if k in r)
+        live = f" ({r['live']} live)" if "live" in r else ""
+        say(f"phase=O {r['op']} alone, {r['rows']} x {r['length']}{live} "
+            f"{errs}{times}")
+        # float32 both sides; the chunkwise form's algebra sums in another
+        # order, the kernel's products are three bfloat16 passes
+        if max(r.get(k, 0.0) for k in kinds) > 1e-4:
             problems.append(
                 f"{r['op']} {r['rows']} x {r['length']} differs from the "
                 "stepwise rule")
@@ -1103,6 +1138,12 @@ def phase_olmo(args, preset) -> dict:
     # program's two ends; the float32 state must not be copied at all.
     out.update(_say_programs("O", records, args, problems,
                              counts=lambda op: "f32[" in op))
+    # a join and one group of an epoch's prefill, every window's rule as the
+    # XLA twin and as the kernel (the same where the widths do not tile)
+    for r in next(r for r in records if r["kind"] == "sizes")["rows"]:
+        say(f"phase=O program={r['program']} rule={r['form']} "
+            f"code_bytes={r['code_bytes']} temp_bytes={r['temp_bytes']} "
+            f"compile_s={r['compile_s']}")
     if problems:
         raise PhaseFailed("; ".join(problems))
     return out

@@ -486,9 +486,10 @@ def test_hybrid_audit_names_a_state_that_is_scanned():
 # page pool of the 4 full-attention layers (30 KV heads, no grouping: the
 # heaviest K and V a layer in the benchmark) and the lane state of the 12
 # delta-rule layers ride the scans' carries. The same compile shows that
-# Mosaic takes the paged kernels at 30 KV heads and the one-token update's
+# Mosaic takes the paged kernels at 30 KV heads, the one-token update's
 # kernel (ops/pallas/delta_step.py) at [96, 5760] a row, handed the layer
-# stack's state whole.
+# stack's state whole, and a window's kernel (ops/pallas/delta_rule.py) at
+# 30 heads of 96 keys and 192 values.
 
 
 @pytest.fixture(scope="module")
@@ -519,9 +520,11 @@ def test_delta_rule_cell_compiles_for_v5e_without_pool_or_state_copies(
     # two copies of it a chunk of 8 steps, 0.1 ms of 100.
     assert not [op for op in report["state_copies"] if "f32[" in op], report
     assert len(report["state_copies"]) <= 2, report
-    # decode: an attention kernel and a step kernel in each of the four
-    # periods' scans; a join: the four chunk kernels (its rule is plain XLA)
-    assert report["kernels"] == (8 if program == "decode" else 4), report
+    # each of the four periods' scans holds an attention kernel and a rule
+    # kernel: decode the one-token update's (ops/pallas/delta_step.py), a
+    # join the window's (ops/pallas/delta_rule.py, [96, 1152] of state a
+    # grid step in VMEM over the chunks)
+    assert report["kernels"] == 8, report
     assert report["state_bytes"] == 32 * 27_371_520
     assert report["temp_bytes"] < report["state_bytes"] // 2, report
 
