@@ -43,6 +43,7 @@ import numpy as np
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.cache import KVCache, init_cache, write_layer
 from cake_tpu.obs.jitwatch import tracked_jit as _tracked_jit
+from cake_tpu.obs.taxonomy import MIXER, SAMPLE
 from cake_tpu.models.llama.paged_cache import (
     PagedKVCache,
     paged_write_pool,
@@ -152,15 +153,16 @@ def _first_sample_fn(temperature, top_k, top_p, repeat_penalty, per_row):
     form dispatched (and, per shape, compiled) a dozen."""
 
     def run(logits, ring, keys):
-        penalized = apply_repeat_penalty(logits, repeat_penalty, ring)
-        if per_row:
-            pair = jax.vmap(jax.random.split)(keys)
-            key, sub = pair[:, 0], pair[:, 1]
-            first = sample_per_row(penalized, sub, temperature, top_k, top_p)
-        else:
-            key, sub = jax.random.split(keys)
-            first = sample(penalized, sub, temperature, top_k, top_p)
-        return first, key
+        with jax.named_scope(SAMPLE):
+            penalized = apply_repeat_penalty(logits, repeat_penalty, ring)
+            if per_row:
+                pair = jax.vmap(jax.random.split)(keys)
+                key, sub = pair[:, 0], pair[:, 1]
+                first = sample_per_row(penalized, sub, temperature, top_k, top_p)
+            else:
+                key, sub = jax.random.split(keys)
+                first = sample(penalized, sub, temperature, top_k, top_p)
+            return first, key
 
     return _tracked_jit(
         run,
@@ -474,51 +476,52 @@ def batched_blocks_forward(
             k_pool, v_pool, li, k, v, write_pos, block_tables,
             starts=write_starts,
         )
-        # One eligibility rule for every paged kernel (decode AND the
-        # chunk family): the page must be a whole number of lane tiles.
-        # A backend that wanted pallas but lands here surfaces a
-        # one-time `kernel-fallback` flight event host-side
-        # (runtime/batch_backend.PagedLocalBackend._kernel_note).
-        kernel_ok = use_pallas and paged_kernel_supported(kv.page_size)
-        if decode:
-            if kernel_ok:
-                attn = paged_decode_attention(
-                    q, k_pool, v_pool, lengths, block_tables, pads,
+        with jax.named_scope(MIXER):
+            # One eligibility rule for every paged kernel (decode AND the
+            # chunk family): the page must be a whole number of lane tiles.
+            # A backend that wanted pallas but lands here surfaces a
+            # one-time `kernel-fallback` flight event host-side
+            # (runtime/batch_backend.PagedLocalBackend._kernel_note).
+            kernel_ok = use_pallas and paged_kernel_supported(kv.page_size)
+            if decode:
+                if kernel_ok:
+                    attn = paged_decode_attention(
+                        q, k_pool, v_pool, lengths, block_tables, pads,
+                        lp.get("win_flag"), layer=li, **attn_kw,
+                    )
+                else:
+                    attn = paged_decode_attention_xla(
+                        q, k_pool, v_pool, q_pos, k_pos, block_tables,
+                        window_flag=lp.get("win_flag"), layer=li, **attn_kw,
+                    )
+            elif kernel_ok:
+                # Every paged prefill under pallas is one call. A cached chunk
+                # at slot ``write_pos`` — the prefix-cache suffix prefill AND
+                # the paged speculative verify — attends the LIVE POOL PREFIX
+                # (cached/earlier pages plus the chunk's own writes just
+                # scattered above); a fresh prefill reads the pool prefix its
+                # own writes just produced (q_starts = 0, so causal pruning
+                # touches exactly the live pages). The ragged page-resolving
+                # chunk kernel (ops/pallas/paged_prefill.py) streams only live
+                # pages: no [chunk, chunk] score tensor, O(live) HBM bytes.
+                attn = paged_chunk_attention(
+                    q, k_pool, v_pool, q_starts, lengths, pads, block_tables,
                     lp.get("win_flag"), layer=li, **attn_kw,
                 )
-            else:
-                attn = paged_decode_attention_xla(
+            elif cached_chunk:
+                # XLA: the gathered dense view, the multi-query form of the
+                # paged decode fallback (bit-identical arithmetic).
+                attn = paged_chunk_attention_xla(
                     q, k_pool, v_pool, q_pos, k_pos, block_tables,
                     window_flag=lp.get("win_flag"), layer=li, **attn_kw,
                 )
-        elif kernel_ok:
-            # Every paged prefill under pallas is one call. A cached chunk
-            # at slot ``write_pos`` — the prefix-cache suffix prefill AND
-            # the paged speculative verify — attends the LIVE POOL PREFIX
-            # (cached/earlier pages plus the chunk's own writes just
-            # scattered above); a fresh prefill reads the pool prefix its
-            # own writes just produced (q_starts = 0, so causal pruning
-            # touches exactly the live pages). The ragged page-resolving
-            # chunk kernel (ops/pallas/paged_prefill.py) streams only live
-            # pages: no [chunk, chunk] score tensor, O(live) HBM bytes.
-            attn = paged_chunk_attention(
-                q, k_pool, v_pool, q_starts, lengths, pads, block_tables,
-                lp.get("win_flag"), layer=li, **attn_kw,
-            )
-        elif cached_chunk:
-            # XLA: the gathered dense view, the multi-query form of the
-            # paged decode fallback (bit-identical arithmetic).
-            attn = paged_chunk_attention_xla(
-                q, k_pool, v_pool, q_pos, k_pos, block_tables,
-                window_flag=lp.get("win_flag"), layer=li, **attn_kw,
-            )
-        else:
-            # Prefill attends over the chunk it just computed — the
-            # dense fresh-chunk arithmetic, no cache read, no gather.
-            attn = gqa_attention(
-                q, k, v, q_pos, k_pos,
-                window_flag=lp.get("win_flag"), **attn_kw,
-            )
+            else:
+                # Prefill attends over the chunk it just computed — the
+                # dense fresh-chunk arithmetic, no cache read, no gather.
+                attn = gqa_attention(
+                    q, k, v, q_pos, k_pos,
+                    window_flag=lp.get("win_flag"), **attn_kw,
+                )
         x_new = M.block_finish(
             lp, x, attn, config, tp_axis=tp_axis, moe_valid=moe_valid,
             moe_dispatch=moe_dispatch, fusion=fusion,
@@ -534,43 +537,44 @@ def batched_blocks_forward(
             k_c, v_c, k, v, write_pos,
             row=0 if row_offset is None else row_offset,
         )
-        if row_offset is not None:
-            # Row-window mode: attention reads this group's rows only (the
-            # same bytes the kernels were going to stream); writes above
-            # already landed at the offset, so the full cache flows through
-            # the scan untouched outside the window.
-            k_att = jax.lax.dynamic_slice_in_dim(k_c, row_offset, b, axis=0)
-            v_att = jax.lax.dynamic_slice_in_dim(v_c, row_offset, b, axis=0)
-        else:
-            k_att, v_att = k_c, v_c
-        if use_pallas:
-            # Kernel operands in SLOT space: left-padding shifts a row's
-            # queries and keys equally, so causal/window comparisons are
-            # pad-invariant; pad key slots are excluded via starts/k_starts
-            # (mask + block pruning), dead tails via per-row lengths. Rope
-            # still uses the relative positions above.
-            if decode:
-                attn = decode_attention(
-                    q, k_att, v_att, lengths, pads, lp.get("win_flag"), **attn_kw
+        with jax.named_scope(MIXER):
+            if row_offset is not None:
+                # Row-window mode: attention reads this group's rows only (the
+                # same bytes the kernels were going to stream); writes above
+                # already landed at the offset, so the full cache flows through
+                # the scan untouched outside the window.
+                k_att = jax.lax.dynamic_slice_in_dim(k_c, row_offset, b, axis=0)
+                v_att = jax.lax.dynamic_slice_in_dim(v_c, row_offset, b, axis=0)
+            else:
+                k_att, v_att = k_c, v_c
+            if use_pallas:
+                # Kernel operands in SLOT space: left-padding shifts a row's
+                # queries and keys equally, so causal/window comparisons are
+                # pad-invariant; pad key slots are excluded via starts/k_starts
+                # (mask + block pruning), dead tails via per-row lengths. Rope
+                # still uses the relative positions above.
+                if decode:
+                    attn = decode_attention(
+                        q, k_att, v_att, lengths, pads, lp.get("win_flag"), **attn_kw
+                    )
+                else:
+                    attn = chunk_prefill_attention(
+                        q, k_att, v_att, q_starts, lengths, lp.get("win_flag"), pads,
+                        **attn_kw,
+                    )
+            elif decode or cached_chunk:
+                # XLA fallback over the cache prefix: decode's one token, or a
+                # cached chunk's width-many queries, both masked by the full-grid
+                # k_pos the caller supplied.
+                attn = gqa_attention_hm(
+                    q, k_att, v_att, q_pos, k_pos,
+                    window_flag=lp.get("win_flag"), **attn_kw,
                 )
             else:
-                attn = chunk_prefill_attention(
-                    q, k_att, v_att, q_starts, lengths, lp.get("win_flag"), pads,
-                    **attn_kw,
+                attn = gqa_attention(
+                    q, k, v, q_pos, k_pos,
+                    window_flag=lp.get("win_flag"), **attn_kw,
                 )
-        elif decode or cached_chunk:
-            # XLA fallback over the cache prefix: decode's one token, or a
-            # cached chunk's width-many queries, both masked by the full-grid
-            # k_pos the caller supplied.
-            attn = gqa_attention_hm(
-                q, k_att, v_att, q_pos, k_pos,
-                window_flag=lp.get("win_flag"), **attn_kw,
-            )
-        else:
-            attn = gqa_attention(
-                q, k, v, q_pos, k_pos,
-                window_flag=lp.get("win_flag"), **attn_kw,
-            )
         x_new = M.block_finish(
             lp, x, attn, config, tp_axis=tp_axis, moe_valid=moe_valid,
             moe_dispatch=moe_dispatch, fusion=fusion,

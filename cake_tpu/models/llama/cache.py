@@ -25,6 +25,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.obs.taxonomy import CACHE_WRITE
+
 
 class KVCache(NamedTuple):
     """Fixed-shape KV storage for a contiguous run of layers."""
@@ -83,12 +85,13 @@ def write_layer(
     cache's rows (the 1F1B interleaved pipeline's per-group decode,
     models/llama/batch.py row_offset mode).
     """
-    start = (row, 0, pos, 0)
-    k_new = jnp.moveaxis(k_new, 1, 2).astype(k_cache.dtype)
-    v_new = jnp.moveaxis(v_new, 1, 2).astype(v_cache.dtype)
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k_new, start)
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v_new, start)
-    return k_cache, v_cache
+    with jax.named_scope(CACHE_WRITE):
+        start = (row, 0, pos, 0)
+        k_new = jnp.moveaxis(k_new, 1, 2).astype(k_cache.dtype)
+        v_new = jnp.moveaxis(v_new, 1, 2).astype(v_cache.dtype)
+        k_cache = jax.lax.dynamic_update_slice(k_cache, k_new, start)
+        v_cache = jax.lax.dynamic_update_slice(v_cache, v_new, start)
+        return k_cache, v_cache
 
 
 # ------------------------------------------------------------- rolling cache
@@ -117,15 +120,16 @@ def write_layer_rolling(
     in a rolling cache a clamped garbage write would destroy live keys
     instead of landing in dead future slots like the dense layout.
     """
-    cache_len = k_cache.shape[2]
-    chunk = k_new.shape[1]
-    j = jnp.arange(chunk)
-    slots = jnp.where(j < valid_len, (pos + j) % cache_len, cache_len)
-    k_new = jnp.moveaxis(k_new, 1, 2).astype(k_cache.dtype)
-    v_new = jnp.moveaxis(v_new, 1, 2).astype(v_cache.dtype)
-    k_cache = k_cache.at[:, :, slots, :].set(k_new, mode="drop")
-    v_cache = v_cache.at[:, :, slots, :].set(v_new, mode="drop")
-    return k_cache, v_cache
+    with jax.named_scope(CACHE_WRITE):
+        cache_len = k_cache.shape[2]
+        chunk = k_new.shape[1]
+        j = jnp.arange(chunk)
+        slots = jnp.where(j < valid_len, (pos + j) % cache_len, cache_len)
+        k_new = jnp.moveaxis(k_new, 1, 2).astype(k_cache.dtype)
+        v_new = jnp.moveaxis(v_new, 1, 2).astype(v_cache.dtype)
+        k_cache = k_cache.at[:, :, slots, :].set(k_new, mode="drop")
+        v_cache = v_cache.at[:, :, slots, :].set(v_new, mode="drop")
+        return k_cache, v_cache
 
 
 ROLLING_DEAD = jnp.int32(2**30)  # sentinel: slot never written (masked out)

@@ -32,6 +32,7 @@ import numpy as np
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.cache import KVCache
 from cake_tpu.models.llama.config import LlamaConfig
+from cake_tpu.obs.taxonomy import SAMPLE
 from cake_tpu.ops.sampling import apply_repeat_penalty, sample, sample_per_row
 
 
@@ -65,54 +66,55 @@ def sample_step(
 
     Returns (next_token [b] int32, advanced key(s), ring, ring_idx).
     """
-    window = ring.shape[1]
-    if tail_impl is not None:
-        from cake_tpu.ops.pallas.fused_sample_tail import (
-            fused_sample_tail,
-            gumbel_noise,
-            sample_tail_supported,
-        )
+    with jax.named_scope(SAMPLE):
+        window = ring.shape[1]
+        if tail_impl is not None:
+            from cake_tpu.ops.pallas.fused_sample_tail import (
+                fused_sample_tail,
+                gumbel_noise,
+                sample_tail_supported,
+            )
 
-        if tail_impl == "pallas" and not sample_tail_supported(
-            logits.shape[-1], top_p
-        ):
-            # The serving-path downgrade for what the kernel cannot express
-            # (top_p's sort; an untileable vocab) — the SAME rule the
-            # backends' kernel-fallback note reads, so the flight event and
-            # the dispatch agree. The low-level entry still refuses an
-            # untiled vocab loudly for direct callers.
-            tail_impl = "xla"
-        if key.ndim == 2:
+            if tail_impl == "pallas" and not sample_tail_supported(
+                logits.shape[-1], top_p
+            ):
+                # The serving-path downgrade for what the kernel cannot express
+                # (top_p's sort; an untileable vocab) — the SAME rule the
+                # backends' kernel-fallback note reads, so the flight event and
+                # the dispatch agree. The low-level entry still refuses an
+                # untiled vocab loudly for direct callers.
+                tail_impl = "xla"
+            if key.ndim == 2:
+                pair = jax.vmap(jax.random.split)(key)  # [b, 2, 2]
+                key, sub = pair[:, 0], pair[:, 1]
+            else:
+                key, sub = jax.random.split(key)
+            noise = None
+            if not (temperature is None or temperature <= 0.0):
+                noise = gumbel_noise(sub, logits)
+            nxt = fused_sample_tail(
+                logits, ring, noise,
+                temperature=temperature, top_k=top_k, top_p=top_p,
+                repeat_penalty=repeat_penalty, impl=tail_impl,
+            )
+        elif key.ndim == 2:
+            logits = apply_repeat_penalty(logits, repeat_penalty, ring)
             pair = jax.vmap(jax.random.split)(key)  # [b, 2, 2]
             key, sub = pair[:, 0], pair[:, 1]
+            nxt = sample_per_row(logits, sub, temperature, top_k, top_p)
+            nxt = nxt.astype(jnp.int32)
         else:
+            logits = apply_repeat_penalty(logits, repeat_penalty, ring)
             key, sub = jax.random.split(key)
-        noise = None
-        if not (temperature is None or temperature <= 0.0):
-            noise = gumbel_noise(sub, logits)
-        nxt = fused_sample_tail(
-            logits, ring, noise,
-            temperature=temperature, top_k=top_k, top_p=top_p,
-            repeat_penalty=repeat_penalty, impl=tail_impl,
-        )
-    elif key.ndim == 2:
-        logits = apply_repeat_penalty(logits, repeat_penalty, ring)
-        pair = jax.vmap(jax.random.split)(key)  # [b, 2, 2]
-        key, sub = pair[:, 0], pair[:, 1]
-        nxt = sample_per_row(logits, sub, temperature, top_k, top_p)
-        nxt = nxt.astype(jnp.int32)
-    else:
-        logits = apply_repeat_penalty(logits, repeat_penalty, ring)
-        key, sub = jax.random.split(key)
-        nxt = sample(logits, sub, temperature, top_k, top_p).astype(jnp.int32)
-    if window > 0:
-        # ring_idx may be a scalar (single sequence) or [b] (per-row prompt
-        # lengths — exact penalty windows); its rank is preserved.
-        b = nxt.shape[0]
-        idx = jnp.broadcast_to(ring_idx, (b,))
-        ring = ring.at[jnp.arange(b), idx].set(nxt, mode="drop")
-        ring_idx = (ring_idx + 1) % window
-    return nxt, key, ring, ring_idx
+            nxt = sample(logits, sub, temperature, top_k, top_p).astype(jnp.int32)
+        if window > 0:
+            # ring_idx may be a scalar (single sequence) or [b] (per-row prompt
+            # lengths — exact penalty windows); its rank is preserved.
+            b = nxt.shape[0]
+            idx = jnp.broadcast_to(ring_idx, (b,))
+            ring = ring.at[jnp.arange(b), idx].set(nxt, mode="drop")
+            ring_idx = (ring_idx + 1) % window
+        return nxt, key, ring, ring_idx
 
 
 def sampled_decode_scan(

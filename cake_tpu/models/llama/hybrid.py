@@ -48,6 +48,7 @@ from cake_tpu.models.llama.config import (
 )
 from cake_tpu.models.llama.paged_cache import PagedKVCache, init_paged_cache
 from cake_tpu.obs.jitwatch import tracked_jit as _tracked_jit
+from cake_tpu.obs.taxonomy import CACHE_WRITE, MIXER, MIXER_IN
 from cake_tpu.ops import delta_rule as D
 from cake_tpu.ops import ssm as S
 from cake_tpu.ops.fuse import resolve_fusion
@@ -242,32 +243,42 @@ def hybrid_blocks_forward(
         # takes its own slice and puts it back in place.
         x, ssm, conv = carry
         lp, li = per_layer
-        c_old = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
-        h = rms_norm(x, lp["ln_attn"], eps) if "ln_attn" in lp else x
+        # The layer's own slices of the state are the mixer's inputs; what
+        # goes back into the carry is the program's cache write.
+        with jax.named_scope(MIXER_IN):
+            c_old = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
+            h = rms_norm(x, lp["ln_attn"], eps) if "ln_attn" in lp else x
         if in_place:
             gated, ssm, c_l = D.mixer_step_stacked(
                 lp, h, ssm, li, c_old, live, eps, config.linear_allow_neg_eigval
             )
         elif lane is None:
-            s_l = jax.lax.dynamic_index_in_dim(ssm, li, 0, keepdims=False)
+            with jax.named_scope(MIXER):
+                s_l = jax.lax.dynamic_index_in_dim(ssm, li, 0, keepdims=False)
             gated, s_l, c_l = mixer(lp, h, s_l, c_old, live, ends)
-            ssm = jax.lax.dynamic_update_index_in_dim(ssm, s_l, li, 0)
+            with jax.named_scope(CACHE_WRITE):
+                ssm = jax.lax.dynamic_update_index_in_dim(ssm, s_l, li, 0)
         else:
             s_l = jnp.zeros((rows, *ssm.shape[2:]), ssm.dtype)
             c_l = jnp.zeros((conv.shape[1], rows, conv.shape[3]), conv.dtype)
             gated, s_l, c_l = mixer(lp, h, s_l, c_l, live, ends)
-            zero = jnp.int32(0)
-            ssm = jax.lax.dynamic_update_slice(
-                ssm, s_l[None], (li, lane, zero, zero)
-            )
-            # The window's lane axis is a tiled one ([.., lanes, channels]):
-            # an update-slice at a lane there makes the TPU compiler re-lay
-            # the whole array out and back (two copies of it, seen compiling
-            # for a described v5e). The rows are placed in a buffer of the
-            # layer's own size, 1 MB, by a gather, and selected in.
-            placed = jnp.take(c_l, jnp.clip(lanes - lane, 0, rows - 1), axis=1)
-            c_l = jnp.where(mine[None, :, None], placed, c_old)
-        conv = jax.lax.dynamic_update_index_in_dim(conv, c_l, li, 0)
+            with jax.named_scope(CACHE_WRITE):
+                zero = jnp.int32(0)
+                ssm = jax.lax.dynamic_update_slice(
+                    ssm, s_l[None], (li, lane, zero, zero)
+                )
+                # The window's lane axis is a tiled one ([.., lanes,
+                # channels]): an update-slice at a lane there makes the TPU
+                # compiler re-lay the whole array out and back (two copies
+                # of it, seen compiling for a described v5e). The rows are
+                # placed in a buffer of the layer's own size, 1 MB, by a
+                # gather, and selected in.
+                placed = jnp.take(
+                    c_l, jnp.clip(lanes - lane, 0, rows - 1), axis=1
+                )
+                c_l = jnp.where(mine[None, :, None], placed, c_old)
+        with jax.named_scope(CACHE_WRITE):
+            conv = jax.lax.dynamic_update_index_in_dim(conv, c_l, li, 0)
         x = M.block_finish(lp, x, gated, config, fusion=fusion)
         return (x, ssm, conv), None
 

@@ -49,6 +49,7 @@ from cake_tpu.models.llama.paged_cache import (
     LatentPagedCache, init_latent_cache, latent_write_pool,
 )
 from cake_tpu.obs.jitwatch import tracked_jit as _tracked_jit
+from cake_tpu.obs.taxonomy import MIXER, MIXER_IN, MIXER_OUT
 from cake_tpu.ops.attention import mla_prefill_attention
 from cake_tpu.ops.fuse import resolve_fusion
 from cake_tpu.ops.norm import rms_norm
@@ -171,21 +172,22 @@ def mla_project(lp, x, cos, sin, positions, config: LlamaConfig):
     """A layer's input norm and the two low-rank projections: (q_nope [b, t,
     heads, nope], q_rope [b, t, heads, rope] after RoPE, latent [b, t,
     latent_width]: ``[rms(ckv) | RoPE(k_rope) | 0]``, what the pool holds)."""
-    b, t, _ = x.shape
-    eps, n = config.rms_norm_eps, config.num_attention_heads
-    nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
-    h = rms_norm(x, lp["ln_attn"], eps)
-    cq = rms_norm(qmat(h, lp["wq_a"]), lp["q_a_ln"], eps)
-    q = qmat(cq, lp["wq_b"]).reshape(b, t, n, -1)
-    q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin, positions)
-    kv = qmat(h, lp["wkv_a"])
-    ckv = rms_norm(kv[..., :rank], lp["kv_a_ln"], eps)
-    k_rope = apply_rope(kv[..., None, rank:], cos, sin, positions)[:, :, 0]
-    pad = config.latent_width - rank - config.qk_rope_head_dim
-    latent = jnp.concatenate(
-        [ckv, k_rope, jnp.zeros((b, t, pad), ckv.dtype)], axis=-1
-    )
-    return q_nope, q_rope, latent
+    with jax.named_scope(MIXER_IN):
+        b, t, _ = x.shape
+        eps, n = config.rms_norm_eps, config.num_attention_heads
+        nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
+        h = rms_norm(x, lp["ln_attn"], eps)
+        cq = rms_norm(qmat(h, lp["wq_a"]), lp["q_a_ln"], eps)
+        q = qmat(cq, lp["wq_b"]).reshape(b, t, n, -1)
+        q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin, positions)
+        kv = qmat(h, lp["wkv_a"])
+        ckv = rms_norm(kv[..., :rank], lp["kv_a_ln"], eps)
+        k_rope = apply_rope(kv[..., None, rank:], cos, sin, positions)[:, :, 0]
+        pad = config.latent_width - rank - config.qk_rope_head_dim
+        latent = jnp.concatenate(
+            [ckv, k_rope, jnp.zeros((b, t, pad), ckv.dtype)], axis=-1
+        )
+        return q_nope, q_rope, latent
 
 
 def latent_blocks_forward(
@@ -231,35 +233,42 @@ def latent_blocks_forward(
         if decode:
             # Absorbed: the keys' up-projection moves onto the query, the
             # values' onto the weighted sum of latents.
-            q_abs = jnp.einsum("bhd,hcd->bhc", q_nope[:, 0], lp["w_uk"])
-            q_full = jnp.concatenate([
-                q_abs, q_rope[:, 0],
-                jnp.zeros((b, n, config.latent_width - rank - q_rope.shape[-1]),
-                          q_abs.dtype),
-            ], axis=-1).astype(x.dtype)
+            with jax.named_scope(MIXER_IN):
+                q_abs = jnp.einsum("bhd,hcd->bhc", q_nope[:, 0], lp["w_uk"])
+                q_full = jnp.concatenate([
+                    q_abs, q_rope[:, 0],
+                    jnp.zeros(
+                        (b, n, config.latent_width - rank - q_rope.shape[-1]),
+                        q_abs.dtype,
+                    ),
+                ], axis=-1).astype(x.dtype)
             attend = (
                 latent_decode_attention if kernel_ok
                 else latent_decode_attention_xla
             )
-            # A dead lane's row is nobody's: it is given one slot to walk,
-            # not the shared slot's worth of pages.
-            c = attend(
-                q_full, pool, ends, block_tables,
-                jnp.where(live[:, 0], pads, ends - 1),
-                layer=li, rank=rank, scale=scale,
-            )
-            attn = jnp.einsum("bhc,hcd->bhd", c, lp["w_uv"])[:, None]
+            with jax.named_scope(MIXER):
+                # A dead lane's row is nobody's: it is given one slot to
+                # walk, not the shared slot's worth of pages.
+                c = attend(
+                    q_full, pool, ends, block_tables,
+                    jnp.where(live[:, 0], pads, ends - 1),
+                    layer=li, rank=rank, scale=scale,
+                )
+            with jax.named_scope(MIXER_OUT):
+                attn = jnp.einsum("bhc,hcd->bhd", c, lp["w_uv"])[:, None]
         else:
             # Expanded: the window's own K and V from its latents.
-            ckv = latent[..., :rank]
-            k_rope = latent[..., rank : rank + config.qk_rope_head_dim]
-            k_nope = jnp.einsum("btc,hcd->bthd", ckv, lp["w_uk"])
-            v = jnp.einsum("btc,hcd->bthd", ckv, lp["w_uv"])
-            attn = mla_prefill_attention(
-                q_nope, q_rope, k_nope, k_rope, v, live, scale=scale,
-                starts=pads - write_pos, lengths=ends - write_pos,
-                use_pallas=use_pallas,
-            )
+            with jax.named_scope(MIXER_IN):
+                ckv = latent[..., :rank]
+                k_rope = latent[..., rank : rank + config.qk_rope_head_dim]
+                k_nope = jnp.einsum("btc,hcd->bthd", ckv, lp["w_uk"])
+                v = jnp.einsum("btc,hcd->bthd", ckv, lp["w_uv"])
+            with jax.named_scope(MIXER):
+                attn = mla_prefill_attention(
+                    q_nope, q_rope, k_nope, k_rope, v, live, scale=scale,
+                    starts=pads - write_pos, lengths=ends - write_pos,
+                    use_pallas=use_pallas,
+                )
         return attn.astype(x.dtype), pool
 
     def layer(carry, per_layer, *, experts):

@@ -35,6 +35,9 @@ from cake_tpu.models.llama.cache import (
     write_layer_rolling,
 )
 from cake_tpu.models.llama.config import LlamaConfig
+from cake_tpu.obs.taxonomy import (
+    EMBED, FEED_FORWARD, HEAD, MIXER, MIXER_IN, MIXER_OUT,
+)
 from cake_tpu.ops.attention import gqa_attention, gqa_attention_hm
 from cake_tpu.ops.fuse import resolve_fusion
 from cake_tpu.ops.mlp import swiglu, swiglu_gu, swiglu_gu_from
@@ -175,10 +178,11 @@ def embed_tokens(
     (config.embedding_scale); the multiplier is cast to the embedding dtype
     first, matching the HF normalizer's rounding.
     """
-    x = tree["embed"][tokens]
-    if config.embedding_scale is not None:
-        x = x * jnp.asarray(config.embedding_scale, x.dtype)
-    return x
+    with jax.named_scope(EMBED):
+        x = tree["embed"][tokens]
+        if config.embedding_scale is not None:
+            x = x * jnp.asarray(config.embedding_scale, x.dtype)
+        return x
 
 
 def is_cached_prefill(pos: int, width: int) -> bool:
@@ -267,63 +271,64 @@ def block_qkv(
     with sentinel positions on pad slots (clamped table gather; the garbage
     values are mask-excluded as keys). ``cos``/``sin`` may be pre-gathered
     3-D rows (ops/rope.apply_rope) ONLY when q and k share ``positions``."""
-    b, chunk, _ = x.shape
-    hd = config.head_dim
-    n_q, n_kv = layer_head_counts(lp, config)
-    if "rope_sel" in lp and config.use_rope:
-        # Dual-rope families (Gemma-3): plane 0 = global rope, 1 = local.
-        # The SAME leading-axis select serves stacked tables [2, seq, hd/2]
-        # and stacked pre-gathered rows [2, b, s, hd/2], so both the
-        # per-layer and once-per-step gather paths stay family-agnostic.
-        cos = cos[lp["rope_sel"]]
-        sin = sin[lp["rope_sel"]]
-    assert not (
-        config.use_rope and cos.ndim == 3 and k_positions is not None
-    ), (
-        "pre-gathered rope rows cannot serve distinct k_positions"
-    )
-    if "wqkv" in lp:
-        # The "norm" fusion site (ops/pallas/fused_norm_matmul.py) lives
-        # inside block_qkv_flat; unfused layer trees (no wqkv) keep the
-        # plain path — serving backends always run fuse_params weights.
-        qkv = block_qkv_flat(lp, x, config, fusion)
-        qw, kw = n_q * hd, n_kv * hd
-        q = qkv[..., :qw]
-        k = qkv[..., qw : qw + kw]
-        v = qkv[..., qw + kw :]
-    else:
-        h = x
-        if "ln_attn" in lp:
-            h = rms_norm(x, lp["ln_attn"], config.rms_norm_eps, config.rmsnorm_offset)
-        q, k, v = qmat(h, lp["wq"]), qmat(h, lp["wk"]), qmat(h, lp["wv"])
-        if "bq" in lp:  # Qwen2-family QKV bias (config.attention_bias)
-            q = q + lp["bq"].astype(q.dtype)
-            k = k + lp["bk"].astype(k.dtype)
-            v = v + lp["bv"].astype(v.dtype)
-    if config.qk_norm_whole:
-        # OLMo-2/3: one RMSNorm over the WHOLE q (and k) projection, all
-        # heads together, before the heads are told apart.
-        q = rms_norm(q, lp["q_norm"], config.rms_norm_eps, config.rmsnorm_offset)
-        k = rms_norm(k, lp["k_norm"], config.rms_norm_eps, config.rmsnorm_offset)
-    q = q.reshape(b, chunk, n_q, hd)
-    k = k.reshape(b, chunk, n_kv, hd)
-    v = v.reshape(b, chunk, n_kv, hd)
-    if "q_norm" in lp and not config.qk_norm_whole:
-        # Qwen3 family: head_dim-wide RMSNorm on every q/k head AFTER the
-        # projection, BEFORE RoPE (HF Qwen3Attention.forward — "only on the
-        # head dim"). The weight is shared across heads, so tensor-parallel
-        # head sharding replicates it untouched.
-        q = rms_norm(q, lp["q_norm"], config.rms_norm_eps, config.rmsnorm_offset)
-        k = rms_norm(k, lp["k_norm"], config.rms_norm_eps, config.rmsnorm_offset)
-    if not config.use_rope:
-        # No positional term at all (Jamba's attention: the state layers
-        # around it carry the order); ``cos``/``sin`` may be None.
-        return q, k, v
-    return (
-        apply_rope(q, cos, sin, positions),
-        apply_rope(k, cos, sin, positions if k_positions is None else k_positions),
-        v,
-    )
+    with jax.named_scope(MIXER_IN):
+        b, chunk, _ = x.shape
+        hd = config.head_dim
+        n_q, n_kv = layer_head_counts(lp, config)
+        if "rope_sel" in lp and config.use_rope:
+            # Dual-rope families (Gemma-3): plane 0 = global rope, 1 = local.
+            # The SAME leading-axis select serves stacked tables [2, seq, hd/2]
+            # and stacked pre-gathered rows [2, b, s, hd/2], so both the
+            # per-layer and once-per-step gather paths stay family-agnostic.
+            cos = cos[lp["rope_sel"]]
+            sin = sin[lp["rope_sel"]]
+        assert not (
+            config.use_rope and cos.ndim == 3 and k_positions is not None
+        ), (
+            "pre-gathered rope rows cannot serve distinct k_positions"
+        )
+        if "wqkv" in lp:
+            # The "norm" fusion site (ops/pallas/fused_norm_matmul.py) lives
+            # inside block_qkv_flat; unfused layer trees (no wqkv) keep the
+            # plain path — serving backends always run fuse_params weights.
+            qkv = block_qkv_flat(lp, x, config, fusion)
+            qw, kw = n_q * hd, n_kv * hd
+            q = qkv[..., :qw]
+            k = qkv[..., qw : qw + kw]
+            v = qkv[..., qw + kw :]
+        else:
+            h = x
+            if "ln_attn" in lp:
+                h = rms_norm(x, lp["ln_attn"], config.rms_norm_eps, config.rmsnorm_offset)
+            q, k, v = qmat(h, lp["wq"]), qmat(h, lp["wk"]), qmat(h, lp["wv"])
+            if "bq" in lp:  # Qwen2-family QKV bias (config.attention_bias)
+                q = q + lp["bq"].astype(q.dtype)
+                k = k + lp["bk"].astype(k.dtype)
+                v = v + lp["bv"].astype(v.dtype)
+        if config.qk_norm_whole:
+            # OLMo-2/3: one RMSNorm over the WHOLE q (and k) projection, all
+            # heads together, before the heads are told apart.
+            q = rms_norm(q, lp["q_norm"], config.rms_norm_eps, config.rmsnorm_offset)
+            k = rms_norm(k, lp["k_norm"], config.rms_norm_eps, config.rmsnorm_offset)
+        q = q.reshape(b, chunk, n_q, hd)
+        k = k.reshape(b, chunk, n_kv, hd)
+        v = v.reshape(b, chunk, n_kv, hd)
+        if "q_norm" in lp and not config.qk_norm_whole:
+            # Qwen3 family: head_dim-wide RMSNorm on every q/k head AFTER the
+            # projection, BEFORE RoPE (HF Qwen3Attention.forward — "only on the
+            # head dim"). The weight is shared across heads, so tensor-parallel
+            # head sharding replicates it untouched.
+            q = rms_norm(q, lp["q_norm"], config.rms_norm_eps, config.rmsnorm_offset)
+            k = rms_norm(k, lp["k_norm"], config.rms_norm_eps, config.rmsnorm_offset)
+        if not config.use_rope:
+            # No positional term at all (Jamba's attention: the state layers
+            # around it carry the order); ``cos``/``sin`` may be None.
+            return q, k, v
+        return (
+            apply_rope(q, cos, sin, positions),
+            apply_rope(k, cos, sin, positions if k_positions is None else k_positions),
+            v,
+        )
 
 
 def block_finish(
@@ -360,72 +365,74 @@ def block_finish(
     if fusion is None:
         fusion = resolve_fusion(config)
     fusions, fimpl = fusion
-    o = qmat(attn.reshape(b, chunk, -1), lp["wo"]).astype(x.dtype)
-    if tp_axis is not None:
-        o = jax.lax.psum(o, tp_axis)
-    if "ln_post_attn" in lp:
-        # Gemma-2 post-attention norm: applied to the branch output (after
-        # the tp psum — norming a partial sum would be wrong) before the
-        # residual add.
-        o = rms_norm(o, lp["ln_post_attn"], config.rms_norm_eps, off)
-    x = x + o
-    if "norm" in fusions and "w_gu" in lp and "router" not in lp and "ln_mlp" in lp:
-        # rms_2 folded into the gate|up matmul; the epilogue is the literal
-        # swiglu_gu tail, so the branch is byte-identical to the unfused one.
-        gu = fused_norm_matmul(
-            x, lp["ln_mlp"], lp["w_gu"],
-            eps=config.rms_norm_eps, offset=off, impl=fimpl,
-        )
-        mlp = swiglu_gu_from(
-            gu, lp["w_down"], config.hidden_activation
-        ).astype(x.dtype)
+    with jax.named_scope(MIXER_OUT):
+        o = qmat(attn.reshape(b, chunk, -1), lp["wo"]).astype(x.dtype)
+        if tp_axis is not None:
+            o = jax.lax.psum(o, tp_axis)
+        if "ln_post_attn" in lp:
+            # Gemma-2 post-attention norm: applied to the branch output (after
+            # the tp psum — norming a partial sum would be wrong) before the
+            # residual add.
+            o = rms_norm(o, lp["ln_post_attn"], config.rms_norm_eps, off)
+        x = x + o
+    with jax.named_scope(FEED_FORWARD):
+        if "norm" in fusions and "w_gu" in lp and "router" not in lp and "ln_mlp" in lp:
+            # rms_2 folded into the gate|up matmul; the epilogue is the literal
+            # swiglu_gu tail, so the branch is byte-identical to the unfused one.
+            gu = fused_norm_matmul(
+                x, lp["ln_mlp"], lp["w_gu"],
+                eps=config.rms_norm_eps, offset=off, impl=fimpl,
+            )
+            mlp = swiglu_gu_from(
+                gu, lp["w_down"], config.hidden_activation
+            ).astype(x.dtype)
+            if tp_axis is not None:
+                mlp = jax.lax.psum(mlp, tp_axis)
+            if "ln_post_mlp" in lp:
+                mlp = rms_norm(mlp, lp["ln_post_mlp"], config.rms_norm_eps, off)
+            return x + mlp
+        # No ``ln_mlp`` = no norm on the feed-forward's input (OLMo's block norms
+        # its output instead: ``ln_post_mlp`` below).
+        h = rms_norm(x, lp["ln_mlp"], config.rms_norm_eps, off) if "ln_mlp" in lp else x
+        if "router" in lp:
+            mlp = moe_swiglu(
+                h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
+                config.num_experts_per_tok, tp_axis=tp_axis,
+                norm_topk=config.norm_topk_prob, valid=moe_valid,
+                dispatch=moe_dispatch, scoring=config.moe_scoring,
+                scale=config.routed_scaling_factor,
+                expert_offset=config.expert_offset, with_counts=moe_counts,
+                layer=moe_layer,
+            )
+            if moe_counts:
+                mlp, counts = mlp
+            mlp = mlp.astype(x.dtype)
+            if "sh_gu" in lp or "sh_gate" in lp:
+                # The always-on shared expert (computed identically on every tp
+                # shard and every rank of an expert-parallel deployment).
+                if "sh_gu" in lp:  # fused gate|up (ops/fuse.py)
+                    shared = swiglu_gu(h, lp["sh_gu"], lp["sh_down"])
+                else:
+                    shared = swiglu(h, lp["sh_gate"], lp["sh_up"], lp["sh_down"])
+                if "se_gate" in lp:
+                    # Qwen2-MoE scales it by a learned sigmoid gate (the product
+                    # distributes over the shared expert's partial sums).
+                    shared = shared * jax.nn.sigmoid(qmat(h, lp["se_gate"]))
+                mlp = mlp + shared.astype(x.dtype)
+        elif "w_gu" in lp:  # fused gate|up (ops/fuse.py): one matmul, split after
+            mlp = swiglu_gu(
+                h, lp["w_gu"], lp["w_down"], activation=config.hidden_activation
+            ).astype(x.dtype)
+        else:
+            mlp = swiglu(
+                h, lp["w_gate"], lp["w_up"], lp["w_down"],
+                activation=config.hidden_activation,
+            ).astype(x.dtype)
         if tp_axis is not None:
             mlp = jax.lax.psum(mlp, tp_axis)
         if "ln_post_mlp" in lp:
             mlp = rms_norm(mlp, lp["ln_post_mlp"], config.rms_norm_eps, off)
-        return x + mlp
-    # No ``ln_mlp`` = no norm on the feed-forward's input (OLMo's block norms
-    # its output instead: ``ln_post_mlp`` below).
-    h = rms_norm(x, lp["ln_mlp"], config.rms_norm_eps, off) if "ln_mlp" in lp else x
-    if "router" in lp:
-        mlp = moe_swiglu(
-            h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            config.num_experts_per_tok, tp_axis=tp_axis,
-            norm_topk=config.norm_topk_prob, valid=moe_valid,
-            dispatch=moe_dispatch, scoring=config.moe_scoring,
-            scale=config.routed_scaling_factor,
-            expert_offset=config.expert_offset, with_counts=moe_counts,
-            layer=moe_layer,
-        )
-        if moe_counts:
-            mlp, counts = mlp
-        mlp = mlp.astype(x.dtype)
-        if "sh_gu" in lp or "sh_gate" in lp:
-            # The always-on shared expert (computed identically on every tp
-            # shard and every rank of an expert-parallel deployment).
-            if "sh_gu" in lp:  # fused gate|up (ops/fuse.py)
-                shared = swiglu_gu(h, lp["sh_gu"], lp["sh_down"])
-            else:
-                shared = swiglu(h, lp["sh_gate"], lp["sh_up"], lp["sh_down"])
-            if "se_gate" in lp:
-                # Qwen2-MoE scales it by a learned sigmoid gate (the product
-                # distributes over the shared expert's partial sums).
-                shared = shared * jax.nn.sigmoid(qmat(h, lp["se_gate"]))
-            mlp = mlp + shared.astype(x.dtype)
-    elif "w_gu" in lp:  # fused gate|up (ops/fuse.py): one matmul, split after
-        mlp = swiglu_gu(
-            h, lp["w_gu"], lp["w_down"], activation=config.hidden_activation
-        ).astype(x.dtype)
-    else:
-        mlp = swiglu(
-            h, lp["w_gate"], lp["w_up"], lp["w_down"],
-            activation=config.hidden_activation,
-        ).astype(x.dtype)
-    if tp_axis is not None:
-        mlp = jax.lax.psum(mlp, tp_axis)
-    if "ln_post_mlp" in lp:
-        mlp = rms_norm(mlp, lp["ln_post_mlp"], config.rms_norm_eps, off)
-    return (x + mlp, counts) if moe_counts else x + mlp
+        return (x + mlp, counts) if moe_counts else x + mlp
 
 
 def block_forward(
@@ -492,72 +499,74 @@ def block_forward(
         assert win is not None, "rolling cache requires sliding_window"
         vl = jnp.int32(chunk) if valid_len is None else valid_len
         k_cache, v_cache = write_layer_rolling(k_cache, v_cache, k, v, pos, vl)
-        kv_pos = rolling_kv_positions(k_cache.shape[2], pos, vl)
-        kv_positions = jnp.broadcast_to(
-            kv_pos[None, :], (b, k_cache.shape[2])
-        )
-        attn = gqa_attention_hm(
-            q, k_cache, v_cache, positions, kv_positions, **attn_kw
-        )
+        with jax.named_scope(MIXER):
+            kv_pos = rolling_kv_positions(k_cache.shape[2], pos, vl)
+            kv_positions = jnp.broadcast_to(
+                kv_pos[None, :], (b, k_cache.shape[2])
+            )
+            attn = gqa_attention_hm(
+                q, k_cache, v_cache, positions, kv_positions, **attn_kw
+            )
         x = block_finish(lp, x, attn, config, tp_axis=tp_axis)
         return x, k_cache, v_cache
 
     k_cache, v_cache = write_layer(k_cache, v_cache, k, v, pos)
 
-    impl = resolve_attention_impl(config.attention_impl)
-    # Per-family attention knobs threaded into the Pallas kernels: sliding
-    # window (static, per-layer traced gate), scale override, tanh softcap.
-    pallas_kw = dict(
-        window=win,
-        window_flag=lp.get("win_flag"),
-        scale=config.attn_scale,
-        softcap=config.attn_logit_softcap,
-    )
-    if chunk > 1 and cached_prefill:
-        # Prefill CONTINUATION: a chunk at pos > 0 attends to the whole live
-        # cache prefix (which already contains this chunk's keys, written
-        # above). This is what lets long prompts prefill in bounded chunks
-        # instead of one giant compile. The Pallas kernel streams only the
-        # live, causally-needed cache blocks; the XLA fallback reads the full
-        # cache and hides dead slots behind the position mask.
-        if impl == "pallas":
-            q_starts = jnp.broadcast_to(pos, (b,)).astype(jnp.int32)
-            attn = chunk_prefill_attention(
-                q, k_cache, v_cache, q_starts, q_starts + chunk, **pallas_kw
-            )
+    with jax.named_scope(MIXER):
+        impl = resolve_attention_impl(config.attention_impl)
+        # Per-family attention knobs threaded into the Pallas kernels: sliding
+        # window (static, per-layer traced gate), scale override, tanh softcap.
+        pallas_kw = dict(
+            window=win,
+            window_flag=lp.get("win_flag"),
+            scale=config.attn_scale,
+            softcap=config.attn_logit_softcap,
+        )
+        if chunk > 1 and cached_prefill:
+            # Prefill CONTINUATION: a chunk at pos > 0 attends to the whole live
+            # cache prefix (which already contains this chunk's keys, written
+            # above). This is what lets long prompts prefill in bounded chunks
+            # instead of one giant compile. The Pallas kernel streams only the
+            # live, causally-needed cache blocks; the XLA fallback reads the full
+            # cache and hides dead slots behind the position mask.
+            if impl == "pallas":
+                q_starts = jnp.broadcast_to(pos, (b,)).astype(jnp.int32)
+                attn = chunk_prefill_attention(
+                    q, k_cache, v_cache, q_starts, q_starts + chunk, **pallas_kw
+                )
+            else:
+                kv_positions = jnp.broadcast_to(
+                    jnp.arange(k_cache.shape[2], dtype=jnp.int32)[None, :],
+                    (b, k_cache.shape[2]),
+                )
+                attn = gqa_attention_hm(
+                    q, k_cache, v_cache, positions, kv_positions, **attn_kw
+                )
+        elif chunk > 1:
+            # Prefill from offset 0 (callers pass pos=0 when cached_prefill is
+            # False): the chunk attends only within itself — avoids materializing
+            # [chunk, max_seq] score rows against an empty cache.
+            if impl == "pallas":
+                attn = flash_attention(q, k, v, **pallas_kw)
+            else:
+                attn = gqa_attention(q, k, v, positions, positions, **attn_kw)
         else:
-            kv_positions = jnp.broadcast_to(
-                jnp.arange(k_cache.shape[2], dtype=jnp.int32)[None, :],
-                (b, k_cache.shape[2]),
-            )
-            attn = gqa_attention_hm(
-                q, k_cache, v_cache, positions, kv_positions, **attn_kw
-            )
-    elif chunk > 1:
-        # Prefill from offset 0 (callers pass pos=0 when cached_prefill is
-        # False): the chunk attends only within itself — avoids materializing
-        # [chunk, max_seq] score rows against an empty cache.
-        if impl == "pallas":
-            attn = flash_attention(q, k, v, **pallas_kw)
-        else:
-            attn = gqa_attention(q, k, v, positions, positions, **attn_kw)
-    else:
-        # Decode: attend over the live cache prefix. The Pallas kernel prunes
-        # blocks past pos (and behind the window); the XLA path reads the
-        # whole cache and hides dead slots behind the position mask.
-        if impl == "pallas":
-            lengths = jnp.broadcast_to(pos + 1, (b,)).astype(jnp.int32)
-            attn = decode_attention(
-                q, k_cache, v_cache, lengths, None, **pallas_kw
-            )
-        else:
-            kv_positions = jnp.broadcast_to(
-                jnp.arange(k_cache.shape[2], dtype=jnp.int32)[None, :],
-                (b, k_cache.shape[2]),
-            )
-            attn = gqa_attention_hm(
-                q, k_cache, v_cache, positions, kv_positions, **attn_kw
-            )
+            # Decode: attend over the live cache prefix. The Pallas kernel prunes
+            # blocks past pos (and behind the window); the XLA path reads the
+            # whole cache and hides dead slots behind the position mask.
+            if impl == "pallas":
+                lengths = jnp.broadcast_to(pos + 1, (b,)).astype(jnp.int32)
+                attn = decode_attention(
+                    q, k_cache, v_cache, lengths, None, **pallas_kw
+                )
+            else:
+                kv_positions = jnp.broadcast_to(
+                    jnp.arange(k_cache.shape[2], dtype=jnp.int32)[None, :],
+                    (b, k_cache.shape[2]),
+                )
+                attn = gqa_attention_hm(
+                    q, k_cache, v_cache, positions, kv_positions, **attn_kw
+                )
 
     x = block_finish(lp, x, attn, config, tp_axis=tp_axis)
     return x, k_cache, v_cache
@@ -644,23 +653,24 @@ def head_forward(
     (ops/pallas/fused_norm_matmul.py) — tied embeddings keep the unfused
     path (the transposed weight would materialize a copy per call).
     """
-    x_last = jax.lax.dynamic_slice_in_dim(x, seq_len - 1, 1, axis=1)
-    if fusion is None:
-        fusion = resolve_fusion(config)
-    fusions, fimpl = fusion
-    if "norm" in fusions and not config.tie_word_embeddings:
-        logits = fused_norm_matmul(
-            x_last, params["ln_f"], params["lm_head"],
-            eps=config.rms_norm_eps, offset=config.rmsnorm_offset,
-            impl=fimpl,
-        )[:, 0, :].astype(jnp.float32)
+    with jax.named_scope(HEAD):
+        x_last = jax.lax.dynamic_slice_in_dim(x, seq_len - 1, 1, axis=1)
+        if fusion is None:
+            fusion = resolve_fusion(config)
+        fusions, fimpl = fusion
+        if "norm" in fusions and not config.tie_word_embeddings:
+            logits = fused_norm_matmul(
+                x_last, params["ln_f"], params["lm_head"],
+                eps=config.rms_norm_eps, offset=config.rmsnorm_offset,
+                impl=fimpl,
+            )[:, 0, :].astype(jnp.float32)
+            return _final_softcap(logits, config)
+        x_last = rms_norm(
+            x_last, params["ln_f"], config.rms_norm_eps, config.rmsnorm_offset
+        )
+        lm_head = params["embed"].T if config.tie_word_embeddings else params["lm_head"]
+        logits = qmat(x_last[:, 0, :], lm_head).astype(jnp.float32)
         return _final_softcap(logits, config)
-    x_last = rms_norm(
-        x_last, params["ln_f"], config.rms_norm_eps, config.rmsnorm_offset
-    )
-    lm_head = params["embed"].T if config.tie_word_embeddings else params["lm_head"]
-    logits = qmat(x_last[:, 0, :], lm_head).astype(jnp.float32)
-    return _final_softcap(logits, config)
 
 
 def head_forward_all(
@@ -674,9 +684,10 @@ def head_forward_all(
     forward scores all draft positions at once. Same ln_f/lm_head weights as
     head_forward — numerics cannot diverge.
     """
-    x = rms_norm(x, params["ln_f"], config.rms_norm_eps, config.rmsnorm_offset)
-    lm_head = params["embed"].T if config.tie_word_embeddings else params["lm_head"]
-    return _final_softcap(qmat(x, lm_head).astype(jnp.float32), config)
+    with jax.named_scope(HEAD):
+        x = rms_norm(x, params["ln_f"], config.rms_norm_eps, config.rmsnorm_offset)
+        lm_head = params["embed"].T if config.tie_word_embeddings else params["lm_head"]
+        return _final_softcap(qmat(x, lm_head).astype(jnp.float32), config)
 
 
 def forward_all_logits(

@@ -35,10 +35,12 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from cake_tpu.obs.jitwatch import tracked_jit
+from cake_tpu.obs.taxonomy import CACHE_WRITE
 from cake_tpu.utils import metrics
 
 UNMAPPED = np.int32(-1)  # block-table sentinel: no physical page mapped
@@ -136,30 +138,31 @@ def paged_write_pool(
     tokens inside its window but must never scribble the shared pages that
     already hold their KV.
     """
-    n_pages, page_size = k_pool.shape[1], k_pool.shape[3]
-    b, chunk = k_new.shape[0], k_new.shape[1]
-    slots = pos + jnp.arange(chunk, dtype=jnp.int32)  # [chunk] absolute
-    logical = jnp.broadcast_to(slots // page_size, (b, chunk))
-    offs = jnp.broadcast_to(slots % page_size, (b, chunk))
-    phys = jnp.take_along_axis(
-        block_tables, logical, axis=1, mode="fill", fill_value=UNMAPPED
-    )
-    # UNMAPPED (-1) -> n_pages: out of bounds, dropped by the scatter.
-    phys = jnp.where(phys < 0, n_pages, phys)
-    if starts is not None:
-        phys = jnp.where(slots[None, :] < starts[:, None], n_pages, phys)
-    k_new = k_new.astype(k_pool.dtype)
-    v_new = v_new.astype(v_pool.dtype)
-    # The KV head is an index of the scatter too, not a window dimension:
-    # each update is then one [head_dim] row, contiguous in the pool's own
-    # (row-major, head-major) layout, which is the layout the kernels read.
-    # With the head in the window XLA lays the pool out token-major for the
-    # scatter and converts the WHOLE pool back for every kernel call.
-    heads = jnp.arange(k_pool.shape[2], dtype=jnp.int32)
-    at = (layer, phys[:, :, None], heads, offs[:, :, None])
-    k_pool = k_pool.at[at].set(k_new, mode="drop")
-    v_pool = v_pool.at[at].set(v_new, mode="drop")
-    return k_pool, v_pool
+    with jax.named_scope(CACHE_WRITE):
+        n_pages, page_size = k_pool.shape[1], k_pool.shape[3]
+        b, chunk = k_new.shape[0], k_new.shape[1]
+        slots = pos + jnp.arange(chunk, dtype=jnp.int32)  # [chunk] absolute
+        logical = jnp.broadcast_to(slots // page_size, (b, chunk))
+        offs = jnp.broadcast_to(slots % page_size, (b, chunk))
+        phys = jnp.take_along_axis(
+            block_tables, logical, axis=1, mode="fill", fill_value=UNMAPPED
+        )
+        # UNMAPPED (-1) -> n_pages: out of bounds, dropped by the scatter.
+        phys = jnp.where(phys < 0, n_pages, phys)
+        if starts is not None:
+            phys = jnp.where(slots[None, :] < starts[:, None], n_pages, phys)
+        k_new = k_new.astype(k_pool.dtype)
+        v_new = v_new.astype(v_pool.dtype)
+        # The KV head is an index of the scatter too, not a window dimension:
+        # each update is then one [head_dim] row, contiguous in the pool's own
+        # (row-major, head-major) layout, which is the layout the kernels read.
+        # With the head in the window XLA lays the pool out token-major for the
+        # scatter and converts the WHOLE pool back for every kernel call.
+        heads = jnp.arange(k_pool.shape[2], dtype=jnp.int32)
+        at = (layer, phys[:, :, None], heads, offs[:, :, None])
+        k_pool = k_pool.at[at].set(k_new, mode="drop")
+        v_pool = v_pool.at[at].set(v_new, mode="drop")
+        return k_pool, v_pool
 
 
 def paged_write_layer(
@@ -294,20 +297,21 @@ def latent_write_pool(
     j) % page_size, :]``, one scatter, in place. Unmapped entries, slots
     below ``starts[b]`` and slots at or past ``ends[b]`` (a window's dead
     tail) drop."""
-    n_pages, page_size = pool.shape[1], pool.shape[2]
-    b, chunk = new.shape[0], new.shape[1]
-    slots = pos + jnp.arange(chunk, dtype=jnp.int32)
-    logical = jnp.broadcast_to(slots // page_size, (b, chunk))
-    offs = jnp.broadcast_to(slots % page_size, (b, chunk))
-    phys = jnp.take_along_axis(
-        block_tables, logical, axis=1, mode="fill", fill_value=UNMAPPED
-    )
-    phys = jnp.where(phys < 0, n_pages, phys)
-    if starts is not None:
-        phys = jnp.where(slots[None, :] < starts[:, None], n_pages, phys)
-    if ends is not None:
-        phys = jnp.where(slots[None, :] >= ends[:, None], n_pages, phys)
-    return pool.at[layer, phys, offs].set(new.astype(pool.dtype), mode="drop")
+    with jax.named_scope(CACHE_WRITE):
+        n_pages, page_size = pool.shape[1], pool.shape[2]
+        b, chunk = new.shape[0], new.shape[1]
+        slots = pos + jnp.arange(chunk, dtype=jnp.int32)
+        logical = jnp.broadcast_to(slots // page_size, (b, chunk))
+        offs = jnp.broadcast_to(slots % page_size, (b, chunk))
+        phys = jnp.take_along_axis(
+            block_tables, logical, axis=1, mode="fill", fill_value=UNMAPPED
+        )
+        phys = jnp.where(phys < 0, n_pages, phys)
+        if starts is not None:
+            phys = jnp.where(slots[None, :] < starts[:, None], n_pages, phys)
+        if ends is not None:
+            phys = jnp.where(slots[None, :] >= ends[:, None], n_pages, phys)
+        return pool.at[layer, phys, offs].set(new.astype(pool.dtype), mode="drop")
 
 
 def gather_latent(

@@ -130,3 +130,35 @@ REQUEST_OUTCOMES = (
 REQUEST_SLO_VERDICTS = (
     "ok", "ttft_miss", "deadline_miss", "refused", "none",
 )
+
+# Parts of a served device program (the ``jax.named_scope`` each operation of
+# a prefill, join or decode-chunk program sits under; models/llama/*, ops/*).
+# Closed: a part is entered in the function that OWNS it, parts never nest
+# in parts, and scopes inside one (``gated_delta_rule``, ``moe_experts_*``)
+# keep their own names. ``bench/parts.py`` restates the tuple (the benchmark
+# reads the program from outside) and a test holds the two equal.
+#
+#   * ``embed``        — the embedding lookup and its scale.
+#   * ``mixer_in``     — the token mixer's input norm and input projections
+#     (q, k, v, their norms and rope; MLA's low-rank projections and the
+#     absorbed ``w_uk`` product; a state layer's in-projections, gates and
+#     convolution).
+#   * ``cache_write``  — what a program writes into a cache it keeps: K and
+#     V rows or latents into the page pool, a state layer's state and
+#     convolution window back into the lane cache.
+#   * ``mixer``        — what READS the cache or runs the recurrence: the
+#     attention kernels and their XLA twins, the selective scan, the gated
+#     delta rule and its one-token step.
+#   * ``mixer_out``    — the output projection, a state mixer's gate, the
+#     norm on the branch's output, the residual add.
+#   * ``feed_forward`` — its norm(s), the dense SwiGLU or the router, routed
+#     and shared experts, the residual add.
+#   * ``head``         — the final norm, the LM head, the soft cap.
+#   * ``sample``       — penalty, key split, arg-max or draw, ring update.
+PROGRAM_PARTS = (
+    "embed", "mixer_in", "cache_write", "mixer", "mixer_out", "feed_forward",
+    "head", "sample",
+)
+(
+    EMBED, MIXER_IN, CACHE_WRITE, MIXER, MIXER_OUT, FEED_FORWARD, HEAD, SAMPLE,
+) = PROGRAM_PARTS

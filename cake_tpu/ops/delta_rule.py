@@ -66,6 +66,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.obs.taxonomy import CACHE_WRITE, MIXER, MIXER_IN, MIXER_OUT
 from cake_tpu.ops import ssm as S
 from cake_tpu.ops.norm import rms_norm
 from cake_tpu.ops.pallas import delta_rule as pallas_rule
@@ -176,46 +177,49 @@ def _inputs(lp, h, conv, live, ends, neg_eigval):
     """Everything of the mixer before the recurrence: (q, k [b, L, H, dk], v
     [b, L, H, dv], log_alpha, beta [b, L, H], all float32; z [b, L, H dv];
     the convolution's new window)."""
-    heads = lp["A_log"].shape[-1]
-    qkvz = qmat(h, lp["in_proj"])
-    width = conv.shape[-1]  # the convolution's channels: q | k | v
-    n_v = qkvz.shape[-1] - width  # z is as wide as v
-    n_k = (width - n_v) // 2
-    dk, dv = n_k // heads, n_v // heads
-    u_in = jnp.where(live[:, :, None], qkvz[..., :width], 0).astype(h.dtype)
-    z = qkvz[..., width:]
-    padded = S.with_window(u_in, conv)
-    u = jax.nn.silu(S.causal_conv(padded, lp["conv_w"], None))
-    b, length = h.shape[:2]
-    q = _unit(u[..., :n_k].reshape(b, length, heads, dk)) * dk ** -0.5
-    k = _unit(u[..., n_k : 2 * n_k].reshape(b, length, heads, dk))
-    v = u[..., 2 * n_k :].reshape(b, length, heads, dv)
-    # The gates' projection keeps its float32 sums: alpha is an exponential
-    # of a, and a rounded to bfloat16 (2^-8 of values up to 10) moves every
-    # step's decay by a percent, which the recurrence compounds.
-    ab = jnp.dot(h, lp["ab_proj"], preferred_element_type=jnp.float32)
-    beta = jax.nn.sigmoid(ab[..., heads:]) * (2.0 if neg_eigval else 1.0)
-    log_alpha = -jnp.exp(lp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
-        ab[..., :heads] + lp["dt_bias"].astype(jnp.float32)
-    )
-    beta = jnp.where(live[:, :, None], beta, 0.0)
-    log_alpha = jnp.where(live[:, :, None], log_alpha, 0.0)
-    if ends is None:
-        ends = jnp.full((b,), length, jnp.int32)
-    new_conv = S.window_at(padded, ends, conv.shape[0]).astype(conv.dtype)
-    # A row without a live position keeps its window (``ops/ssm.py``): its
-    # state read beta = 0 and alpha = 1 above and is its old state already.
-    touched = jnp.any(live, axis=1)
-    new_conv = jnp.where(touched[None, :, None], new_conv, conv)
+    with jax.named_scope(MIXER_IN):
+        heads = lp["A_log"].shape[-1]
+        qkvz = qmat(h, lp["in_proj"])
+        width = conv.shape[-1]  # the convolution's channels: q | k | v
+        n_v = qkvz.shape[-1] - width  # z is as wide as v
+        n_k = (width - n_v) // 2
+        dk, dv = n_k // heads, n_v // heads
+        u_in = jnp.where(live[:, :, None], qkvz[..., :width], 0).astype(h.dtype)
+        z = qkvz[..., width:]
+        padded = S.with_window(u_in, conv)
+        u = jax.nn.silu(S.causal_conv(padded, lp["conv_w"], None))
+        b, length = h.shape[:2]
+        q = _unit(u[..., :n_k].reshape(b, length, heads, dk)) * dk ** -0.5
+        k = _unit(u[..., n_k : 2 * n_k].reshape(b, length, heads, dk))
+        v = u[..., 2 * n_k :].reshape(b, length, heads, dv)
+        # The gates' projection keeps its float32 sums: alpha is an exponential
+        # of a, and a rounded to bfloat16 (2^-8 of values up to 10) moves every
+        # step's decay by a percent, which the recurrence compounds.
+        ab = jnp.dot(h, lp["ab_proj"], preferred_element_type=jnp.float32)
+        beta = jax.nn.sigmoid(ab[..., heads:]) * (2.0 if neg_eigval else 1.0)
+        log_alpha = -jnp.exp(lp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            ab[..., :heads] + lp["dt_bias"].astype(jnp.float32)
+        )
+        beta = jnp.where(live[:, :, None], beta, 0.0)
+        log_alpha = jnp.where(live[:, :, None], log_alpha, 0.0)
+    with jax.named_scope(CACHE_WRITE):
+        if ends is None:
+            ends = jnp.full((b,), length, jnp.int32)
+        new_conv = S.window_at(padded, ends, conv.shape[0]).astype(conv.dtype)
+        # A row without a live position keeps its window (``ops/ssm.py``): its
+        # state read beta = 0 and alpha = 1 above and is its old state already.
+        touched = jnp.any(live, axis=1)
+        new_conv = jnp.where(touched[None, :, None], new_conv, conv)
     return q, k, v, log_alpha, beta, z, new_conv
 
 
 def _gated(lp, o, z, eps, dtype):
     """rms(o; o_norm) * silu(z) a head, heads side by side: [b, L, H dv]."""
-    b, length, heads, dv = o.shape
-    y = rms_norm(o, lp["o_norm"].astype(jnp.float32), eps)
-    y = y * jax.nn.silu(z.astype(jnp.float32)).reshape(b, length, heads, dv)
-    return y.reshape(b, length, heads * dv).astype(dtype)
+    with jax.named_scope(MIXER_OUT):
+        b, length, heads, dv = o.shape
+        y = rms_norm(o, lp["o_norm"].astype(jnp.float32), eps)
+        y = y * jax.nn.silu(z.astype(jnp.float32)).reshape(b, length, heads, dv)
+        return y.reshape(b, length, heads * dv).astype(dtype)
 
 
 def mixer_forward(
@@ -239,31 +243,32 @@ def mixer_forward(
         lp, h, conv, live, ends, neg_eigval
     )
     heads = q.shape[2]
-    if h.shape[1] == 1:
-        with jax.named_scope("gated_delta_step"):
-            o, s = gated_delta_step(
-                q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0],
-                to_heads(ssm, heads),
-            )
-        o, s = o[:, None], from_heads(s)
-    elif window_in_kernel(ssm.shape[-2:], heads, allow_pallas):
-        with jax.named_scope("gated_delta_rule"):
-            # The chunks before a row's first live position and after its
-            # last are not walked (a join's left pads, an epoch's dead tail).
-            length = live.shape[1]
-            lo = jnp.argmax(live, axis=1)
-            hi = length - jnp.argmax(live[:, ::-1], axis=1)
-            hi = jnp.where(jnp.any(live, axis=1), hi, lo)
-            o, s = pallas_rule.gated_delta_rule(
-                q, k, v, log_alpha, beta, ssm,
-                jnp.stack([lo, hi], axis=1).astype(jnp.int32),
-            )
-    else:
-        with jax.named_scope("gated_delta_rule"):
-            o, s = gated_delta_rule(
-                q, k, v, log_alpha, beta, to_heads(ssm, heads), chunk
-            )
-            s = from_heads(s)
+    with jax.named_scope(MIXER):
+        if h.shape[1] == 1:
+            with jax.named_scope("gated_delta_step"):
+                o, s = gated_delta_step(
+                    q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0],
+                    to_heads(ssm, heads),
+                )
+            o, s = o[:, None], from_heads(s)
+        elif window_in_kernel(ssm.shape[-2:], heads, allow_pallas):
+            with jax.named_scope("gated_delta_rule"):
+                # The chunks before a row's first live position and after its
+                # last are not walked (a join's left pads, an epoch's dead tail).
+                length = live.shape[1]
+                lo = jnp.argmax(live, axis=1)
+                hi = length - jnp.argmax(live[:, ::-1], axis=1)
+                hi = jnp.where(jnp.any(live, axis=1), hi, lo)
+                o, s = pallas_rule.gated_delta_rule(
+                    q, k, v, log_alpha, beta, ssm,
+                    jnp.stack([lo, hi], axis=1).astype(jnp.int32),
+                )
+        else:
+            with jax.named_scope("gated_delta_rule"):
+                o, s = gated_delta_rule(
+                    q, k, v, log_alpha, beta, to_heads(ssm, heads), chunk
+                )
+                s = from_heads(s)
     return _gated(lp, o, z, eps, h.dtype), s, new_conv
 
 
@@ -301,7 +306,8 @@ def mixer_step_stacked(
     q, k, v, log_alpha, beta, z, new_conv = _inputs(
         lp, h, conv, live, None, neg_eigval
     )
-    o, ssm = pallas_step.gated_delta_step(
-        ssm, layer, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0]
-    )
+    with jax.named_scope(MIXER):
+        o, ssm = pallas_step.gated_delta_step(
+            ssm, layer, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0]
+        )
     return _gated(lp, o[:, None], z, eps, h.dtype), ssm, new_conv

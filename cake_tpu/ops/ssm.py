@@ -47,6 +47,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.obs.taxonomy import CACHE_WRITE, MIXER, MIXER_IN, MIXER_OUT
 from cake_tpu.ops.norm import rms_norm
 from cake_tpu.ops.pallas import selective_scan as pallas_scan
 from cake_tpu.ops.quant import qmat
@@ -166,51 +167,55 @@ def mixer_forward(
     live position keeps its state bit for bit."""
     d = ssm.shape[-1]
     n = ssm.shape[-2]
-    uz = qmat(h, lp["in_proj"])
-    u_in = jnp.where(live[:, :, None], uz[..., :d], 0).astype(h.dtype)
-    z = uz[..., d:]
-    padded = with_window(u_in, conv)
-    u = jax.nn.silu(causal_conv(padded, lp["conv_w"], lp["conv_b"]))
-    dbc = qmat(u.astype(h.dtype), lp["x_proj"])
-    r = dbc.shape[-1] - 2 * n
-    dt_r = rms_norm(dbc[..., :r], lp["dt_ln"], eps)
-    b_in = rms_norm(dbc[..., r : r + n], lp["b_ln"], eps).astype(jnp.float32)
-    c_out = rms_norm(dbc[..., r + n :], lp["c_ln"], eps).astype(jnp.float32)
-    dt = jax.nn.softplus(
-        qmat(dt_r, lp["dt_proj"]).astype(jnp.float32)
-        + lp["dt_bias"].astype(jnp.float32)
-    )
-    dt = jnp.where(live[:, :, None], dt, 0.0)
-    a = -jnp.exp(lp["A_log"].astype(jnp.float32))  # stored [n, d]
-    if h.shape[1] == 1:
-        # Decode: the same equations for one t, no chunking.
-        s = jnp.exp(dt[:, 0, None, :] * a[None]) * ssm + (
-            (dt[:, 0] * u[:, 0])[:, None, :] * b_in[:, 0, :, None]
+    with jax.named_scope(MIXER_IN):
+        uz = qmat(h, lp["in_proj"])
+        u_in = jnp.where(live[:, :, None], uz[..., :d], 0).astype(h.dtype)
+        z = uz[..., d:]
+        padded = with_window(u_in, conv)
+        u = jax.nn.silu(causal_conv(padded, lp["conv_w"], lp["conv_b"]))
+        dbc = qmat(u.astype(h.dtype), lp["x_proj"])
+        r = dbc.shape[-1] - 2 * n
+        dt_r = rms_norm(dbc[..., :r], lp["dt_ln"], eps)
+        b_in = rms_norm(dbc[..., r : r + n], lp["b_ln"], eps).astype(jnp.float32)
+        c_out = rms_norm(dbc[..., r + n :], lp["c_ln"], eps).astype(jnp.float32)
+        dt = jax.nn.softplus(
+            qmat(dt_r, lp["dt_proj"]).astype(jnp.float32)
+            + lp["dt_bias"].astype(jnp.float32)
         )
-        y = jnp.einsum("bnd,bn->bd", s, c_out[:, 0])[:, None, :]
-    else:
-        # The chunks no row is live in are not walked (a join's left pads).
-        some = jnp.any(live, axis=0)
-        lo = jnp.argmax(some).astype(jnp.int32)
-        hi = (some.shape[0] - jnp.argmax(some[::-1])).astype(jnp.int32)
-        hi = jnp.where(jnp.any(some), hi, lo)
-        if allow_pallas and pallas_scan.tiles(d, n):
-            y, s = pallas_scan.selective_scan(
-                u, dt, a, b_in, c_out, ssm, (lo, hi)
+        dt = jnp.where(live[:, :, None], dt, 0.0)
+        a = -jnp.exp(lp["A_log"].astype(jnp.float32))  # stored [n, d]
+    with jax.named_scope(MIXER):
+        if h.shape[1] == 1:
+            # Decode: the same equations for one t, no chunking.
+            s = jnp.exp(dt[:, 0, None, :] * a[None]) * ssm + (
+                (dt[:, 0] * u[:, 0])[:, None, :] * b_in[:, 0, :, None]
             )
+            y = jnp.einsum("bnd,bn->bd", s, c_out[:, 0])[:, None, :]
         else:
-            with jax.named_scope("selective_scan_xla"):
-                y, s = selective_scan(
-                    u, dt, a, b_in, c_out, ssm, chunk, (lo, hi)
+            # The chunks no row is live in are not walked (a join's left pads).
+            some = jnp.any(live, axis=0)
+            lo = jnp.argmax(some).astype(jnp.int32)
+            hi = (some.shape[0] - jnp.argmax(some[::-1])).astype(jnp.int32)
+            hi = jnp.where(jnp.any(some), hi, lo)
+            if allow_pallas and pallas_scan.tiles(d, n):
+                y, s = pallas_scan.selective_scan(
+                    u, dt, a, b_in, c_out, ssm, (lo, hi)
                 )
-    y = y + lp["D"].astype(jnp.float32) * u
-    gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype)
-    if ends is None:
-        ends = jnp.full((h.shape[0],), h.shape[1], jnp.int32)
-    new_conv = window_at(padded, ends, conv.shape[0]).astype(conv.dtype)
-    # A row without a live position (a dead lane of a decode dispatch) read
-    # dt = 0 and u_in = 0 above, so ``s`` is its old state already; its
-    # window would shift in a zero, so it is kept explicitly.
-    touched = jnp.any(live, axis=1)
-    new_conv = jnp.where(touched[None, :, None], new_conv, conv)
+            else:
+                with jax.named_scope("selective_scan_xla"):
+                    y, s = selective_scan(
+                        u, dt, a, b_in, c_out, ssm, chunk, (lo, hi)
+                    )
+    with jax.named_scope(MIXER_OUT):
+        y = y + lp["D"].astype(jnp.float32) * u
+        gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype)
+    with jax.named_scope(CACHE_WRITE):
+        if ends is None:
+            ends = jnp.full((h.shape[0],), h.shape[1], jnp.int32)
+        new_conv = window_at(padded, ends, conv.shape[0]).astype(conv.dtype)
+        # A row without a live position (a dead lane of a decode dispatch) read
+        # dt = 0 and u_in = 0 above, so ``s`` is its old state already; its
+        # window would shift in a zero, so it is kept explicitly.
+        touched = jnp.any(live, axis=1)
+        new_conv = jnp.where(touched[None, :, None], new_conv, conv)
     return gated, s, new_conv

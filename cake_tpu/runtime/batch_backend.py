@@ -568,8 +568,37 @@ class _PagedBackend:
         ))
         group = self.shapes.prefill_group(b, tokens.shape[1])
         tables = jnp.asarray(self.allocator.block_tables.copy())
-        groups = [slice(lo, lo + group) for lo in range(0, b, group)]
+        groups = [slice(lo, min(lo + group, b)) for lo in range(0, b, group)]
         return tokens, jnp.asarray(pads), ends, tables, groups
+
+    @staticmethod
+    def _group_span(index: int, rows: slice, slots: int):
+        """One engine-track span a group of an epoch's prefill, inside the
+        engine's ``prefill`` span: a profiler window that opens mid-epoch
+        holds the groups still to come (a span that opened before the window
+        did is not in the trace). Read through ``breakdown.idle_gaps``."""
+        from cake_tpu.obs.timeline import PROFILED_TRACK, timeline
+
+        return timeline.span(
+            "prefill-group", track=PROFILED_TRACK,
+            args={"group": index, "rows": rows.stop - rows.start,
+                  "slots": int(slots)},
+        )
+
+    @staticmethod
+    def _group_logits(logits: list):
+        """The groups' last-position logits as the epoch's: the
+        ``concatenate`` waits for nothing but is a program of its own, and
+        the host sits in it while the groups run (its own span)."""
+        from cake_tpu.obs.timeline import PROFILED_TRACK, timeline
+
+        if len(logits) == 1:
+            return logits[0]
+        with timeline.span(
+            "prefill-logits", track=PROFILED_TRACK,
+            args={"groups": len(logits)},
+        ):
+            return jnp.concatenate(logits)
 
     def warm_programs(self, lanes: int, sampling, n_steps: int) -> dict:
         """Run ``shapes.programs(lanes)`` once each, on a scratch cache. A
@@ -819,14 +848,15 @@ class PagedHybridBackend(_PagedBackend):
         tokens, pads, ends, tables, groups = self._epoch_groups(tokens, pads, ends)
         self.state_lane_writes += tokens.shape[0]
         logits = []
-        for rows in groups:
-            out, kv = _hybrid_prefill_jit(
-                self.params, tokens[rows], kv, pads[rows], ends[rows],
-                tables[rows], self.config, lane=rows.start,
-                allow_pallas=self.allow_pallas,
-            )
+        for index, rows in enumerate(groups):
+            with self._group_span(index, rows, tokens.shape[1]):
+                out, kv = _hybrid_prefill_jit(
+                    self.params, tokens[rows], kv, pads[rows], ends[rows],
+                    tables[rows], self.config, lane=rows.start,
+                    allow_pallas=self.allow_pallas,
+                )
             logits.append(out)
-        return (logits[0] if len(logits) == 1 else jnp.concatenate(logits)), kv
+        return self._group_logits(logits), kv
 
     def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
         from cake_tpu.models.llama.hybrid import _hybrid_decode_fn
@@ -958,13 +988,14 @@ class PagedLatentBackend(_PagedBackend):
 
         tokens, pads, ends, tables, groups = self._epoch_groups(tokens, pads, ends)
         logits = []
-        for rows in groups:
-            out, kv, _ = _latent_prefill_jit(
-                self.params, tokens[rows], kv, pads[rows], ends[rows],
-                tables[rows], self.config, allow_pallas=self.allow_pallas,
-            )
+        for index, rows in enumerate(groups):
+            with self._group_span(index, rows, tokens.shape[1]):
+                out, kv, _ = _latent_prefill_jit(
+                    self.params, tokens[rows], kv, pads[rows], ends[rows],
+                    tables[rows], self.config, allow_pallas=self.allow_pallas,
+                )
             logits.append(out)
-        return (logits[0] if len(logits) == 1 else jnp.concatenate(logits)), kv
+        return self._group_logits(logits), kv
 
     def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
         from cake_tpu.models.llama.latent import _latent_decode_fn

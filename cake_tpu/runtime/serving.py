@@ -2876,17 +2876,26 @@ class BatchEngine:
                 sp.row.n_at_restore = sp.row.n
                 self._note_restore(sp.row)
         else:
-            keys = jnp.stack(
-                [
-                    jax.random.PRNGKey(
-                        r.sampling.seed if r is not None else 0
-                    )
-                    for r in reqs
-                ]
-            )
-            first, keys, ring, ring_idx = first_sample(
-                logits, s, ring, ring_idx, keys
-            )
+            # The ``prefill`` span above closed after the ENQUEUE: the wait
+            # for the device is here, where ``first_sample`` reads the
+            # sampled tokens (and compiles, for a batch size it has not
+            # seen), behind the rows' keys, a handful of small programs
+            # each. No period is open: a plain span, no ``_phase``.
+            with timeline.span(
+                "epoch-first-sample", rid=self._epoch_head_rid,
+                track=PROFILED_TRACK, args={"lanes": B, "bucket": int(bucket)},
+            ):
+                keys = jnp.stack(
+                    [
+                        jax.random.PRNGKey(
+                            r.sampling.seed if r is not None else 0
+                        )
+                        for r in reqs
+                    ]
+                )
+                first, keys, ring, ring_idx = first_sample(
+                    logits, s, ring, ring_idx, keys
+                )
             for lane, row in enumerate(rows):
                 if row is not None:
                     row.push(int(first[lane]))

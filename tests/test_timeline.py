@@ -402,6 +402,30 @@ def test_engine_track_spans_land_on_the_profilers_host_plane(tmp_path):
     }
 
 
+def test_an_epochs_prefill_in_groups_has_a_span_a_group_and_one_for_the_logits():
+    """``batch_backend._PagedBackend``'s two helpers of an epoch's prefill in
+    groups: one engine-track span a program and one around the logits'
+    ``concatenate``, none for a single group's (nothing is concatenated)."""
+    import jax.numpy as jnp
+
+    from cake_tpu.obs.timeline import PROFILED_TRACK, timeline
+    from cake_tpu.runtime.batch_backend import _PagedBackend
+
+    before = len(timeline.snapshot())
+    logits = []
+    for index, rows in enumerate([slice(0, 2), slice(2, 3)]):
+        with _PagedBackend._group_span(index, rows, 64):
+            logits.append(jnp.zeros((rows.stop - rows.start, 8)))
+    assert _PagedBackend._group_logits(logits).shape == (3, 8)
+    assert _PagedBackend._group_logits(logits[:1]) is logits[0]
+    mine = [e for e in timeline.snapshot()[before:] if e.get("track") == PROFILED_TRACK]
+    assert [(e["name"], e["args"]) for e in mine] == [
+        ("prefill-group", {"group": 0, "rows": 2, "slots": 64}),
+        ("prefill-group", {"group": 1, "rows": 1, "slots": 64}),
+        ("prefill-logits", {"groups": 2}),
+    ]
+
+
 def test_obs_imports_and_plain_spans_cost_no_jax_import():
     import subprocess
     import sys
