@@ -66,6 +66,7 @@ from cake_tpu.ops.pallas.paged_prefill import (
     paged_chunk_attention_xla,
     paged_kernel_supported,
 )
+from cake_tpu.ops.pallas.paged_write import compiled_here
 from cake_tpu.ops.rope import model_rope_tables
 from cake_tpu.ops.sampling import apply_repeat_penalty, sample, sample_per_row
 
@@ -470,19 +471,20 @@ def batched_blocks_forward(
         x, k_pool, v_pool = carry
         lp, ok, li = per_layer
         q, k, v = qkv(lp, x)
+        # One eligibility rule for every paged kernel (the write, decode AND
+        # the chunk family): the page must be a whole number of lane tiles.
+        # A backend that wanted pallas but lands here surfaces a one-time
+        # `kernel-fallback` flight event host-side
+        # (runtime/batch_backend.PagedLocalBackend._kernel_note). The write
+        # is the kernel where it is compiled, not interpreted.
+        kernel_ok = use_pallas and paged_kernel_supported(kv.page_size)
         # An inert (``valid``-gated) layer still writes its own layer's
         # rows, as the scanned form did; only ``x`` is gated.
         k_pool, v_pool = paged_write_pool(
             k_pool, v_pool, li, k, v, write_pos, block_tables,
-            starts=write_starts,
+            starts=write_starts, kernel=kernel_ok and compiled_here(),
         )
         with jax.named_scope(MIXER):
-            # One eligibility rule for every paged kernel (decode AND the
-            # chunk family): the page must be a whole number of lane tiles.
-            # A backend that wanted pallas but lands here surfaces a
-            # one-time `kernel-fallback` flight event host-side
-            # (runtime/batch_backend.PagedLocalBackend._kernel_note).
-            kernel_ok = use_pallas and paged_kernel_supported(kv.page_size)
             if decode:
                 if kernel_ok:
                     attn = paged_decode_attention(

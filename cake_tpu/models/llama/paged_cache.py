@@ -26,9 +26,15 @@ Refcounts let a shared prompt prefix map the same physical pages from several
 lanes (``fork``), copy-on-write (``make_private`` + ``copy_pages``) splitting a
 page only when a lane is about to write it.
 
-Writes through an UNMAPPED table entry are DROPPED (out-of-bounds scatter with
-``mode="drop"``): left-pad garbage, dummy lanes, and finished lanes cost no
-storage and can never corrupt a recycled page.
+Writes through an UNMAPPED table entry are DROPPED: left-pad garbage, dummy
+lanes, and finished lanes cost no storage and can never corrupt a recycled
+page. ``paged_write_pool`` has two forms of one contract. Where the paged
+attention kernels run (the TPU, a page of whole lane tiles) it is the Pallas
+write ``ops/pallas/paged_write.py``, which moves ``[n_kv, rows, head_dim]``
+slabs by DMA and issues NO copy for a dropped write (nothing is read, nothing
+is written back). Everywhere else (the CPU, where the kernel would be
+interpreted; a page that is not whole tiles; the tests' oracle) it is a scatter of ``[head_dim]`` rows whose dropped
+indices are out of bounds (``mode="drop"``).
 """
 
 from __future__ import annotations
@@ -41,6 +47,7 @@ import numpy as np
 
 from cake_tpu.obs.jitwatch import tracked_jit
 from cake_tpu.obs.taxonomy import CACHE_WRITE
+from cake_tpu.ops.pallas.paged_write import paged_pool_write
 from cake_tpu.utils import metrics
 
 UNMAPPED = np.int32(-1)  # block-table sentinel: no physical page mapped
@@ -117,20 +124,29 @@ def paged_write_pool(
     pos: jnp.ndarray,
     block_tables: jnp.ndarray,
     starts: jnp.ndarray | None = None,
+    kernel: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Write a [batch, chunk, n_kv, head_dim] chunk at sequence offset ``pos``
     into layer ``layer`` of the WHOLE pool, in place.
 
     The paged sibling of cache.write_layer, in the form the model's layer
     scan uses: the pool [n_layers, n_pages, n_kv, page_size, head_dim] is the
-    scan's CARRY and this is its only write — one scatter of token
-    ``pos + j`` of row ``b`` to ``[layer, block_tables[b, (pos + j) //
-    page_size], :, (pos + j) % page_size, :]``. No layer is ever sliced out
-    of the pool or stacked back into it, so the buffer a program was given
-    is the buffer it returns. UNMAPPED entries (and logical pages beyond the
-    table) become out-of-bounds scatter indices and are dropped — the
-    caller's allocator decides what holds storage, the write path cannot
-    corrupt it.
+    scan's CARRY and this is its only write — token ``pos + j`` of row
+    ``b`` goes to ``[layer, block_tables[b, (pos + j) // page_size], :,
+    (pos + j) % page_size, :]``. No layer is ever sliced out of the pool or
+    stacked back into it, so the buffer a program was given is the buffer it
+    returns. UNMAPPED entries (and logical pages beyond the table) are
+    dropped — the caller's allocator decides what holds storage, the write
+    path cannot corrupt it.
+
+    ``kernel`` (STATIC) is the callers' one rule for every paged kernel
+    (``use_pallas and paged_kernel_supported(page_size)``,
+    models/llama/batch.py) where Mosaic compiles the call
+    (``paged_write.compiled_here``: not the CPU's interpreter): true, the write is the Pallas kernel
+    ``ops/pallas/paged_write.paged_pool_write`` (slabs of all KV heads by
+    DMA; a dropped write issues no copy at all); false, one scatter of
+    ``[head_dim]`` rows with the dropped ones out of bounds, the kernel's
+    twin and oracle. The same bytes either way.
 
     ``starts`` (optional [B] int32) drops row ``b``'s writes at slots below
     ``starts[b]`` even when those slots ARE mapped: a suffix prefill over a
@@ -139,6 +155,10 @@ def paged_write_pool(
     already hold their KV.
     """
     with jax.named_scope(CACHE_WRITE):
+        if kernel:
+            return paged_pool_write(
+                k_pool, v_pool, layer, k_new, v_new, pos, block_tables, starts
+            )
         n_pages, page_size = k_pool.shape[1], k_pool.shape[3]
         b, chunk = k_new.shape[0], k_new.shape[1]
         slots = pos + jnp.arange(chunk, dtype=jnp.int32)  # [chunk] absolute

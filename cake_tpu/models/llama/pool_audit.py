@@ -43,6 +43,8 @@ _HLO_DEF = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\w+)\[([\d,]*)\][^\s]*\s+([\w\-]+)\("
 )
 _MOVERS = ("copy", "dynamic-slice", "dynamic-update-slice")
+# The pool's write as a kernel (ops/pallas/paged_write.py) in compiled HLO.
+_POOL_WRITE = re.compile(r"^\s*(?:ROOT\s+)?%?paged_pool_write[\w.\-]* = ", re.M)
 
 
 def pool_shapes(kv_shape: tuple[int, ...]) -> tuple[tuple[int, ...], ...]:
@@ -70,8 +72,11 @@ def audit_paged_programs(
     block table of ``table_pages`` pages a row and compiled for the default
     backend, or for the device ``sharding`` names (one that is described and
     not attached will do). A report holds ``scans`` (``scans_moving_pool``),
-    ``pool_ops`` (``pool_ops_in_hlo``), ``temp_bytes`` (None where the
-    backend gives no memory analysis), ``pool_bytes`` and ``seconds``."""
+    ``pool_ops`` (``pool_ops_in_hlo``), ``pool_writes`` (how many of its
+    custom calls are the pool's write as a kernel: one a layer scan where
+    the paged kernels run, none where the write is the scatter),
+    ``temp_bytes`` (None where the backend gives no memory analysis),
+    ``pool_bytes`` and ``seconds``."""
     from cake_tpu.models.llama.batch import (
         _paged_decode_fn,
         _paged_suffix_join_jit,
@@ -116,10 +121,12 @@ def audit_paged_programs(
         t0 = time.perf_counter()
         traced = fn.trace(*args, **kwargs)
         compiled = traced.lower().compile()
+        hlo = compiled.as_text()
         mem = compiled.memory_analysis()
         reports[name] = {
             "scans": scans_moving_pool(traced.jaxpr, kv_shape),
-            "pool_ops": pool_ops_in_hlo(compiled.as_text(), kv_shape, dtype),
+            "pool_ops": pool_ops_in_hlo(hlo, kv_shape, dtype),
+            "pool_writes": len(_POOL_WRITE.findall(hlo)),
             "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
             "pool_bytes": math.prod(kv_shape) * jnp.dtype(dtype).itemsize,
             "seconds": round(time.perf_counter() - t0, 1),
@@ -231,6 +238,7 @@ def audit_hybrid_programs(
                 for shape, dt in state.values()
             ),
             "kernels": hlo.count("tpu_custom_call"),
+            "pool_writes": len(_POOL_WRITE.findall(hlo)),
             "seconds": round(time.perf_counter() - t0, 1),
         }
     return reports

@@ -35,6 +35,8 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
+import itertools
 import time
 
 import jax
@@ -59,6 +61,9 @@ class Geometry:
     int4_group: int
     dtype: str  # "bf16" | "f32"
     batches: tuple[int, ...] = (1, 8)
+    # KV-head counts beside ``n_kv`` at which the pool's write is tried: a
+    # slab is all the KV heads of a row, so its block follows them
+    write_kv: tuple[int, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -406,9 +411,43 @@ def _int4_cases(c: _Cases) -> None:
             c.run("int4_matmul", f"rows={rows} site={name}", int4, tol=tol)
 
 
+def _pool_write_cases(c: _Cases, kv_heads: tuple[int, ...]) -> None:
+    """The pool's write as a kernel (ops/pallas/paged_write.py) against its
+    scatter, every byte of a stale pool equal: a decode step at each batch
+    (one row with no page where there are several) and a chunk whose window
+    starts and ends inside pages, its first row's first slots not written."""
+    from cake_tpu.models.llama.paged_cache import UNMAPPED, paged_write_pool
+
+    g = c.g
+    n_p = g.max_seq // g.page_size
+    for n_kv, b, width in itertools.product(
+        dict.fromkeys(kv_heads), g.batches, (1, g.chunk)
+    ):
+        perm = np.random.default_rng(b).permutation(b * n_p).reshape(b, n_p)
+        if b > 1:
+            perm[1] = UNMAPPED
+        tables = jnp.asarray(perm, jnp.int32)
+        pool = (2, b * n_p, n_kv, g.page_size, g.head_dim)
+        k_pool, v_pool = c.normal(pool), c.normal(pool)
+        k_new = c.normal((b, width, n_kv, g.head_dim))
+        v_new = c.normal((b, width, n_kv, g.head_dim))
+        pos = jnp.int32(g.max_seq - width - 3)
+        starts = jnp.zeros((b,), jnp.int32).at[0].set(pos + width // 3)
+
+        def write():
+            args = (k_pool, v_pool, jnp.int32(1), k_new, v_new, pos, tables)
+            got, s = _timed(lambda: paged_write_pool(
+                *args, starts=starts, kernel=True))
+            return got, paged_write_pool(*args, starts=starts), s
+
+        c.run("paged_pool_write", f"b={b} width={width} kv_heads={n_kv}",
+              write, tol=0)
+
+
 def _paged_head_cases(c: _Cases, n_q: int, n_kv: int) -> None:
-    """The paged decode and chunk kernels at another head layout than the
-    geometry's (one KV head under a group of 20: a block of 20 query rows)."""
+    """The paged decode and chunk kernels, and the pool's write, at another
+    head layout than the geometry's (one KV head under a group of 20: a
+    block of 20 query rows)."""
     from cake_tpu.ops.pallas.paged_attention import (
         paged_decode_attention,
         paged_decode_attention_xla,
@@ -420,6 +459,7 @@ def _paged_head_cases(c: _Cases, n_q: int, n_kv: int) -> None:
 
     g = c.g
     n_p = g.max_seq // g.page_size
+    _pool_write_cases(c, (n_kv,))
     for b in g.batches:
         perm = np.random.default_rng(b).permutation(b * n_p)
         tables = jnp.asarray(perm.reshape(b, n_p), jnp.int32)
@@ -594,9 +634,71 @@ def run_checks(geom: Geometry) -> dict:
     c = _Cases(geom)
     with recorded_interpret() as seen:
         _attention_cases(c)
+        _pool_write_cases(c, (geom.n_kv, *geom.write_kv))
         _fused_cases(c)
         _int4_cases(c)
     return {"results": c.results, "interpret": seen}
+
+
+def _write_chain(kernel: bool, calls: int, layers: int, page_size: int):
+    """``calls`` dependent writes as one program: the pools are the loop's
+    carry, the layer and the slot change from call to call."""
+    from cake_tpu.models.llama.paged_cache import paged_write_pool
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def chain(k_pool, v_pool, new, tables):
+        return jax.lax.fori_loop(
+            0, calls,
+            lambda i, kv: paged_write_pool(
+                *kv, i % layers, new[0], new[1],
+                (7 * i) % (2 * page_size - 1), tables, kernel=kernel,
+            ),
+            (k_pool, v_pool),
+        )
+
+    return chain
+
+
+def timed_pool_write(
+    shapes: tuple[tuple[int, int, int], ...],
+    head_dim: int,
+    page_size: int,
+    layers: int = 4,
+    dtype: str = "bf16",
+    calls: int = 256,
+    repeats: int = 3,
+) -> list[dict]:
+    """The pool's write alone, as a layer calls it, kernel and scatter side
+    by side: for each ``(rows, kv_heads, width)`` the microseconds a call of
+    ``paged_write_pool`` with and without ``kernel`` (``kernel_us``,
+    ``twin_us``) and the ``[head_dim]`` rows a call moves. ``calls``
+    dependent calls make one program (``_write_chain``), timed on the host
+    clock around ``block_until_ready``; the fastest of ``repeats``."""
+    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    rows = []
+    for b, n_kv, width in shapes:
+        n_p = -(-width // page_size) + 2
+        pool = (layers, b * n_p, n_kv, page_size, head_dim)
+        tables = jnp.asarray(
+            np.random.default_rng(0).permutation(b * n_p).reshape(b, n_p),
+            jnp.int32,
+        )
+        new = jax.random.normal(
+            jax.random.PRNGKey(6), (2, b, width, n_kv, head_dim), dt)
+        rec = {"rows": b, "kv_heads": n_kv, "width": width,
+               "head_rows": 2 * b * width * n_kv}
+        for name, kernel in (("kernel_us", True), ("twin_us", False)):
+            chain = _write_chain(kernel, calls, layers, page_size)
+            kv = _timed(chain, jnp.zeros(pool, dt), jnp.zeros(pool, dt),
+                        new, tables)[0]  # compile + warm
+            fastest = float("inf")
+            for _ in range(repeats):
+                kv, s = _timed(chain, *kv, new, tables)
+                fastest = min(fastest, s)
+            rec[name] = round(fastest / calls * 1e6, 1)
+            del kv
+        rows.append(rec)
+    return rows
 
 
 def timed_paged_decode(

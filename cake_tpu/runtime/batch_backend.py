@@ -429,15 +429,26 @@ class _PagedBackend:
             return "fallback"
         return "pallas"
 
+    def pool_write(self) -> str:
+        """Which form a layer's write into the pool takes, by the same rule
+        as the reads where the kernel is compiled and not interpreted:
+        "pallas" (ops/pallas/paged_write.py: slabs by DMA) or "xla" (a
+        scatter of rows). ``GET /stats`` engine.cache.pool_write."""
+        from cake_tpu.ops.pallas.paged_write import compiled_here
+
+        kernel = self.kernel_impl() == "pallas" and compiled_here()
+        return "pallas" if kernel else "xla"
+
     def _kernel_note(self, op: str, end_slot: int) -> None:
         """Every paged dispatch starts here. A timeline breadcrumb (the
         trace-smoke gate reads these to prove the kernel path engaged), a
         ONE-TIME ``kernel-fallback`` flight event when a paged path silently
         downgrades to XLA (attention_impl wanted pallas, pool layout says
-        no), and the write bound: a write past the sliced table would DROP
-        silently (take_along_axis fill) and corrupt the stream, so fail
-        loudly instead; the engine's capacity formula is supposed to make
-        this unreachable."""
+        no: the pool's write and the attention reads alike), and the write
+        bound: a write past the sliced table would DROP silently (a logical
+        page the table does not hold) and corrupt the stream, so fail loudly
+        instead; the engine's capacity formula is supposed to make this
+        unreachable."""
         from cake_tpu.obs.timeline import timeline
         from cake_tpu.utils import metrics
 
@@ -452,6 +463,7 @@ class _PagedBackend:
             self._fallback_noted = True
             metrics.flight.record(
                 "kernel-fallback", op=op, page_size=self.page_size,
+                pool_write=self.pool_write(), attention="xla",
                 reason="page_size not a multiple of the 128-lane tile",
             )
         timeline.instant(
@@ -509,6 +521,7 @@ class _PagedBackend:
             "page_size": self.page_size,
             "pages": self.max_pages,
             "bytes": stored * self.page_size * self.max_pages,
+            "pool_write": self.pool_write(),
         }
 
     def _note_cache(self) -> None:
@@ -933,6 +946,9 @@ class PagedLatentBackend(_PagedBackend):
 
         per = cache_bytes_per_token(self.config, self.cache_dtype)
         return per["needed"], per["stored"]
+
+    def pool_write(self) -> str:
+        return "xla"  # ``latent_write_pool``: one row a token, a scatter
 
     def moe_facts(self) -> dict:
         """``GET /stats`` engine.moe, cumulative over decode chunks READ:

@@ -100,6 +100,13 @@ PRESETS = {
         max_seq_len=2048, prompts=(32, 300, 1500), shared_prefix=1000,
         new_tokens=64, page_size=128, chunk=256, int4_group=128,
         batches=(1, 8), matmul=(8192, 20),
+        # the pool's write (ops/pallas/paged_write.py): its slab at the
+        # widest KV layout served beside the model's 8, and alone on the
+        # clock beside its scatter as (rows, KV heads, window) of a decode
+        # step of each paged K/V cell and of a 512-token join
+        write_kv=(30,),
+        timed_write=dict(shapes=((32, 30, 1), (8, 8, 1), (32, 1, 1),
+                                 (1, 30, 512))),
         # mistral7b-chat-closed (bench/configs/mistral-7b-v0.1-d16.json)
         pool=dict(layers=16, pages=256, lanes=8, table_pages=8, steps=8,
                   join_width=256),
@@ -135,6 +142,9 @@ PRESETS = {
         shared_prefix=100, new_tokens=8, page_size=128, chunk=32,
         int4_group=64, batches=(1, 2), matmul=(256, 4),
         timed_decode=dict(table_pages=(2, 4), calls=2, repeats=1),
+        write_kv=(3,),
+        timed_write=dict(shapes=((2, 3, 1), (1, 3, 40)), layers=2, calls=2,
+                         repeats=1),
         pool=dict(layers=3, pages=64, lanes=2, table_pages=2, steps=4,
                   join_width=64),
         hybrid=dict(
@@ -336,6 +346,7 @@ def child_kernels(preset: dict) -> None:
         run_checks,
         timed_matmul_chain,
         timed_paged_decode,
+        timed_pool_write,
     )
     from cake_tpu.obs import jitwatch
     from cake_tpu.utils.device import describe_devices, setup_compile_cache
@@ -352,6 +363,7 @@ def child_kernels(preset: dict) -> None:
         max_seq=preset["max_seq_len"], chunk=preset["chunk"],
         int4_group=preset["int4_group"], dtype=preset["dtype"],
         batches=tuple(preset["batches"]),
+        write_kv=tuple(preset["write_kv"]),
     ))
     for rec in out["results"]:
         emit({"kind": "case", **rec})
@@ -360,6 +372,10 @@ def child_kernels(preset: dict) -> None:
         m["head_dim_override"], preset["page_size"], preset["pool"]["lanes"],
         preset["pool"]["layers"], preset["dtype"],
         **preset.get("timed_decode", {}),
+    )})
+    emit({"kind": "write", "rows": timed_pool_write(
+        head_dim=m["head_dim_override"], page_size=preset["page_size"],
+        dtype=preset["dtype"], **preset["timed_write"],
     )})
     chain = timed_matmul_chain(*preset["matmul"])
     peaks = device_peaks()  # raises on an accelerator it has no peaks for
@@ -975,6 +991,12 @@ def phase_kernels(args, preset) -> dict:
             say(f"phase=C   FAILED {name} {c['case']}: "
                 f"{c.get('error') or 'max_err %.3g > tol %.3g' % (c['max_err'], c['tol'])}")
     _say_timed_decode("C", records, args)
+    if not args.rehearse_cpu:  # a time is a device's
+        for r in next(r for r in records if r["kind"] == "write")["rows"]:
+            say(f"phase=C paged_write_pool alone, {r['rows']} rows x "
+                f"{r['kv_heads']} KV heads x {r['width']} slots: kernel "
+                f"{r['kernel_us']} us a call, scatter {r['twin_us']} "
+                f"({r['head_rows']} [head_dim] rows a call)")
     chain = summary["matmul"]
     if summary["peak_tflops"]:  # a rate is a device's; the cpu gets none
         say(f"phase=C bf16 matmul chain on {summary['device_kind']}: "

@@ -161,6 +161,45 @@ def test_chip_smoke_pool_phase_rehearses():
     assert lines[-1].endswith("not a result)")
 
 
+def test_pool_write_check_rehearses_at_the_tiny_preset():
+    """Phase C's cases of the pool's write (ops/pallas/check.py) and its
+    clock, at the tiny preset through the interpreter: every case's bytes
+    are the scatter's (tol 0), at the preset's own KV heads and at
+    ``write_kv``'s, a decode step and a chunk at each batch."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    from cake_tpu.ops.pallas import check
+
+    preset = chip_smoke.PRESETS["tiny"]
+    m = preset["model"]
+    kv_heads = (m["num_key_value_heads"], *preset["write_kv"])
+    c = check._Cases(check.Geometry(
+        hidden=m["hidden_size"], intermediate=m["intermediate_size"],
+        n_q=m["num_attention_heads"], n_kv=m["num_key_value_heads"],
+        head_dim=m["head_dim_override"], vocab=m["vocab_size"],
+        window=m["sliding_window"], page_size=preset["page_size"],
+        max_seq=preset["max_seq_len"], chunk=preset["chunk"],
+        int4_group=preset["int4_group"], dtype=preset["dtype"],
+        batches=tuple(preset["batches"]), write_kv=tuple(preset["write_kv"]),
+    ))
+    with check.recorded_interpret() as seen:
+        check._pool_write_cases(c, kv_heads)
+    assert seen and all(seen), seen  # the kernel ran, in the interpreter
+    assert len(c.results) == len(set(kv_heads)) * len(preset["batches"]) * 2
+    for rec in c.results:
+        assert rec["kernel"] == "paged_pool_write" and rec["tol"] == 0
+        assert rec["ok"] and rec["max_err"] == 0, rec
+    rows = check.timed_pool_write(
+        head_dim=m["head_dim_override"], page_size=preset["page_size"],
+        dtype=preset["dtype"], **preset["timed_write"])
+    assert [(r["rows"], r["kv_heads"], r["width"]) for r in rows] == [
+        tuple(s) for s in preset["timed_write"]["shapes"]]
+    assert all(r["kernel_us"] > 0 and r["twin_us"] > 0 for r in rows)
+
+
 def test_chip_smoke_is_nothing_without_the_repo(tmp_path):
     """Alone in a directory it exits non-zero and prints no result."""
     import shutil
@@ -192,4 +231,7 @@ def test_chip_smoke_cpu_rehearsal_passes_every_phase():
     assert all("platform=cpu" in ln for ln in lines)
     for phase in ("A", "B", "Bf", "C", "D-tp4", "D-mesh4"):
         assert any(f"phase={phase} ok" in ln for ln in lines), phase
+    assert any(
+        "kernel=paged_pool_write" in ln and "failed=0" in ln for ln in lines
+    )
     assert "rehearsal passed" in lines[-1]

@@ -367,6 +367,7 @@ def test_the_backend_and_the_shapes_are_picked_from_the_config(share):
     # 3 layers x float32 x (32 + 8) needed, x 128 stored (whole lane tiles)
     assert (facts["bytes_per_token_needed"], facts["bytes_per_token"]) == (480, 1536)
     assert facts["bytes"] == 1536 * PAGE * 48
+    assert facts["pool_write"] == "xla"  # latent_write_pool: one row a token
     cache = be.init_kv(2)
     assert cache.latent.shape == (3, 48, PAGE, 128)
 

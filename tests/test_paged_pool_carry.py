@@ -293,7 +293,11 @@ CASES = {
 
 @pytest.mark.parametrize("kernel", [True, False], ids=["pallas", "xla"])
 @pytest.mark.parametrize("case", CASES)
-def test_carried_pool_equals_layer_loop(case, kernel):
+def test_carried_pool_equals_layer_loop(case, kernel, monkeypatch):
+    # Here the pool's write is the kernel too (ops/pallas/paged_write.py),
+    # interpreted like the attention kernels; a server on the CPU takes the
+    # scatter (``compiled_here``), which the layer loop beside it calls.
+    monkeypatch.setattr(B, "compiled_here", lambda: True)
     seed = list(CASES).index(case)
     got_logits, got_kv, want_logits, want_kv = CASES[case](
         kernel, stale_pool(seed)
@@ -424,7 +428,41 @@ def test_cell_geometry_compiles_for_v5e_without_pool_copies(
 ):
     report = cell_reports[program]
     assert report["scans"] == [] and report["pool_ops"] == [], report
+    # the layer scan's write is the kernel (ops/pallas/paged_write.py): at 8
+    # KV heads, 8 rows of one token or one row's window of 256
+    assert report["pool_writes"] == 1, report
     assert report["temp_bytes"] < report["pool_bytes"] // 8, report
+
+
+# The pool's write alone (ops/pallas/paged_write.py), compiled for a v5e at
+# the shapes the cells' servers run ahead: a decode step, a join, an epoch's
+# group and the blank rows of start-up, at each cell's KV heads. How many
+# slabs a group holds follows (KV heads, rows, width), and Mosaic counts what
+# they take: 32 rows x 64 slots at ONE head made a group of 160 slabs, 960 DMA
+# semaphores for a core's 512, and Jamba's server did not start (PR 39).
+WRITE_SHAPES = [
+    (rows, n_kv, width)
+    for n_kv in (1, 8, 30)
+    for rows, width in ((32, 1), (64, 1), (32, 64), (64, 40), (1, 512), (8, 2560))
+]
+
+
+@pytest.mark.parametrize("rows,n_kv,width", WRITE_SHAPES)
+def test_pool_write_compiles_for_v5e(rows, n_kv, width, one_chip):
+    from cake_tpu.ops.pallas.paged_write import paged_pool_write
+
+    def spec(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = spec((2, 64, n_kv, PS, 128), jnp.bfloat16)
+    new = spec((rows, width, n_kv, 128), jnp.bfloat16)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = paged_pool_write.trace(
+            pool, pool, spec(()), new, new, spec(()), spec((rows, 24)),
+            spec((rows,)),
+        ).lower().compile()
+    assert len(pool_audit._POOL_WRITE.findall(compiled.as_text())) == 1
 
 
 # ------------- a model with state layers, at its cell's geometry, for a v5e
@@ -459,9 +497,11 @@ def test_hybrid_cell_compiles_for_v5e_without_pool_or_state_copies(
     report = hybrid_reports[program]
     assert report["scans"] == [] and report["pool_ops"] == [], report
     assert report["state_scans"] == [] and report["state_copies"] == [], report
-    # one a layer of attention; a join's prefill scan is one more in each of
-    # the three scanned runs of state layers
-    assert report["kernels"] == (2 if program == "decode" else 5), report
+    # the pool's write and the attention, one each a layer of attention; a
+    # join's prefill scan is one more in each of the three scanned runs of
+    # state layers
+    assert report["pool_writes"] == 2, report
+    assert report["kernels"] == (4 if program == "decode" else 7), report
     # 298 MB of state, 134 MB of pool: a second copy of either would show
     assert report["state_bytes"] == 32 * 9_318_400
     assert report["temp_bytes"] < report["state_bytes"] // 2, report
@@ -520,11 +560,13 @@ def test_delta_rule_cell_compiles_for_v5e_without_pool_or_state_copies(
     # two copies of it a chunk of 8 steps, 0.1 ms of 100.
     assert not [op for op in report["state_copies"] if "f32[" in op], report
     assert len(report["state_copies"]) <= 2, report
-    # each of the four periods' scans holds an attention kernel and a rule
+    # each of the four periods' scans holds the pool's write (30 KV heads a
+    # slab: ops/pallas/paged_write.py), an attention kernel and a rule
     # kernel: decode the one-token update's (ops/pallas/delta_step.py), a
     # join the window's (ops/pallas/delta_rule.py, [96, 1152] of state a
     # grid step in VMEM over the chunks)
-    assert report["kernels"] == 8, report
+    assert report["pool_writes"] == 4, report
+    assert report["kernels"] == 12, report
     assert report["state_bytes"] == 32 * 27_371_520
     assert report["temp_bytes"] < report["state_bytes"] // 2, report
 

@@ -471,12 +471,16 @@ def test_kernel_fallback_flight_event_fires_once(model):
         if e["event"] == "kernel-fallback"
     ]
     assert len(events) == 1
+    # the event names the pool's write with the reads, and /stats agrees
+    assert (events[0]["pool_write"], events[0]["attention"]) == ("xla", "xla")
+    assert be.cache_facts()["pool_write"] == "xla"
     # xla-by-choice is not a fallback: no event.
     metrics.flight.clear()
     be2 = PagedLocalBackend(
         cfg, params, max_seq_len=128, cache_dtype=jnp.float32, page_size=PAGE
     )
     assert be2.kernel_impl() == "xla"
+    assert be2.cache_facts()["pool_write"] == "xla"
     kv2 = be2.init_kv(1)
     be2.allocator.map_range(0, 0, 32)
     be2.prefill(tokens, kv2, np.zeros((1,), np.int32))
@@ -498,6 +502,12 @@ def test_pallas_paged_engine_cold_warm_identical(model):
         max_batch=2,
     )
     assert eng.backend.kernel_impl() == "pallas"
+    # the write is the kernel where Mosaic compiles it; interpreted it is
+    # the scatter (ops/pallas/paged_write.compiled_here)
+    assert eng.backend.cache_facts()["pool_write"] == "xla"
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        assert eng.backend.cache_facts()["pool_write"] == "pallas"
     eng.start()
     try:
         rounds = []
