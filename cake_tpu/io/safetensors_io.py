@@ -26,7 +26,7 @@ import numpy as np
 
 from cake_tpu.models.llama.capability import refuse_unsupported
 from cake_tpu.models.llama.config import (
-    CACHE_KV_STATE, CACHE_LATENT, LlamaConfig,
+    CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, LlamaConfig,
 )
 from cake_tpu.models.llama.model import Params
 
@@ -583,6 +583,39 @@ _LATENT_KV_B = "self_attn.kv_b_proj.weight"
 _SWIGLU = {"gate": "gate_proj.weight", "up": "up_proj.weight", "down": "down_proj.weight"}
 
 
+def _read_feed_forward(reader: SafetensorsReader, config: LlamaConfig, p: str, sparse: bool, dtype, shared: str) -> Params:
+    """A layer's feed-forward under prefix ``p``: a dense SwiGLU, or the
+    router, the experts HELD stacked (their own numbers on disk:
+    ``config.expert_offset`` on) and the shared expert under ``mlp.<shared>``
+    (the by-run loaders of a latent model and of a pool a kind share it)."""
+    if not sparse:
+        return {f"w_{k}": reader.jax(f"{p}mlp.{name}", dtype, transpose=True)
+                for k, name in _SWIGLU.items()}
+    out = {"router": reader.jax(p + "mlp.gate.weight", dtype, transpose=True)}
+    held = range(config.expert_offset, config.expert_offset + config.num_local_experts)
+    for k, name in _SWIGLU.items():
+        out[f"w_{k}"] = jnp.stack([
+            reader.jax(f"{p}mlp.experts.{e}.{name}", dtype, transpose=True) for e in held
+        ])
+        if config.shared_expert_intermediate_size:
+            out[f"sh_{k}"] = reader.jax(f"{p}mlp.{shared}.{name}", dtype, transpose=True)
+    return out
+
+
+def _put_feed_forward(put, run: Params, k: int, config: LlamaConfig, p: str, sparse: bool, shared: str) -> None:
+    """THE inverse of ``_read_feed_forward`` for layer ``k`` of a run."""
+    for key, name in _SWIGLU.items():
+        if not sparse:
+            put(f"{p}mlp.{name}", run[f"w_{key}"][k], True)
+            continue
+        for j in range(config.num_local_experts):
+            put(f"{p}mlp.experts.{config.expert_offset + j}.{name}", run[f"w_{key}"][k, j], True)
+        if f"sh_{key}" in run:
+            put(f"{p}mlp.{shared}.{name}", run[f"sh_{key}"][k], True)
+    if sparse:
+        put(p + "mlp.gate.weight", run["router"][k], True)
+
+
 def _latent_layer(reader: SafetensorsReader, config: LlamaConfig, i: int, sparse: bool, dtype) -> Params:
     p = f"model.layers.{i}."
     out = {k: reader.jax(p + n, dtype, transpose=True) for k, n in _LATENT_MATRICES.items()}
@@ -591,18 +624,7 @@ def _latent_layer(reader: SafetensorsReader, config: LlamaConfig, i: int, sparse
     kv_b = reader.jax(p + _LATENT_KV_B, dtype).reshape(n, -1, config.kv_lora_rank)
     out["w_uk"] = jnp.swapaxes(kv_b[:, :nope], 1, 2)  # [heads, rank, nope]
     out["w_uv"] = jnp.swapaxes(kv_b[:, nope:], 1, 2)
-    if not sparse:
-        for k, name in _SWIGLU.items():
-            out[f"w_{k}"] = reader.jax(f"{p}mlp.{name}", dtype, transpose=True)
-        return out
-    out["router"] = reader.jax(p + "mlp.gate.weight", dtype, transpose=True)
-    held = range(config.expert_offset, config.expert_offset + config.num_local_experts)
-    for k, name in _SWIGLU.items():
-        out[f"w_{k}"] = jnp.stack([
-            reader.jax(f"{p}mlp.experts.{e}.{name}", dtype, transpose=True) for e in held
-        ])
-        if config.shared_expert_intermediate_size:
-            out[f"sh_{k}"] = reader.jax(f"{p}mlp.shared_experts.{name}", dtype, transpose=True)
+    out.update(_read_feed_forward(reader, config, p, sparse, dtype, "shared_experts"))
     return out
 
 
@@ -641,17 +663,76 @@ def latent_tensor_dict(params: Params, config: LlamaConfig, dtype) -> dict[str, 
                 put(p + name, run[key][k])
             kv_b = jnp.concatenate([run["w_uk"][k], run["w_uv"][k]], axis=-1)  # [n, rank, nope+v]
             put(p + _LATENT_KV_B, jnp.swapaxes(kv_b, 1, 2).reshape(-1, config.kv_lora_rank))
-            for key, name in _SWIGLU.items():
-                if kind != SPARSE:
-                    put(f"{p}mlp.{name}", run[f"w_{key}"][k], True)
-                    continue
-                for j in range(config.num_local_experts):
-                    e = config.expert_offset + j
-                    put(f"{p}mlp.experts.{e}.{name}", run[f"w_{key}"][k, j], True)
-                if f"sh_{key}" in run:
-                    put(f"{p}mlp.shared_experts.{name}", run[f"sh_{key}"][k], True)
-            if kind == SPARSE:
-                put(p + "mlp.gate.weight", run["router"][k], True)
+            _put_feed_forward(put, run, k, config, p, kind == SPARSE, "shared_experts")
+    return tensors
+
+
+# Stacks by attention kind and feed-forward (models/llama/kinds.py,
+# ``model_type: laguna``): HF names (ASSUMED: the catalog row carries the
+# config alone) -> key in the run's tree. The gate a head is ``g_proj``; a
+# sparse layer's experts are stacked over the experts HELD, under their own
+# numbers on disk, the shared one under ``shared_expert``.
+_KINDS_MATRICES = {
+    "wq": "self_attn.q_proj.weight", "wk": "self_attn.k_proj.weight",
+    "wv": "self_attn.v_proj.weight", "wo": "self_attn.o_proj.weight",
+    "wg": "self_attn.g_proj.weight",
+}
+_KINDS_VECTORS = {
+    "ln_attn": "input_layernorm.weight",
+    "ln_mlp": "post_attention_layernorm.weight",
+}
+
+
+def _kinds_matrices(config: LlamaConfig) -> dict[str, str]:
+    return {k: n for k, n in _KINDS_MATRICES.items() if k != "wg" or config.attn_gate}
+
+
+def _kinds_vectors(config: LlamaConfig) -> dict[str, str]:
+    qk = {"q_norm": "self_attn.q_norm.weight", "k_norm": "self_attn.k_norm.weight"}
+    return {**_KINDS_VECTORS, **(qk if config.qk_norm else {})}
+
+
+def _kinds_layer(reader: SafetensorsReader, config: LlamaConfig, i: int, sparse: bool, dtype) -> Params:
+    p = f"model.layers.{i}."
+    out = {k: reader.jax(p + n, dtype, transpose=True) for k, n in _kinds_matrices(config).items()}
+    out.update({k: reader.jax(p + n, dtype) for k, n in _kinds_vectors(config).items()})
+    out.update(_read_feed_forward(reader, config, p, sparse, dtype, "shared_expert"))
+    return out
+
+
+def load_kinds_layers(reader: SafetensorsReader, config: LlamaConfig, dtype) -> list[Params]:
+    """One stacked tree a run of layers alike in attention kind and
+    feed-forward (``config.stack_runs``), a key at a time."""
+    from cake_tpu.models.llama.config import SPARSE
+
+    runs = []
+    for _, ff, lo, hi, _ in config.stack_runs:
+        layers = [_kinds_layer(reader, config, i, ff == SPARSE, dtype) for i in range(lo, hi)]
+        run = {}
+        for key in list(layers[0]):
+            run[key] = jnp.stack([layer.pop(key) for layer in layers])
+        runs.append(run)
+    return runs
+
+
+def kinds_tensor_dict(params: Params, config: LlamaConfig, dtype) -> dict[str, np.ndarray]:
+    """THE inverse of ``load_kinds_layers`` (fixtures and round trips)."""
+    from cake_tpu.models.llama.config import SPARSE
+
+    tensors = head_tensor_dict(params, config, dtype)
+
+    def put(name, a, transpose=False):
+        a = np.asarray(a.astype(dtype))
+        tensors[name] = (a.T if transpose else a).copy()
+
+    for run, (_, ff, lo, hi, _) in zip(params["layers"], config.stack_runs):
+        for k, i in enumerate(range(lo, hi)):
+            p = f"model.layers.{i}."
+            for key, name in _kinds_matrices(config).items():
+                put(p + name, run[key][k], True)
+            for key, name in _kinds_vectors(config).items():
+                put(p + name, run[key][k])
+            _put_feed_forward(put, run, k, config, p, ff == SPARSE, "shared_expert")
     return tensors
 
 
@@ -681,10 +762,14 @@ def load_params(
             "layers": load_hybrid_layers(reader, config, dtype),
             "ln_f": reader.jax(_HYBRID_TABLES[config.model_type][1], dtype),
         }
-    elif config.cache_kind == CACHE_LATENT:
+    elif config.cache_kind in (CACHE_LATENT, CACHE_KV_KINDS):
+        by_run = (
+            load_latent_layers if config.cache_kind == CACHE_LATENT
+            else load_kinds_layers
+        )
         params = {
             "embed": reader.jax("model.embed_tokens.weight", dtype),
-            "layers": load_latent_layers(reader, config, dtype),
+            "layers": by_run(reader, config, dtype),
             "ln_f": reader.jax("model.norm.weight", dtype),
         }
     elif layer_range is not None:
@@ -740,6 +825,8 @@ def hf_tensor_dict(
         return hybrid_tensor_dict(params, config, dtype)
     if config.cache_kind == CACHE_LATENT:
         return latent_tensor_dict(params, config, dtype)
+    if config.cache_kind == CACHE_KV_KINDS:
+        return kinds_tensor_dict(params, config, dtype)
     tensors = head_tensor_dict(params, config, dtype)
     tensors.update(
         layer_tensor_dict(

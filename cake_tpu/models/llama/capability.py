@@ -6,8 +6,10 @@ speculative verify and its rewind, tensor / sequence / pipeline parallelism,
 the distributed workers' block ranges, weight quantisation's expert and
 projection tables, the single-stream generator. A model whose lanes keep
 something else (``config.cache_kind``: a recurrent state beside K and V,
-whichever mixer keeps it (Jamba's Mamba-1, Olmo-Hybrid's gated delta rule), or
-one latent a token in place of them) is served by its own paged leaf
+whichever mixer keeps it (Jamba's Mamba-1, Olmo-Hybrid's gated delta rule), one
+latent a token in place of them, or K and V in a pool a KIND of attention layer
+with a windowed kind's pages freed behind the window: Laguna's) is served by its
+own paged leaf
 (``runtime/batch_backend.paged_backend``) on one chip, and everything else is
 refused HERE, with one message, before a weight is read: over such a cache
 each would serve wrong tokens silently, and there is no fallback to serve
@@ -19,7 +21,7 @@ the message names the feature as a user would have written it.
 from __future__ import annotations
 
 from cake_tpu.models.llama.config import (
-    CACHE_KV, CACHE_KV_STATE, STATE, LlamaConfig,
+    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, SLIDING, STATE, LlamaConfig,
 )
 
 
@@ -54,6 +56,15 @@ def _why(config: LlamaConfig) -> str:
             f"{config.num_hidden_layers} layers keep a recurrent state "
             "per lane, and this feature restores, rewinds, shares or "
             "shards K and V only"
+        )
+    if config.cache_kind == CACHE_KV_KINDS:
+        return (
+            f"{len(config.kind_layers(SLIDING))} of its "
+            f"{config.num_hidden_layers} attention layers keep K and V in a "
+            f"pool of their own whose pages are freed {config.sliding_window} "
+            "tokens behind a lane's position, and this feature restores, "
+            "rewinds, shares, shards or re-packs one pool that holds every "
+            "token of every layer"
         )
     return (
         f"its {config.num_hidden_layers} layers keep one latent of "

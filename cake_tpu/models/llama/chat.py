@@ -318,6 +318,19 @@ def encode_dialog_pangu(messages: list[Message]) -> str:
     return "".join(parts)
 
 
+def encode_dialog_laguna(messages: list[Message]) -> str:
+    """Laguna template (ASSUMED: the catalog row carries none and no network
+    reaches the model card; a role-tagged frame a message, as poolside's
+    earlier instruct models wrote them):
+
+        <s><|system|>\n{sys}\n<|user|>\n{u}\n<|assistant|>\n
+    """
+    parts = ["<s>"]
+    parts += [f"<|{m.role.value}|>\n{m.content.strip()}\n" for m in messages]
+    parts.append("<|assistant|>\n")
+    return "".join(parts)
+
+
 # Template key -> dialog encoder. The generator picks by
 # config.dialog_template (the model family, or the --chat-template override);
 # the Llama-3 encoder is the reference-parity surface (history.rs), the
@@ -340,6 +353,7 @@ DIALOG_ENCODERS = {
     "jamba": encode_dialog_jamba,
     "pangu_ultra_moe": encode_dialog_pangu,
     "olmo_hybrid": encode_dialog_olmo,
+    "laguna": encode_dialog_laguna,
 }
 
 

@@ -22,7 +22,7 @@ from typing import Any
 SUPPORTED_MODEL_TYPES = (
     "llama", "qwen2", "mistral", "mixtral", "qwen2_moe",
     "gemma", "gemma2", "phi3", "qwen3", "qwen3_moe", "gemma3_text", "jamba",
-    "pangu_ultra_moe", "olmo_hybrid",
+    "pangu_ultra_moe", "olmo_hybrid", "laguna",
 )
 
 # The two kinds a decoder layer's token mixer can be (``layer_kinds``).
@@ -33,9 +33,16 @@ ATTENTION, STATE = "attention", "state"
 MAMBA, GATED_DELTA = "mamba", "gated_delta"
 # The two kinds its feed-forward can be (``ff_kinds``).
 DENSE, SPARSE = "dense", "sparse"
+# The kinds an ATTENTION layer can be (``attention_kinds``): every key the
+# causal mask admits, or only those inside ``sliding_window``. A model with
+# both keeps K and V in a pool a kind (``cache_kind`` "kv+kinds").
+FULL, SLIDING = "full", "sliding"
 # What a lane keeps on the device between programs (``cache_kind``): K and V
-# a KV head, those beside a recurrent state, or one latent a token.
+# a KV head, those beside a recurrent state, one latent a token, or K and V
+# in a pool a kind of attention layer (a windowed kind's pages are freed
+# behind the window).
 CACHE_KV, CACHE_KV_STATE, CACHE_LATENT = "kv", "kv+state", "latent"
+CACHE_KV_KINDS = "kv+kinds"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,6 +54,25 @@ class RopeScaling:
     high_freq_factor: float = 4.0
     original_max_position_embeddings: int = 8192
     rope_type: str = "llama3"
+
+
+@dataclasses.dataclass(frozen=True)
+class KindRope:
+    """One attention kind's rotary term (``LlamaConfig.kind_ropes``): plain
+    (``factor`` 1) or YaRN (HF ``rope_type: yarn``: the inverse frequencies
+    blended between ``theta^(-2i/d)`` and that over ``factor`` by a ramp from
+    ``beta_fast`` to ``beta_slow`` rotations over
+    ``original_max_position_embeddings``, cos and sin multiplied by
+    ``attention_factor``), over the first ``rotary_dim`` numbers of a head
+    (``partial_rotary_factor``; the rest pass through)."""
+
+    theta: float
+    rotary_dim: int
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -196,6 +222,23 @@ class LlamaConfig:
     # the combine weights are multiplied by ``routed_scaling_factor``.
     moe_scoring: str = "softmax"
     routed_scaling_factor: float = 1.0
+    # Attention layers of more than one kind (``laguna``): per layer, which
+    # kind (``attention_types``: FULL or SLIDING; None = one kind, the
+    # family's), how many query heads (``heads_per_layer``; None =
+    # ``num_attention_heads`` everywhere) and, a kind, its rotary term
+    # (``kind_ropes``: (kind, KindRope) pairs). A SLIDING layer's window is
+    # ``sliding_window``.
+    attention_types: tuple[str, ...] | None = None
+    heads_per_layer: tuple[int, ...] | None = None
+    kind_ropes: tuple[tuple[str, KindRope], ...] | None = None
+    # A gate on each query head's attention output before ``o_proj``:
+    # ``attn_gate_act`` of a linear map of the layer's normed input, one
+    # scalar a head (``wg`` [hidden, heads]). None = no gate.
+    attn_gate: str | None = None
+    attn_gate_act: str = "sigmoid"
+    # The feed-forward of every layer as a list (``mlp_layer_types``); None =
+    # by ``first_k_dense_replace``.
+    ff_types: tuple[str, ...] | None = None
     # Chat-template override (--chat-template; not an HF field). None = pick
     # by model_type. Needed for Llama-2-chat checkpoints, whose config.json
     # is indistinguishable from base Llama (chat.DIALOG_ENCODERS keys).
@@ -252,6 +295,8 @@ class LlamaConfig:
     @property
     def ff_kinds(self) -> tuple[str, ...]:
         """The feed-forward of every layer: the second per-layer fact."""
+        if self.ff_types is not None:
+            return self.ff_types
         if not self.num_local_experts:
             return (DENSE,) * self.num_hidden_layers
         return tuple(
@@ -278,7 +323,44 @@ class LlamaConfig:
         programs' shapes and the capability check are chosen by."""
         if self.kv_lora_rank:
             return CACHE_LATENT
+        if self.attention_types is not None:
+            return CACHE_KV_KINDS
         return CACHE_KV_STATE if self.has_state_layers else CACHE_KV
+
+    @property
+    def attention_kinds(self) -> tuple[str, ...]:
+        """The kinds of attention layer the model has, FULL first: one pool
+        and one block table a kind (``paged_cache.PagePools``)."""
+        if self.attention_types is None:
+            return (FULL,)
+        return tuple(k for k in (FULL, SLIDING) if k in self.attention_types)
+
+    def kind_window(self, kind: str) -> int | None:
+        """Keys a layer of ``kind`` admits behind its query; None = all."""
+        return self.sliding_window if kind == SLIDING else None
+
+    def kind_layers(self, kind: str) -> tuple[int, ...]:
+        """Absolute indices of the attention layers of one kind."""
+        return tuple(
+            i for i, k in enumerate(self.attention_types or ()) if k == kind
+        )
+
+    @property
+    def stack_runs(self) -> tuple[tuple[str, str, int, int, int], ...]:
+        """Maximal runs of layers alike in attention kind AND feed-forward,
+        as (kind, ff, lo, hi, first): ``lo..hi`` the model's own indices,
+        ``first`` the run's first layer among its KIND's (its pool layer).
+        Layers of different head counts cannot share a stacked scan
+        (models/llama/kinds.py stacks by these)."""
+        runs: list[tuple[str, str, int, int, int]] = []
+        seen = dict.fromkeys(self.attention_kinds, 0)
+        for i, (k, f) in enumerate(zip(self.attention_types, self.ff_kinds)):
+            if runs and runs[-1][:2] == (k, f):
+                runs[-1] = (*runs[-1][:3], i + 1, runs[-1][4])
+            else:
+                runs.append((k, f, i, i + 1, seen[k]))
+            seen[k] += 1
+        return tuple(runs)
 
     @property
     def latent_width(self) -> int:
@@ -404,6 +486,8 @@ class LlamaConfig:
             return cls._pangu_from_hf_dict(d, eos_ids)
         if model_type == "olmo_hybrid":
             return cls._olmo_hybrid_from_hf_dict(d, eos_ids)
+        if model_type == "laguna":
+            return cls._laguna_from_hf_dict(d, eos_ids)
         if model_type == "phi3" and d.get("rope_scaling"):
             # Phi-3 128k variants use longrope (per-dim su-scaled factors);
             # only the base-rope variants (4k/8k) are supported.
@@ -453,8 +537,10 @@ class LlamaConfig:
         # use_sliding_window (default false) — honor the gate. When on,
         # transformers applies the window only to layers >= max_window_layers;
         # the common shipped shape (max_window_layers == num_hidden_layers)
-        # means NO layer is windowed. Per-layer windows aren't supported here,
-        # so the mixed shape is an explicit error rather than wrong numerics.
+        # means NO layer is windowed. A window on SOME layers needs a pool a
+        # kind of layer (``cache_kind`` "kv+kinds": ``laguna`` is parsed into
+        # one); this family's parser builds none, so the mixed shape is an
+        # explicit error rather than wrong numerics.
         if model_type in ("qwen2", "qwen2_moe", "qwen3", "qwen3_moe"):
             if not d.get("use_sliding_window", False):
                 sw = None
@@ -464,9 +550,10 @@ class LlamaConfig:
                     sw = None  # threshold never reached: full causal everywhere
                 elif mwl > 0:
                     raise ValueError(
-                        f"qwen2 max_window_layers={mwl} < num_hidden_layers="
-                        f"{n_layers} needs per-layer sliding windows, which "
-                        "this framework does not support"
+                        f"{model_type} max_window_layers={mwl} < "
+                        f"num_hidden_layers={n_layers} needs a window on "
+                        "some layers only, which this framework serves for "
+                        "model_type 'laguna' alone (models/llama/kinds.py)"
                     )
         if model_type == "gemma3_text" and sw is None:
             sw = 4096  # HF Gemma3TextConfig class default
@@ -695,6 +782,142 @@ class LlamaConfig:
         )
 
     @classmethod
+    def _laguna_from_hf_dict(
+        cls, d: dict[str, Any], eos_ids: tuple[int, ...]
+    ) -> "LlamaConfig":
+        """``model_type: laguna`` (poolside Laguna): ``layer_types`` mixes
+        ``full_attention`` and ``sliding_attention`` layers of different
+        query-head counts (``num_attention_heads_per_layer``) on the same KV
+        heads, each kind with its own rotary term (``rope_parameters``), a
+        gate a head on the attention output (``gating``), and
+        ``mlp_layer_types`` dense or sparse (sigmoid scores, the chosen
+        renormalised and scaled, a shared expert). ``num_experts`` counts the
+        experts HELD; ``num_experts_total`` (absent = the same: the whole
+        model) what the router ranks and ``first_expert`` where the held
+        ones start among them. What the config does not say (the score
+        function, the gate's activation, no q/k norm) is DATA here:
+        ``moe_scoring``, ``attn_gate_act``, ``qk_norm`` (a checkpoint whose
+        config says ``use_qk_norm`` carries ``q_norm`` / ``k_norm`` a layer)."""
+        kinds = {"full_attention": FULL, "sliding_attention": SLIDING}
+        ffs = {"dense": DENSE, "sparse": SPARSE}
+        n_layers = int(d.get("num_hidden_layers", 48))
+        raw = d.get("layer_types")
+        if raw is None or len(raw) != n_layers or set(raw) - set(kinds):
+            raise ValueError(
+                f"laguna needs layer_types: {n_layers} entries "
+                f"(num_hidden_layers) of {sorted(kinds)}, got {raw!r}"
+            )
+        heads = d.get("num_attention_heads_per_layer") or (
+            [int(d.get("num_attention_heads", 48))] * n_layers
+        )
+        n_kv = int(d.get("num_key_value_heads", 8))
+        if len(heads) != n_layers or any(int(h) % n_kv for h in heads):
+            raise ValueError(
+                f"laguna needs num_attention_heads_per_layer: {n_layers} "
+                f"multiples of num_key_value_heads={n_kv}, got {heads!r}"
+            )
+        by_kind = {k: {int(h) for h, t in zip(heads, raw) if kinds[t] == k}
+                   for k in kinds.values()}
+        if any(len(v) > 1 for v in by_kind.values()):
+            raise ValueError(
+                "laguna with layers of one kind but different head counts "
+                f"is not supported (by kind: {by_kind})"
+            )
+        mlp = d.get("mlp_layer_types") or (
+            ["dense" if i in (d.get("mlp_only_layers") or []) else "sparse"
+             for i in range(n_layers)]
+        )
+        if len(mlp) != n_layers or set(mlp) - set(ffs):
+            raise ValueError(
+                f"laguna needs mlp_layer_types: {n_layers} entries of "
+                f"{sorted(ffs)}, got {mlp!r}"
+            )
+        gating = d.get("gating")
+        if gating not in (None, False, "per-head"):
+            raise ValueError(
+                f"laguna with gating={gating!r} is not supported (per-head "
+                "or none)"
+            )
+        if float(d.get("moe_router_logit_softcapping") or 0):
+            raise ValueError("laguna with a router soft-cap is not supported")
+        if d.get("moe_apply_router_weight_on_input", False):
+            raise ValueError(
+                "laguna with moe_apply_router_weight_on_input is not supported"
+            )
+        if d.get("attention_bias", False):
+            raise ValueError("laguna with attention_bias is not supported")
+        head_dim = int(d.get("head_dim", 128))
+        ropes = []
+        for name, kind in kinds.items():
+            if kind not in by_kind or not by_kind[kind]:
+                continue
+            r = (d.get("rope_parameters") or {}).get(name)
+            if r is None:
+                raise ValueError(f"laguna needs rope_parameters[{name!r}]")
+            rope_type = r.get("rope_type", "default")
+            if rope_type not in ("default", "yarn"):
+                raise ValueError(
+                    f"laguna with rope_type={rope_type!r} is not supported"
+                )
+            yarn = rope_type == "yarn"
+            ropes.append((kind, KindRope(
+                theta=float(r.get("rope_theta", 10000.0)),
+                rotary_dim=int(head_dim * float(r.get("partial_rotary_factor", 1))),
+                factor=float(r["factor"]) if yarn else 1.0,
+                original_max_position_embeddings=int(
+                    r.get("original_max_position_embeddings", 0)) if yarn else 0,
+                beta_fast=float(r.get("beta_fast", 32)),
+                beta_slow=float(r.get("beta_slow", 1)),
+                attention_factor=float(r.get("attention_factor", 1.0)) if yarn else 1.0,
+            )))
+        held = int(d.get("num_experts", 256))
+        ranked = int(d.get("num_experts_total", held))
+        first = int(d.get("first_expert", 0))
+        if not 0 <= first <= ranked - held:
+            raise ValueError(
+                f"experts {first}..{first + held - 1} are held of {ranked}: "
+                "first_expert + num_experts must not pass num_experts_total"
+            )
+        window = d.get("sliding_window")
+        if by_kind[SLIDING] and not window:
+            raise ValueError("laguna with sliding layers needs sliding_window")
+        full_heads = next(iter(by_kind[FULL] or by_kind[SLIDING]))
+        shared = int(d.get("shared_expert_intermediate_size") or 0)
+        return cls(
+            hidden_size=int(d.get("hidden_size", 3072)),
+            intermediate_size=int(d.get("intermediate_size", 12288)),
+            vocab_size=int(d.get("vocab_size", 100352)),
+            num_hidden_layers=n_layers,
+            num_attention_heads=full_heads,
+            num_key_value_heads=n_kv,
+            rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+            max_position_embeddings=int(
+                d.get("max_position_embeddings", 1048576)
+            ),
+            bos_token_id=int(d.get("bos_token_id", 1)),
+            eos_token_ids=eos_ids if "eos_token_id" in d else (2,),
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            model_type="laguna",
+            head_dim_override=head_dim,
+            sliding_window=None if not window else int(window),
+            attention_types=tuple(kinds[t] for t in raw),
+            heads_per_layer=tuple(int(h) for h in heads),
+            kind_ropes=tuple(ropes),
+            attn_gate="per-head" if gating else None,
+            qk_norm=bool(d.get("use_qk_norm", False)),
+            ff_types=tuple(ffs[t] for t in mlp),
+            num_local_experts=held,
+            router_experts=ranked,
+            expert_offset=first,
+            num_experts_per_tok=int(d.get("num_experts_per_tok", 10)),
+            norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+            moe_intermediate_size=int(d.get("moe_intermediate_size", 1024)),
+            shared_expert_intermediate_size=shared or None,
+            moe_scoring="sigmoid",
+            routed_scaling_factor=float(d.get("moe_routed_scaling_factor", 1.0)),
+        )
+
+    @classmethod
     def _pangu_from_hf_dict(
         cls, d: dict[str, Any], eos_ids: tuple[int, ...]
     ) -> "LlamaConfig":
@@ -839,6 +1062,7 @@ class LlamaConfig:
             "jamba": "JambaForCausalLM",
             "pangu_ultra_moe": "PanguUltraMoEForCausalLM",
             "olmo_hybrid": "OlmoHybridForCausalLM",
+            "laguna": "LagunaForCausalLM",
         }[self.model_type]
         d: dict[str, Any] = {
             "architectures": [arch],
@@ -870,7 +1094,41 @@ class LlamaConfig:
                 d["max_window_layers"] = 0
         if self.head_dim_override is not None:
             d["head_dim"] = self.head_dim_override
-        if self.model_type == "pangu_ultra_moe":
+        if self.model_type == "laguna":
+            del d["rope_theta"]
+            names = {FULL: "full_attention", SLIDING: "sliding_attention"}
+            d.update(
+                head_dim=self.head_dim,
+                layer_types=[names[k] for k in self.attention_types],
+                num_attention_heads_per_layer=list(self.heads_per_layer),
+                mlp_layer_types=list(self.ff_kinds),
+                gating=self.attn_gate or False,
+                use_qk_norm=self.qk_norm,
+                rope_parameters={
+                    names[k]: {
+                        "rope_type": "yarn" if r.factor != 1.0 else "default",
+                        "rope_theta": r.theta,
+                        "partial_rotary_factor": r.rotary_dim / self.head_dim,
+                        **({"factor": r.factor,
+                            "original_max_position_embeddings":
+                                r.original_max_position_embeddings,
+                            "beta_fast": r.beta_fast, "beta_slow": r.beta_slow,
+                            "attention_factor": r.attention_factor}
+                           if r.factor != 1.0 else {}),
+                    } for k, r in self.kind_ropes
+                },
+                num_experts=self.num_local_experts,
+                num_experts_total=self.n_router_experts,
+                first_expert=self.expert_offset,
+                num_experts_per_tok=self.num_experts_per_tok,
+                norm_topk_prob=self.norm_topk_prob,
+                moe_intermediate_size=self.moe_intermediate_size,
+                shared_expert_intermediate_size=(
+                    self.shared_expert_intermediate_size or 0
+                ),
+                moe_routed_scaling_factor=self.routed_scaling_factor,
+            )
+        elif self.model_type == "pangu_ultra_moe":
             del d["num_key_value_heads"]
             d.update(
                 num_key_value_heads=self.num_attention_heads,

@@ -371,6 +371,7 @@ class PageAllocator:
         batch: int,
         max_pages_per_seq: int,
         reserve_pages: int = 1,
+        window: int | None = None,
     ):
         if n_pages <= 0 or page_size <= 0:
             raise ValueError("n_pages and page_size must be positive")
@@ -378,6 +379,14 @@ class PageAllocator:
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
         self.reserve_pages = max(0, reserve_pages)
+        # A WINDOWED kind's pool (``PagePools``): a layer of it admits the
+        # ``window`` keys behind its query, so a page wholly behind that is
+        # never mapped (``map_range`` clips) and goes back to the free list
+        # as the shared slot passes it (``free_behind``). None (every pool
+        # of a model whose layers are all of one kind) = neither: this
+        # class's arithmetic as it always was.
+        self.window = window
+        self.freed_behind_window = 0  # pages, cumulative
         self.refcount = np.zeros(n_pages, np.int32)
         # LIFO free list: recently-freed pages are re-used first (their bytes
         # are likelier to still be resident in any cache hierarchy).
@@ -462,6 +471,8 @@ class PageAllocator:
             return 0
         first = start_slot // self.page_size
         last = -(-end_slot // self.page_size)  # exclusive
+        if self.window is not None:
+            first = max(first, self.first_live_page(end_slot))
         return int((self.block_tables[lanes, first:last] < 0).sum())
 
     def map_range(self, lane: int, start_slot: int, end_slot: int) -> None:
@@ -477,6 +488,11 @@ class PageAllocator:
             return
         first = start_slot // self.page_size
         last = -(-end_slot // self.page_size)  # exclusive
+        if self.window is not None:
+            # The next query sits at ``end_slot`` (a prefill's or a join's
+            # window ends at the shared slot; a decode chunk's slots are
+            # inside the window anyway): what it cannot see is not stored.
+            first = max(first, self.first_live_page(end_slot))
         if last > self.max_pages_per_seq:
             raise ValueError(
                 f"slots [{start_slot}, {end_slot}) need logical page "
@@ -509,6 +525,35 @@ class PageAllocator:
                 self._free.append(phys)
         row[:] = UNMAPPED
         self._update_gauges()
+
+    # ------------------------------------------------- a windowed kind
+
+    def first_live_page(self, slot: int) -> int:
+        """The first logical page a query at ``slot`` still reads: it admits
+        keys at ``slot + 1 - window`` and after."""
+        return max(0, slot + 1 - self.window) // self.page_size
+
+    def free_behind(self, slot: int) -> int:
+        """Before the program whose first query sits at the lanes' shared
+        ``slot`` is enqueued: unmap every lane's pages wholly behind that
+        query's window; returns how many went back to the free list. The
+        lanes share the slot, so it is a range of the table's columns. A
+        program already enqueued holds its own copy of the tables, and a
+        page recycled here is written only by a program enqueued later."""
+        stale = self.block_tables[:, : self.first_live_page(slot)]
+        lanes, pages = np.nonzero(stale >= 0)
+        if not len(lanes):
+            return 0
+        freed = 0
+        for phys in stale[lanes, pages]:
+            self.refcount[phys] -= 1
+            if self.refcount[phys] == 0:
+                self._free.append(int(phys))
+                freed += 1
+        stale[lanes, pages] = UNMAPPED
+        self.freed_behind_window += freed
+        self._update_gauges()
+        return freed
 
     # ----------------------------------------------- prefix sharing (CoW)
 
@@ -624,6 +669,8 @@ class PageAllocator:
     # ------------------------------------------------------------- telemetry
 
     def _update_gauges(self) -> None:
+        if self.window is not None:
+            return  # the gauges are the primary kind's (``PagePools``)
         reg = metrics.registry
         reg.gauge(_G_TOTAL, "Physical KV pages in the pool.").set(
             self.pages_total
@@ -634,3 +681,127 @@ class PageAllocator:
         reg.gauge(
             _G_SHARED, "KV pages mapped by more than one lane (CoW-shared)."
         ).set(self.pages_shared)
+
+
+class PagePools:
+    """The allocators of a model whose attention layers are of more than one
+    KIND (``config.attention_kinds``; ``cache_kind`` "kv+kinds"), one
+    ``PageAllocator`` a kind over that kind's own pool, behind the interface
+    the serving engine drives one allocator by. A model of one kind has no
+    ``PagePools``: its backend hands the engine the ``PageAllocator`` itself.
+
+    The FIRST kind is the primary: it stores every token (no window), so its
+    pages are what admission is priced in (``pages_needed``, ``pages_free``,
+    ``pages_total``, ``reserve_pages``) and what tells a live lane from a
+    dead one (``block_tables``, ``lane_mapped``). A windowed kind holds at
+    most ``window // page_size + 2`` pages a lane; its pool is sized for
+    that (``runtime/batch_backend.PagedKindsBackend``), and every check here
+    still counts it: ``can_admit`` asks every kind, ``pages_missing`` adds a
+    windowed kind's shortfall, ``map_range`` maps all kinds or none.
+    """
+
+    def __init__(self, kinds: dict[str, PageAllocator]):
+        self.kinds = kinds
+        self.primary = next(iter(kinds.values()))
+        if self.primary.window is not None:
+            raise ValueError("the first kind stores every token: no window")
+        self._windowed = [a for a in kinds.values() if a.window is not None]
+        # Each lane's first and last mapped slot while it holds pages (its pad
+        # and the end of the chunk it is writing), -1 otherwise: the tokens
+        # a lane has cached, to within the chunk mapped ahead of them.
+        self._spans(self.primary.block_tables.shape[0])
+
+    # What the engine prices in, and reads a lane's life from: the primary's.
+    page_size = property(lambda self: self.primary.page_size)
+    reserve_pages = property(lambda self: self.primary.reserve_pages)
+    max_pages_per_seq = property(lambda self: self.primary.max_pages_per_seq)
+    pages_total = property(lambda self: self.primary.pages_total)
+    pages_free = property(lambda self: self.primary.pages_free)
+    pages_shared = property(lambda self: self.primary.pages_shared)
+    block_tables = property(lambda self: self.primary.block_tables)
+
+    def pages_needed(self, n_tokens: int) -> int:
+        return self.primary.pages_needed(n_tokens)
+
+    def lane_mapped(self, lane: int) -> bool:
+        return self.primary.lane_mapped(lane)
+
+    def lane_pages(self, lane: int) -> int:
+        return self.primary.lane_pages(lane)
+
+    def can_admit(self, prompt_tokens: int) -> bool:
+        """By kind: a windowed kind prices a prompt at its window's pages."""
+        return all(
+            a.can_admit(
+                prompt_tokens if a.window is None
+                else min(prompt_tokens, a.window + a.page_size)
+            )
+            for a in self.kinds.values()
+        )
+
+    def pages_missing(self, lanes: list[int], start_slot: int, end_slot: int) -> int:
+        """The primary's missing pages, and what a windowed kind would be
+        SHORT of its own: the engine holds the sum against ``pages_free``."""
+        short = sum(
+            max(0, a.pages_missing(lanes, start_slot, end_slot) - a.pages_free)
+            for a in self._windowed
+        )
+        return self.primary.pages_missing(lanes, start_slot, end_slot) + short
+
+    def map_range(self, lane: int, start_slot: int, end_slot: int) -> None:
+        """``PageAllocator.map_range`` in every kind, or in none."""
+        for a in self.kinds.values():
+            if a.pages_missing([lane], start_slot, end_slot) > a.pages_free:
+                a.map_range(lane, start_slot, end_slot)  # raises, maps nothing
+        for a in self.kinds.values():
+            a.map_range(lane, start_slot, end_slot)
+        if end_slot > start_slot:
+            if self.first_slot[lane] < 0:
+                self.first_slot[lane] = start_slot
+            self.last_slot[lane] = max(self.last_slot[lane], end_slot)
+
+    def _spans(self, batch: int) -> None:
+        self.first_slot = np.full(batch, -1, np.int64)
+        self.last_slot = np.full(batch, -1, np.int64)
+
+    def release(self, lane: int) -> None:
+        for a in self.kinds.values():
+            a.release(lane)
+        self.first_slot[lane] = self.last_slot[lane] = -1
+
+    def reset(self, batch: int) -> None:
+        for a in self.kinds.values():
+            a.reset(batch)
+        self._spans(batch)
+
+    def release_lanes(self, batch: int) -> None:
+        for a in self.kinds.values():
+            a.release_lanes(batch)
+        self._spans(batch)
+
+    def free_behind(self, slot: int) -> int:
+        """The windowed kinds' sweep, once a period (the engine calls it
+        where it extends the lanes' pages; it has no such call for a model
+        of one kind)."""
+        return sum(a.free_behind(slot) for a in self._windowed)
+
+    def cached_tokens(self) -> int:
+        """Tokens the lanes that hold pages have mapped storage for, from
+        each lane's pad to the end of the chunk it is writing: as of now,
+        like ``pages_mapped`` beside it."""
+        held = self.first_slot >= 0
+        return int((self.last_slot[held] - self.first_slot[held]).sum())
+
+    def facts(self, bytes_per_page: dict[str, int]) -> dict:
+        """``GET /stats`` engine.cache.kinds: a kind's pool as it stands.
+        ``freed_behind_window`` only grows."""
+        return {
+            kind: {
+                "window": a.window,
+                "pages_total": a.pages_total,
+                "pages_mapped": a.pages_total - a.pages_free,
+                "bytes_per_page": bytes_per_page[kind],
+                "freed_behind_window": a.freed_behind_window,
+            }
+            for kind, a in self.kinds.items()
+        }

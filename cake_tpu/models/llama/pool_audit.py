@@ -320,6 +320,89 @@ def audit_latent_programs(
     return reports
 
 
+def audit_kinds_programs(
+    config: LlamaConfig,
+    *,
+    n_pages: tuple[int, ...],
+    page_size: int,
+    lanes: int,
+    table_pages: int,
+    n_steps: int,
+    join_width: int,
+    prefill_rows: int = 0,
+    dtype=jnp.bfloat16,
+    allow_pallas: bool = True,
+    sharding=None,
+) -> dict[str, dict]:
+    """``audit_latent_programs`` for a model whose attention layers are of
+    more than one kind (models/llama/kinds.py): the cache is a pool a kind
+    (``n_pages[k]`` pages of kind k), NEITHER of which a program may slice,
+    stack or copy, whole or a layer of it: ``scans`` and ``pool_ops`` list
+    both kinds' findings, ``pool_bytes`` sums them."""
+    from cake_tpu.models.llama import kinds as K
+    from cake_tpu.ops.fuse import fuse_params
+
+    def spec(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    params = abstract(jax.eval_shape(lambda: fuse_params(
+        K.init_params(config, jax.random.PRNGKey(0), dtype)
+    )))
+    cache = abstract(jax.eval_shape(
+        lambda: K.init_cache(config, n_pages, page_size, dtype)
+    ))
+    pool_shapes_ = [tuple(p.k.shape) for p in cache.pools]
+
+    def tables(rows):
+        return tuple(spec((rows, table_pages)) for _ in pool_shapes_)
+
+    decode = K._kinds_decode_fn(
+        config, n_steps, 0.0, None, None, 1.0, allow_pallas=allow_pallas
+    )
+    join = K._kinds_join_fn(config, join_width, allow_pallas)
+    programs = {
+        "decode": lambda: decode._jitted.trace(
+            params, cache, spec((lanes,)), spec(()), spec((lanes,)),
+            tables(lanes), spec((lanes,), jnp.bool_),
+            spec((lanes, 2), jnp.uint32), spec((lanes, 0)), spec((lanes,)),
+        ),
+        "join": lambda: join._jitted.trace(
+            params, cache, spec((1, join_width)), spec((1,)), spec((1,)),
+            tables(1), spec(()),
+        ),
+    }
+    if prefill_rows:
+        g = prefill_rows
+        programs["prefill"] = lambda: K._kinds_prefill_jit._jitted.trace(
+            params, spec((g, join_width)), cache, spec((g,)), spec((g,)),
+            tables(g), config, spec(()), allow_pallas=allow_pallas,
+        )
+    reports = {}
+    for name, trace in programs.items():
+        t0 = time.perf_counter()
+        traced = trace()
+        compiled = traced.lower().compile()
+        hlo = compiled.as_text()
+        mem = compiled.memory_analysis()
+        reports[name] = {
+            "scans": [f for s in pool_shapes_ for f in scans_moving_pool(traced.jaxpr, s)],
+            "pool_ops": [f for s in pool_shapes_ for f in pool_ops_in_hlo(hlo, s, dtype)],
+            "pool_writes": len(_POOL_WRITE.findall(hlo)),
+            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
+            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
+            "code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
+            "pool_bytes": sum(
+                2 * math.prod(s) * jnp.dtype(dtype).itemsize for s in pool_shapes_
+            ),
+            "kernels": hlo.count("tpu_custom_call"),
+            "seconds": round(time.perf_counter() - t0, 1),
+        }
+    return reports
+
+
 def scans_moving_pool(jaxpr, kv_shape: tuple[int, ...]) -> list[str]:
     """Every ``scan`` in ``jaxpr`` (nested ones included) that takes the
     pool, or a layer of it, as a scanned input or gives one back as a

@@ -87,6 +87,49 @@ def model_rope_tables(config, max_seq_len: int):
     return jnp.stack([cos_g, cos_l]), jnp.stack([sin_g, sin_l])
 
 
+def yarn_frequencies(rope) -> np.ndarray:
+    """Inverse frequencies [rotary_dim // 2] of one attention kind's rotary
+    term (``config.KindRope``), as HF's ``rope_type: yarn`` computes them:
+    plain ``theta^(-2i/d)`` where ``factor`` is 1; else each blended between
+    that (extrapolation, the fast dims) and that over ``factor``
+    (interpolation, the slow ones) by a linear ramp between the dims that
+    turn ``beta_fast`` and ``beta_slow`` times over the original context."""
+    d = rope.rotary_dim
+    pos_freqs = rope.theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    extrapolation = 1.0 / pos_freqs
+    if rope.factor == 1.0:
+        return extrapolation.astype(np.float32)
+    interpolation = 1.0 / (rope.factor * pos_freqs)
+    orig = rope.original_max_position_embeddings
+
+    def correction_dim(rotations: float) -> float:
+        return d * np.log(orig / (rotations * 2 * np.pi)) / (2 * np.log(rope.theta))
+
+    low = max(np.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(np.ceil(correction_dim(rope.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001  # HF's guard against a zero-width ramp
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    extrapolated = 1.0 - ramp
+    inv_freq = interpolation * (1 - extrapolated) + extrapolation * extrapolated
+    return inv_freq.astype(np.float32)
+
+
+def kind_rope_rows(rope, positions: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(cos, sin), each [*positions.shape, rotary_dim // 2] in f32, of one
+    attention kind's rotary term at ``positions``, ``attention_factor`` on
+    both: the second rope beside the first where a model's kinds differ
+    (models/llama/kinds.py). Computed from the positions, not gathered from
+    a table: a table a kind over a 16,384-slot lane is 12 MB of constants in
+    every served program. ``apply_rope`` takes the rows as pre-gathered,
+    rotates the first ``rotary_dim`` numbers of a head and passes the rest
+    through."""
+    inv_freq = jnp.asarray(yarn_frequencies(rope))
+    freqs = positions.astype(jnp.float32)[..., None] * inv_freq
+    scale = jnp.float32(rope.attention_factor)
+    return jnp.cos(freqs) * scale, jnp.sin(freqs) * scale
+
+
 def apply_rope(
     x: jnp.ndarray,
     cos: jnp.ndarray,
@@ -102,8 +145,16 @@ def apply_rope(
         stacked-layer scans gather once per step instead of once per layer
         (model.blocks_forward / batch.batched_blocks_forward).
       positions: [batch, seq] int32 absolute positions
+
+    A table narrower than ``head_dim // 2`` rotates the first ``2 * width``
+    numbers of a head only (a partial rotary, pairs (i, i + width)); the
+    rest pass through.
     """
     dtype = x.dtype
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        turned = apply_rope(x[..., :rot], cos, sin, positions)
+        return jnp.concatenate((turned, x[..., rot:]), axis=-1)
     if cos.ndim == 3:  # pre-gathered per-token rows
         c = cos[:, :, None, :]  # [b, s, 1, hd/2]
         s = sin[:, :, None, :]

@@ -64,7 +64,7 @@ from cake_tpu.models.llama.paged_cache import (
     init_paged_cache,
 )
 from cake_tpu.models.llama.config import (
-    CACHE_KV, CACHE_KV_STATE, CACHE_LATENT, LlamaConfig,
+    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, LlamaConfig,
 )
 from cake_tpu.models.llama.fused import sample_step, sampled_decode_scan
 from cake_tpu.ops.rope import model_rope_tables
@@ -373,10 +373,14 @@ class _PagedBackend:
         max_pages: int | None = None,
         page_reserve: int = 1,
         allow_pallas: bool = True,
+        lanes: int = 0,
     ):
         from cake_tpu.ops.fuse import fuse_params
 
         self.config = config
+        # The most lanes an epoch will have (the engine's ``max_batch``; 0 =
+        # not told): only a leaf that sizes a pool by the lane reads it.
+        self.lanes = lanes
         self.params = fuse_params(params)
         self.max_seq_len = max_seq_len
         self.cache_dtype = cache_dtype
@@ -390,11 +394,7 @@ class _PagedBackend:
         # Default pool = one dense-equivalent 8-lane footprint; servers size
         # it DOWN (that is the capacity win) via ServeConfig.max_pages.
         self.max_pages = max_pages or 8 * self.pages_per_seq
-        self.allocator = PageAllocator(
-            self.max_pages, page_size, batch=1,
-            max_pages_per_seq=self.pages_per_seq,
-            reserve_pages=page_reserve,
-        )
+        self.allocator = self._make_allocator(page_reserve)
         self.allow_pallas = allow_pallas
         # Epoch-bounded table capacity in PAGES (None = full table).
         self._cap_pages: int | None = None
@@ -409,6 +409,15 @@ class _PagedBackend:
         self.state_decode_rows = 0
         self._state_lanes = 0
         self._note_cache()
+
+    def _make_allocator(self, page_reserve: int):
+        """One pool, one allocator: every leaf but the one whose model has
+        attention layers of more than one kind."""
+        return PageAllocator(
+            self.max_pages, self.page_size, batch=1,
+            max_pages_per_seq=self.pages_per_seq,
+            reserve_pages=page_reserve,
+        )
 
     # --------------------------------------------------- kernel dispatch
 
@@ -915,23 +924,13 @@ class PagedHybridBackend(_PagedBackend):
         )
 
 
-class PagedLatentBackend(_PagedBackend):
-    """The same for a model with latent attention (models/llama/latent.py):
-    the cache is a ``LatentPagedCache``, one latent a token a layer in the
-    same pages the same allocator hands out. An epoch's prefill and a join
-    compute the window's own K and V from its latents and write the latents;
-    decode reads them back absorbed. Like the hybrid leaf it has no suffix,
-    verify or copy-on-write operation and takes no prefix cache (the
-    engine's capability gates read that absence; ``capability.REFUSED``).
-
-    The decode program also returns its account of the expert layer
-    (``latent.MOE_COUNTS``). ``decode`` keeps it on the device beside the
-    chunk's tokens; the engine takes it with them (``take_chunk_counters``)
-    and hands it back when it READS the chunk (``absorb_chunk_counters``), so
-    the account is read at the same boundary as the tokens and never makes
-    the host wait for a chunk of its own."""
-
-    cache_kind = CACHE_LATENT
+class _ExpertAccount:
+    """The decode program's account of the expert layer, for a leaf whose
+    programs return one (``latent.MOE_COUNTS``): ``decode`` and ``join`` keep
+    it on the device beside the chunk's tokens; the engine takes it with them
+    (``take_chunk_counters``) and hands it back when it READS the chunk
+    (``absorb_chunk_counters``), so the account is read at the same boundary
+    as the tokens and never makes the host wait for a chunk of its own."""
 
     def __init__(self, *args, **kw):
         super().__init__(*args, **kw)
@@ -940,15 +939,6 @@ class PagedLatentBackend(_PagedBackend):
         self.moe_counts = dict.fromkeys(MOE_COUNTS, 0)
         self.moe_join_counts = {"joins": 0, "routed": 0, "held": 0}
         self._chunk_counters = None
-
-    def _cache_token_bytes(self) -> tuple[int, int]:
-        from cake_tpu.models.llama.latent import cache_bytes_per_token
-
-        per = cache_bytes_per_token(self.config, self.cache_dtype)
-        return per["needed"], per["stored"]
-
-    def pool_write(self) -> str:
-        return "xla"  # ``latent_write_pool``: one row a token, a scatter
 
     def moe_facts(self) -> dict:
         """``GET /stats`` engine.moe, cumulative over decode chunks READ:
@@ -988,6 +978,34 @@ class PagedLatentBackend(_PagedBackend):
                 else self.moe_counts[key] + v
             )
         return got
+
+
+class PagedLatentBackend(_ExpertAccount, _PagedBackend):
+    """The same for a model with latent attention (models/llama/latent.py):
+    the cache is a ``LatentPagedCache``, one latent a token a layer in the
+    same pages the same allocator hands out. An epoch's prefill and a join
+    compute the window's own K and V from its latents and write the latents;
+    decode reads them back absorbed. Like the hybrid leaf it has no suffix,
+    verify or copy-on-write operation and takes no prefix cache (the
+    engine's capability gates read that absence; ``capability.REFUSED``).
+
+    The decode program also returns its account of the expert layer
+    (``latent.MOE_COUNTS``). ``decode`` keeps it on the device beside the
+    chunk's tokens; the engine takes it with them (``take_chunk_counters``)
+    and hands it back when it READS the chunk (``absorb_chunk_counters``), so
+    the account is read at the same boundary as the tokens and never makes
+    the host wait for a chunk of its own."""
+
+    cache_kind = CACHE_LATENT
+
+    def _cache_token_bytes(self) -> tuple[int, int]:
+        from cake_tpu.models.llama.latent import cache_bytes_per_token
+
+        per = cache_bytes_per_token(self.config, self.cache_dtype)
+        return per["needed"], per["stored"]
+
+    def pool_write(self) -> str:
+        return "xla"  # ``latent_write_pool``: one row a token, a scatter
 
     def init_kv(self, b: int):
         from cake_tpu.models.llama.latent import init_cache
@@ -1049,10 +1067,143 @@ class PagedLatentBackend(_PagedBackend):
         return logits, kv
 
 
+class PagedKindsBackend(_ExpertAccount, _PagedBackend):
+    """The same for a model whose attention layers are of more than one kind
+    (models/llama/kinds.py): the cache is a ``KindsCache``, a page pool a
+    kind, and ``allocator`` a ``PagePools``, an allocator and a block table
+    a kind behind the one interface the engine drives. ``max_pages`` sizes
+    the FIRST kind's pool (every token of every lane: what admission is
+    priced in); a windowed kind's holds ``window // page_size + 2`` pages a
+    lane, the most a lane maps of it at a time (the window, the page its
+    start lies in and the page the next chunk writes), for every lane the
+    engine may run. Like the other two it has no suffix, verify or
+    copy-on-write operation and takes no prefix cache."""
+
+    cache_kind = CACHE_KV_KINDS
+    def _cache_token_bytes(self) -> tuple[int, int]:
+        """A cached token's bytes in the FIRST kind's layers, which keep it
+        for the lane's life; a windowed kind's layers keep the window's
+        tokens, a constant a lane (``cache_facts``' ``kinds``)."""
+        c = self.config
+        per = 2 * c.num_key_value_heads * c.head_dim * len(
+            c.kind_layers(c.attention_kinds[0])
+        ) * jnp.dtype(self.cache_dtype).itemsize
+        return per, per
+
+    def _make_allocator(self, page_reserve: int):
+        from cake_tpu.models.llama.paged_cache import PagePools
+
+        c = self.config
+        pools = {}
+        for kind in c.attention_kinds:
+            window = c.kind_window(kind)
+            pages = self.max_pages if window is None else max(1, self.lanes) * (
+                -(-window // self.page_size) + 2
+            )
+            pools[kind] = PageAllocator(
+                pages, self.page_size, batch=1,
+                max_pages_per_seq=self.pages_per_seq,
+                reserve_pages=page_reserve if window is None else 0,
+                window=window,
+            )
+        return PagePools(pools)
+
+    def _kind_bytes(self) -> dict[str, int]:
+        from cake_tpu.models.llama.kinds import bytes_per_page
+
+        return bytes_per_page(self.config, self.page_size, self.cache_dtype)
+
+    def cache_facts(self) -> dict:
+        """``engine.cache`` with the pools a kind: ``kinds.<kind>`` as
+        ``PagePools.facts`` says, ``cached_tokens`` what the lanes hold
+        storage for now, ``bytes`` all pools'."""
+        facts = super().cache_facts()
+        kinds = self.allocator.facts(self._kind_bytes())
+        facts["bytes"] = sum(
+            k["pages_total"] * k["bytes_per_page"] for k in kinds.values()
+        )
+        facts["kinds"] = kinds
+        facts["cached_tokens"] = self.allocator.cached_tokens()
+        return facts
+
+    def _kind_tables(self, rows: slice = slice(None), pages: int | None = None) -> tuple:
+        """``_tables`` a kind, in ``config.attention_kinds``' order: copies
+        of ``rows`` of each kind's table, the first ``pages`` of a row (a
+        prefill and a join read a lane's whole row, like the other leaves')."""
+        return tuple(
+            jnp.asarray(a.block_tables[rows, :pages].copy())
+            for a in self.allocator.kinds.values()
+        )
+
+    def init_kv(self, b: int):
+        from cake_tpu.models.llama.kinds import init_cache
+
+        self.allocator.reset(batch=b)
+        return init_cache(
+            self.config,
+            tuple(a.n_pages for a in self.allocator.kinds.values()),
+            self.page_size, self.cache_dtype,
+        )
+
+    def prefill(self, tokens, kv, pads, ends=None):
+        """An epoch's prefill in groups of rows (``shapes.prefill_group``),
+        every group one program that writes its own lanes' K and V a kind."""
+        from cake_tpu.models.llama.kinds import _kinds_prefill_jit
+
+        tokens, pads, ends, _, groups = self._epoch_groups(tokens, pads, ends)
+        tables = self._kind_tables()
+        logits = []
+        for index, rows in enumerate(groups):
+            with self._group_span(index, rows, tokens.shape[1]):
+                out, kv, _ = _kinds_prefill_jit(
+                    self.params, tokens[rows], kv, pads[rows], ends[rows],
+                    tuple(t[rows] for t in tables), self.config,
+                    allow_pallas=self.allow_pallas,
+                )
+            logits.append(out)
+        return self._group_logits(logits), kv
+
+    def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
+        from cake_tpu.models.llama.kinds import _kinds_decode_fn
+
+        self._kernel_note("decode", int(slot) + n)
+        fn = _kinds_decode_fn(
+            self.config, n, s.temperature, s.top_k, s.top_p,
+            s.repeat_penalty, allow_pallas=self.allow_pallas,
+        )
+        # A lane is live while it holds pages of the first kind
+        # (``PagedHybridBackend.decode`` says when).
+        b = int(jnp.shape(tok)[0])
+        valid = (self.allocator.block_tables[:b] >= 0).any(axis=1)
+        *out, self._chunk_counters = fn(
+            self.params, kv, tok, jnp.int32(slot), pads,
+            self._kind_tables(pages=self._cap_pages),
+            jnp.asarray(valid), keys, ring, ring_idx,
+        )
+        return tuple(out)
+
+    def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
+        """One row's window [start, start + width) as the engine cut it
+        (``shapes.window``) into lane ``lane``, through its table rows."""
+        from cake_tpu.models.llama.kinds import _kinds_join_fn
+
+        self._kernel_note("join", int(np.asarray(ends1).max()))
+        fn = _kinds_join_fn(
+            self.config, row_tokens.shape[1], self.allow_pallas
+        )
+        logits, kv, self._chunk_counters = fn(
+            self.params, kv, jnp.asarray(row_tokens),
+            jnp.asarray(pads1, jnp.int32), jnp.asarray(ends1, jnp.int32),
+            self._kind_tables(slice(lane, lane + 1)), jnp.int32(start),
+        )
+        return logits, kv
+
+
 _PAGED_LEAVES = {
     CACHE_KV: PagedLocalBackend,
     CACHE_KV_STATE: PagedHybridBackend,
     CACHE_LATENT: PagedLatentBackend,
+    CACHE_KV_KINDS: PagedKindsBackend,
 }
 
 

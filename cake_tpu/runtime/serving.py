@@ -658,6 +658,7 @@ class BatchEngine:
                     max_pages=serve.max_pages
                     or max(1, max_batch) * pages_per_seq,
                     page_reserve=serve.page_reserve,
+                    lanes=max(1, max_batch),
                 )
             else:
                 from cake_tpu.runtime.batch_backend import LocalBatchBackend
@@ -707,6 +708,13 @@ class BatchEngine:
         # Paged accounting seam: the allocator (when the backend has one)
         # drives admission, page growth, and release; None = dense lanes.
         self._alloc = getattr(backend, "allocator", None)
+        # A pool a kind of attention layer (``paged_cache.PagePools``) frees
+        # a windowed kind's pages behind the shared slot, once a period where
+        # the lanes' pages are extended; None for every other allocator,
+        # whose per-period path has no such call.
+        self._free_behind = (
+            self._alloc.free_behind if hasattr(self._alloc, "kinds") else None
+        )
         self.kv_mode = getattr(backend, "kv_mode", "dense")
         # Layers whose recurrent state a join overwrites (0 = none: every
         # family but the hybrid ones); rides the ``join`` span.
@@ -3286,6 +3294,8 @@ class BatchEngine:
         any_live = grew = False
         free0 = self._alloc.pages_free
         with self._phase("page-extend", args={"slot": int(slot), "n": int(n)}):
+            if self._free_behind is not None:
+                self._free_behind(slot)
             for lane, row in enumerate(rows):
                 if row is None:
                     continue

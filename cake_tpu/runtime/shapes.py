@@ -10,9 +10,10 @@ instances that differ in data, picked by ``for_model`` from the config alone:
     programs for as long as it runs (PERF.md: 45% of Mistral's window is
     compile stall) and runs none ahead.
   * CLOSED, for a model whose cache is not plain K and V (state layers:
-    programs that compile in 3 to 10 s each; a latent pool: its prefill
-    computes a window's own K and V, so a window is a whole prompt): eleven
-    window widths and three capacities, shares of a lane's table. An epoch's prefill is right-padded to a width (a dead tail under
+    programs that compile in 3 to 10 s each; a latent pool or a pool a kind
+    of attention layer: its prefill computes a window's own K and V, so a
+    window is a whole prompt): eleven window widths (six for pools a kind)
+    and three capacities, shares of a lane's table. An epoch's prefill is right-padded to a width (a dead tail under
     ``ends``: the recurrence stands still there), a joiner's window is as
     wide as its prompt and ends at the shared slot, and the few dozen
     programs there are run once at start-up (``programs``).
@@ -27,12 +28,18 @@ import dataclasses
 
 from cake_tpu.models.llama.batch import prompt_bucket
 from cake_tpu.models.llama.config import (
-    CACHE_KV, CACHE_KV_STATE, CACHE_LATENT, GATED_DELTA,
+    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, GATED_DELTA,
 )
 
 # The closed tables as shares of a lane's table: widths in 64ths of its
 # slots, capacities in quarters of its pages.
 _WIDTH_64THS = (1, 2, 4, 8, 12, 16, 24, 32, 40, 48, 64)
+# Pools a kind: six widths, not eleven. A program of Laguna's holds a body a
+# run of layers and a megabyte of code a large product in it: a join compiles
+# to 19 to 33 MB (compiled for a described v5e, PERF.md section 4) and takes
+# 17 s to compile, so the set can never sit in a 192 MiB compile cache and
+# every start pays for every program it runs ahead.
+_WIDTH_64THS_BY_KIND = {CACHE_KV_KINDS: (4, 8, 16, 32, 48, 64)}
 _CAPACITY_QUARTERS = (1, 2, 4)
 # Tokens a prefill program may hold, by cache kind. State layers: the mixer's
 # float32 intermediates are [rows, width, d_inner] several times over, so an
@@ -51,7 +58,16 @@ _CAPACITY_QUARTERS = (1, 2, 4)
 # token is the twin's alone (an epoch's group of 2 x 2560 compiles to 0.89 GB
 # of temporaries for the twin's 1.41), and 8,192 stands until a PR of its own
 # re-measures it.
-_PREFILL_TOKENS = {CACHE_KV_STATE: 16384, CACHE_LATENT: 4096}
+# Pools a kind (models/llama/kinds.py): a window attends over its own K and V
+# ([tokens, heads, 128]: 72 heads at Laguna's widest kind, 18 KB a token for
+# q, as much for the gated output, 4 KB for K and V, 6 KB for a residual
+# copy or two), and its tail runs 2,048 tokens at a time whatever the width
+# (``kinds._TAIL_TOKENS``), so the widest join's temporaries are its
+# attention's: compiled for a v5e about 155 KB a token (2.9 GB at 18,432
+# slots, 4.1 at 24,576), which is what an epoch's groups are held to as well.
+_PREFILL_TOKENS = {
+    CACHE_KV_STATE: 16384, CACHE_LATENT: 4096, CACHE_KV_KINDS: 16384,
+}
 _PREFILL_TOKENS_BY_MIXER = {GATED_DELTA: 8192}
 
 
@@ -94,7 +110,8 @@ class ProgramShapes:
             return cls()
         slots = page_size * pages_per_seq
         widths = {
-            min(slots, -(-slots * f // (64 * 64)) * 64) for f in _WIDTH_64THS
+            min(slots, -(-slots * f // (64 * 64)) * 64)
+            for f in _WIDTH_64THS_BY_KIND.get(config.cache_kind, _WIDTH_64THS)
         }
         pages = {max(1, -(-pages_per_seq * q // 4)) for q in _CAPACITY_QUARTERS}
         return cls(

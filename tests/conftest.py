@@ -90,10 +90,24 @@ def pytest_collection_modifyitems(items):
     failure here, strictly (the benchmark PR that makes the test ask each
     configuration's own source takes this out); what it checks is checked
     for that cell, against its own catalog row, in
-    ``tests/zbench/test_bench_jamba.py``, ``test_bench_pangu.py`` and
-    ``test_bench_olmo_hybrid.py``."""
+    ``tests/zbench/test_bench_jamba.py``, ``test_bench_pangu.py``,
+    ``test_bench_olmo_hybrid.py`` and ``test_bench_laguna.py``.
+
+    ``tests/zbench/test_bench_architecture.py::
+    test_the_addition_changes_no_file_that_was_there`` also holds that a cell
+    a later PR adds reports all sixteen of Mistral's per-layer metrics. Since
+    PR 41 three of them (``decode_dispatch_dev_ms``,
+    ``decode_weight_stream_pct``, ``join_prefill_dev_ms``) list the four
+    cells accepted before it (ISSUE 41: they read null on accepted lines, and
+    a PR that adds a cell may give them the list), so an added cell reports
+    thirteen and that one assertion is an expected failure, strictly, until
+    the ``benchmark`` PR of PERF.md row 12 drops the count
+    (``test_bench_period_metrics.py::test_manifest_holds_the_new_entries``
+    pins that ``join_prefill_dev_ms`` has no such list: the same); the rest of what
+    the test holds (no file changed, the manifest loads) is held for
+    Laguna's cell in ``test_bench_laguna.py``."""
     other_models = ("jamba2-3b-chat-closed", "pangu-ultra-ep16-chat-closed",
-                    "olmo-hybrid-7b-chat-closed")
+                    "olmo-hybrid-7b-chat-closed", "laguna-s-ep8-code-closed")
     for item in items:
         if item.nodeid.endswith(
             tuple(f"test_cell_loads[{cell}]" for cell in other_models)
@@ -103,4 +117,13 @@ def pytest_collection_modifyitems(items):
                 reason="test_cell_loads hard-codes Mistral-7B's widths for "
                 "every cell; see tests/zbench/test_bench_jamba.py, "
                 "test_bench_pangu.py and test_bench_olmo_hybrid.py",
+            ))
+        if item.nodeid.endswith((
+            "test_bench_architecture.py::test_the_addition_changes_no_file_that_was_there",
+            "test_bench_period_metrics.py::test_manifest_holds_the_new_entries",
+        )):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="pins sixteen every-cell metrics without a list; three "
+                "list their cells since PR 41 (PERF.md section 7, row 12)",
             ))

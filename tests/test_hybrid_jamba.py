@@ -540,7 +540,13 @@ def test_the_other_families_parse_as_they_did(family):
         "layer_types": None, "state_mixer": "mamba", "linear_num_key_heads": 0,
         "linear_num_value_heads": 0, "linear_key_head_dim": 0, "linear_value_head_dim": 0,
         "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": False,
-        "pre_block_norms": True, "qk_norm_whole": False}
+        "pre_block_norms": True, "qk_norm_whole": False,
+        # PR 41: attention layers of one kind at one head count under the
+        # family's one rope, no gate a head, the feed-forward by
+        # ``first_k_dense_replace``
+        "attention_types": None, "heads_per_layer": None, "kind_ropes": None,
+        "attn_gate": None, "attn_gate_act": "sigmoid", "ff_types": None}
+    assert config.attention_kinds == ("full",)
     assert set(config.layer_kinds) == {"attention"} and not config.has_state_layers
     assert config.cache_kind == "kv" and len(set(config.ff_kinds)) == 1
     assert config.n_router_experts == config.num_local_experts
@@ -548,7 +554,7 @@ def test_the_other_families_parse_as_they_did(family):
 
 
 def test_unsupported_message_is_built_from_the_tuple():
-    assert sorted([*GOLDEN, "jamba", "pangu_ultra_moe", "olmo_hybrid"]) == sorted(SUPPORTED_MODEL_TYPES)
+    assert sorted([*GOLDEN, "jamba", "pangu_ultra_moe", "olmo_hybrid", "laguna"]) == sorted(SUPPORTED_MODEL_TYPES)
     with pytest.raises(ValueError) as e:
         LlamaConfig.from_hf_dict({"model_type": "mamba2"})
     assert f"(supported: {', '.join(SUPPORTED_MODEL_TYPES)})" in str(e.value)
