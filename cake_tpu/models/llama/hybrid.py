@@ -189,6 +189,40 @@ def window_form(config: LlamaConfig, allow_pallas: bool) -> str | None:
     return "pallas" if kernel else "xla"
 
 
+def _state_ops(config: LlamaConfig):
+    """The state layers' mixer as data: (its module, what its forms take of
+    the config beside ``eps``, what its ``steps_in_place`` takes beside the
+    state)."""
+    if config.state_mixer == GATED_DELTA:
+        return (
+            D, {"neg_eigval": config.linear_allow_neg_eigval},
+            (config.linear_num_value_heads,),
+        )
+    return S, {}, ()
+
+
+def _steps_in_place(config: LlamaConfig, ssm, switch: bool) -> bool:
+    """Whether a decode step updates the stack's state ``ssm`` ([n_state,
+    lanes, *state_shape]; its shape is all that is read) in place through the
+    mixer's Pallas kernel, which takes the stack whole: the kernel switch and
+    widths that tile."""
+    ops, _, widths = _state_ops(config)
+    return switch and ops.steps_in_place(ssm, *widths)
+
+
+def step_form(config: LlamaConfig, allow_pallas: bool) -> str | None:
+    """Which form the one-token update of the state layers' recurrence takes
+    in a decode program, by the predicate the program itself follows:
+    ``"pallas"`` (``ops/pallas/delta_step.py`` or ``selective_step.py``: the
+    state read once and written once, in place) or ``"xla"`` (the twin); None
+    without state layers. ``GET /stats`` engine.state.step_form."""
+    if not config.layers_of(STATE):
+        return None
+    stack = jax.ShapeDtypeStruct((1, 1, *config.state_shape), jnp.float32)
+    kernel = _steps_in_place(config, stack, _kernel_switch(config, allow_pallas))
+    return "pallas" if kernel else "xla"
+
+
 def hybrid_blocks_forward(
     runs: list,
     x: jnp.ndarray,
@@ -221,18 +255,15 @@ def hybrid_blocks_forward(
     kv, ssm, conv = cache
     eps = config.rms_norm_eps
     rows = x.shape[0]
-    if config.state_mixer == GATED_DELTA:
-        mixer = functools.partial(
-            D.mixer_forward, eps=eps, neg_eigval=config.linear_allow_neg_eigval,
-            allow_pallas=use_pallas,
-        )
-    else:
-        mixer = functools.partial(S.mixer_forward, eps=eps, allow_pallas=use_pallas)
-    # A decode step of a delta-rule stack updates the carry's state in place
-    # through the Pallas kernel, which takes the stack whole.
+    ops, of_config, _ = _state_ops(config)
+    mixer = functools.partial(
+        ops.mixer_forward, eps=eps, allow_pallas=use_pallas, **of_config
+    )
+    # A decode step updates the carry's state in place through the mixer's
+    # Pallas kernel, which takes the stack whole.
     in_place = (
-        config.state_mixer == GATED_DELTA and lane is None and x.shape[1] == 1
-        and use_pallas and D.steps_in_place(ssm, config.linear_num_value_heads)
+        lane is None and x.shape[1] == 1
+        and _steps_in_place(config, ssm, use_pallas)
     )
     if lane is not None:
         lanes = jnp.arange(conv.shape[2], dtype=jnp.int32)
@@ -249,8 +280,8 @@ def hybrid_blocks_forward(
             c_old = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
             h = rms_norm(x, lp["ln_attn"], eps) if "ln_attn" in lp else x
         if in_place:
-            gated, ssm, c_l = D.mixer_step_stacked(
-                lp, h, ssm, li, c_old, live, eps, config.linear_allow_neg_eigval
+            gated, ssm, c_l = ops.mixer_step_stacked(
+                lp, h, ssm, li, c_old, live, eps, **of_config
             )
         elif lane is None:
             with jax.named_scope(MIXER):

@@ -203,8 +203,6 @@ def _inputs(lp, h, conv, live, ends, neg_eigval):
         beta = jnp.where(live[:, :, None], beta, 0.0)
         log_alpha = jnp.where(live[:, :, None], log_alpha, 0.0)
     with jax.named_scope(CACHE_WRITE):
-        if ends is None:
-            ends = jnp.full((b,), length, jnp.int32)
         new_conv = S.window_at(padded, ends, conv.shape[0]).astype(conv.dtype)
         # A row without a live position keeps its window (``ops/ssm.py``): its
         # state read beta = 0 and alpha = 1 above and is its old state already.

@@ -560,8 +560,17 @@ def _mixer_cases(c: _Cases, g: HybridGeometry) -> None:
     # program (a 3-minute compile at 8 rows on the chip)
     prefill_fn = jax.jit(lambda lp, h, s0, w0, live, ends: ssm.mixer_forward(
         lp, h, s0, w0, live, ends, eps))
-    step_fn = jax.jit(lambda lp, h1, s1, w1, alive: ssm.mixer_forward(
-        lp, h1, s1, w1, alive[:, None], None, eps))
+    # the decode step as the model takes it: the stack whole, in place
+    stacked = ssm.steps_in_place(jnp.zeros((1, 1, g.d_state, g.d_inner)))
+    if stacked:
+        step_fn = jax.jit(lambda lp, h1, stack, w1, alive: ssm.mixer_step_stacked(
+            lp, h1, stack, jnp.int32(1), w1, alive[:, None], eps))
+    else:
+        def step_fn(lp, h1, stack, w1, alive):
+            gated, s, w = ssm.mixer_forward(
+                lp, h1, stack[1], w1, alive[:, None], None, eps)
+            return gated, stack.at[1].set(s), w
+        step_fn = jax.jit(step_fn)
     for b in g.batches:
         # ---- prefill: rows left-padded by differing amounts, from zero
         length = g.prompt
@@ -596,26 +605,29 @@ def _mixer_cases(c: _Cases, g: HybridGeometry) -> None:
         c.run("ssm_mixer", f"prefill b={b} L={length}", prefill)
 
         # ---- one token from a state that is there, one lane not live
-        s1 = jnp.abs(c.normal((b, g.d_state, g.d_inner), 0.5, jnp.float32))
+        stack = jnp.abs(c.normal((3, b, g.d_state, g.d_inner), 0.5, jnp.float32))
         w1 = c.normal((g.d_conv - 1, b, g.d_inner), 0.5)
         h1 = c.normal((b, 1, g.hidden))
         alive = jnp.arange(b) != b - 1 if b > 1 else jnp.ones((1,), bool)
 
         def step():
-            (gated, s, w), first = _timed(step_fn, lp, h1, s1, w1, alive)
-            want_g, want_s, want_w = _mixer_reference(lp, h1, s1, w1, eps)
+            # the stack is not donated here: the kernel's alias copies it
+            (gated, out, w), first = _timed(step_fn, lp, h1, stack, w1, alive)
+            want_g, want_s, want_w = _mixer_reference(lp, h1, stack[1], w1, eps)
             keep = alive[:, None, None]
-            want_s = jnp.where(keep, want_s, s1)
+            want_s = jnp.where(keep, want_s, stack[1])
             want_w = jnp.where(alive[None, :, None], want_w, w1.astype(jnp.float32))
-            return (gated[alive], s, w), (want_g[alive], want_s, want_w), first
+            want_stack = stack.at[1].set(want_s)
+            return (gated[alive], out, w), (want_g[alive], want_stack, want_w), first
 
-        c.run("ssm_mixer", f"step b={b}", step)
+        c.run("ssm_mixer", f"step b={b} {'kernel' if stacked else 'xla'}", step)
 
 
 def run_hybrid_checks(geom: HybridGeometry) -> dict:
     """``run_checks`` for a model with state layers: the paged kernels at
     its attention layers' head layout, and the state-space mixer's prefill
-    (chunked scan) and one-token step against the stepwise float32 scan."""
+    (chunked scan) and one-token step (the Pallas kernel where the widths
+    tile) against the stepwise float32 scan."""
     c = _Cases(Geometry(
         hidden=geom.hidden, intermediate=0, n_q=geom.n_q, n_kv=geom.n_kv,
         head_dim=geom.head_dim, vocab=0, window=None,
@@ -830,6 +842,72 @@ def timed_selective_scan(
             rec[f"{name}_us"] = round(fastest / calls * 1e6, 1)
         rows.append(rec)
     return rows
+
+
+def timed_selective_step(
+    d_inner: int,
+    d_state: int,
+    lanes: int = 32,
+    layers: int = 26,
+    passes: int = 20,
+    repeats: int = 3,
+) -> list[dict]:
+    """The selective mixer's one-token update alone at ``lanes`` rows: the
+    Pallas kernel on a stack's state in place (``ops/pallas/selective_step.py``)
+    beside its XLA twin (``ops/ssm.mixer_forward``'s decode branch over a
+    layer of the stack, written back). One program steps each of the donated
+    stack's ``layers`` layers ``passes`` times, a call's ``y`` fed to the
+    next call's ``u``: 520 calls under one dispatch, because the host's part
+    of a dispatch (a millisecond on the clock) is as long as 26 calls. The
+    host clock around ``block_until_ready`` with the inputs ready before it
+    starts, the fastest of ``repeats``. Microseconds a call beside
+    ``floor_us`` (a layer's state read once and written once at 819 GB/s),
+    the kernel's errors against the twin over the twin's largest value."""
+    from cake_tpu.ops.pallas import selective_step
+
+    keys = jax.random.split(jax.random.PRNGKey(9), 6)
+    n = lambda k, *shape: jax.random.normal(k, shape, jnp.float32)
+    u = n(keys[0], lanes, d_inner)
+    dt = jax.nn.softplus(n(keys[1], lanes, d_inner) - 3.0)
+    a = -jnp.exp(n(keys[2], d_state, d_inner) * 0.5)
+    b_in, c_out = n(keys[3], lanes, d_state), n(keys[4], lanes, d_state)
+    stack = n(keys[5], layers, lanes, d_state, d_inner)
+
+    def twin_step(stack, i, u):
+        s = jnp.exp(dt[:, None, :] * a[None]) * stack[i] + (
+            (dt * u)[:, None, :] * b_in[:, :, None])
+        return jnp.einsum("bnd,bn->bd", s, c_out), stack.at[i].set(s)
+
+    def kernel_step(stack, i, u):
+        return selective_step.selective_step(stack, i, u, dt, a, b_in, c_out)
+
+    def chain(step):
+        def steps(stack, u):
+            def one(i, carry):
+                stack, u = carry
+                y, stack = step(stack, jax.lax.rem(i, layers), u)
+                return stack, u + 1e-3 * y
+
+            return jax.lax.fori_loop(0, layers * passes, one, (stack, u))
+
+        return jax.jit(steps, donate_argnums=0)
+
+    rec = {"op": "selective_step", "rows": lanes, "length": 1,
+           "calls": layers * passes,
+           "floor_us": round(2 * lanes * d_state * d_inner * 4 / 819e9 * 1e6, 1)}
+    chains = {"twin": chain(twin_step)}
+    if selective_step.tiles(d_inner, d_state):
+        chains["kernel"] = chain(kernel_step)
+    fresh = lambda: jax.block_until_ready(stack + 0.0)  # the chain donates it
+    outs = {}
+    for name, stepped in chains.items():
+        outs[name] = jax.tree.map(np.asarray, stepped(fresh(), u))
+        fastest = min(_timed(stepped, fresh(), u)[1] for _ in range(repeats))
+        rec[f"{name}_us"] = round(fastest / (layers * passes) * 1e6, 1)
+    if "kernel" in outs:
+        rec["err_s"] = _rel_err(outs["kernel"][0], outs["twin"][0])
+        rec["err_y"] = _rel_err(outs["kernel"][1], outs["twin"][1])
+    return [rec]
 
 
 @dataclasses.dataclass(frozen=True)

@@ -28,7 +28,12 @@ unchanged and the window holds zeros: a recurrence never sees a pad.
 Which scan a window takes is read off what ``mixer_forward`` can see, and
 nothing else (no flag, field or environment variable chooses):
 
-  * ``L == 1`` (every decode step): the one-token update, in line.
+  * ``L == 1`` (every decode step): the one-token update. Where the model
+    hands the layer stack's state whole (``mixer_step_stacked``: the switch,
+    and widths that tile, ``steps_in_place``) it is the Pallas kernel
+    ``ops/pallas/selective_step.py``: one read and one write of ``s``, ``y``
+    taken on the way, the operation ``selective_step`` of a device trace.
+    Else the XLA form in ``mixer_forward``, its twin and oracle.
   * ``L > 1`` with ``allow_pallas`` (the switch every Pallas kernel follows,
     handed down from ``hybrid_blocks_forward``) and widths that tile
     (``d_inner`` in 128-lane tiles, ``d_state`` in sublane tiles):
@@ -50,6 +55,7 @@ import jax.numpy as jnp
 from cake_tpu.obs.taxonomy import CACHE_WRITE, MIXER, MIXER_IN, MIXER_OUT
 from cake_tpu.ops.norm import rms_norm
 from cake_tpu.ops.pallas import selective_scan as pallas_scan
+from cake_tpu.ops.pallas import selective_step as pallas_step
 from cake_tpu.ops.quant import qmat
 
 # Steps of the prefill scan whose decay/input terms are built at once:
@@ -81,13 +87,20 @@ def causal_conv(
     return out
 
 
-def window_at(padded: jnp.ndarray, ends: jnp.ndarray, k1: int) -> jnp.ndarray:
+def window_at(
+    padded: jnp.ndarray, ends: jnp.ndarray | None, k1: int
+) -> jnp.ndarray:
     """The convolution's window after each row's last live token: inputs at
     positions ends-(K-1) .. ends-1 of the chunk (``ends`` [b], one past the
     last live position), reaching into the old window for a row shorter
-    than K-1. [K-1, b, d]."""
-    idx = ends[:, None] + jnp.arange(k1, dtype=jnp.int32)[None, :]  # [b, K-1]
-    picked = jnp.take_along_axis(padded, idx[:, :, None], axis=1)
+    than K-1. [K-1, b, d]. ``ends`` None = every row's last position is the
+    chunk's last (a decode step): the window is ``padded``'s tail, a static
+    slice where the general form gathers at indices that are constants."""
+    if ends is None:
+        picked = padded[:, padded.shape[1] - k1:]
+    else:
+        idx = ends[:, None] + jnp.arange(k1, dtype=jnp.int32)[None, :]  # [b, K-1]
+        picked = jnp.take_along_axis(padded, idx[:, :, None], axis=1)
     return jnp.moveaxis(picked, 1, 0)
 
 
@@ -149,6 +162,45 @@ def selective_scan(
     return y[:, :length], s
 
 
+def _inputs(lp, h, conv, live, ends, eps):
+    """Everything of the mixer before the recurrence: (u, dt [b, L, d], a
+    [n, d], B, C [b, L, n], all float32; z [b, L, d]; the convolution's new
+    window)."""
+    n, d = lp["A_log"].shape  # stored [n, d]
+    with jax.named_scope(MIXER_IN):
+        uz = qmat(h, lp["in_proj"])
+        u_in = jnp.where(live[:, :, None], uz[..., :d], 0).astype(h.dtype)
+        z = uz[..., d:]
+        padded = with_window(u_in, conv)
+        u = jax.nn.silu(causal_conv(padded, lp["conv_w"], lp["conv_b"]))
+        dbc = qmat(u.astype(h.dtype), lp["x_proj"])
+        r = dbc.shape[-1] - 2 * n
+        dt_r = rms_norm(dbc[..., :r], lp["dt_ln"], eps)
+        b_in = rms_norm(dbc[..., r : r + n], lp["b_ln"], eps).astype(jnp.float32)
+        c_out = rms_norm(dbc[..., r + n :], lp["c_ln"], eps).astype(jnp.float32)
+        dt = jax.nn.softplus(
+            qmat(dt_r, lp["dt_proj"]).astype(jnp.float32)
+            + lp["dt_bias"].astype(jnp.float32)
+        )
+        dt = jnp.where(live[:, :, None], dt, 0.0)
+        a = -jnp.exp(lp["A_log"].astype(jnp.float32))
+    with jax.named_scope(CACHE_WRITE):
+        new_conv = window_at(padded, ends, conv.shape[0]).astype(conv.dtype)
+        # A row without a live position (a dead lane of a decode dispatch) read
+        # dt = 0 and u_in = 0 above, so ``s`` is its old state already; its
+        # window would shift in a zero, so it is kept explicitly.
+        touched = jnp.any(live, axis=1)
+        new_conv = jnp.where(touched[None, :, None], new_conv, conv)
+    return u, dt, a, b_in, c_out, z, new_conv
+
+
+def _gated(lp, y, u, z, dtype):
+    """(y + D u) * silu(z) [b, L, d]."""
+    with jax.named_scope(MIXER_OUT):
+        y = y + lp["D"].astype(jnp.float32) * u
+        return (y * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
+
+
 def mixer_forward(
     lp: dict,
     h: jnp.ndarray,  # [b, L, hidden] input-normed
@@ -167,23 +219,7 @@ def mixer_forward(
     live position keeps its state bit for bit."""
     d = ssm.shape[-1]
     n = ssm.shape[-2]
-    with jax.named_scope(MIXER_IN):
-        uz = qmat(h, lp["in_proj"])
-        u_in = jnp.where(live[:, :, None], uz[..., :d], 0).astype(h.dtype)
-        z = uz[..., d:]
-        padded = with_window(u_in, conv)
-        u = jax.nn.silu(causal_conv(padded, lp["conv_w"], lp["conv_b"]))
-        dbc = qmat(u.astype(h.dtype), lp["x_proj"])
-        r = dbc.shape[-1] - 2 * n
-        dt_r = rms_norm(dbc[..., :r], lp["dt_ln"], eps)
-        b_in = rms_norm(dbc[..., r : r + n], lp["b_ln"], eps).astype(jnp.float32)
-        c_out = rms_norm(dbc[..., r + n :], lp["c_ln"], eps).astype(jnp.float32)
-        dt = jax.nn.softplus(
-            qmat(dt_r, lp["dt_proj"]).astype(jnp.float32)
-            + lp["dt_bias"].astype(jnp.float32)
-        )
-        dt = jnp.where(live[:, :, None], dt, 0.0)
-        a = -jnp.exp(lp["A_log"].astype(jnp.float32))  # stored [n, d]
+    u, dt, a, b_in, c_out, z, new_conv = _inputs(lp, h, conv, live, ends, eps)
     with jax.named_scope(MIXER):
         if h.shape[1] == 1:
             # Decode: the same equations for one t, no chunking.
@@ -206,16 +242,33 @@ def mixer_forward(
                     y, s = selective_scan(
                         u, dt, a, b_in, c_out, ssm, chunk, (lo, hi)
                     )
-    with jax.named_scope(MIXER_OUT):
-        y = y + lp["D"].astype(jnp.float32) * u
-        gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype)
-    with jax.named_scope(CACHE_WRITE):
-        if ends is None:
-            ends = jnp.full((h.shape[0],), h.shape[1], jnp.int32)
-        new_conv = window_at(padded, ends, conv.shape[0]).astype(conv.dtype)
-        # A row without a live position (a dead lane of a decode dispatch) read
-        # dt = 0 and u_in = 0 above, so ``s`` is its old state already; its
-        # window would shift in a zero, so it is kept explicitly.
-        touched = jnp.any(live, axis=1)
-        new_conv = jnp.where(touched[None, :, None], new_conv, conv)
-    return gated, s, new_conv
+    return _gated(lp, y, u, z, h.dtype), s, new_conv
+
+
+def steps_in_place(ssm: jnp.ndarray) -> bool:
+    """Whether the one-token update of a layer stack's state [n_state, b,
+    d_state, d_inner] is the Pallas kernel's (widths that tile), given the
+    switch."""
+    return pallas_step.tiles(ssm.shape[-1], ssm.shape[-2])
+
+
+def mixer_step_stacked(
+    lp: dict,
+    h: jnp.ndarray,  # [b, 1, hidden] input-normed
+    ssm: jnp.ndarray,  # [n_state, b, n, d] float32: the whole stack
+    layer: jnp.ndarray,  # which of the stack's layers this is (traced)
+    conv: jnp.ndarray,  # [K-1, b, d] this layer's window
+    live: jnp.ndarray,  # [b, 1] bool
+    eps: float,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``mixer_forward`` for ``L == 1`` over the STACK's state in place
+    (``ops/delta_rule.mixer_step_stacked``'s contract): the kernel is handed
+    the whole array and the layer's index, reads and writes that layer's
+    rows once, and leaves the others where they are. (gated, the stack,
+    conv')."""
+    u, dt, a, b_in, c_out, z, new_conv = _inputs(lp, h, conv, live, None, eps)
+    with jax.named_scope(MIXER):
+        y, ssm = pallas_step.selective_step(
+            ssm, layer, u[:, 0], dt[:, 0], a, b_in[:, 0], c_out[:, 0]
+        )
+    return _gated(lp, y[:, None], u, z, h.dtype), ssm, new_conv

@@ -551,7 +551,7 @@ class _PagedBackend:
         pool (zeros for a model without state layers). ``bytes`` is what
         the current epoch's lanes hold; ``lane_writes`` is cumulative."""
         from cake_tpu.models.llama.config import STATE
-        from cake_tpu.models.llama.hybrid import window_form
+        from cake_tpu.models.llama.hybrid import step_form, window_form
 
         per_lane = self.config.state_bytes_per_lane
         layers = len(self.config.layers_of(STATE))
@@ -562,6 +562,9 @@ class _PagedBackend:
             # the form every window (a prefill, a join) of their recurrence
             # takes: "pallas" or "xla", from the widths and the kernel switch
             "window_form": window_form(self.config, self.allow_pallas),
+            # the form a decode step's one-token update takes: "pallas" (the
+            # state read once and written once, in place) or "xla"
+            "step_form": step_form(self.config, self.allow_pallas),
             "bytes_per_lane": per_lane,
             "bytes": per_lane * self._state_lanes,
             "lane_writes": self.state_lane_writes,

@@ -433,6 +433,7 @@ def child_hybrid(preset: dict) -> None:
         run_hybrid_checks,
         timed_paged_decode,
         timed_selective_scan,
+        timed_selective_step,
     )
     from cake_tpu.utils.device import describe_devices, setup_compile_cache
 
@@ -466,6 +467,10 @@ def child_hybrid(preset: dict) -> None:
         "d_inner": config.mamba_d_inner, "d_state": config.mamba_d_state,
         **g.get("timed_scan", {}),
     })})
+    emit({"kind": "step", "rows": timed_selective_step(
+        config.mamba_d_inner, config.mamba_d_state, g["lanes"],
+        len(config.layers_of("state")),
+    )})
     reports = pool_audit.audit_hybrid_programs(
         config, n_pages=g["pages"], page_size=preset["page_size"],
         lanes=g["lanes"], table_pages=g["table_pages"], n_steps=g["steps"],
@@ -1062,8 +1067,8 @@ def phase_pool(args, preset) -> dict:
 
 def phase_hybrid(args, preset) -> dict:
     """Phase H: a model with state layers at the benchmark cell's geometry
-    (jamba2-3b-chat-closed): kernel cases, the prefill scan's kernel beside
-    its twin, then the compiled programs."""
+    (jamba2-3b-chat-closed): kernel cases, the prefill scan's kernel and the
+    one-token update's beside their twins, then the compiled programs."""
     records = run_child("hybrid", args, timeout=1200)
     problems = _say_cases("H", records, args)
     _say_timed_decode("H", records, args)
@@ -1078,6 +1083,14 @@ def phase_hybrid(args, preset) -> dict:
             problems.append(
                 f"selective_scan {r['rows']} x {r['length']} differs from "
                 "its twin")
+    for r in next(r for r in records if r["kind"] == "step")["rows"]:
+        times = "" if args.rehearse_cpu else (
+            f": twin {r['twin_us']} us a call, kernel {r.get('kernel_us')} "
+            f"for a floor of {r['floor_us']}")
+        errs = "".join(f" {k}={r[k]:.3g}" for k in ("err_y", "err_s") if k in r)
+        say(f"phase=H selective_step alone, {r['rows']} rows{errs}{times}")
+        if max(r.get("err_y", 0.0), r.get("err_s", 0.0)) > 1e-4:
+            problems.append("selective_step differs from its twin")
     out = {"cases": sum(r["kind"] == "case" for r in records)}
     out.update(_say_programs("H", records, args, problems))
     if problems:

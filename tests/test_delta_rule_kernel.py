@@ -255,19 +255,22 @@ def test_window_form_is_a_fact_of_the_widths_and_the_switch(widths, impl, allow,
     facts = state_facts(config, allow)
     assert facts["mixer"] == "gated_delta" and facts["window_form"] == form
     assert H.window_form(config, allow) == form
+    # the one-token update's kernel follows the same switch and tiles here too
+    assert facts["step_form"] == H.step_form(config, allow) == form
 
 
 @pytest.mark.parametrize("d_state,form", [(4, "xla"), (8, "pallas")])
 def test_a_jamba_shaped_state_is_unchanged_but_for_the_key(d_state, form):
-    """A vector state's ``engine.state`` as PR 34 left it, and the new key
-    beside it: its scan kernel takes ``d_inner`` 128 at whole sublane tiles
-    of ``d_state``."""
+    """A vector state's ``engine.state`` as PR 34 left it, and the new keys
+    beside it: its scan kernel and (PR 42) its step kernel take ``d_inner``
+    128 at whole sublane tiles of ``d_state``."""
     config = dataclasses.replace(
         LlamaConfig.from_hf_dict({**JAMBA_HF, "mamba_d_state": d_state}),
         attention_impl="pallas",
     )
     facts = state_facts(config)
     assert facts.pop("window_form") == form
+    assert facts.pop("step_form") == form
     assert facts == {
         "layers": 6, "mixer": config.state_mixer,
         "bytes_per_lane": config.state_bytes_per_lane, "bytes": 0,
@@ -278,4 +281,4 @@ def test_a_jamba_shaped_state_is_unchanged_but_for_the_key(d_state, form):
 def test_a_model_without_state_layers_has_no_window_form():
     facts = state_facts(LlamaConfig.tiny(num_hidden_layers=2))
     assert facts["layers"] == 0 and facts["mixer"] is None
-    assert facts["window_form"] is None
+    assert facts["window_form"] is None and facts["step_form"] is None

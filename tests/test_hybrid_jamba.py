@@ -69,6 +69,69 @@ def backend(config, params, **kw):
     return be
 
 
+# Widths at which the state layers' kernels engage: d_inner 128 is one lane
+# tile, d_state 8 one sublane tile (the tiny model's 4 is none: the twins').
+TILING = {**HF, "mamba_d_state": 8}
+STEP_FORMS = ("xla", "pallas")
+
+
+@pytest.fixture(scope="module")
+def tiling_model():
+    config = LlamaConfig.from_hf_dict(TILING)
+    return config, H.init_params(config, jax.random.PRNGKey(0), jnp.float32)
+
+
+def backend_of_form(config, params, step_form):
+    """A backend whose decode steps take ``step_form``: every kernel on
+    (interpreted here; pages of 128 slots, the kernels' page) or every twin."""
+    if step_form == "xla":
+        be = backend(config, params)
+    else:
+        be = paged_backend(
+            dataclasses.replace(config, attention_impl="pallas"), params,
+            max_seq_len=256, cache_dtype=jnp.float32, page_size=128,
+            max_pages=16, allow_pallas=True,
+        )
+    assert be.state_facts()["step_form"] == step_form
+    return be
+
+
+def window_gathers(traced, config, lanes) -> list:
+    """The ``gather`` equations of a traced program, nested jaxprs included,
+    whose operand is a state layer's convolution input with its window in
+    front ([lanes, K-1 + 1, channels]: ``ops/ssm.with_window`` at one
+    position)."""
+    kept, channels = config.conv_window
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "gather"
+                    and tuple(eqn.invars[0].aval.shape) == (lanes, kept + 1, channels)):
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(traced.jaxpr.jaxpr)
+    return found
+
+
+def decode_program(config, lanes=3, allow_pallas=False):
+    """A decode chunk of 4 steps over ``lanes`` rows, traced from shapes."""
+    spec = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt)
+    abstract = lambda build: jax.tree.map(
+        lambda a: spec(a.shape, a.dtype), jax.eval_shape(build))
+    params = abstract(lambda: H.init_params(config, jax.random.PRNGKey(0), jnp.float32))
+    cache = abstract(lambda: H.init_hybrid_cache(config, lanes, 8, PAGE, jnp.float32))
+    fn = H._hybrid_decode_fn(config, 8 * PAGE, 4, 0.0, None, None, 1.0,
+                             allow_pallas=allow_pallas)
+    return fn._jitted.trace(
+        params, cache, spec((lanes,)), spec(()), spec((lanes,)), spec((lanes, 8)),
+        spec((lanes,), jnp.bool_), spec((lanes, 2), jnp.uint32), spec((lanes, 0)),
+        spec((lanes,)),
+    )
+
+
 def with_shapes(be, **fields):
     """The backend's own instance with some of its tables replaced."""
     be.shapes = dataclasses.replace(be.shapes, **fields)
@@ -281,9 +344,10 @@ def test_warm_programs_runs_the_closed_set_and_leaves_nothing(model, monkeypatch
 # --------------------------------------------------------- (5) dead lanes
 
 
-def test_a_lane_that_is_not_live_keeps_its_state(model):
-    config, _, loaded, *_ = model
-    be = backend(config, loaded)
+@pytest.mark.parametrize("step_form", STEP_FORMS)
+def test_a_lane_that_is_not_live_keeps_its_state(tiling_model, step_form):
+    config, params = tiling_model
+    be = backend_of_form(config, params, step_form)
     cache, tokens, pads = lay_out(be, prompts(4, 18, 27), 4, 32)
     logits, cache = be.prefill(tokens, cache, jnp.asarray(pads))
     be.allocator.release(1)  # lane 1's request ended; lanes 2, 3 never lived
@@ -294,6 +358,102 @@ def test_a_lane_that_is_not_live_keeps_its_state(model):
     np.testing.assert_array_equal(after[0][:, 1:], before[0][:, 1:])
     np.testing.assert_array_equal(after[1][:, :, 1:], before[1][:, :, 1:])
     assert not np.array_equal(after[0][:, 0], before[0][:, 0])
+
+
+def test_the_in_place_path_equals_the_twins_path(tiling_model):
+    """A prefill and 16 decode steps with the state stepped in place by the
+    kernel (``ops/pallas/selective_step.py``, the stack whole) and with the
+    twin over a layer's slice: float32 both, the sums over ``d_state`` and a
+    window's scan in another order. Logits of spread 0.3 agree to 2e-5, the
+    served tokens are the same, and so is what the lanes hold after."""
+    config, params = tiling_model
+    rows = prompts(7, 23, 31)
+    out = {}
+    for form in STEP_FORMS:
+        be = backend_of_form(config, params, form)
+        cache, tokens, pads = lay_out(be, rows, 3, 32)
+        logits, cache = be.prefill(tokens, cache, jnp.asarray(pads))
+        tok = np.asarray(logits).argmax(-1).astype(np.int32)
+        served, slot = [], 32
+        for _ in range(2):
+            toks, cache = decode(be, cache, tok, slot, pads, 8, live=(0, 1))
+            served.append(toks[:2])
+            tok, slot = toks[:, -1], slot + 8
+        out[form] = (np.asarray(logits[:2]), np.concatenate(served, 1),
+                     np.asarray(cache.ssm[:, :2]), np.asarray(cache.conv[:, :, :2]))
+    near = dict(rtol=0, atol=2e-5)
+    np.testing.assert_allclose(out["pallas"][0], out["xla"][0], **near)
+    np.testing.assert_array_equal(out["pallas"][1], out["xla"][1])
+    np.testing.assert_allclose(out["pallas"][2], out["xla"][2], **near)
+    np.testing.assert_allclose(out["pallas"][3], out["xla"][3], **near)
+    assert np.abs(out["xla"][2]).max() > 0.01 and out["xla"][1].shape == (2, 16)
+
+
+# ------------------------------------------- (5b) the window's shift, decode
+
+
+def gathered_window(padded, k1):
+    """``ops/ssm.window_at`` as it was for a decode step before PR 42: a
+    gather at every row's ``ends`` = the chunk's length."""
+    ends = jnp.full((padded.shape[0],), padded.shape[1] - k1, jnp.int32)
+    return S.window_at(padded, ends, k1)
+
+
+def test_a_decode_step_shifts_the_window_by_a_slice(tiling_model):
+    """With ``ends`` None the window after a step is ``padded``'s tail, bit
+    for bit what the gather at constant indices gave; a decode program holds
+    no gather over a window (a join, ``ends`` given, keeps it)."""
+    rng = np.random.default_rng(0)
+    padded = jnp.asarray(rng.normal(size=(5, 4, 128)), jnp.float32)
+    np.testing.assert_array_equal(
+        S.window_at(padded, None, 3), gathered_window(padded, 3))
+    long = jnp.asarray(rng.normal(size=(2, 3 + 9, 128)), jnp.float32)
+    np.testing.assert_array_equal(
+        S.window_at(long, None, 3), gathered_window(long, 3))
+    for config in (LlamaConfig.from_hf_dict(HF), tiling_model[0]):
+        assert window_gathers(decode_program(config), config, 3) == []
+    # the reader finds the gathered form where it is
+    kept, channels = tiling_model[0].conv_window
+    old = jax.jit(lambda p: gathered_window(p, kept)).trace(
+        jax.ShapeDtypeStruct((3, kept + 1, channels), jnp.float32))
+    assert len(window_gathers(old, tiling_model[0], 3)) == 1
+
+
+def state_after_a_dispatch(make_backend, monkeypatch, gathered):
+    """(conv, ssm) after a prefill and one decode dispatch of 8 steps over
+    four lanes, two of them live; with ``gathered`` the decode steps' window
+    is taken by the gather that ``window_at`` was before PR 42."""
+    with monkeypatch.context() as mp:
+        if gathered:
+            real = S.window_at
+            mp.setattr(
+                S, "window_at",
+                lambda padded, ends, k1: real(padded, ends, k1) if ends is not None
+                else gathered_window(padded, k1))
+        jax.clear_caches()  # the decode program is traced anew under the patch
+        H._hybrid_decode_fn.cache_clear()
+        be = make_backend()
+        cache, tokens, pads = lay_out(be, prompts(4, 18, 27), 4, 32)
+        logits, cache = be.prefill(tokens, cache, jnp.asarray(pads))
+        tok = np.asarray(logits).argmax(-1).astype(np.int32)
+        _, cache = decode(be, cache, tok, 32, pads, 8, live=(0, 1))
+        out = jax.tree.map(np.asarray, (cache.conv, cache.ssm))
+    jax.clear_caches()
+    H._hybrid_decode_fn.cache_clear()
+    return out
+
+
+def test_conv_after_a_step_equals_the_gathered_forms(model, monkeypatch):
+    """One decode dispatch of the tiny model with ``window_at`` as it is and
+    with the gather in its place: ``cache.conv`` (and ``cache.ssm``) equal
+    bit for bit, dead lanes included."""
+    config, _, loaded, *_ = model
+    make = lambda: backend(config, loaded)
+    sliced = state_after_a_dispatch(make, monkeypatch, gathered=False)
+    jax.tree.map(
+        np.testing.assert_array_equal, sliced,
+        state_after_a_dispatch(make, monkeypatch, gathered=True))
+    assert np.abs(sliced[0]).max() > 0
 
 
 # ----------------------------------------------------- (6) the chunked scan

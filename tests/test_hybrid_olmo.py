@@ -46,8 +46,9 @@ from cake_tpu.runtime.serving import BatchEngine, ServeConfig
 # The engine's epoch layout, a decode dispatch, the engine fixture and the
 # table of refused command lines are Jamba's tests' (one hybrid stack, two
 # mixers): what is refused for one is refused for the other.
-from test_hybrid_jamba import (GREEDY, PAGE, REFUSED, collect, decode, engine, lay_out,
-                               prompts)
+from test_hybrid_jamba import (GREEDY, PAGE, REFUSED, collect, decode, decode_program,
+                               engine, lay_out, prompts, state_after_a_dispatch,
+                               window_gathers)
 
 REPO = Path(__file__).resolve().parents[1]
 HF = dict(
@@ -336,6 +337,20 @@ def test_a_lane_that_is_not_live_keeps_its_state(model):
     np.testing.assert_array_equal(after[0][:, 1:], before[0][:, 1:])
     np.testing.assert_array_equal(after[1][:, :, 1:], before[1][:, :, 1:])
     assert not np.array_equal(after[0][:, 0], before[0][:, 0])
+
+
+def test_a_decode_step_shifts_the_window_by_a_slice(model, monkeypatch):
+    """The delta rule's window (q | k | v, ``D._inputs``) goes through the
+    same helper as Jamba's: no gather over it in a decode program, and
+    ``cache.conv`` after a dispatch is the gathered form's bit for bit."""
+    config, _, loaded, *_ = model
+    assert window_gathers(decode_program(config), config, 3) == []
+    make = lambda: backend(config, loaded)
+    sliced = state_after_a_dispatch(make, monkeypatch, gathered=False)
+    jax.tree.map(
+        np.testing.assert_array_equal, sliced,
+        state_after_a_dispatch(make, monkeypatch, gathered=True))
+    assert np.abs(sliced[0]).max() > 0
 
 
 def test_an_epoch_prefill_in_groups_equals_one_program(model):

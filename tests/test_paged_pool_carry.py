@@ -471,7 +471,9 @@ def test_pool_write_compiles_for_v5e(rows, n_kv, width, one_chip):
 # the 2 attention layers AND the lane state of the 26 state layers ride the
 # scans' carries (models/llama/hybrid.py). The same compile also shows that
 # Mosaic takes the paged kernels at one KV head under a group of 20 query
-# heads (a block of 20 rows): both are in each program.
+# heads (a block of 20 rows): both are in each program; and (PR 42) the
+# one-token update's kernel (ops/pallas/selective_step.py) at [16, 5120] a
+# row, eight rows a grid step, handed the layer stack's state whole.
 
 
 @pytest.fixture(scope="module")
@@ -497,11 +499,12 @@ def test_hybrid_cell_compiles_for_v5e_without_pool_or_state_copies(
     report = hybrid_reports[program]
     assert report["scans"] == [] and report["pool_ops"] == [], report
     assert report["state_scans"] == [] and report["state_copies"] == [], report
-    # the pool's write and the attention, one each a layer of attention; a
-    # join's prefill scan is one more in each of the three scanned runs of
-    # state layers
+    # the pool's write and the attention, one each a layer of attention (4);
+    # each of the three scanned runs of state layers holds one more: a join's
+    # prefill scan, and since PR 42 a decode step's one-token update
+    # (``selective_step``; 4 before it, the update then in XLA)
     assert report["pool_writes"] == 2, report
-    assert report["kernels"] == (4 if program == "decode" else 7), report
+    assert report["kernels"] == 7, report
     # 298 MB of state, 134 MB of pool: a second copy of either would show
     assert report["state_bytes"] == 32 * 9_318_400
     assert report["temp_bytes"] < report["state_bytes"] // 2, report

@@ -14,6 +14,7 @@ away: a scope is metadata and nothing else.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import re
 
@@ -230,6 +231,29 @@ def test_every_operation_sits_under_one_part(family, program):
                 assert parts_of(name) == [part], name
                 assert path.index(part) < path.index(scope), name
     assert older == HOLDS.get((family, program), set())
+
+
+def test_the_in_place_step_sits_inside_mixer():
+    """Jamba's decode program at widths that tile (``d_state`` 8 is a sublane
+    tile, ``d_inner`` 128 a lane tile) with the kernel switch on: the
+    one-token update is ``selective_step`` (PR 42), every operation of it
+    under ``mixer`` and no other part, and the twin's update is not there."""
+    config = dataclasses.replace(
+        LlamaConfig.from_hf_dict({**JAMBA, "mamba_d_state": 8}),
+        attention_impl="pallas",
+    )
+    geometry = {**GEOMETRY, "page_size": 128, "n_pages": 8, "table_pages": 2}
+    traced = served_program(config, "decode", **geometry, allow_pallas=True)
+    names = operation_names(traced.lower().compiler_ir())
+    step = [name for _, name in names if "jit(selective_step)" in name.split("/")]
+    assert step and {tuple(parts_of(name)) for name in step} == {(MIXER,)}
+    assert H.step_form(config, True) == "pallas"
+    twin = served_program(config, "decode", **geometry, allow_pallas=False)
+    assert not [
+        name for _, name in operation_names(twin.lower().compiler_ir())
+        if "selective_step" in name
+    ]
+    assert H.step_form(config, False) == "xla"
 
 
 def test_the_first_token_is_sampled_under_sample():
