@@ -1714,6 +1714,7 @@ def main(argv: list[str] | None = None) -> int:
                 topology=topology is not None or args.backend is not None,
                 distributed=bool(args.distributed),
                 quantize=bool(args.quantize),
+                kv_dtype_narrow=jnp.dtype(kv_dtype).itemsize < jnp.dtype(dtype).itemsize,
             )
         except UnsupportedForCacheKind as e:
             print(f"cake-tpu: {e}", file=sys.stderr)

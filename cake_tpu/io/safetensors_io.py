@@ -26,7 +26,7 @@ import numpy as np
 
 from cake_tpu.models.llama.capability import refuse_unsupported
 from cake_tpu.models.llama.config import (
-    CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, LlamaConfig,
+    CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, CACHE_LATENT_INDEX, LlamaConfig,
 )
 from cake_tpu.models.llama.model import Params
 
@@ -579,8 +579,36 @@ _LATENT_VECTORS = {
     "ln_mlp": "pre_mlp_layernorm.weight",
     "ln_post_mlp": "post_mlp_layernorm.weight",
 }
+# ``model_type: deepseek_v32``: one norm on each branch's INPUT (HF's
+# ``post_attention_layernorm`` is the feed-forward's), the learned index's
+# tensors under ``self_attn.indexer`` and the router's correction bias.
+_LATENT_VECTORS_PRE_NORM = {
+    "q_a_ln": "self_attn.q_a_layernorm.weight",
+    "kv_a_ln": "self_attn.kv_a_layernorm.weight",
+    "ln_attn": "input_layernorm.weight",
+    "ln_mlp": "post_attention_layernorm.weight",
+}
+_INDEX_MATRICES = {
+    "wi_q": "self_attn.indexer.wq_b.weight", "wi_k": "self_attn.indexer.wk.weight",
+    "wi_w": "self_attn.indexer.weights_proj.weight",
+}
+_INDEX_VECTORS = {
+    "i_k_ln": "self_attn.indexer.k_norm.weight",
+    "i_k_ln_b": "self_attn.indexer.k_norm.bias",
+}
+_ROUTER_BIAS = "mlp.gate.e_score_correction_bias"
 _LATENT_KV_B = "self_attn.kv_b_proj.weight"
 _SWIGLU = {"gate": "gate_proj.weight", "up": "up_proj.weight", "down": "down_proj.weight"}
+
+
+def _latent_names(config: LlamaConfig) -> tuple[dict[str, str], dict[str, str]]:
+    """(matrices, vectors) of a latent layer's attention, by the config."""
+    matrices = dict(_LATENT_MATRICES)
+    vectors = dict(_LATENT_VECTORS if config.post_block_norms else _LATENT_VECTORS_PRE_NORM)
+    if config.index_topk:
+        matrices.update(_INDEX_MATRICES)
+        vectors.update(_INDEX_VECTORS)
+    return matrices, vectors
 
 
 def _read_feed_forward(reader: SafetensorsReader, config: LlamaConfig, p: str, sparse: bool, dtype, shared: str) -> Params:
@@ -592,6 +620,8 @@ def _read_feed_forward(reader: SafetensorsReader, config: LlamaConfig, p: str, s
         return {f"w_{k}": reader.jax(f"{p}mlp.{name}", dtype, transpose=True)
                 for k, name in _SWIGLU.items()}
     out = {"router": reader.jax(p + "mlp.gate.weight", dtype, transpose=True)}
+    if config.router_bias:
+        out["router_bias"] = reader.jax(p + _ROUTER_BIAS, dtype)
     held = range(config.expert_offset, config.expert_offset + config.num_local_experts)
     for k, name in _SWIGLU.items():
         out[f"w_{k}"] = jnp.stack([
@@ -614,12 +644,15 @@ def _put_feed_forward(put, run: Params, k: int, config: LlamaConfig, p: str, spa
             put(f"{p}mlp.{shared}.{name}", run[f"sh_{key}"][k], True)
     if sparse:
         put(p + "mlp.gate.weight", run["router"][k], True)
+        if "router_bias" in run:
+            put(p + _ROUTER_BIAS, run["router_bias"][k])
 
 
 def _latent_layer(reader: SafetensorsReader, config: LlamaConfig, i: int, sparse: bool, dtype) -> Params:
     p = f"model.layers.{i}."
-    out = {k: reader.jax(p + n, dtype, transpose=True) for k, n in _LATENT_MATRICES.items()}
-    out.update({k: reader.jax(p + n, dtype) for k, n in _LATENT_VECTORS.items()})
+    matrices, vectors = _latent_names(config)
+    out = {k: reader.jax(p + n, dtype, transpose=True) for k, n in matrices.items()}
+    out.update({k: reader.jax(p + n, dtype) for k, n in vectors.items()})
     n, nope = config.num_attention_heads, config.qk_nope_head_dim
     kv_b = reader.jax(p + _LATENT_KV_B, dtype).reshape(n, -1, config.kv_lora_rank)
     out["w_uk"] = jnp.swapaxes(kv_b[:, :nope], 1, 2)  # [heads, rank, nope]
@@ -654,12 +687,13 @@ def latent_tensor_dict(params: Params, config: LlamaConfig, dtype) -> dict[str, 
         a = np.asarray(a.astype(dtype))
         tensors[name] = (a.T if transpose else a).copy()
 
+    matrices, vectors = _latent_names(config)
     for run, (kind, lo, hi) in zip(params["layers"], config.ff_runs):
         for k, i in enumerate(range(lo, hi)):
             p = f"model.layers.{i}."
-            for key, name in _LATENT_MATRICES.items():
+            for key, name in matrices.items():
                 put(p + name, run[key][k], True)
-            for key, name in _LATENT_VECTORS.items():
+            for key, name in vectors.items():
                 put(p + name, run[key][k])
             kv_b = jnp.concatenate([run["w_uk"][k], run["w_uv"][k]], axis=-1)  # [n, rank, nope+v]
             put(p + _LATENT_KV_B, jnp.swapaxes(kv_b, 1, 2).reshape(-1, config.kv_lora_rank))
@@ -762,10 +796,10 @@ def load_params(
             "layers": load_hybrid_layers(reader, config, dtype),
             "ln_f": reader.jax(_HYBRID_TABLES[config.model_type][1], dtype),
         }
-    elif config.cache_kind in (CACHE_LATENT, CACHE_KV_KINDS):
+    elif config.cache_kind in (CACHE_LATENT, CACHE_LATENT_INDEX, CACHE_KV_KINDS):
         by_run = (
-            load_latent_layers if config.cache_kind == CACHE_LATENT
-            else load_kinds_layers
+            load_kinds_layers if config.cache_kind == CACHE_KV_KINDS
+            else load_latent_layers
         )
         params = {
             "embed": reader.jax("model.embed_tokens.weight", dtype),
@@ -823,7 +857,7 @@ def hf_tensor_dict(
     (bit-identical round trip, tests/test_quantized_checkpoint.py)."""
     if config.cache_kind == CACHE_KV_STATE:
         return hybrid_tensor_dict(params, config, dtype)
-    if config.cache_kind == CACHE_LATENT:
+    if config.cache_kind in (CACHE_LATENT, CACHE_LATENT_INDEX):
         return latent_tensor_dict(params, config, dtype)
     if config.cache_kind == CACHE_KV_KINDS:
         return kinds_tensor_dict(params, config, dtype)

@@ -7,7 +7,8 @@ the distributed workers' block ranges, weight quantisation's expert and
 projection tables, the single-stream generator. A model whose lanes keep
 something else (``config.cache_kind``: a recurrent state beside K and V,
 whichever mixer keeps it (Jamba's Mamba-1, Olmo-Hybrid's gated delta rule), one
-latent a token in place of them, or K and V in a pool a KIND of attention layer
+latent a token in place of them (Pangu's; beside the key of a learned index
+that chooses what attention reads: DeepSeek-V3.2's), or K and V in a pool a KIND of attention layer
 with a windowed kind's pages freed behind the window: Laguna's) is served by its
 own paged leaf
 (``runtime/batch_backend.paged_backend``) on one chip, and everything else is
@@ -21,7 +22,8 @@ the message names the feature as a user would have written it.
 from __future__ import annotations
 
 from cake_tpu.models.llama.config import (
-    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, SLIDING, STATE, LlamaConfig,
+    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT_INDEX, SLIDING, STATE,
+    LlamaConfig,
 )
 
 
@@ -47,6 +49,11 @@ REFUSED = {
     "layer_range": "a worker's layer range (--topology)",
     "split_model": "cake-split-model",
 }
+# Refused over one cache kind only: a pool narrower than the activations
+# would round the index keys too, and with them which tokens are chosen.
+REFUSED_BY_KIND = {
+    CACHE_LATENT_INDEX: {"kv_dtype_narrow": "--kv-dtype narrower than --dtype"},
+}
 
 
 def _why(config: LlamaConfig) -> str:
@@ -66,6 +73,17 @@ def _why(config: LlamaConfig) -> str:
             "rewinds, shares, shards or re-packs one pool that holds every "
             "token of every layer"
         )
+    if config.cache_kind == CACHE_LATENT_INDEX:
+        return (
+            f"its {config.num_hidden_layers} layers keep one latent of "
+            f"{config.kv_lora_rank} + {config.qk_rope_head_dim} numbers a token "
+            f"and, behind the same block table, an index key of "
+            f"{config.index_head_dim} by which each query chooses the "
+            f"{config.index_topk} cached tokens it attends, and this feature "
+            "restores, rewinds, shares, shards or re-packs K and V a KV head "
+            "(a shared prefix would need both pools copied and a verify chunk "
+            "a choice a drafted position)"
+        )
     return (
         f"its {config.num_hidden_layers} layers keep one latent of "
         f"{config.kv_lora_rank} + {config.qk_rope_head_dim} numbers a token "
@@ -77,13 +95,14 @@ def _why(config: LlamaConfig) -> str:
 def refuse_unsupported(config: LlamaConfig, **facts: bool) -> None:
     """``facts`` maps names of ``REFUSED`` to whether the caller was asked
     for that feature; the first that was raises, for a model whose cache is
-    not plain K and V."""
+    not plain K and V (a fact of ``REFUSED_BY_KIND`` for its kind alone)."""
     if config.cache_kind == CACHE_KV:
         return
+    refused = {**REFUSED, **REFUSED_BY_KIND.get(config.cache_kind, {})}
     for fact, on in facts.items():
-        if on:
+        if on and fact in refused:
             raise UnsupportedForCacheKind(
-                f"{REFUSED[fact]} is not supported for model_type "
+                f"{refused[fact]} is not supported for model_type "
                 f"{config.model_type!r}: {_why(config)}. Serve it with --api "
                 "HOST:PORT --api-batch N (N > 1) --kv-mode paged "
                 "--prefix-cache off on one chip, unquantized."

@@ -331,6 +331,27 @@ def encode_dialog_laguna(messages: list[Message]) -> str:
     return "".join(parts)
 
 
+def encode_dialog_deepseek(messages: list[Message]) -> str:
+    """DeepSeek-V3 family template (written from memory of the published
+    chat template; the catalog row carries none): the system text bare
+    behind the begin-of-sentence word, a role word before each turn, an end
+    word behind an assistant's, and the prompt ends with the assistant's:
+
+        <｜begin▁of▁sentence｜>{sys}<｜User｜>{u}<｜Assistant｜>
+    """
+    parts = ["<｜begin▁of▁sentence｜>"]
+    for m in messages:
+        text = m.content.strip()
+        if m.role.value == "system":
+            parts.append(text)
+        elif m.role.value == "assistant":
+            parts.append(f"<｜Assistant｜>{text}<｜end▁of▁sentence｜>")
+        else:
+            parts.append(f"<｜User｜>{text}")
+    parts.append("<｜Assistant｜>")
+    return "".join(parts)
+
+
 # Template key -> dialog encoder. The generator picks by
 # config.dialog_template (the model family, or the --chat-template override);
 # the Llama-3 encoder is the reference-parity surface (history.rs), the
@@ -354,6 +375,7 @@ DIALOG_ENCODERS = {
     "pangu_ultra_moe": encode_dialog_pangu,
     "olmo_hybrid": encode_dialog_olmo,
     "laguna": encode_dialog_laguna,
+    "deepseek_v32": encode_dialog_deepseek,
 }
 
 

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from pathlib import Path
 from typing import Any
 
@@ -22,7 +23,7 @@ from typing import Any
 SUPPORTED_MODEL_TYPES = (
     "llama", "qwen2", "mistral", "mixtral", "qwen2_moe",
     "gemma", "gemma2", "phi3", "qwen3", "qwen3_moe", "gemma3_text", "jamba",
-    "pangu_ultra_moe", "olmo_hybrid", "laguna",
+    "pangu_ultra_moe", "olmo_hybrid", "laguna", "deepseek_v32",
 )
 
 # The two kinds a decoder layer's token mixer can be (``layer_kinds``).
@@ -43,6 +44,9 @@ FULL, SLIDING = "full", "sliding"
 # behind the window).
 CACHE_KV, CACHE_KV_STATE, CACHE_LATENT = "kv", "kv+state", "latent"
 CACHE_KV_KINDS = "kv+kinds"
+# A latent a token and, behind the SAME block table, the key of a learned
+# index that chooses which cached tokens a query attends (``deepseek_v32``).
+CACHE_LATENT_INDEX = "latent+index"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -222,6 +226,27 @@ class LlamaConfig:
     # the combine weights are multiplied by ``routed_scaling_factor``.
     moe_scoring: str = "softmax"
     routed_scaling_factor: float = 1.0
+    # Group-limited choice (the DeepSeek-V3 family's ``noaux_tc``): the ranked
+    # experts lie in ``n_group`` equal groups, a group's score is the sum of
+    # its two largest, the best ``topk_group`` groups stay and the experts are
+    # chosen inside them. ``router_bias``: a learned correction (a layer's
+    # ``router_bias`` [ranked]) added to the scores for CHOOSING only; the
+    # combine weights are the scores without it. 1 / False = neither.
+    n_group: int = 1
+    topk_group: int = 1
+    router_bias: bool = False
+    # A learned index over the cached tokens (``deepseek_v32``): a layer
+    # scores every cached token for each query with ``index_n_heads`` heads
+    # of ``index_head_dim`` against ONE index key a token, and attention
+    # reads the ``index_topk`` best and no other. 0 = attention reads all.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # The rotary term of a latent model's 64 rotary numbers when it is not
+    # plain ``rope_theta`` (YaRN: ``ops/rope.yarn_frequencies``), and the
+    # factor ``m`` whose square multiplies the score scale (``mla_scale``).
+    latent_rope: KindRope | None = None
+    latent_mscale: float = 1.0
     # Attention layers of more than one kind (``laguna``): per layer, which
     # kind (``attention_types``: FULL or SLIDING; None = one kind, the
     # family's), how many query heads (``heads_per_layer``; None =
@@ -322,7 +347,7 @@ class LlamaConfig:
         """What a lane keeps on the device: THE fact the backend's leaf, the
         programs' shapes and the capability check are chosen by."""
         if self.kv_lora_rank:
-            return CACHE_LATENT
+            return CACHE_LATENT_INDEX if self.index_topk else CACHE_LATENT
         if self.attention_types is not None:
             return CACHE_KV_KINDS
         return CACHE_KV_STATE if self.has_state_layers else CACHE_KV
@@ -368,6 +393,13 @@ class LlamaConfig:
         the shared rotary key, padded to whole 128-lane tiles (512 + 64 ->
         640: what is STORED; the padding is zeros)."""
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def mla_scale(self) -> float:
+        """Latent attention's score scale: of the expanded head, times the
+        square of YaRN's ``m`` where the rotary term is scaled."""
+        dims = self.qk_nope_head_dim + self.qk_rope_head_dim
+        return dims ** -0.5 * self.latent_mscale ** 2
 
     @property
     def n_router_experts(self) -> int:
@@ -484,6 +516,8 @@ class LlamaConfig:
             return cls._jamba_from_hf_dict(d, eos_ids)
         if model_type == "pangu_ultra_moe":
             return cls._pangu_from_hf_dict(d, eos_ids)
+        if model_type == "deepseek_v32":
+            return cls._deepseek_v32_from_hf_dict(d, eos_ids)
         if model_type == "olmo_hybrid":
             return cls._olmo_hybrid_from_hf_dict(d, eos_ids)
         if model_type == "laguna":
@@ -939,6 +973,22 @@ class LlamaConfig:
             raise ValueError(
                 "pangu_ultra_moe without sandwich_norm is not supported"
             )
+        return cls(
+            **cls._mla_share_fields(d, eos_ids, hidden=7680, vocab=153600,
+                                    eps=1e-5, theta=25600000.0, context=131072),
+            model_type="pangu_ultra_moe",
+            post_block_norms=True,
+        )
+
+    @classmethod
+    def _mla_share_fields(
+        cls, d: dict[str, Any], eos_ids: tuple[int, ...], *, hidden: int,
+        vocab: int, eps: float, theta: float, context: int,
+    ) -> dict[str, Any]:
+        """What the DeepSeek-V3 family's configs share, as constructor
+        fields: MLA's sizes, leading dense layers, sigmoid routing beside a
+        shared expert, and this repository's three keys for a rank's share of
+        the experts. The defaults that differ by model are the caller's."""
         held = int(d.get("n_routed_experts", 256))
         ranked = int(d.get("n_routed_experts_total", held))
         first = int(d.get("first_routed_expert", 0))
@@ -950,22 +1000,21 @@ class LlamaConfig:
             )
         heads = int(d.get("num_attention_heads", 128))
         moe_inter = int(d.get("moe_intermediate_size", 2048))
-        return cls(
-            hidden_size=int(d.get("hidden_size", 7680)),
+        return dict(
+            hidden_size=int(d.get("hidden_size", hidden)),
             intermediate_size=int(d.get("intermediate_size", 18432)),
-            vocab_size=int(d.get("vocab_size", 153600)),
+            vocab_size=int(d.get("vocab_size", vocab)),
             num_hidden_layers=int(d.get("num_hidden_layers", 61)),
             num_attention_heads=heads,
             num_key_value_heads=heads,
-            rms_norm_eps=float(d.get("rms_norm_eps", 1e-5)),
-            rope_theta=float(d.get("rope_theta", 25600000.0)),
+            rms_norm_eps=float(d.get("rms_norm_eps", eps)),
+            rope_theta=float(d.get("rope_theta", theta)),
             max_position_embeddings=int(
-                d.get("max_position_embeddings", 131072)
+                d.get("max_position_embeddings", context)
             ),
             bos_token_id=int(d.get("bos_token_id", 0)),
             eos_token_ids=eos_ids,
             tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
-            model_type="pangu_ultra_moe",
             head_dim_override=int(d.get("qk_nope_head_dim", 128))
             + int(d.get("qk_rope_head_dim", 64)),
             q_lora_rank=int(d.get("q_lora_rank", 1536)),
@@ -985,7 +1034,95 @@ class LlamaConfig:
             ),
             moe_scoring="sigmoid",
             routed_scaling_factor=float(d.get("routed_scaling_factor", 2.5)),
-            post_block_norms=True,
+        )
+
+    @classmethod
+    def _deepseek_v32_from_hf_dict(
+        cls, d: dict[str, Any], eos_ids: tuple[int, ...]
+    ) -> "LlamaConfig":
+        """``model_type: deepseek_v32`` (DeepSeek-V3.2-Exp): Pangu's latent
+        attention and expert share without the sandwich norms, with YaRN over
+        the rotary numbers (``rope_scaling``), group-limited routing with a
+        correction bias (``n_group``, ``topk_group``, ``topk_method:
+        noaux_tc``) and a learned index that chooses the ``index_topk``
+        cached tokens a query attends (``index_n_heads`` x
+        ``index_head_dim``)."""
+        if str(d.get("scoring_func", "sigmoid")) != "sigmoid":
+            raise ValueError(
+                f"deepseek_v32 with scoring_func={d['scoring_func']!r} is not "
+                "supported (sigmoid is)"
+            )
+        method = str(d.get("topk_method", "noaux_tc"))
+        if method != "noaux_tc":
+            raise ValueError(
+                f"deepseek_v32 with topk_method={method!r} is not supported "
+                "(noaux_tc is)"
+            )
+        fields = cls._mla_share_fields(
+            d, eos_ids, hidden=7168, vocab=129280, eps=1e-6, theta=10000.0,
+            context=163840,
+        )
+        n_group, topk_group = int(d.get("n_group", 8)), int(d.get("topk_group", 4))
+        ranked = fields["router_experts"]
+        if ranked % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(
+                f"{ranked} ranked experts in n_group={n_group} groups of which "
+                f"topk_group={topk_group} stay: the groups must be equal and "
+                "topk_group between 1 and n_group"
+            )
+        if fields["num_experts_per_tok"] > topk_group * (ranked // n_group):
+            raise ValueError(
+                "num_experts_per_tok exceeds the experts of topk_group groups"
+            )
+        rope, mscale = None, 1.0
+        scaling = d.get("rope_scaling")
+        if scaling:
+            kind = scaling.get("type", scaling.get("rope_type"))
+            if kind != "yarn":
+                raise ValueError(
+                    f"deepseek_v32 with rope_scaling type {kind!r} is not "
+                    "supported (yarn is)"
+                )
+            factor = float(scaling["factor"])
+            m, m_all = (
+                float(scaling.get(k, 1) or 1) for k in ("mscale", "mscale_all_dim")
+            )
+            if m != m_all:
+                raise ValueError(
+                    "deepseek_v32 with mscale != mscale_all_dim would scale cos "
+                    "and sin, which is not supported"
+                )
+            rope = KindRope(
+                theta=fields["rope_theta"],
+                rotary_dim=fields["qk_rope_head_dim"],
+                factor=factor,
+                original_max_position_embeddings=int(
+                    scaling["original_max_position_embeddings"]
+                ),
+                beta_fast=float(scaling.get("beta_fast", 32)),
+                beta_slow=float(scaling.get("beta_slow", 1)),
+            )
+            # YaRN's attention temperature, squared into the score scale.
+            if factor > 1:
+                mscale = 0.1 * m_all * math.log(factor) + 1.0
+        else:
+            rope = KindRope(
+                theta=fields["rope_theta"], rotary_dim=fields["qk_rope_head_dim"]
+            )
+        index_dim = int(d.get("index_head_dim", 128))
+        if index_dim < fields["qk_rope_head_dim"]:
+            raise ValueError("index_head_dim is smaller than qk_rope_head_dim")
+        return cls(
+            **fields,
+            model_type="deepseek_v32",
+            n_group=n_group,
+            topk_group=topk_group,
+            router_bias=True,
+            index_n_heads=int(d.get("index_n_heads", 64)),
+            index_head_dim=index_dim,
+            index_topk=int(d.get("index_topk", 2048)),
+            latent_rope=rope,
+            latent_mscale=mscale,
         )
 
     @classmethod
@@ -1063,6 +1200,7 @@ class LlamaConfig:
             "pangu_ultra_moe": "PanguUltraMoEForCausalLM",
             "olmo_hybrid": "OlmoHybridForCausalLM",
             "laguna": "LagunaForCausalLM",
+            "deepseek_v32": "DeepseekV32ForCausalLM",
         }[self.model_type]
         d: dict[str, Any] = {
             "architectures": [arch],
@@ -1128,8 +1266,27 @@ class LlamaConfig:
                 ),
                 moe_routed_scaling_factor=self.routed_scaling_factor,
             )
-        elif self.model_type == "pangu_ultra_moe":
+        elif self.model_type in ("pangu_ultra_moe", "deepseek_v32"):
             del d["num_key_value_heads"]
+            if self.model_type == "deepseek_v32":
+                r = self.latent_rope
+                d.update(
+                    n_group=self.n_group, topk_group=self.topk_group,
+                    scoring_func="sigmoid", topk_method="noaux_tc",
+                    index_n_heads=self.index_n_heads,
+                    index_head_dim=self.index_head_dim,
+                    index_topk=self.index_topk,
+                )
+                if r.factor != 1.0:
+                    d["rope_scaling"] = {
+                        "type": "yarn", "factor": r.factor,
+                        "original_max_position_embeddings":
+                            r.original_max_position_embeddings,
+                        "beta_fast": r.beta_fast, "beta_slow": r.beta_slow,
+                        "mscale": 1, "mscale_all_dim": 1,
+                    }
+            else:
+                d["sandwich_norm"] = True
             d.update(
                 num_key_value_heads=self.num_attention_heads,
                 q_lora_rank=self.q_lora_rank,
@@ -1149,7 +1306,6 @@ class LlamaConfig:
                 num_experts_per_tok=self.num_experts_per_tok,
                 norm_topk_prob=self.norm_topk_prob,
                 routed_scaling_factor=self.routed_scaling_factor,
-                sandwich_norm=True,
             )
         elif self.num_local_experts:
             if self.model_type in ("qwen2_moe", "qwen3_moe"):

@@ -114,9 +114,18 @@ def run_shapes(config: LlamaConfig, ff_kind: str) -> dict[str, tuple[int, ...]]:
         "w_uk": (n, config.kv_lora_rank, nope),
         "w_uv": (n, config.kv_lora_rank, vd),
         "wo": (n * vd, h),
-        "ln_attn": (h,), "ln_post_attn": (h,), "ln_mlp": (h,),
-        "ln_post_mlp": (h,),
+        "ln_attn": (h,), "ln_mlp": (h,),
     }
+    if config.post_block_norms:
+        shapes.update({"ln_post_attn": (h,), "ln_post_mlp": (h,)})
+    if config.index_topk:
+        # The learned index (ops/sparse_index.py): its queries from the
+        # query latent, ONE key a token behind a LayerNorm, a weight a head.
+        ih, idim = config.index_n_heads, config.index_head_dim
+        shapes.update({
+            "wi_q": (config.q_lora_rank, ih * idim), "wi_k": (h, idim),
+            "wi_w": (h, ih), "i_k_ln": (idim,), "i_k_ln_b": (idim,),
+        })
     if ff_kind == SPARSE:
         e, inter = config.num_local_experts, config.moe_intermediate_size
         shapes.update({
@@ -124,6 +133,8 @@ def run_shapes(config: LlamaConfig, ff_kind: str) -> dict[str, tuple[int, ...]]:
             "w_gate": (e, h, inter), "w_up": (e, h, inter),
             "w_down": (e, inter, h),
         })
+        if config.router_bias:
+            shapes["router_bias"] = (config.n_router_experts,)
         if config.shared_expert_intermediate_size:
             s = config.shared_expert_intermediate_size
             shapes.update(
@@ -145,6 +156,8 @@ def init_params(
     def draw(k, name, shape):
         if name.startswith("ln_") or name.endswith("_ln"):
             return jnp.ones(shape, dtype)
+        if name.endswith("_ln_b"):
+            return jnp.zeros(shape, dtype)
         return (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
 
     runs = []
@@ -175,19 +188,27 @@ def mla_project(lp, x, cos, sin, positions, config: LlamaConfig):
     with jax.named_scope(MIXER_IN):
         b, t, _ = x.shape
         eps, n = config.rms_norm_eps, config.num_attention_heads
-        nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
+        nope = config.qk_nope_head_dim
         h = rms_norm(x, lp["ln_attn"], eps)
         cq = rms_norm(qmat(h, lp["wq_a"]), lp["q_a_ln"], eps)
         q = qmat(cq, lp["wq_b"]).reshape(b, t, n, -1)
         q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin, positions)
-        kv = qmat(h, lp["wkv_a"])
-        ckv = rms_norm(kv[..., :rank], lp["kv_a_ln"], eps)
-        k_rope = apply_rope(kv[..., None, rank:], cos, sin, positions)[:, :, 0]
-        pad = config.latent_width - rank - config.qk_rope_head_dim
-        latent = jnp.concatenate(
-            [ckv, k_rope, jnp.zeros((b, t, pad), ckv.dtype)], axis=-1
-        )
-        return q_nope, q_rope, latent
+        return q_nope, q_rope, token_latent(lp, h, cos, sin, positions, config)
+
+
+def token_latent(lp, h, cos, sin, positions, config: LlamaConfig):
+    """What the pool holds of a token, from the layer's normed input ``h``
+    [b, t, hidden]: ``[rms(ckv) | RoPE(k_rope) | 0]`` [b, t, latent_width].
+    Its callers stand in ``mixer_in`` (a part's scope is never nested)."""
+    b, t, _ = h.shape
+    rank = config.kv_lora_rank
+    kv = qmat(h, lp["wkv_a"])
+    ckv = rms_norm(kv[..., :rank], lp["kv_a_ln"], config.rms_norm_eps)
+    k_rope = apply_rope(kv[..., None, rank:], cos, sin, positions)[:, :, 0]
+    pad = config.latent_width - rank - config.qk_rope_head_dim
+    return jnp.concatenate(
+        [ckv, k_rope, jnp.zeros((b, t, pad), ckv.dtype)], axis=-1
+    )
 
 
 def latent_blocks_forward(

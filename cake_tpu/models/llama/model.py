@@ -402,7 +402,8 @@ def block_finish(
                 dispatch=moe_dispatch, scoring=config.moe_scoring,
                 scale=config.routed_scaling_factor,
                 expert_offset=config.expert_offset, with_counts=moe_counts,
-                layer=moe_layer,
+                layer=moe_layer, router_bias=lp.get("router_bias"),
+                n_group=config.n_group, topk_group=config.topk_group,
             )
             if moe_counts:
                 mlp, counts = mlp

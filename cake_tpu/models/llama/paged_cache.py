@@ -303,6 +303,49 @@ _zero_latent_pool = tracked_jit(
 )
 
 
+class LatentIndexPagedCache(NamedTuple):
+    """The latent pool and, behind the SAME block table, the pool of a
+    learned index's keys (``config.cache_kind == "latent+index"``): a page
+    holds ``page_size`` tokens in both, so the allocator is not told. Both
+    are carried whole through the layer scans and written in place by
+    ``latent_write_pool``; a model without an index keeps
+    ``LatentPagedCache``, one leaf."""
+
+    latent: jnp.ndarray  # [n_layers, n_pages, page_size, latent_width]
+    index: jnp.ndarray  # [n_layers, n_pages, page_size, index_head_dim]
+
+    @property
+    def n_layers(self) -> int:
+        return self.latent.shape[0]
+
+    @property
+    def n_pages(self) -> int:
+        return self.latent.shape[1]
+
+    @property
+    def page_size(self) -> int:
+        return self.latent.shape[2]
+
+
+def init_latent_index_cache(
+    n_layers: int, n_pages: int, page_size: int, width: int, index_width: int,
+    dtype: jnp.dtype = jnp.bfloat16,
+) -> LatentIndexPagedCache:
+    return _zero_latent_index_pools(
+        (n_layers, n_pages, page_size), width, index_width, jnp.dtype(dtype)
+    )
+
+
+_zero_latent_index_pools = tracked_jit(
+    lambda shape, width, index_width, dtype: LatentIndexPagedCache(
+        latent=jnp.zeros((*shape, width), dtype),
+        index=jnp.zeros((*shape, index_width), dtype),
+    ),
+    name="paged.init_latent_index_cache",
+    static_argnames=("shape", "width", "index_width", "dtype"),
+)
+
+
 def latent_write_pool(
     pool: jnp.ndarray,
     layer: jnp.ndarray,

@@ -257,14 +257,28 @@ def audit_latent_programs(
     dtype=jnp.bfloat16,
     allow_pallas: bool = True,
     sharding=None,
+    only: tuple[str, ...] = (),
 ) -> dict[str, dict]:
     """``audit_paged_programs`` for a model with latent attention
     (models/llama/latent.py): {"decode": report, "join": report} and, with
-    ``prefill_rows``, "prefill" (one group of an epoch's). The pool is the
-    latent one, [n_layers, n_pages, page_size, latent_width]; ``argument_bytes``
+    ``prefill_rows``, "prefill" (one group of an epoch's); ``only`` names the
+    programs wanted. The pool is the
+    latent one, [n_layers, n_pages, page_size, latent_width] (and, for a model
+    with a learned index, the pool of its keys); ``argument_bytes``
     and ``temp_bytes`` together are what the program needs on the device."""
     from cake_tpu.models.llama import latent as L
     from cake_tpu.ops.fuse import fuse_params
+
+    if config.index_topk:
+        # The index's keys ride behind the same table in a pool of their own
+        # (models/llama/latent_index.py): neither pool may be copied.
+        from cake_tpu.models.llama import latent_index as LI
+
+        init_cache, prefill = LI.init_cache, LI._prefill_jit
+        make_decode, make_join = LI._decode_fn, LI._join_fn
+    else:
+        init_cache, prefill = L.init_cache, L._latent_prefill_jit
+        make_decode, make_join = L._latent_decode_fn, L._latent_join_fn
 
     def spec(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
@@ -276,13 +290,13 @@ def audit_latent_programs(
         L.init_params(config, jax.random.PRNGKey(0), dtype)
     )))
     cache = abstract(jax.eval_shape(
-        lambda: L.init_cache(config, n_pages, page_size, dtype)
+        lambda: init_cache(config, n_pages, page_size, dtype)
     ))
-    pool_shape = tuple(cache.latent.shape)
-    decode = L._latent_decode_fn(
+    pool_shapes_ = [tuple(p.shape) for p in cache]
+    decode = make_decode(
         config, n_steps, 0.0, None, None, 1.0, allow_pallas=allow_pallas
     )
-    join = L._latent_join_fn(config, join_width, allow_pallas)
+    join = make_join(config, join_width, allow_pallas)
     programs = {
         "decode": lambda: decode._jitted.trace(
             params, cache, spec((lanes,)), spec(()), spec((lanes,)),
@@ -296,24 +310,29 @@ def audit_latent_programs(
     }
     if prefill_rows:
         g = prefill_rows
-        programs["prefill"] = lambda: L._latent_prefill_jit._jitted.trace(
+        programs["prefill"] = lambda: prefill._jitted.trace(
             params, spec((g, join_width)), cache, spec((g,)), spec((g,)),
             spec((g, table_pages)), config, spec(()),
             allow_pallas=allow_pallas,
         )
     reports = {}
     for name, trace in programs.items():
+        if only and name not in only:
+            continue
         t0 = time.perf_counter()
         traced = trace()
         compiled = traced.lower().compile()
         hlo = compiled.as_text()
         mem = compiled.memory_analysis()
         reports[name] = {
-            "scans": scans_moving_pool(traced.jaxpr, pool_shape),
-            "pool_ops": pool_ops_in_hlo(hlo, pool_shape, dtype),
+            "scans": [f for s in pool_shapes_ for f in scans_moving_pool(traced.jaxpr, s)],
+            "pool_ops": [f for s in pool_shapes_ for f in pool_ops_in_hlo(hlo, s, dtype)],
             "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
             "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
-            "pool_bytes": math.prod(pool_shape) * jnp.dtype(dtype).itemsize,
+            "code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
+            "pool_bytes": sum(
+                math.prod(s) * jnp.dtype(dtype).itemsize for s in pool_shapes_
+            ),
             "kernels": hlo.count("tpu_custom_call"),
             "seconds": round(time.perf_counter() - t0, 1),
         }

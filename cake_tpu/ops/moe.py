@@ -71,9 +71,18 @@ def route_topk_select(
     norm_topk: bool = True,
     scoring: str = "softmax",
     scale: float = 1.0,
+    bias: jnp.ndarray | None = None,
+    n_group: int = 1,
+    topk_group: int = 1,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Scores (f32) over every ranked expert -> top-k -> optional
     renormalise -> scale.
+
+    ``bias`` [E] (the DeepSeek-V3 family's ``e_score_correction_bias``) is
+    added to the scores for CHOOSING only: the values returned are the
+    chosen experts' scores without it. ``n_group`` > 1 limits the choice to
+    the best ``topk_group`` of ``n_group`` equal groups of experts, a group
+    scored by the sum of its two largest (biased) scores.
 
     ``softmax``: Mixtral always renormalises the selected probabilities to
     sum 1; Qwen2-MoE gates this with ``norm_topk_prob`` (usually off).
@@ -88,7 +97,22 @@ def route_topk_select(
         scores = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"unknown MoE scoring {scoring!r}")
-    topv, topi = jax.lax.top_k(scores, top_k)
+    if bias is None and n_group == 1:
+        topv, topi = jax.lax.top_k(scores, top_k)
+    else:
+        choice = scores if bias is None else scores + bias.astype(jnp.float32)
+        if n_group > 1:
+            grouped = choice.reshape(*choice.shape[:-1], n_group, -1)
+            group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+            _, best = jax.lax.top_k(group_score, topk_group)
+            stays = jnp.any(
+                best[..., None] == jnp.arange(n_group), axis=-2
+            )  # [..., n_group]
+            choice = jnp.where(stays[..., None], grouped, -jnp.inf).reshape(
+                choice.shape
+            )
+        _, topi = jax.lax.top_k(choice, top_k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
     if norm_topk:
         topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
     if scale != 1.0:
@@ -369,6 +393,9 @@ def moe_swiglu(
     expert_offset: int = 0,
     with_counts: bool = False,
     layer=None,
+    router_bias: jnp.ndarray | None = None,
+    n_group: int = 1,
+    topk_group: int = 1,
 ):
     """Routed SwiGLU over the stacked experts held here.
 
@@ -390,7 +417,8 @@ def moe_swiglu(
         layer's slice passes the run whole (``_ragged`` says why).
       expert_offset: where the held experts start among the ranked when
         ``tp_axis`` is None (config.expert_offset; 0 with the whole model).
-      norm_topk / scoring / scale: ``route_topk_select``'s.
+      norm_topk / scoring / scale / router_bias / n_group / topk_group:
+        ``route_topk_select``'s.
       valid: optional [batch, chunk] bool. False marks slots that are no
         token (left pads, dead lanes): their assignments take no expert's
         rows or capacity; their own outputs are garbage nobody reads.
@@ -424,7 +452,10 @@ def moe_swiglu(
     logits = router_logits(x, router_w)  # [b, t, E] float32
     b, t, h = x.shape
     n_ranked = logits.shape[-1]
-    topv, topi = route_topk_select(logits, top_k, norm_topk, scoring, scale)
+    topv, topi = route_topk_select(
+        logits, top_k, norm_topk, scoring, scale,
+        bias=router_bias, n_group=n_group, topk_group=topk_group,
+    )
     offset = (
         expert_offset if tp_axis is None
         else jax.lax.axis_index(tp_axis) * e_local

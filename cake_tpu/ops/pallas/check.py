@@ -1407,3 +1407,179 @@ def timed_expert_layer(
             )
         rows.append(rec)
     return rows
+
+
+# ------------------------------------------- a learned index, sparse attention
+
+
+def _sparse_index_operands(
+    n_heads, rank, rope, index_heads, index_dim, page_size, lanes, table_pages,
+    layers, dt,
+):
+    """Seeded pools, queries and a table whose pages are scattered over the
+    pool, at one model's widths (ops/sparse_index.py's operands)."""
+    width = -(-(rank + rope) // 128) * 128
+    n_pages = lanes * table_pages
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    latent = jnp.pad(
+        jax.random.normal(ks[0], (layers, n_pages, page_size, rank + rope), dt) * 0.3,
+        ((0, 0),) * 3 + ((0, width - rank - rope),),
+    )
+    keys = jax.random.normal(ks[1], (layers, n_pages, page_size, index_dim), dt)
+    q = jnp.pad(
+        jax.random.normal(ks[2], (lanes, n_heads, rank + rope), dt),
+        ((0, 0), (0, 0), (0, width - rank - rope)),
+    )
+    q_i = jax.random.normal(ks[3], (lanes, index_heads, index_dim), dt)
+    w = jax.random.normal(ks[4], (lanes, index_heads), jnp.float32) * (
+        index_heads * index_dim
+    ) ** -0.5
+    perm = np.random.default_rng(0).permutation(n_pages)
+    tables = jnp.asarray(perm.reshape(lanes, table_pages), jnp.int32)
+    return latent, keys, q, q_i, w, tables
+
+
+def run_sparse_index_checks(
+    n_heads: int, rank: int, rope: int, index_heads: int, index_dim: int,
+    topk: int, page_size: int, lanes: int, table_pages: int, dtype: str = "bf16",
+) -> list[dict]:
+    """One layer's index scores, choice and sparse attention (ops/
+    sparse_index.py, as a decode step runs them) against the same arithmetic
+    in float32 at the highest matmul precision over the same pools, rows of
+    lengths spread from ``topk`` to the whole table: the error of ``I`` (in
+    units of a row's spread of scores), the share of the reference's chosen
+    set the program chose, and the error of the attention's output where
+    both are given the REFERENCE's set (a rounding of ``I`` moves the set's
+    edge; the output over one set tells the attention's own arithmetic)."""
+    from cake_tpu.models.llama.paged_cache import gather_latent
+    from cake_tpu.ops import sparse_index as SI
+
+    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    latent, keys, q, q_i, w, tables = _sparse_index_operands(
+        n_heads, rank, rope, index_heads, index_dim, page_size, lanes,
+        table_pages, 1, dt,
+    )
+    slots = table_pages * page_size
+    lengths = jnp.asarray(
+        np.linspace(min(topk, slots), slots, lanes).astype(np.int32)
+    )
+    starts = jnp.zeros((lanes,), jnp.int32)
+    scale = (rank // 4 + rope) ** -0.5
+    layer = jnp.int32(0)
+
+    @jax.jit
+    def program(q, q_i, w, latent, keys):
+        scores = SI.index_scores(q_i, w, keys, tables, starts, lengths, layer=layer)
+        picked, chosen = SI.select_topk(scores, topk)
+        return scores, picked, chosen
+
+    table_rows = SI.pool_rows(tables, page_size)
+
+    @jax.jit
+    def attend(q, latent, picked, chosen):
+        rows = jnp.take_along_axis(table_rows, picked, axis=1)
+        return SI.sparse_latent_attention(
+            q, latent, rows, chosen, layer=layer, rank=rank, scale=scale
+        )
+
+    @jax.jit
+    def reference(q, q_i, w, latent, keys):
+        with jax.default_matmul_precision("highest"):
+            f32 = jnp.float32
+            k = gather_latent(keys, tables, layer).astype(f32)
+            s = jnp.einsum("bhd,bsd->bhs", q_i.astype(f32), k)
+            scores = jnp.einsum("bh,bhs->bs", w, jax.nn.relu(s))
+            live = jnp.arange(slots)[None, :] < lengths[:, None]
+            scores = jnp.where(live, scores, -jnp.inf)
+            order = jnp.argsort(-scores, axis=-1, stable=True)[:, :topk]
+            chosen = jnp.take_along_axis(scores, order, axis=-1) > -jnp.inf
+            rows = jnp.take_along_axis(
+                gather_latent(latent, tables, layer).astype(f32), order[..., None], axis=1
+            )
+            a = jnp.einsum("bhw,bkw->bhk", q.astype(f32), rows) * scale
+            p = jax.nn.softmax(jnp.where(chosen[:, None, :], a, -jnp.inf), axis=-1)
+            return scores, order, chosen, jnp.einsum("bhk,bkr->bhr", p, rows[..., :rank])
+
+    tol = 2.0**-6 if dtype == "bf16" else 1e-4
+    rec = {"kernel": "sparse_index", "case": f"lanes={lanes} slots={slots} topk={topk}",
+           "tol": tol}
+    try:
+        (scores, picked, chosen), first = _timed(program, q, q_i, w, latent, keys)
+        want_scores, order, want_chosen, want_out = reference(q, q_i, w, latent, keys)
+        got_out = attend(q, latent, order.astype(jnp.int32), want_chosen)
+        scores, want_scores = np.asarray(scores), np.asarray(want_scores)
+        live = np.isfinite(want_scores)
+        spread = np.asarray([want_scores[b][live[b]].std() for b in range(lanes)])
+        err_i = float(np.max(
+            np.abs(np.where(live, scores, 0.0) - np.where(live, want_scores, 0.0))
+            / spread[:, None]
+        ))
+        overlap = []
+        for b in range(lanes):
+            got = set(np.asarray(picked[b])[np.asarray(chosen[b])].tolist())
+            want = set(np.asarray(order[b])[np.asarray(want_chosen[b])].tolist())
+            overlap.append(len(got & want) / len(want))
+        got_out, want_out = np.asarray(got_out, np.float32), np.asarray(want_out)
+        err_out = float(np.max(np.abs(got_out - want_out)) / np.max(np.abs(want_out)))
+        rec.update(
+            ok=bool(err_out <= tol and err_i <= 0.05 and min(overlap) >= 0.95),
+            max_err=err_out, score_err_in_spreads=err_i,
+            chosen_overlap_min=min(overlap), chosen_overlap_mean=float(np.mean(overlap)),
+            first_call_s=round(first, 2),
+        )
+    except Exception as e:  # noqa: BLE001 - a refused shape is a failed case
+        rec.update(ok=False, error=f"{type(e).__name__}: {str(e)[:300]}")
+    return [rec]
+
+
+def timed_sparse_index(
+    n_heads: int, rank: int, rope: int, index_heads: int, index_dim: int,
+    topk: int, page_size: int, lanes: int, table_pages: int, layers: int,
+    dtype: str = "bf16", lengths: tuple[int, ...] = (2048, 8192, 21504),
+    calls: int = 20, repeats: int = 3,
+) -> list[dict]:
+    """A decode step's three pieces alone on the clock, microseconds a call a
+    layer with every row at ``lengths`` cached tokens: the index's scores,
+    the choice, and the attention over the chosen (``sparse_us``: flat in the
+    cached length where its bytes follow the tokens chosen)."""
+    from cake_tpu.ops import sparse_index as SI
+
+    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    latent, keys, q, q_i, w, tables = _sparse_index_operands(
+        n_heads, rank, rope, index_heads, index_dim, page_size, lanes,
+        table_pages, layers, dt,
+    )
+    scale = (rank // 4 + rope) ** -0.5
+    starts = jnp.zeros((lanes,), jnp.int32)
+
+    def chained(fn):
+        @jax.jit
+        def run(*args):
+            def body(i, acc):
+                return acc + jnp.sum(fn(i % layers, *args).astype(jnp.float32)) * 0
+            return jax.lax.fori_loop(0, calls, body, jnp.float32(0))
+        return run
+
+    def us(fn, *args):
+        _timed(fn, *args)
+        return round(min(_timed(fn, *args)[1] for _ in range(repeats)) / calls * 1e6, 1)
+
+    score = chained(lambda li, q_i, w, keys, lens: SI.index_scores(
+        q_i, w, keys, tables, starts, lens, layer=li))
+    table_rows = SI.pool_rows(tables, page_size)
+    select = chained(lambda li, scores: SI.select_topk(scores + li, topk, table_rows)[0])
+    attend = chained(lambda li, q, latent, picked, chosen: SI.sparse_latent_attention(
+        q, latent, picked, chosen, layer=li, rank=rank, scale=scale))
+    rows = []
+    for length in lengths:
+        length = min(length, table_pages * page_size)
+        lens = jnp.full((lanes,), length, jnp.int32)
+        scores = SI.index_scores(q_i, w, keys, tables, starts, lens, layer=jnp.int32(0))
+        picked, chosen = SI.select_topk(scores, topk, table_rows)
+        rows.append({
+            "cached_tokens": length,
+            "scores_us": us(score, q_i, w, keys, lens),
+            "select_us": us(select, scores),
+            "sparse_us": us(attend, q, latent, picked, chosen),
+        })
+    return rows

@@ -886,7 +886,7 @@ class ApiServer:
                         # current depth — preempted lanes parked host-side
                         # awaiting a restore.
                         backend = getattr(api.engine, "backend", None)
-                        for key in ("cache", "moe"):
+                        for key in ("cache", "moe", "sparse"):
                             # What the lanes' pages hold and cost; the
                             # decode programs' account of the expert layer
                             # (a latent model's: runtime/batch_backend.py).

@@ -64,7 +64,8 @@ from cake_tpu.models.llama.paged_cache import (
     init_paged_cache,
 )
 from cake_tpu.models.llama.config import (
-    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, LlamaConfig,
+    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, CACHE_LATENT_INDEX,
+    LlamaConfig,
 )
 from cake_tpu.models.llama.fused import sample_step, sampled_decode_scan
 from cake_tpu.ops.rope import model_rope_tables
@@ -1021,24 +1022,27 @@ class PagedLatentBackend(_ExpertAccount, _PagedBackend):
     def prefill(self, tokens, kv, pads, ends=None):
         """An epoch's prefill in groups of rows (``shapes.prefill_group``),
         every group one program that writes its own lanes' latents."""
-        from cake_tpu.models.llama.latent import _latent_prefill_jit
-
+        prefill, _, _ = self._programs()
         tokens, pads, ends, tables, groups = self._epoch_groups(tokens, pads, ends)
         logits = []
         for index, rows in enumerate(groups):
             with self._group_span(index, rows, tokens.shape[1]):
-                out, kv, _ = _latent_prefill_jit(
+                out, kv, _ = prefill(
                     self.params, tokens[rows], kv, pads[rows], ends[rows],
                     tables[rows], self.config, allow_pallas=self.allow_pallas,
                 )
             logits.append(out)
         return self._group_logits(logits), kv
 
-    def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
-        from cake_tpu.models.llama.latent import _latent_decode_fn
+    def _programs(self):
+        """(an epoch's prefill, the decode chunk's maker, a join's maker)."""
+        from cake_tpu.models.llama import latent
 
+        return latent._latent_prefill_jit, latent._latent_decode_fn, latent._latent_join_fn
+
+    def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
         self._kernel_note("decode", int(slot) + n)
-        fn = _latent_decode_fn(
+        fn = self._programs()[1](
             self.config, n, s.temperature, s.top_k, s.top_p,
             s.repeat_penalty, allow_pallas=self.allow_pallas,
         )
@@ -1055,10 +1059,8 @@ class PagedLatentBackend(_ExpertAccount, _PagedBackend):
     def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
         """One row's window [start, start + width) as the engine cut it
         (``shapes.window``) into lane ``lane``."""
-        from cake_tpu.models.llama.latent import _latent_join_fn
-
         self._kernel_note("join", int(np.asarray(ends1).max()))
-        fn = _latent_join_fn(
+        fn = self._programs()[2](
             self.config, row_tokens.shape[1], self.allow_pallas
         )
         logits, kv, self._chunk_counters = fn(
@@ -1068,6 +1070,117 @@ class PagedLatentBackend(_ExpertAccount, _PagedBackend):
             jnp.int32(start),
         )
         return logits, kv
+
+
+class PagedLatentIndexBackend(PagedLatentBackend):
+    """The same for a latent model whose attention reads the tokens a learned
+    index chooses (models/llama/latent_index.py): the cache is a
+    ``LatentIndexPagedCache``, the latent pool and the pool of index keys
+    behind the one block table the one allocator keeps. Its programs return
+    the expert layer's account and, behind it, the index's
+    (``latent_index.SPARSE_COUNTS``): both ride with the chunk's tokens."""
+
+    cache_kind = CACHE_LATENT_INDEX
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        from cake_tpu.models.llama.latent_index import SPARSE_COUNTS
+
+        self.sparse_counts = dict.fromkeys(SPARSE_COUNTS, 0)
+        self.sparse_traced = dict.fromkeys(SPARSE_COUNTS, 0)
+        self.sparse_join_counts = dict.fromkeys(SPARSE_COUNTS, 0)
+
+    def _cache_token_bytes(self) -> tuple[int, int]:
+        per = self._by_what()
+        return per["latent_needed"] + per["index"], per["latent"] + per["index"]
+
+    def _by_what(self) -> dict[str, int]:
+        from cake_tpu.models.llama.latent_index import cache_bytes_per_token
+
+        return cache_bytes_per_token(self.config, self.cache_dtype)
+
+    def cache_facts(self) -> dict:
+        """``bytes_per_token`` stays the sum; ``bytes_per_token_by`` says what
+        of it is the latent and what the index key."""
+        per = self._by_what()
+        return {
+            **super().cache_facts(),
+            "bytes_per_token_by": {"latent": per["latent"], "index": per["index"]},
+        }
+
+    def sparse_facts(self) -> dict:
+        """``GET /stats`` engine.sparse, cumulative over decode chunks READ:
+        ``dispatches`` decode steps x layers, ``rows`` live rows in them,
+        ``scanned`` cached tokens their queries scored, ``chosen`` tokens
+        attention then read; ``traced``: the same of the chunks DISPATCHED
+        while a profiler session was open (what a device trace's times are
+        of); ``join``: the same of the joins' windows."""
+        return {
+            "index_topk": self.config.index_topk, **self.sparse_counts,
+            "traced": {"index_topk": self.config.index_topk, **self.sparse_traced},
+            "join": dict(self.sparse_join_counts),
+        }
+
+    def take_chunk_counters(self):
+        counters = super().take_chunk_counters()
+        if counters is None:
+            return None
+        return counters, _profiler_open()
+
+    def absorb_chunk_counters(self, counters, decode: bool = True) -> dict:
+        from cake_tpu.models.llama.latent import MOE_COUNTS
+        from cake_tpu.models.llama.latent_index import SPARSE_COUNTS
+
+        counters, traced = counters
+        values = np.asarray(counters)
+        got = super().absorb_chunk_counters(values[: len(MOE_COUNTS)], decode)
+        sparse = dict(zip(
+            SPARSE_COUNTS, (int(v) for v in values[len(MOE_COUNTS):]), strict=True
+        ))
+        totals = [self.sparse_counts if decode else self.sparse_join_counts]
+        if decode and traced:
+            totals.append(self.sparse_traced)
+        for total in totals:
+            for key, v in sparse.items():
+                total[key] += v
+        return {**got, **{f"index_{k}": v for k, v in sparse.items()}}
+
+    def init_kv(self, b: int):
+        from cake_tpu.models.llama.latent_index import init_cache
+
+        self.allocator.reset(batch=b)
+        return init_cache(
+            self.config, self.max_pages, self.page_size, self.cache_dtype
+        )
+
+    def _programs(self):
+        from cake_tpu.models.llama import latent_index
+
+        def prefill(params, tokens, kv, pads, ends, tables, config, allow_pallas):
+            """A group of an epoch's prefill. One row (every window wider
+            than a block: ``shapes.prefill_group``) is the JOIN's program of
+            that width, from slot 0: one executable a width, 27 MB of code
+            and 15 s of compile each (``shapes.one_row_prefill_is_join``)."""
+            if tokens.shape[0] > 1:
+                return latent_index._prefill_jit(
+                    params, tokens, kv, pads, ends, tables, config,
+                    allow_pallas=allow_pallas,
+                )
+            fn = latent_index._join_fn(config, tokens.shape[1], allow_pallas)
+            return fn(params, kv, tokens, pads, ends, tables, jnp.int32(0))
+
+        return prefill, latent_index._decode_fn, latent_index._join_fn
+
+
+def _profiler_open() -> bool:
+    """Whether a ``jax.profiler`` session is recording in this process (the
+    benchmark's control socket opens one; ``POST /profile`` another)."""
+    try:
+        from jax._src import profiler
+
+        return profiler._profile_state.profile_session is not None
+    except (ImportError, AttributeError):
+        return False
 
 
 class PagedKindsBackend(_ExpertAccount, _PagedBackend):
@@ -1206,6 +1319,7 @@ _PAGED_LEAVES = {
     CACHE_KV: PagedLocalBackend,
     CACHE_KV_STATE: PagedHybridBackend,
     CACHE_LATENT: PagedLatentBackend,
+    CACHE_LATENT_INDEX: PagedLatentIndexBackend,
     CACHE_KV_KINDS: PagedKindsBackend,
 }
 

@@ -28,7 +28,8 @@ import dataclasses
 
 from cake_tpu.models.llama.batch import prompt_bucket
 from cake_tpu.models.llama.config import (
-    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, GATED_DELTA,
+    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, CACHE_LATENT_INDEX,
+    GATED_DELTA,
 )
 
 # The closed tables as shares of a lane's table: widths in 64ths of its
@@ -39,7 +40,17 @@ _WIDTH_64THS = (1, 2, 4, 8, 12, 16, 24, 32, 40, 48, 64)
 # to 19 to 33 MB (compiled for a described v5e, PERF.md section 4) and takes
 # 17 s to compile, so the set can never sit in a 192 MiB compile cache and
 # every start pays for every program it runs ahead.
-_WIDTH_64THS_BY_KIND = {CACHE_KV_KINDS: (4, 8, 16, 32, 48, 64)}
+# A latent pool beside an index's keys: six too, eighths of the table so that
+# every width is whole 128s on a 168-page table (2,688, 5,376, 8,064, 13,440,
+# 18,816 and 21,504 slots: a window's attention kernel tiles its keys by
+# 128s, ops/pallas/masked_prefill.py), placed for prompts of 2,048 to 16,384
+# tokens (18,816 holds the longest with its template; the cell's probes of
+# 300, 3,000 and 8,000 tokens take the first, second and third). A join is
+# 27 MB of code (compiled for a described v5e, PERF.md section 4).
+_WIDTH_64THS_BY_KIND = {
+    CACHE_KV_KINDS: (4, 8, 16, 32, 48, 64),
+    CACHE_LATENT_INDEX: (8, 16, 24, 40, 56, 64),
+}
 _CAPACITY_QUARTERS = (1, 2, 4)
 # Tokens a prefill program may hold, by cache kind. State layers: the mixer's
 # float32 intermediates are [rows, width, d_inner] several times over, so an
@@ -65,8 +76,14 @@ _CAPACITY_QUARTERS = (1, 2, 4)
 # (``kinds._TAIL_TOKENS``), so the widest join's temporaries are its
 # attention's: compiled for a v5e about 155 KB a token (2.9 GB at 18,432
 # slots, 4.1 at 24,576), which is what an epoch's groups are held to as well.
+# A latent pool beside an index's keys: a window goes through its layer a
+# block of at most 2,048 tokens at a time whatever its width (a block's index
+# scores and attention scores are [block, keys], and the grouped experts'
+# combine rows x tokens), so rows share a program only where all of them fit
+# one block; a wider row is a program of its own.
 _PREFILL_TOKENS = {
     CACHE_KV_STATE: 16384, CACHE_LATENT: 4096, CACHE_KV_KINDS: 16384,
+    CACHE_LATENT_INDEX: 2048,
 }
 _PREFILL_TOKENS_BY_MIXER = {GATED_DELTA: 8192}
 
@@ -95,6 +112,10 @@ class ProgramShapes:
     capacities: tuple[int, ...] = ()
     # None: an epoch's prefill is one program, whatever it holds.
     prefill_tokens: int | None = None
+    # A one-row group of an epoch's prefill IS the join's program of that
+    # width (the backend dispatches it so: ``PagedLatentIndexBackend``), and
+    # is not run ahead a second time.
+    one_row_prefill_is_join: bool = False
 
     @classmethod
     def for_model(cls, config, page_size: int = 0, pages_per_seq: int = 0):
@@ -118,6 +139,7 @@ class ProgramShapes:
             widths=tuple(sorted(widths)),
             capacities=tuple(p * page_size for p in sorted(pages)),
             prefill_tokens=_prefill_tokens(config),
+            one_row_prefill_is_join=config.cache_kind == CACHE_LATENT_INDEX,
         )
 
     def lanes(self, n_seed: int, max_batch: int) -> int:
@@ -186,5 +208,7 @@ class ProgramShapes:
         be run ahead of a set that has no end."""
         out = []
         for width in self.widths:
-            out += [("prefill", lanes, width), ("join", 1, width)]
+            if not (self.one_row_prefill_is_join and self.prefill_group(lanes, width) == 1):
+                out.append(("prefill", lanes, width))
+            out.append(("join", 1, width))
         return (*out, *(("decode", lanes, c) for c in self.capacities))

@@ -70,7 +70,7 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, ".chip_smoke")  # listed in .gitignore
-PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "O", "L", "D")
+PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "O", "L", "S", "D")
 
 MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
     model_type="mistral", hidden_size=4096, intermediate_size=14336,
@@ -92,6 +92,7 @@ def _benchmark_model(name: str) -> dict:
 JAMBA2_3B = _benchmark_model("ai21-jamba2-3b")
 PANGU_EP16 = _benchmark_model("openpangu-ultra-moe-718b-ep16")
 OLMO_HYBRID_D16 = _benchmark_model("olmo-hybrid-7b-d16")
+DEEPSEEK_V32_EP16 = _benchmark_model("deepseek-v3.2-exp-ep16-d5")
 PRESETS = {
     # Prompt lengths are in characters: without a tokenizer file the byte
     # tokenizer serves, one token a byte.
@@ -127,6 +128,12 @@ PRESETS = {
         latent=dict(
             model=dict(PANGU_EP16), pages=2048, lanes=64, table_pages=8,
             steps=8, join_width=512, expert_tokens=(1, 8, 64, 512, 2048),
+        ),
+        # deepseek-v32-ep16-longdoc-closed
+        # (bench/configs/deepseek-v3.2-exp-ep16-d5.json)
+        sparse=dict(
+            model=dict(DEEPSEEK_V32_EP16), pages=2688, lanes=16, table_pages=168,
+            steps=8, join_width=8064,
         ),
     ),
     # The rehearsal: same family and head layout rules (tp 4 divides the
@@ -190,6 +197,20 @@ PRESETS = {
             ),
             pages=16, lanes=2, table_pages=2, steps=4, join_width=64,
             expert_tokens=(8, 64), timed=dict(table_pages=(2,), calls=2, repeats=1),
+        ),
+        sparse=dict(
+            model=dict(
+                DEEPSEEK_V32_EP16, hidden_size=128, intermediate_size=256,
+                moe_intermediate_size=64, num_attention_heads=8,
+                num_key_value_heads=8, q_lora_rank=48, kv_lora_rank=128,
+                qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                num_hidden_layers=3, n_routed_experts=2,
+                n_routed_experts_total=16, num_experts_per_tok=4, n_group=4,
+                topk_group=2, index_n_heads=4, index_head_dim=128,
+                index_topk=64, vocab_size=512,
+            ),
+            pages=16, lanes=2, table_pages=2, steps=4, join_width=256,
+            timed=dict(lengths=(64, 256), calls=2, repeats=1),
         ),
     ),
 }
@@ -630,7 +651,53 @@ def child_latent(preset: dict) -> None:
         emit({"kind": "program", "program": name, **report})
 
 
-CHILDREN = {"probe": child_probe, "setup": child_setup,
+def child_sparse(preset: dict) -> None:
+    """A model whose attention reads the tokens a learned index chooses, at
+    the benchmark cell's geometry: one layer's index scores, choice and
+    sparse attention against float32, the three alone on the clock at three
+    cached lengths, then its decode chunk and a join compiled for the device
+    this process holds, from shapes alone."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import pool_audit
+    from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.ops.pallas.check import run_sparse_index_checks, timed_sparse_index
+    from cake_tpu.utils.device import describe_devices, setup_compile_cache
+
+    setup_compile_cache()
+    device = describe_devices()
+    g = preset["sparse"]
+    config = dataclasses.replace(
+        LlamaConfig.from_hf_dict(g["model"]), attention_impl="pallas"
+    )
+    geometry = dict(
+        n_heads=config.num_attention_heads, rank=config.kv_lora_rank,
+        rope=config.qk_rope_head_dim, index_heads=config.index_n_heads,
+        index_dim=config.index_head_dim, topk=config.index_topk,
+        page_size=preset["page_size"], lanes=g["lanes"],
+        table_pages=g["table_pages"], dtype=preset["dtype"],
+    )
+    for rec in run_sparse_index_checks(**geometry):
+        emit({"kind": "case", **rec})
+    emit({"kind": "summary", **device})
+    emit({"kind": "timed", "rows": timed_sparse_index(
+        **geometry, layers=config.num_hidden_layers, **g.get("timed", {}),
+    )})
+    reports = pool_audit.audit_latent_programs(
+        config, n_pages=g["pages"], page_size=preset["page_size"],
+        lanes=g["lanes"], table_pages=g["table_pages"], n_steps=g["steps"],
+        join_width=g["join_width"],
+        dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]],
+        allow_pallas=jax.default_backend() != "cpu",
+    )
+    for name, report in reports.items():
+        emit({"kind": "program", "program": name, **report})
+
+
+CHILDREN = {"probe": child_probe, "sparse": child_sparse, "setup": child_setup,
             "kernels": child_kernels, "pool": child_pool,
             "hybrid": child_hybrid, "olmo": child_olmo,
             "latent": child_latent}
@@ -1230,6 +1297,59 @@ def phase_latent(args, preset) -> dict:
     return out
 
 
+def phase_sparse(args, preset) -> dict:
+    """Phase S: a learned index over the cached tokens and latent attention
+    over the tokens it chooses, at the benchmark cell's geometry
+    (deepseek-v32-ep16-longdoc-closed): the case against float32, the three
+    pieces' times alone beside the work's floor, then the compiled programs.
+    ``sparse_us`` must not follow the cached length: its bytes are the chosen
+    tokens'."""
+    from bench.manifest import architecture
+
+    records = run_child("sparse", args, timeout=1800)
+    g = preset["sparse"]
+    arch = architecture(REPO, g["model"])
+    problems = []
+    for c in (r for r in records if r["kind"] == "case"):
+        say(f"phase=S kernel={c['kernel']} {c['case']}: "
+            + (f"max_err={c['max_err']:.3g} of tol {c['tol']:.3g} "
+               f"score_err_in_spreads={c['score_err_in_spreads']:.3g} "
+               f"chosen_overlap min={c['chosen_overlap_min']:.4f} "
+               f"mean={c['chosen_overlap_mean']:.4f} first_call_s={c['first_call_s']}"
+               if "max_err" in c else f"FAILED {c.get('error')}"))
+        if not c["ok"]:
+            problems.append(f"{c['kernel']} {c['case']}")
+    where = "" if not args.rehearse_cpu else " (cpu rehearsal: no device time)"
+    rows = next(r for r in records if r["kind"] == "timed")["rows"]
+    lanes, topk = g["lanes"], g["model"]["index_topk"]
+    for row in rows:
+        n = row["cached_tokens"]
+        ops, moved = arch.index_scores_cost(g["model"], lanes, lanes * n, preset["dtype"])
+        scores_floor = max(ops / 197e12, moved / 819e9) * 1e6
+        ops, moved = arch.sparse_attention_cost(
+            g["model"], lanes, lanes * min(n, topk), preset["dtype"])
+        sparse_floor = max(ops / 197e12, moved / 819e9) * 1e6
+        say(f"phase=S alone{where}, cached_tokens={n} a row: scores_us={row['scores_us']} "
+            f"(floor {scores_floor:.1f}) select_us={row['select_us']} "
+            f"sparse_us={row['sparse_us']} (floor {sparse_floor:.1f})")
+    sparse = [row["sparse_us"] for row in rows if row["cached_tokens"] >= topk]
+    if not args.rehearse_cpu and sparse and max(sparse) > 1.3 * min(sparse):
+        problems.append(f"sparse attention follows the cached length: {sparse} us")
+    out = {"cases": sum(r["kind"] == "case" for r in records)}
+    for r in (r for r in records if r["kind"] == "program"):
+        moved = r["scans"] + ([] if args.rehearse_cpu else r["pool_ops"])
+        say(f"phase=S program={r['program']} temp_bytes={r['temp_bytes']} "
+            f"argument_bytes={r['argument_bytes']} pool_bytes={r['pool_bytes']} "
+            f"code_bytes={r['code_bytes']} kernels={r['kernels']} "
+            f"moving_ops={len(moved)} compile_s={r['seconds']}")
+        if moved:
+            problems.append(f"{r['program']}: {len(moved)} op(s) move a pool")
+        out[f"{r['program']}_temp_bytes"] = r["temp_bytes"]
+    if problems:
+        raise PhaseFailed("; ".join(problems))
+    return out
+
+
 def phase_four_chips(args, preset) -> dict:
     cpu = args.rehearse_cpu
     out = {}
@@ -1319,6 +1439,7 @@ def main() -> int:
         "H": lambda: phase_hybrid(args, preset),
         "O": lambda: phase_olmo(args, preset),
         "L": lambda: phase_latent(args, preset),
+        "S": lambda: phase_sparse(args, preset),
         "D": lambda: phase_four_chips(args, preset),
     }
 

@@ -1,0 +1,85 @@
+"""DeepSeek-V3.2-Exp's cell at its published widths, compiled for a described
+v5e (no chip: ``tests/test_paged_pool_carry.py`` says how): the decode chunk
+and a join of ``deepseek-v3.2-exp-ep16-d5`` carry BOTH pools (the latents and
+the index's keys) without a copy, the join's attention lowers through Mosaic
+(``ops/pallas/masked_prefill.py``), and both fit the chip beside 9.27 GB of
+weights and 2.64 GB of pools."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import pytest
+
+from cake_tpu.models.llama import pool_audit
+from cake_tpu.models.llama.config import LlamaConfig
+
+from test_paged_pool_carry import one_chip  # noqa: F401  (a fixture)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+with open(os.path.join(ROOT, "bench/configs/deepseek-v3.2-exp-ep16-d5.json")) as _f:
+    CELL_CONFIG = json.load(_f)
+FLAGS = CELL_CONFIG["server_flags"]
+TABLE_PAGES = 168  # --max-seq-len 21504 over --page-size 128
+
+
+@pytest.fixture(scope="module")
+def deepseek():
+    return dataclasses.replace(
+        LlamaConfig.from_hf_dict(CELL_CONFIG), attention_impl="pallas"
+    )
+
+
+@pytest.fixture(scope="module")
+def cell_reports(deepseek, one_chip):  # noqa: F811
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        with jax.default_matmul_precision("default"):
+            return pool_audit.audit_latent_programs(
+                deepseek, n_pages=2688, page_size=128, lanes=16, n_steps=8,
+                table_pages=TABLE_PAGES, join_width=8064, sharding=one_chip,
+            )
+
+
+@pytest.mark.parametrize("program", ["decode", "join"])
+def test_the_cell_compiles_for_v5e_without_pool_copies(program, cell_reports):
+    report = cell_reports[program]
+    assert report["scans"] == [] and report["pool_ops"] == [], report
+    # 2688 pages x 128 tokens x 5 layers x (640 + 128) numbers in bf16
+    assert report["pool_bytes"] == 2688 * 128 * 5 * 768 * 2 == 2_642_411_520
+    # weights 9.27 GB + the pools 2.64: the chip's 15.75 GB hold the program
+    assert 11.8e9 < report["argument_bytes"] < 12.0e9, report
+    assert report["argument_bytes"] + report["temp_bytes"] < 14.5e9, report
+    # the grouped experts' three products a sparse run; a join's attention kernel a run too
+    assert report["kernels"] == (3 if program == "decode" else 8), report
+    assert report["code_bytes"] < 40e6, report  # one block's code whatever the width
+
+
+def test_the_cells_closed_shapes(deepseek):
+    """What ``--max-seq-len 21504 --page-size 128`` makes of the CLOSED
+    instance: six widths in whole 128s and three capacities."""
+    from cake_tpu.models.llama.latent_index import window_block
+    from cake_tpu.ops.sparse_index import window_kernel_supported
+    from cake_tpu.runtime.shapes import ProgramShapes
+
+    assert FLAGS[FLAGS.index("--max-seq-len") + 1] == str(128 * TABLE_PAGES)
+    assert FLAGS[FLAGS.index("--max-pages") + 1] == str(16 * TABLE_PAGES)  # every lane backed
+    shapes = ProgramShapes.for_model(deepseek, 128, TABLE_PAGES)
+    assert shapes.widths == (2688, 5376, 8064, 13440, 18816, 21504)
+    assert shapes.capacities == (5376, 10752, 21504)
+    # a one-row group of an epoch's prefill is the join's program: six joins and three decode chunks
+    assert len(shapes.programs(16)) == 9 and shapes.prefill_tokens == 2048
+    assert not [p for p in shapes.programs(16) if p[0] == "prefill"]
+    # the longest prompt with its template, and the probes
+    assert shapes.program_width(16384 + 3) == 18816 and shapes.program_width(8003) == 8064
+    assert shapes.program_width(303) == 2688 and shapes.program_width(3003) == 5376
+    # a row wider than a block is a program of its own
+    assert shapes.prefill_group(16, 2688) == 1 and shapes.prefill_group(16, 64) == 16
+    for width in shapes.widths:
+        assert window_kernel_supported(width, 128, 64, 128), width
+        block = window_block(1, width)
+        assert width % block == 0 and 1024 < block <= 2048 and block % 16 == 0, (width, block)
+    step = int(FLAGS[FLAGS.index("--step-prefill") + 1])
+    assert step >= 16384 + 3  # without it no prompt of this mix ever joins (PERF.md row 23)

@@ -4,8 +4,8 @@
 (``embed`` .. ``sample``); the model's functions enter them with
 ``jax.named_scope``, and the benchmark reads a part's device time from the
 trace's ``op_name`` metadata (``bench/parts.py``). Here the served programs
-of the four families (dense GQA, a Jamba-like and an Olmo-Hybrid-like hybrid,
-latent attention with sparse experts) are LOWERED at a tiny size, never run:
+of the five families (dense GQA, a Jamba-like and an Olmo-Hybrid-like hybrid,
+latent attention with sparse experts, the same behind a learned index) are LOWERED at a tiny size, never run:
 the operations' names are read from the lowered module, and the same
 programs lower to the same text, locations aside, with the scopes taken
 away: a scope is metadata and nothing else.
@@ -25,6 +25,7 @@ import pytest
 from cake_tpu.models.llama import batch as B
 from cake_tpu.models.llama import hybrid as H
 from cake_tpu.models.llama import latent as L
+from cake_tpu.models.llama import latent_index as LI
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.models.llama.paged_cache import PagedKVCache
@@ -35,6 +36,7 @@ from cake_tpu.ops.fuse import fuse_params
 
 from test_hybrid_jamba import HF as JAMBA
 from test_hybrid_olmo import HF as OLMO_HYBRID
+from test_deepseek_v32 import TINY as LATENT_INDEX
 from test_latent_pangu import SHARE as LATENT_MOE
 
 FAMILIES = {
@@ -42,6 +44,7 @@ FAMILIES = {
     "jamba": LlamaConfig.from_hf_dict(JAMBA),
     "olmo_hybrid": LlamaConfig.from_hf_dict(OLMO_HYBRID),
     "latent_moe": LlamaConfig.from_hf_dict(LATENT_MOE),
+    "latent_index": LlamaConfig.from_hf_dict(LATENT_INDEX),
 }
 PROGRAMS = ("decode", "join", "prefill")
 # A tiny server's shapes: lanes, pages of 16 slots, a table of 8 pages a
@@ -56,6 +59,9 @@ OLDER = {
     "gated_delta_step": MIXER, "gated_delta_rule": MIXER,
     "selective_scan_xla": MIXER, "moe_experts_grouped": FEED_FORWARD,
     "moe_experts_dense": FEED_FORWARD,
+    # the learned index's three (PR 43): what a decode step or a window's
+    # block scores, chooses and attends
+    "index_scores": MIXER, "index_select": MIXER, "sparse_attention": MIXER,
 }
 # Which of them a family's program holds at these shapes (the XLA forms:
 # tiny widths tile no kernel; ``moe_experts_dense`` is a ``--tp`` verify
@@ -69,6 +75,8 @@ HOLDS = {
     ("latent_moe", "decode"): {"moe_experts_grouped"},
     ("latent_moe", "join"): {"moe_experts_grouped"},
     ("latent_moe", "prefill"): {"moe_experts_grouped"},
+    **{("latent_index", p): {"moe_experts_grouped", "index_scores", "index_select",
+                             "sparse_attention"} for p in ("decode", "join", "prefill")},
 }
 WEIGHTY = ("dot_general", "convolution", "custom_call", "scatter")
 
@@ -145,6 +153,23 @@ def served_program(
             table, config, spec(()), spec(()), allow_pallas=allow_pallas,
         )
     params = abstract(lambda: fuse_params(L.init_params(config, key, dtype)))
+    if config.cache_kind == "latent+index":
+        cache = abstract(lambda: LI.init_cache(config, n_pages, page_size, dtype))
+        if program == "decode":
+            fn = LI._decode_fn(config, *sampling, allow_pallas=allow_pallas)
+            return fn._jitted.trace(
+                params, cache, spec((lanes,)), spec(()), spec((lanes,)), table,
+                spec((lanes,), jnp.bool_), *decode_tail,
+            )
+        if program == "join":
+            return LI._join_fn(config, width, allow_pallas)._jitted.trace(
+                params, cache, spec((1, width)), spec((1,)), spec((1,)), table,
+                spec(()),
+            )
+        return LI._prefill_jit._jitted.trace(
+            params, spec((rows, width)), cache, spec((rows,)), spec((rows,)),
+            table, config, spec(()), allow_pallas=allow_pallas,
+        )
     cache = abstract(lambda: L.init_cache(config, n_pages, page_size, dtype))
     if program == "decode":
         fn = L._latent_decode_fn(config, *sampling, allow_pallas=allow_pallas)
