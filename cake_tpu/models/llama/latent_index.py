@@ -22,9 +22,10 @@ that are the model's, all of them data in the config:
 Two forms, as latent.py's:
 
   * **decode**, absorbed: the latent and the index key are written, the
-    row's index keys scored, ``index_topk`` slots chosen, and their rows of
-    the latent pool gathered and attended: what the attention reads follows
-    the tokens chosen, not the tokens cached;
+    row's index keys scored where they lie in the pool (a Pallas kernel over
+    the row's live pages on the chip), ``index_topk`` slots chosen, and their
+    rows of the latent pool gathered and attended: what the attention reads
+    follows the tokens chosen, not the tokens cached;
   * **a window** (an epoch's prefill, a join), four passes a layer: what the
     layer keeps of every token (latents and index keys, written through the
     table); each block's queries' index scores against the window's keys
@@ -63,6 +64,7 @@ from cake_tpu.obs.taxonomy import MIXER, MIXER_IN, MIXER_OUT
 from cake_tpu.ops import sparse_index as SI
 from cake_tpu.ops.fuse import resolve_fusion
 from cake_tpu.ops.norm import rms_norm
+from cake_tpu.ops.pallas.index_scores import paged_index_scores_supported
 from cake_tpu.ops.quant import qmat
 from cake_tpu.ops.rope import apply_rope, kind_rope_rows
 
@@ -145,6 +147,23 @@ def index_queries(lp, cq, cos, sin, config: LlamaConfig):
         return apply_rope(q_i, cos, sin, None)
 
 
+def _kernel_switch(config: LlamaConfig, allow_pallas: bool) -> bool:
+    """This layer kind's kernels follow the attention kernels' switch."""
+    return allow_pallas and M.resolve_attention_impl(config.attention_impl) == "pallas"
+
+
+def scores_form(config: LlamaConfig, page_size: int, allow_pallas: bool) -> str:
+    """Which form a decode step's index scores take, by the predicate the
+    program itself follows: ``"pallas"`` (ops/pallas/index_scores.py: the
+    pool of index keys read in place, a row's live pages only) or ``"xla"``
+    (the twin: the row's whole table gathered). ``GET /stats``
+    engine.sparse.scores_form."""
+    kernel = _kernel_switch(config, allow_pallas) and paged_index_scores_supported(
+        page_size, config.index_head_dim, config.index_n_heads
+    )
+    return "pallas" if kernel else "xla"
+
+
 def window_block(rows: int, width: int) -> int:
     """Slots of a row one block of a window takes: the largest number of
     whole 16s that divides the width and keeps the rows' block together
@@ -175,9 +194,8 @@ def latent_index_blocks_forward(
     """The model's layers in order, run by run: (x, cache, ``MOE_COUNTS`` of
     this pass over its sparse layers, ``SPARSE_COUNTS`` over all layers)."""
     fusion = resolve_fusion(config, allow_pallas)
-    use_kernel = (
-        allow_pallas and M.resolve_attention_impl(config.attention_impl) == "pallas"
-    )
+    use_kernel = _kernel_switch(config, allow_pallas)
+    scores_kernel = scores_form(config, cache.page_size, allow_pallas) == "pallas"
     rank, n, rope = config.kv_lora_rank, config.num_attention_heads, config.qk_rope_head_dim
     scale, topk = config.mla_scale, config.index_topk
     b, t, _ = x.shape
@@ -222,7 +240,8 @@ def latent_index_blocks_forward(
             # A dead lane's row is nobody's: it is given one slot to score.
             starts = jnp.where(live[:, 0], pads, ends - 1)
             scores = SI.index_scores(
-                q_i[:, 0], w[:, 0], ipool, block_tables, starts, ends, layer=li
+                q_i[:, 0], w[:, 0], ipool, block_tables, starts, ends, layer=li,
+                kernel=scores_kernel,
             )
             rows_of, chosen = SI.select_topk(scores, topk, table_rows)
             c = SI.sparse_latent_attention(

@@ -1446,15 +1446,20 @@ def run_sparse_index_checks(
     """One layer's index scores, choice and sparse attention (ops/
     sparse_index.py, as a decode step runs them) against the same arithmetic
     in float32 at the highest matmul precision over the same pools, rows of
-    lengths spread from ``topk`` to the whole table: the error of ``I`` (in
-    units of a row's spread of scores), the share of the reference's chosen
-    set the program chose, and the error of the attention's output where
-    both are given the REFERENCE's set (a rounding of ``I`` moves the set's
-    edge; the output over one set tells the attention's own arithmetic)."""
+    lengths spread from ``topk`` to the whole table, every other one behind
+    pads: the error of ``I`` (in units of a row's spread of scores), the
+    share of the reference's chosen set the program chose, and the error of
+    the attention's output where both are given the REFERENCE's set (a
+    rounding of ``I`` moves the set's edge; the output over one set tells the
+    attention's own arithmetic). The scores are the Pallas kernel's
+    (ops/pallas/index_scores.py) where the widths tile, as a served decode
+    step's are (``form`` says which)."""
     from cake_tpu.models.llama.paged_cache import gather_latent
     from cake_tpu.ops import sparse_index as SI
+    from cake_tpu.ops.pallas.index_scores import paged_index_scores_supported
 
     dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    kernel = paged_index_scores_supported(page_size, index_dim, index_heads)
     latent, keys, q, q_i, w, tables = _sparse_index_operands(
         n_heads, rank, rope, index_heads, index_dim, page_size, lanes,
         table_pages, 1, dt,
@@ -1463,13 +1468,17 @@ def run_sparse_index_checks(
     lengths = jnp.asarray(
         np.linspace(min(topk, slots), slots, lanes).astype(np.int32)
     )
-    starts = jnp.zeros((lanes,), jnp.int32)
+    starts = jnp.asarray(
+        np.where(np.arange(lanes) % 2, min(page_size + 37, slots // 4), 0).astype(np.int32)
+    )
     scale = (rank // 4 + rope) ** -0.5
     layer = jnp.int32(0)
 
     @jax.jit
     def program(q, q_i, w, latent, keys):
-        scores = SI.index_scores(q_i, w, keys, tables, starts, lengths, layer=layer)
+        scores = SI.index_scores(
+            q_i, w, keys, tables, starts, lengths, layer=layer, kernel=kernel
+        )
         picked, chosen = SI.select_topk(scores, topk)
         return scores, picked, chosen
 
@@ -1489,7 +1498,8 @@ def run_sparse_index_checks(
             k = gather_latent(keys, tables, layer).astype(f32)
             s = jnp.einsum("bhd,bsd->bhs", q_i.astype(f32), k)
             scores = jnp.einsum("bh,bhs->bs", w, jax.nn.relu(s))
-            live = jnp.arange(slots)[None, :] < lengths[:, None]
+            slot = jnp.arange(slots)[None, :]
+            live = (slot >= starts[:, None]) & (slot < lengths[:, None])
             scores = jnp.where(live, scores, -jnp.inf)
             order = jnp.argsort(-scores, axis=-1, stable=True)[:, :topk]
             chosen = jnp.take_along_axis(scores, order, axis=-1) > -jnp.inf
@@ -1502,13 +1512,15 @@ def run_sparse_index_checks(
 
     tol = 2.0**-6 if dtype == "bf16" else 1e-4
     rec = {"kernel": "sparse_index", "case": f"lanes={lanes} slots={slots} topk={topk}",
-           "tol": tol}
+           "tol": tol, "form": "pallas" if kernel else "xla"}
     try:
         (scores, picked, chosen), first = _timed(program, q, q_i, w, latent, keys)
         want_scores, order, want_chosen, want_out = reference(q, q_i, w, latent, keys)
         got_out = attend(q, latent, order.astype(jnp.int32), want_chosen)
         scores, want_scores = np.asarray(scores), np.asarray(want_scores)
         live = np.isfinite(want_scores)
+        if not np.array_equal(live, scores > -np.inf):
+            raise AssertionError("the scores are -inf at other slots than the reference's")
         spread = np.asarray([want_scores[b][live[b]].std() for b in range(lanes)])
         err_i = float(np.max(
             np.abs(np.where(live, scores, 0.0) - np.where(live, want_scores, 0.0))
@@ -1539,18 +1551,25 @@ def timed_sparse_index(
     calls: int = 20, repeats: int = 3,
 ) -> list[dict]:
     """A decode step's three pieces alone on the clock, microseconds a call a
-    layer with every row at ``lengths`` cached tokens: the index's scores,
-    the choice, and the attention over the chosen (``sparse_us``: flat in the
-    cached length where its bytes follow the tokens chosen)."""
+    layer with every row at ``lengths`` cached tokens, then with the rows as
+    the benchmark's cell holds them (``cached_tokens`` "mixed": 9 of 16 live
+    at about 8k tokens, the rest dead lanes of one slot): the index's scores
+    (``scores_us``: the Pallas kernel where the widths tile, and then its
+    time follows what the rows hold), the choice, and the attention over the
+    chosen (``sparse_us``: flat in the cached length where its bytes follow
+    the tokens chosen). A row also says what its floor is of: ``live_rows``,
+    ``scanned_tokens``, ``chosen_tokens``."""
     from cake_tpu.ops import sparse_index as SI
+    from cake_tpu.ops.pallas.index_scores import paged_index_scores_supported
 
     dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    kernel = paged_index_scores_supported(page_size, index_dim, index_heads)
     latent, keys, q, q_i, w, tables = _sparse_index_operands(
         n_heads, rank, rope, index_heads, index_dim, page_size, lanes,
         table_pages, layers, dt,
     )
     scale = (rank // 4 + rope) ** -0.5
-    starts = jnp.zeros((lanes,), jnp.int32)
+    slots = table_pages * page_size
 
     def chained(fn):
         @jax.jit
@@ -1564,21 +1583,33 @@ def timed_sparse_index(
         _timed(fn, *args)
         return round(min(_timed(fn, *args)[1] for _ in range(repeats)) / calls * 1e6, 1)
 
-    score = chained(lambda li, q_i, w, keys, lens: SI.index_scores(
-        q_i, w, keys, tables, starts, lens, layer=li))
+    score = chained(lambda li, q_i, w, keys, starts, lens: SI.index_scores(
+        q_i, w, keys, tables, starts, lens, layer=li, kernel=kernel))
     table_rows = SI.pool_rows(tables, page_size)
     select = chained(lambda li, scores: SI.select_topk(scores + li, topk, table_rows)[0])
     attend = chained(lambda li, q, latent, picked, chosen: SI.sparse_latent_attention(
         q, latent, picked, chosen, layer=li, rank=rank, scale=scale))
+    sets = [(min(n, slots), np.full((lanes,), min(n, slots), np.int32)) for n in lengths]
+    # The cell's rows: 9 of 16 live at 3/8 of the table on average (8k of
+    # 21,504), a dead lane one slot (``starts = ends - 1``).
+    n_live = max(1, lanes * 9 // 16)
+    held = np.ones((lanes,), np.int32)
+    held[:n_live] = np.linspace(slots * 9 // 32, slots * 15 // 32, n_live)
+    sets.append(("mixed", held))
+    starts = jnp.zeros((lanes,), jnp.int32)
     rows = []
-    for length in lengths:
-        length = min(length, table_pages * page_size)
-        lens = jnp.full((lanes,), length, jnp.int32)
+    for label, held in sets:
+        lens = jnp.asarray(held)
         scores = SI.index_scores(q_i, w, keys, tables, starts, lens, layer=jnp.int32(0))
         picked, chosen = SI.select_topk(scores, topk, table_rows)
+        live = held > 1  # a dead lane holds its one slot
         rows.append({
-            "cached_tokens": length,
-            "scores_us": us(score, q_i, w, keys, lens),
+            "cached_tokens": label,
+            "live_rows": int(live.sum()),
+            "scanned_tokens": int(held[live].sum()),
+            "chosen_tokens": int(np.minimum(held, topk)[live].sum()),
+            "scores_form": "pallas" if kernel else "xla",
+            "scores_us": us(score, q_i, w, keys, starts, lens),
             "select_us": us(select, scores),
             "sparse_us": us(attend, q, latent, picked, chosen),
         })

@@ -13,8 +13,11 @@ towards the smaller position.
 
 Two forms of one arithmetic, as latent attention's own:
 
-  * **decode** (one query a row): ``index_scores`` over the row's pages of the
-    index pool, ``select_topk`` (one sort a row, the chosen tokens' rows of
+  * **decode** (one query a row): ``index_scores`` over the row's LIVE pages
+    of the index pool, read in place (the Pallas kernel
+    ops/pallas/index_scores.py on the chip: its time follows what the rows
+    hold; the XLA twin gathers the row's whole table of keys and scores all
+    of it), ``select_topk`` (one sort a row, the chosen tokens' rows of
     the pool riding through it), ``sparse_latent_attention``: the chosen rows
     of the LATENT pool are gathered, ``index_topk`` of them whatever the row
     holds, and the absorbed attention runs over those: its bytes follow the
@@ -28,7 +31,7 @@ Two forms of one arithmetic, as latent attention's own:
     ``masked_latent_attention`` as its XLA twin.
 
 Every function enters its own scope (``obs/taxonomy``: nested inside
-``mixer``); the chip's numbers are in PERF.md section 6, PR 43.
+``mixer``); the chip's numbers are in PERF.md section 6, PR 43 and PR 44.
 """
 
 from __future__ import annotations
@@ -38,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from cake_tpu.models.llama.paged_cache import gather_latent
+from cake_tpu.ops.pallas.index_scores import paged_index_scores
 
 INDEX_SCORES, INDEX_SELECT, SPARSE_ATTENTION = (
     "index_scores", "index_select", "sparse_attention",
@@ -65,10 +69,19 @@ def index_scores(
     lengths: jnp.ndarray,  # [b] one past the last live slot
     *,
     layer: jnp.ndarray,
+    kernel: bool = False,
 ) -> jnp.ndarray:
     """``I`` [b, table slots] float32 of one query a row against the row's
-    pages of the index pool; ``-inf`` where a slot holds no token of the row."""
+    pages of the index pool; ``-inf`` where a slot holds no token of the row.
+    With ``kernel`` the Pallas kernel ops/pallas/index_scores.py, which reads
+    the pool in place and walks a row's LIVE pages only; without it (the CPU,
+    shapes that do not tile) the XLA twin, which gathers the row's whole
+    table of keys and scores all of it."""
     with jax.named_scope(INDEX_SCORES):
+        if kernel:
+            return paged_index_scores(
+                q_i, w, index_pool, block_tables, starts, lengths, layer=layer
+            )
         keys = gather_latent(index_pool, block_tables, layer)  # [b, S, dim]
         s = jnp.einsum(
             "bhd,bsd->bhs", q_i.astype(keys.dtype), keys,

@@ -1114,9 +1114,13 @@ class PagedLatentIndexBackend(PagedLatentBackend):
         ``scanned`` cached tokens their queries scored, ``chosen`` tokens
         attention then read; ``traced``: the same of the chunks DISPATCHED
         while a profiler session was open (what a device trace's times are
-        of); ``join``: the same of the joins' windows."""
+        of); ``join``: of the joins' windows; ``scores_form``: the form of a
+        decode step's index scores, ``"pallas"`` (the pool in place) or ``"xla"``."""
+        from cake_tpu.models.llama.latent_index import scores_form
+
         return {
             "index_topk": self.config.index_topk, **self.sparse_counts,
+            "scores_form": scores_form(self.config, self.page_size, self.allow_pallas),
             "traced": {"index_topk": self.config.index_topk, **self.sparse_traced},
             "join": dict(self.sparse_join_counts),
         }

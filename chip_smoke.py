@@ -1311,7 +1311,7 @@ def phase_sparse(args, preset) -> dict:
     arch = architecture(REPO, g["model"])
     problems = []
     for c in (r for r in records if r["kind"] == "case"):
-        say(f"phase=S kernel={c['kernel']} {c['case']}: "
+        say(f"phase=S kernel={c['kernel']} {c['case']} scores={c.get('form')}: "
             + (f"max_err={c['max_err']:.3g} of tol {c['tol']:.3g} "
                f"score_err_in_spreads={c['score_err_in_spreads']:.3g} "
                f"chosen_overlap min={c['chosen_overlap_min']:.4f} "
@@ -1321,20 +1321,34 @@ def phase_sparse(args, preset) -> dict:
             problems.append(f"{c['kernel']} {c['case']}")
     where = "" if not args.rehearse_cpu else " (cpu rehearsal: no device time)"
     rows = next(r for r in records if r["kind"] == "timed")["rows"]
-    lanes, topk = g["lanes"], g["model"]["index_topk"]
+    topk = g["model"]["index_topk"]
     for row in rows:
-        n = row["cached_tokens"]
-        ops, moved = arch.index_scores_cost(g["model"], lanes, lanes * n, preset["dtype"])
+        ops, moved = arch.index_scores_cost(
+            g["model"], row["live_rows"], row["scanned_tokens"], preset["dtype"])
         scores_floor = max(ops / 197e12, moved / 819e9) * 1e6
         ops, moved = arch.sparse_attention_cost(
-            g["model"], lanes, lanes * min(n, topk), preset["dtype"])
+            g["model"], row["live_rows"], row["chosen_tokens"], preset["dtype"])
         sparse_floor = max(ops / 197e12, moved / 819e9) * 1e6
-        say(f"phase=S alone{where}, cached_tokens={n} a row: scores_us={row['scores_us']} "
-            f"(floor {scores_floor:.1f}) select_us={row['select_us']} "
+        say(f"phase=S alone{where}, cached_tokens={row['cached_tokens']} a row "
+            f"(live_rows={row['live_rows']} scanned={row['scanned_tokens']}): "
+            f"scores_us={row['scores_us']} ({row['scores_form']}, floor {scores_floor:.1f}) "
+            f"select_us={row['select_us']} "
             f"sparse_us={row['sparse_us']} (floor {sparse_floor:.1f})")
-    sparse = [row["sparse_us"] for row in rows if row["cached_tokens"] >= topk]
+    uniform = [row for row in rows if row["cached_tokens"] != "mixed"]
+    sparse = [row["sparse_us"] for row in uniform if row["cached_tokens"] >= topk]
     if not args.rehearse_cpu and sparse and max(sparse) > 1.3 * min(sparse):
         problems.append(f"sparse attention follows the cached length: {sparse} us")
+    # The scores' kernel walks a row's live pages: its time follows what the
+    # rows hold (the XLA form's was flat: it gathered the whole table).
+    shortest, longest = uniform[0], uniform[-1]
+    if not args.rehearse_cpu and (
+        longest["cached_tokens"] >= 4 * shortest["cached_tokens"]
+        and shortest["scores_us"] > 0.5 * longest["scores_us"]
+    ):
+        problems.append(
+            f"the index scores do not follow the cached length: {shortest['scores_us']} us at "
+            f"{shortest['cached_tokens']} tokens a row, {longest['scores_us']} at "
+            f"{longest['cached_tokens']}")
     out = {"cases": sum(r["kind"] == "case" for r in records)}
     for r in (r for r in records if r["kind"] == "program"):
         moved = r["scans"] + ([] if args.rehearse_cpu else r["pool_ops"])

@@ -271,6 +271,80 @@ def test_the_window_kernel_is_its_twin():
     assert np.abs(out[False][rows]).max() > 1.0
 
 
+# (starts, lengths) of the row under test, in a 16-page table of 128-token
+# pages scored 8 pages a group; a plain full row rides beside it.
+_SCORE_ROWS = {
+    "pads-in-front": (300, 2048),
+    "dead-tail": (0, 777),
+    "length-on-a-page-boundary": (0, 1024),
+    "a-dead-lanes-one-slot": (1299, 1300),
+    "an-unmapped-table-entry": (0, 1100),  # the pages behind the row are -1
+    "not-whole-groups": (130, 1300),  # pages 1..10: both groups partly live
+    "one-token-on-a-groups-first-slot": (1024, 1025),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SCORE_ROWS))
+def test_the_scores_kernel_is_its_twin(case):
+    """ops/pallas/index_scores.py (interpreted here) against the XLA twin at
+    the published index sizes (64 heads x 128, pages of 128, bf16): equal to
+    float32 rounding where a slot is live, ``-inf`` exactly where the twin's
+    is."""
+    heads, dim, page, table, layers = 64, 128, 128, 16, 2
+    start, length = _SCORE_ROWS[case]
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    n_pages = 2 * table + 1
+    pool = jax.random.normal(ks[0], (layers, n_pages, page, dim), jnp.bfloat16)
+    q_i = jax.random.normal(ks[1], (2, heads, dim), jnp.bfloat16)
+    w = jax.random.normal(ks[2], (2, heads), jnp.float32) * (heads * dim) ** -0.5
+    tables = 1 + np.random.default_rng(1).permutation(2 * table).reshape(2, table).astype(np.int32)
+    if case == "an-unmapped-table-entry":
+        tables[0, -(-length // page):] = -1
+    starts, lengths = jnp.asarray([start, 0], jnp.int32), jnp.asarray([length, table * page], jnp.int32)
+    got, want = (np.asarray(SI.index_scores(
+        q_i, w, pool, jnp.asarray(tables), starts, lengths, layer=jnp.int32(1), kernel=kernel,
+    )) for kernel in (True, False))
+    live = want > -np.inf
+    assert live.sum() == length - start + table * page and np.array_equal(got > -np.inf, live)
+    assert np.all(np.isneginf(got[~live]))
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    assert np.abs(want[live]).max() > 0.5
+    if case == "not-whole-groups":  # the pages a loop turn takes are the sweep's handle, not the result's
+        from cake_tpu.ops.pallas.index_scores import paged_index_scores
+
+        by_two = np.asarray(paged_index_scores(
+            q_i, w, pool, jnp.asarray(tables), starts, lengths, layer=jnp.int32(1), group=2))
+        assert np.array_equal(by_two > -np.inf, live)
+        np.testing.assert_allclose(by_two[live], got[live], atol=2e-5)
+        with pytest.raises(ValueError, match="does not divide"):
+            paged_index_scores(q_i, w, pool, jnp.asarray(tables), starts, lengths,
+                               layer=jnp.int32(1), group=5)
+
+
+def test_which_form_the_decode_steps_scores_take():
+    """The predicate on shapes both ways, and ``scores_form`` as the decode
+    program and ``GET /stats`` read it: the kernel switch AND the shapes."""
+    from cake_tpu.ops.pallas.index_scores import pages_a_group, paged_index_scores_supported
+
+    assert paged_index_scores_supported(128, 128, 64)
+    assert not paged_index_scores_supported(16, 128, 64)  # a page of no whole lane tile
+    assert not paged_index_scores_supported(128, 16, 64)  # nor a key
+    assert not paged_index_scores_supported(128, 128, 4)  # heads of no whole sublane tile
+    assert [pages_a_group(n) for n in (168, 42, 16, 7, 13)] == [8, 7, 8, 7, 1]
+    published = LlamaConfig.from_hf_dict({**TINY, "index_n_heads": 64, "index_head_dim": 128})
+    pallas = dataclasses.replace(published, attention_impl="pallas")
+    assert LI.scores_form(pallas, 128, True) == "pallas"
+    assert LI.scores_form(pallas, 128, False) == "xla"  # the kernel switch
+    assert LI.scores_form(pallas, 16, True) == "xla"  # the page
+    assert LI.scores_form(dataclasses.replace(published, attention_impl="xla"), 128, True) == "xla"
+    tiny = dataclasses.replace(LlamaConfig.from_hf_dict(TINY), attention_impl="pallas")
+    assert LI.scores_form(tiny, 128, True) == "xla"  # 4 heads x 16
+    with pytest.raises(ValueError, match="use the XLA twin"):
+        SI.index_scores(jnp.zeros((1, 4, 16)), jnp.zeros((1, 4)), jnp.zeros((1, 2, 16, 16)),
+                        jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
+                        jnp.ones((1,), jnp.int32), layer=jnp.int32(0), kernel=True)
+
+
 def test_pangus_cache_keeps_its_pytree_and_this_one_has_two_leaves(tiny):
     from cake_tpu.models.llama import latent as L
 
@@ -329,6 +403,7 @@ def test_the_engine_serves_it_and_counts_what_it_scanned_and_chose(tiny):
     eng.stop()
     assert joined == alone and joins >= 1
     assert sparse["index_topk"] == 8 and sparse["dispatches"] > 0 and sparse["dispatches"] % 3 == 0
+    assert sparse["scores_form"] == "xla"  # the CPU, and 4 index heads x 16
     assert 0 < sparse["chosen"] < sparse["scanned"]  # both lanes are longer than the budget
     assert sparse["chosen"] <= 8 * sparse["rows"] and sparse["rows"] <= 2 * sparse["dispatches"]
     assert set(sparse["traced"]) == {"index_topk", "dispatches", "rows", "scanned", "chosen"}
