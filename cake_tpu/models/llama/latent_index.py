@@ -65,6 +65,7 @@ from cake_tpu.ops import sparse_index as SI
 from cake_tpu.ops.fuse import resolve_fusion
 from cake_tpu.ops.norm import rms_norm
 from cake_tpu.ops.pallas.index_scores import paged_index_scores_supported
+from cake_tpu.ops.pallas.kth_largest import kth_largest_supported
 from cake_tpu.ops.quant import qmat
 from cake_tpu.ops.rope import apply_rope, kind_rope_rows
 
@@ -164,6 +165,15 @@ def scores_form(config: LlamaConfig, page_size: int, allow_pallas: bool) -> str:
     return "pallas" if kernel else "xla"
 
 
+def select_form(config: LlamaConfig, page_size: int, allow_pallas: bool) -> str:
+    """Which form the search of a decode step's choice takes (the k-th
+    largest score a row; the rest of the choice is one form everywhere):
+    ``"pallas"`` (ops/pallas/kth_largest.py: one operation) or ``"xla"`` (the
+    twin: a loop of 32 counts). ``GET /stats`` engine.sparse.select_form."""
+    kernel = _kernel_switch(config, allow_pallas) and kth_largest_supported(page_size)
+    return "pallas" if kernel else "xla"
+
+
 def window_block(rows: int, width: int) -> int:
     """Slots of a row one block of a window takes: the largest number of
     whole 16s that divides the width and keeps the rows' block together
@@ -196,6 +206,7 @@ def latent_index_blocks_forward(
     fusion = resolve_fusion(config, allow_pallas)
     use_kernel = _kernel_switch(config, allow_pallas)
     scores_kernel = scores_form(config, cache.page_size, allow_pallas) == "pallas"
+    select_kernel = select_form(config, cache.page_size, allow_pallas) == "pallas"
     rank, n, rope = config.kv_lora_rank, config.num_attention_heads, config.qk_rope_head_dim
     scale, topk = config.mla_scale, config.index_topk
     b, t, _ = x.shape
@@ -208,9 +219,6 @@ def latent_index_blocks_forward(
         ends=None if decode else ends,
     )
     no_counts = jnp.zeros((len(MOE_COUNTS),), jnp.int32)
-    if decode:
-        with jax.named_scope(MIXER):  # once a step, not a layer
-            table_rows = SI.pool_rows(block_tables, cache.page_size)
 
     def finish(lp, experts, k, x, attn, valid):
         """A block's tail; (x, MOE_COUNTS of it: zeros of a dense layer)."""
@@ -243,7 +251,9 @@ def latent_index_blocks_forward(
                 q_i[:, 0], w[:, 0], ipool, block_tables, starts, ends, layer=li,
                 kernel=scores_kernel,
             )
-            rows_of, chosen = SI.select_topk(scores, topk, table_rows)
+            rows_of, chosen = SI.select_topk(
+                scores, topk, block_tables, cache.page_size, kernel=select_kernel
+            )
             c = SI.sparse_latent_attention(
                 q_full, pool, rows_of, chosen, layer=li, rank=rank, scale=scale,
             )

@@ -1457,6 +1457,7 @@ def run_sparse_index_checks(
     from cake_tpu.models.llama.paged_cache import gather_latent
     from cake_tpu.ops import sparse_index as SI
     from cake_tpu.ops.pallas.index_scores import paged_index_scores_supported
+    from cake_tpu.ops.pallas.kth_largest import kth_largest_supported
 
     dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
     kernel = paged_index_scores_supported(page_size, index_dim, index_heads)
@@ -1479,14 +1480,16 @@ def run_sparse_index_checks(
         scores = SI.index_scores(
             q_i, w, keys, tables, starts, lengths, layer=layer, kernel=kernel
         )
-        picked, chosen = SI.select_topk(scores, topk)
+        picked, chosen = SI.select_topk(
+            scores, topk, tables, page_size, kernel=kth_largest_supported(page_size)
+        )
         return scores, picked, chosen
 
     table_rows = SI.pool_rows(tables, page_size)
 
     @jax.jit
-    def attend(q, latent, picked, chosen):
-        rows = jnp.take_along_axis(table_rows, picked, axis=1)
+    def attend(q, latent, order, chosen):
+        rows = jnp.take_along_axis(table_rows, order, axis=1)
         return SI.sparse_latent_attention(
             q, latent, rows, chosen, layer=layer, rank=rank, scale=scale
         )
@@ -1527,9 +1530,10 @@ def run_sparse_index_checks(
             / spread[:, None]
         ))
         overlap = []
-        for b in range(lanes):
+        for b in range(lanes):  # as pool rows, which is what the choice carries
             got = set(np.asarray(picked[b])[np.asarray(chosen[b])].tolist())
-            want = set(np.asarray(order[b])[np.asarray(want_chosen[b])].tolist())
+            want = np.asarray(order[b])[np.asarray(want_chosen[b])]
+            want = set(np.asarray(table_rows[b])[want].tolist())
             overlap.append(len(got & want) / len(want))
         got_out, want_out = np.asarray(got_out, np.float32), np.asarray(want_out)
         err_out = float(np.max(np.abs(got_out - want_out)) / np.max(np.abs(want_out)))
@@ -1544,6 +1548,19 @@ def run_sparse_index_checks(
     return [rec]
 
 
+def _select_set_err(scores, picked, chosen, carried, topk) -> int:
+    """Rows whose chosen set (what ``select_topk`` says its choice carries) is
+    not what the first ``topk`` of a stable argsort of ``-scores`` carry."""
+    scores, picked, chosen = (np.asarray(a) for a in (scores, picked, chosen))
+    bad = 0
+    for r in range(scores.shape[0]):
+        order = np.argsort(-scores[r], kind="stable")[:topk]
+        want = carried[r][order[scores[r][order] > -np.inf]]
+        got = picked[r][chosen[r]]
+        bad += not (len(got) == len(want) and set(got.tolist()) == set(want.tolist()))
+    return bad
+
+
 def timed_sparse_index(
     n_heads: int, rank: int, rope: int, index_heads: int, index_dim: int,
     topk: int, page_size: int, lanes: int, table_pages: int, layers: int,
@@ -1555,12 +1572,14 @@ def timed_sparse_index(
     the benchmark's cell holds them (``cached_tokens`` "mixed": 9 of 16 live
     at about 8k tokens, the rest dead lanes of one slot): the index's scores
     (``scores_us``: the Pallas kernel where the widths tile, and then its
-    time follows what the rows hold), the choice, and the attention over the
-    chosen (``sparse_us``: flat in the cached length where its bytes follow
-    the tokens chosen). A row also says what its floor is of: ``live_rows``,
-    ``scanned_tokens``, ``chosen_tokens``."""
+    time follows what the rows hold), the choice (``select_us``, and
+    ``select_set_err``: the rows whose chosen set is not a stable argsort's),
+    and the attention over the chosen (``sparse_us``: flat in the cached
+    length where its bytes follow the tokens chosen). A row also says what
+    its floor is of: ``live_rows``, ``scanned_tokens``, ``chosen_tokens``."""
     from cake_tpu.ops import sparse_index as SI
     from cake_tpu.ops.pallas.index_scores import paged_index_scores_supported
+    from cake_tpu.ops.pallas.kth_largest import kth_largest_supported
 
     dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
     kernel = paged_index_scores_supported(page_size, index_dim, index_heads)
@@ -1585,8 +1604,10 @@ def timed_sparse_index(
 
     score = chained(lambda li, q_i, w, keys, starts, lens: SI.index_scores(
         q_i, w, keys, tables, starts, lens, layer=li, kernel=kernel))
-    table_rows = SI.pool_rows(tables, page_size)
-    select = chained(lambda li, scores: SI.select_topk(scores + li, topk, table_rows)[0])
+    table_rows = np.asarray(SI.pool_rows(tables, page_size))
+    select_kernel = kth_largest_supported(page_size)
+    select = chained(lambda li, scores: SI.select_topk(
+        scores + li, topk, tables, page_size, kernel=select_kernel)[0])
     attend = chained(lambda li, q, latent, picked, chosen: SI.sparse_latent_attention(
         q, latent, picked, chosen, layer=li, rank=rank, scale=scale))
     sets = [(min(n, slots), np.full((lanes,), min(n, slots), np.int32)) for n in lengths]
@@ -1601,7 +1622,7 @@ def timed_sparse_index(
     for label, held in sets:
         lens = jnp.asarray(held)
         scores = SI.index_scores(q_i, w, keys, tables, starts, lens, layer=jnp.int32(0))
-        picked, chosen = SI.select_topk(scores, topk, table_rows)
+        picked, chosen = SI.select_topk(scores, topk, tables, page_size, kernel=select_kernel)
         live = held > 1  # a dead lane holds its one slot
         rows.append({
             "cached_tokens": label,
@@ -1610,7 +1631,9 @@ def timed_sparse_index(
             "chosen_tokens": int(np.minimum(held, topk)[live].sum()),
             "scores_form": "pallas" if kernel else "xla",
             "scores_us": us(score, q_i, w, keys, starts, lens),
+            "select_form": "pallas" if select_kernel else "xla",
             "select_us": us(select, scores),
+            "select_set_err": _select_set_err(scores, picked, chosen, table_rows, topk),
             "sparse_us": us(attend, q, latent, picked, chosen),
         })
     return rows

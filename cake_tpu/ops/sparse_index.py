@@ -17,11 +17,14 @@ Two forms of one arithmetic, as latent attention's own:
     of the index pool, read in place (the Pallas kernel
     ops/pallas/index_scores.py on the chip: its time follows what the rows
     hold; the XLA twin gathers the row's whole table of keys and scores all
-    of it), ``select_topk`` (one sort a row, the chosen tokens' rows of
-    the pool riding through it), ``sparse_latent_attention``: the chosen rows
-    of the LATENT pool are gathered, ``index_topk`` of them whatever the row
-    holds, and the absorbed attention runs over those: its bytes follow the
-    tokens chosen, not the tokens cached;
+    of it), ``select_topk`` (the window's search for the k-th largest, on
+    the chip as one operation, ops/pallas/kth_largest.py; then the chosen
+    set's rows of the pool from counts a page and one one-hot product: dense
+    work only, no sort, and a SET: its order is the slots', not the scores'),
+    ``sparse_latent_attention``: the chosen rows of the
+    LATENT pool are gathered, ``index_topk`` of them whatever the row holds,
+    and the absorbed attention runs over those: its bytes follow the tokens
+    chosen, not the tokens cached;
   * **a window** (prefill, a join), in the mask form (``-inf`` off the chosen
     set) as the published inference code runs a prefill: its queries' choice
     block by block (``window_index_scores``, ``topk_mask``: float32 scores
@@ -31,7 +34,7 @@ Two forms of one arithmetic, as latent attention's own:
     ``masked_latent_attention`` as its XLA twin.
 
 Every function enters its own scope (``obs/taxonomy``: nested inside
-``mixer``); the chip's numbers are in PERF.md section 6, PR 43 and PR 44.
+``mixer``); the chip's numbers are in PERF.md section 6, PRs 43 to 45.
 """
 
 from __future__ import annotations
@@ -42,6 +45,7 @@ import numpy as np
 
 from cake_tpu.models.llama.paged_cache import gather_latent
 from cake_tpu.ops.pallas.index_scores import paged_index_scores
+from cake_tpu.ops.pallas.kth_largest import kth_largest_key
 
 INDEX_SCORES, INDEX_SELECT, SPARSE_ATTENTION = (
     "index_scores", "index_select", "sparse_attention",
@@ -104,27 +108,118 @@ def pool_rows(block_tables: jnp.ndarray, page_size: int) -> jnp.ndarray:
 
 
 def select_topk(
-    scores: jnp.ndarray, k: int, carried: jnp.ndarray | None = None
+    scores: jnp.ndarray,
+    k: int,
+    block_tables: jnp.ndarray | None = None,
+    page_size: int | None = None,
+    *,
+    kernel: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(what the ``k`` largest scores a row carry [b, k'], which of them are
     tokens [b, k']), ``k' = min(k, slots)``; equal scores go to the smaller
-    slot. ``carried`` [b, slots] rides through the sort beside the scores
-    (``pool_rows``: the chosen tokens' rows of the pool come out of the
-    choice itself, where a gather of 2,048 table entries a row afterwards
-    took as long as the sort: PERF.md section 6, PR 43); None carries the
-    slots themselves. A row with fewer live slots than ``k`` gets them all
-    and dead slots marked so. The TPU compiler sorts the whole row for a
-    ``top_k`` of this size too."""
+    slot, ``-inf`` is never chosen. With ``block_tables`` [b, pages] and
+    ``page_size`` a chosen token carries its row of the pool (``pool_rows``'
+    value at its slot), without them its slot. A row with fewer live slots
+    than ``k`` gets them all and the rest marked not chosen. **The order of
+    the ``k'`` entries is no part of the contract**: attention sums over a
+    set (they come out by slot, not by score).
+
+    A table no wider than ``k``: every live slot, as it lies. A wider one:
+    the k-th largest by 32 counts (``_kth_largest``, the window's search;
+    with ``kernel`` one operation, ops/pallas/kth_largest.py: rows of whole
+    128s), the tie rule and then the chosen set's ``k`` pool rows
+    (``_compact``) from counts a GROUP of slots at a time (the page, where it
+    is given and no wider than 256) and the groups' running totals, all of it
+    dense products, compares and sums: no sort, gather, scatter or scan of
+    the row's width. A stable sort of 21,504 scores a row with the rows
+    riding through it took 450 us a layer-step where this takes 95; a gather
+    of 2,048 table entries a row after the choice took as long as that sort
+    (PERF.md section 6, PRs 45 and 43)."""
     with jax.named_scope(INDEX_SELECT):
-        if carried is None:
-            carried = jnp.broadcast_to(
-                jnp.arange(scores.shape[-1], dtype=jnp.int32), scores.shape
+        b, slots = scores.shape
+        if slots <= k:
+            carried = (
+                jnp.broadcast_to(jnp.arange(slots, dtype=jnp.int32), scores.shape)
+                if block_tables is None else pool_rows(block_tables, page_size)
             )
-        k = min(k, scores.shape[-1])
-        top, picked = jax.lax.sort(
-            (-scores, carried), dimension=-1, is_stable=True, num_keys=1
-        )
-        return picked[:, :k], top[:, :k] < jnp.inf
+            return carried, scores > _NEG_INF
+        if block_tables is None:
+            page_size, starts = slots, jnp.zeros((1, 1), jnp.int32)
+        else:
+            starts = jnp.maximum(block_tables, 0).astype(jnp.int32) * page_size
+        g = next(g for g in range(min(page_size, 256), 0, -1) if page_size % g == 0)
+        # [b or 1, groups]: what a group's first slot carries.
+        base = (
+            starts[:, :, None] + jnp.arange(0, page_size, g, dtype=jnp.int32)
+        ).reshape(starts.shape[0], -1)
+        kth, room = _kth_largest(scores, k, kernel)
+        x = scores.reshape(b, slots // g, g)
+        key, kth, room = _sortable(x), kth[:, :, None], room[:, :, None]
+        ties = key == kth
+        inside, _, before = _group_counts(ties)
+        first = inside + before[:, :, None] <= room
+        chosen = ((key > kth) | (ties & first)) & (x > _NEG_INF)
+        return _compact(chosen, base, k)
+
+
+def _ones_below(n: int, strictly: bool = False) -> jnp.ndarray:
+    """[n, n] bfloat16: 1 where the row's index is below (or at) the column's."""
+    i = jnp.arange(n, dtype=jnp.int32)
+    return (i[:, None] < i[None, :] if strictly else i[:, None] <= i[None, :]).astype(
+        jnp.bfloat16
+    )
+
+
+def _group_counts(m: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Of a bool [b, groups, g], ``g`` no more than 256: (the set bits up to
+    and with each slot INSIDE its group [b, groups, g], a group's own count,
+    the count of the groups before it [b, groups]), int32. Two products with
+    triangles of ones: operands of whole numbers up to 256, exact in
+    bfloat16, summed in float32 (a ``cumsum`` over the groups is eight
+    operations in the compiled program, and one over the row's width 60 us)."""
+    f32 = jnp.float32
+    inside = jnp.einsum(
+        "bpi,ig->bpg", m.astype(jnp.bfloat16), _ones_below(m.shape[-1]),
+        preferred_element_type=f32,
+    )
+    total = inside[..., -1]
+    before = jnp.einsum(
+        "bp,pq->bq", total.astype(jnp.bfloat16), _ones_below(m.shape[1], strictly=True),
+        preferred_element_type=f32,
+    )
+    return tuple(a.astype(jnp.int32) for a in (inside, total, before))
+
+
+def _compact(
+    mask: jnp.ndarray,  # [b, groups, g] bool
+    base: jnp.ndarray,  # [b or 1, groups] int32: what a group's first slot carries
+    k: int,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(``base`` of its group + its place in the group, of the j-th set bit
+    of a row's ``mask`` in slot order, j < k: [b, k] int32; j < the row's
+    count: [b, k]). Output slot j lies in the group whose running totals
+    straddle it; ONE product of that choice of group, as a one-hot, with the
+    groups' counts-under-the-mask brings the group's counts beside j, and j's
+    bit is where they equal its rank in the group (a set bit's inclusive
+    count is its rank). ``k`` lies on the lanes throughout, and the one-hot
+    is made where it is used."""
+    inside, total, before = _group_counts(mask)
+    after = before + total
+    under = jnp.where(mask, inside, 0).astype(jnp.bfloat16)  # <= 256: exact
+    j = jnp.arange(k, dtype=jnp.int32)
+    holds = (before[:, :, None] <= j) & (j < after[:, :, None])  # [b, groups, k]
+
+    def of_its_group(a):  # [b or 1, groups] -> [b, k]: a's entry at j's group
+        return jnp.sum(jnp.where(holds, a[:, :, None], 0), axis=1)
+
+    brought = jnp.einsum(
+        "bpg,bpk->bgk", under, holds.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+    rank = j + 1 - of_its_group(before)
+    lane = jnp.arange(mask.shape[-1], dtype=jnp.int32)
+    place = jnp.sum(jnp.where(brought == rank[:, None, :], lane[:, None], 0), axis=1)
+    return of_its_group(base) + place, j < after[:, -1:]
 
 
 def sparse_latent_attention(
@@ -193,33 +288,51 @@ def window_index_scores(
 
 
 def _sortable(x: jnp.ndarray) -> jnp.ndarray:
-    """float32 -> uint32 whose unsigned order is the floats' order."""
-    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    """float32 -> uint32 whose unsigned order is the floats' order, the two
+    zeros one key (a select: ``x + 0.0`` is folded away by the compiler)."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
     return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def _kth_largest(
+    scores: jnp.ndarray, k: int, kernel: bool = False
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Of rows (the last axis) wider than ``k``: (the k-th largest score as
+    ``_sortable``'s key, uint32 [..., 1]; ``k`` less the scores above it,
+    int32 [..., 1]: how many of the scores EQUAL to it the ``k`` hold, the
+    first by position, the caller's to count). The key is found bit by bit:
+    32 counts over the row, no sort; with ``kernel`` (rows [b, slots] of
+    whole 128s) as ONE operation, ops/pallas/kth_largest.py. A row with fewer
+    than ``k`` above ``-inf`` ends at ``-inf``'s key: the caller takes
+    ``-inf`` out."""
+    if kernel:
+        return kth_largest_key(scores, k=k)
+    key = _sortable(scores)
+
+    def bit(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(key >= cand[..., None], axis=-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, prefix)
+
+    kth = jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32)
+    )[..., None]
+    return kth, k - jnp.sum(key > kth, axis=-1, keepdims=True, dtype=jnp.int32)
 
 
 def topk_mask(scores: jnp.ndarray, k: int) -> jnp.ndarray:
     """Bool mask, ``scores``' shape, of the ``k`` largest a row (last axis)
-    that are above ``-inf``; equal scores go to the smaller position. The
-    k-th largest is found bit by bit (32 counts over the row, no sort): a
+    that are above ``-inf``; equal scores go to the smaller position. No
+    sort (``_kth_largest``, which the decode step's choice shares): a
     window's rows are thousands of queries by thousands of keys."""
     with jax.named_scope(INDEX_SELECT):
         if scores.shape[-1] <= k:
             return scores > _NEG_INF
         key = _sortable(scores)
-
-        def bit(i, prefix):
-            cand = prefix | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
-            enough = jnp.sum(key >= cand[..., None], axis=-1, dtype=jnp.int32) >= k
-            return jnp.where(enough, cand, prefix)
-
-        kth = jax.lax.fori_loop(
-            0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32)
-        )[..., None]
-        above, ties = key > kth, key == kth
-        room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+        kth, room = _kth_largest(scores, k)
+        ties = key == kth
         first = jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room
-        return (above | (ties & first)) & (scores > _NEG_INF)
+        return ((key > kth) | (ties & first)) & (scores > _NEG_INF)
 
 
 def window_attention(

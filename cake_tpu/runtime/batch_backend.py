@@ -1115,12 +1115,15 @@ class PagedLatentIndexBackend(PagedLatentBackend):
         attention then read; ``traced``: the same of the chunks DISPATCHED
         while a profiler session was open (what a device trace's times are
         of); ``join``: of the joins' windows; ``scores_form``: the form of a
-        decode step's index scores, ``"pallas"`` (the pool in place) or ``"xla"``."""
-        from cake_tpu.models.llama.latent_index import scores_form
+        decode step's index scores, ``"pallas"`` (the pool in place) or
+        ``"xla"``; ``select_form``: of its choice's search for the k-th
+        largest score, ``"pallas"`` (one operation) or ``"xla"`` (32 counts)."""
+        from cake_tpu.models.llama.latent_index import scores_form, select_form
 
         return {
             "index_topk": self.config.index_topk, **self.sparse_counts,
             "scores_form": scores_form(self.config, self.page_size, self.allow_pallas),
+            "select_form": select_form(self.config, self.page_size, self.allow_pallas),
             "traced": {"index_topk": self.config.index_topk, **self.sparse_traced},
             "join": dict(self.sparse_join_counts),
         }

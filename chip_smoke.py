@@ -1332,8 +1332,11 @@ def phase_sparse(args, preset) -> dict:
         say(f"phase=S alone{where}, cached_tokens={row['cached_tokens']} a row "
             f"(live_rows={row['live_rows']} scanned={row['scanned_tokens']}): "
             f"scores_us={row['scores_us']} ({row['scores_form']}, floor {scores_floor:.1f}) "
-            f"select_us={row['select_us']} "
+            f"select_us={row['select_us']} ({row['select_form']}) select_set_err={row['select_set_err']} "
             f"sparse_us={row['sparse_us']} (floor {sparse_floor:.1f})")
+    wrong = {row["cached_tokens"]: row["select_set_err"] for row in rows if row["select_set_err"]}
+    if wrong:
+        problems.append(f"the choice is not a stable argsort's set: rows wrong by cached_tokens {wrong}")
     uniform = [row for row in rows if row["cached_tokens"] != "mixed"]
     sparse = [row["sparse_us"] for row in uniform if row["cached_tokens"] >= topk]
     if not args.rehearse_cpu and sparse and max(sparse) > 1.3 * min(sparse):

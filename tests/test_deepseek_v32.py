@@ -181,6 +181,168 @@ def test_the_choice_breaks_ties_towards_the_smaller_position():
     assert np.asarray(SI.topk_mask(short, 4)).tolist() == [[True, False, False, True, False, False]]
 
 
+def _choice_case(name):
+    """(scores [b, slots], k, block table or None, page size or None)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    inf = np.inf
+
+    def scattered(b, groups, g, k, quantum=0.0):
+        s = rng.standard_normal((b, groups * g)).astype(np.float32)
+        return (np.round(s / quantum) * quantum if quantum else s), k, None, g
+
+    if name == "ties-across-the-threshold-and-a-groups-edge":
+        # Page of 8, k 5: one score above, then a run of equal scores from
+        # slot 5 to slot 18: the threshold's ties straddle two group edges.
+        s = np.full((2, 32), -1.0, np.float32)
+        s[:, 5:19] = 0.5
+        s[0, 30], s[1, 2] = 2.0, 2.0
+        return s, 5, None, 8
+    if name == "exactly-k-live":
+        s = np.full((2, 64), -inf, np.float32)
+        s[0, rng.permutation(64)[:12]] = rng.standard_normal(12)
+        s[1, 20:32] = 1.0
+        return s, 12, None, 16
+    if name == "fewer-than-k-live":
+        s = np.full((3, 64), -inf, np.float32)
+        s[0, [3, 17, 18, 63]] = [0.1, -2.0, 0.1, 5.0]
+        s[1, 40:49] = rng.standard_normal(9)
+        s[2, 0] = 0.0
+        return s, 12, None, 16
+    if name == "a-dead-lane-of-one-slot":
+        s = rng.standard_normal((3, 96)).astype(np.float32)
+        s[1] = -inf
+        s[1, 77] = -3.0  # ``starts = ends - 1``: the lane's one slot
+        return s, 10, None, 16
+    if name == "both-zeros-are-one-score":
+        # Four above the zeros, room for three of them: the first three by
+        # POSITION, whatever their sign (the sort held -0.0 == +0.0).
+        s = np.where(rng.random((2, 48)) < 0.5, 0.0, -0.0).astype(np.float32)
+        s[:, [7, 21, 22, 40]] = 1.0
+        s[0, :6], s[1, :6] = -0.0, 0.0
+        s[0, 6], s[1, 6] = 0.0, -0.0
+        return s, 7, None, 16
+    if name == "k-not-a-multiple-of-the-group":
+        return scattered(3, 12, 16, 37, quantum=0.25)
+    if name in ("42-groups", "84-groups", "168-groups"):
+        return scattered(2, int(name.split("-")[0]), 8, 100, quantum=0.125)
+    if name == "168-pages-of-128-k-2048":
+        s, k, _, g = scattered(2, 168, 128, 2048, quantum=1 / 64)
+        s[1, 9000:] = -inf
+        return s, k, None, g
+    if name == "pool-rows-through-a-shuffled-table-with-unmapped-pages":
+        b, pages, page = 3, 20, 16
+        s = rng.standard_normal((b, pages * page)).astype(np.float32)
+        s = np.round(s * 4) / 4
+        tables = rng.permutation(b * pages).reshape(b, pages).astype(np.int32)
+        live = [pages * page, 13 * page - 5, 2 * page + 1]
+        for r, n in enumerate(live):  # the pages behind a row's tokens are -1
+            s[r, n:] = -inf
+            tables[r, -(-n // page):] = -1
+        return s, 40, tables, page
+    if name == "a-page-wider-than-a-group-may-be":
+        b, pages, page = 2, 3, 320  # groups of 160 inside a page
+        s = np.round(rng.standard_normal((b, pages * page)).astype(np.float32) * 2) / 2
+        tables = np.asarray([[4, 0, 2], [5, 1, -1]], np.int32)
+        s[1, 500:] = -inf
+        return s, 70, tables, page
+    raise KeyError(name)
+
+
+_CHOICE_CASES = [
+    "ties-across-the-threshold-and-a-groups-edge", "exactly-k-live", "fewer-than-k-live",
+    "a-dead-lane-of-one-slot", "both-zeros-are-one-score", "k-not-a-multiple-of-the-group",
+    "42-groups", "84-groups", "168-groups", "168-pages-of-128-k-2048",
+    "pool-rows-through-a-shuffled-table-with-unmapped-pages",
+    "a-page-wider-than-a-group-may-be",
+]
+
+
+def _select(scores, k, table, page_size):
+    run = jax.jit(lambda s, t: SI.select_topk(s, k, t, page_size))
+    out = run(jnp.asarray(scores), None if table is None else jnp.asarray(table))
+    return tuple(np.asarray(a) for a in out)
+
+
+@pytest.mark.parametrize("case", _CHOICE_CASES)
+def test_the_decode_choice_is_a_stable_argsorts_set(case):
+    """``select_topk`` of a table wider than ``k`` (the k-th largest by
+    counts, the mask compacted by dense products: no sort) against numpy's
+    stable argsort of ``-scores``, AS SETS: what the chosen carry, and how
+    many a row."""
+    scores, k, tables, page = _choice_case(case)
+    assert scores.shape[1] > k
+    if tables is None:
+        # Without a table the group comes from the width alone; the case's
+        # group size is forced through a table of pages in order.
+        in_order = np.arange(scores.shape[1] // page, dtype=np.int32)[None].repeat(len(scores), 0)
+        forms = [(None, None, None), (in_order, page, None)]
+    else:
+        forms = [(tables, page, np.asarray(SI.pool_rows(jnp.asarray(tables), page)))]
+    for table, page_size, carried in forms:
+        picked, chosen = _select(scores, k, table, page_size)
+        assert picked.shape == chosen.shape == (len(scores), k)
+        for r, row in enumerate(scores):
+            order = np.argsort(-row, kind="stable")[:k]
+            want = order[row[order] > -np.inf]
+            want = want if carried is None else carried[r][want]
+            got = picked[r][chosen[r]]
+            assert len(got) == len(want), (case, r)
+            assert sorted(got.tolist()) == sorted(want.tolist()), (case, r)
+
+
+def _topk_mask_as_it_was(scores, k):
+    """``topk_mask`` as PR 43 wrote it, before the decode step's choice
+    shared its search (and before the two zeros shared a key)."""
+    if scores.shape[-1] <= k:
+        return scores > -jnp.inf
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def bit(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(key >= cand[..., None], axis=-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, prefix)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
+    above, ties = key > kth, key == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    first = jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room
+    return (above | (ties & first)) & (scores > -jnp.inf)
+
+
+def _window_scores():
+    """``test_the_window_kernel_is_its_twin``'s: two rows of 256 queries
+    under the causal mask, the second behind pads with a dead tail."""
+    pos = jnp.arange(256)
+    starts, lengths = jnp.asarray([0, 37]), jnp.asarray([256, 200])
+    live = (pos[None, :] >= starts[:, None]) & (pos[None, :] < lengths[:, None])
+    admitted = live[:, None, :] & (pos[None, None, :] <= pos[None, :, None])
+    return jnp.where(admitted, jax.random.normal(jax.random.PRNGKey(6), (2, 256, 256)), -jnp.inf)
+
+
+@pytest.mark.parametrize("case, k", [
+    ("ties", 4), ("short", 4), ("a-window", 20), ("a-window", 1), ("a-window", 255),
+    ("ties-across-the-threshold-and-a-groups-edge", 5), ("k-not-a-multiple-of-the-group", 37),
+    ("168-groups", 100),
+])
+def test_the_windows_masks_are_what_they_were_before_the_search_was_shared(case, k):
+    scores = {
+        "ties": lambda: jnp.asarray([[0.5, 1.0, 0.5, 0.5, -jnp.inf, 0.5, 2.0, 0.5]]),
+        "short": lambda: jnp.asarray([[0.1, -jnp.inf, -jnp.inf, 0.3, -jnp.inf, -jnp.inf]]),
+        "a-window": _window_scores,
+    }.get(case, lambda: jnp.asarray(_choice_case(case)[0]))()
+    got = jax.jit(lambda s: SI.topk_mask(s, k))(scores)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(_topk_mask_as_it_was(scores, k)))
+    assert bool(got.any())
+
+
+def test_the_windows_mask_holds_the_two_zeros_equal_too():
+    scores, k, _, _ = _choice_case("both-zeros-are-one-score")
+    mask = np.asarray(jax.jit(lambda s: SI.topk_mask(s, k))(jnp.asarray(scores)))
+    for r, row in enumerate(scores):
+        assert np.flatnonzero(mask[r]).tolist() == sorted(np.argsort(-row, kind="stable")[:k].tolist())
+
+
 def _recording(monkeypatch, name, keep):
     """Wrap ``sparse_index.<name>`` so that what it returns is also kept."""
     inner = getattr(SI, name)
@@ -345,6 +507,59 @@ def test_which_form_the_decode_steps_scores_take():
                         jnp.ones((1,), jnp.int32), layer=jnp.int32(0), kernel=True)
 
 
+_KTH_ROWS = {  # rows x slots, k
+    "the-cells-table": (16, 168 * 128, 2048),
+    "a-capacity-of-42-pages": (16, 42 * 128, 2048),
+    "rows-of-no-whole-eight": (3, 512, 100),
+    "k-of-one": (8, 256, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KTH_ROWS))
+def test_the_search_kernel_is_its_twin(case):
+    """ops/pallas/kth_largest.py (interpreted here) against the loop of 32
+    counts: the k-th largest key and the room among its equals, bit for bit,
+    over rows with ties at the threshold, a dead tail, a dead lane's one
+    slot, both zeros, fewer than ``k`` live; then the whole choice through
+    either is one set."""
+    b, slots, k = _KTH_ROWS[case]
+    rng = np.random.default_rng(b * slots + k)
+    s = (np.round(rng.standard_normal((b, slots)) * 16) / 16).astype(np.float32)
+    s[0, slots // 3:] = -np.inf
+    s[1, :] = -np.inf
+    s[1, slots // 2] = -2.5
+    s[2] = np.where(rng.random(slots) < 0.5, 0.0, -0.0)
+    s[2, :max(k - 3, 0)] = 1.0
+    if b > 3:
+        s[3, k // 2:] = -np.inf  # fewer than k live
+    scores = jnp.asarray(s)
+    got = jax.jit(lambda x: SI._kth_largest(x, k, True))(scores)
+    want = jax.jit(lambda x: SI._kth_largest(x, k))(scores)
+    assert got[0].dtype == want[0].dtype == jnp.uint32 and got[0].shape == want[0].shape == (b, 1)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    picked, chosen = (np.asarray(a) for a in jax.jit(
+        lambda x: SI.select_topk(x, k, kernel=True))(scores))
+    for r in range(b):
+        order = np.argsort(-s[r], kind="stable")[:k]
+        assert sorted(picked[r][chosen[r]].tolist()) == sorted(order[s[r][order] > -np.inf].tolist())
+
+
+def test_which_form_the_choices_search_takes():
+    from cake_tpu.ops.pallas.kth_largest import kth_largest_key, kth_largest_supported
+
+    assert kth_largest_supported(128) and kth_largest_supported(21504)
+    assert not kth_largest_supported(16)  # a row of no whole lane tiles
+    tiny = LlamaConfig.from_hf_dict(TINY)
+    pallas = dataclasses.replace(tiny, attention_impl="pallas")
+    assert LI.select_form(pallas, 128, True) == "pallas"  # whatever the index's widths
+    assert LI.select_form(pallas, 128, False) == "xla"  # the kernel switch
+    assert LI.select_form(pallas, 16, True) == "xla"  # the page
+    assert LI.select_form(dataclasses.replace(tiny, attention_impl="xla"), 128, True) == "xla"
+    with pytest.raises(ValueError, match="use the XLA twin"):
+        kth_largest_key(jnp.zeros((8, 48)), k=4)
+
+
 def test_pangus_cache_keeps_its_pytree_and_this_one_has_two_leaves(tiny):
     from cake_tpu.models.llama import latent as L
 
@@ -404,6 +619,7 @@ def test_the_engine_serves_it_and_counts_what_it_scanned_and_chose(tiny):
     assert joined == alone and joins >= 1
     assert sparse["index_topk"] == 8 and sparse["dispatches"] > 0 and sparse["dispatches"] % 3 == 0
     assert sparse["scores_form"] == "xla"  # the CPU, and 4 index heads x 16
+    assert sparse["select_form"] == "xla"  # the CPU, and a page of 16
     assert 0 < sparse["chosen"] < sparse["scanned"]  # both lanes are longer than the budget
     assert sparse["chosen"] <= 8 * sparse["rows"] and sparse["rows"] <= 2 * sparse["dispatches"]
     assert set(sparse["traced"]) == {"index_topk", "dispatches", "rows", "scanned", "chosen"}
