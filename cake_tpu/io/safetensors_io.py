@@ -482,29 +482,88 @@ _OLMO_HYBRID_TEMPLATES = {
         **_OLMO_FFN,
     },
 }
-# model_type -> (per-kind tables, the final norm's name)
+# ``model_type: lfm2_moe`` (names ASSUMED: written from memory of
+# transformers' ``Lfm2MoeForCausalLM``; the catalog row carries the config
+# alone). The mixer's table by kind and, beside it, the feed-forward's by
+# ITS kind (``config.run_ff_kinds``): SwiGLU as w1 (gate), w3 (up), w2
+# (down). "experts" = one tensor a HELD expert (``{e}``: its own number on
+# disk), each turned, stacked.
+_LFM2_NORMS = {
+    "ln_attn": ("operator_norm.weight", None),
+    "ln_mlp": ("ffn_norm.weight", None),
+}
+_LFM2_MOE_TEMPLATES = {
+    "attention": {
+        "wq": ("self_attn.q_proj.weight", "T"),
+        "wk": ("self_attn.k_proj.weight", "T"),
+        "wv": ("self_attn.v_proj.weight", "T"),
+        "wo": ("self_attn.out_proj.weight", "T"),
+        "q_norm": ("self_attn.q_layernorm.weight", None),
+        "k_norm": ("self_attn.k_layernorm.weight", None),
+        **_LFM2_NORMS,
+    },
+    "state": {
+        "in_proj": ("conv.in_proj.weight", "T"),
+        # [channels, 1, taps] -> [taps, channels]
+        "conv_w": ("conv.conv.weight", "conv"),
+        "wo": ("conv.out_proj.weight", "T"),
+        **_LFM2_NORMS,
+    },
+    "dense": {
+        "w_gate": ("feed_forward.w1.weight", "T"),
+        "w_up": ("feed_forward.w3.weight", "T"),
+        "w_down": ("feed_forward.w2.weight", "T"),
+    },
+    "sparse": {
+        "router": ("feed_forward.gate.weight", "T"),
+        "router_bias": ("feed_forward.expert_bias", None),
+        "w_gate": ("feed_forward.experts.{e}.w1.weight", "experts"),
+        "w_up": ("feed_forward.experts.{e}.w3.weight", "experts"),
+        "w_down": ("feed_forward.experts.{e}.w2.weight", "experts"),
+    },
+}
+# model_type -> (tables a mixer kind and, where the model's feed-forwards
+# differ, a feed-forward kind; the final norm's name)
 _HYBRID_TABLES = {
     "jamba": (_JAMBA_TEMPLATES, "model.final_layernorm.weight"),
     "olmo_hybrid": (_OLMO_HYBRID_TEMPLATES, "model.norm.weight"),
+    "lfm2_moe": (_LFM2_MOE_TEMPLATES, "model.embedding_norm.weight"),
 }
 
 
-def _hybrid_read(reader: SafetensorsReader, names, how, dtype):
+def _hybrid_table(config: LlamaConfig, kind: str, ff: str) -> dict:
+    """A run's table: its mixer's and, where the model type's tables say the
+    feed-forward apart, its feed-forward's (without a selection bias the
+    config leaves out)."""
+    tables = _HYBRID_TABLES[config.model_type][0]
+    table = {**tables[kind], **tables.get(ff, {})}
+    if not config.router_bias:
+        table.pop("router_bias", None)
+    return table
+
+
+def _hybrid_read(reader: SafetensorsReader, names, how, dtype, config=None):
     if isinstance(names, tuple):
         return jnp.concatenate(
             [_hybrid_read(reader, n, how, dtype) for n in names], axis=-1
         )
     if how == "conv":
         return reader.jax(names, dtype)[:, 0, :].T
+    if how == "experts":
+        first = config.expert_offset
+        return jnp.stack([
+            reader.jax(names.format(e=e), dtype, transpose=True)
+            for e in range(first, first + config.num_local_experts)
+        ])
     return reader.jax(names, dtype, transpose=how == "T")
 
 
 def load_hybrid_layers(
     reader: SafetensorsReader, config: LlamaConfig, dtype
 ) -> list[Params]:
-    """One stacked tree a run of layers of one kind, in the model's order
-    (``config.layer_runs``), from the model type's per-kind HF names."""
-    tables, _ = _HYBRID_TABLES[config.model_type]
+    """One stacked tree a run of layers alike in mixer and feed-forward, in
+    the model's order (``config.layer_runs``), from the model type's HF
+    names."""
     at = lambda i, names: (
         tuple(f"model.layers.{i}.{n}" for n in names)
         if isinstance(names, tuple) else f"model.layers.{i}.{names}"
@@ -512,12 +571,12 @@ def load_hybrid_layers(
     return [
         {
             key: jnp.stack([
-                _hybrid_read(reader, at(i, names), how, dtype)
+                _hybrid_read(reader, at(i, names), how, dtype, config)
                 for i in config.layers_of(kind)[lo:hi]
             ])
-            for key, (names, how) in tables[kind].items()
+            for key, (names, how) in _hybrid_table(config, kind, ff).items()
         }
-        for kind, lo, hi in config.layer_runs
+        for (kind, lo, hi), ff in zip(config.layer_runs, config.run_ff_kinds)
     ]
 
 
@@ -536,20 +595,27 @@ def hybrid_tensor_dict(
     params: Params, config: LlamaConfig, dtype
 ) -> dict[str, np.ndarray]:
     """THE inverse of ``load_hybrid_layers`` (fixtures and round trips)."""
-    tables, final_norm = _HYBRID_TABLES[config.model_type]
+    _, final_norm = _HYBRID_TABLES[config.model_type]
     tensors = {
         "model.embed_tokens.weight": np.asarray(params["embed"].astype(dtype)),
         final_norm: np.asarray(params["ln_f"].astype(dtype)),
     }
     if not config.tie_word_embeddings:
         _emit_tensor(tensors, "lm_head.weight", params["lm_head"], True, dtype)
-    for run, (kind, lo, hi) in zip(params["layers"], config.layer_runs):
-        for key, (names, how) in tables[kind].items():
+    for run, (kind, lo, hi), ff in zip(
+        params["layers"], config.layer_runs, config.run_ff_kinds
+    ):
+        for key, (names, how) in _hybrid_table(config, kind, ff).items():
             for k, i in enumerate(config.layers_of(kind)[lo:hi]):
                 whole = np.asarray(run[key][k].astype(dtype))
                 if isinstance(names, tuple):
                     cuts = np.cumsum(_joined_widths(config, key))[:-1]
                     parts = zip(names, np.split(whole, cuts, axis=-1))
+                elif how == "experts":  # a tensor a held expert, turned back
+                    parts = [
+                        (names.format(e=config.expert_offset + j), a.T)
+                        for j, a in enumerate(whole)
+                    ]
                 else:
                     parts = [(names, whole)]
                 for name, a in parts:

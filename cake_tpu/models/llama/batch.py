@@ -43,10 +43,12 @@ import numpy as np
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.cache import KVCache, init_cache, write_layer
 from cake_tpu.obs.jitwatch import tracked_jit as _tracked_jit
-from cake_tpu.obs.taxonomy import MIXER, SAMPLE
+from cake_tpu.obs.taxonomy import MIXER, MIXER_IN, MIXER_OUT, SAMPLE
 from cake_tpu.models.llama.paged_cache import (
     PagedKVCache,
+    pack_heads,
     paged_write_pool,
+    unpack_heads,
 )
 from cake_tpu.models.llama.chat import Message, encode_dialog
 from cake_tpu.models.llama.config import LlamaConfig
@@ -335,6 +337,8 @@ def batched_blocks_forward(
     block_tables: jnp.ndarray | None = None,
     write_starts: jnp.ndarray | None = None,
     layer_base: int = 0,
+    tail=None,
+    tail_carry=None,
 ) -> tuple[jnp.ndarray, KVCache]:
     """THE pad-aware stacked-layer scan for left-padded batches.
 
@@ -394,6 +398,12 @@ def batched_blocks_forward(
         that other kinds of layer interleave (models/llama/hybrid.py): its
         k-th layer reads and writes pool layer ``layer_base + k``, and the
         pool may hold more layers than the run.
+      tail / tail_carry: (PAGED only) the layer's tail in the caller's hands
+        in ``block_finish``'s place: ``tail(lp, x, attn, k, carry) -> (x,
+        carry)`` with ``k`` the layer's index in the run and ``carry`` riding
+        the scan from ``tail_carry`` (a hybrid stack's sparse attention run:
+        the routed experts outside the scanned tree and the account of them,
+        models/llama/hybrid.py). The carry is then returned third.
     """
     use_pallas = (
         allow_pallas and M.resolve_attention_impl(config.attention_impl) == "pallas"
@@ -468,9 +478,17 @@ def batched_blocks_forward(
     def paged_layer(carry, per_layer):
         # The pool rides in the carry and is only ever written in place and
         # read through ``li``: nothing here may slice a layer out of it.
-        x, k_pool, v_pool = carry
+        x, k_pool, v_pool, *more = carry
         lp, ok, li = per_layer
         q, k, v = qkv(lp, x)
+        # A pool whose rows hold several narrow KV heads side by side
+        # (``paged_cache.kv_pack``: its builder's choice, read off its shape).
+        n_kv, pack = k.shape[2], k_pool.shape[-1] // k.shape[-1]
+        kw = attn_kw
+        if pack > 1:
+            with jax.named_scope(MIXER_IN):
+                q, k, v = pack_heads(q, k, v, pack)
+            kw = {**kw, "scale": attn_kw["scale"] or config.head_dim ** -0.5}
         # One eligibility rule for every paged kernel (the write, decode AND
         # the chunk family): the page must be a whole number of lane tiles.
         # A backend that wanted pallas but lands here surfaces a one-time
@@ -489,12 +507,12 @@ def batched_blocks_forward(
                 if kernel_ok:
                     attn = paged_decode_attention(
                         q, k_pool, v_pool, lengths, block_tables, pads,
-                        lp.get("win_flag"), layer=li, **attn_kw,
+                        lp.get("win_flag"), layer=li, **kw,
                     )
                 else:
                     attn = paged_decode_attention_xla(
                         q, k_pool, v_pool, q_pos, k_pos, block_tables,
-                        window_flag=lp.get("win_flag"), layer=li, **attn_kw,
+                        window_flag=lp.get("win_flag"), layer=li, **kw,
                     )
             elif kernel_ok:
                 # Every paged prefill under pallas is one call. A cached chunk
@@ -508,22 +526,28 @@ def batched_blocks_forward(
                 # pages: no [chunk, chunk] score tensor, O(live) HBM bytes.
                 attn = paged_chunk_attention(
                     q, k_pool, v_pool, q_starts, lengths, pads, block_tables,
-                    lp.get("win_flag"), layer=li, **attn_kw,
+                    lp.get("win_flag"), layer=li, **kw,
                 )
             elif cached_chunk:
                 # XLA: the gathered dense view, the multi-query form of the
                 # paged decode fallback (bit-identical arithmetic).
                 attn = paged_chunk_attention_xla(
                     q, k_pool, v_pool, q_pos, k_pos, block_tables,
-                    window_flag=lp.get("win_flag"), layer=li, **attn_kw,
+                    window_flag=lp.get("win_flag"), layer=li, **kw,
                 )
             else:
                 # Prefill attends over the chunk it just computed — the
                 # dense fresh-chunk arithmetic, no cache read, no gather.
                 attn = gqa_attention(
                     q, k, v, q_pos, k_pos,
-                    window_flag=lp.get("win_flag"), **attn_kw,
+                    window_flag=lp.get("win_flag"), **kw,
                 )
+        if pack > 1:
+            with jax.named_scope(MIXER_OUT):
+                attn = unpack_heads(attn, n_kv, pack)
+        if tail is not None:
+            x, *more = tail(lp, x, attn, li - layer_base, *more)
+            return (x, k_pool, v_pool, *more), None
         x_new = M.block_finish(
             lp, x, attn, config, tp_axis=tp_axis, moe_valid=moe_valid,
             moe_dispatch=moe_dispatch, fusion=fusion,
@@ -588,10 +612,11 @@ def batched_blocks_forward(
         n_run = jax.tree.leaves(layers)[0].shape[0]
         ok = jnp.ones((n_run,), bool) if valid is None else valid
         li = layer_base + jnp.arange(n_run, dtype=jnp.int32)
-        (x, k_out, v_out), _ = jax.lax.scan(
-            paged_layer, (x, kv.k, kv.v), (layers, ok, li)
+        more = () if tail is None else (tail_carry,)
+        (x, k_out, v_out, *more), _ = jax.lax.scan(
+            paged_layer, (x, kv.k, kv.v, *more), (layers, ok, li)
         )
-        return x, PagedKVCache(k=k_out, v=v_out)
+        return (x, PagedKVCache(k=k_out, v=v_out), *more)
     ok = jnp.ones((kv.k.shape[0],), bool) if valid is None else valid
     x, (k_out, v_out) = jax.lax.scan(layer, x, (layers, kv.k, kv.v, ok))
     return x, KVCache(k=k_out, v=v_out)

@@ -57,6 +57,15 @@ REFUSED_BY_KIND = {
 
 
 def _why(config: LlamaConfig) -> str:
+    if config.cache_kind == CACHE_KV_STATE and config.state_shape is None:
+        kept, channels = config.conv_window
+        return (
+            f"{len(config.layers_of(STATE))} of its "
+            f"{config.num_hidden_layers} layers keep the window of a short "
+            f"convolution per lane (its last {kept} inputs of {channels} "
+            "channels) in place of K and V, and this feature restores, "
+            "rewinds, shares or shards K and V only"
+        )
     if config.cache_kind == CACHE_KV_STATE:
         return (
             f"{len(config.layers_of(STATE))} of its "

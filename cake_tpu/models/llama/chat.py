@@ -281,6 +281,16 @@ def encode_dialog_jamba(messages: list[Message]) -> str:
     return "".join(parts)
 
 
+def encode_dialog_lfm2(messages: list[Message]) -> str:
+    """LFM2 template (written from memory of LiquidAI's published chat
+    template; the catalog row carries none): ChatML frames behind the
+    begin-of-text word, no default system prompt:
+
+        <|startoftext|><|im_start|>user\n{u}<|im_end|>\n<|im_start|>assistant\n
+    """
+    return "<|startoftext|>" + encode_dialog_chatml_no_default_system(messages)
+
+
 def encode_dialog_olmo(messages: list[Message]) -> str:
     """OLMo-2 (Tulu) template (written from memory of allenai's published
     chat template; the catalog row carries none):
@@ -376,6 +386,7 @@ DIALOG_ENCODERS = {
     "olmo_hybrid": encode_dialog_olmo,
     "laguna": encode_dialog_laguna,
     "deepseek_v32": encode_dialog_deepseek,
+    "lfm2_moe": encode_dialog_lfm2,
 }
 
 

@@ -23,15 +23,17 @@ from typing import Any
 SUPPORTED_MODEL_TYPES = (
     "llama", "qwen2", "mistral", "mixtral", "qwen2_moe",
     "gemma", "gemma2", "phi3", "qwen3", "qwen3_moe", "gemma3_text", "jamba",
-    "pangu_ultra_moe", "olmo_hybrid", "laguna", "deepseek_v32",
+    "pangu_ultra_moe", "olmo_hybrid", "laguna", "deepseek_v32", "lfm2_moe",
 )
 
 # The two kinds a decoder layer's token mixer can be (``layer_kinds``).
 ATTENTION, STATE = "attention", "state"
 # Which mixer a STATE layer runs (``state_mixer``): Mamba-1's diagonal
 # selective scan over a vector state a channel (ops/ssm.py), or a gated delta
-# rule over a matrix state a head (ops/delta_rule.py).
-MAMBA, GATED_DELTA = "mamba", "gated_delta"
+# rule over a matrix state a head (ops/delta_rule.py), or a gated short
+# convolution whose state is the convolution's window ALONE
+# (ops/short_conv.py: no float32 state at all).
+MAMBA, GATED_DELTA, SHORT_CONV = "mamba", "gated_delta", "short_conv"
 # The two kinds its feed-forward can be (``ff_kinds``).
 DENSE, SPARSE = "dense", "sparse"
 # The kinds an ATTENTION layer can be (``attention_kinds``): every key the
@@ -190,6 +192,9 @@ class LlamaConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = False
+    # The gated short convolution's taps (HF ``conv_L_cache``; ``lfm2_moe``):
+    # a depthwise causal convolution over ``hidden_size`` channels.
+    short_conv_taps: int = 3
     # False = attention carries no positional term at all (Jamba,
     # Olmo-Hybrid: the state layers carry the order).
     use_rope: bool = True
@@ -304,18 +309,29 @@ class LlamaConfig:
 
     @property
     def layer_runs(self) -> tuple[tuple[str, int, int], ...]:
-        """Maximal runs of one kind in the model's order, as (kind, lo, hi)
-        over that KIND's own stack: jamba2-3b walks state[0:7],
-        attention[0:1], state[7:20], attention[1:2], state[20:26]."""
+        """Maximal runs of layers alike in mixer kind AND feed-forward kind
+        (``run_ff_kinds`` says the second) in the model's order, as (kind,
+        lo, hi) over that KIND's own stack: jamba2-3b walks state[0:7],
+        attention[0:1], state[7:20], attention[1:2], state[20:26]; a model
+        whose leading layers are dense and the rest sparse (``lfm2_moe``)
+        state[0:2], attention[0:1], state[2:5], ..."""
         runs: list[tuple[str, int, int]] = []
         seen = {ATTENTION: 0, STATE: 0}
-        for k in self.layer_kinds:
-            if runs and runs[-1][0] == k:
+        last = None
+        for k, f in zip(self.layer_kinds, self.ff_kinds, strict=True):
+            if runs and last == (k, f):
                 runs[-1] = (k, runs[-1][1], runs[-1][2] + 1)
             else:
                 runs.append((k, seen[k], seen[k] + 1))
+            last = (k, f)
             seen[k] += 1
         return tuple(runs)
+
+    @property
+    def run_ff_kinds(self) -> tuple[str, ...]:
+        """The feed-forward kind of each of ``layer_runs``, in their order."""
+        ff = self.ff_kinds
+        return tuple(ff[self.layers_of(k)[lo]] for k, lo, _ in self.layer_runs)
 
     @property
     def ff_kinds(self) -> tuple[str, ...]:
@@ -410,11 +426,14 @@ class LlamaConfig:
         return self.mamba_expand * self.hidden_size
 
     @property
-    def state_shape(self) -> tuple[int, int]:
+    def state_shape(self) -> tuple[int, int] | None:
         """One lane's float32 state in one state layer, as the cache lays it
         out (the minor axis whole 128-lane tiles at published widths):
         Mamba's [d_state, d_inner]; the delta rule's [dk, H * dv], head h's
-        S^T in columns h * dv .. (h + 1) * dv."""
+        S^T in columns h * dv .. (h + 1) * dv. None: the mixer keeps no such
+        state (a short convolution's lane state is its window alone)."""
+        if self.state_mixer == SHORT_CONV:
+            return None
         if self.state_mixer == GATED_DELTA:
             return (
                 self.linear_key_head_dim,
@@ -425,7 +444,10 @@ class LlamaConfig:
     @property
     def conv_window(self) -> tuple[int, int]:
         """(inputs kept, channels) of a state layer's causal convolution:
-        Mamba's over u; the delta rule's over q, k and v side by side."""
+        Mamba's over u; the delta rule's over q, k and v side by side; the
+        short convolution's over ``B * u``, ``hidden_size`` wide."""
+        if self.state_mixer == SHORT_CONV:
+            return (self.short_conv_taps - 1, self.hidden_size)
         if self.state_mixer == GATED_DELTA:
             channels = (
                 2 * self.linear_num_key_heads * self.linear_key_head_dim
@@ -437,9 +459,10 @@ class LlamaConfig:
     @property
     def state_bytes_per_lane(self) -> int:
         """Recurrent state one lane holds over all state layers, whatever
-        their mixer: the float32 state (``state_shape``) and the
-        convolution's window (counted at 2 bytes, the served type)."""
-        rows, cols = self.state_shape
+        their mixer: the float32 state (``state_shape``; none for a mixer
+        that keeps none) and the convolution's window (counted at 2 bytes,
+        the served type)."""
+        rows, cols = self.state_shape or (0, 0)
         kept, channels = self.conv_window
         per_layer = 4 * rows * cols + 2 * kept * channels
         return per_layer * len(self.layers_of(STATE))
@@ -522,6 +545,8 @@ class LlamaConfig:
             return cls._olmo_hybrid_from_hf_dict(d, eos_ids)
         if model_type == "laguna":
             return cls._laguna_from_hf_dict(d, eos_ids)
+        if model_type == "lfm2_moe":
+            return cls._lfm2_moe_from_hf_dict(d, eos_ids)
         if model_type == "phi3" and d.get("rope_scaling"):
             # Phi-3 128k variants use longrope (per-dim su-scaled factors);
             # only the base-rope variants (4k/8k) are supported.
@@ -813,6 +838,73 @@ class LlamaConfig:
             pre_block_norms=False,
             post_block_norms=True,
             qk_norm_whole=True,
+        )
+
+    @classmethod
+    def _lfm2_moe_from_hf_dict(
+        cls, d: dict[str, Any], eos_ids: tuple[int, ...]
+    ) -> "LlamaConfig":
+        """``model_type: lfm2_moe`` (LiquidAI LFM2-8B-A1B): ``layer_types``
+        lists gated short convolutions (``conv``: ``conv_L_cache`` taps over
+        ``B * u``, the lane's state the window alone) beside grouped-query
+        attention (``full_attention``) with a norm a head on q and k BEFORE a
+        rotary term; the first ``num_dense_layers`` feed-forwards are dense
+        SwiGLU, the rest ``num_experts`` routed experts (sigmoid scores, a
+        selection bias where ``use_expert_bias``, no groups, no shared
+        expert), ALL held here. What the config does not say (the tied head,
+        the ids) is DATA here, overridden by the config's own keys."""
+        kinds = {"conv": STATE, "full_attention": ATTENTION}
+        n_layers = int(d.get("num_hidden_layers", 24))
+        raw = d.get("layer_types")
+        if raw is None or len(raw) != n_layers or set(raw) - set(kinds):
+            raise ValueError(
+                f"lfm2_moe needs layer_types: {n_layers} entries "
+                f"(num_hidden_layers) of {sorted(kinds)}, got {raw!r}"
+            )
+        if d.get("conv_bias", False):
+            raise ValueError(
+                "lfm2_moe with conv_bias=true is not supported (the short "
+                "convolution and its projections are read without a bias)"
+            )
+        if d.get("rope_scaling"):
+            raise ValueError("lfm2_moe with rope_scaling is not supported")
+        heads = int(d.get("num_attention_heads", 32))
+        hidden = int(d.get("hidden_size", 2048))
+        head_dim = d.get("head_dim")
+        if head_dim is not None and int(head_dim) * heads == hidden:
+            head_dim = None
+        theta = (d.get("rope_parameters") or {}).get(
+            "rope_theta", d.get("rope_theta", 1000000.0))
+        if "eos_token_id" not in d:
+            eos_ids = (7,)  # <|im_end|>
+        return cls(
+            hidden_size=hidden,
+            intermediate_size=int(d.get("intermediate_size", 7168)),
+            vocab_size=int(d.get("vocab_size", 65536)),
+            num_hidden_layers=n_layers,
+            num_attention_heads=heads,
+            num_key_value_heads=int(d.get("num_key_value_heads", 8)),
+            rms_norm_eps=float(d.get("norm_eps", 1e-5)),
+            rope_theta=float(theta),
+            max_position_embeddings=int(d.get("max_position_embeddings", 128000)),
+            bos_token_id=int(d.get("bos_token_id", 1)),
+            eos_token_ids=eos_ids,
+            tie_word_embeddings=bool(
+                d.get("tie_word_embeddings", d.get("tie_embedding", True))),
+            model_type="lfm2_moe",
+            head_dim_override=None if head_dim is None else int(head_dim),
+            layer_types=tuple(kinds[t] for t in raw),
+            state_mixer=SHORT_CONV,
+            short_conv_taps=int(d.get("conv_L_cache", 3)),
+            qk_norm=True,
+            first_k_dense_replace=int(d.get("num_dense_layers", 2)),
+            num_local_experts=int(d.get("num_experts", 32)),
+            num_experts_per_tok=int(d.get("num_experts_per_tok", 4)),
+            norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+            moe_intermediate_size=int(d.get("moe_intermediate_size", 1792)),
+            moe_scoring="sigmoid",
+            router_bias=bool(d.get("use_expert_bias", True)),
+            routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
         )
 
     @classmethod
@@ -1201,6 +1293,7 @@ class LlamaConfig:
             "olmo_hybrid": "OlmoHybridForCausalLM",
             "laguna": "LagunaForCausalLM",
             "deepseek_v32": "DeepseekV32ForCausalLM",
+            "lfm2_moe": "Lfm2MoeForCausalLM",
         }[self.model_type]
         d: dict[str, Any] = {
             "architectures": [arch],
@@ -1305,6 +1398,23 @@ class LlamaConfig:
                 moe_intermediate_size=self.moe_intermediate_size,
                 num_experts_per_tok=self.num_experts_per_tok,
                 norm_topk_prob=self.norm_topk_prob,
+                routed_scaling_factor=self.routed_scaling_factor,
+            )
+        elif self.model_type == "lfm2_moe":
+            del d["rms_norm_eps"], d["attention_bias"]
+            d.update(
+                norm_eps=self.rms_norm_eps,
+                layer_types=[
+                    "full_attention" if k == ATTENTION else "conv"
+                    for k in self.layer_kinds
+                ],
+                conv_L_cache=self.short_conv_taps, conv_bias=False,
+                num_dense_layers=self.first_k_dense_replace,
+                num_experts=self.num_local_experts,
+                num_experts_per_tok=self.num_experts_per_tok,
+                norm_topk_prob=self.norm_topk_prob,
+                moe_intermediate_size=self.moe_intermediate_size,
+                use_expert_bias=self.router_bias,
                 routed_scaling_factor=self.routed_scaling_factor,
             )
         elif self.num_local_experts:

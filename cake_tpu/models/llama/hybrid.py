@@ -1,5 +1,6 @@
 """Hybrid stacks: layers that keep a recurrent state beside attention
-(``model_type: jamba``: Mamba-1 mixers; ``olmo_hybrid``: a gated delta rule).
+(``model_type: jamba``: Mamba-1 mixers; ``olmo_hybrid``: a gated delta rule;
+``lfm2_moe``: gated short convolutions, and routed experts in most layers).
 
 Every other family is a stack of identical attention blocks: one stacked
 tree, one ``lax.scan`` (models/llama/batch.batched_blocks_forward), K and V
@@ -8,20 +9,30 @@ the only per-lane device state. A hybrid model has layers of two KINDS
 per-lane state, so here:
 
   * ``params["layers"]`` is a LIST of stacked trees, one a maximal run of
-    layers of one kind in the model's order (``config.layer_runs``; Jamba2-3B:
+    layers alike in mixer AND feed-forward in the model's order
+    (``config.layer_runs`` with ``config.run_ff_kinds``; Jamba2-3B:
     7 state, 1 attention, 13 state, 1 attention, 6 state; Olmo-Hybrid: 3
-    state, 1 attention, a period). A run is one ``lax.scan``. The attention
+    state, 1 attention, a period; LFM2 cut to 16: 2 state dense, then 1
+    attention and 3 state, all sparse, a period). A run is one ``lax.scan``;
+    a sparse run's routed experts ride outside the scanned tree, whole, with
+    the layer's index (``latent.py``'s rule), its tail runs a block of tokens
+    at a time where a window is wide (``kinds.py``'s rule), and the programs
+    of a model that has a sparse layer return the account of them
+    (``latent.MOE_COUNTS``) third. The attention
     runs go through the paged branch of ``batched_blocks_forward`` itself
     (same kernels, same write), told which pool layers they own; a state run
     scans the layer's mixer, which the config names (``config.state_mixer``:
-    ``ops/ssm.mixer_forward`` or ``ops/delta_rule.mixer_forward``). Both
+    ``ops/ssm.mixer_forward``, ``ops/delta_rule.mixer_forward`` or
+    ``ops/short_conv.mixer_forward``). Both
     kinds share the block's tail (residual, norms where the tree has them,
     SwiGLU: ``model.block_finish``; a state layer's out-projection is its
     ``wo``).
   * ``HybridCache`` is the one cache value: a ``PagedKVCache`` that holds
     the ATTENTION layers only, and the lane state ``ssm`` / ``conv`` of the
-    state layers (``config.state_shape`` / ``conv_window``: the mixer's),
-    indexed by lane and not by page. It is passed wherever the
+    state layers (``config.state_shape`` / ``conv_window``: the mixer's;
+    ``ssm`` is None for a mixer whose state is its window alone: nothing is
+    allocated, carried or stepped for it), indexed by lane and not by page.
+    It is passed wherever the
     paged backend passes ``kv``, donated, and carried through every scan: a
     layer reads and writes its slice in place (PR 26's rule, extended to the
     state: ``pool_audit.audit_programs``).
@@ -44,11 +55,15 @@ import jax.numpy as jnp
 
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.config import (
-    ATTENTION, GATED_DELTA, STATE, LlamaConfig,
+    ATTENTION, DENSE, GATED_DELTA, SHORT_CONV, SPARSE, STATE, LlamaConfig,
 )
-from cake_tpu.models.llama.paged_cache import PagedKVCache, init_paged_cache
-from cake_tpu.obs.taxonomy import CACHE_WRITE, MIXER, MIXER_IN
+from cake_tpu.models.llama.latent import _EXPERT_STACKS, MOE_COUNTS, _add_counts
+from cake_tpu.models.llama.paged_cache import (
+    PagedKVCache, init_paged_cache, kv_pack,
+)
+from cake_tpu.obs.taxonomy import CACHE_WRITE, FEED_FORWARD, MIXER, MIXER_IN
 from cake_tpu.ops import delta_rule as D
+from cake_tpu.ops import short_conv as C
 from cake_tpu.ops import ssm as S
 from cake_tpu.ops.fuse import resolve_fusion
 from cake_tpu.ops.norm import rms_norm
@@ -62,7 +77,8 @@ class HybridCache(NamedTuple):
     dv)``); ``ssm`` is float32 (an accumulator), ``conv`` the served type."""
 
     kv: PagedKVCache  # the attention layers' page pool, [n_attention, ...]
-    ssm: jnp.ndarray  # [n_state, lanes, *state_shape] float32
+    # [n_state, lanes, *state_shape] float32; None where the mixer keeps none
+    ssm: jnp.ndarray | None
     conv: jnp.ndarray  # [n_state, taps - 1, lanes, channels]
 
 
@@ -72,29 +88,47 @@ def init_hybrid_cache(
     """Zeroed: a lane's recurrence starts from s = 0 and a window of zeros."""
     n_state = len(config.layers_of(STATE))
     kept, channels = config.conv_window
+    # Heads narrower than a lane tile lie side by side in the pool's rows
+    # (LFM2's 64: two a row), and the attention runs pack to match.
+    pack = kv_pack(config.num_key_value_heads, config.head_dim)
     return HybridCache(
         kv=init_paged_cache(
             len(config.layers_of(ATTENTION)), n_pages,
-            config.num_key_value_heads, page_size, config.head_dim, dtype,
+            config.num_key_value_heads // pack, page_size,
+            config.head_dim * pack, dtype,
         ),
-        ssm=jnp.zeros((n_state, lanes, *config.state_shape), jnp.float32),
+        ssm=None if config.state_shape is None else jnp.zeros(
+            (n_state, lanes, *config.state_shape), jnp.float32),
         conv=jnp.zeros((n_state, kept, lanes, channels), dtype),
     )
 
 
 # ------------------------------------------------------------------ params
 
-def run_shapes(config: LlamaConfig, kind: str) -> dict[str, tuple[int, ...]]:
-    """Per-layer shapes of one kind's tree (stacked over its run), as this
-    module holds them. Matrices are [in, out] like every other weight here;
+def run_shapes(
+    config: LlamaConfig, kind: str, ff: str = DENSE
+) -> dict[str, tuple[int, ...]]:
+    """Per-layer shapes of one run's tree (stacked over its run), as this
+    module holds them: the mixer of ``kind`` and the feed-forward of ``ff``.
+    Matrices are [in, out] like every other weight here;
     Mamba's ``A_log`` is stored [d_state, d_inner] and a ``conv_w`` [taps,
     channels] (io/safetensors_io.py transposes both); a state layer's
     out-projection is its ``wo``. The delta rule's ``in_proj`` is q | k | v |
     z side by side, ``ab_proj`` a | b and ``conv_w`` q's, k's and v's taps
-    (the loader joins the checkpoint's tensors). Which norms a layer has is
-    the config's (``pre_block_norms`` / ``post_block_norms``)."""
+    (the loader joins the checkpoint's tensors); the short convolution's
+    ``in_proj`` is B | C | u as the checkpoint has it. Which norms a layer
+    has is the config's (``pre_block_norms`` / ``post_block_norms``). A
+    sparse feed-forward is a ``router``, its selection bias where the config
+    has one, and the stacked experts (``latent.run_shapes``' names)."""
     h, inter = config.hidden_size, config.intermediate_size
-    ffn = {"w_gate": (h, inter), "w_up": (h, inter), "w_down": (inter, h)}
+    if ff == SPARSE:
+        e, inter = config.num_local_experts, config.moe_intermediate_size
+        ffn = {"router": (h, config.n_router_experts)}
+        if config.router_bias:
+            ffn["router_bias"] = (config.n_router_experts,)
+        ffn.update(w_gate=(e, h, inter), w_up=(e, h, inter), w_down=(e, inter, h))
+    else:
+        ffn = {"w_gate": (h, inter), "w_up": (h, inter), "w_down": (inter, h)}
     if config.pre_block_norms:
         ffn.update(ln_attn=(h,), ln_mlp=(h,))
     if config.post_block_norms:
@@ -103,7 +137,15 @@ def run_shapes(config: LlamaConfig, kind: str) -> dict[str, tuple[int, ...]]:
         hd = config.head_dim
         q, kv = config.num_attention_heads * hd, config.num_key_value_heads * hd
         qk = {"q_norm": (q,), "k_norm": (kv,)} if config.qk_norm_whole else {}
+        if config.qk_norm:  # a norm a head (Qwen3's), its weight the heads share
+            qk = {"q_norm": (hd,), "k_norm": (hd,)}
         return {"wq": (h, q), "wk": (h, kv), "wv": (h, kv), "wo": (q, h), **qk, **ffn}
+    if config.state_mixer == SHORT_CONV:
+        kept, channels = config.conv_window
+        return {
+            "in_proj": (h, 3 * channels), "conv_w": (kept + 1, channels),
+            "wo": (channels, h), **ffn,
+        }
     if config.state_mixer == GATED_DELTA:
         heads, dv = config.linear_num_value_heads, config.linear_value_head_dim
         kept, channels = config.conv_window
@@ -124,12 +166,15 @@ def run_shapes(config: LlamaConfig, kind: str) -> dict[str, tuple[int, ...]]:
 # How ``init_params`` draws a name: norms (and Mamba's D) are ones; the
 # convolution's taps and the gates' terms are drawn wide enough (0.2) for
 # the recurrence to be visible at a tiny width (alpha and beta spread, beta
-# on both sides of 1); everything else at 0.02.
+# on both sides of 1; a router's selection bias, so that the chosen set is
+# the biased scores' and not the scores'); everything else at 0.02.
 _ONES = frozenset((
     "D", "dt_ln", "b_ln", "c_ln", "ln_attn", "ln_mlp", "ln_post_attn",
     "ln_post_mlp", "q_norm", "k_norm", "o_norm",
 ))
-_WIDE = frozenset(("conv_w", "conv_b", "A_log", "dt_bias", "ab_proj"))
+_WIDE = frozenset((
+    "conv_w", "conv_b", "A_log", "dt_bias", "ab_proj", "router_bias",
+))
 
 
 def init_params(config: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> M.Params:
@@ -143,8 +188,10 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> M.Pa
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
     runs = []
-    for r, (kind, lo, hi) in enumerate(config.layer_runs):
-        shapes = run_shapes(config, kind)
+    for r, ((kind, lo, hi), ff) in enumerate(
+        zip(config.layer_runs, config.run_ff_kinds, strict=True)
+    ):
+        shapes = run_shapes(config, kind, ff)
         keys = jax.random.split(jax.random.fold_in(key, r), len(shapes))
         runs.append({
             name: draw(k, name, (hi - lo, *shape))
@@ -174,11 +221,14 @@ def window_form(config: LlamaConfig, allow_pallas: bool) -> str | None:
     """Which form a window (``L > 1``: every prefill and join) of the state
     layers' recurrence takes, decided once from the config's widths and the
     kernel switch: ``"pallas"`` (``ops/pallas/delta_rule.py`` or
-    ``selective_scan.py``) or ``"xla"`` (the twin); None without state
-    layers. ``GET /stats`` engine.state.window_form."""
+    ``selective_scan.py``) or ``"xla"`` (the twin; a short convolution has
+    no other form); None without state layers. ``GET /stats``
+    engine.state.window_form."""
     if not config.layers_of(STATE):
         return None
     switch = _kernel_switch(config, allow_pallas)
+    if config.state_mixer == SHORT_CONV:
+        return "xla"
     if config.state_mixer == GATED_DELTA:
         kernel = D.window_in_kernel(
             config.state_shape, config.linear_num_value_heads, switch)
@@ -197,6 +247,8 @@ def _state_ops(config: LlamaConfig):
             D, {"neg_eigval": config.linear_allow_neg_eigval},
             (config.linear_num_value_heads,),
         )
+    if config.state_mixer == SHORT_CONV:
+        return C, {}, ()
     return S, {}, ()
 
 
@@ -204,7 +256,9 @@ def _steps_in_place(config: LlamaConfig, ssm, switch: bool) -> bool:
     """Whether a decode step updates the stack's state ``ssm`` ([n_state,
     lanes, *state_shape]; its shape is all that is read) in place through the
     mixer's Pallas kernel, which takes the stack whole: the kernel switch and
-    widths that tile."""
+    widths that tile. Never where the mixer keeps no such state."""
+    if ssm is None:
+        return False
     ops, _, widths = _state_ops(config)
     return switch and ops.steps_in_place(ssm, *widths)
 
@@ -217,9 +271,39 @@ def step_form(config: LlamaConfig, allow_pallas: bool) -> str | None:
     without state layers. ``GET /stats`` engine.state.step_form."""
     if not config.layers_of(STATE):
         return None
-    stack = jax.ShapeDtypeStruct((1, 1, *config.state_shape), jnp.float32)
+    stack = config.state_shape and jax.ShapeDtypeStruct(
+        (1, 1, *config.state_shape), jnp.float32)
     kernel = _steps_in_place(config, stack, _kernel_switch(config, allow_pallas))
     return "pallas" if kernel else "xla"
+
+
+def _routed_tail(lp, x, gated, live, k, config: LlamaConfig, fusion):
+    """``block_finish`` of a sparse layer whose tree holds its RUN's expert
+    stacks (``k``: the layer's index in them), a block of tokens at a time
+    where the window is wide (``kinds._TAIL_TOKENS``: the grouped experts'
+    rows and their combine stay a block's): (x, ``moe.held_counts`` of the
+    layer, summed over its blocks and the largest load the largest)."""
+    from cake_tpu.models.llama.kinds import _tail_block
+
+    def finish(x, gated, live):
+        return M.block_finish(
+            lp, x, gated, config, moe_valid=live, fusion=fusion,
+            moe_counts=True, moe_layer=k,
+        )
+
+    b, t, _ = x.shape
+    block = _tail_block(t, b)
+    if block == t:
+        return finish(x, gated, live)
+    n = b * t // block
+    _, (out, counts) = jax.lax.scan(lambda _, args: (None, finish(*args)), None, (
+        x.reshape(n, 1, block, -1), gated.reshape(n, 1, block, *gated.shape[2:]),
+        live.reshape(n, 1, block),
+    ))
+    counts = jnp.concatenate(
+        [jnp.sum(counts[:, :-1], axis=0), jnp.max(counts[:, -1:], axis=0)]
+    )
+    return out.reshape(x.shape), counts
 
 
 def hybrid_blocks_forward(
@@ -241,13 +325,17 @@ def hybrid_blocks_forward(
     cached_chunk: bool = False,
     write_starts: jnp.ndarray | None = None,
     allow_pallas: bool = True,
-) -> tuple[jnp.ndarray, HybridCache]:
-    """The model's layers in order, run by run. With ``lane`` (a traced
+):
+    """The model's layers in order, run by run: (x, cache), and where the
+    model has a sparse layer a third value, the pass's account of them
+    (``latent.MOE_COUNTS``; a position that is not ``live`` takes no
+    expert's rows and is not counted). With ``lane`` (a traced
     scalar; every prefill) the rows of ``x`` are NEW tenants of lanes
     ``lane``, ``lane + 1``, ...: their recurrence starts from zero and their
     final state OVERWRITES those lanes' (never continues the last tenant's).
     Without it (decode) row r of ``x`` continues lane r's state."""
-    from cake_tpu.models.llama.batch import batched_blocks_forward
+    from cake_tpu.models.llama.batch import batched_blocks_forward, paged_seq_len
+    from cake_tpu.ops.rope import model_rope_tables
 
     fusion = resolve_fusion(config, allow_pallas)
     use_pallas = _kernel_switch(config, allow_pallas)
@@ -267,12 +355,26 @@ def hybrid_blocks_forward(
     if lane is not None:
         lanes = jnp.arange(conv.shape[2], dtype=jnp.int32)
         mine = (lanes >= lane) & (lanes < lane + rows)
+    cos = sin = None
+    if config.use_rope:
+        # The attention layers' rotary term, at a row's own positions
+        # (``q_pos``: relative to its pad, whatever slot its window starts at).
+        cos, sin = model_rope_tables(config, paged_seq_len(kv, block_tables))
 
-    def state_layer(carry, per_layer):
+    def routed(lp, x, gated, k, counts, *, experts):
+        """A sparse layer's tail and the account with it. The run's routed
+        experts ride outside the scanned tree, whole, with the layer's index
+        (``latent.latent_blocks_forward`` says why)."""
+        x, c = _routed_tail({**lp, **experts}, x, gated, live, k, config, fusion)
+        with jax.named_scope(FEED_FORWARD):  # the account is the experts' own
+            one = jnp.ones((1,), jnp.int32)
+            return x, _add_counts(counts, jnp.concatenate([one, c]))
+
+    def state_layer(carry, per_layer, *, experts=None):
         # The lane state rides in the carry like the page pool: a layer
         # takes its own slice and puts it back in place.
-        x, ssm, conv = carry
-        lp, li = per_layer
+        x, ssm, conv, *counts = carry
+        lp, li, *k = per_layer
         # The layer's own slices of the state are the mixer's inputs; what
         # goes back into the carry is the program's cache write.
         with jax.named_scope(MIXER_IN):
@@ -282,6 +384,12 @@ def hybrid_blocks_forward(
             gated, ssm, c_l = ops.mixer_step_stacked(
                 lp, h, ssm, li, c_old, live, eps, **of_config
             )
+        elif ssm is None:
+            # The window is all the state there is: a new tenant's starts
+            # from zeros, a decode step continues the lane's own.
+            c_l = c_old if lane is None else jnp.zeros(
+                (conv.shape[1], rows, conv.shape[3]), conv.dtype)
+            gated, _, c_l = mixer(lp, h, None, c_l, live, ends)
         elif lane is None:
             with jax.named_scope(MIXER):
                 s_l = jax.lax.dynamic_index_in_dim(ssm, li, 0, keepdims=False)
@@ -297,6 +405,8 @@ def hybrid_blocks_forward(
                 ssm = jax.lax.dynamic_update_slice(
                     ssm, s_l[None], (li, lane, zero, zero)
                 )
+        if lane is not None:
+            with jax.named_scope(CACHE_WRITE):
                 # The window's lane axis is a tiled one ([.., lanes,
                 # channels]): an update-slice at a lane there makes the TPU
                 # compiler re-lay the whole array out and back (two copies
@@ -309,24 +419,50 @@ def hybrid_blocks_forward(
                 c_l = jnp.where(mine[None, :, None], placed, c_old)
         with jax.named_scope(CACHE_WRITE):
             conv = jax.lax.dynamic_update_index_in_dim(conv, c_l, li, 0)
-        x = M.block_finish(lp, x, gated, config, fusion=fusion)
-        return (x, ssm, conv), None
+        if experts is None:
+            x = M.block_finish(lp, x, gated, config, fusion=fusion)
+        else:
+            x, *counts = routed(lp, x, gated, *k, *counts, experts=experts)
+        return (x, ssm, conv, *counts), None
 
-    for lp, (kind, lo, hi) in zip(runs, config.layer_runs, strict=True):
+    counts = None
+    if SPARSE in config.ff_kinds:
+        counts = jnp.zeros((len(MOE_COUNTS),), jnp.int32)
+    for lp, (kind, lo, hi), ff in zip(
+        runs, config.layer_runs, config.run_ff_kinds, strict=True
+    ):
+        more, experts = {}, None
+        if ff == SPARSE:
+            experts = {k: lp[k] for k in _EXPERT_STACKS}
+            lp = {k: v for k, v in lp.items() if k not in _EXPERT_STACKS}
         if kind == ATTENTION:
-            x, kv = batched_blocks_forward(
-                lp, x, kv, None, None, q_pos, k_pos, config,
+            if experts is not None:
+                more = dict(
+                    tail=functools.partial(routed, experts=experts),
+                    tail_carry=counts,
+                )
+            x, kv, *got = batched_blocks_forward(
+                lp, x, kv, cos, sin, q_pos, k_pos, config,
                 decode=decode, pads=pads, lengths=lengths,
                 write_pos=write_pos, allow_pallas=allow_pallas,
                 block_tables=block_tables, layer_base=lo,
-                cached_chunk=cached_chunk, write_starts=write_starts,
+                cached_chunk=cached_chunk, write_starts=write_starts, **more,
             )
         else:
-            li = jnp.arange(lo, hi, dtype=jnp.int32)
-            (x, ssm, conv), _ = jax.lax.scan(
-                state_layer, (x, ssm, conv), (lp, li)
+            # a sparse run carries the account and scans its layers' indices
+            # in the run's expert stacks beside their indices in the state (a
+            # dense run's body keeps its own name: it is in the lowered text)
+            sparse = experts is not None
+            (x, ssm, conv, *got), _ = jax.lax.scan(
+                functools.partial(state_layer, experts=experts) if sparse else state_layer,
+                (x, ssm, conv, *((counts,) if sparse else ())),
+                (lp, jnp.arange(lo, hi, dtype=jnp.int32),
+                 *((jnp.arange(hi - lo, dtype=jnp.int32),) if sparse else ())),
             )
-    return x, HybridCache(kv=kv, ssm=ssm, conv=conv)
+        if got:
+            (counts,) = got
+    cache = HybridCache(kv=kv, ssm=ssm, conv=conv)
+    return (x, cache) if counts is None else (x, cache, counts)
 
 
 def hybrid_prefill(
@@ -340,22 +476,25 @@ def hybrid_prefill(
     start: jnp.ndarray | int = 0,
     lane: jnp.ndarray | int = 0,
     allow_pallas: bool = True,
-) -> tuple[jnp.ndarray, HybridCache]:
+):
     """Every prefill of a hybrid model: the rows are NEW tenants of lanes
     ``lane``... (``block_tables`` holds those lanes' rows), each row's
     tokens sit at slots [pads, ends) of a window that starts at ``start``.
     An epoch's prefill starts at 0 and ends every row at the shared slot; a
     joiner's window is only as wide as its prompt and ENDS at the shared
     slot — neither kind of layer needs the slots before it (the state
-    layers start from zero, and the attention layers carry no positional
-    term and read only this row's keys), so a join costs its prompt, not
+    layers start from zero, and the attention layers read only this row's
+    keys and carry no positional term, or a rotary one at the row's OWN
+    positions, which count from its pad), so a join costs its prompt, not
     the batch's slot.
 
     The attention layers run the paged cached-chunk arithmetic (the window's
     K and V are written through the table, then read back with the pool's
     prefix: ``batch.paged_suffix_prefill``'s grids); the state layers'
     recurrence stands still wherever the window is not the row's. Logits
-    are the first row's last slot's, ``ends[0] - 1``: the shared slot."""
+    are the first row's last slot's, ``ends[0] - 1``: the shared slot; a
+    third value, the window's account of its sparse layers
+    (``latent.MOE_COUNTS``), where the model has one."""
     from cake_tpu.models.llama.batch import paged_seq_len, verify_positions
 
     b, w = tokens.shape
@@ -365,14 +504,14 @@ def hybrid_prefill(
     q_pos, k_pos, _ = verify_positions(w, pads, start, capacity)
     grid = start + jnp.arange(w, dtype=jnp.int32)[None, :]
     live = (grid >= pads[:, None]) & (grid < ends[:, None])
-    x, cache = hybrid_blocks_forward(
+    x, cache, *counts = hybrid_blocks_forward(
         params["layers"], x, cache, q_pos, k_pos, config,
         decode=False, cached_chunk=True, pads=pads, lengths=ends,
         write_pos=start, write_starts=pads, block_tables=block_tables,
         live=live, ends=ends - start, lane=jnp.asarray(lane, jnp.int32),
         allow_pallas=allow_pallas,
     )
-    return M.head_forward(params, x, ends[0] - start, config), cache
+    return M.head_forward(params, x, ends[0] - start, config), cache, *counts
 
 
 def hybrid_forward_one(
@@ -386,7 +525,8 @@ def hybrid_forward_one(
 ):
     """``batch.paged_forward_one`` for a hybrid model: one token of every
     lane through the by-run walk, the whole ``HybridCache`` the carried
-    cache (``programs.decode_program`` scans it)."""
+    cache (``programs.decode_program`` scans it); the step's account of its
+    sparse layers rides back beside it where the model has one."""
     from cake_tpu.models.llama.batch import decode_positions
 
     fusion = resolve_fusion(config, allow_pallas)
@@ -394,13 +534,13 @@ def hybrid_forward_one(
     def forward_one(tok, cache, slot):
         x = M.embed_tokens(params, tok, config)
         q_pos, k_pos, lengths = decode_positions(slot, pads, padded_seq)
-        x, cache = hybrid_blocks_forward(
+        x, cache, *counts = hybrid_blocks_forward(
             params["layers"], x, cache, q_pos, k_pos, config,
             decode=True, pads=pads, lengths=lengths, write_pos=slot,
             block_tables=block_tables, live=live, ends=None,
             allow_pallas=allow_pallas,
         )
         logits = M.head_forward(params, x, jnp.int32(1), config, fusion=fusion)
-        return logits, cache
+        return logits, cache, *counts
 
     return forward_one

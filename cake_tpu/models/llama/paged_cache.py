@@ -105,6 +105,55 @@ def init_paged_cache(
     return _zero_pool(shape, jnp.dtype(dtype))
 
 
+_LANES = 128
+
+
+def kv_pack(n_kv_heads: int, head_dim: int) -> int:
+    """KV heads a pool's row may hold SIDE BY SIDE. A head narrower than the
+    128 lanes of a TPU tile is stored padded to them (``[page_size, 64]`` in
+    bf16 takes the room of ``[page_size, 128]``: twice the pool, twice the
+    bytes a decode step's attention streams, and the pool's write as a kernel
+    cannot cut a 64-wide slab out of a 128-wide tile at all). So where whole
+    heads fill a tile exactly, a pool is built ``[..., n_kv / pack, page_size,
+    pack * head_dim]`` (``init_paged_cache``'s caller passes those numbers) and
+    the layer that reads and writes it packs its q, K and V to match
+    (``pack_heads``): every kernel then sees heads of 128. 1 = as projected
+    (every head of 128 or more; heads that do not fill a tile in wholes)."""
+    pack = _LANES // head_dim if head_dim < _LANES and _LANES % head_dim == 0 else 1
+    return pack if n_kv_heads % pack == 0 else 1
+
+
+def _own_slot(n_q: int, n_kv: int, pack: int, dtype) -> jnp.ndarray:
+    """[n_q, pack] one-hot: which of its packed KV head's ``pack`` slots query
+    head ``i`` reads (its own KV head ``i // group`` is slot ``% pack`` of
+    packed head ``// pack``: grouped-query order is kept, the groups grow)."""
+    slot = (np.arange(n_q) // (n_q // n_kv)) % pack
+    return jnp.asarray(np.eye(pack)[slot], dtype)
+
+
+def pack_heads(q, k, v, pack: int):
+    """q [b, t, n_q, d], K and V [b, t, n_kv, d] for a pool whose rows hold
+    ``pack`` KV heads side by side: K and V are a reshape ([b, t, n_kv / pack,
+    pack * d]); a query head keeps its numbers in its own KV head's slot and
+    zeros in the others, so its score against a packed key is its score
+    against its own key (the other slots add exact zeros) and its weighted
+    sum of packed values holds its own in that slot (``unpack_heads``). The
+    score scale stays the unpacked head's."""
+    b, t, n_q, d = q.shape
+    n_kv = k.shape[2]
+    own = _own_slot(n_q, n_kv, pack, q.dtype)
+    q = (q[:, :, :, None, :] * own[:, :, None]).reshape(b, t, n_q, pack * d)
+    return q, k.reshape(b, t, n_kv // pack, pack * d), v.reshape(b, t, n_kv // pack, pack * d)
+
+
+def unpack_heads(attn, n_kv: int, pack: int):
+    """Attention over packed heads [b, t, n_q, pack * d] -> each query head's
+    own slot [b, t, n_q, d]."""
+    b, t, n_q, w = attn.shape
+    own = _own_slot(n_q, n_kv, pack, attn.dtype)
+    return jnp.sum(attn.reshape(b, t, n_q, pack, w // pack) * own[:, :, None], axis=3)
+
+
 def _zero_pool_impl(shape, dtype) -> PagedKVCache:
     return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 

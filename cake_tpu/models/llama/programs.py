@@ -39,7 +39,7 @@ from cake_tpu.models.llama import latent_index as LI
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.config import (
     ATTENTION, CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT,
-    CACHE_LATENT_INDEX, LlamaConfig,
+    CACHE_LATENT_INDEX, SPARSE, LlamaConfig,
 )
 from cake_tpu.models.llama.fused import sampled_decode_scan
 from cake_tpu.models.llama.paged_cache import init_paged_cache
@@ -106,6 +106,9 @@ class CacheKind:
     # What the programs return beside the tokens, one vector an account, in
     # order (``latent.ExpertAccount``, ``latent_index.IndexAccount``).
     accounts: tuple[type, ...] = ()
+    # Which models of the kind return them (``accounts_of``): a kind whose
+    # models may have no layer to count returns none for those.
+    counts_where: Callable = lambda config: True
     # (``/stats`` section, key, predicate(config, page_size, allow_pallas)).
     forms: tuple[tuple[str, str, Callable], ...] = _STATE_FORMS
     # (config, allocator, page_size, dtype) -> what ``engine.cache`` adds.
@@ -115,6 +118,11 @@ class CacheKind:
     more_programs: Mapping[str, tuple[Callable, tuple[str, ...]]] = dataclasses.field(
         default_factory=dict, hash=False, compare=False
     )
+
+
+    def accounts_of(self, config: LlamaConfig) -> tuple[type, ...]:
+        """The accounts this model's programs return beside their tokens."""
+        return self.accounts if self.counts_where(config) else ()
 
 
 def _kv_token_bytes(layers: Callable) -> Callable:
@@ -177,7 +185,12 @@ _ALL = (
         token_bytes=_kv_token_bytes(lambda c: c.layers_of(ATTENTION)),
         pools=lambda cache: (cache.kv.k,),
         window_operands=("start", "lane"), closes_over_capacity=True,
-        lane_state=lambda cache: (cache.ssm, cache.conv),
+        # ``ssm`` is None where the mixer's state is its window alone
+        lane_state=lambda cache: tuple(
+            a for a in (cache.ssm, cache.conv) if a is not None),
+        # routed experts beside state layers (``lfm2_moe``): the account for a
+        # model that has a sparse layer, nothing for one that has none
+        accounts=(L.ExpertAccount,), counts_where=lambda c: SPARSE in c.ff_kinds,
     ),
     CacheKind(
         name=CACHE_LATENT, label="latent", module="paged_latent",
@@ -288,7 +301,7 @@ def decode_program(
     programs count, the chunk's count vectors as one."""
     fusions, fimpl = resolve_fusion(config, allow_pallas)
     tail_impl = fimpl if "tail" in fusions else None
-    accounts = kind.accounts
+    accounts = kind.accounts_of(config)
 
     def run(params, cache, tok, slot, pads, block_tables, *rest):
         *valid, key, ring, ring_idx = rest

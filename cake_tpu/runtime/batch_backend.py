@@ -396,8 +396,8 @@ class _PagedBackend:
         # by ``/stats`` section. The API reads the PRESENCE of ``moe_facts``
         # and ``sparse_facts`` (runtime/api.py): a kind whose programs count
         # neither reports neither.
-        self._accounts = {a.section: a(config) for a in self.kind.accounts}
-        self._traced = any(a.keeps_traced for a in self.kind.accounts)
+        self._accounts = {a.section: a(config) for a in self.kind.accounts_of(config)}
+        self._traced = any(a.keeps_traced for a in self._accounts.values())
         self._chunk_counters = None
         for section in self._accounts:
             setattr(self, f"{section}_facts",
@@ -691,8 +691,18 @@ class _PagedBackend:
         if self.kind.lane_state is not None:
             self.state_lane_writes += tokens.shape[0]
         width = tokens.shape[1]
+        spare = None
+        if self.shapes.whole_batch:
+            # Every epoch is ``max_batch`` lanes wide: a spare lane's dummy
+            # row is not run. It holds no pages, so its K and V would drop,
+            # a joiner starts its lane's state from zeros, and nobody reads
+            # its logits.
+            spare = ~(self.allocator.block_tables[:tokens.shape[0]] >= 0).any(axis=1)
         logits = []
         for index, rows in enumerate(groups):
+            if spare is not None and spare[rows].all():
+                logits.append(None)
+                continue
             table = jax.tree.map(lambda t: t[rows], tables)
             with self._group_span(index, rows, width):
                 if self.shapes.one_row_prefill_is_join and rows.stop - rows.start == 1:
@@ -711,6 +721,13 @@ class _PagedBackend:
                         allow_pallas=self.allow_pallas,
                     )
             logits.append(out)
+        if spare is not None:
+            ran = next(out for out in logits if out is not None)
+            logits = [
+                jnp.zeros((rows.stop - rows.start, *ran.shape[1:]), ran.dtype)
+                if out is None else out
+                for out, rows in zip(logits, groups)
+            ]
         return self._group_logits(logits), kv
 
     def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
@@ -823,9 +840,13 @@ class _PagedBackend:
                     cache, blank, zeros[:1], jnp.asarray([slots], jnp.int32), 0
                 )
             else:
+                # an epoch's slots are whole chunks from a width on: its last
+                # chunk is what is left under the ceiling
+                tail = op == "decode_tail"
+                n = self.shapes.decode_steps(n_steps, slots, slots - n_steps) if tail else n_steps
                 self.set_epoch_capacity(slots)
                 cache = self.decode(
-                    cache, zeros, 0, zeros, keys, ring, zeros, n_steps, sampling
+                    cache, zeros, 0, zeros, keys, ring, zeros, n, sampling
                 )[1]
         jax.block_until_ready(cache)
         self.set_epoch_capacity(None)

@@ -12,8 +12,8 @@ instances that differ in data, picked by ``for_model`` from the config alone:
   * CLOSED, for a model whose cache is not plain K and V (state layers:
     programs that compile in 3 to 10 s each; a latent pool or a pool a kind
     of attention layer: its prefill computes a window's own K and V, so a
-    window is a whole prompt): eleven window widths (six for pools a kind)
-    and three capacities, shares of a lane's table. An epoch's prefill is right-padded to a width (a dead tail under
+    window is a whole prompt): eleven window widths (six for pools a kind,
+    and for state layers beside routed experts) and three capacities, shares of a lane's table. An epoch's prefill is right-padded to a width (a dead tail under
     ``ends``: the recurrence stands still there), a joiner's window is as
     wide as its prompt and ends at the shared slot, and the few dozen
     programs there are run once at start-up (``programs``).
@@ -29,7 +29,7 @@ import dataclasses
 from cake_tpu.models.llama.batch import prompt_bucket
 from cake_tpu.models.llama.config import (
     CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT, CACHE_LATENT_INDEX,
-    GATED_DELTA,
+    GATED_DELTA, SPARSE,
 )
 
 # The closed tables as shares of a lane's table: widths in 64ths of its
@@ -51,6 +51,34 @@ _WIDTH_64THS_BY_KIND = {
     CACHE_KV_KINDS: (4, 8, 16, 32, 48, 64),
     CACHE_LATENT_INDEX: (8, 16, 24, 40, 56, 64),
 }
+# State layers beside routed experts (``kv+state`` with a sparse feed-forward:
+# ``lfm2_moe``): PROGRAMS ARE DEAR AND DEAD LANES CHEAP, and one rule follows
+# (``_dear_programs``). The body of every run of layers but the first holds
+# the grouped experts' sort and three grouped products, so a program is code
+# by the run: at nine runs, compiled for a described v5e at 64 lanes of 32
+# pages, a decode chunk is 11.8 MB, a join 9.1 (64 slots) to 28.6 (4,096), an
+# epoch's group 21.6 to 23.4 (PERF.md section 4): the kind's eleven joins,
+# eleven groups and three chunks are 490 MB for a 192 MiB compile cache, and
+# each is 8 to 20 s of compile that stalls every live stream when it is met
+# inside a window (16 to 22 of them in 51 s: my chip call 2, PR 48). So:
+#   * six widths (these shares), not the kind's eleven;
+#   * an epoch's rows ONE a program, which is then the join's program of that
+#     width (``one_row_prefill_is_join``): no group programs at all. The
+#     experts somebody chose are read once a program either way, so 64 rows
+#     of 400 tokens one at a time stream them 64 times: 0.8 s beside 0.4 s of
+#     products at the chip's peak, once a segment;
+#   * every epoch ``max_batch`` lanes wide (``whole_batch``), the chunks that
+#     run into a capacity warmed too: a hybrid's lane state has a lane axis,
+#     so its programs are compiled a lane count, and a dead lane here costs
+#     next to nothing (it takes no expert's rows and the attention kernel
+#     walks one slot of it). The closed set is then ALL the model runs.
+_DEAR_WIDTH_64THS = (4, 8, 16, 32, 48, 64)
+
+
+def _dear_programs(config) -> bool:
+    return config.cache_kind == CACHE_KV_STATE and SPARSE in config.ff_kinds
+
+
 _CAPACITY_QUARTERS = (1, 2, 4)
 # Tokens a prefill program may hold, by cache kind. State layers: the mixer's
 # float32 intermediates are [rows, width, d_inner] several times over, so an
@@ -116,6 +144,10 @@ class ProgramShapes:
     # width (the backend dispatches it so: ``_PagedBackend.prefill``), and
     # is not run ahead a second time.
     one_row_prefill_is_join: bool = False
+    # Every epoch has ``max_batch`` lanes, whatever seeds it (``lanes``), and
+    # the chunk that runs into an epoch's capacity is warmed with the others
+    # (``programs``: "decode_tail").
+    whole_batch: bool = False
 
     @classmethod
     def for_model(cls, config, page_size: int = 0, pages_per_seq: int = 0):
@@ -130,22 +162,26 @@ class ProgramShapes:
         if config.cache_kind == CACHE_KV:
             return cls()
         slots = page_size * pages_per_seq
-        widths = {
-            min(slots, -(-slots * f // (64 * 64)) * 64)
-            for f in _WIDTH_64THS_BY_KIND.get(config.cache_kind, _WIDTH_64THS)
-        }
+        dear = _dear_programs(config)
+        shares = _DEAR_WIDTH_64THS if dear else (
+            _WIDTH_64THS_BY_KIND.get(config.cache_kind, _WIDTH_64THS))
+        widths = {min(slots, -(-slots * f // (64 * 64)) * 64) for f in shares}
         pages = {max(1, -(-pages_per_seq * q // 4)) for q in _CAPACITY_QUARTERS}
         return cls(
             widths=tuple(sorted(widths)),
             capacities=tuple(p * page_size for p in sorted(pages)),
-            prefill_tokens=_prefill_tokens(config),
-            one_row_prefill_is_join=config.cache_kind == CACHE_LATENT_INDEX,
+            prefill_tokens=1 if dear else _prefill_tokens(config),
+            one_row_prefill_is_join=dear or config.cache_kind == CACHE_LATENT_INDEX,
+            whole_batch=dear,
         )
 
     def lanes(self, n_seed: int, max_batch: int) -> int:
         """Lanes of an epoch seeded with ``n_seed`` rows: the next power of
         two, doubled once (joins need free lanes), capped (light load must
-        not pay ``max_batch``-wide programs)."""
+        not pay ``max_batch``-wide programs); all of them where the model's
+        programs are dear and its dead lanes cheap (``whole_batch``)."""
+        if self.whole_batch:
+            return max_batch
         b = 1
         while b < n_seed:
             b *= 2
@@ -205,10 +241,15 @@ class ProgramShapes:
         """What a saturated server dispatches at ``lanes`` lanes, as
         (operation, rows, slots): an epoch's prefill and a join at each
         width, a decode chunk at each capacity. Empty when open: nothing can
-        be run ahead of a set that has no end."""
+        be run ahead of a set that has no end. "decode_tail": the chunk that
+        runs into the capacity (``decode_steps``: what is left under the
+        ceiling, a program of its own step count)."""
         out = []
         for width in self.widths:
             if not (self.one_row_prefill_is_join and self.prefill_group(lanes, width) == 1):
                 out.append(("prefill", lanes, width))
             out.append(("join", 1, width))
-        return (*out, *(("decode", lanes, c) for c in self.capacities))
+        out += [("decode", lanes, c) for c in self.capacities]
+        if self.whole_batch:
+            out += [("decode_tail", lanes, c) for c in self.capacities]
+        return tuple(out)

@@ -43,6 +43,13 @@ every phase runs in a child that has exited before the next one starts.
            clock, one sparse layer's 16 held experts of 256 by the dense
            combine and by the grouped path, and its decode chunk and join
            compiled: no copy of the latent pool
+  F        a model of gated short convolutions and routed experts, all held,
+           at lfm2-8b-a1b-chat-closed's geometry: one period of the stack
+           (conv, conv, attention, conv; a dense and three sparse layers) at
+           the published widths through a join and a decode chunk for real,
+           the kernels' programs against their XLA twins' (heads of 64 two a
+           pool row of 128), and the cell's decode chunk and join compiled:
+           no copy of the pool or of the convolutions' windows
   D        four chips: the phase-A server under --tp 4 and as a four-stage
            pipeline, with per-device memory (skipped below four devices)
 
@@ -70,7 +77,7 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, ".chip_smoke")  # listed in .gitignore
-PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "O", "L", "S", "D")
+PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "O", "L", "S", "F", "D")
 
 MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
     model_type="mistral", hidden_size=4096, intermediate_size=14336,
@@ -93,6 +100,7 @@ JAMBA2_3B = _benchmark_model("ai21-jamba2-3b")
 PANGU_EP16 = _benchmark_model("openpangu-ultra-moe-718b-ep16")
 OLMO_HYBRID_D16 = _benchmark_model("olmo-hybrid-7b-d16")
 DEEPSEEK_V32_EP16 = _benchmark_model("deepseek-v3.2-exp-ep16-d5")
+LFM2_D16 = _benchmark_model("lfm2-8b-a1b-d16")
 PRESETS = {
     # Prompt lengths are in characters: without a tokenizer file the byte
     # tokenizer serves, one token a byte.
@@ -134,6 +142,11 @@ PRESETS = {
         sparse=dict(
             model=dict(DEEPSEEK_V32_EP16), pages=2688, lanes=16, table_pages=168,
             steps=8, join_width=8064,
+        ),
+        # lfm2-8b-a1b-chat-closed (bench/configs/lfm2-8b-a1b-d16.json)
+        lfm2=dict(
+            model=dict(LFM2_D16), pages=2048, lanes=64, table_pages=32,
+            steps=8, join_width=512, prompts=(300, 190), block_lanes=8,
         ),
     ),
     # The rehearsal: same family and head layout rules (tp 4 divides the
@@ -211,6 +224,17 @@ PRESETS = {
             ),
             pages=16, lanes=2, table_pages=2, steps=4, join_width=256,
             timed=dict(lengths=(64, 256), calls=2, repeats=1),
+        ),
+        lfm2=dict(
+            # heads of 64: two KV heads a pool row, as at the published widths
+            model=dict(
+                LFM2_D16, hidden_size=128, intermediate_size=256,
+                moe_intermediate_size=64, num_attention_heads=4,
+                num_key_value_heads=2, head_dim=64, num_experts=8,
+                num_experts_per_tok=2, vocab_size=512,
+            ),
+            pages=16, lanes=4, table_pages=2, steps=4, join_width=64,
+            prompts=(37, 21), block_lanes=4,
         ),
     ),
 }
@@ -698,7 +722,125 @@ def child_sparse(preset: dict) -> None:
         emit({"kind": "program", "program": name, **report})
 
 
+def child_lfm2(preset: dict) -> None:
+    """Gated short convolutions beside routed experts at the benchmark cell's
+    widths: one period of the stack (a dense layer and three sparse ones, all
+    experts held) through an epoch's prefill and a decode chunk FOR REAL, as
+    the kernels' programs and as their XLA twins', then the cell's decode
+    chunk and join compiled for the device this process holds, from shapes
+    alone."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cake_tpu.models.llama import hybrid, pool_audit
+    from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.models.llama.generator import SamplingConfig
+    from cake_tpu.runtime.batch_backend import paged_backend
+    from cake_tpu.utils.device import describe_devices, setup_compile_cache
+
+    setup_compile_cache()
+    emit({"kind": "summary", **describe_devices()})
+    g = preset["lfm2"]
+    dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]]
+    page, lanes, steps = preset["page_size"], g["block_lanes"], g["steps"]
+    period = dataclasses.replace(
+        LlamaConfig.from_hf_dict({
+            **g["model"], "num_hidden_layers": 4, "num_dense_layers": 1,
+            "layer_types": g["model"]["layer_types"][:4]}),
+        attention_impl="pallas",
+    )
+    params = hybrid.init_params(period, jax.random.PRNGKey(0), dtype)
+    rng = np.random.default_rng(0)
+    rows = [rng.integers(8, period.vocab_size, n).tolist() for n in g["prompts"]]
+    bucket = -(-max(g["prompts"]) // page) * page
+    greedy = SamplingConfig(temperature=0.0, repeat_penalty=1.0)
+
+    def served(allow_pallas: bool):
+        """(the prefill's logits, a decode chunk's tokens, seconds) of the
+        rows on ``lanes`` lanes, the spare ones dead."""
+        t0 = time.perf_counter()
+        be = paged_backend(
+            period, params, max_seq_len=bucket + page, cache_dtype=dtype,
+            page_size=page, max_pages=lanes * (bucket // page + 1),
+            allow_pallas=allow_pallas, lanes=lanes,
+        )
+        cache = be.init_kv(lanes)
+        tokens = np.zeros((lanes, bucket), np.int32)
+        pads = np.full((lanes,), bucket - 1, np.int32)
+        tokens[:, -1] = 1
+        for r, ids in enumerate(rows):
+            pads[r] = bucket - len(ids)
+            tokens[r, pads[r]:] = ids
+            be.allocator.map_range(r, int(pads[r]), bucket + steps)
+        logits, cache = be.prefill(tokens, cache, jnp.asarray(pads))
+        logits = np.asarray(logits[:len(rows)], np.float32)
+        tok = jnp.asarray(np.asarray(logits.argmax(-1), np.int32).tolist()
+                          + [1] * (lanes - len(rows)), jnp.int32)
+        keys = jnp.stack([jax.random.PRNGKey(0)] * lanes)
+        toks, cache, *_ = be.decode(
+            cache, tok, bucket, jnp.asarray(pads), keys,
+            jnp.zeros((lanes, 0), jnp.int32), jnp.zeros((lanes,), jnp.int32),
+            steps, greedy,
+        )
+        counts = be.absorb_chunk_counters(be.take_chunk_counters())
+        toks = np.asarray(toks)[:len(rows)]
+        return logits, toks, counts, round(time.perf_counter() - t0, 1)
+
+    kernels, kernel_toks, counts, kernel_s = served(jax.default_backend() != "cpu")
+    twins, twin_toks, _, twin_s = served(False)
+    spread = float(twins.std())
+    # The benchmark's plain reference (float32, a full forward pass, nothing
+    # of this program) over the same weights, teacher-forced on what the
+    # kernels' programs served: the judge's reading (``bench/reference.py``).
+    from bench.manifest import architecture
+    from cake_tpu.io.safetensors_io import hybrid_tensor_dict
+
+    hf = period.to_hf_dict()
+    arch = architecture(REPO, hf)
+    tensors = hybrid_tensor_dict(params, period, dtype)
+    firsts = kernels.argmax(-1)
+    served_ids = [[int(firsts[r]), *kernel_toks[r].tolist()] for r in range(len(rows))]
+    # (without ``first_rows``: with them the rows are the judge's, in which
+    # every served position reads the call's mean deficit)
+    full = [lg[len(p) - 1:] for lg, p in zip(arch.forward_logits(
+        tensors.__getitem__, hf, [p + s[:-1] for p, s in zip(rows, served_ids)],
+    ), rows)]
+    deficits = np.concatenate([
+        (lg.max(-1) - lg[np.arange(len(s)), s]) / lg.std(-1)
+        for lg, s in zip(full, served_ids)
+    ])
+    prefill_err = max(
+        float(np.abs(kernels[r] - full[r][0]).max() / full[r][0].std())
+        for r in range(len(rows))
+    )
+    emit({"kind": "block", "rows": len(rows), "lanes": lanes,
+          "logit_err_in_spreads": float(np.abs(kernels - twins).max() / spread),
+          "tokens_agree": float((kernel_toks == twin_toks).mean()),
+          "finite": bool(np.isfinite(kernels).all()),
+          "reference_deficit_worst": float(deficits.max()),
+          "reference_deficit_mean": float(deficits.mean()),
+          "reference_positions": int(deficits.size),
+          "reference_prefill_err_in_spreads": prefill_err,
+          "counts": counts, "kernel_s": kernel_s, "twin_s": twin_s,
+          "sparse_layers": 3, "top_k": period.num_experts_per_tok})
+    config = dataclasses.replace(
+        LlamaConfig.from_hf_dict(g["model"]), attention_impl="pallas"
+    )
+    reports = pool_audit.audit_programs(
+        config, n_pages=g["pages"], page_size=page, lanes=g["lanes"],
+        table_pages=g["table_pages"], n_steps=steps, width=g["join_width"],
+        dtype=dtype, allow_pallas=jax.default_backend() != "cpu",
+        only=("decode", "join"),
+    )
+    for name, report in reports.items():
+        emit({"kind": "program", "program": name, **report})
+
+
 CHILDREN = {"probe": child_probe, "sparse": child_sparse, "setup": child_setup,
+            "lfm2": child_lfm2,
             "kernels": child_kernels, "pool": child_pool,
             "hybrid": child_hybrid, "olmo": child_olmo,
             "latent": child_latent}
@@ -1368,6 +1510,72 @@ def phase_sparse(args, preset) -> dict:
     return out
 
 
+def phase_lfm2(args, preset) -> dict:
+    """Phase F: gated short convolutions beside routed experts, all held, at
+    the benchmark cell's geometry (lfm2-8b-a1b-chat-closed): one period of
+    the stack served for real by the kernels' programs and by their twins',
+    then the compiled programs."""
+    records = run_child("lfm2", args, timeout=1800)
+    problems = []
+    b = next(r for r in records if r["kind"] == "block")
+    c = b["counts"]
+    say(f"phase=F one period at the cell's widths, {b['rows']} rows on {b['lanes']} lanes: "
+        f"kernels against twins logit_err_in_spreads={b['logit_err_in_spreads']:.3g} "
+        f"tokens_agree={b['tokens_agree']:.3f}; against the plain float32 reference over "
+        f"{b['reference_positions']} served positions deficit worst="
+        f"{b['reference_deficit_worst']:.3g} mean={b['reference_deficit_mean']:.3g} "
+        f"prefill_logit_err_in_spreads={b['reference_prefill_err_in_spreads']:.3g}; "
+        f"dispatches={c['dispatches']} "
+        f"held={c['held']} touched={c['touched']} "
+        f"first_pass_s={b['kernel_s']} twins_s={b['twin_s']}")
+    # The served type on both sides: the kernels' online softmax and the
+    # grouped products sum in another order than their twins. Jamba's and
+    # Pangu's sound reads against float32 are 0.05 to 0.23 of a spread; a
+    # fault in the packed pool's rows (two KV heads of 64 side by side) or in
+    # a window's taps moves a logit by whole spreads.
+    if not b["finite"] or b["logit_err_in_spreads"] > 0.25:
+        problems.append(
+            f"the kernels' programs differ from their twins' by "
+            f"{b['logit_err_in_spreads']:.3g} of a logit spread")
+    # Against the plain float32 reference the period read 0.074 at its worst
+    # of 18 served positions (0.004 on average; PR 48's call 3): three sparse
+    # layers turn few of the router's near-ties. The whole cut's 14 turn so
+    # many that its judge tells nothing (``judge.why`` in the configuration);
+    # a fault in the path moves a position here by whole spreads.
+    if not b["reference_deficit_worst"] <= 0.5:
+        problems.append(
+            f"one period differs from the plain reference by "
+            f"{b['reference_deficit_worst']:.3g} of a logit spread at its worst position")
+    steps = preset["lfm2"]["steps"]
+    want = steps * b["sparse_layers"] * b["rows"] * b["top_k"]
+    if c["held"] != want or c["routed"] != want:
+        problems.append(
+            f"the decode chunk counted {c['held']} held assignments of "
+            f"{c['routed']} routed; {want} live ones were made (dead lanes take none)")
+    out = {"block_logit_err_in_spreads": b["logit_err_in_spreads"]}
+    for r in (r for r in records if r["kind"] == "program"):
+        # (what the CPU's compiler copies says nothing of the chip's layouts)
+        compiled = [] if args.rehearse_cpu else r["pool_ops"] + r["state_copies"]
+        moved = r["scans"] + r["state_scans"] + compiled
+        say(f"phase=F program={r['program']} temp_bytes={r['temp_bytes']} "
+            f"argument_bytes={r['argument_bytes']} pool_bytes={r['pool_bytes']} "
+            f"state_bytes={r['state_bytes']} code_bytes={r['code_bytes']} "
+            f"kernels={r['kernels']} moving_ops={len(moved)} compile_s={r['seconds']}")
+        for m in moved:
+            say(f"phase=F   {r['program']} moves the pool or a window: {m}")
+        if moved:
+            problems.append(f"{r['program']}: {len(moved)} op(s) move the pool or a window")
+        # the windows are 6 MB: the temporaries are held to the pool (2.1 GB)
+        if (not args.rehearse_cpu and r["temp_bytes"] is not None
+                and r["temp_bytes"] >= r["pool_bytes"]):
+            problems.append(f"{r['program']}: {r['temp_bytes']} B of temporaries, "
+                            f"the pool is {r['pool_bytes']} B")
+        out[f"{r['program']}_temp_bytes"] = r["temp_bytes"]
+    if problems:
+        raise PhaseFailed("; ".join(problems))
+    return out
+
+
 def phase_four_chips(args, preset) -> dict:
     cpu = args.rehearse_cpu
     out = {}
@@ -1458,6 +1666,7 @@ def main() -> int:
         "O": lambda: phase_olmo(args, preset),
         "L": lambda: phase_latent(args, preset),
         "S": lambda: phase_sparse(args, preset),
+        "F": lambda: phase_lfm2(args, preset),
         "D": lambda: phase_four_chips(args, preset),
     }
 

@@ -57,6 +57,9 @@ PARENT_FACTS = {
                      "moe": MOE, "sparse": SPARSE},
     "laguna": {"cache": CACHE | {"kinds", "cached_tokens"}, "state": STATE,
                "moe": MOE, "sparse": None},
+    # PR 48's own (no parent had it): the hybrids' sections and, since the
+    # model has sparse layers, Pangu's expert account
+    "lfm2_moe": {"cache": CACHE, "state": STATE, "moe": MOE, "sparse": None},
 }
 
 
@@ -100,8 +103,9 @@ def test_the_record_is_complete_and_the_backend_is_the_kinds(name):
         assert not plain and not isinstance(be, PagedLocalBackend)
     # what rides back beside the tokens is the record's accounts', and only a
     # kind with accounts has a section for them
+    # (of THIS model's: a ``kv+state`` model without a sparse layer has none)
     assert [s for s in ("moe", "sparse") if hasattr(be, f"{s}_facts")] == [
-        a.section for a in kind.accounts]
+        a.section for a in kind.accounts_of(config)]
     needed, stored = kind.token_bytes(config, jnp.float32)
     assert 0 < needed <= stored == be.cache_facts()["bytes_per_token"]
     cache = be.init_kv(2)

@@ -4,8 +4,9 @@
 (``embed`` .. ``sample``); the model's functions enter them with
 ``jax.named_scope``, and the benchmark reads a part's device time from the
 trace's ``op_name`` metadata (``bench/parts.py``). Here the served programs
-of the five families (dense GQA, a Jamba-like and an Olmo-Hybrid-like hybrid,
-latent attention with sparse experts, the same behind a learned index) are LOWERED at a tiny size, never run:
+of the six families (dense GQA, a Jamba-like and an Olmo-Hybrid-like hybrid,
+latent attention with sparse experts, the same behind a learned index, an
+LFM2-like hybrid of short convolutions with routed experts) are LOWERED at a tiny size, never run:
 the operations' names are read from the lowered module, and the same
 programs lower to the same text, locations aside, with the scopes taken
 away: a scope is metadata and nothing else.
@@ -34,6 +35,7 @@ from test_hybrid_jamba import HF as JAMBA
 from test_hybrid_olmo import HF as OLMO_HYBRID
 from test_deepseek_v32 import TINY as LATENT_INDEX
 from test_latent_pangu import SHARE as LATENT_MOE
+from test_lfm2_moe import HF as LFM2_MOE
 
 FAMILIES = {
     "dense": LlamaConfig.tiny(),
@@ -41,6 +43,7 @@ FAMILIES = {
     "olmo_hybrid": LlamaConfig.from_hf_dict(OLMO_HYBRID),
     "latent_moe": LlamaConfig.from_hf_dict(LATENT_MOE),
     "latent_index": LlamaConfig.from_hf_dict(LATENT_INDEX),
+    "lfm2_moe": LlamaConfig.from_hf_dict(LFM2_MOE),
 }
 PROGRAMS = ("decode", "join", "prefill")
 # A tiny server's shapes: lanes, pages of 16 slots, a table of 8 pages a
@@ -58,6 +61,8 @@ OLDER = {
     # the learned index's three (PR 43): what a decode step or a window's
     # block scores, chooses and attends
     "index_scores": MIXER, "index_select": MIXER, "sparse_attention": MIXER,
+    # the gated short convolution's three multiply-adds a channel (PR 48)
+    "short_conv": MIXER,
 }
 # Which of them a family's program holds at these shapes (the XLA forms:
 # tiny widths tile no kernel; ``moe_experts_dense`` is a ``--tp`` verify
@@ -73,6 +78,8 @@ HOLDS = {
     ("latent_moe", "prefill"): {"moe_experts_grouped"},
     **{("latent_index", p): {"moe_experts_grouped", "index_scores", "index_select",
                              "sparse_attention"} for p in ("decode", "join", "prefill")},
+    **{("lfm2_moe", p): {"moe_experts_grouped", "short_conv"}
+       for p in ("decode", "join", "prefill")},
 }
 WEIGHTY = ("dot_general", "convolution", "custom_call", "scatter")
 
@@ -155,6 +162,32 @@ def test_every_operation_sits_under_one_part(family, program):
                 assert parts_of(name) == [part], name
                 assert path.index(part) < path.index(scope), name
     assert older == HOLDS.get((family, program), set())
+
+
+def unscoped(family: str, program: str) -> list[str]:
+    """The operations under no part, constants aside (a constant takes no
+    time on a device, and their number follows the bodies' size)."""
+    names = operation_names(lowered(family, program).compiler_ir())
+    return [kind for kind, name in names
+            if not parts_of(name) and not kind.endswith("constant")]
+
+
+def test_the_short_convolutions_programs_leave_no_more_unscoped_than_jambas():
+    """What sits under no part (the loops, the carries' plumbing: a device
+    trace's ``decode_unscoped_pct``): the window-only state adds nothing to
+    it, and the expert stacks outside the scanned trees add no operation.
+    The projections are ``mixer_in``'s and ``mixer_out``'s, the window's
+    write ``cache_write``'s."""
+    for program in PROGRAMS:
+        assert len(unscoped("lfm2_moe", program)) <= len(unscoped("jamba", program)), program
+    names = operation_names(lowered("lfm2_moe", "decode").compiler_ir())
+    conv = [name for _, name in names if "short_conv" in name.split("/")]
+    assert conv and {tuple(parts_of(n)) for n in conv} == {(MIXER,)}
+    dots = [parts_of(name) for kind, name in names if kind.endswith("dot_general")]
+    assert ["mixer_in"] in dots and ["mixer_out"] in dots
+    updates = [parts_of(name) for kind, name in names if kind.endswith("dynamic_update_slice")]
+    # (the one under no part is the chunk's own: a step's tokens into the scan's output)
+    assert ["cache_write"] in updates and all(p in ([], ["cache_write"]) for p in updates)
 
 
 def test_the_in_place_step_sits_inside_mixer():

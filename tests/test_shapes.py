@@ -17,9 +17,13 @@ import pytest
 from cake_tpu.runtime.shapes import ProgramShapes
 
 ATTENTION = types.SimpleNamespace(cache_kind="kv")
-STATE = types.SimpleNamespace(cache_kind="kv+state", state_mixer="mamba")
-DELTA = types.SimpleNamespace(cache_kind="kv+state", state_mixer="gated_delta")
+STATE = types.SimpleNamespace(cache_kind="kv+state", state_mixer="mamba", ff_kinds=("dense",))
+DELTA = types.SimpleNamespace(cache_kind="kv+state", state_mixer="gated_delta",
+                              ff_kinds=("dense",))
 LATENT = types.SimpleNamespace(cache_kind="latent", state_mixer="mamba")
+# state layers beside routed experts, whatever the mixer (PR 48: lfm2_moe's case)
+STATE_SPARSE = types.SimpleNamespace(cache_kind="kv+state", state_mixer="mamba",
+                                     ff_kinds=("dense", "sparse"))
 # (page size, pages of a lane's table, --max-seq-len, --api-batch)
 MISTRAL = (128, 32, 4096, 8)
 JAMBA = (128, 32, 4096, 32)
@@ -43,6 +47,21 @@ def instance(kind, geometry):
 
 
 # ---------------------------------------------------------- who gets which
+
+
+def test_state_layers_beside_routed_experts_take_the_dear_programs_rule():
+    """One rule from the cause (a sparse feed-forward in a ``kv+state`` stack:
+    a program is code by the run), not from the mixer: six widths, an epoch's
+    rows one a program through the joins' programs, every epoch whole."""
+    dear = ProgramShapes.for_model(STATE_SPARSE, 128, 32)
+    assert dear.widths == (256, 512, 1024, 2048, 3072, 4096)
+    assert dear.prefill_tokens == 1 and dear.one_row_prefill_is_join and dear.whole_batch
+    assert dear.lanes(1, 64) == 64
+    ops = [op for op, _, _ in dear.programs(64)]
+    assert ops.count("join") == 6 and ops.count("prefill") == 0
+    assert ops.count("decode") == ops.count("decode_tail") == 3
+    plain = ProgramShapes.for_model(STATE, 128, 32)
+    assert not plain.whole_batch and not plain.one_row_prefill_is_join and len(plain.widths) == 11
 
 
 def test_the_instance_is_picked_from_the_config_alone():
