@@ -400,27 +400,33 @@ class ExpertAccount:
     def __init__(self, config: LlamaConfig):
         self.config = config
         self.counts = dict.fromkeys(MOE_COUNTS, 0)
+        self.dense_dispatches = 0
         self.join_counts = {"joins": 0, "routed": 0, "held": 0}
 
     def facts(self) -> dict:
         """Cumulative over decode chunks READ: ``dispatches`` decode steps x
-        sparse layers, ``routed`` assignments of live lanes' tokens, ``held``
-        those to experts held here, ``touched`` held experts with an
-        assignment summed over dispatches (what the steps had to read),
-        ``max_load`` the largest load of one held expert in one dispatch;
-        ``join``: the joins' windows read, the assignments of their tokens
-        and those to held experts."""
+        sparse layers, ``dense_dispatches`` those of them that took the dense
+        combine (the HOST's count: ``ops/moe.dispatch_path``'s answer for the
+        rows of the program launched, which is what its trace asked),
+        ``routed`` assignments of live lanes' tokens, ``held`` those to
+        experts held here, ``touched`` held experts with an assignment summed
+        over dispatches (what the grouped path had to read; the dense combine
+        reads every held expert), ``max_load`` the largest load of one held
+        expert in one dispatch; ``join``: the joins' windows read, the
+        assignments of their tokens and those to held experts."""
         c = self.config
         return {
-            **self.counts, "join": dict(self.join_counts),
+            **self.counts, "dense_dispatches": self.dense_dispatches,
+            "join": dict(self.join_counts),
             "experts_held": c.num_local_experts,
             "experts_ranked": c.n_router_experts,
             "first_held": c.expert_offset, "top_k": c.num_experts_per_tok,
         }
 
-    def absorb(self, got: dict[str, int], decode: bool, traced: bool) -> dict:
+    def absorb(self, got: dict[str, int], decode: bool, traced: bool, rows: int) -> dict:
         """A read program's counts into the cumulative ones (a decode
-        chunk's, or a join's window); what the timeline's span says of them."""
+        chunk's, a step of ``rows`` rows; or a join's window); what the
+        timeline's span says of them."""
         if not decode:
             self.join_counts["joins"] += 1
             for key in ("routed", "held"):
@@ -431,4 +437,9 @@ class ExpertAccount:
                 max(self.counts[key], v) if key == "max_load"
                 else self.counts[key] + v
             )
+        from cake_tpu.ops.moe import dispatch_path  # (nothing else of this module asks the rule)
+
+        c = self.config
+        if dispatch_path(rows, 1, c.num_experts_per_tok, c.n_router_experts) == "dense":
+            self.dense_dispatches += got["dispatches"]
         return got

@@ -476,7 +476,8 @@ class IndexAccount:
             "join": dict(self.join_counts),
         }
 
-    def absorb(self, got: dict[str, int], decode: bool, traced: bool) -> dict:
+    def absorb(self, got: dict[str, int], decode: bool, traced: bool, rows: int) -> dict:
+        del rows  # the index scores the tokens its rows hold, whatever their number
         totals = [self.counts if decode else self.join_counts]
         if decode and traced:
             totals.append(self.traced)

@@ -44,6 +44,8 @@ _HLO_DEF = re.compile(
 _MOVERS = ("copy", "dynamic-slice", "dynamic-update-slice")
 # The pool's write as a kernel (ops/pallas/paged_write.py) in compiled HLO.
 _POOL_WRITE = re.compile(r"^\s*(?:ROOT\s+)?%?paged_pool_write[\w.\-]* = ", re.M)
+# A routed expert layer's grouped product (``megablox.gmm``, ops/moe._ragged).
+_GROUPED_PRODUCT = re.compile(r"^\s*(?:ROOT\s+)?%?gmm[\w.\-]* = ", re.M)
 
 
 def pool_shapes(kv_shape: tuple[int, ...]) -> tuple[tuple[int, ...], ...]:
@@ -69,7 +71,10 @@ def audit_programs(
     of index keys; a pool a kind of attention layer: NONE may be sliced,
     stacked or copied, whole or a layer of it), ``pool_writes`` (how many of
     its custom calls are the pool's write as a kernel: one a layer scan where
-    the paged kernels run, none where the write is the scatter), ``kernels``,
+    the paged kernels run, none where the write is the scatter),
+    ``grouped_products`` (how many are a routed expert layer's grouped
+    product: three a run of sparse layers on the grouped path, none where the
+    dispatch takes the dense combine: ``ops/moe.dispatch_path``), ``kernels``,
     ``pool_bytes`` (the named pools'), the compiler's ``temp_bytes``,
     ``argument_bytes`` (together what the program needs on the device) and
     ``code_bytes`` (the compiled program's own size: what a start-up's
@@ -108,6 +113,7 @@ def audit_programs(
             "scans": [f for s in shapes for f in scans_moving_pool(traced.jaxpr, s)],
             "pool_ops": [f for s, dt in shapes.items() for f in pool_ops_in_hlo(hlo, s, dt)],
             "pool_writes": len(_POOL_WRITE.findall(hlo)),
+            "grouped_products": len(_GROUPED_PRODUCT.findall(hlo)),
             "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
             "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
             "code_bytes": getattr(mem, "generated_code_size_in_bytes", None),

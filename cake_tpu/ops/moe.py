@@ -20,27 +20,27 @@ from the configuration (``config.expert_offset``) and the absent experts'
 part is left out, in the program and in the plain reference alike. Nothing
 stands in for the absent chips or their exchange.
 
-Three ways to compute it, ONE rule to choose (``moe_swiglu``):
+Three ways to compute it, ONE rule to choose (``dispatch_path``, at the
+end of this file, where the timings are):
 
-  * **Grouped, drop-free** (``dispatch="auto"``, any number of tokens in the
-    dispatch ``n = batch * chunk``; ``GROUPED_MIN_TOKENS`` says where the
-    timings are): the token-expert assignments are sorted by held expert,
-    assignments to absent experts and of pad slots past the end, the rows
-    filled to whole tiles, and each expert multiplies its own contiguous
-    rows (``_ragged``: the Pallas grouped matmul on the TPU,
-    ``jax.lax.ragged_dot`` elsewhere): FLOPs follow the assignments that
-    land here, and an expert nobody chose is never read. Shapes are static
-    (sort, counts, a product with the routing-weight matrix to combine),
-    only the group boundaries are data; no assignment is ever dropped.
-  * **Dense combine** (``dispatch="dense"``, or below ``GROUPED_MIN_TOKENS``
-    when a test raises it): every held expert's SwiGLU runs on every token
-    as one batched einsum and the routing weight (zero where the expert was
-    not chosen) is applied in the combine. It reads every held expert
-    whatever the routing, which is why it lost at every size timed.
+  * **Grouped, drop-free** (``dispatch="auto"`` wherever the dense combine's
+    two conditions do not both hold; ``dispatch="grouped"`` forces it): the
+    token-expert assignments are sorted by held expert, assignments to
+    absent experts and of pad slots past the end, the rows filled to whole
+    tiles, and each expert multiplies its own contiguous rows (``_ragged``:
+    the Pallas grouped matmul on the TPU, ``jax.lax.ragged_dot`` elsewhere):
+    FLOPs follow the assignments that land here, and an expert nobody chose
+    is never read. Shapes are static, only the group boundaries are data;
+    no assignment is ever dropped.
+  * **Dense combine** (``dispatch="auto"`` where the dispatch touches every
+    held expert anyway and is at most a row tile wide; ``dispatch="dense"``
+    forces it): every held expert's SwiGLU runs on every token as batched
+    einsums, the routing weight (zero where the expert was not chosen)
+    applied on the way. It reads every held expert whatever the routing, and
+    pays no sort, gather, tile filling or one-hot combine.
   * **Capacity buckets** (a ``--tp``-sharded PREFILL chunk only: ``tp_axis``
     set and ``chunk >= EP_CAPACITY_MIN_CHUNK``): a fixed row budget an
-    expert, overflow DROPS (``EP_CAPACITY_FACTOR``): the accepted trade of
-    that path, kept where it was.
+    expert, overflow DROPS (``EP_CAPACITY_FACTOR``).
 """
 
 from __future__ import annotations
@@ -130,22 +130,22 @@ def router_logits(x: jnp.ndarray, router_w: jnp.ndarray) -> jnp.ndarray:
     )
 
 
-# Tokens in a dispatch (batch * chunk) from which the grouped path serves:
-# from the first. Timed against the dense combine on the chip at
-# ``pangu-ultra-ep16-chat-closed``'s sparse layer (16 held experts of 256, 8
-# a token; ms a layer, the run's stacks with a layer index; PERF.md section
-# 6, PR 32), the dense combine reads all 16 experts whatever the routing and
-# takes 2.19 to 2.30 at any of these sizes, the grouped path reads the
-# experts somebody chose: 0.18 at 1 token, 0.25 at 2, 0.40 at 4, 0.67 at 8,
-# 0.96 at 16, 1.38 at 32, 2.14 at 64. So ``dispatch="auto"`` never takes the
-# dense combine at the default; it stays for ``dispatch="dense"`` (a ``--tp``
-# verify chunk) and as the reference the tests hold the grouped path to.
+# What tests force ONE path process-wide with, before tracing: 0 = never the
+# dense combine by shape (always grouped when ungated), a huge value = always
+# dense. 1, the default, leaves the rule to the shapes (``dispatch_path``).
 #
 # ACCEPTED NUMERICS SEAM: the two paths reduce expert contributions in
-# different orders. Parity tests compare within tolerance. To force ONE path
-# process-wide, set this to 0 (always grouped when ungated) or a huge value
-# (always dense) before tracing.
+# different orders. Parity tests compare within tolerance.
 GROUPED_MIN_TOKENS = 1
+# ``dispatch="auto"`` takes the dense combine where BOTH hold (the timings
+# behind the two numbers are with the rule, ``dispatch_path``):
+#   1. every held expert is read anyway: the expected share of them that the
+#      dispatch's rows leave untouched, ``(1 - top_k / n_ranked) ** rows``, is
+#      at most this;
+DENSE_MAX_UNTOUCHED = 0.01
+#   2. the extra products hide under the weights' stream: the rows are at most
+#      this, one row tile of the grouped kernel.
+DENSE_MAX_TOKENS = 128
 
 
 _TILE = 128  # rows, and every matrix dimension, the TPU's grouped kernel tiles by
@@ -426,14 +426,14 @@ def moe_swiglu(
     ``dispatch`` = "dense" forces the drop-free dense combine whatever the
     tokens: REQUIRED for speculative verify chunks under tp (the capacity
     path may drop expert contributions, and greedy speculation promises
-    byte-exact streams; runtime/batch_backend.py's tp verify ops set this).
-    "auto" (default) is the module docstring's rule.
+    byte-exact streams; runtime/batch_backend.py's tp verify ops set this);
+    "grouped" the grouped path; "auto" (default) is ``dispatch_path``'s rule.
 
     Returns [batch, chunk, hidden] in x's dtype (partial under tp, or of a
     share); with ``with_counts`` a pair of that and ``held_counts``' int32
     [4] of this call.
     """
-    if dispatch not in ("auto", "dense"):
+    if dispatch not in ("auto", "dense", "grouped"):
         raise ValueError(f"unknown MoE dispatch {dispatch!r}")
     # Expert stacks are never int4 (quantize_layer_tree keeps them int8 under
     # mode="int4" — the documented mixed mode); guard hand-built trees HERE,
@@ -460,11 +460,11 @@ def moe_swiglu(
         expert_offset if tp_axis is None
         else jax.lax.axis_index(tp_axis) * e_local
     )
-    # THE rule (module docstring): by the tokens in the dispatch.
-    if dispatch != "dense" and b * t >= GROUPED_MIN_TOKENS:
+    how = dispatch_path(b * t, t, top_k, n_ranked, tp_axis is not None, dispatch)
+    if how != "dense":
         path = (
             _capacity_dispatch  # (--tp prefill: one layer's stacks)
-            if tp_axis is not None and t >= EP_CAPACITY_MIN_CHUNK
+            if how == "capacity"
             else functools.partial(_grouped_dispatch, layer=layer)
         )
         out = path(
@@ -472,18 +472,103 @@ def moe_swiglu(
             valid=valid,
         )
     else:
-        onehot = jax.nn.one_hot(topi - offset, e_local, dtype=jnp.float32)
-        weights = jnp.einsum("...k,...ke->...e", topv, onehot)
-        with jax.named_scope("moe_experts_dense"):
-            g = jax.nn.silu(_qeinsum("bth,ehi->btei", x, _of_layer(w_gate, layer)))
-            u = _qeinsum("bth,ehi->btei", x, _of_layer(w_up, layer))
-            y = _qeinsum("btei,eih->bteh", g * u, _of_layer(w_down, layer))
-        out = jnp.einsum(
-            "bteh,bte->bth", y, weights.astype(y.dtype)
-        ).astype(x.dtype)
+        out = _dense_combine(
+            x, topv, topi, w_gate, w_up, w_down, e_local, offset, valid, layer
+        )
     if with_counts:
         return out, held_counts(topi, e_local, offset, valid)
     return out
+
+
+# WHERE THE TIMINGS ARE (ms a sparse layer alone on the clock, the run's stacks
+# with a layer index, ``ops/pallas/check.timed_expert_layer``):
+#
+#   * A share of a large set: ``pangu-ultra-ep16-chat-closed``'s layer, 16 held
+#     experts of 256 ranked, 8 a token (PERF.md section 6, PR 32). The dense
+#     combine reads all 16 whatever the routing and takes 2.19 to 2.30 at any
+#     of these sizes; the grouped path reads the experts somebody chose: 0.18
+#     at 1 token, 0.25 at 2, 0.40 at 4, 0.67 at 8, 0.96 at 16, 1.38 at 32,
+#     2.14 at 64 (11 of the 16 touched).
+#   * A whole small set: ``lfm2-8b-a1b-chat-closed``'s layer, 32 held of 32, 4
+#     a token. Every expert is touched from some thirty rows on, so both paths
+#     read all 704 MB (0.86 ms at the HBM peak): the grouped path 1.180 to
+#     1.189 at 36 to 64 rows alike, the dense combine 0.980 to 0.990 (PR 48);
+#     over the sizes (PR 50, every third row dead through ``valid``), dense
+#     against grouped: 0.983 against 0.809 at 16 rows, 0.983 against 0.949 at
+#     32, 0.993 against 1.185 at 64, 0.996 against 1.249 at 128, 1.154 against
+#     1.313 at 256 (all rows live: 0.988 against 0.917, 0.981 against 1.205,
+#     0.990 against 1.267, 1.163 against 1.363 at 16, 64, 128, 256; at 512 the
+#     grouped path 1.582 and the dense combine about 2): the dense combine's
+#     time is the stream's up to a tile of rows, its products show from 256 on
+#     and it has lost by 512. (Pangu's layer again: 2.147 against 1.652 at 64
+#     rows, 2.113 against 2.288 at 128, 2.302 against 2.504 at 256.)
+#
+# The two conditions (``DENSE_MAX_UNTOUCHED``, ``DENSE_MAX_TOKENS``) at the
+# cells' shapes: LFM2's decode chunk (64 rows, 4 of 32) leaves 0.02% of the
+# held untouched; Pangu's (64 rows, 8 of 256) 13%, and its 128-slot join 1.7%;
+# Laguna's (32 rows, 10 of 256) 28%; DeepSeek's (16 rows, 8 of 256) 60%; every
+# other join and prefill of theirs and of LFM2's is wider than a tile.
+# Mixtral's 2 of 8 passes from 17 rows. Up to a tile of rows the dense combine
+# multiplies no more than the grouped kernel, which multiplies a whole tile
+# for every expert it visits, and ``rows`` operations a byte stay at half the
+# chip's ridge (197 TFLOP/s over 819 GB/s: 240).
+def dispatch_path(
+    tokens: int, chunk: int, top_k: int, n_ranked: int,
+    tp: bool = False, dispatch: str = "auto",
+) -> str:
+    """THE rule: "dense", "grouped" or "capacity" for a dispatch of
+    ``tokens`` = batch * ``chunk`` rows that chooses ``top_k`` of
+    ``n_ranked`` experts (``tp``: inside a ``--tp`` shard_map). Pure, of
+    static shapes and this module's constants alone: ``moe_swiglu`` asks it
+    while it traces and the account of ``/stats`` engine.moe asks it again
+    for the program it launched. How many experts are held does not enter:
+    an expert stays untouched with the same probability wherever it lies, and
+    both paths' work grows alike with the number held."""
+    if dispatch != "auto":
+        return dispatch
+    if tokens < GROUPED_MIN_TOKENS:
+        return "dense"
+    if tp and chunk >= EP_CAPACITY_MIN_CHUNK:
+        return "capacity"  # a --tp prefill chunk
+    if (
+        GROUPED_MIN_TOKENS
+        and tokens <= DENSE_MAX_TOKENS
+        and (1 - top_k / n_ranked) ** tokens <= DENSE_MAX_UNTOUCHED
+    ):
+        return "dense"
+    return "grouped"
+
+
+def _dense_combine(
+    x: jnp.ndarray,  # [b, t, h]
+    topv: jnp.ndarray,  # [b, t, k]
+    topi: jnp.ndarray,  # [b, t, k]
+    w_gate, w_up, w_down,  # [e_local, ...], or a run's stacks with ``layer``
+    e_local: int,
+    offset,  # the first held expert among the ranked (int or traced)
+    valid: jnp.ndarray | None = None,  # [b, t] bool; False = not a token
+    layer=None,
+) -> jnp.ndarray:
+    """Every held expert's SwiGLU on every row as batched einsums, combined
+    under the routing weights (zero where the expert was not chosen, or is
+    not held, or the row is no token: its result is zero, as the grouped
+    path's is) in float32. A row's result is its own. (The weight
+    applied to ``g * u`` and one contraction over expert and inter into
+    ``w_down``, which never makes [b, t, e, h], was timed beside this: 0.980
+    for 0.981 ms at 64 rows, 1.002 for 0.990 at 128, 1.081 for 1.163 at 256,
+    1.992 at 512; it is not taken: the rule stops at 128.)"""
+    onehot = jax.nn.one_hot(topi - offset, e_local, dtype=jnp.float32)
+    weights = jnp.einsum("...k,...ke->...e", topv, onehot)
+    if valid is not None:
+        weights = jnp.where(valid[..., None], weights, 0.0)
+    with jax.named_scope("moe_experts_dense"):
+        g = jax.nn.silu(_qeinsum("bth,ehi->btei", x, _of_layer(w_gate, layer)))
+        u = _qeinsum("bth,ehi->btei", x, _of_layer(w_up, layer))
+        y = _qeinsum("btei,eih->bteh", g * u, _of_layer(w_down, layer))
+    return jnp.einsum(
+        "bteh,bte->bth", y, weights.astype(y.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(x.dtype)
 
 
 def held_counts(

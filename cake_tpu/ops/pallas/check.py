@@ -1345,15 +1345,18 @@ def timed_latent_decode(
 def timed_expert_layer(
     hidden: int, inter: int, held: int, ranked: int, top_k: int,
     tokens: tuple[int, ...], dtype: str = "bf16", layers: int = 4,
-    calls: int = 16, repeats: int = 3,
+    calls: int = 16, repeats: int = 3, dead_every: int = 0,
 ) -> list[dict]:
     """One sparse layer's routed experts at a model's widths, ``held`` of
     ``ranked`` experts here (ops/moe.moe_swiglu, sigmoid routing): at each
     number of tokens in a dispatch the DENSE COMBINE against the GROUPED
-    path, milliseconds a call, and the largest difference of the two results
-    of ONE call (in units of the result's spread). ``calls`` dependent calls over
-    ``layers`` different layers' weights make one program, so a call streams
-    its weights from HBM as a decode step does."""
+    path, milliseconds a call, the largest difference of the two results
+    of ONE call (in units of the result's spread), and which of the two
+    ``dispatch="auto"`` takes at that shape (``rule``: ``moe.dispatch_path``).
+    ``calls`` dependent calls over ``layers`` different layers' weights make
+    one program, so a call streams its weights from HBM as a decode step
+    does. ``dead_every`` k > 0: every k-th row is no token (``valid``), as a
+    dispatch's dead lanes are; the difference is then over the live rows."""
     from cake_tpu.ops import moe
 
     dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
@@ -1367,12 +1370,12 @@ def timed_expert_layer(
 
     def chain(dispatch):
         @jax.jit
-        def run(x, router, w_gate, w_up, w_down):
+        def run(x, valid, router, w_gate, w_up, w_down):
             def call(i, x):
                 li = i % layers
                 y = moe.moe_swiglu(  # the run's stacks and the layer, as the model passes them
                     x, router[li], w_gate, w_up, w_down, layer=li,
-                    dispatch=dispatch, **kw,
+                    dispatch=dispatch, valid=valid, **kw,
                 )
                 x = (x + y).astype(jnp.float32)  # the next call's input
                 return (x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True))).astype(dt)
@@ -1382,28 +1385,32 @@ def timed_expert_layer(
         return run
 
     def once(dispatch):
-        return jax.jit(lambda x, r, g, u, d: moe.moe_swiglu(
-            x, r[0], g, u, d, layer=jnp.int32(0), dispatch=dispatch, **kw
+        return jax.jit(lambda x, v, r, g, u, d: moe.moe_swiglu(
+            x, r[0], g, u, d, layer=jnp.int32(0), dispatch=dispatch, valid=v, **kw
         ))
 
     rows = []
     for n in tokens:
         x = jax.random.normal(keys[4], (n, 1, hidden), dt)
-        args = (x, router, w_gate, w_up, w_down)
-        rec = {"tokens": n}
+        live = np.ones((n, 1), bool)
+        if dead_every:
+            live[dead_every - 1::dead_every] = False
+        args = (x, jnp.asarray(live), router, w_gate, w_up, w_down)
+        rec = {"tokens": n, "live": int(live.sum()),
+               "rule": moe.dispatch_path(n, 1, top_k, ranked)}
         outs = {}
-        for dispatch, name in (("dense", "dense_ms"), ("auto", "grouped_ms")):
+        for dispatch in ("dense", "grouped"):
             if dispatch == "dense" and n * held * inter * 4 > 2**31:
                 continue  # the dense combine's [tokens, held, inter] is too large
             fn = chain(dispatch)
             _timed(fn, *args)  # compile + warm
             fastest = min(_timed(fn, *args)[1] for _ in range(repeats))
-            outs[dispatch] = np.asarray(once(dispatch)(*args), np.float32)
-            rec[name] = round(fastest / calls * 1e3, 3)
+            outs[dispatch] = np.asarray(once(dispatch)(*args), np.float32)[live[:, 0]]
+            rec[f"{dispatch}_ms"] = round(fastest / calls * 1e3, 3)
         if len(outs) == 2:
             # ONE call's results (a chain re-routes on its own rounding)
             rec["max_diff_in_stds"] = float(
-                np.abs(outs["dense"] - outs["auto"]).max() / max(outs["dense"].std(), 1e-9)
+                np.abs(outs["dense"] - outs["grouped"]).max() / max(outs["dense"].std(), 1e-9)
             )
         rows.append(rec)
     return rows

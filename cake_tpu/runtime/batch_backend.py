@@ -764,7 +764,7 @@ class _PagedBackend:
             *lanes, keys, ring, ring_idx,
         )
         if self._accounts:
-            *out, self._chunk_counters = out
+            out, self._chunk_counters = out[:-1], (out[-1], tok.shape[0])
         return tuple(out)
 
     def join(self, kv, row_tokens, pads1, ends1, lane, start=0):
@@ -782,7 +782,7 @@ class _PagedBackend:
             self._tables(lane, whole=not self.kind.capped_windows),
             *self._window_operands(start, lane),
         )
-        self._chunk_counters = counters[0] if counters else None
+        self._chunk_counters = (counters[0], row_tokens.shape[1]) if counters else None
         return logits, kv
 
     # The programs' account beside the tokens (a record with ``accounts``):
@@ -796,19 +796,19 @@ class _PagedBackend:
         counters, self._chunk_counters = self._chunk_counters, None
         if counters is None:
             return None
-        return counters, self._traced and _profiler_open()
+        return *counters, self._traced and _profiler_open()
 
     def absorb_chunk_counters(self, counters, decode: bool = True) -> dict:
         """A read program's counts into the cumulative accounts (a decode
-        chunk's, or a join's window; ``traced``: dispatched while a profiler
-        session was open); returns them as a dict (the timeline's span
-        arguments)."""
-        counters, traced = counters
+        chunk's, or a join's window, of ``rows`` rows; ``traced``: dispatched
+        while a profiler session was open); returns them as a dict (the
+        timeline's span arguments)."""
+        counters, rows, traced = counters
         values = iter(int(v) for v in np.asarray(counters))
         said = {}
         for account in self._accounts.values():
             got = dict(zip(account.names, values))
-            said.update(account.absorb(got, decode, traced))
+            said.update(account.absorb(got, decode, traced, rows))
         assert next(values, None) is None, "a count vector no account names"
         return said
 

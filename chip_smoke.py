@@ -147,6 +147,7 @@ PRESETS = {
         lfm2=dict(
             model=dict(LFM2_D16), pages=2048, lanes=64, table_pages=32,
             steps=8, join_width=512, prompts=(300, 190), block_lanes=8,
+            expert_tokens=(16, 32, 64, 128, 256),
         ),
     ),
     # The rehearsal: same family and head layout rules (tp 4 divides the
@@ -235,6 +236,7 @@ PRESETS = {
             ),
             pages=16, lanes=4, table_pages=2, steps=4, join_width=64,
             prompts=(37, 21), block_lanes=4,
+            expert_tokens=(8, 32), timed=dict(calls=2, repeats=1),
         ),
     ),
 }
@@ -726,7 +728,10 @@ def child_lfm2(preset: dict) -> None:
     """Gated short convolutions beside routed experts at the benchmark cell's
     widths: one period of the stack (a dense layer and three sparse ones, all
     experts held) through an epoch's prefill and a decode chunk FOR REAL, as
-    the kernels' programs and as their XLA twins', then the cell's decode
+    the kernels' programs and as their XLA twins', one sparse layer's routed
+    experts alone on the clock by the dense combine and by the grouped path
+    (every third row dead, as a dispatch's spare lanes are: the table that
+    set ``ops/moe.dispatch_path``'s two numbers), then the cell's decode
     chunk and join compiled for the device this process holds, from shapes
     alone."""
     import dataclasses
@@ -738,6 +743,7 @@ def child_lfm2(preset: dict) -> None:
     from cake_tpu.models.llama import hybrid, pool_audit
     from cake_tpu.models.llama.config import LlamaConfig
     from cake_tpu.models.llama.generator import SamplingConfig
+    from cake_tpu.ops.pallas.check import timed_expert_layer
     from cake_tpu.runtime.batch_backend import paged_backend
     from cake_tpu.utils.device import describe_devices, setup_compile_cache
 
@@ -829,6 +835,13 @@ def child_lfm2(preset: dict) -> None:
     config = dataclasses.replace(
         LlamaConfig.from_hf_dict(g["model"]), attention_impl="pallas"
     )
+    del params, tensors  # the layer alone wants the memory
+    emit({"kind": "experts", "rows": timed_expert_layer(
+        config.hidden_size, config.moe_intermediate_size,
+        config.num_local_experts, config.n_router_experts,
+        config.num_experts_per_tok, tuple(g["expert_tokens"]),
+        dtype=preset["dtype"], dead_every=3, **g.get("timed", {}),
+    )})
     reports = pool_audit.audit_programs(
         config, n_pages=g["pages"], page_size=page, lanes=g["lanes"],
         table_pages=g["table_pages"], n_steps=steps, width=g["join_width"],
@@ -1394,6 +1407,21 @@ def phase_olmo(args, preset) -> dict:
     return out
 
 
+def say_experts(phase: str, records: list, where: str) -> list[str]:
+    """A child's ``timed_expert_layer`` rows, one a line with the path the
+    rule takes at that shape; the sizes at which the two paths' results
+    differ, as problems."""
+    problems = []
+    for row in next(r for r in records if r["kind"] == "experts")["rows"]:
+        say(f"phase={phase} routed experts{where}, tokens={row['tokens']} "
+            f"live={row['live']}: rule={row['rule']} "
+            f"dense_ms={row.get('dense_ms')} grouped_ms={row.get('grouped_ms')} "
+            f"max_diff_in_stds={row.get('max_diff_in_stds')}")
+        if row.get("max_diff_in_stds", 0.0) > 0.1:
+            problems.append(f"dense and grouped experts differ at {row['tokens']} tokens")
+    return problems
+
+
 def phase_latent(args, preset) -> dict:
     """Phase L: a model with latent attention and a share of its experts at
     the benchmark cell's geometry (pangu-ultra-ep16-chat-closed): the
@@ -1417,12 +1445,7 @@ def phase_latent(args, preset) -> dict:
         say(f"phase=L latent_decode_attention alone{where}, "
             f"table_pages={row['table_pages']}: full_us={row['full_us']} "
             f"live_us={row['live_us']} (live_tokens={row['live_tokens']})")
-    for row in next(r for r in records if r["kind"] == "experts")["rows"]:
-        say(f"phase=L routed experts{where}, tokens={row['tokens']}: "
-            f"dense_ms={row.get('dense_ms')} grouped_ms={row.get('grouped_ms')} "
-            f"max_diff_in_stds={row.get('max_diff_in_stds')}")
-        if row.get("max_diff_in_stds", 0.0) > 0.1:
-            problems.append(f"dense and grouped experts differ at {row['tokens']} tokens")
+    problems += say_experts("L", records, where)
     out = {"cases": sum(r["kind"] == "case" for r in records)}
     for r in (r for r in records if r["kind"] == "program"):
         moved = r["scans"] + ([] if args.rehearse_cpu else r["pool_ops"])
@@ -1553,6 +1576,8 @@ def phase_lfm2(args, preset) -> dict:
             f"the decode chunk counted {c['held']} held assignments of "
             f"{c['routed']} routed; {want} live ones were made (dead lanes take none)")
     out = {"block_logit_err_in_spreads": b["logit_err_in_spreads"]}
+    where = "" if not args.rehearse_cpu else " (cpu rehearsal: no device time)"
+    problems += say_experts("F", records, where)
     for r in (r for r in records if r["kind"] == "program"):
         # (what the CPU's compiler copies says nothing of the chip's layouts)
         compiled = [] if args.rehearse_cpu else r["pool_ops"] + r["state_copies"]

@@ -7,6 +7,10 @@ behind a learned index, and Laguna-shaped pools a kind.
 
     python tests/lowered_programs.py > tests/data/lowered_programs_pr45.json
 
+(with the argument ``grouped``: the routed experts' dense combine never taken
+by shape, ``ops/moe.GROUPED_MIN_TOKENS`` 0, which is how a tree since PR 50
+stands to a recording made before it: tiny joins and prefills of two experts
+a token in four fall under that rule where no cell's do)
 run IN A CHECKOUT OF THE PARENT (this file copied into its ``tests/``) makes
 a recording; ``tests/test_lowered_programs.py`` holds the tree that stands to
 it. (PR 46's own recording was made on ITS parent by that parent's helpers,
@@ -71,4 +75,8 @@ def digests() -> dict[str, str]:
 
 
 if __name__ == "__main__":
+    if sys.argv[1:] == ["grouped"]:
+        from cake_tpu.ops import moe
+
+        moe.GROUPED_MIN_TOKENS = 0
     json.dump(digests(), sys.stdout, indent=1)

@@ -34,8 +34,9 @@ CACHE = {"kind", "bytes_per_token", "bytes_per_token_needed", "page_size", "page
          "bytes", "pool_write"}
 STATE = {"layers", "mixer", "window_form", "step_form", "bytes_per_lane", "bytes",
          "lane_writes", "decode_dispatches", "decode_rows"}
-MOE = {"dispatches", "routed", "held", "touched", "max_load", "experts_held",
-       "experts_ranked", "first_held", "top_k", "join"}
+# (``dense_dispatches``: PR 50's, the one key a later PR added to a section)
+MOE = {"dispatches", "dense_dispatches", "routed", "held", "touched", "max_load",
+       "experts_held", "experts_ranked", "first_held", "top_k", "join"}
 SPARSE = {"index_topk", "dispatches", "rows", "scanned", "chosen", "scores_form",
           "select_form", "traced", "join"}
 NESTED = {

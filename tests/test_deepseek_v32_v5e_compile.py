@@ -58,6 +58,9 @@ def test_the_cell_compiles_for_v5e_without_pool_copies(program, cell_reports):
     # scores and its choice's search (ops/pallas/kth_largest.py) a run (the dense
     # run and the sparse one); a join's attention kernel a run
     assert report["kernels"] == (7 if program == "decode" else 8), report
+    # 16 rows, or a join's blocks of 2,048 under either of a share's two row
+    # budgets (``moe._row_budget``), 8 of 256: never dense
+    assert report["grouped_products"] == (3 if program == "decode" else 6), report
     if program == "decode":
         # PR 43's chunk held a layer's gathered index keys (16 rows x 21,504
         # slots x 128 in bf16: 88 MB) among its 640,564,224 bytes of temporaries

@@ -52,6 +52,9 @@ def test_laguna_cell_compiles_for_v5e_without_pool_copies(program, cell_reports)
     report = cell_reports[program]
     assert report["scans"] == [] and report["pool_ops"] == [], report
     assert report["pool_writes"] == 5, report  # one a run of layers
+    # 32 held of 256 at 10 a token, 32 rows or 6,144: the grouped path, three
+    # products a sparse run
+    assert report["grouped_products"] >= 3 and report["grouped_products"] % 3 == 0, report
     # 2048 pages x 3 layers and 192 x 6, K and V, 1 MB a page a layer
     assert report["pool_bytes"] == 2 * (3 * 2048 + 6 * 192) * 8 * 128 * 128 * 2
     # weights 6.40 GB + the pools 3.83: the chip's 16 GB hold the program

@@ -215,12 +215,14 @@ def _share(weights, lo, hi):
 KW = dict(top_k=4, scoring="sigmoid", scale=2.5, norm_topk=True)
 
 
-@pytest.mark.parametrize("n_tokens,dispatch", [(5, "auto"), (64, "auto"), (5, "dense")],
-                         ids=["grouped-5", "grouped-64", "dense-combine"])
+@pytest.mark.parametrize(
+    "n_tokens,dispatch", [(5, "grouped"), (64, "grouped"), (5, "dense"), (5, "auto"), (64, "auto")],
+    ids=["grouped-5", "grouped-64", "dense-combine", "by-shape-5", "by-shape-64"])
 def test_the_shares_add_up_to_the_uncut_layer(n_tokens, dispatch):
     """Eight ranks hold two experts each of sixteen: the parts of a sparse
     layer's result that the shares give, summed, are the uncut layer's, on
-    the grouped path any dispatch takes and on the dense combine."""
+    the grouped path, on the dense combine and on whichever of the two the
+    dispatch's shape takes."""
     x, router, *experts = _moe_layer(0, n_tokens)
     assert n_tokens >= moe.GROUPED_MIN_TOKENS == 1
     kw = dict(KW, dispatch=dispatch)

@@ -248,6 +248,60 @@ def test_prefill_decode_chunks_and_a_join_match_the_reference(model):
     assert state["window_form"] == state["step_form"] == "xla"
 
 
+def _two_chunks(model, lanes):
+    """Two rows' prefill on ``lanes`` lanes (the rest dead) and two decode
+    chunks of 8: (the 16 served tokens a row, ``/stats`` engine.moe)."""
+    config, loaded, *_ = model
+    be = backend(config, loaded)
+    cache, tokens, pads = lay_out(be, prompts(0, 21, 37), lanes, 48)
+    logits, cache = be.prefill(tokens, cache, jnp.asarray(pads))
+    tok, slot, served = np.asarray(logits).argmax(-1).astype(np.int32), 48, []
+    for _ in range(2):
+        toks, cache = decode(be, cache, tok, slot, pads, 8, live=(0, 1))
+        said = be.absorb_chunk_counters(be.take_chunk_counters())
+        assert said["dispatches"] == 48 and said["held"] == 48 * 2 * 2  # dead lanes: no rows
+        served.append(toks[:2])
+        tok, slot = toks[:, -1], slot + 8
+    return np.concatenate(served, axis=1), be.moe_facts()
+
+
+@pytest.mark.parametrize("lanes,forced,dense", [
+    (4, None, False),  # 4 rows leave 0.75 ** 4 = 32% of the 8 experts untouched: grouped
+    (20, None, True),  # 20 rows 0.3%: the dense combine, by shape
+    (20, 0, False), (4, 10**9, True),  # either path forced for a test
+])
+def test_a_decode_chunk_serves_the_same_tokens_by_either_path(
+        model, monkeypatch, lanes, forced, dense):
+    """The decode chunk's sparse layers take the path ``ops/moe.dispatch_path``
+    says for the rows of the dispatch (or the one a test forces), the account
+    counts the dense ones on the host by the same rule, and the served tokens
+    are the same: the paths differ in the order of a sum."""
+    from cake_tpu.models.llama import programs
+
+    def fresh():  # a served program is remembered by its shapes, not by the rule's constants
+        programs.decode_program.cache_clear()
+        programs.join_program.cache_clear()
+        jax.clear_caches()
+
+    want, facts = _two_chunks(model, 4)
+    assert facts["dispatches"] == 96 and facts["dense_dispatches"] == 0
+    fresh()
+    traced, combine = [], moe._dense_combine
+    try:
+        if forced is not None:
+            monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", forced)
+        monkeypatch.setattr(
+            moe, "_dense_combine", lambda x, *a: traced.append(x.shape[:2]) or combine(x, *a))
+        got, facts = _two_chunks(model, lanes)
+    finally:
+        monkeypatch.undo()
+        fresh()
+    np.testing.assert_array_equal(got, want)
+    assert facts["dispatches"] == 96
+    assert facts["dense_dispatches"] == (96 if dense else 0)
+    assert ((lanes, 1) in traced) == dense  # what the decode program itself took
+
+
 def test_through_the_engine_a_joiner_equals_the_request_alone(model):
     """Through serving.py's loop, the continuous scheduler and the one paged
     backend: a late request joins a running segment on four lanes (two stay

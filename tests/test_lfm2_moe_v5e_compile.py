@@ -2,9 +2,10 @@
 (no chip: ``tests/test_paged_pool_carry.py`` says how): the decode chunk and a
 join of ``lfm2-8b-a1b-d16`` carry the page pool (heads of 64 two a row of 128:
 every paged kernel compiles at 128 lanes) and the convolutions' windows
-without a copy, every sparse run's three grouped products lower through Mosaic
-with the run's stacked experts whole, and both fit the chip beside 10.80 GB of
-weights and 2.15 GB of pool."""
+without a copy, every sparse run's three grouped products of a join lower
+through Mosaic with the run's stacked experts whole, the decode chunk's sparse
+layers take the dense combine (no grouped product in it), and both fit the
+chip beside 10.80 GB of weights and 2.15 GB of pool."""
 
 import dataclasses
 import json
@@ -57,9 +58,13 @@ def test_the_cell_compiles_for_v5e_without_pool_or_window_copies(program, cell_r
     # weights 10.80 GB + pool 2.15: the chip's 15.75 GB hold the program
     assert 12.9e9 < report["argument_bytes"] < 13.0e9, report
     assert report["temp_bytes"] < 64e6, report
-    # a pool write and an attention kernel an attention run (4 + 4), three
-    # grouped products a sparse run (8 x 3)
-    assert report["pool_writes"] == 4 and report["kernels"] == 32, report
+    # a pool write and an attention kernel an attention run (4 + 4); a join's
+    # window (512 rows) takes three grouped products a sparse run (8 x 3), the
+    # decode chunk's dispatch (64 rows, 4 of 32 experts: every one touched) the
+    # dense combine and holds none (``ops/moe.dispatch_path``, PR 50)
+    grouped = 0 if program == "decode" else 24
+    assert report["grouped_products"] == grouped, report
+    assert report["pool_writes"] == 4 and report["kernels"] == 8 + grouped, report
     assert report["code_bytes"] < 24e6, report  # nine runs' bodies: code by the run
 
 

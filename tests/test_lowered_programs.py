@@ -6,7 +6,16 @@ went). Here they are built by the one constructor of a cache kind's programs
 (``models/llama/programs.py``): what a kind needs is chosen by its record in
 Python, so no served program holds one operation more or fewer. The six
 digests ``lowered_programs_pr42.json`` held (Pangu's and Laguna's, recorded
-on PR 43's parent) are in the new file unchanged."""
+on PR 43's parent) are in the new file unchanged.
+
+Since PR 50 the routed experts take the dense combine where a dispatch
+touches every held expert and is at most a row tile wide (``ops/moe.
+dispatch_path``). No cell's join or prefill does, but THESE tiny ones do (64
+and 128 rows that choose 2 of 4 experts, or of 8): six programs' text changed
+for that reason alone. So the recording is held twice: with the shape rule
+off (``GROUPED_MIN_TOKENS`` 0: the script's ``grouped``) all 20 lower to the
+parent's text, to the digit; as served the six lower to this PR's own
+recording (``lowered_programs_pr50.json``) and the other 14 to the parent's."""
 
 import json
 import os
@@ -21,21 +30,47 @@ RECORDED = json.loads(
 FAMILIES = ("dense", "jamba", "laguna", "latent_index", "olmo_hybrid", "pangu")
 
 
-@pytest.fixture(scope="module")
-def digests():
+# The tiny joins and prefills that the dense combine's rule moves, as served.
+MOVED = json.loads(
+    (Path(__file__).parent / "data" / "lowered_programs_pr50.json").read_text())
+
+
+def _digests(*argv: str) -> dict[str, str]:
     """As the recording was made: the script in a process of its own (this
     suite's conftest pins the CPU's matmul precision, which is in the text)."""
     script = Path(__file__).parent / "lowered_programs.py"
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, str(script)], env=env, capture_output=True,
-                         text=True, timeout=300, check=True)
+    out = subprocess.run([sys.executable, str(script), *argv], env=env,
+                         capture_output=True, text=True, timeout=300, check=True)
     return json.loads(out.stdout)
+
+
+@pytest.fixture(scope="module")
+def digests():
+    return _digests("grouped")
+
+
+@pytest.fixture(scope="module")
+def served_digests():
+    return _digests()
 
 
 @pytest.mark.parametrize("program", sorted(RECORDED))
 def test_the_program_lowers_to_the_parents_text(digests, program):
     assert digests[program] == RECORDED[program]
+
+
+@pytest.mark.parametrize("program", sorted(RECORDED))
+def test_the_program_as_served_lowers_to_its_recording(served_digests, program):
+    assert served_digests[program] == {**RECORDED, **MOVED}[program]
+    assert (program in MOVED) == (served_digests[program] != RECORDED[program])
+
+
+def test_the_moved_programs_are_tiny_joins_and_prefills_with_a_router():
+    assert sorted(MOVED) == [
+        f"{family}.{program}" for family in ("laguna", "latent_index", "pangu")
+        for program in ("join", "prefill")]
 
 
 def test_every_program_is_held(digests):

@@ -779,3 +779,148 @@ def test_dispatch_dense_forces_drop_free_even_with_min_tokens_zero():
     # with GROUPED_MIN_TOKENS=0 must still match the oracle bit-for-bit.
     forced = run("dense", 0, 0.25)
     np.testing.assert_array_equal(forced, oracle)
+
+
+# ------------------------------------------- the rule that chooses the path
+
+
+def _cell_dispatches():
+    """Every routed dispatch the benchmark's cells can run, from their
+    configurations' own flags and the closed shapes those make: (case id,
+    rows in the dispatch, a row's chunk, experts a token, experts ranked,
+    the path ``dispatch="auto"`` must take). A decode step at every lane
+    count an epoch can have, every join, every group of an epoch's prefill."""
+    import glob
+    import json
+    import os
+
+    from cake_tpu.runtime.shapes import ProgramShapes
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cases = {}
+    for path in sorted(glob.glob(os.path.join(root, "bench/configs/*.json"))):
+        with open(path) as f:
+            hf = json.load(f)
+        config = LlamaConfig.from_hf_dict(hf)
+        if config.n_router_experts <= 1:
+            continue  # Mistral's, Jamba's, Olmo-Hybrid's: no router
+        flags = hf["server_flags"]
+        flag = lambda name: int(flags[flags.index(name) + 1])  # noqa: E731
+        page, lanes = flag("--page-size"), flag("--api-batch")
+        shapes = ProgramShapes.for_model(config, page, flag("--max-seq-len") // page)
+        epochs = sorted({shapes.lanes(seeds, lanes) for seeds in range(1, lanes + 1)})
+        dispatches = {("decode", b, 1) for b in epochs}
+        for width in shapes.widths:
+            dispatches.add(("join", width, width))
+            for b in epochs:
+                group = shapes.prefill_group(b, width)
+                if not (shapes.one_row_prefill_is_join and group == 1):
+                    dispatches.add(("prefill", group * width, width))
+        for op, rows, chunk in sorted(dispatches):
+            dense = config.model_type == "lfm2_moe" and op == "decode"
+            cases[f"{config.model_type}.{op}.{rows}"] = (
+                rows, chunk, config.num_experts_per_tok, config.n_router_experts,
+                "dense" if dense else "grouped",
+            )
+    return cases
+
+
+_CELLS = _cell_dispatches()
+_RULE = {
+    **_CELLS,
+    # (rows, chunk, top_k, ranked, path[, more])
+    "mixtral.decode.16": (16, 1, 2, 8, "grouped"),  # 0.75 ** 16 = 1.002%
+    "mixtral.decode.17": (17, 1, 2, 8, "dense"),
+    "lfm2.a_tile_of_rows": (128, 1, 4, 32, "dense"),
+    "lfm2.a_row_past_a_tile": (129, 1, 4, 32, "grouped"),
+    "lfm2.too_few_rows_touch_every_expert": (34, 1, 4, 32, "grouped"),  # 1.07%
+    "tp.prefill_chunk": (32, 16, 2, 8, "capacity", dict(tp=True)),
+    "tp.decode_step": (32, 1, 2, 8, "dense", dict(tp=True)),
+    "tp.decode_step_few_rows": (8, 1, 2, 8, "grouped", dict(tp=True)),
+    "tp.verify_chunk_forced_dense": (32, 16, 2, 8, "dense", dict(tp=True, dispatch="dense")),
+    "forced.dense": (4096, 4096, 8, 256, "dense", dict(dispatch="dense")),
+    "forced.grouped": (64, 1, 4, 32, "grouped", dict(dispatch="grouped")),
+}
+
+
+def test_the_cells_dispatches_are_all_there():
+    families = {name.split(".")[0] for name in _CELLS}
+    assert families == {"lfm2_moe", "pangu_ultra_moe", "laguna", "deepseek_v32"}
+    assert [n for n, c in _CELLS.items() if c[-1] == "dense"] == ["lfm2_moe.decode.64"]
+    # the narrowest and the widest join of each, and Pangu's 128-slot one
+    for name in ("lfm2_moe.join.256", "lfm2_moe.join.4096", "pangu_ultra_moe.join.64",
+                 "pangu_ultra_moe.join.128", "pangu_ultra_moe.join.4096",
+                 "pangu_ultra_moe.decode.64", "laguna.decode.32", "laguna.join.1536",
+                 "laguna.join.24576", "deepseek_v32.decode.16", "deepseek_v32.join.2688",
+                 "deepseek_v32.join.21504"):
+        assert name in _CELLS, sorted(_CELLS)
+
+
+@pytest.mark.parametrize("case", sorted(_RULE))
+def test_the_rule_takes_the_path_the_shapes_say(case):
+    """``dispatch_path`` at every dispatch the cells run (LFM2's decode chunk
+    alone takes the dense combine: 64 rows that choose 4 of 32 leave 0.02% of
+    the experts untouched; Pangu's, Laguna's and DeepSeek's decode steps
+    leave 13 to 97%, and every join and prefill but Pangu's two narrowest is
+    wider than a tile), at the rule's edges, under ``--tp`` and forced."""
+    import cake_tpu.ops.moe as moe
+
+    rows, chunk, top_k, ranked, path, *more = _RULE[case]
+    assert moe.dispatch_path(rows, chunk, top_k, ranked, **(more[0] if more else {})) == path
+
+
+@pytest.mark.parametrize("min_tokens,path", [(0, "grouped"), (10**9, "dense")])
+@pytest.mark.parametrize("case", ["lfm2_moe.decode.64", "lfm2_moe.join.256",
+                                  "pangu_ultra_moe.decode.64", "pangu_ultra_moe.join.128"])
+def test_a_test_forces_either_path_process_wide(monkeypatch, case, min_tokens, path):
+    import cake_tpu.ops.moe as moe
+
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", min_tokens)
+    assert moe.dispatch_path(*_CELLS[case][:4]) == path
+    # a --tp prefill chunk keeps its buckets under the grouped forcing
+    assert moe.dispatch_path(32, 16, 2, 8, tp=True) == ("capacity" if min_tokens == 0 else "dense")
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 0.05)])
+def test_the_dense_combine_at_lfm2s_shape_is_the_grouped_path(dtype, tol):
+    """LFM2's decode dispatch: 64 rows of one token, 4 of 32 experts by
+    sigmoid scores behind a selection bias, every third row dead. The rule
+    takes the dense combine (no grouped product in the program); on the live
+    rows it is the grouped path within the seam's tolerance (in units of the
+    result's spread), on the dead rows both are zero, and the account's
+    counts are the same digits whichever path ran."""
+    import cake_tpu.ops.moe as moe
+
+    rng = np.random.default_rng(50)
+    n, h, inter, e, k = 64, 64, 32, 32, 4
+    dt = jnp.dtype(dtype)
+    x = jnp.asarray(rng.standard_normal((n, 1, h)), dt)
+    router = jnp.asarray(rng.standard_normal((h, e)) * 0.1, dt)
+    bias = jnp.asarray(rng.standard_normal((e,)) * 0.02, jnp.float32)
+    wg = jnp.asarray(rng.standard_normal((e, h, inter)) * h**-0.5, dt)
+    wu = jnp.asarray(rng.standard_normal((e, h, inter)) * h**-0.5, dt)
+    wd = jnp.asarray(rng.standard_normal((e, inter, h)) * inter**-0.5, dt)
+    valid = (np.arange(n) % 3 != 2)[:, None]
+
+    def run(dispatch):
+        def fn(*a):
+            return moe.moe_swiglu(
+                *a, k, scoring="sigmoid", router_bias=bias, valid=jnp.asarray(valid),
+                dispatch=dispatch, with_counts=True)
+
+        out, counts = jax.jit(fn)(x, router, wg, wu, wd)
+        program = str(jax.make_jaxpr(fn)(x, router, wg, wu, wd))
+        return np.asarray(out, np.float32)[:, 0], np.asarray(counts), "ragged_dot" in program
+
+    auto, auto_counts, auto_grouped = run("auto")
+    dense, dense_counts, _ = run("dense")
+    grouped, grouped_counts, is_grouped = run("grouped")
+    assert is_grouped and not auto_grouped
+    np.testing.assert_array_equal(auto, dense)
+    live = valid[:, 0]
+    assert not dense[~live].any() and not grouped[~live].any()
+    assert np.abs(dense[live] - grouped[live]).max() <= tol * grouped[live].std()
+    np.testing.assert_array_equal(dense_counts, grouped_counts)
+    np.testing.assert_array_equal(auto_counts, grouped_counts)
+    routed, held, touched, _ = (int(c) for c in auto_counts)
+    assert routed == held == int(live.sum()) * k and e - 1 <= touched <= e

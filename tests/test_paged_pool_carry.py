@@ -608,6 +608,10 @@ def test_latent_cell_compiles_for_v5e_without_pool_copies(program, latent_report
     report = latent_reports[program]
     assert report["scans"] == [] and report["pool_ops"] == [], report
     assert report["kernels"] >= 2, report  # one a run of layers
+    # 16 held of 256 at 8 a token: a decode chunk's 64 rows and a join's 512
+    # stay on the grouped path: three products the sparse run, under each of
+    # the two row budgets a share chooses between (``moe._row_budget``)
+    assert report["grouped_products"] == 6, report
     assert report["pool_bytes"] == 5 * 2048 * 128 * 640 * 2  # 1.68 GB: what is stored
     # weights 9.84 GB + the pool: the chip's 16 GB hold the program
     assert report["argument_bytes"] + report["temp_bytes"] < 14.0e9, report

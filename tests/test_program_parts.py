@@ -65,21 +65,22 @@ OLDER = {
     "short_conv": MIXER,
 }
 # Which of them a family's program holds at these shapes (the XLA forms:
-# tiny widths tile no kernel; ``moe_experts_dense`` is a ``--tp`` verify
-# chunk's, which no paged server dispatches).
+# tiny widths tile no kernel). The routed experts' path follows the dispatch's
+# shape (``ops/moe.dispatch_path``, PR 50): a decode step of 3 rows is
+# grouped, a join's 64 rows and a prefill's 128 that choose 2 of 4 experts (of
+# 8) touch every one and take the dense combine; no cell's join does.
+_EXPERTS = {"decode": "moe_experts_grouped", "join": "moe_experts_dense",
+            "prefill": "moe_experts_dense"}
 HOLDS = {
     ("jamba", "join"): {"selective_scan_xla"},
     ("jamba", "prefill"): {"selective_scan_xla"},
     ("olmo_hybrid", "decode"): {"gated_delta_step"},
     ("olmo_hybrid", "join"): {"gated_delta_rule"},
     ("olmo_hybrid", "prefill"): {"gated_delta_rule"},
-    ("latent_moe", "decode"): {"moe_experts_grouped"},
-    ("latent_moe", "join"): {"moe_experts_grouped"},
-    ("latent_moe", "prefill"): {"moe_experts_grouped"},
-    **{("latent_index", p): {"moe_experts_grouped", "index_scores", "index_select",
-                             "sparse_attention"} for p in ("decode", "join", "prefill")},
-    **{("lfm2_moe", p): {"moe_experts_grouped", "short_conv"}
-       for p in ("decode", "join", "prefill")},
+    **{("latent_moe", p): {experts} for p, experts in _EXPERTS.items()},
+    **{("latent_index", p): {experts, "index_scores", "index_select", "sparse_attention"}
+       for p, experts in _EXPERTS.items()},
+    **{("lfm2_moe", p): {experts, "short_conv"} for p, experts in _EXPERTS.items()},
 }
 WEIGHTY = ("dot_general", "convolution", "custom_call", "scatter")
 
