@@ -544,3 +544,59 @@ def hybrid_forward_one(
         return logits, cache, *counts
 
     return forward_one
+
+
+def hybrid_join_rows(
+    params: M.Params,
+    tokens: jnp.ndarray,  # [R, W]: absolute slots [start, start + W)
+    cache: HybridCache,
+    pads: jnp.ndarray,  # [R]
+    ends: jnp.ndarray,  # [R]; a dead row's is its pad
+    block_tables: jnp.ndarray,  # [R, pages]; a dead row's holds no page
+    config: LlamaConfig,
+    *,
+    lanes: jnp.ndarray,  # [R] each row's lane; a dead row's is no lane (-1)
+    start: jnp.ndarray | int = 0,
+    allow_pallas: bool = True,
+):
+    """The joiners of one step as ONE window of R rows (``hybrid_prefill``
+    says what a window is): every row ends at the shared slot, row r is the
+    new tenant of lane ``lanes[r]``, whichever lanes were free, and a row the
+    step did not fill is dead: no position of it is ``live``, so it takes no
+    expert's rows, its table row holds no page, so its K and V drop, no lane
+    is its own, so its state goes nowhere, and nobody reads its logits. The
+    first row is never dead (the logits are read at ITS last slot, which is
+    every live row's). The experts somebody chose are read once for all the
+    rows, which is what the group is for.
+
+    The rows' recurrence runs in a lane state of R lanes of its own, from
+    zeros as every new tenant's does, and each layer's result is then placed
+    in the tenants' lanes by a gather and a select over the whole array (6 MB
+    at LFM2's widths; ``state_layer`` says why no update-slice at a lane):
+    ``hybrid_prefill`` itself is as it was, to the operation."""
+    rows = tokens.shape[0]
+    with jax.named_scope(CACHE_WRITE):
+        scratch = HybridCache(
+            kv=cache.kv,
+            ssm=None if cache.ssm is None else jnp.zeros(
+                (cache.ssm.shape[0], rows, *cache.ssm.shape[2:]), cache.ssm.dtype),
+            conv=jnp.zeros(
+                (*cache.conv.shape[:2], rows, cache.conv.shape[3]), cache.conv.dtype),
+        )
+    logits, new, *counts = hybrid_prefill(
+        params, tokens, scratch, pads, ends, block_tables, config,
+        start=start, lane=0, allow_pallas=allow_pallas,
+    )
+    with jax.named_scope(CACHE_WRITE):
+        lanes = jnp.asarray(lanes, jnp.int32)
+        tenant = jnp.arange(cache.conv.shape[2], dtype=jnp.int32)[:, None] == lanes[None, :]
+        mine, row = tenant.any(axis=1), jnp.argmax(tenant, axis=1)
+        conv = jnp.where(
+            mine[None, None, :, None], jnp.take(new.conv, row, axis=2), cache.conv)
+        ssm = cache.ssm
+        if ssm is not None:
+            ssm = jnp.where(
+                mine.reshape(1, -1, *(1,) * (ssm.ndim - 2)),
+                jnp.take(new.ssm, row, axis=1), ssm,
+            )
+    return logits, HybridCache(kv=new.kv, ssm=ssm, conv=conv), *counts

@@ -118,7 +118,7 @@ class CacheKind:
     more_programs: Mapping[str, tuple[Callable, tuple[str, ...]]] = dataclasses.field(
         default_factory=dict, hash=False, compare=False
     )
-
+    rows_window: Callable | None = None  # ``join_rows_program``'s: R joining rows, a lane each
 
     def accounts_of(self, config: LlamaConfig) -> tuple[type, ...]:
         """The accounts this model's programs return beside their tokens."""
@@ -181,7 +181,7 @@ _ALL = (
     CacheKind(
         name=CACHE_KV_STATE, label="hybrid", module="paged_hybrid",
         init_params=H.init_params, init_cache=H.init_hybrid_cache,
-        window=H.hybrid_prefill, forward_one=H.hybrid_forward_one,
+        window=H.hybrid_prefill, forward_one=H.hybrid_forward_one, rows_window=H.hybrid_join_rows,
         token_bytes=_kv_token_bytes(lambda c: c.layers_of(ATTENTION)),
         pools=lambda cache: (cache.kv.k,),
         window_operands=("start", "lane"), closes_over_capacity=True,
@@ -361,7 +361,7 @@ def served_programs(
     table_pages: int,
     n_steps: int,
     width: int,
-    prefill_rows: int = 2,
+    prefill_rows: int = 2, join_rows: int = 1,
     dtype=jnp.bfloat16,
     allow_pallas: bool = True,
     sharding=None,
@@ -396,7 +396,7 @@ def served_programs(
             "params": params, "cache": cache, "config": config,
             "tokens": spec((rows, width)), "tok": spec((rows,)), "slot": spec(()),
             "pads": spec((rows,)), "ends": spec((rows,)),
-            "write_starts": spec((rows,)), "start": spec(()), "lane": spec(()),
+            "write_starts": spec((rows,)), "start": spec(()), "lane": spec(()), "lanes": spec((rows,)),
             "tables": tuple(table for _ in n_pages) if kind.pools_by_kind else table,
             "valid": spec((rows,), jnp.bool_), "key": spec((rows, 2), jnp.uint32),
             "ring": spec((rows, 0)), "ring_idx": spec((rows,)),
@@ -416,14 +416,52 @@ def served_programs(
                  ("params", "cache", "tokens", *window, *kind.window_operands)),
         "prefill": (prefill_program(kind),
                     ("params", "tokens", "cache", *window, "config", *kind.window_operands)),
-        **kind.more_programs,
+        **kind.more_programs, **_rows_programs(kind, config, join_rows, width, allow_pallas),
     }
 
     def thunk(name, fn, roles):
-        rows = lanes if name == "decode" else 1 if name.endswith("join") else prefill_rows
+        rows = {"decode": lanes, "join_rows": join_rows}.get(name, 1 if name.endswith("join") else prefill_rows)
         static = {"allow_pallas": allow_pallas} if "config" in roles else {}
         return lambda: fn._jitted.trace(*operands(roles, rows), **static)
 
     return Served(params, cache, {
         name: thunk(name, fn, roles) for name, (fn, roles) in programs.items()
     })
+
+
+# ------------------------------------------------- the joiners of one step
+# (below everything else: a Mosaic kernel's payload carries its callers' line
+# numbers, so a line that moves above a served program's frame is a new
+# compile-cache key for every cell: PERF.md section 7, row 17)
+
+
+@functools.lru_cache(maxsize=8)
+def join_rows_program(
+    kind: CacheKind, config: LlamaConfig, rows: int, width: int, allow_pallas: bool = True
+):
+    """``join_program`` for the joiners one step accepted together, where the
+    kind has a window of rows with a lane each (``rows_window``): ``rows``
+    rows of ``width`` slots, one program, a join by its module's name. Its
+    operands are the join's with ``lanes`` [rows] in the lane's place. One
+    compile a (rows, width): which of them exist is ``shapes.join_widths``."""
+
+    def run(params, cache, tokens, pads, ends, tables, start, lanes):
+        return kind.rows_window(
+            params, tokens, cache, pads, ends, tables, config,
+            start=start, lanes=lanes, allow_pallas=allow_pallas,
+        )
+
+    return tracked_jit(
+        run, name=f"batch.{kind.label}_join[r={rows},w={width}]",
+        module=f"prefill_join_{kind.module}", donate_argnums=(1,),
+    )
+
+
+def _rows_programs(kind, config, rows, width, allow_pallas) -> dict:
+    """``served_programs``' entry for the group of ``rows`` joining rows."""
+    if rows < 2 or kind.rows_window is None:
+        return {}
+    return {"join_rows": (
+        join_rows_program(kind, config, rows, width, allow_pallas),
+        ("params", "cache", "tokens", "pads", "ends", "tables", "start", "lanes"),
+    )}

@@ -301,7 +301,9 @@ def explain(events: list[dict], request_id: str) -> dict | None:
             phases["convoy"] += fov * (1.0 - 1.0 / lanes)
         elif name == "join":
             fov = fork_inside(s["t0"], s["t1"])
-            if s["rid"] != request_id:
+            # (a step's joiners that went as one program share its span:
+            # ``rids`` names them all, ``rid`` the first)
+            if request_id not in ((s["args"] or {}).get("rids") or (s["rid"],)):
                 # Another request joining the shared epoch: this lane sat
                 # out its prefill — lockstep tax, fork included.
                 phases["convoy"] += _eff(s, ov, forks=fov) + fov

@@ -91,6 +91,11 @@ class PeriodAccount:
             "undispatched": {"count": 0, "seconds": 0.0},
             "phase_seconds": dict.fromkeys(PHASES, 0.0),
             "joins": 0, "join_seconds": 0.0, "join_readback_seconds": 0.0,
+            # Of ``joins``, those that went several a program (the joiners
+            # of one step as one join program of ``rows`` rows, dead rows
+            # among them): ``joiners / joins`` is the share that went
+            # grouped, ``joiners / rows`` a group's fill.
+            "join_groups": {"programs": 0, "rows": 0, "joiners": 0},
             "lane_seconds": {"live": 0.0, "offered": 0.0, "idle_queued": 0.0},
             "ahead": 0, "serial": dict.fromkeys(SERIAL_WHY, 0),
             # Sum over dispatching periods of the tokens their live lanes
@@ -110,6 +115,7 @@ class PeriodAccount:
         self._self = dict.fromkeys(PHASES, 0.0)
         self._joins = 0
         self._join_s = self._join_readback_s = 0.0
+        self._groups = [0, 0, 0]  # programs, rows, joiners
         # When the engine last had work and no segment (set by the loop).
         self._t_work: float | None = None
 
@@ -123,6 +129,7 @@ class PeriodAccount:
         self._self = dict.fromkeys(PHASES, 0.0)
         self._joins = 0
         self._join_s = self._join_readback_s = 0.0
+        self._groups = [0, 0, 0]
 
     def push(self, span: str) -> None:
         """A span of the loop opens: the time since the last boundary was
@@ -144,12 +151,17 @@ class PeriodAccount:
         self._mark = now
         return now - t_in
 
-    def note_join(self, seconds: float) -> None:
-        """One join's ``join`` span: the host's work to enqueue its prefill,
-        its first sample and its lane's writes. The wait for its first
-        token is no part of it (``note_join_wait``)."""
-        self._joins += 1
+    def note_join(self, seconds: float, joiners: int = 1, rows: int = 0) -> None:
+        """One join program's ``join`` span: the host's work to enqueue its
+        prefill, its first sample and its lanes' writes, for its ``joiners``
+        (several where a step's joiners went as one program of ``rows``
+        rows). The wait for a first token is no part of it
+        (``note_join_wait``)."""
+        self._joins += joiners
         self._join_s += seconds
+        if rows:
+            for i, n in enumerate((1, rows, joiners)):
+                self._groups[i] += n
 
     def note_join_wait(self, seconds: float) -> None:
         """The host's wait for a joiner's first token, read with its
@@ -187,6 +199,8 @@ class PeriodAccount:
                 p["joins"] += self._joins
                 p["join_seconds"] += self._join_s
                 p["join_readback_seconds"] += self._join_readback_s
+                for key, n in zip(("programs", "rows", "joiners"), self._groups):
+                    p["join_groups"][key] += n
             lanes = p["lane_seconds"]
             lanes["live"] += live * wall
             lanes["offered"] += self.lanes * wall

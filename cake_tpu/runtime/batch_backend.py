@@ -835,13 +835,13 @@ class _PagedBackend:
             blank = np.zeros((rows, slots), np.int32)
             if op == "prefill":
                 _, cache = self.prefill(blank, cache, zeros)
-            elif op == "join":
+            elif op == "join" and rows == 1:
                 _, cache = self.join(
                     cache, blank, zeros[:1], jnp.asarray([slots], jnp.int32), 0
                 )
-            else:
-                # an epoch's slots are whole chunks from a width on: its last
-                # chunk is what is left under the ceiling
+            elif op == "join":  # a step's joiners together: every row dead
+                cache = self.join_rows(cache, blank, [slots] * rows, [slots] * rows, [-1] * rows)[1]
+            else:  # a tail: what is left under the ceiling (slots are whole chunks from a width on)
                 tail = op == "decode_tail"
                 n = self.shapes.decode_steps(n_steps, slots, slots - n_steps) if tail else n_steps
                 self.set_epoch_capacity(slots)
@@ -2217,3 +2217,43 @@ class DistributedBatchBackend:
             jnp.asarray(n_drafts, jnp.int32), keys,
         )
         return n_accs, nxts, kv, keys
+
+
+# ------------------------------------------------- the joiners of one step
+# Below every class and bound to the paged backend here: a Mosaic kernel's
+# payload carries its callers' line numbers (the dispatches above among
+# them), so a line that moves up there is a new compile-cache key for every
+# cell's programs, and two trees that take turns on one machine then evict
+# each other's (PERF.md section 7, rows 17 and 26). A PR whose cells all
+# change anyway moves this into ``_PagedBackend`` beside ``join``.
+
+
+def _join_rows(self, kv, tokens, pads, ends, lanes, start=0):
+    """``join`` for the joiners one step accepted together, as ONE program
+    of ``shapes.join_rows`` rows (``programs.join_rows_program``): row r's
+    window [start, start + width) into lane ``lanes[r]`` through that lane's
+    table row, the lane's state from zero. A row the step did not fill is
+    DEAD: lane -1, a table row that holds no page, pad == end; the first row
+    never is. Logits [rows, vocab]: a dead row's are nobody's."""
+    from cake_tpu.models.llama.programs import join_rows_program
+
+    tokens = np.asarray(tokens)
+    lanes = np.asarray(lanes, np.int32)
+    live = lanes >= 0
+    self._kernel_note("join", int(np.max(ends)))
+    if self.kind.lane_state is not None:
+        self.state_lane_writes += int(live.sum())
+    table = np.where(
+        live[:, None], self.allocator.block_tables[np.maximum(lanes, 0)], -1
+    ).astype(np.int32)
+    fn = join_rows_program(self.kind, self.config, *tokens.shape, self.allow_pallas)
+    logits, kv, *counters = fn(
+        self.params, kv, jnp.asarray(tokens), jnp.asarray(pads, jnp.int32),
+        jnp.asarray(ends, jnp.int32), jnp.asarray(table), jnp.int32(start),
+        jnp.asarray(lanes),
+    )
+    self._chunk_counters = (counters[0], tokens.size) if counters else None
+    return logits, kv
+
+
+_PagedBackend.join_rows = _join_rows

@@ -1985,7 +1985,7 @@ class BatchEngine:
                 if entry.n:
                     self._emit_chunk(rows, entry, host)
                 else:
-                    self._emit_first(rows, entry, int(host[0]))
+                    self._emit_first(rows, entry, int(host[entry.row]))
 
     def _emit_chunk(self, rows: list, entry: "_Unread", toks_np) -> None:
         """A decode chunk's tokens reach their streams: the rows are the
@@ -3013,13 +3013,13 @@ class BatchEngine:
                         )
                         joined: set[int] = set()
                         try:
-                            for lane, req in join_args:
+                            for group in self._join_groups(join_args, slot):
                                 while True:
                                     try:
                                         (
                                             tok, kv, keys, ring_j, ring_idx_j
-                                        ) = self._join(
-                                            req, lane, rows, slot, tok, kv,
+                                        ) = self._join_group(
+                                            group, rows, slot, tok, kv,
                                             keys, ring_j, ring_idx_j, s,
                                         )
                                         break
@@ -3035,11 +3035,11 @@ class BatchEngine:
                                         self._settle(rows, 0, "join")
                                         self._failover_or_raise(e)
                                         kv = self._migrate_kv(rows, B, slot)
-                                joined.add(id(req))
-                                (pads_j,) = _set_lane(
-                                    (pads_j,), lane,
-                                    (slot - len(req.prompt_ids),),
-                                )
+                                joined.update(id(req) for _, req in group)
+                                # (each lane's pad: one lane's as ever, a
+                                # group's rows' in one program)
+                                pads_j = self._set_pads(pads_j, group, slot)
+                                # (a group is one program: ``_join_group``)
                                 if not look:
                                     # Serial: the joiner's first token is
                                     # read before anything else is enqueued.
@@ -4222,6 +4222,181 @@ class BatchEngine:
         self.stats["rows"] += 1
         return tok, kv, keys, ring_j, ring_idx_j
 
+    # The joiners of one step as one program (``shapes.join_rows`` > 1: a
+    # model whose join re-reads routed experts the chip holds whole). Below
+    # the step loop and ``_join``: a Mosaic kernel's payload carries its
+    # callers' line numbers, and a line that moves above a dispatch is a new
+    # compile-cache key for every cell's programs (PERF.md section 7, row 17).
+
+    def _join_groups(self, join_args: list, slot: int) -> list[list]:
+        """What ``_take_joins`` accepted, as the programs it goes as: lists
+        of (lane, request), in the order accepted. One a program unless the
+        backend's shapes group a step's joiners (``shapes.join_groups``, by
+        each row's own window width)."""
+        if self.shapes.join_rows < 2 or len(join_args) < 2:
+            return [[pair] for pair in join_args]
+        widths = [
+            self.shapes.window(slot - len(req.prompt_ids), slot, self.max_seq_len)[1]
+            for _, req in join_args
+        ]
+        return [
+            [join_args[i] for i in program]
+            for program in self.shapes.join_groups(widths)
+        ]
+
+    def _set_pads(self, pads_j, group: list, slot: int):
+        """The joiners' pads into their lanes' rows of ``pads_j``."""
+        pads = [slot - len(req.prompt_ids) for _, req in group]
+        if len(group) == 1:
+            return _set_lane((pads_j,), group[0][0], (pads[0],))[0]
+        lanes = self._row_lanes(group, dead=int(pads_j.shape[0]))
+        pads = np.asarray(pads + [0] * (len(lanes) - len(pads)), np.int32)
+        return _set_lanes((pads_j,), lanes, (pads,))[0]
+
+    def _row_lanes(self, group: list, dead: int) -> np.ndarray:
+        """The lanes of a group program's ``shapes.join_rows`` rows: the
+        group's own, then ``dead`` for the rows the step did not fill (-1 to
+        the backend; past the last lane to a write, which then drops)."""
+        spare = self.shapes.join_rows - len(group)
+        return np.asarray([lane for lane, _ in group] + [dead] * spare, np.int32)
+
+    def _join_group(
+        self, group: list, rows, slot, tok, kv, keys, ring_j, ring_idx_j, s
+    ):
+        """``_join`` for one program's joiners: the request alone through
+        ``_join`` as ever, a group through ONE join program of
+        ``shapes.join_rows`` rows (``backend.join_rows``), one first sample
+        and one write of the lanes' rows: what every joiner's stream holds
+        is what its own join gives it (each row its own pad, lane, table row
+        and PRNG stream)."""
+        if len(group) == 1:
+            (lane, req), = group
+            return self._join(
+                req, lane, rows, slot, tok, kv, keys, ring_j, ring_idx_j, s
+            )
+        states = [
+            _RowState(
+                req, set(self.config.eos_token_ids), self.tokenizer,
+                lane=lane, engine=self,
+            )
+            for lane, req in group
+        ]
+        for row in states:
+            row.open_span(slot=slot)  # the prefill is lane time (``_join``)
+        t_join = time.perf_counter()
+        try:
+            return self._join_rows(
+                group, states, rows, slot, tok, kv, keys, ring_j,
+                ring_idx_j, s, t_join,
+            )
+        except BaseException as e:
+            for row in states:
+                row.close_span(error=str(e)[:200])
+            raise
+
+    def _join_rows(
+        self, group, states, rows, slot, tok, kv, keys, ring_j, ring_idx_j,
+        s, t_join,
+    ):
+        from cake_tpu.models.llama.batch import _first_sample_fn, seed_rings
+
+        n_rows = self.shapes.join_rows
+        prompts = [req.prompt_ids for _, req in group]
+        pads = [slot - len(ids) for ids in prompts]
+        # every row ends at the shared slot, in the group program's width
+        W = self.shapes.join_width([
+            self.shapes.window(pad, slot, self.max_seq_len)[1] for pad in pads
+        ])
+        start = max(0, slot - W)
+        spare = n_rows - len(group)
+        with self._phase(
+            "join", rid=group[0][1].rid,
+            args={
+                "lanes": [lane for lane, _ in group], "slot": int(slot),
+                "rids": [req.rid for _, req in group],
+                "rows": n_rows, "state_layers": self._state_layers,
+            },
+        ) as join:
+            tokens = np.zeros((n_rows, W), np.int32)
+            for r, ((lane, _), ids, pad) in enumerate(zip(group, prompts, pads)):
+                lo = max(pad, start)
+                tokens[r, lo - start : slot - start] = ids[lo - pad :]
+                if self._alloc is not None:
+                    self._alloc.map_range(lane, pad, slot)
+            logits, kv = self._dispatch(
+                "join",
+                lambda: self.backend.join_rows(
+                    kv, tokens, pads + [slot] * spare, [slot] * n_rows,
+                    self._row_lanes(group, dead=-1), start,
+                ),
+            )
+            # ``_join_inner``'s first-token arithmetic at ``n_rows`` rows,
+            # each row its own ring and its own key; a dead row's sample is
+            # seated nowhere.
+            row_ring, row_ring_idx = seed_rings(
+                prompts + [[]] * spare, s.repeat_last_n
+            )
+            key0 = jnp.stack([
+                jax.random.PRNGKey(req.sampling.seed) for _, req in group
+            ] + [jax.random.PRNGKey(0)] * spare)
+            first, key_next = _first_sample_fn(
+                s.temperature, s.top_k, s.top_p, s.repeat_penalty, True
+            )(logits, jnp.asarray(row_ring), key0)
+            tok, keys, ring_j, ring_idx_j = _seat_joiners(
+                tok, keys, ring_j, ring_idx_j,
+                self._row_lanes(group, dead=len(rows)), first, key_next,
+                row_ring, row_ring_idx,
+            )
+        self.periods.note_join(join.seconds, joiners=len(group), rows=n_rows)
+        counters = self._take_counters()
+        for r, ((lane, req), row) in enumerate(zip(group, states)):
+            row.inflight = 1
+            # one device value, a row an entry: read once, emitted a joiner
+            self._unread.append(_Unread(
+                first, [(lane, row)], slot, 0, t_join, W,
+                counters=counters if r == 0 else None, row=r,
+            ))
+            rows[lane] = row if req.max_tokens > 1 else None
+            self._record_admissions([req], "joined", lane=lane, slot=slot)
+            metrics.registry.counter(
+                "cake_engine_joins_total",
+                "Requests that joined a RUNNING epoch at a chunk boundary.",
+            ).inc()
+        self.stats["joins"] += len(group)
+        self.stats["rows"] += len(group)
+        return tok, kv, keys, ring_j, ring_idx_j
+
+
+def _set_lanes_rows(arrays, lanes, values):
+    return tuple(
+        a.at[lanes].set(v, mode="drop") for a, v in zip(arrays, values)
+    )
+
+
+# ``_set_lane`` for a group program's rows: ``lanes`` [R], a dead row's past
+# the last lane, where a write drops. One shape whatever the group holds.
+_set_lanes = tracked_jit(_set_lanes_rows, name="engine.set_lanes")
+
+
+def _seat_joiners_rows(
+    tok, keys, ring, ring_idx, lanes, first, key, row_ring, row_ring_idx
+):
+    window = ring.shape[1]
+    if window > 0:
+        pushed = row_ring.at[jnp.arange(first.shape[0]), row_ring_idx].set(first)
+        ring = ring.at[lanes].set(pushed, mode="drop")
+        ring_idx = ring_idx.at[lanes].set(
+            (row_ring_idx + 1) % window, mode="drop"
+        )
+    return (
+        tok.at[lanes].set(first, mode="drop"),
+        keys.at[lanes].set(key, mode="drop"), ring, ring_idx,
+    )
+
+
+# ``_seat_joiner`` for a group program's rows, a dead row seated nowhere.
+_seat_joiners = tracked_jit(_seat_joiners_rows, name="engine.seat_joiners")
+
 
 def _fail_request(
     req: _Request, error: str, engine: "BatchEngine | None" = None
@@ -4269,6 +4444,7 @@ class _Unread:
     host: np.ndarray | None = None
     t_read: float = 0.0
     counters: jax.Array | None = None
+    row: int = 0  # a joiner's row of ``value``: a group's joiners share one
 
 
 @dataclasses.dataclass

@@ -73,6 +73,49 @@ _WIDTH_64THS_BY_KIND = {
 #     next to nothing (it takes no expert's rows and the attention kernel
 #     walks one slot of it). The closed set is then ALL the model runs.
 _DEAR_WIDTH_64THS = (4, 8, 16, 32, 48, 64)
+# THE JOINERS OF ONE STEP AS ONE PROGRAM (``join_rows``, ``join_groups``). A
+# join of this kind reads every expert its row touches, and at 200 tokens x 4
+# of 32 that is all of them: 9.9 GB over 14 sparse layers, 12 ms at the HBM
+# peak, of a one-row join's 18 to 23. A step's grant of join work is spent on
+# two or three such rows, so they go as one program of ``_DEAR_JOIN_ROWS``
+# rows at the widest row's width, rows the step did not fill dead, and the
+# experts are read once. The chip's clock (``chip_smoke.py`` phase J: my chip
+# call 1, PR 52; device ms a program at the cell's widths and geometry, rows
+# x slots: live rows x tokens), a program alone | as many rows one a program:
+#     1 x 256: 200 18.3      1 x 512: 300 23.1      1 x 1024: 600 34.4
+#     2 x 256: 2x200 26.6 | 36.6     2 x 512: 2x300 38.6 | 46.2
+#     3 x 256: 2x200 31.1 | 36.6, 3x200 33.4 | 54.9, 1x200 28.8
+#     3 x 512: 2x300 48.5 | 46.2, 3x300 51.6 | 69.2, 3x200 48.3 | 54.9
+#     4 x 256: 2x200 36.2 | 36.6, 3x200 38.6 | 54.9, 4x200 40.0 | 73.2
+#     4 x 512: 3x300 62.5 | 69.2      3 x 1024: 2x600 90.7 | 68.9, 3x600 97.5 | 103.3
+# A program is 10.7 ms whatever it holds (the experts' stream and the
+# launch), a token 11.5 us, and a SLOT, live or dead, 20.5 us at 256 and 512
+# slots and 24 at 1,024 (the attention kernel's grid steps over a row's whole
+# table, the sorts, the projections and the combine's one-hot product, which
+# is rows x tokens): dead slots are not free, so
+#   * three rows: a step's grant (1,024 tokens in the cell) holds at most
+#     three rows wider than 256 slots, the steps of three joiners are the
+#     long periods that set the judged percentile, and a fourth row costs a
+#     two-joiner step what it saves a four-joiner one (4 x 256 beside 3 x
+#     256 above); two rows would leave the three-joiner steps at two programs;
+#   * ONE group program, at 512 slots (``_DEAR_JOIN_64THS``; of 4,096): a
+#     start-up program of this kind is 5 s of ``setup_s`` in the served
+#     process even from the compile cache (trace, lower, fetch, load: 8 to 10
+#     s under a probe, my chip call 5, PR 52; with programs at 256 AND 512
+#     the cell's warm ``setup_s`` read 78.6 to 80.8 s for the parent's 68.2
+#     to 68.5, my chip call 2), so the set grows by the one program most
+#     steps can take: narrower rows go in it too (three rows of 200 tokens
+#     48.3 | 54.9 above, where 3 x 256 would run 33.4), and at 1,024 slots
+#     three rows run no faster than their rows one a program;
+#   * a group is taken where the slots it adds to its rows' own windows (the
+#     narrower rows' and the dead rows') are at most ``_DEAR_DEAD_SLOTS`` a
+#     program saved: 10.7 ms / 20.5 us = 524. Two rows of 512 in three (512
+#     added) run 48.5 for 46.2 on the device and save a launch and the host's
+#     enqueue of a program: taken. A row of 512 and one of 256 (768 added)
+#     would run 47 for 41, two of 256 (1,024 added) 46 for 37: one a program.
+_DEAR_JOIN_ROWS = 3
+_DEAR_JOIN_64THS = (8,)
+_DEAR_DEAD_SLOTS = 512
 
 
 def _dear_programs(config) -> bool:
@@ -148,6 +191,12 @@ class ProgramShapes:
     # the chunk that runs into an epoch's capacity is warmed with the others
     # (``programs``: "decode_tail").
     whole_batch: bool = False
+    # The joiners one step accepts go as one program of ``join_rows`` rows at
+    # one of ``join_widths`` where ``join_groups`` says so (rows the step did
+    # not fill are dead); 1: one a program, always.
+    join_rows: int = 1
+    join_widths: tuple[int, ...] = ()
+    dead_slots: int = 0
 
     @classmethod
     def for_model(cls, config, page_size: int = 0, pages_per_seq: int = 0):
@@ -165,14 +214,19 @@ class ProgramShapes:
         dear = _dear_programs(config)
         shares = _DEAR_WIDTH_64THS if dear else (
             _WIDTH_64THS_BY_KIND.get(config.cache_kind, _WIDTH_64THS))
-        widths = {min(slots, -(-slots * f // (64 * 64)) * 64) for f in shares}
+        def in_slots(shares):
+            return tuple(sorted({min(slots, -(-slots * f // (64 * 64)) * 64) for f in shares}))
+
         pages = {max(1, -(-pages_per_seq * q // 4)) for q in _CAPACITY_QUARTERS}
         return cls(
-            widths=tuple(sorted(widths)),
+            widths=in_slots(shares),
             capacities=tuple(p * page_size for p in sorted(pages)),
             prefill_tokens=1 if dear else _prefill_tokens(config),
             one_row_prefill_is_join=dear or config.cache_kind == CACHE_LATENT_INDEX,
             whole_batch=dear,
+            join_rows=_DEAR_JOIN_ROWS if dear else 1,
+            join_widths=in_slots(_DEAR_JOIN_64THS) if dear else (),
+            dead_slots=_DEAR_DEAD_SLOTS if dear else 0,
         )
 
     def lanes(self, n_seed: int, max_batch: int) -> int:
@@ -224,6 +278,44 @@ class ProgramShapes:
             return slot - width, width
         return 0, min(_ceil_to(slot, self.window_multiple), limit)
 
+    def join_width(self, widths: list[int]) -> int:
+        """The width of the group program that holds rows whose own windows
+        are ``widths`` wide: the least of ``join_widths`` that holds the
+        widest (0: none does)."""
+        return next((w for w in self.join_widths if w >= max(widths)), 0)
+
+    def join_groups(self, widths: list[int]) -> list[list[int]]:
+        """The programs one step's joiners go as, given each row's own window
+        width (``window``), in the order they were accepted: lists of their
+        indices, one a program. A list of two or more is one program of
+        ``join_rows`` rows, ``join_width`` of its rows wide. Rows wider than
+        the widest group program go alone; the others are taken ``join_rows``
+        at a time, and such a run is one program where the slots that adds to
+        the rows' own windows (the narrower rows' and the dead rows') are at
+        most ``dead_slots`` a program saved, else one a program as before."""
+        out: list[list[int]] = []
+        run: list[int] = []
+
+        def flush():
+            if len(run) > 1:
+                own = [widths[i] for i in run]
+                added = self.join_rows * self.join_width(own) - sum(own)
+                if added <= (len(run) - 1) * self.dead_slots:
+                    out.append(list(run))
+                    run.clear()
+            out.extend([i] for i in run)
+            run.clear()
+
+        for i, width in enumerate(widths):
+            if self.join_rows < 2 or not self.join_width([width]):
+                out.append([i])
+                continue
+            run.append(i)
+            if len(run) == self.join_rows:
+                flush()
+        flush()
+        return sorted(out)  # by each program's first row
+
     def decode_steps(self, chunk: int, cap: int, slot: int) -> int:
         """A chunk, or what is left under the epoch's slot ceiling."""
         return min(chunk, cap - 1 - slot)
@@ -240,7 +332,8 @@ class ProgramShapes:
     def programs(self, lanes: int) -> tuple[tuple[str, int, int], ...]:
         """What a saturated server dispatches at ``lanes`` lanes, as
         (operation, rows, slots): an epoch's prefill and a join at each
-        width, a decode chunk at each capacity. Empty when open: nothing can
+        width, a step's joiners together at each of ``join_widths``, a decode
+        chunk at each capacity. Empty when open: nothing can
         be run ahead of a set that has no end. "decode_tail": the chunk that
         runs into the capacity (``decode_steps``: what is left under the
         ceiling, a program of its own step count)."""
@@ -249,6 +342,7 @@ class ProgramShapes:
             if not (self.one_row_prefill_is_join and self.prefill_group(lanes, width) == 1):
                 out.append(("prefill", lanes, width))
             out.append(("join", 1, width))
+        out += [("join", self.join_rows, width) for width in self.join_widths]
         out += [("decode", lanes, c) for c in self.capacities]
         if self.whole_batch:
             out += [("decode_tail", lanes, c) for c in self.capacities]

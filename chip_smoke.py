@@ -77,7 +77,7 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, ".chip_smoke")  # listed in .gitignore
-PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "O", "L", "S", "F", "D")
+PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "O", "L", "S", "F", "J", "D")
 
 MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
     model_type="mistral", hidden_size=4096, intermediate_size=14336,
@@ -148,6 +148,12 @@ PRESETS = {
             model=dict(LFM2_D16), pages=2048, lanes=64, table_pages=32,
             steps=8, join_width=512, prompts=(300, 190), block_lanes=8,
             expert_tokens=(16, 32, 64, 128, 256),
+            # phase J: join programs alone (rows, slots, the live rows' tokens)
+            joins=dict(
+                rows=(2, 3, 4), slots=(256, 512, 1024),
+                tokens={256: (200,), 512: (200, 300), 1024: (200, 300, 600)},
+                runs=4,
+            ),
         ),
     ),
     # The rehearsal: same family and head layout rules (tp 4 divides the
@@ -237,6 +243,7 @@ PRESETS = {
             pages=16, lanes=4, table_pages=2, steps=4, join_width=64,
             prompts=(37, 21), block_lanes=4,
             expert_tokens=(8, 32), timed=dict(calls=2, repeats=1),
+            joins=dict(rows=(3,), slots=(32,), tokens={32: (20,)}, runs=2),
         ),
     ),
 }
@@ -852,7 +859,120 @@ def child_lfm2(preset: dict) -> None:
         emit({"kind": "program", "program": name, **report})
 
 
-CHILDREN = {"probe": child_probe, "sparse": child_sparse, "setup": child_setup,
+def child_joins(preset: dict) -> None:
+    """LFM2's join programs alone on the clock at the cell's published widths
+    and geometry (the 16-layer cut's weights drawn on the device, 64 lanes of
+    32 pages): one row a program at each width, and a step's joiners together
+    as R rows (``_PagedBackend.join_rows``) with 1, 2 and R live rows, the
+    others dead. A case is compiled by its first call, then run a few times
+    under ``jax.profiler``; its device ms and their parts are read from that
+    trace by the benchmark's own reader (``bench/parts.part_seconds``: whole
+    runs of ``jit_prefill_join*``, own time by PART scope). The table that
+    sets ``runtime/shapes.py``'s ``_DEAR_JOIN_ROWS``, ``_DEAR_JOIN_64THS``
+    and ``_DEAR_DEAD_SLOTS``."""
+    import dataclasses
+    import glob
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench import parts
+    from cake_tpu.models.llama import hybrid
+    from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.runtime.batch_backend import paged_backend
+    from cake_tpu.utils.device import describe_devices
+
+    # a dozen programs of 10 to 30 MB that nothing serves: they are kept out
+    # of the persistent compile cache, which holds 192 MiB on the machine
+    # with the chip and is the cells' (PERF.md section 7, row 17)
+    jax.config.update("jax_enable_compilation_cache", False)
+    emit({"kind": "summary", **describe_devices()})
+    g = preset["lfm2"]
+    j = g["joins"]
+    dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]]
+    page = preset["page_size"]
+    config = dataclasses.replace(
+        LlamaConfig.from_hf_dict(g["model"]), attention_impl="pallas")
+    on_chip = jax.default_backend() != "cpu"
+    be = paged_backend(
+        config, hybrid.init_params(config, jax.random.PRNGKey(0), dtype),
+        max_seq_len=page * g["table_pages"], cache_dtype=dtype, page_size=page,
+        max_pages=g["pages"], allow_pallas=on_chip, lanes=g["lanes"],
+    )
+    cache = be.init_kv(g["lanes"])
+    rng = np.random.default_rng(0)
+
+    def timed(rows: int, slots: int, live: int, n_tokens: int):
+        """One case: rows ``live`` of ``n_tokens`` tokens that end at slot
+        ``slots`` in lanes 0.., the window from slot 0."""
+        nonlocal cache
+        tokens = np.zeros((rows, slots), np.int32)
+        tokens[:live, slots - n_tokens:] = rng.integers(
+            8, config.vocab_size, (live, n_tokens))
+        pads = [slots - n_tokens] * live + [slots] * (rows - live)
+        be.allocator.reset(batch=g["lanes"])
+        for lane in range(live):
+            be.allocator.map_range(lane, pads[lane], slots)
+
+        def run():
+            nonlocal cache
+            if rows == 1:
+                out, cache = be.join(
+                    cache, tokens, jnp.asarray(pads, jnp.int32),
+                    jnp.asarray([slots], jnp.int32), 0)
+            else:
+                lanes = list(range(live)) + [-1] * (rows - live)
+                out, cache = be.join_rows(cache, tokens, pads, [slots] * rows, lanes)
+            be.take_chunk_counters()
+            return out
+
+        t0 = time.perf_counter()
+        jax.block_until_ready(run())
+        first_s = time.perf_counter() - t0
+        with tempfile.TemporaryDirectory(dir=WORK) as trace:
+            # a small program at either end: a run that touches the trace's
+            # first or last operation counts as cut by the window's edge
+            edge = jnp.zeros((8, 128), jnp.float32)
+            jax.profiler.start_trace(trace)
+            jax.block_until_ready(edge + 1)
+            t0 = time.perf_counter()
+            for _ in range(j["runs"]):
+                out = run()
+            jax.block_until_ready(out)
+            wall_ms = 1e3 * (time.perf_counter() - t0) / j["runs"]
+            jax.block_until_ready(edge + 2)
+            jax.profiler.stop_trace()
+            files = glob.glob(f"{trace}/plugins/profile/*/*.xplane.pb")
+            got = parts.part_seconds(files[0], "^jit_prefill_join") if files else {"runs": 0}
+        own = be.shapes.program_width(n_tokens)  # the row's own window
+        record = {"kind": "join", "rows": rows, "slots": slots, "live": live,
+                  "tokens": n_tokens, "first_s": round(first_s, 1),
+                  # whether a step's ``live`` such joiners go as THIS program
+                  "rule": "group" if rows == be.shapes.join_rows and len(
+                      be.shapes.join_groups([own] * live)) < live and (
+                      slots == be.shapes.join_width([own] * live)) else "single",
+                  "wall_ms": round(wall_ms, 3), "dev_ms": None, "parts_ms": {}}
+        if got["runs"]:
+            record["dev_ms"] = round(1e3 * got["program_s"] / got["runs"], 3)
+            record["parts_ms"] = {
+                part or "unscoped": round(1e3 * s / got["runs"], 2)
+                for part, s in got["own_s"].items()}
+        emit(record)
+
+    os.makedirs(WORK, exist_ok=True)
+    for slots in j["slots"]:
+        for n_tokens in j["tokens"][slots]:
+            timed(1, slots, 1, n_tokens)
+    for rows in j["rows"]:
+        for slots in j["slots"]:
+            for n_tokens in j["tokens"][slots]:
+                for live in sorted({1, 2, rows - 1, rows} - {0}):
+                    timed(rows, slots, live, n_tokens)
+
+
+CHILDREN = {"probe": child_probe, "joins": child_joins, "sparse": child_sparse, "setup": child_setup,
             "lfm2": child_lfm2,
             "kernels": child_kernels, "pool": child_pool,
             "hybrid": child_hybrid, "olmo": child_olmo,
@@ -1601,6 +1721,38 @@ def phase_lfm2(args, preset) -> dict:
     return out
 
 
+def phase_joins(args, preset) -> dict:
+    """Phase J: LFM2's join programs alone on the clock (``child_joins``): a
+    line a case, the group beside its live rows one a program (each at its
+    OWN width's one-row program, which is what the engine would dispatch),
+    and what ``shapes.join_groups`` makes of those rows. Fails where the
+    rule takes a group at the cell's own row count that the device runs more
+    than a tenth slower than its rows one by one."""
+    records = [r for r in run_child("joins", args, timeout=2400) if r["kind"] == "join"]
+    ms = "wall_ms" if args.rehearse_cpu else "dev_ms"
+    alone = {}  # tokens -> a one-row join of them, at the narrowest width that holds them
+    for r in sorted((r for r in records if r["rows"] == 1), key=lambda r: -r["slots"]):
+        alone[r["tokens"]] = r
+    problems, out = [], {}
+    for r in records:
+        own = alone[r["tokens"]]
+        singly = r["live"] * own[ms]
+        grouped = r["rule"] == "group"
+        say(f"phase=J join rows={r['rows']} slots={r['slots']} live={r['live']} "
+            f"tokens={r['tokens']} {ms}={r[ms]} one_a_program_{ms}={singly:.3f} "
+            f"rule={r['rule']} first_s={r['first_s']} "
+            f"wall_ms={r['wall_ms']} parts_ms={r['parts_ms']}")
+        if grouped and not args.rehearse_cpu and r[ms] > 1.1 * singly:
+            problems.append(
+                f"{r['rows']} rows x {r['slots']} slots with {r['live']} live rows of "
+                f"{r['tokens']} tokens ran {r[ms]} ms, its rows one a program {singly:.3f}")
+        if grouped:
+            out[f"join_{r['rows']}x{r['slots']}_live{r['live']}_{r['tokens']}_{ms}"] = r[ms]
+    if problems:
+        raise PhaseFailed("; ".join(problems))
+    return out
+
+
 def phase_four_chips(args, preset) -> dict:
     cpu = args.rehearse_cpu
     out = {}
@@ -1692,6 +1844,7 @@ def main() -> int:
         "L": lambda: phase_latent(args, preset),
         "S": lambda: phase_sparse(args, preset),
         "F": lambda: phase_lfm2(args, preset),
+        "J": lambda: phase_joins(args, preset),
         "D": lambda: phase_four_chips(args, preset),
     }
 

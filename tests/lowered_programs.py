@@ -74,7 +74,28 @@ def digests() -> dict[str, str]:
             for k, t in sorted(texts.items())}
 
 
+def dear_digests() -> dict[str, str]:
+    """The family whose programs PR 52 adds to (``lfm2_moe``: state layers
+    beside routed experts, which no recording before it held), as served: the
+    three every tree has, and ``join_rows`` (a step's joiners as one program
+    of three rows) where the tree has it. ``python tests/lowered_programs.py
+    dear`` in a checkout of PR 52's parent recorded the three of ``tests/
+    data/lowered_programs_pr52.json``; the fourth is PR 52's own."""
+    from cake_tpu.models.llama import programs
+
+    from test_program_parts import FAMILIES
+
+    more = {"join_rows": 3} if hasattr(programs, "join_rows_program") else {}
+    served = programs.served_programs(
+        FAMILIES["lfm2_moe"], **GEOMETRY, **more, allow_pallas=False).programs
+    return {f"lfm2_moe.{name}": hashlib.sha256(
+        thunk().lower().as_text().encode()).hexdigest() for name, thunk in sorted(served.items())}
+
+
 if __name__ == "__main__":
+    if sys.argv[1:] == ["dear"]:
+        json.dump(dear_digests(), sys.stdout, indent=1)
+        sys.exit(0)
     if sys.argv[1:] == ["grouped"]:
         from cake_tpu.ops import moe
 

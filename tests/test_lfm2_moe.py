@@ -106,7 +106,9 @@ def test_the_cut_to_sixteen_layers_walks_nine_runs():
     assert shapes.capacities == (1024, 2048, 4096) and shapes.one_row_prefill_is_join
     assert shapes.prefill_group(64, 256) == 1
     assert [p for p, _, _ in shapes.programs(64)] == (
-        ["join"] * 6 + ["decode"] * 3 + ["decode_tail"] * 3)
+        ["join"] * 6 + ["join"] + ["decode"] * 3 + ["decode_tail"] * 3)
+    # (the one more: a step's joiners as one program of three rows, PR 52)
+    assert shapes.programs(64)[6] == ("join", 3, 512)
     assert shapes.whole_batch and shapes.lanes(1, 64) == shapes.lanes(40, 64) == 64
     assert shapes.decode_steps(8, 1024, 1024 - 8) == 7  # what a tail is warmed at
 

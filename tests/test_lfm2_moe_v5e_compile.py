@@ -42,7 +42,7 @@ def cell_reports(lfm2, one_chip):  # noqa: F811
             return pool_audit.audit_programs(
                 lfm2, n_pages=2048, page_size=128, lanes=64, n_steps=8,
                 table_pages=TABLE_PAGES, width=512, sharding=one_chip,
-                only=("decode", "join"),
+                join_rows=3, only=("decode", "join", "join_rows"),
             )
 
 
@@ -68,6 +68,22 @@ def test_the_cell_compiles_for_v5e_without_pool_or_window_copies(program, cell_r
     assert report["code_bytes"] < 24e6, report  # nine runs' bodies: code by the run
 
 
+def test_a_steps_joiners_as_three_rows_compile_for_v5e(cell_reports):
+    """PR 52's program at the cell's geometry: three rows of 512 slots. The
+    pool is carried like the one-row join's, the grouped products are the
+    join's 24 (the experts are read once for the three rows), and the rows'
+    windows are placed in their lanes by a gather and a select over the lane
+    state WHOLE: two copies of its 6 MB (15 us each at the HBM's peak, of a
+    program of 40 ms), which is what no update-slice at a lane costs."""
+    report, one = cell_reports["join_rows"], cell_reports["join"]
+    assert report["scans"] == [] and report["pool_ops"] == [] and report["state_scans"] == []
+    assert [c.split(" ")[1] for c in report["state_copies"]] == ["bf16[12,2,64,2048]"] * 2
+    assert report["grouped_products"] == one["grouped_products"] == 24
+    assert report["pool_writes"] == 4 and report["kernels"] == one["kernels"] == 32
+    assert report["argument_bytes"] - one["argument_bytes"] < 16_384  # two more rows' operands
+    assert report["temp_bytes"] < 64e6 and report["code_bytes"] < 24e6, report
+
+
 def test_the_cells_closed_shapes(lfm2):
     """What ``--max-seq-len 4096 --page-size 128`` makes of the CLOSED
     instance: six joins, three decode chunks and their tails, no program for
@@ -78,7 +94,8 @@ def test_the_cells_closed_shapes(lfm2):
     assert FLAGS[FLAGS.index("--max-pages") + 1] == str(64 * TABLE_PAGES)  # every lane backed
     shapes = ProgramShapes.for_model(lfm2, 128, TABLE_PAGES)
     assert shapes.widths == (256, 512, 1024, 2048, 3072, 4096)
-    assert len(shapes.programs(64)) == 12 and shapes.whole_batch
+    # and, since PR 52, a step's joiners together as three rows of 512 slots
+    assert len(shapes.programs(64)) == 12 + 1 and shapes.whole_batch
     assert not [p for p in shapes.programs(64) if p[0] == "prefill"]
     # the longest prompt with its template, and the probes
     assert shapes.program_width(3000 + 7) == 3072 and shapes.program_width(1207) == 2048

@@ -73,6 +73,28 @@ def test_the_moved_programs_are_tiny_joins_and_prefills_with_a_router():
         for program in ("join", "prefill")]
 
 
+# PR 52: the dear kind (``lfm2_moe``: no recording before it held its
+# programs): the three every tree serves, recorded on PR 52's PARENT
+# (``python tests/lowered_programs.py dear`` there), and the program PR 52
+# adds, a step's joiners as three rows, as this tree lowers it.
+DEAR = json.loads(
+    (Path(__file__).parent / "data" / "lowered_programs_pr52.json").read_text())
+
+
+@pytest.fixture(scope="module")
+def dear_digests():
+    return _digests("dear")
+
+
+@pytest.mark.parametrize("program", sorted(DEAR))
+def test_the_dear_kinds_programs_lower_to_their_recording(dear_digests, program):
+    """The decode chunk, the one-row join and an epoch's group are the
+    parent's text; the group of joining rows is held to its first lowering."""
+    assert dear_digests[program] == DEAR[program]
+    assert sorted(dear_digests) == sorted(DEAR) == [
+        f"lfm2_moe.{p}" for p in ("decode", "join", "join_rows", "prefill")]
+
+
 def test_every_program_is_held(digests):
     held = sorted([f"{m}.{p}" for m in FAMILIES for p in ("decode", "join", "prefill")]
                   + ["dense.join_plain", "dense.prefill_plain"])

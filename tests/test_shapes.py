@@ -58,8 +58,14 @@ def test_state_layers_beside_routed_experts_take_the_dear_programs_rule():
     assert dear.prefill_tokens == 1 and dear.one_row_prefill_is_join and dear.whole_batch
     assert dear.lanes(1, 64) == 64
     ops = [op for op, _, _ in dear.programs(64)]
-    assert ops.count("join") == 6 and ops.count("prefill") == 0
+    assert ops.count("join") == 6 + 1 and ops.count("prefill") == 0
     assert ops.count("decode") == ops.count("decode_tail") == 3
+    # PR 52: a step's joiners as one program of three rows of 512 slots: one
+    # start-up program more than the twelve
+    assert (dear.join_rows, dear.join_widths) == (3, (512,))
+    assert [p for p in dear.programs(64) if p[0] == "join"] == [
+        *(("join", 1, w) for w in dear.widths), ("join", 3, 512)]
+    assert len(dear.programs(64)) == 13
     plain = ProgramShapes.for_model(STATE, 128, 32)
     assert not plain.whole_batch and not plain.one_row_prefill_is_join and len(plain.widths) == 11
 
@@ -205,6 +211,35 @@ def test_the_open_set_runs_nothing_ahead_and_the_closed_one_all_of_it():
     assert programs[:2] == (("prefill", 32, 64), ("join", 1, 64))
     assert programs[-3:] == (("decode", 32, 1024), ("decode", 32, 2048), ("decode", 32, 4096))
     assert [p[0] for p in programs].count("join") == 11
+
+
+KINDS = types.SimpleNamespace(cache_kind="kv+kinds", state_mixer="mamba")
+INDEX = types.SimpleNamespace(cache_kind="latent+index", state_mixer="mamba")
+
+
+@pytest.mark.parametrize("name,config,lanes,table,n_programs,n_joins,digest", [
+    ("open", ATTENTION, 8, 32, 0, 0, "2e38e77b22c3"),
+    ("jamba", STATE, 32, 32, 25, 11, "3384ff304c9f"),
+    ("olmo", DELTA, 32, 32, 25, 11, "3384ff304c9f"),
+    ("pangu", LATENT, 64, 32, 25, 11, "cbab03f57741"),
+    ("laguna", KINDS, 48, 192, 15, 6, "e8d5e6809747"),
+    ("deepseek", INDEX, 16, 168, 9, 6, "b74014258e52"),
+])
+def test_every_other_kind_runs_the_programs_it_ran_and_joins_one_a_program(
+        name, config, lanes, table, n_programs, n_joins, digest):
+    """PR 52 groups a step's joiners for the dear kind alone: every other
+    kind's start-up list, at its cell's lanes and table, is what PR 52's
+    parent listed (``digest``: of the list's ``repr``, computed there), its
+    joins are all one row, and its ``join_groups`` never groups."""
+    import hashlib
+
+    shapes = ProgramShapes.for_model(config, 128, table)
+    assert (shapes.join_rows, shapes.join_widths, shapes.dead_slots) == (1, (), 0)
+    programs = shapes.programs(lanes)
+    assert len(programs) == n_programs
+    assert hashlib.sha256(repr(programs).encode()).hexdigest()[:12] == digest
+    assert [rows for op, rows, _ in programs if op == "join"] == [1] * n_joins
+    assert shapes.join_groups([256, 256, 512]) == [[0], [1], [2]]
 
 
 @pytest.mark.parametrize("rows,width,want", [
