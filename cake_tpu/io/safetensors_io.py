@@ -522,23 +522,105 @@ _LFM2_MOE_TEMPLATES = {
         "w_down": ("feed_forward.experts.{e}.w2.weight", "experts"),
     },
 }
+# ``model_type: qwen3_next`` (names ASSUMED: written from memory of
+# transformers' ``Qwen3NextForCausalLM``). Three of its tensors are laid out
+# a head at a time and are RE-ORDERED here, once, into the tree's layouts, so
+# that no served program permutes (``_by_head`` says how): ``in_proj_qkvz``
+# [q dk | k dk | v g dv | z g dv] a KEY head (g value heads a key head) ->
+# ``in_proj`` q | k | v | z; ``in_proj_ba`` [b g | a g] a key head ->
+# ``ab_proj`` a | b; ``q_proj`` [q | gate] a query head -> ``wq`` and the
+# gate's matrix ``wg``. The convolution's channels are q | k | v already.
+_QWEN3_NEXT_FFN = {
+    "ln_attn": ("input_layernorm.weight", None),
+    "ln_mlp": ("post_attention_layernorm.weight", None),
+    "router": ("mlp.gate.weight", "T"),
+    "sh_gate": ("mlp.shared_expert.gate_proj.weight", "T"),
+    "sh_up": ("mlp.shared_expert.up_proj.weight", "T"),
+    "sh_down": ("mlp.shared_expert.down_proj.weight", "T"),
+    "se_gate": ("mlp.shared_expert_gate.weight", "T"),
+    "w_gate": ("mlp.experts.{e}.gate_proj.weight", "experts"),
+    "w_up": ("mlp.experts.{e}.up_proj.weight", "experts"),
+    "w_down": ("mlp.experts.{e}.down_proj.weight", "experts"),
+}
+_QWEN3_NEXT_TEMPLATES = {
+    "attention": {
+        "wq": ("self_attn.q_proj.weight", "q|gate:0"),
+        "wg": ("self_attn.q_proj.weight", "q|gate:1"),
+        "wk": ("self_attn.k_proj.weight", "T"),
+        "wv": ("self_attn.v_proj.weight", "T"),
+        "wo": ("self_attn.o_proj.weight", "T"),
+        "q_norm": ("self_attn.q_norm.weight", None),
+        "k_norm": ("self_attn.k_norm.weight", None),
+        **_QWEN3_NEXT_FFN,
+    },
+    "state": {
+        "in_proj": ("linear_attn.in_proj_qkvz.weight", "qkvz"),
+        "ab_proj": ("linear_attn.in_proj_ba.weight", "ba"),
+        "conv_w": ("linear_attn.conv1d.weight", "conv"),
+        "A_log": ("linear_attn.A_log", None),
+        "dt_bias": ("linear_attn.dt_bias", None),
+        "o_norm": ("linear_attn.norm.weight", None),
+        "wo": ("linear_attn.out_proj.weight", "T"),
+        **_QWEN3_NEXT_FFN,
+    },
+}
 # model_type -> (tables a mixer kind and, where the model's feed-forwards
 # differ, a feed-forward kind; the final norm's name)
 _HYBRID_TABLES = {
     "jamba": (_JAMBA_TEMPLATES, "model.final_layernorm.weight"),
     "olmo_hybrid": (_OLMO_HYBRID_TEMPLATES, "model.norm.weight"),
     "lfm2_moe": (_LFM2_MOE_TEMPLATES, "model.embedding_norm.weight"),
+    "qwen3_next": (_QWEN3_NEXT_TEMPLATES, "model.norm.weight"),
 }
+
+
+_HEAD_MAJOR = ("qkvz", "ba", "q|gate:0", "q|gate:1")
+
+
+def _by_head(config: LlamaConfig, how: str) -> tuple[int, list[int], list[int]]:
+    """A tensor laid out a head at a time, as (heads, the widths of a head's
+    parts in the checkpoint's order, the order the tree joins them in: part
+    ``p`` of every head side by side, then the next part)."""
+    if how.startswith("q|gate"):
+        return config.num_attention_heads, [config.head_dim] * 2, [int(how[-1])]
+    keys = config.linear_num_key_heads
+    group = config.linear_num_value_heads // keys
+    if how == "ba":  # [b | a] a key head -> a | b
+        return keys, [group, group], [1, 0]
+    dk, dv = config.linear_key_head_dim, group * config.linear_value_head_dim
+    return keys, [dk, dk, dv, dv], [0, 1, 2, 3]
+
+
+def _from_heads(a, config: LlamaConfig, how: str):
+    """[in, heads x parts] as the checkpoint has it (turned) -> the tree's
+    [in, part | part | ...]."""
+    heads, widths, order = _by_head(config, how)
+    cuts = np.cumsum(widths)[:-1]
+    parts = jnp.split(a.reshape(a.shape[0], heads, sum(widths)), cuts, axis=-1)
+    return jnp.concatenate([parts[p].reshape(a.shape[0], -1) for p in order], axis=-1)
+
+
+def _to_heads(parts: list[np.ndarray], config: LlamaConfig, how: str) -> np.ndarray:
+    """The inverse: the tree's parts [in, heads * width] in the CHECKPOINT's
+    order -> [in, heads x parts]."""
+    heads, widths, _ = _by_head(config, how)
+    rows = parts[0].shape[0]
+    return np.concatenate(
+        [p.reshape(rows, heads, w) for p, w in zip(parts, widths)], axis=-1
+    ).reshape(rows, -1)
 
 
 def _hybrid_table(config: LlamaConfig, kind: str, ff: str) -> dict:
     """A run's table: its mixer's and, where the model type's tables say the
-    feed-forward apart, its feed-forward's (without a selection bias the
-    config leaves out)."""
+    feed-forward apart, its feed-forward's (without a selection bias or a
+    shared expert the config leaves out)."""
     tables = _HYBRID_TABLES[config.model_type][0]
     table = {**tables[kind], **tables.get(ff, {})}
     if not config.router_bias:
         table.pop("router_bias", None)
+    if not config.shared_expert_intermediate_size:
+        for key in ("sh_gate", "sh_up", "sh_down", "se_gate"):
+            table.pop(key, None)
     return table
 
 
@@ -549,6 +631,8 @@ def _hybrid_read(reader: SafetensorsReader, names, how, dtype, config=None):
         )
     if how == "conv":
         return reader.jax(names, dtype)[:, 0, :].T
+    if how in _HEAD_MAJOR:
+        return _from_heads(reader.jax(names, dtype, transpose=True), config, how)
     if how == "experts":
         first = config.expert_offset
         return jnp.stack([
@@ -580,6 +664,22 @@ def load_hybrid_layers(
     ]
 
 
+def _head_major(run: Params, k: int, key: str, how: str, config: LlamaConfig, dtype):
+    """One layer's tensor in the checkpoint's head-at-a-time layout from the
+    tree's (``hybrid_tensor_dict``): [out, in], or None for the key whose
+    tensor another key writes (``wg`` rides in ``wq``'s ``q_proj``)."""
+    if how == "q|gate:1":
+        return None
+    if how == "q|gate:0":
+        parts = [np.asarray(run[n][k].astype(dtype)) for n in ("wq", "wg")]
+    else:
+        heads, widths, order = _by_head(config, how)
+        whole = np.asarray(run[key][k].astype(dtype))
+        joined = np.split(whole, np.cumsum([heads * widths[p] for p in order])[:-1], axis=-1)
+        parts = [joined[order.index(p)] for p in range(len(widths))]
+    return _to_heads(parts, config, how).T
+
+
 def _joined_widths(config: LlamaConfig, key: str) -> list[int]:
     """Widths of the checkpoint's tensors that one joined key holds."""
     heads = config.linear_num_value_heads
@@ -608,6 +708,11 @@ def hybrid_tensor_dict(
         for key, (names, how) in _hybrid_table(config, kind, ff).items():
             for k, i in enumerate(config.layers_of(kind)[lo:hi]):
                 whole = np.asarray(run[key][k].astype(dtype))
+                if how in _HEAD_MAJOR:
+                    a = _head_major(run, k, key, how, config, dtype)
+                    if a is not None:
+                        tensors[f"model.layers.{i}.{names}"] = a.copy()
+                    continue
                 if isinstance(names, tuple):
                     cuts = np.cumsum(_joined_widths(config, key))[:-1]
                     parts = zip(names, np.split(whole, cuts, axis=-1))

@@ -387,6 +387,8 @@ DIALOG_ENCODERS = {
     "laguna": encode_dialog_laguna,
     "deepseek_v32": encode_dialog_deepseek,
     "lfm2_moe": encode_dialog_lfm2,
+    # Qwen3-Next-Instruct (ASSUMED, from memory): ChatML, no default system turn
+    "qwen3_next": encode_dialog_chatml_no_default_system,
 }
 
 

@@ -24,6 +24,7 @@ SUPPORTED_MODEL_TYPES = (
     "llama", "qwen2", "mistral", "mixtral", "qwen2_moe",
     "gemma", "gemma2", "phi3", "qwen3", "qwen3_moe", "gemma3_text", "jamba",
     "pangu_ultra_moe", "olmo_hybrid", "laguna", "deepseek_v32", "lfm2_moe",
+    "qwen3_next",
 )
 
 # The two kinds a decoder layer's token mixer can be (``layer_kinds``).
@@ -49,6 +50,22 @@ CACHE_KV_KINDS = "kv+kinds"
 # A latent a token and, behind the SAME block table, the key of a learned
 # index that chooses which cached tokens a query attends (``deepseek_v32``).
 CACHE_LATENT_INDEX = "latent+index"
+
+
+def _expert_share(d: dict, held_key: str, first_key: str, default: int) -> tuple[int, int, int]:
+    """(held, ranked, first) of a configuration that may hold a SHARE of its
+    routed experts: ``held_key`` counts the experts held, ``<held_key>_total``
+    (absent = the same: the whole model) what the router ranks, ``first_key``
+    where the held ones start among them."""
+    held = int(d.get(held_key, default))
+    ranked = int(d.get(f"{held_key}_total", held))
+    first = int(d.get(first_key, 0))
+    if not 0 <= first <= ranked - held:
+        raise ValueError(
+            f"experts {first}..{first + held - 1} are held of {ranked}: "
+            f"{first_key} + {held_key} must not pass {held_key}_total"
+        )
+    return held, ranked, first
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,6 +215,9 @@ class LlamaConfig:
     # False = attention carries no positional term at all (Jamba,
     # Olmo-Hybrid: the state layers carry the order).
     use_rope: bool = True
+    # The share of a head's numbers the rotary term turns (HF
+    # ``partial_rotary_factor``): the first ``rotary_dim``, the rest pass.
+    partial_rotary_factor: float = 1.0
     # Norm placement, as data. ``pre_block_norms``: a norm on each branch's
     # INPUT (``ln_attn`` / ``ln_mlp``; every family but OLMo's).
     # ``post_block_norms`` (above): one on each branch's OUTPUT before the
@@ -261,9 +281,12 @@ class LlamaConfig:
     attention_types: tuple[str, ...] | None = None
     heads_per_layer: tuple[int, ...] | None = None
     kind_ropes: tuple[tuple[str, KindRope], ...] | None = None
-    # A gate on each query head's attention output before ``o_proj``:
-    # ``attn_gate_act`` of a linear map of the layer's normed input, one
-    # scalar a head (``wg`` [hidden, heads]). None = no gate.
+    # A gate on the attention output before ``o_proj``: ``attn_gate_act`` of
+    # a linear map of the layer's normed input. "per-head": one scalar a
+    # query head (``wg`` [hidden, heads]; ``laguna``); "per-number": one a
+    # number of the output (``wg`` [hidden, heads * head_dim], in a checkpoint
+    # the second half of each head's ``q_proj`` rows; ``qwen3_next``). None =
+    # no gate.
     attn_gate: str | None = None
     attn_gate_act: str = "sigmoid"
     # The feed-forward of every layer as a list (``mlp_layer_types``); None =
@@ -474,6 +497,10 @@ class LlamaConfig:
         return self.hidden_size // self.num_attention_heads
 
     @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
     def num_query_groups(self) -> int:
         """Query heads per KV head (GQA group size)."""
         return self.num_attention_heads // self.num_key_value_heads
@@ -547,6 +574,8 @@ class LlamaConfig:
             return cls._laguna_from_hf_dict(d, eos_ids)
         if model_type == "lfm2_moe":
             return cls._lfm2_moe_from_hf_dict(d, eos_ids)
+        if model_type == "qwen3_next":
+            return cls._qwen3_next_from_hf_dict(d, eos_ids)
         if model_type == "phi3" and d.get("rope_scaling"):
             # Phi-3 128k variants use longrope (per-dim su-scaled factors);
             # only the base-rope variants (4k/8k) are supported.
@@ -908,6 +937,102 @@ class LlamaConfig:
         )
 
     @classmethod
+    def _qwen3_next_from_hf_dict(
+        cls, d: dict[str, Any], eos_ids: tuple[int, ...]
+    ) -> "LlamaConfig":
+        """``model_type: qwen3_next`` (Qwen3-Next-80B-A3B): gated-delta-rule
+        layers (``linear_attention``: ``linear_num_value_heads`` value heads
+        in groups on ``linear_num_key_heads`` key heads) beside gated
+        grouped-query attention (``full_attention``: a norm a head on q and k
+        before a rotary term over ``partial_rotary_factor`` of the head, a
+        sigmoid gate a number of the output), by ``layer_types`` where the
+        file has the list, else every ``full_attention_interval``-th layer
+        full; a pre-norm block whose norms are ``(1 + w)`` (not the delta
+        rule's output norm); every layer ``num_experts`` softmax-routed
+        experts beside a shared one behind a sigmoid gate. ``num_experts``
+        counts the experts HELD; ``num_experts_total`` (absent = the same:
+        the whole model) what the router ranks and ``first_expert`` where the
+        held ones start among them. What the config does not say (which
+        norms take the 1, the gate's form, beta without a factor 2, the ids)
+        is DATA here."""
+        kinds = {"linear_attention": STATE, "full_attention": ATTENTION}
+        n_layers = int(d.get("num_hidden_layers", 48))
+        raw = d.get("layer_types")
+        if raw is None:
+            interval = int(d.get("full_attention_interval", 4))
+            raw = [
+                "full_attention" if (i + 1) % interval == 0 else "linear_attention"
+                for i in range(n_layers)
+            ]
+        if len(raw) != n_layers or set(raw) - set(kinds):
+            raise ValueError(
+                f"qwen3_next needs layer_types: {n_layers} entries "
+                f"(num_hidden_layers) of {sorted(kinds)}, got {raw!r}"
+            )
+        if int(d.get("decoder_sparse_step", 1)) != 1 or d.get("mlp_only_layers"):
+            raise ValueError(
+                "qwen3_next with decoder_sparse_step != 1 or mlp_only_layers "
+                "needs dense layers among the sparse, which this parser does "
+                "not build"
+            )
+        if d.get("rope_scaling"):
+            raise ValueError("qwen3_next with rope_scaling is not supported")
+        if d.get("attention_bias", False):
+            raise ValueError("qwen3_next with attention_bias is not supported")
+        if d.get("use_sliding_window", False):
+            raise ValueError("qwen3_next with use_sliding_window is not supported")
+        lin_k = int(d.get("linear_num_key_heads", 16))
+        lin_v = int(d.get("linear_num_value_heads", 32))
+        if lin_v % lin_k:
+            raise ValueError(
+                f"qwen3_next with linear_num_value_heads={lin_v} needs whole "
+                f"groups on linear_num_key_heads={lin_k}"
+            )
+        held, ranked, first = _expert_share(d, "num_experts", "first_expert", 512)
+        heads = int(d.get("num_attention_heads", 16))
+        hidden = int(d.get("hidden_size", 2048))
+        head_dim = int(d.get("head_dim", 256))
+        if "eos_token_id" not in d:
+            eos_ids = (151645,)  # <|im_end|>
+        return cls(
+            hidden_size=hidden,
+            intermediate_size=int(d.get("intermediate_size", 5120)),
+            vocab_size=int(d.get("vocab_size", 151936)),
+            num_hidden_layers=n_layers,
+            num_attention_heads=heads,
+            num_key_value_heads=int(d.get("num_key_value_heads", 2)),
+            rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+            rope_theta=float(d.get("rope_theta", 10000000.0)),
+            max_position_embeddings=int(d.get("max_position_embeddings", 262144)),
+            bos_token_id=int(d.get("bos_token_id", 151643)),
+            eos_token_ids=eos_ids,
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            model_type="qwen3_next",
+            head_dim_override=None if head_dim * heads == hidden else head_dim,
+            layer_types=tuple(kinds[t] for t in raw),
+            state_mixer=GATED_DELTA,
+            linear_num_key_heads=lin_k,
+            linear_num_value_heads=lin_v,
+            linear_key_head_dim=int(d.get("linear_key_head_dim", 128)),
+            linear_value_head_dim=int(d.get("linear_value_head_dim", 128)),
+            linear_conv_kernel_dim=int(d.get("linear_conv_kernel_dim", 4)),
+            linear_allow_neg_eigval=False,
+            partial_rotary_factor=float(d.get("partial_rotary_factor", 0.25)),
+            qk_norm=True,
+            rmsnorm_offset=True,
+            attn_gate="per-number",
+            num_local_experts=held,
+            router_experts=ranked,
+            expert_offset=first,
+            num_experts_per_tok=int(d.get("num_experts_per_tok", 10)),
+            norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+            moe_intermediate_size=int(d.get("moe_intermediate_size", 512)),
+            shared_expert_intermediate_size=int(
+                d.get("shared_expert_intermediate_size", 512)) or None,
+            moe_scoring="softmax",
+        )
+
+    @classmethod
     def _laguna_from_hf_dict(
         cls, d: dict[str, Any], eos_ids: tuple[int, ...]
     ) -> "LlamaConfig":
@@ -996,14 +1121,7 @@ class LlamaConfig:
                 beta_slow=float(r.get("beta_slow", 1)),
                 attention_factor=float(r.get("attention_factor", 1.0)) if yarn else 1.0,
             )))
-        held = int(d.get("num_experts", 256))
-        ranked = int(d.get("num_experts_total", held))
-        first = int(d.get("first_expert", 0))
-        if not 0 <= first <= ranked - held:
-            raise ValueError(
-                f"experts {first}..{first + held - 1} are held of {ranked}: "
-                "first_expert + num_experts must not pass num_experts_total"
-            )
+        held, ranked, first = _expert_share(d, "num_experts", "first_expert", 256)
         window = d.get("sliding_window")
         if by_kind[SLIDING] and not window:
             raise ValueError("laguna with sliding layers needs sliding_window")
@@ -1081,15 +1199,7 @@ class LlamaConfig:
         fields: MLA's sizes, leading dense layers, sigmoid routing beside a
         shared expert, and this repository's three keys for a rank's share of
         the experts. The defaults that differ by model are the caller's."""
-        held = int(d.get("n_routed_experts", 256))
-        ranked = int(d.get("n_routed_experts_total", held))
-        first = int(d.get("first_routed_expert", 0))
-        if not 0 <= first <= ranked - held:
-            raise ValueError(
-                f"experts {first}..{first + held - 1} are held of {ranked}: "
-                "first_routed_expert + n_routed_experts must not pass "
-                "n_routed_experts_total"
-            )
+        held, ranked, first = _expert_share(d, "n_routed_experts", "first_routed_expert", 256)
         heads = int(d.get("num_attention_heads", 128))
         moe_inter = int(d.get("moe_intermediate_size", 2048))
         return dict(
@@ -1294,6 +1404,7 @@ class LlamaConfig:
             "laguna": "LagunaForCausalLM",
             "deepseek_v32": "DeepseekV32ForCausalLM",
             "lfm2_moe": "Lfm2MoeForCausalLM",
+            "qwen3_next": "Qwen3NextForCausalLM",
         }[self.model_type]
         d: dict[str, Any] = {
             "architectures": [arch],
@@ -1416,6 +1527,30 @@ class LlamaConfig:
                 moe_intermediate_size=self.moe_intermediate_size,
                 use_expert_bias=self.router_bias,
                 routed_scaling_factor=self.routed_scaling_factor,
+            )
+        elif self.model_type == "qwen3_next":
+            del d["attention_bias"]
+            d.update(
+                head_dim=self.head_dim,
+                partial_rotary_factor=self.partial_rotary_factor,
+                layer_types=[
+                    "full_attention" if k == ATTENTION else "linear_attention"
+                    for k in self.layer_kinds
+                ],
+                linear_num_key_heads=self.linear_num_key_heads,
+                linear_num_value_heads=self.linear_num_value_heads,
+                linear_key_head_dim=self.linear_key_head_dim,
+                linear_value_head_dim=self.linear_value_head_dim,
+                linear_conv_kernel_dim=self.linear_conv_kernel_dim,
+                decoder_sparse_step=1, mlp_only_layers=[],
+                num_experts=self.num_local_experts,
+                num_experts_total=self.n_router_experts,
+                first_expert=self.expert_offset,
+                num_experts_per_tok=self.num_experts_per_tok,
+                norm_topk_prob=self.norm_topk_prob,
+                moe_intermediate_size=self.moe_intermediate_size,
+                shared_expert_intermediate_size=(
+                    self.shared_expert_intermediate_size or 0),
             )
         elif self.num_local_experts:
             if self.model_type in ("qwen2_moe", "qwen3_moe"):

@@ -1,6 +1,6 @@
 """Hybrid stacks: layers that keep a recurrent state beside attention
-(``model_type: jamba``: Mamba-1 mixers; ``olmo_hybrid``: a gated delta rule;
-``lfm2_moe``: gated short convolutions, and routed experts in most layers).
+(``model_type: jamba``: Mamba-1 mixers; ``olmo_hybrid``, ``qwen3_next``: a gated delta rule;
+``lfm2_moe``: gated short convolutions; routed experts in most layers of the last two).
 
 Every other family is a stack of identical attention blocks: one stacked
 tree, one ``lax.scan`` (models/llama/batch.batched_blocks_forward), K and V
@@ -61,7 +61,7 @@ from cake_tpu.models.llama.latent import _EXPERT_STACKS, MOE_COUNTS, _add_counts
 from cake_tpu.models.llama.paged_cache import (
     PagedKVCache, init_paged_cache, kv_pack,
 )
-from cake_tpu.obs.taxonomy import CACHE_WRITE, FEED_FORWARD, MIXER, MIXER_IN
+from cake_tpu.obs.taxonomy import CACHE_WRITE, FEED_FORWARD, MIXER, MIXER_IN, MIXER_OUT
 from cake_tpu.ops import delta_rule as D
 from cake_tpu.ops import short_conv as C
 from cake_tpu.ops import ssm as S
@@ -123,7 +123,7 @@ def run_shapes(
     h, inter = config.hidden_size, config.intermediate_size
     if ff == SPARSE:
         e, inter = config.num_local_experts, config.moe_intermediate_size
-        ffn = {"router": (h, config.n_router_experts)}
+        ffn = {"router": (h, config.n_router_experts), **_shared_expert_shapes(config)}
         if config.router_bias:
             ffn["router_bias"] = (config.n_router_experts,)
         ffn.update(w_gate=(e, h, inter), w_up=(e, h, inter), w_down=(e, inter, h))
@@ -139,7 +139,7 @@ def run_shapes(
         qk = {"q_norm": (q,), "k_norm": (kv,)} if config.qk_norm_whole else {}
         if config.qk_norm:  # a norm a head (Qwen3's), its weight the heads share
             qk = {"q_norm": (hd,), "k_norm": (hd,)}
-        return {"wq": (h, q), "wk": (h, kv), "wv": (h, kv), "wo": (q, h), **qk, **ffn}
+        return {"wq": (h, q), "wk": (h, kv), "wv": (h, kv), "wo": (q, h), **qk, **_gate_shapes(config), **ffn}
     if config.state_mixer == SHORT_CONV:
         kept, channels = config.conv_window
         return {
@@ -182,8 +182,8 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> M.Pa
     std = 0.02
 
     def draw(k, name, shape):
-        if name in _ONES:
-            return jnp.ones(shape, dtype)
+        if name in _ONES and not (config.rmsnorm_offset and name != "o_norm"):
+            return jnp.ones(shape, dtype)  # a (1 + w) norm's weight is drawn about 0
         scale = 0.2 if name in _WIDE else std
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
@@ -202,7 +202,7 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> M.Pa
     params = {
         "embed": draw(k_embed, "embed", (v, h)),
         "layers": runs,
-        "ln_f": jnp.ones((h,), dtype),
+        "ln_f": (jnp.zeros if config.rmsnorm_offset else jnp.ones)((h,), dtype),
     }
     if not config.tie_word_embeddings:
         params["lm_head"] = draw(k_head, "lm_head", (h, v))
@@ -379,7 +379,7 @@ def hybrid_blocks_forward(
         # goes back into the carry is the program's cache write.
         with jax.named_scope(MIXER_IN):
             c_old = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
-            h = rms_norm(x, lp["ln_attn"], eps) if "ln_attn" in lp else x
+            h = rms_norm(x, lp["ln_attn"], eps, config.rmsnorm_offset) if "ln_attn" in lp else x
         if in_place:
             gated, ssm, c_l = ops.mixer_step_stacked(
                 lp, h, ssm, li, c_old, live, eps, **of_config
@@ -438,7 +438,7 @@ def hybrid_blocks_forward(
         if kind == ATTENTION:
             if experts is not None:
                 more = dict(
-                    tail=functools.partial(routed, experts=experts),
+                    tail=functools.partial(_gate_first(routed, lp, config), experts=experts),
                     tail_carry=counts,
                 )
             x, kv, *got = batched_blocks_forward(
@@ -595,8 +595,61 @@ def hybrid_join_rows(
             mine[None, None, :, None], jnp.take(new.conv, row, axis=2), cache.conv)
         ssm = cache.ssm
         if ssm is not None:
-            ssm = jnp.where(
-                mine.reshape(1, -1, *(1,) * (ssm.ndim - 2)),
-                jnp.take(new.ssm, row, axis=1), ssm,
-            )
+            ssm = _place_rows(ssm, new.ssm, lanes)
     return logits, HybridCache(kv=new.kv, ssm=ssm, conv=conv), *counts
+
+
+def _shared_expert_shapes(config: LlamaConfig) -> dict[str, tuple[int, ...]]:
+    """A sparse layer's shared expert, where the config has one, in the
+    scanned tree beside the router (``model.block_finish``'s names: computed
+    whole on this chip, under the scope ``shared_expert``), behind its
+    sigmoid gate (``se_gate``: one number a token)."""
+    s = config.shared_expert_intermediate_size
+    if not s:
+        return {}
+    h = config.hidden_size
+    return {"sh_gate": (h, s), "sh_up": (h, s), "sh_down": (s, h), "se_gate": (h, 1)}
+
+
+def _gate_shapes(config: LlamaConfig) -> dict[str, tuple[int, ...]]:
+    """The attention output's gate a NUMBER (``config.attn_gate``
+    "per-number"): ``wg`` as wide as ``wq``, a head at a time."""
+    if config.attn_gate != "per-number":
+        return {}
+    return {"wg": (config.hidden_size, config.num_attention_heads * config.head_dim)}
+
+
+def _gate_first(tail, lp, config: LlamaConfig):
+    """An attention run's ``tail`` (``batched_blocks_forward``'s hook), the
+    attention output gated a number first where the run's tree has the
+    gate's matrix ``wg``: ``attn * sigmoid(h wg)`` before ``wo``, ``h`` the
+    layer's normed input (the norm ``block_qkv`` computed, once in the
+    compiled program). A tree without one gets ``tail`` itself. The parser
+    that gives a model such a gate makes every layer sparse, so every
+    attention run has a tail."""
+    if "wg" not in lp:
+        return tail
+
+    def gated_tail(lp, x, attn, *rest, **kw):
+        with jax.named_scope(MIXER_OUT):
+            h = rms_norm(x, lp["ln_attn"], config.rms_norm_eps, config.rmsnorm_offset)
+            gate = jax.nn.sigmoid(M.qmat(h, lp["wg"]).astype(jnp.float32))
+            attn = (attn * gate.reshape(attn.shape)).astype(attn.dtype)
+        return tail(lp, x, attn, *rest, **kw)
+
+    return gated_tail
+
+
+def _place_rows(ssm: jnp.ndarray, new: jnp.ndarray, lanes: jnp.ndarray) -> jnp.ndarray:
+    """A group's rows' float32 states ``new`` [n_state, R, ...] into their
+    tenants' lanes of ``ssm`` [n_state, lanes, ...], in place and a row at a
+    time: the lane axis is no tiled one here, and a select over the whole
+    array (the window's way above) would copy a matrix state whole (1.2 GB at
+    Qwen3-Next's widths and 64 lanes). A dead row (lane -1) puts back what
+    lane 0 holds."""
+    for r in range(new.shape[1]):
+        lane = jnp.maximum(lanes[r], 0)
+        old = jax.lax.dynamic_slice_in_dim(ssm, lane, 1, axis=1)
+        row = jnp.where(lanes[r] >= 0, new[:, r : r + 1], old)
+        ssm = jax.lax.dynamic_update_slice_in_dim(ssm, row, lane, axis=1)
+    return ssm

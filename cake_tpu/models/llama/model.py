@@ -36,7 +36,7 @@ from cake_tpu.models.llama.cache import (
 )
 from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.obs.taxonomy import (
-    EMBED, FEED_FORWARD, HEAD, MIXER, MIXER_IN, MIXER_OUT,
+    EMBED, FEED_FORWARD, HEAD, MIXER, MIXER_IN, MIXER_OUT, SHARED_EXPERT,
 )
 from cake_tpu.ops.attention import gqa_attention, gqa_attention_hm
 from cake_tpu.ops.fuse import resolve_fusion
@@ -409,17 +409,17 @@ def block_finish(
                 mlp, counts = mlp
             mlp = mlp.astype(x.dtype)
             if "sh_gu" in lp or "sh_gate" in lp:
-                # The always-on shared expert (computed identically on every tp
-                # shard and every rank of an expert-parallel deployment).
-                if "sh_gu" in lp:  # fused gate|up (ops/fuse.py)
-                    shared = swiglu_gu(h, lp["sh_gu"], lp["sh_down"])
-                else:
-                    shared = swiglu(h, lp["sh_gate"], lp["sh_up"], lp["sh_down"])
-                if "se_gate" in lp:
-                    # Qwen2-MoE scales it by a learned sigmoid gate (the product
-                    # distributes over the shared expert's partial sums).
-                    shared = shared * jax.nn.sigmoid(qmat(h, lp["se_gate"]))
-                mlp = mlp + shared.astype(x.dtype)
+                # The always-on shared expert (the same on every tp shard and on
+                # every rank of an expert-parallel deployment), a scope its own.
+                with jax.named_scope(SHARED_EXPERT):
+                    if "sh_gu" in lp:  # fused gate|up (ops/fuse.py)
+                        shared = swiglu_gu(h, lp["sh_gu"], lp["sh_down"])
+                    else:
+                        shared = swiglu(h, lp["sh_gate"], lp["sh_up"], lp["sh_down"])
+                    if "se_gate" in lp:
+                        # Qwen2-MoE's and Qwen3-Next's learned sigmoid gate on it.
+                        shared = shared * jax.nn.sigmoid(qmat(h, lp["se_gate"]))
+                    mlp = mlp + shared.astype(x.dtype)
         elif "w_gu" in lp:  # fused gate|up (ops/fuse.py): one matmul, split after
             mlp = swiglu_gu(
                 h, lp["w_gu"], lp["w_down"], activation=config.hidden_activation

@@ -162,3 +162,7 @@ PROGRAM_PARTS = (
 (
     EMBED, MIXER_IN, CACHE_WRITE, MIXER, MIXER_OUT, FEED_FORWARD, HEAD, SAMPLE,
 ) = PROGRAM_PARTS
+# A scope INSIDE ``feed_forward``: a sparse layer's always-on shared expert
+# (its products and its gate), so that a device trace tells it from the
+# routed experts beside it (``feed_forward/shared_expert``).
+SHARED_EXPERT = "shared_expert"

@@ -173,24 +173,24 @@ def _unit(x: jnp.ndarray) -> jnp.ndarray:
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
 
 
-def _inputs(lp, h, conv, live, ends, neg_eigval):
+def _inputs(lp, h, conv, live, ends, neg_eigval, dk=None):
     """Everything of the mixer before the recurrence: (q, k [b, L, H, dk], v
     [b, L, H, dv], log_alpha, beta [b, L, H], all float32; z [b, L, H dv];
-    the convolution's new window)."""
+    the convolution's new window). ``dk``: the state's (``_to_value_heads``)."""
     with jax.named_scope(MIXER_IN):
         heads = lp["A_log"].shape[-1]
         qkvz = qmat(h, lp["in_proj"])
         width = conv.shape[-1]  # the convolution's channels: q | k | v
         n_v = qkvz.shape[-1] - width  # z is as wide as v
         n_k = (width - n_v) // 2
-        dk, dv = n_k // heads, n_v // heads
+        dk, dv = dk or n_k // heads, n_v // heads
         u_in = jnp.where(live[:, :, None], qkvz[..., :width], 0).astype(h.dtype)
         z = qkvz[..., width:]
         padded = S.with_window(u_in, conv)
         u = jax.nn.silu(S.causal_conv(padded, lp["conv_w"], None))
         b, length = h.shape[:2]
-        q = _unit(u[..., :n_k].reshape(b, length, heads, dk)) * dk ** -0.5
-        k = _unit(u[..., n_k : 2 * n_k].reshape(b, length, heads, dk))
+        q = _to_value_heads(_unit(u[..., :n_k].reshape(b, length, -1, dk)) * dk ** -0.5, heads)
+        k = _to_value_heads(_unit(u[..., n_k : 2 * n_k].reshape(b, length, -1, dk)), heads)
         v = u[..., 2 * n_k :].reshape(b, length, heads, dv)
         # The gates' projection keeps its float32 sums: alpha is an exponential
         # of a, and a rounded to bfloat16 (2^-8 of values up to 10) moves every
@@ -238,7 +238,7 @@ def mixer_forward(
     out-projection with the block's tail), ssm', conv'). A row with no live
     position keeps its state bit for bit."""
     q, k, v, log_alpha, beta, z, new_conv = _inputs(
-        lp, h, conv, live, ends, neg_eigval
+        lp, h, conv, live, ends, neg_eigval, ssm.shape[-2]
     )
     heads = q.shape[2]
     with jax.named_scope(MIXER):
@@ -302,10 +302,22 @@ def mixer_step_stacked(
     out and back), reads and writes that layer's rows once, and leaves the
     others where they are. (gated, the stack, conv')."""
     q, k, v, log_alpha, beta, z, new_conv = _inputs(
-        lp, h, conv, live, None, neg_eigval
+        lp, h, conv, live, None, neg_eigval, ssm.shape[-2]
     )
     with jax.named_scope(MIXER):
         o, ssm = pallas_step.gated_delta_step(
             ssm, layer, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0]
         )
     return _gated(lp, o[:, None], z, eps, h.dtype), ssm, new_conv
+
+
+def _to_value_heads(x: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """q or k [b, L, key heads, dk] as the ``heads`` VALUE heads read it:
+    where a model has fewer key heads than value heads (Qwen3-Next: 16 under
+    32) value head ``j`` reads key head ``j // group`` (HF's
+    ``repeat_interleave``), so the recurrence and both kernels see one q and
+    one k a value head, as they do where the counts are equal (Olmo-Hybrid:
+    nothing is repeated and nothing is traced here). The key heads are read
+    off the convolution's width and the state's ``dk``."""
+    group = heads // x.shape[2]
+    return x if group == 1 else jnp.repeat(x, group, axis=2)

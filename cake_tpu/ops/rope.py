@@ -68,7 +68,9 @@ def rope_table(
 def model_rope_tables(config, max_seq_len: int):
     """THE rope-table builder every runner uses (one call site per backend).
 
-    Single-rope families get the plain [max_seq, hd//2] tables. Dual-rope
+    Single-rope families get the plain [max_seq, hd//2] tables (over the
+    first ``config.rotary_dim`` numbers of a head where the rotary term is
+    partial: ``apply_rope`` passes the rest through). Dual-rope
     families (Gemma-3: ``rope_local_base_freq``) get STACKED [2, max_seq,
     hd//2] tables — plane 0 the global rope (with any rope_scaling), plane 1
     the local rope (unscaled, HF reassigns only the theta) — selected per
@@ -76,7 +78,7 @@ def model_rope_tables(config, max_seq_len: int):
     scanned bodies stay family-agnostic."""
     if getattr(config, "rope_local_base_freq", None) is None:
         return rope_table(
-            config.head_dim, max_seq_len, config.rope_theta, config.rope_scaling
+            config.rotary_dim, max_seq_len, config.rope_theta, config.rope_scaling,
         )
     cos_g, sin_g = rope_table(
         config.head_dim, max_seq_len, config.rope_theta, config.rope_scaling

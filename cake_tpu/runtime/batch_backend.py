@@ -579,8 +579,8 @@ class _PagedBackend:
         layers = len(self.config.layers_of(STATE))
         return {
             "layers": layers,
-            # which mixer the state layers run (``config.state_mixer``)
-            "mixer": self.config.state_mixer if layers else None,
+            # which mixer the state layers run, and a delta rule's head counts
+            "mixer": self.config.state_mixer if layers else None, **_delta_heads(self.config),
             # ``window_form``: the form every window (a prefill, a join) of
             # their recurrence takes; ``step_form``: a decode step's
             # one-token update ("pallas": the state read once and written
@@ -2257,3 +2257,18 @@ def _join_rows(self, kv, tokens, pads, ends, lanes, start=0):
 
 
 _PagedBackend.join_rows = _join_rows
+
+
+def _delta_heads(config) -> dict:
+    """``engine.state``'s ``key_heads`` and ``value_heads``: a gated delta
+    rule's two head counts (value heads read their key heads in groups where
+    the counts differ: ``ops/delta_rule._to_value_heads``); nothing for
+    another mixer. Down here for the reason above."""
+    from cake_tpu.models.llama.config import GATED_DELTA
+
+    if config.state_mixer != GATED_DELTA or not config.has_state_layers:
+        return {}
+    return {
+        "key_heads": config.linear_num_key_heads,
+        "value_heads": config.linear_num_value_heads,
+    }

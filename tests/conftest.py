@@ -92,7 +92,8 @@ def pytest_collection_modifyitems(items):
     for that cell, against its own catalog row, in
     ``tests/zbench/test_bench_jamba.py``, ``test_bench_pangu.py``,
     ``test_bench_olmo_hybrid.py``, ``test_bench_laguna.py``,
-    ``test_bench_deepseek_v32.py`` and ``test_bench_lfm2_moe.py``.
+    ``test_bench_deepseek_v32.py``, ``test_bench_lfm2_moe.py`` and
+    ``test_bench_qwen3_next.py``.
 
     ``tests/zbench/test_bench_architecture.py::
     test_the_addition_changes_no_file_that_was_there`` also holds that a cell
@@ -109,7 +110,8 @@ def pytest_collection_modifyitems(items):
     Laguna's cell in ``test_bench_laguna.py``."""
     other_models = ("jamba2-3b-chat-closed", "pangu-ultra-ep16-chat-closed",
                     "olmo-hybrid-7b-chat-closed", "laguna-s-ep8-code-closed",
-                    "deepseek-v32-ep16-longdoc-closed", "lfm2-8b-a1b-chat-closed")
+                    "deepseek-v32-ep16-longdoc-closed", "lfm2-8b-a1b-chat-closed",
+                    "qwen3-next-ep4-chat-closed")
     for item in items:
         if item.nodeid.endswith(
             tuple(f"test_cell_loads[{cell}]" for cell in other_models)

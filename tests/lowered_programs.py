@@ -51,7 +51,7 @@ def families() -> dict:
         # (the families the recording holds: a later family's programs have
         # no parent text to be held to)
         **{"pangu" if name == "latent_moe" else name: (config, GEOMETRY)
-           for name, config in FAMILIES.items() if name != "lfm2_moe"},
+           for name, config in FAMILIES.items() if name not in ("lfm2_moe", "qwen3_next")},
         # the windowed pool as the first recording held it (12 pages; a
         # server's would be 9 at 3 lanes)
         "laguna": (LlamaConfig.from_hf_dict(HF), {**GEOMETRY, "n_pages": (32, 12)}),

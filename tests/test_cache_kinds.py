@@ -47,12 +47,14 @@ NESTED = {
     ("cache", "kinds"): {"full", "sliding"},
 }
 POOL = {"window", "pages_total", "pages_mapped", "bytes_per_page", "freed_behind_window"}
+DELTA_HEADS = {"key_heads", "value_heads"}
 # family -> section -> its keys on the parent (None: the backend has no such
 # method, and ``runtime/api.py`` reports no such section)
 PARENT_FACTS = {
     "dense": {"cache": CACHE, "state": STATE, "moe": None, "sparse": None},
     "jamba": {"cache": CACHE, "state": STATE, "moe": None, "sparse": None},
-    "olmo_hybrid": {"cache": CACHE, "state": STATE, "moe": None, "sparse": None},
+    # (PR 53: a delta rule's two head counts beside ``mixer``)
+    "olmo_hybrid": {"cache": CACHE, "state": STATE | DELTA_HEADS, "moe": None, "sparse": None},
     "latent_moe": {"cache": CACHE, "state": STATE, "moe": MOE, "sparse": None},
     "latent_index": {"cache": CACHE | {"bytes_per_token_by"}, "state": STATE,
                      "moe": MOE, "sparse": SPARSE},
@@ -61,6 +63,8 @@ PARENT_FACTS = {
     # PR 48's own (no parent had it): the hybrids' sections and, since the
     # model has sparse layers, Pangu's expert account
     "lfm2_moe": {"cache": CACHE, "state": STATE, "moe": MOE, "sparse": None},
+    # PR 53's own: grouped delta-rule heads beside a share of routed experts
+    "qwen3_next": {"cache": CACHE, "state": STATE | DELTA_HEADS, "moe": MOE, "sparse": None},
 }
 
 

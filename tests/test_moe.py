@@ -845,14 +845,16 @@ _RULE = {
 
 def test_the_cells_dispatches_are_all_there():
     families = {name.split(".")[0] for name in _CELLS}
-    assert families == {"lfm2_moe", "pangu_ultra_moe", "laguna", "deepseek_v32"}
+    assert families == {"lfm2_moe", "pangu_ultra_moe", "laguna", "deepseek_v32", "qwen3_next"}
     assert [n for n, c in _CELLS.items() if c[-1] == "dense"] == ["lfm2_moe.decode.64"]
     # the narrowest and the widest join of each, and Pangu's 128-slot one
     for name in ("lfm2_moe.join.256", "lfm2_moe.join.4096", "pangu_ultra_moe.join.64",
                  "pangu_ultra_moe.join.128", "pangu_ultra_moe.join.4096",
                  "pangu_ultra_moe.decode.64", "laguna.decode.32", "laguna.join.1536",
                  "laguna.join.24576", "deepseek_v32.decode.16", "deepseek_v32.join.2688",
-                 "deepseek_v32.join.21504"):
+                 "deepseek_v32.join.21504",
+                 # 64 rows that choose 10 of 512 leave 28% untouched: GROUPED (PR 53)
+                 "qwen3_next.decode.64", "qwen3_next.join.256", "qwen3_next.join.4096"):
         assert name in _CELLS, sorted(_CELLS)
 
 

@@ -6,7 +6,9 @@
 trace's ``op_name`` metadata (``bench/parts.py``). Here the served programs
 of the six families (dense GQA, a Jamba-like and an Olmo-Hybrid-like hybrid,
 latent attention with sparse experts, the same behind a learned index, an
-LFM2-like hybrid of short convolutions with routed experts) are LOWERED at a tiny size, never run:
+LFM2-like hybrid of short convolutions with routed experts, a Qwen3-Next-like
+hybrid of grouped delta-rule heads, gated attention and a share of routed experts
+beside a gated shared one) are LOWERED at a tiny size, never run:
 the operations' names are read from the lowered module, and the same
 programs lower to the same text, locations aside, with the scopes taken
 away: a scope is metadata and nothing else.
@@ -14,6 +16,7 @@ away: a scope is metadata and nothing else.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import functools
@@ -36,6 +39,7 @@ from test_hybrid_olmo import HF as OLMO_HYBRID
 from test_deepseek_v32 import TINY as LATENT_INDEX
 from test_latent_pangu import SHARE as LATENT_MOE
 from test_lfm2_moe import HF as LFM2_MOE
+from test_qwen3_next import HF as QWEN3_NEXT
 
 FAMILIES = {
     "dense": LlamaConfig.tiny(),
@@ -44,6 +48,7 @@ FAMILIES = {
     "latent_moe": LlamaConfig.from_hf_dict(LATENT_MOE),
     "latent_index": LlamaConfig.from_hf_dict(LATENT_INDEX),
     "lfm2_moe": LlamaConfig.from_hf_dict(LFM2_MOE),
+    "qwen3_next": LlamaConfig.from_hf_dict(QWEN3_NEXT),
 }
 PROGRAMS = ("decode", "join", "prefill")
 # A tiny server's shapes: lanes, pages of 16 slots, a table of 8 pages a
@@ -81,6 +86,8 @@ HOLDS = {
     **{("latent_index", p): {experts, "index_scores", "index_select", "sparse_attention"}
        for p, experts in _EXPERTS.items()},
     **{("lfm2_moe", p): {experts, "short_conv"} for p, experts in _EXPERTS.items()},
+    **{("qwen3_next", p): {experts, "gated_delta_step" if p == "decode" else "gated_delta_rule"}
+       for p, experts in _EXPERTS.items()},
 }
 WEIGHTY = ("dot_general", "convolution", "custom_call", "scatter")
 
@@ -189,6 +196,44 @@ def test_the_short_convolutions_programs_leave_no_more_unscoped_than_jambas():
     updates = [parts_of(name) for kind, name in names if kind.endswith("dynamic_update_slice")]
     # (the one under no part is the chunk's own: a step's tokens into the scan's output)
     assert ["cache_write"] in updates and all(p in ([], ["cache_write"]) for p in updates)
+
+
+def test_qwen3_nexts_programs_leave_nothing_new_unscoped():
+    """What the model adds sits under a part: the head groups' repeat of q and
+    k under ``mixer_in``, the attention output's gate (a product with ``wg``
+    and a sigmoid) under ``mixer_out``, the shared expert's three products and
+    its gate's under ``feed_forward/shared_expert`` and nowhere else."""
+    from cake_tpu.obs.taxonomy import MIXER_OUT, SHARED_EXPERT
+
+    def plumbing(family, program):
+        """Unscoped operations by kind, the scan's own read of a layer's leaf
+        aside (a ``dynamic_slice`` and a ``squeeze`` a leaf a loop: they follow
+        the tree's size, which is the model's, and take no time on a device)."""
+        kinds = collections.Counter(unscoped(family, program))
+        return kinds - collections.Counter(
+            {"stablehlo.dynamic_slice": 10**6, "stablehlo.reshape": 10**6})
+
+    for program in PROGRAMS:
+        # what is left is the loops' and the expert account's plumbing: no more
+        # of any kind than the hybrid that has sparse runs already (LFM2-like,
+        # more runs), and beyond Olmo-Hybrid's only what that account brings
+        ours, lfm2, olmo = (plumbing(f, program) for f in ("qwen3_next", "lfm2_moe", "olmo_hybrid"))
+        assert not ours - lfm2, (program, ours - lfm2)
+        assert set(ours - olmo) <= set(lfm2 - olmo), program
+    names = operation_names(lowered("qwen3_next", "decode").compiler_ir())
+    shared = [(kind, name) for kind, name in names if SHARED_EXPERT in name.split("/")]
+    assert {tuple(parts_of(name)) for _, name in shared} == {(FEED_FORWARD,)}
+    assert all(name.split("/").index(FEED_FORWARD) < name.split("/").index(SHARED_EXPERT)
+               for _, name in shared)
+    # its gate, up and down products, in the body of each of the four runs
+    assert sum(kind.endswith("dot_general") for kind, _ in shared) == 3 * 4
+    # ``wo`` in each of the four runs' bodies, the gate's ``wg`` beside it in
+    # the two attention runs': the gate is ``mixer_out``'s
+    out = [kind for kind, name in names if parts_of(name) == [MIXER_OUT]]
+    assert sum(kind.endswith("dot_general") for kind in out) == 4 + 2
+    # Laguna's and Pangu's shared experts (no gate) take the scope too
+    names = operation_names(lowered("latent_moe", "decode").compiler_ir())
+    assert [n for _, n in names if SHARED_EXPERT in n.split("/")]
 
 
 def test_the_in_place_step_sits_inside_mixer():
