@@ -324,7 +324,7 @@ def hybrid_blocks_forward(
     lane: jnp.ndarray | None = None,
     cached_chunk: bool = False,
     write_starts: jnp.ndarray | None = None,
-    allow_pallas: bool = True,
+    allow_pallas: bool = True, live_rows: jnp.ndarray | None = None,  # a decode dispatch's ``_stepped_rows``
 ):
     """The model's layers in order, run by run: (x, cache), and where the
     model has a sparse layer a third value, the pass's account of them
@@ -346,8 +346,8 @@ def hybrid_blocks_forward(
     mixer = functools.partial(
         ops.mixer_forward, eps=eps, allow_pallas=use_pallas, **of_config
     )
-    # A decode step updates the carry's state in place through the mixer's
-    # Pallas kernel, which takes the stack whole.
+    # A decode step updates the carry's state in place through the mixer's Pallas kernel, which takes the stack whole
+    stepped = {} if live_rows is None else {"rows": live_rows}  # and, the delta rule's, ``_stepped_rows``
     in_place = (
         lane is None and x.shape[1] == 1
         and _steps_in_place(config, ssm, use_pallas)
@@ -382,7 +382,7 @@ def hybrid_blocks_forward(
             h = rms_norm(x, lp["ln_attn"], eps, config.rmsnorm_offset) if "ln_attn" in lp else x
         if in_place:
             gated, ssm, c_l = ops.mixer_step_stacked(
-                lp, h, ssm, li, c_old, live, eps, **of_config
+                lp, h, ssm, li, c_old, live, eps, **of_config, **stepped
             )
         elif ssm is None:
             # The window is all the state there is: a new tenant's starts
@@ -528,7 +528,7 @@ def hybrid_forward_one(
     cache (``programs.decode_program`` scans it); the step's account of its
     sparse layers rides back beside it where the model has one."""
     from cake_tpu.models.llama.batch import decode_positions
-
+    rows = _stepped_rows(config, live, allow_pallas)  # once a dispatch
     fusion = resolve_fusion(config, allow_pallas)
 
     def forward_one(tok, cache, slot):
@@ -538,7 +538,7 @@ def hybrid_forward_one(
             params["layers"], x, cache, q_pos, k_pos, config,
             decode=True, pads=pads, lengths=lengths, write_pos=slot,
             block_tables=block_tables, live=live, ends=None,
-            allow_pallas=allow_pallas,
+            allow_pallas=allow_pallas, live_rows=rows,
         )
         logits = M.head_forward(params, x, jnp.int32(1), config, fusion=fusion)
         return logits, cache, *counts
@@ -653,3 +653,22 @@ def _place_rows(ssm: jnp.ndarray, new: jnp.ndarray, lanes: jnp.ndarray) -> jnp.n
         row = jnp.where(lanes[r] >= 0, new[:, r : r + 1], old)
         ssm = jax.lax.dynamic_update_slice_in_dim(ssm, row, lane, axis=1)
     return ssm
+
+
+def steps_live_rows(config: LlamaConfig, allow_pallas: bool) -> bool:
+    """Whether a decode step's one-token update walks the dispatch's live
+    lanes ALONE (a dead lane's state neither read nor written): the delta
+    rule's kernel does (``ops/pallas/delta_step.py``), no other form
+    (``batch_backend._count_stepped`` has the list)."""
+    return config.state_mixer == GATED_DELTA and step_form(config, allow_pallas) == "pallas"
+
+
+def _stepped_rows(config: LlamaConfig, live: jnp.ndarray, allow_pallas: bool):
+    """A decode dispatch's live lanes as that kernel walks them
+    (``delta_step.live_rows``), from ``live`` [b, 1]: made here ONCE a
+    dispatch, outside the step scan and the runs' layer scans, for every
+    state layer's call; None where ``steps_live_rows`` does not hold."""
+    if not steps_live_rows(config, allow_pallas):
+        return None
+    with jax.named_scope(MIXER_IN):  # the kernel's operand, like q and k
+        return D.live_rows(live[:, 0])

@@ -37,8 +37,8 @@ else (no flag, field or environment variable chooses), as ``ops/ssm.py``:
   * ``L == 1`` (every decode step): the one-token update,
     ``gated_delta_step``. With ``allow_pallas`` and widths that tile, on the
     layer stack's own state in place (``mixer_step_stacked``: the Pallas
-    kernel ``ops/pallas/delta_step.py``, one read and one write of ``S``,
-    the operation ``gated_delta_step`` of a device trace);
+    kernel ``ops/pallas/delta_step.py``, one read and one write of a LIVE
+    row's ``S``, the operation ``gated_delta_step`` of a device trace);
     else the XLA form below.
   * ``L > 1``: the chunkwise (WY / UT transform) form under the scope
     ``gated_delta_rule``: within a chunk of ``CHUNK`` = 64 positions every
@@ -295,20 +295,25 @@ def mixer_step_stacked(
     live: jnp.ndarray,  # [b, 1] bool
     eps: float,
     neg_eigval: bool = True,
+    rows: jnp.ndarray | None = None,  # ``live_rows(live[:, 0])``, made once a dispatch
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``mixer_forward`` for ``L == 1`` over the STACK's state in place: the
-    kernel is handed the whole array and the layer's index (a kernel's
-    operand is a whole array; a slice of the scan's carry would be copied
-    out and back), reads and writes that layer's rows once, and leaves the
-    others where they are. (gated, the stack, conv')."""
+    kernel is handed the whole array, the layer's index and the live rows
+    (a kernel's operand is a whole array; a slice of the scan's carry would
+    be copied out and back), reads and writes that layer's LIVE rows once,
+    and leaves every other row where it is. (gated, the stack, conv')."""
     q, k, v, log_alpha, beta, z, new_conv = _inputs(
         lp, h, conv, live, None, neg_eigval, ssm.shape[-2]
     )
     with jax.named_scope(MIXER):
         o, ssm = pallas_step.gated_delta_step(
-            ssm, layer, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0]
+            ssm, layer, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0],
+            live[:, 0], rows=rows,
         )
     return _gated(lp, o[:, None], z, eps, h.dtype), ssm, new_conv
+
+
+live_rows = pallas_step.live_rows
 
 
 def _to_value_heads(x: jnp.ndarray, heads: int) -> jnp.ndarray:

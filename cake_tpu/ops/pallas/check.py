@@ -1080,6 +1080,7 @@ def timed_delta_rule(
     lanes: int = 32,
     calls: int = 12,
     repeats: int = 3,
+    step_live: tuple[int, ...] = (20, 11),
 ) -> list[dict]:
     """The gated delta rule alone, ``calls`` dependent calls a program (a
     model's state layers: each call's state and output feed the next), timed
@@ -1093,8 +1094,8 @@ def timed_delta_rule(
     against the rule one position at a time, errors over the largest value
     of the stepwise form's, and microseconds a call (the stepwise form's up
     to 512 positions only: at 2048 it is most of the phase's minutes).
-    Then the one-token update at ``lanes`` rows: the Pallas kernel on a
-    stack's state in place (where the widths tile) beside its XLA twin."""
+    Then the one-token update at ``lanes`` rows with all and with
+    ``step_live`` of them live (``timed_delta_step``)."""
     from cake_tpu.ops import delta_rule as D
     from cake_tpu.ops.pallas import delta_rule, delta_step
 
@@ -1165,45 +1166,98 @@ def timed_delta_rule(
             rec[f"{name}_us"] = round(fastest / calls * 1e6, 1)
         rows.append(rec)
 
-    # the one-token update: ``calls`` layers of one stack, each stepped once
-    q, k, v, log_alpha, beta = (
-        x[:, 0] for x in draw(jax.random.PRNGKey(7), lanes, 1)[:5])
-    stack = jax.random.normal(
-        jax.random.PRNGKey(8), (calls, lanes, dk, heads * dv), jnp.float32)
+    rows += timed_delta_step(heads, dk, dv, lanes, step_live, calls, repeats)
+    return rows
 
-    def twin_chain(stack, q, k, v, log_alpha, beta):
+
+def timed_delta_step(
+    heads: int,
+    dk: int,
+    dv: int,
+    lanes: int = 32,
+    live: tuple[int, ...] = (),
+    calls: int = 12,
+    repeats: int = 3,
+    steps: int = 8,
+) -> list[dict]:
+    """The gated delta rule's one-token update alone at ``lanes`` rows:
+    ``calls`` layers of one stack, each stepped ``steps`` times a program (a
+    decode chunk's state layers), timed as ``timed_delta_rule``'s, once with every
+    row live and once a count of ``live`` (those rows drawn from a seed;
+    below ``lanes`` the first and the last row are dead). The Pallas kernel
+    on the stack's state in place (``ops/pallas/delta_step.py``, where the
+    widths tile: it walks the live rows alone, so its time follows the
+    count) beside its XLA twin (every row, through gates that make a dead
+    one the identity): errors over the twin's largest value ON THE LIVE
+    ROWS, ``dead_rows_moved`` (dead rows of any layer whose state is not the
+    input's bit for bit after the kernel's chain, or whose ``o`` is not
+    zero: must be 0), and microseconds a call, the fresh stack ready before
+    the clock starts."""
+    from cake_tpu.ops import delta_rule as D
+    from cake_tpu.ops.pallas import delta_step
+
+    keys = jax.random.split(jax.random.PRNGKey(7), 6)
+    n = lambda k, *shape: jax.random.normal(k, shape, jnp.float32)
+    q = D._unit(n(keys[0], lanes, heads, dk)) * dk ** -0.5
+    k = D._unit(n(keys[1], lanes, heads, dk))
+    v = n(keys[2], lanes, heads, dv)
+    log_alpha = -jax.nn.softplus(n(keys[3], lanes, heads))
+    beta = 2.0 * jax.nn.sigmoid(n(keys[4], lanes, heads))
+    stack = n(keys[5], calls, lanes, dk, heads * dv)
+
+    def twin_chain(stack, q, k, v, log_alpha, beta, mask):
+        log_alpha = jnp.where(mask[:, None], log_alpha, 0.0)
+        beta = jnp.where(mask[:, None], beta, 0.0)
+
         def one(i, carry):
             stack, v = carry
             o, s = D.gated_delta_step(
-                q, k, v, log_alpha, beta, D.to_heads(stack[i], heads))
-            return stack.at[i].set(D.from_heads(s)), v + 1e-3 * o
+                q, k, v, log_alpha, beta, D.to_heads(stack[i % calls], heads))
+            return stack.at[i % calls].set(D.from_heads(s)), v + 1e-3 * o
 
-        return jax.lax.fori_loop(0, calls, one, (stack, v))
+        return jax.lax.fori_loop(0, calls * steps, one, (stack, v))
 
-    def kernel_chain(stack, q, k, v, log_alpha, beta):
+    def kernel_chain(stack, q, k, v, log_alpha, beta, mask):
+        rows = delta_step.live_rows(mask)  # once a program, as a decode chunk's
+
         def one(i, carry):
-            stack, v = carry
-            o, stack = delta_step.gated_delta_step(stack, i, q, k, v, log_alpha, beta)
-            return stack, v + 1e-3 * o
+            stack, v, dead_o = carry
+            o, stack = delta_step.gated_delta_step(
+                stack, i % calls, q, k, v, log_alpha, beta, mask, rows=rows)
+            dead_o = dead_o | jnp.any((o != 0) & ~mask[:, None, None])
+            return stack, v + 1e-3 * o, dead_o
 
-        return jax.lax.fori_loop(0, calls, one, (stack, v))
+        return jax.lax.fori_loop(0, calls * steps, one, (stack, v, jnp.bool_(False)))
 
-    rec = {"op": "gated_delta_step", "rows": lanes, "length": 1}
     chains = {"twin": jax.jit(twin_chain, donate_argnums=0)}
     if delta_step.tiles(dk, heads * dv, dv):
         chains["kernel"] = jax.jit(kernel_chain, donate_argnums=0)
-    outs = {}
-    for name, stepped in chains.items():
-        outs[name] = jax.tree.map(np.asarray, stepped(stack + 0.0, q, k, v, log_alpha, beta))
-        fastest = min(
-            _timed(stepped, stack + 0.0, q, k, v, log_alpha, beta)[1]
-            for _ in range(repeats))
-        rec[f"{name}_us"] = round(fastest / calls * 1e6, 1)
-    if "kernel" in outs:
-        rec["err_s"] = _rel_err(outs["kernel"][0], outs["twin"][0])
-        rec["err_o"] = _rel_err(outs["kernel"][1], outs["twin"][1])
-    rows.append(rec)
-    return rows
+    recs = []
+    for count in dict.fromkeys((lanes, *(c for c in live if 0 < c <= lanes - 2))):
+        mask = np.zeros((lanes,), bool)
+        inner = np.arange(lanes) if count == lanes else 1 + np.random.default_rng(
+            count).permutation(lanes - 2)[:count]
+        mask[inner] = True
+        at = jnp.asarray(mask)
+        rec = {"op": "gated_delta_step", "rows": lanes, "length": 1, "live": count}
+        outs = {}
+        fresh = lambda: jax.block_until_ready(stack + 0.0)  # the chain donates it
+        for name, stepped in chains.items():
+            outs[name] = jax.tree.map(
+                np.asarray, stepped(fresh(), q, k, v, log_alpha, beta, at))
+            fastest = min(
+                _timed(stepped, fresh(), q, k, v, log_alpha, beta, at)[1]
+                for _ in range(repeats))
+            rec[f"{name}_us"] = round(fastest / (calls * steps) * 1e6, 1)
+        if "kernel" in outs:
+            (s_k, o_k, dead_o), (s_t, o_t) = outs["kernel"], outs["twin"]
+            rec["err_s"] = _rel_err(s_k[:, mask], s_t[:, mask])
+            rec["err_o"] = _rel_err(o_k[mask], o_t[mask])
+            was = np.asarray(stack)[:, ~mask]
+            moved = int((s_k[:, ~mask] != was).any(axis=(2, 3)).sum())
+            rec["dead_rows_moved"] = moved + int(dead_o)
+        recs.append(rec)
+    return recs
 
 
 def timed_matmul_chain(n: int, steps: int, repeats: int = 3) -> dict:

@@ -389,7 +389,7 @@ class _PagedBackend:
         # (``GET /stats`` engine.state.lane_writes).
         self.state_lane_writes = 0
         self.state_decode_dispatches = 0
-        self.state_decode_rows = 0
+        self.state_decode_rows = self.state_decode_lanes = 0
         self._state_lanes = 0
         self._fusions = resolve_fusion(config, allow_pallas)[0]
         # What the programs return beside the tokens (``take_chunk_counters``),
@@ -583,15 +583,15 @@ class _PagedBackend:
             "mixer": self.config.state_mixer if layers else None, **_delta_heads(self.config),
             # ``window_form``: the form every window (a prefill, a join) of
             # their recurrence takes; ``step_form``: a decode step's
-            # one-token update ("pallas": the state read once and written
-            # once, in place)
+            # one-token update ("pallas": a stepped row's state read once and
+            # written once, in place; the delta rule's passes over dead rows)
             **self._forms("state"),
             "bytes_per_lane": per_lane,
             "bytes": per_lane * self._state_lanes,
             "lane_writes": self.state_lane_writes,
-            # decode chunks enqueued and the rows they stepped together
-            # (every row of a dispatch is stepped, live or not): cumulative
-            "decode_dispatches": self.state_decode_dispatches,
+            # decode chunks enqueued, the rows they STEPPED together and the
+            # lanes they were wide (``_count_stepped`` says which mixer steps what)
+            "decode_dispatches": self.state_decode_dispatches, "decode_lanes": self.state_decode_lanes,
             "decode_rows": self.state_decode_rows,
         }
 
@@ -751,14 +751,14 @@ class _PagedBackend:
             # steps the dead row's state, which nobody reads and a joiner
             # starts from zero) and maps a joiner's before its prefill, and
             # spare lanes never hold any. The same fact that drops a dead
-            # lane's K/V writes keeps its state and its token out of the
-            # experts' rows.
+            # lane's K/V writes keeps its state (the delta rule's step does not
+            # even read it) and its token out of the experts' rows.
             b = int(jnp.shape(tok)[0])
             lanes = (jnp.asarray(
-                (self.allocator.block_tables[:b] >= 0).any(axis=1)),)
+                live := (self.allocator.block_tables[:b] >= 0).any(axis=1)),)
             if self.kind.lane_state is not None:
                 self.state_decode_dispatches += 1
-                self.state_decode_rows += b
+                _count_stepped(self, b, int(live.sum()))
         out = fn(
             self.params, kv, tok, jnp.int32(slot), pads, self._tables(),
             *lanes, keys, ring, ring_idx,
@@ -852,7 +852,7 @@ class _PagedBackend:
         self.set_epoch_capacity(None)
         self.allocator.reset(batch=1)
         self.state_lane_writes = 0
-        self.state_decode_dispatches = self.state_decode_rows = 0
+        self.state_decode_dispatches = self.state_decode_rows = self.state_decode_lanes = 0
         return {
             "programs": len(programs),
             "seconds": round(time.perf_counter() - t0, 3),
@@ -2272,3 +2272,20 @@ def _delta_heads(config) -> dict:
         "key_heads": config.linear_num_key_heads,
         "value_heads": config.linear_num_value_heads,
     }
+
+
+def _count_stepped(self, lanes: int, live: int) -> None:
+    """One decode dispatch into ``engine.state``'s ``decode_lanes`` (the rows
+    it is wide) and ``decode_rows`` (the rows whose state its one-token
+    update READS AND WRITES, every step and state layer alike). Which mixer
+    counts what: the gated delta rule's kernel walks the live rows alone
+    (``ops/pallas/delta_step.py``), so it counts ``live``; Jamba's
+    ``selective_step`` takes eight rows a block and steps them all, the XLA
+    twins step every row through gates that make a dead one the identity,
+    and a short convolution's window shifts for every row: they count
+    ``lanes``. ``1 - decode_rows / decode_lanes`` is the share passed over.
+    Down here for the reason above."""
+    from cake_tpu.models.llama.hybrid import steps_live_rows
+
+    self.state_decode_lanes += lanes
+    self.state_decode_rows += live if steps_live_rows(self.config, self.allow_pallas) else lanes

@@ -223,7 +223,7 @@ PRESETS = {
             prompt=70, pages=64, lanes=4, table_pages=2, steps=4,
             join_width=64, epoch=(2, 128, 2),
             timed_delta=dict(windows=((1, 70), (2, 200, 70, 130)), lanes=4,
-                             calls=2, repeats=1),
+                             calls=2, repeats=1, step_live=(2, 1)),
         ),
         latent=dict(
             model=dict(
@@ -1568,6 +1568,11 @@ def phase_olmo(args, preset) -> dict:
             problems.append(
                 f"{r['op']} {r['rows']} x {r['length']} differs from the "
                 "stepwise rule")
+        # the step's kernel passes over a dead row: state as it was, o zero
+        if r.get("dead_rows_moved"):
+            problems.append(
+                f"{r['op']} {r['rows']} rows, {r['live']} live: "
+                f"{r['dead_rows_moved']} dead rows did not keep their state")
     out = {"cases": sum(r["kind"] == "case" for r in records)}
     # The convolution's window (bf16, 26 MB) changes layout at a decode
     # program's two ends; the float32 state must not be copied at all.

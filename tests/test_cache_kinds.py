@@ -33,7 +33,7 @@ PLAIN_ONLY = ("suffix_prefill", "suffix_join", "cow_copy", "verify_greedy",
 CACHE = {"kind", "bytes_per_token", "bytes_per_token_needed", "page_size", "pages",
          "bytes", "pool_write"}
 STATE = {"layers", "mixer", "window_form", "step_form", "bytes_per_lane", "bytes",
-         "lane_writes", "decode_dispatches", "decode_rows"}
+         "lane_writes", "decode_dispatches", "decode_rows", "decode_lanes"}
 # (``dense_dispatches``: PR 50's, the one key a later PR added to a section)
 MOE = {"dispatches", "dense_dispatches", "routed", "held", "touched", "max_load",
        "experts_held", "experts_ranked", "first_held", "top_k", "join"}

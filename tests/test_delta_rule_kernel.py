@@ -274,7 +274,7 @@ def test_a_jamba_shaped_state_is_unchanged_but_for_the_key(d_state, form):
     assert facts == {
         "layers": 6, "mixer": config.state_mixer,
         "bytes_per_lane": config.state_bytes_per_lane, "bytes": 0,
-        "lane_writes": 0, "decode_dispatches": 0, "decode_rows": 0,
+        "lane_writes": 0, "decode_dispatches": 0, "decode_rows": 0, "decode_lanes": 0,
     }
 
 

@@ -196,13 +196,194 @@ def test_the_mixer_takes_the_kernel_where_the_widths_tile():
     assert D.steps_in_place(stack, 3)
     gated, out, conv = D.mixer_step_stacked(lp, h, stack, jnp.int32(1), window, live, 1e-6)
     want, s, want_conv = D.mixer_forward(lp, h, stack[1], window, live, None, 1e-6)
-    np.testing.assert_allclose(gated, want, rtol=0, atol=2e-6)
+    at = np.asarray(live[:, 0])
+    np.testing.assert_allclose(np.asarray(gated)[at], np.asarray(want)[at], rtol=0, atol=2e-6)
     np.testing.assert_allclose(out[1], s, rtol=0, atol=2e-6)
     np.testing.assert_array_equal(conv, want_conv)
     np.testing.assert_array_equal(out[1, 2], stack[1, 2])  # the dead lane
+    # its ``o`` is zero where the twin's is S q of a state it read: nobody's either way
+    assert not np.asarray(gated)[2].any() and np.asarray(want)[2].any()
     np.testing.assert_array_equal(conv[:, 2], window[:, 2])
     tiny = H.init_hybrid_cache(LlamaConfig.from_hf_dict(HF), 2, 4, PAGE, jnp.float32)
     assert not D.steps_in_place(tiny.ssm, 3)
+
+
+# The two cells' head shapes cut small: Olmo-Hybrid's dk 96 / dv 192 (a
+# group is two heads: 384 lanes), and Qwen3-Next's dk 128 / dv 128 with 16
+# key heads under 32 value heads. Two blocks of columns a row in both.
+STEP_SHAPES = {
+    "olmo": dict(heads=4, key_heads=4, dk=96, dv=192, w_block=384),
+    "qwen3next": dict(heads=32, key_heads=16, dk=128, dv=128, w_block=2048),
+}
+LIVE_MASKS = {
+    "all_live": (1, 1, 1, 1, 1, 1), "alternating": (1, 0, 1, 0, 1, 0),
+    "first_and_last_dead": (0, 1, 1, 1, 1, 0), "one_live": (0, 0, 0, 1, 0, 0),
+    "none_live": (0, 0, 0, 0, 0, 0),
+}
+
+
+def step_inputs(seed, b, heads, key_heads, dk, dv):
+    """One position's q, k (a key head read by its group of value heads, as
+    ``D._to_value_heads`` hands them to the kernels), v and gates."""
+    q, k, _, _, _, _ = delta_inputs(seed, b, 1, key_heads, dk, dv)
+    _, _, v, log_alpha, beta, _ = delta_inputs(seed + 1, b, 1, heads, dk, dv)
+    q, k = D._to_value_heads(q, heads), D._to_value_heads(k, heads)
+    return tuple(x[:, 0] for x in (q, k, v, log_alpha, beta))
+
+
+@pytest.mark.parametrize("mask", LIVE_MASKS)
+@pytest.mark.parametrize("shape", STEP_SHAPES)
+def test_the_step_kernel_walks_the_live_rows_alone(shape, mask):
+    """Layer 1 of a stack of 3, six rows under a mask: a live row's ``o``
+    and state are the twin's to float32 rounding; a dead row's state is the
+    input's BIT FOR BIT (it is not read: gates that are NOT the identity ride
+    in for it here, and the twin is given the identity), its ``o`` exactly
+    zero; the other layers untouched. With no row live the one row the grid
+    still walks goes back as it came."""
+    widths = dict(STEP_SHAPES[shape])
+    w_block, heads = widths.pop("w_block"), widths["heads"]
+    live = jnp.asarray(LIVE_MASKS[mask], bool)
+    q, k, v, log_alpha, beta = step_inputs(11, 6, **widths)
+    stack = jnp.asarray(
+        np.random.default_rng(3).normal(size=(3, 6, widths["dk"], heads * widths["dv"])),
+        jnp.float32)
+    stack = stack.at[1, 5].multiply(-0.0)  # zeros of both signs in a row: a copy keeps them
+    o, out = delta_step.gated_delta_step(
+        stack, jnp.int32(1), q, k, v, log_alpha, beta, live, w_block=w_block)
+    want_o, want_s = D.gated_delta_step(
+        q, k, v, jnp.where(live[:, None], log_alpha, 0.0), jnp.where(live[:, None], beta, 0.0),
+        D.to_heads(stack[1], heads))
+    at, dead = np.asarray(live), ~np.asarray(live)
+    np.testing.assert_allclose(np.asarray(o)[at], np.asarray(want_o)[at], rtol=0, atol=2e-6)
+    np.testing.assert_allclose(
+        np.asarray(out[1])[at], np.asarray(D.from_heads(want_s))[at], rtol=0, atol=2e-6)
+    assert np.asarray(out[1])[dead].tobytes() == np.asarray(stack[1])[dead].tobytes()
+    if mask != "none_live":  # (the twin's -0.0 + 0.0 is +0.0: the kernel's copy is stricter)
+        np.testing.assert_array_equal(np.asarray(D.from_heads(want_s))[dead][:-1], np.asarray(stack[1])[dead][:-1])
+    assert not np.asarray(o)[dead].any()
+    np.testing.assert_array_equal(out[0], stack[0])
+    np.testing.assert_array_equal(out[2], stack[2])
+    if at.any():
+        assert np.abs(np.asarray(out[1] - stack[1])[at]).max() > 0.1
+
+
+def test_live_rows_lists_the_live_rows_first_and_counts_them():
+    for mask in LIVE_MASKS.values():
+        rows = np.asarray(delta_step.live_rows(jnp.asarray(mask, bool)))
+        n = sum(mask)
+        assert rows[-1] == n and sorted(rows[:-1]) == list(range(6))
+        assert list(rows[:n]) == [r for r in range(6) if mask[r]]
+        assert list(rows[n:-1]) == [r for r in range(6) if not mask[r]]
+
+
+def test_eight_steps_in_a_scan_under_one_mask_equal_the_twins():
+    """The served shape: a decode chunk's eight steps of a state layer
+    through ``mixer_step_stacked`` inside a ``lax.scan``, the live rows made
+    ONCE outside it, against the XLA form stepped the same way. Dead rows
+    (the first and the last among them) hold their state and window bit for
+    bit after all eight, and their ``gated`` is finite (a norm of zeros)."""
+    hf = {**HF, "linear_value_head_dim": 128}
+    config = LlamaConfig.from_hf_dict(hf)
+    params = H.init_params(config, jax.random.PRNGKey(1), jnp.float32)
+    lp = jax.tree.map(lambda a: a[1], params["layers"][0])
+    rng = np.random.default_rng(9)
+    stack = jnp.asarray(rng.normal(size=(2, 5, *config.state_shape)), jnp.float32)
+    window = jnp.asarray(rng.normal(size=(3, 5, config.conv_window[1])), jnp.float32)
+    hs = jnp.asarray(rng.normal(size=(8, 5, 1, 64)), jnp.float32)
+    live = jnp.asarray([[False], [True], [False], [True], [False]])
+
+    @jax.jit
+    def kernel(stack, window):
+        rows = D.live_rows(live[:, 0])
+
+        def step(carry, h):
+            gated, stack, window = D.mixer_step_stacked(
+                lp, h, carry[0], jnp.int32(1), carry[1], live, 1e-6, rows=rows)
+            return (stack, window), gated
+
+        return jax.lax.scan(step, (stack, window), hs)
+
+    @jax.jit
+    def twin(s, window):
+        def step(carry, h):
+            gated, s, window = D.mixer_forward(lp, h, *carry, live, None, 1e-6)
+            return (s, window), gated
+
+        return jax.lax.scan(step, (s, window), hs)
+
+    (out, conv), gated = kernel(stack, window)
+    (want_s, want_conv), want = twin(stack[1], window)
+    at = np.asarray(live[:, 0])
+    np.testing.assert_allclose(np.asarray(gated)[:, at], np.asarray(want)[:, at], rtol=0, atol=5e-6)
+    np.testing.assert_allclose(out[1], want_s, rtol=0, atol=5e-6)
+    np.testing.assert_array_equal(conv, want_conv)
+    np.testing.assert_array_equal(np.asarray(out[1])[~at], np.asarray(stack[1])[~at])
+    np.testing.assert_array_equal(out[0], stack[0])
+    assert np.isfinite(np.asarray(gated)).all() and not np.asarray(gated)[:, ~at].any()
+    assert np.abs(np.asarray(out[1] - stack[1])[at]).max() > 0.1
+
+
+def test_the_live_rows_are_listed_once_a_dispatch():
+    """The decode chunk of a model whose step is the kernel sorts its lanes'
+    mask ONCE, at the program's top, outside the step scan and the runs'
+    layer scans; every ``gated_delta_step`` call inside them takes that list
+    (two scalar-prefetch operands). A model whose step is the twin sorts
+    nothing."""
+    def sorts(jaxpr, depth=0):
+        found = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "sort":
+                found.append(depth)
+            if eqn.primitive.name == "pallas_call" and eqn.params["name"] == "gated_delta_step":
+                assert eqn.params["grid_mapping"].num_index_operands == 2 and depth >= 2
+                found.append("kernel")
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                if eqn.primitive.name != "pallas_call":
+                    found += sorts(sub, depth + (eqn.primitive.name in ("scan", "while")))
+        return found
+
+    tiling = dataclasses.replace(
+        LlamaConfig.from_hf_dict({**HF, "linear_value_head_dim": 128}), attention_impl="pallas")
+    found = sorts(decode_program(tiling, lanes=4, allow_pallas=True).jaxpr.jaxpr)
+    assert found.count(0) == 1 and found.count("kernel") >= 1
+    assert set(found) == {0, "kernel"}, found
+    assert sorts(decode_program(LlamaConfig.from_hf_dict(HF), lanes=4).jaxpr.jaxpr) == []
+
+
+@pytest.mark.parametrize("form", ["pallas", "xla"])
+def test_engine_state_counts_the_rows_stepped_and_the_lanes(form):
+    """``/stats engine.state`` after two decode dispatches of four lanes with
+    two and then one live: ``decode_lanes`` adds the rows of each, and
+    ``decode_rows`` the rows the one-token update read and wrote: the live
+    ones where the step is the kernel (interpreted here), every row where it
+    is the twin. Either way the dead lanes' state comes back bit for bit."""
+    config = LlamaConfig.from_hf_dict({**HF, "linear_value_head_dim": 128})
+    params = H.init_params(config, jax.random.PRNGKey(0), jnp.float32)
+    if form == "xla":
+        be = backend(config, params)
+    else:
+        be = paged_backend(
+            dataclasses.replace(config, attention_impl="pallas"), params, max_seq_len=256,
+            cache_dtype=jnp.float32, page_size=128, max_pages=16, allow_pallas=True)
+    assert be.state_facts()["step_form"] == form
+    assert H.steps_live_rows(be.config, be.allow_pallas) == (form == "pallas")
+    cache, tokens, pads = lay_out(be, prompts(4, 18, 27), 4, 32)
+    logits, cache = be.prefill(tokens, cache, jnp.asarray(pads))
+    tok = np.asarray(logits).argmax(-1).astype(np.int32)
+    before = np.asarray(cache.ssm)
+    toks, cache = decode(be, cache, tok, 32, pads, 4, live=(0, 1))
+    state = be.state_facts()
+    assert (state["decode_dispatches"], state["decode_lanes"]) == (1, 4)
+    assert state["decode_rows"] == (2 if form == "pallas" else 4)
+    be.allocator.release(1)
+    after = np.asarray(cache.ssm)
+    _, cache = decode(be, cache, toks[:, -1], 36, pads, 4, live=(0,))
+    state = be.state_facts()
+    assert (state["decode_dispatches"], state["decode_lanes"]) == (2, 8)
+    assert state["decode_rows"] == (3 if form == "pallas" else 8)
+    np.testing.assert_array_equal(after[:, 2:], before[:, 2:])
+    np.testing.assert_array_equal(np.asarray(cache.ssm)[:, 1:], after[:, 1:])
+    assert not np.array_equal(after[:, :2], before[:, :2])
 
 
 # ------------------------------------------ (2) against the plain reference
