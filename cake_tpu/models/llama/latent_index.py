@@ -454,7 +454,7 @@ class IndexAccount:
     engine.sparse."""
 
     section, names, add = "sparse", SPARSE_COUNTS, staticmethod(jnp.add)
-    keeps_traced = True  # ``traced``: the backend asks whether a profiler is open
+    keeps_traced = True  # ``traced``: the backend asks ``timeline.recording()``
 
     def __init__(self, config: LlamaConfig):
         self.config = config
@@ -466,9 +466,9 @@ class IndexAccount:
         """Cumulative over decode chunks READ: ``dispatches`` decode steps x
         layers, ``rows`` live rows in them, ``scanned`` cached tokens their
         queries scored, ``chosen`` tokens attention then read; ``traced``:
-        the same of the chunks DISPATCHED while a profiler session was open
-        (what a device trace's times are of); ``join``: of the joins'
-        windows."""
+        the same of the chunks DISPATCHED while ``timeline.recording()``
+        (until a profiler's stop is CALLED, not through its closing: what a
+        device trace's times are of); ``join``: of the joins' windows."""
         topk = self.config.index_topk
         return {
             "index_topk": topk, **self.counts,

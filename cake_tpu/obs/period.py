@@ -87,6 +87,11 @@ class PeriodAccount:
         self._lock = threading.Lock()
         self._period = {
             "count": 0, "seconds": 0.0,
+            # Sum over dispatching periods of the decode steps their chunk
+            # or round was dispatched for (a segment's last chunk is a
+            # program of fewer): over ``count`` the steps a dispatch made,
+            # what a device time of a chunk is divided by for a step's.
+            "steps": 0,
             "with_join": {"count": 0, "seconds": 0.0},
             "undispatched": {"count": 0, "seconds": 0.0},
             "phase_seconds": dict.fromkeys(PHASES, 0.0),
@@ -108,8 +113,9 @@ class PeriodAccount:
             "count": 0, "seconds": 0.0, "prefill_seconds": 0.0,
             "between_seconds": 0.0,
         }
-        # The open period (engine thread only).
+        # The open period (engine thread only; ``_t0`` and ``_open`` under the lock).
         self._t0 = self._mark = 0.0
+        self._open = False
         self._queued = False
         self._stack: list[tuple[str, float]] = []
         self._self = dict.fromkeys(PHASES, 0.0)
@@ -123,7 +129,9 @@ class PeriodAccount:
 
     def begin(self, queued: bool) -> None:
         """The iteration starts; ``queued``: a request is waiting for a lane."""
-        self._t0 = self._mark = time.perf_counter()
+        self._mark = now = time.perf_counter()
+        with self._lock:  # a reader takes the pair (``open_seconds``)
+            self._t0, self._open = now, True
         self._queued = queued
         self._stack.clear()
         self._self = dict.fromkeys(PHASES, 0.0)
@@ -169,16 +177,21 @@ class PeriodAccount:
         the host got there."""
         self._join_readback_s += seconds
 
-    def end(self, live: int | None, order: str = "", cached: int = 0) -> None:
+    def end(
+        self, live: int | None, order: str = "", cached: int = 0, steps: int = 0,
+    ) -> None:
         """The iteration ends. ``live``: lanes that decoded in it; None when
         it dispatched nothing (the segment's last look for work, a chunk
         lost to a failover), which is no period. ``order``: ``"ahead"``
         when its chunk was enqueued before the one in front of it was
-        read, else one of ``SERIAL_WHY``."""
+        read, else one of ``SERIAL_WHY``. ``steps``: the decode steps its
+        chunk was dispatched for (a speculative round: the positions it
+        verified)."""
         now = time.perf_counter()
         self._self["other"] += now - self._mark
         wall = now - self._t0
         with self._lock:
+            self._open = False
             p = self._period
             if live is None:
                 p["undispatched"]["count"] += 1
@@ -187,6 +200,7 @@ class PeriodAccount:
             p["count"] += 1
             p["seconds"] += wall
             p["cached_tokens"] += cached
+            p["steps"] += steps
             if order == "ahead":
                 p["ahead"] += 1
             else:
@@ -233,14 +247,24 @@ class PeriodAccount:
 
     # ------------------------------------------------------------- reading
 
-    def snapshot(self) -> dict:
+    def snapshot(self, now: float | None = None) -> dict:
         """{"period": ..., "segment": ...} as ``GET /stats`` carries them
-        under ``engine``."""
+        under ``engine``. ``period.open_seconds`` is the one number that is
+        not cumulative: how long the iteration open at ``now`` (this read's
+        ``time.perf_counter()``) has run, 0.0 between iterations. A period's
+        wall is committed whole at its end, so ``seconds + open_seconds`` is
+        the loop's time in periods UP TO the read, and its difference over
+        two reads the time in periods BETWEEN them (an open iteration that
+        then dispatches nothing, a segment's last look, is no period: the
+        difference errs by that look)."""
         with self._lock:
             p = self._period
             period = {
                 k: dict(v) if isinstance(v, dict) else v for k, v in p.items()
             }
+            if now is None:
+                now = time.perf_counter()
+            period["open_seconds"] = max(0.0, now - self._t0) if self._open else 0.0
             period["hist"] = {
                 "edges_s": list(EDGES_S), "counts": list(self._hist),
             }

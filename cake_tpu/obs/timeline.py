@@ -41,6 +41,12 @@ One primitive, two sinks: a span on the ``engine`` track (``span()`` or
 name, so it lands on the host plane of whatever profiler window is open, on
 the device trace's clock. With no window open that is one flag check. jax
 is imported when the first such span opens, never by importing this module.
+
+The same flag is ``recording()``, the tree's one test of whether a profiler
+is recording. A timeline compares it with the value last seen wherever it
+enters or leaves such an annotation (``notice``) and tells the one listener
+(``listen``; the engine's) of a flip: ``GET /stats`` engine.profiled holds
+the engine's accounts at those two edges (runtime/serving.py).
 """
 
 from __future__ import annotations
@@ -51,6 +57,7 @@ import itertools
 import json
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Iterable
 
@@ -81,14 +88,32 @@ PROFILED_TRACK = "engine"
 _annotation_cls = None
 
 
-def _annotation(name: str, args: dict | None):
-    """An entered ``TraceAnnotation`` carrying ``args`` as its metadata."""
+def _annotation_class():
     global _annotation_cls
     if _annotation_cls is None:
         from jax.profiler import TraceAnnotation
 
         _annotation_cls = TraceAnnotation
-    ann = _annotation_cls(name, **(args or {}))
+    return _annotation_cls
+
+
+def recording() -> bool:
+    """Whether a profiler is recording this process's annotations: the
+    TraceMe recorder's own switch (``TraceAnnotation.is_enabled()``). True
+    from ``jax.profiler.start_trace`` to the instant ``stop_trace`` (or the
+    end of ``POST /profile``'s window) is CALLED, false through the closing,
+    which takes seconds to minutes and which the engine serves through.
+
+    It is the host recorder at level 1, which the benchmark's window and
+    ``utils/trace.jax_profile(host_python=False)`` both set. A session opened
+    with ``host_tracer_level = 0`` records none of the engine's spans and is
+    no session to the engine."""
+    return _annotation_class().is_enabled()
+
+
+def _annotation(name: str, args: dict | None):
+    """An entered ``TraceAnnotation`` carrying ``args`` as its metadata."""
+    ann = _annotation_class()(name, **(args or {}))
     ann.__enter__()
     return ann
 
@@ -103,12 +128,47 @@ class Timeline:
         self.node = node  # default pid label; per-event ``node=`` overrides
         # begin() spans on the profiled track: span id -> open annotation.
         self._annotations: dict[int, Any] = {}
+        # ``recording()`` as last seen at a profiled span's boundary, and who
+        # hears of a flip (``listen``).
+        self._recording = False
+        self._listener: weakref.WeakMethod | None = None
+        self._flips = threading.Lock()  # taken at a flip alone
 
     @property
     def capacity(self) -> int:
         return self._ring.maxlen or 0
 
     # ------------------------------------------------------------- recording
+
+    def recording(self) -> bool:
+        """The module's ``recording()``, for a holder of the instance (the
+        package exports it under the module's name)."""
+        return recording()
+
+    def listen(self, method) -> None:
+        """``method(recording: bool)`` is called on every flip of
+        ``recording()`` that ``notice`` sees. One listener, held weakly: a
+        later engine's replaces an earlier one's, and hears of a recording
+        under way at the next notice."""
+        with self._flips:
+            self._listener = weakref.WeakMethod(method)
+            self._recording = False
+
+    def notice(self) -> None:
+        """Compare ``recording()`` with the value last seen and tell the
+        listener of a flip. Called at every boundary of a span on the
+        profiled track, on the thread that opened it: a flag read and a
+        compare when nothing flipped, a lock only at a flip. A reader of
+        what the listener keeps calls it too: a thread that idles, or sits
+        in one long span, passes no boundary."""
+        if recording() != self._recording:
+            with self._flips:
+                now = recording()
+                if now != self._recording:
+                    self._recording = now
+                    heard = self._listener and self._listener()
+                    if heard:
+                        heard(now)
 
     def _record(self, ev: dict) -> dict:
         with self._lock:
@@ -183,7 +243,10 @@ class Timeline:
         span id so the body can parent flight events / flow arrows to it."""
         sid = next(_ids)
         parent = current_span_id()
-        ann = _annotation(name, args) if track == PROFILED_TRACK else None
+        ann = None
+        if track == PROFILED_TRACK:
+            self.notice()
+            ann = _annotation(name, args)
         wall, mono = _clocks()
         token = _CURRENT.set((self, sid))
         try:
@@ -192,6 +255,7 @@ class Timeline:
             _CURRENT.reset(token)
             if ann is not None:
                 ann.__exit__(None, None, None)
+                self.notice()
             self._event(
                 "X", name, sid=sid, parent=parent, rid=rid, node=node,
                 track=track, args=args, wall=wall, mono=mono,
@@ -215,6 +279,7 @@ class Timeline:
         is admitted — parenting it there would double-count their self time)."""
         sid = next(_ids)
         if track == PROFILED_TRACK:
+            self.notice()
             self._annotations[sid] = _annotation(name, args)
         self._event(
             "B", name, sid=sid,
@@ -230,6 +295,7 @@ class Timeline:
         ann = self._annotations.pop(sid, None)
         if ann is not None:
             ann.__exit__(None, None, None)
+            self.notice()
         self._event("E", "", sid=sid, args=args)
 
     def instant(self, name: str, **kw) -> None:

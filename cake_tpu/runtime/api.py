@@ -875,38 +875,15 @@ class ApiServer:
                         # `cake-tpu stats` renders as the per-node table.
                         body["cluster"] = cluster.snapshot()
                     if api.engine is not None:
-                        body["engine"] = dict(api.engine.stats)
-                        periods = getattr(api.engine, "periods", None)
-                        if periods is not None:
-                            # The step loop's cumulative account
-                            # (obs/period.py): ``period`` and ``segment``.
-                            body["engine"].update(periods.snapshot())
-                        # Which scheduler shape is serving (README
-                        # "Continuous scheduling") plus the spill table's
-                        # current depth — preempted lanes parked host-side
-                        # awaiting a restore.
-                        backend = getattr(api.engine, "backend", None)
-                        for key in ("cache", "moe", "sparse"):
-                            # What the lanes' pages hold and cost; the
-                            # decode programs' account of the expert layer
-                            # (a latent model's: runtime/batch_backend.py).
-                            facts = getattr(backend, f"{key}_facts", None)
-                            if facts is not None:
-                                body["engine"][key] = facts()
-                        state_facts = getattr(backend, "state_facts", None)
-                        if state_facts is not None:
-                            # The recurrent state beside the page pool
-                            # (models/llama/hybrid.py): cumulative
-                            # ``lane_writes`` like ``period``; zeros for a
-                            # model without state layers.
-                            body["engine"]["state"] = state_facts()
-                        body["engine"]["scheduler"] = getattr(
-                            api.engine, "scheduler", "epoch"
-                        )
-                        spilled = getattr(api.engine, "_spilled", None)
-                        if spilled is not None:
-                            with api.engine._cv:
-                                body["engine"]["spilled"] = len(spilled)
+                        # The engine's cumulative accounts (counters,
+                        # ``period`` / ``segment``, the backend's ``cache`` /
+                        # ``moe`` / ``sparse`` / ``state``, the scheduler's
+                        # shape) and the copies of them it kept where a
+                        # profiler last started and stopped recording.
+                        body["engine"] = {
+                            **api.engine.accounts(),
+                            "profiled": api.engine.profiled(),
+                        }
                         if hasattr(api.engine, "phase_stats"):
                             # Latency attribution aggregate + per-epoch
                             # convoy meter (the lockstep tax) — rendered

@@ -393,9 +393,9 @@ class _PagedBackend:
         self._state_lanes = 0
         self._fusions = resolve_fusion(config, allow_pallas)[0]
         # What the programs return beside the tokens (``take_chunk_counters``),
-        # by ``/stats`` section. The API reads the PRESENCE of ``moe_facts``
-        # and ``sparse_facts`` (runtime/api.py): a kind whose programs count
-        # neither reports neither.
+        # by ``/stats`` section. The engine's ``accounts()`` reads the PRESENCE
+        # of ``moe_facts`` and ``sparse_facts`` (runtime/serving.py): a kind
+        # whose programs count neither reports neither.
         self._accounts = {a.section: a(config) for a in self.kind.accounts_of(config)}
         self._traced = any(a.keeps_traced for a in self._accounts.values())
         self._chunk_counters = None
@@ -796,12 +796,12 @@ class _PagedBackend:
         counters, self._chunk_counters = self._chunk_counters, None
         if counters is None:
             return None
-        return *counters, self._traced and _profiler_open()
+        return *counters, self._traced and _recording()
 
     def absorb_chunk_counters(self, counters, decode: bool = True) -> dict:
         """A read program's counts into the cumulative accounts (a decode
         chunk's, or a join's window, of ``rows`` rows; ``traced``: dispatched
-        while a profiler session was open); returns them as a dict (the
+        while a profiler was recording); returns them as a dict (the
         timeline's span arguments)."""
         counters, rows, traced = counters
         values = iter(int(v) for v in np.asarray(counters))
@@ -999,15 +999,15 @@ class PagedLocalBackend(_PagedBackend):
         )
 
 
-def _profiler_open() -> bool:
-    """Whether a ``jax.profiler`` session is recording in this process (the
-    benchmark's control socket opens one; ``POST /profile`` another)."""
-    try:
-        from jax._src import profiler
+def _recording() -> bool:
+    """Whether a profiler is recording this process's annotations: the
+    timeline's ``recording()`` (obs/timeline.py), the tree's one test of it
+    (the benchmark's control socket opens a session; ``POST /profile``
+    another). False from the instant the session's stop is CALLED: the
+    engine serves through a closing of seconds to minutes."""
+    from cake_tpu.obs.timeline import timeline
 
-        return profiler._profile_state.profile_session is not None
-    except (ImportError, AttributeError):
-        return False
+    return timeline.recording()
 
 
 def paged_backend(config: LlamaConfig, params: M.Params, **kw) -> _PagedBackend:

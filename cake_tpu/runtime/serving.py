@@ -859,10 +859,10 @@ class BatchEngine:
             # lanes re-attached (bit-identical resume).
             "preemptions": 0, "restores": 0,
         }
-        # The step loop's cumulative account (obs/period.py): periods by
-        # phase, joins, lane-seconds, segments — ``GET /stats`` carries its
-        # snapshot under ``engine.period`` / ``engine.segment``.
+        # The step loop's cumulative account (obs/period.py: ``engine.period`` / ``engine.segment``).
         self.periods = PeriodAccount(self.max_batch)
+        self._profiled = {"sessions": 0, "open": None, "close": None}  # ``engine.profiled``
+        timeline.listen(self._profiler_edge)  # the accounts at a profiler's edges: the class's end
         self._segment_args: dict = {}
         # Device values the step loop has enqueued and not read yet, oldest
         # first (``_settle``): joiners' first tokens and at most one decode
@@ -2072,8 +2072,8 @@ class BatchEngine:
     @contextlib.contextmanager
     def _period(self, slot: int):
         """Root span of one iteration of the step loop. The body sets
-        ``dispatched``, ``live`` and ``order`` (``"ahead"``, or why the
-        chunk was not enqueued ahead) on the yielded arguments once it has
+        ``dispatched``, ``live``, ``steps`` and ``order`` (``"ahead"``, or why
+        the chunk was not enqueued ahead) on the yielded arguments once it has
         dispatched a chunk or a round; an iteration that did not is no
         period (obs/period.py)."""
         with self._cv:
@@ -2086,7 +2086,7 @@ class BatchEngine:
             finally:
                 self.periods.end(
                     args["live"] if args["dispatched"] else None,
-                    args.get("order", ""), args.get("cached", 0),
+                    args.get("order", ""), args.get("cached", 0), args.get("steps", 0),
                 )
 
     # ------------------------------------------------- replica failover
@@ -3119,7 +3119,7 @@ class BatchEngine:
                         res = None
                     if res is not None:
                         tok, kv, keys, slot = res
-                        period.update(dispatched=True, live=live, order="spec")
+                        period.update(dispatched=True, live=live, order="spec", steps=self.speculative_k + 1)
                         continue
                 if self._alloc is not None and not self._extend_pages(
                     rows, slot, n, spill_ctx=(keys, ring_j, ring_idx_j)
@@ -3197,7 +3197,7 @@ class BatchEngine:
                     continue
                 self._settle(rows, look, serial)
                 period.update(
-                    dispatched=True, live=live, order=order, cached=cached
+                    dispatched=True, live=live, order=order, cached=cached, steps=n
                 )
         self._settle(rows, 0)
         self._segment_args["ended"] = ended
@@ -4365,6 +4365,62 @@ class BatchEngine:
         self.stats["joins"] += len(group)
         self.stats["rows"] += len(group)
         return tok, kv, keys, ring_j, ring_idx_j
+
+    # ------------------------------------- the accounts at a profiler's edges
+    # A device trace's times are of the dispatches a profiler recorded; what
+    # they are divided by has to be of the same dispatches. The engine's
+    # accounts are cumulative, so one copy where the recorder starts and one
+    # where it stops (obs/timeline.py ``recording``: the instant
+    # ``stop_trace`` is CALLED, not the end of its closing) give, by
+    # difference, the periods that ended under the recorder.
+
+    def accounts(self, now: float | None = None) -> dict:
+        """What ``GET /stats`` serves under ``engine``, less ``profiled``:
+        the counters, the step loop's ``period`` and ``segment``
+        (obs/period.py), the backend's ``cache`` / ``moe`` / ``sparse`` /
+        ``state`` facts where its kind keeps them (a section's PRESENCE is
+        read: runtime/batch_backend.py), the scheduler's shape and the
+        spill table's depth. All cumulative: difference two reads
+        (``period.open_seconds`` is of the iteration open at ``now``, this
+        read's clock by default). Any thread's to call, and under no lock of
+        the engine's."""
+        out = dict(self.stats)
+        out.update(self.periods.snapshot(now))
+        for key in ("cache", "moe", "sparse", "state"):
+            facts = getattr(self.backend, f"{key}_facts", None)
+            if facts is not None:
+                out[key] = facts()
+        out["scheduler"] = self.scheduler
+        out["spilled"] = len(self._spilled)
+        return out
+
+    def _profiler_edge(self, recording: bool) -> None:
+        """The timeline saw ``recording()`` flip (one flip at a time: its
+        lock): keep the accounts as they stand, with the clock the
+        benchmark's ``trace_start`` / ``trace_stop`` answer with.
+        ``_profiled`` is of the LAST session (``close`` None while it
+        records) and is replaced whole: a reader holds one or the other."""
+        now = time.perf_counter()
+        edge = {"mono": now, "engine": self.accounts(now)}
+        was = self._profiled
+        if recording:
+            self._profiled = {**was, "open": edge, "close": None}
+        elif was["open"] is not None:
+            self._profiled = {**was, "sessions": was["sessions"] + 1, "close": edge}
+
+    def profiled(self) -> dict:
+        """``GET /stats`` engine.profiled: {``sessions`` closed so far,
+        ``open`` and ``close``: {``mono``, ``engine``: ``accounts()``} where
+        the engine noticed the recorder start and stop}. The step loop
+        notices at its spans' boundaries; an engine that idles when the
+        recorder stops passes none, so the reader looks too: no period has
+        ended since, and only ``mono`` is the later for it. A period's wall
+        enters ``period.seconds`` at its END: each copy's
+        ``period.open_seconds`` is what the period open at that notice had
+        run, so ``close - open`` of ``seconds + open_seconds`` is the time in
+        periods BETWEEN the notices and no more than ``mono``'s."""
+        timeline.notice()
+        return self._profiled
 
 
 def _set_lanes_rows(arrays, lanes, values):

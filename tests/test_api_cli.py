@@ -599,6 +599,12 @@ class _StubEngine:
     def start(self):
         pass
 
+    def accounts(self):  # ``GET /stats`` engine, and ``profiled`` beside it
+        return dict(self.stats)
+
+    def profiled(self):
+        return {"sessions": 0, "open": None, "close": None}
+
     def submit(
         self, messages, max_tokens, sampling, request_id=None, priority=None,
         tenant=None, deadline_s=None,
