@@ -180,17 +180,29 @@ def init_params(
 # ----------------------------------------------------------------- forward
 
 
+def into_heads(y: jnp.ndarray, dims: int) -> jnp.ndarray:
+    """A projection's result [b, t, heads * dims] as [b, t, heads, dims],
+    behind an optimisation barrier. Without it the chip's compiler makes ONE
+    convolution of the product and this reshape, over the weight seen as
+    [heads, dims, in], and that form wants the weight transposed: the run's
+    stack was transposed whole at a decode chunk's entry and a layer of it
+    laid out again every layer-step (``wq_b``: 75 MB, as long as the product
+    it fed; PERF.md, PR 56). Behind the barrier the product is a plain one
+    that reads its layer where it lies in the stack, as every other weight
+    is read, and what is reshaped is the small result. ``pool_audit.
+    weight_ops_in_hlo`` holds the compiled decode chunk to it."""
+    return jax.lax.optimization_barrier(y).reshape(*y.shape[:2], -1, dims)
+
+
 def mla_project(lp, x, cos, sin, positions, config: LlamaConfig):
     """A layer's input norm and the two low-rank projections: (q_nope [b, t,
     heads, nope], q_rope [b, t, heads, rope] after RoPE, latent [b, t,
     latent_width]: ``[rms(ckv) | RoPE(k_rope) | 0]``, what the pool holds)."""
     with jax.named_scope(MIXER_IN):
-        b, t, _ = x.shape
-        eps, n = config.rms_norm_eps, config.num_attention_heads
-        nope = config.qk_nope_head_dim
+        eps, nope = config.rms_norm_eps, config.qk_nope_head_dim
         h = rms_norm(x, lp["ln_attn"], eps)
         cq = rms_norm(qmat(h, lp["wq_a"]), lp["q_a_ln"], eps)
-        q = qmat(cq, lp["wq_b"]).reshape(b, t, n, -1)
+        q = into_heads(qmat(cq, lp["wq_b"]), nope + config.qk_rope_head_dim)
         q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin, positions)
         return q_nope, q_rope, token_latent(lp, h, cos, sin, positions, config)
 

@@ -54,7 +54,7 @@ import jax.numpy as jnp
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.config import SPARSE, LlamaConfig
 from cake_tpu.models.llama.latent import (
-    _EXPERT_STACKS, MOE_COUNTS, _add_counts, token_latent,
+    _EXPERT_STACKS, MOE_COUNTS, _add_counts, into_heads, token_latent,
 )
 from cake_tpu.models.llama.paged_cache import (
     LatentIndexPagedCache, init_latent_index_cache, latent_write_pool,
@@ -130,9 +130,8 @@ def attention_queries(wq_b, cq, cos, sin, config: LlamaConfig):
     holds, from the normed query latent: (q_nope [b, t, heads, nope], q_rope
     [b, t, heads, rope] after RoPE)."""
     with jax.named_scope(MIXER_IN):
-        b, t, _ = cq.shape
         nope = config.qk_nope_head_dim
-        q = qmat(cq, wq_b).reshape(b, t, -1, nope + config.qk_rope_head_dim)
+        q = into_heads(qmat(cq, wq_b), nope + config.qk_rope_head_dim)
         return q[..., :nope], apply_rope(q[..., nope:], cos, sin, None)
 
 
@@ -140,10 +139,7 @@ def index_queries(lp, cq, cos, sin, config: LlamaConfig):
     """The index's q_I [b, t, index heads, dim] from the SAME normed query
     latent, after RoPE on its first rotary numbers."""
     with jax.named_scope(MIXER_IN):
-        b, t, _ = cq.shape
-        q_i = qmat(cq, lp["wi_q"]).reshape(
-            b, t, config.index_n_heads, config.index_head_dim
-        )
+        q_i = into_heads(qmat(cq, lp["wi_q"]), config.index_head_dim)
         return apply_rope(q_i, cos, sin, None)
 
 

@@ -21,7 +21,13 @@ chip) find out whether that still holds:
     layer-of-pool-shaped scanned input or stacked output is the defect;
   * ``pool_ops_in_hlo`` reads compiled HLO text: ``copy``, ``dynamic-slice``
     and ``dynamic-update-slice`` ops (fused or not) whose result is a pool
-    or one layer of it.
+    or one layer of it;
+  * ``weight_ops_in_hlo`` reads it for a run's stacked WEIGHTS: an operation
+    that writes a stack, or a layer of one, out again (PR 56: a product whose
+    result is reshaped into heads was rewritten into a convolution that wants
+    its weight transposed, and the chip laid 75 MB out again a layer-step).
+    A layer's weights are read where they lie: a ``dynamic-slice`` INSIDE the
+    product's own fusion.
 """
 
 from __future__ import annotations
@@ -48,6 +54,16 @@ _POOL_WRITE = re.compile(r"^\s*(?:ROOT\s+)?%?paged_pool_write[\w.\-]* = ", re.M)
 _GROUPED_PRODUCT = re.compile(r"^\s*(?:ROOT\s+)?%?gmm[\w.\-]* = ", re.M)
 
 
+# Lines that define no new array, and the compiler's own prefetch of an
+# operand (asynchronous, beside the work: the stream itself).
+_NOT_WRITTEN = frozenset((
+    "parameter", "get-tuple-element", "tuple", "bitcast", "while", "conditional",
+    "call", "constant", "copy-start", "copy-done",
+))
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->.*\{\s*$")
+_HLO_FUSED = re.compile(r"kind=k\w+, calls=%?([\w.\-]+)")
+
+
 def pool_shapes(kv_shape: tuple[int, ...]) -> tuple[tuple[int, ...], ...]:
     """The two shapes that must not be moved: the pool, and one layer."""
     return (tuple(kv_shape), tuple(kv_shape[1:]))
@@ -57,6 +73,7 @@ def audit_programs(
     config: LlamaConfig,
     *,
     only: tuple[str, ...] = ("decode", "join"),
+    watch: tuple[str, ...] = ("wq_b", "wi_q"),
     **geometry,
 ) -> dict[str, dict]:
     """{program: report} for the programs ``only`` names of those a saturated
@@ -74,7 +91,10 @@ def audit_programs(
     the paged kernels run, none where the write is the scatter),
     ``grouped_products`` (how many are a routed expert layer's grouped
     product: three a run of sparse layers on the grouped path, none where the
-    dispatch takes the dense combine: ``ops/moe.dispatch_path``), ``kernels``,
+    dispatch takes the dense combine: ``ops/moe.dispatch_path``),
+    ``weight_ops`` (``weight_ops_in_hlo`` over the stacks ``watch`` names of
+    every run's tree, those the model has: the projections ``latent.
+    into_heads`` splits into heads; none may be written out again), ``kernels``,
     ``pool_bytes`` (the named pools'), the compiler's ``temp_bytes``,
     ``argument_bytes`` (together what the program needs on the device) and
     ``code_bytes`` (the compiled program's own size: what a start-up's
@@ -98,6 +118,7 @@ def audit_programs(
     weight_dims = {
         ",".join(map(str, a.shape)) for a in jax.tree.leaves(params["layers"])
     }
+    watched = [run[key].shape for run in params["layers"] for key in watch if key in run]
 
     def nbytes(arrays):
         return sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in arrays)
@@ -114,6 +135,7 @@ def audit_programs(
             "pool_ops": [f for s, dt in shapes.items() for f in pool_ops_in_hlo(hlo, s, dt)],
             "pool_writes": len(_POOL_WRITE.findall(hlo)),
             "grouped_products": len(_GROUPED_PRODUCT.findall(hlo)),
+            "weight_ops": weight_ops_in_hlo(hlo, watched),
             "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
             "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
             "code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
@@ -185,3 +207,41 @@ def pool_ops_in_hlo(hlo_text: str, kv_shape: tuple[int, ...], dtype) -> list[str
         ):
             found.append(f"{name} {ty}[{shape}]")
     return found
+
+
+def weight_ops_in_hlo(hlo_text: str, stacks: list[tuple[int, ...]]) -> list[dict]:
+    """The compiled operations that WRITE an array of the size and sides of
+    one of ``stacks`` ([layers, in, out]) or of a layer of one, in any order
+    of its sides (the compiler's copies are often transposes): {``op``:
+    ``copy.103 bf16[4,1536,24576] copy``, ``text``: its line and, of a
+    fusion, the computation it calls}. An operation inside a fusion writes
+    nothing (a ``dynamic-slice`` fused into the product that reads it is how
+    a layer's weights are meant to be read); a ``bitcast`` is a view; the
+    compiler's asynchronous prefetch of an operand (``copy-start`` /
+    ``copy-done``: a one-layer run's weights, fetched ahead beside the work)
+    is the stream itself, not a second one."""
+    def sides(shape):
+        return tuple(sorted(d for d in shape if d != 1))
+
+    want = {sides(s) for stack in stacks for s in (stack, stack[1:])}
+    fused = set(_HLO_FUSED.findall(hlo_text))
+    bodies: dict[str, list[str]] = {}
+    found, here = [], None
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            here = m.group(1)
+            continue
+        bodies.setdefault(here, []).append(line)
+        m = None if here in fused else _HLO_DEF.match(line)
+        if not m or m.group(4) in _NOT_WRITTEN:
+            continue
+        name, ty, shape, opcode = m.groups()
+        if sides(int(d) for d in shape.split(",") if d) in want:
+            found.append((f"{name} {ty}[{shape}] {opcode}", line))
+    out = []
+    for op, line in found:
+        called = _HLO_FUSED.search(line)
+        body = bodies.get(called.group(1), []) if called else []
+        out.append({"op": op, "text": "\n".join(t[:400] for t in [line, *body[:24]])})
+    return out

@@ -1604,6 +1604,25 @@ def say_experts(phase: str, records: list, where: str) -> list[str]:
     return problems
 
 
+def say_weight_ops(phase: str, r: dict, args) -> list[str]:
+    """A compiled program's operations that write a watched weight out again
+    (``pool_audit.weight_ops_in_hlo``: the queries' up-projection, the
+    index's), with the offending operation's own text. A problem in the
+    DECODE chunk, where a layer's weights are the step (PR 56: 75 MB laid
+    out again a layer-step); a window's program runs a layer once, is bound
+    by its products, and is only said. The CPU's compiler lays weights out
+    otherwise: the rehearsal says nothing."""
+    if args.rehearse_cpu:
+        return []
+    for f in r["weight_ops"]:
+        say(f"phase={phase}   {r['program']} writes a weight out again: {f['op']}")
+        if r["program"] == "decode":
+            say(f["text"])
+    if r["program"] == "decode" and r["weight_ops"]:
+        return [f"decode: {len(r['weight_ops'])} op(s) write a layer's query projection out again"]
+    return []
+
+
 def phase_latent(args, preset) -> dict:
     """Phase L: a model with latent attention and a share of its experts at
     the benchmark cell's geometry (pangu-ultra-ep16-chat-closed): the
@@ -1634,11 +1653,12 @@ def phase_latent(args, preset) -> dict:
         say(f"phase=L program={r['program']} temp_bytes={r['temp_bytes']} "
             f"argument_bytes={r['argument_bytes']} pool_bytes={r['pool_bytes']} "
             f"kernels={r['kernels']} moving_ops={len(moved)} "
-            f"compile_s={r['seconds']}")
+            f"weight_ops={'unread' if args.rehearse_cpu else len(r['weight_ops'])} compile_s={r['seconds']}")
         for m in moved:
             say(f"phase=L   {r['program']} moves the pool: {m}")
         if moved:
             problems.append(f"{r['program']}: {len(moved)} op(s) move the pool")
+        problems += say_weight_ops("L", r, args)
         out[f"{r['program']}_temp_bytes"] = r["temp_bytes"]
     if problems:
         raise PhaseFailed("; ".join(problems))
@@ -1706,9 +1726,10 @@ def phase_sparse(args, preset) -> dict:
         say(f"phase=S program={r['program']} temp_bytes={r['temp_bytes']} "
             f"argument_bytes={r['argument_bytes']} pool_bytes={r['pool_bytes']} "
             f"code_bytes={r['code_bytes']} kernels={r['kernels']} "
-            f"moving_ops={len(moved)} compile_s={r['seconds']}")
+            f"moving_ops={len(moved)} weight_ops={'unread' if args.rehearse_cpu else len(r['weight_ops'])} compile_s={r['seconds']}")
         if moved:
             problems.append(f"{r['program']}: {len(moved)} op(s) move a pool")
+        problems += say_weight_ops("S", r, args)
         out[f"{r['program']}_temp_bytes"] = r["temp_bytes"]
     if problems:
         raise PhaseFailed("; ".join(problems))

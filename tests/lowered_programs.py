@@ -11,6 +11,10 @@ behind a learned index, and Laguna-shaped pools a kind.
 by shape, ``ops/moe.GROUPED_MIN_TOKENS`` 0, which is how a tree since PR 50
 stands to a recording made before it: tiny joins and prefills of two experts
 a token in four fall under that rule where no cell's do)
+(with ``unbarred``: ``jax.lax.optimization_barrier`` taken out, which is how
+a tree since PR 56 stands to a recording made before it: the latent models'
+projections that are reshaped into heads go behind one, ``latent.into_heads``,
+and nothing else of their programs changed)
 run IN A CHECKOUT OF THE PARENT (this file copied into its ``tests/``) makes
 a recording; ``tests/test_lowered_programs.py`` holds the tree that stands to
 it. (PR 46's own recording was made on ITS parent by that parent's helpers,
@@ -96,8 +100,12 @@ if __name__ == "__main__":
     if sys.argv[1:] == ["dear"]:
         json.dump(dear_digests(), sys.stdout, indent=1)
         sys.exit(0)
-    if sys.argv[1:] == ["grouped"]:
+    if "grouped" in sys.argv[1:]:
         from cake_tpu.ops import moe
 
         moe.GROUPED_MIN_TOKENS = 0
+    if "unbarred" in sys.argv[1:]:
+        import jax
+
+        jax.lax.optimization_barrier = lambda x: x
     json.dump(digests(), sys.stdout, indent=1)

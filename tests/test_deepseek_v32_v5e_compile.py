@@ -68,6 +68,17 @@ def test_the_cell_compiles_for_v5e_without_pool_copies(program, cell_reports):
     assert report["code_bytes"] < 40e6, report  # one block's code whatever the width
 
 
+def test_the_decode_chunk_reads_the_query_projections_where_they_lie(cell_reports):
+    """PR 56: neither ``wq_b`` (75.5 MB a layer) nor the index's ``wi_q``
+    (25 MB) is written out again by the decode chunk, a layer a layer-step or
+    the stack at the chunk's entry (``latent.into_heads``); the transposed
+    stacks were 504 MB of the parent's 553 MB of temporaries."""
+    report = cell_reports["decode"]
+    assert report["weight_ops"] == [], "\n".join(
+        f"{f['op']}\n{f['text']}" for f in report["weight_ops"])
+    assert report["temp_bytes"] < 64e6, report["temp_bytes"]
+
+
 def test_the_cells_closed_shapes(deepseek):
     """What ``--max-seq-len 21504 --page-size 128`` makes of the CLOSED
     instance: six widths in whole 128s and three capacities."""

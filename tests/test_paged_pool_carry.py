@@ -616,3 +616,16 @@ def test_latent_cell_compiles_for_v5e_without_pool_copies(program, latent_report
     # weights 9.84 GB + the pool: the chip's 16 GB hold the program
     assert report["argument_bytes"] + report["temp_bytes"] < 14.0e9, report
     assert report["temp_bytes"] < report["pool_bytes"], report
+
+
+def test_latent_decode_chunk_reads_wq_b_where_it_lies(latent_reports):
+    """PR 56: no operation of the decode chunk writes the queries'
+    up-projection out again, a layer of it (75.5 MB a layer-step, as the
+    parent's ``constant_dynamic-slice_fusion``) or the run's stack (its
+    transposes at the chunk's entry): ``latent.into_heads``. With them went
+    the 377 MB of transposed stacks among the program's temporaries (424 MB
+    before). A join lays its one layer out as it likes: reported, not held."""
+    report = latent_reports["decode"]
+    assert report["weight_ops"] == [], "\n".join(
+        f"{f['op']}\n{f['text']}" for f in report["weight_ops"])
+    assert report["temp_bytes"] < 64e6, report["temp_bytes"]
