@@ -168,6 +168,23 @@ def build_parser() -> argparse.ArgumentParser:
         "--backend mesh masters; workers quantize their own ranges",
     )
     p.add_argument(
+        "--denoise-steps", type=int, default=None,
+        help="block-diffusion models (sdar_moe): denoising passes a block "
+        "before its commit, 1..block_length (default: the checkpoint's, the "
+        "block length)",
+    )
+    p.add_argument(
+        "--remask", default=None,
+        choices=("sequential", "low_confidence_static", "low_confidence_dynamic"),
+        help="block-diffusion models: which masked slots a pass reveals: the "
+        "first, the most confident, or every one past --confidence-threshold",
+    )
+    p.add_argument(
+        "--confidence-threshold", type=float, default=None,
+        help="block-diffusion models, --remask low_confidence_dynamic: a "
+        "masked slot whose token's probability passes this is revealed",
+    )
+    p.add_argument(
         "--speculative-k",
         type=int,
         default=0,
@@ -1714,8 +1731,10 @@ def main(argv: list[str] | None = None) -> int:
             distributed=bool(args.distributed),
             quantize=bool(args.quantize),
             kv_dtype_narrow=jnp.dtype(kv_dtype).itemsize < jnp.dtype(dtype).itemsize,
+            repeat_penalty=args.repeat_penalty != 1.0,
         )
-    except UnsupportedForCacheKind as e:
+        config = _generation_flags(args, config)
+    except (UnsupportedForCacheKind, ValueError) as e:
         print(f"cake-tpu: {e}", file=sys.stderr)
         return 2
     if args.fusion != "none":
@@ -1773,6 +1792,35 @@ def main(argv: list[str] | None = None) -> int:
     return _run_leader(args, step, config, sampling, dtype, kv_dtype, startup)
 
 
+def _generation_flags(args, config):
+    """``--denoise-steps`` / ``--remask`` / ``--confidence-threshold`` onto the
+    config of a model that generates by diffusion over blocks (where they
+    override the checkpoint's defaults); given for any other model they are a
+    mistake, said by name."""
+    import dataclasses
+
+    given = {
+        "denoising_steps": args.denoise_steps, "remask": args.remask,
+        "confidence_threshold": args.confidence_threshold,
+    }
+    given = {k: v for k, v in given.items() if v is not None}
+    if not given:
+        return config
+    if not config.block_length:
+        raise ValueError(
+            "--denoise-steps / --remask / --confidence-threshold are for a "
+            "model that generates by diffusion over blocks; model_type "
+            f"{config.model_type!r} generates one token a step"
+        )
+    steps = given.get("denoising_steps", config.denoising_steps)
+    if not 1 <= steps <= config.block_length:
+        raise ValueError(
+            f"--denoise-steps {steps} must lie in 1..{config.block_length} "
+            "(the block length): a pass reveals at least one slot"
+        )
+    return dataclasses.replace(config, **given)
+
+
 def _resolve_kv_dtype(args, dtype):
     """--kv-dtype -> jnp dtype (auto = the activation --dtype)."""
     import jax.numpy as jnp
@@ -1797,7 +1845,9 @@ def _run_leader(
     if args.prefix_cache == "auto":
         # On for --api, but for a cache that is not plain K and V: a reused
         # prefix restores K and V only ("on" is refused outright, cli.main).
-        prefix_cache = bool(args.api) and config.cache_kind == CACHE_KV
+        prefix_cache = (
+            bool(args.api) and config.cache_kind == CACHE_KV and not config.block_length
+        )
     else:
         prefix_cache = args.prefix_cache == "on"
     # With a batch engine attached, the API path bypasses the generator for

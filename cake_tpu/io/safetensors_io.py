@@ -1123,7 +1123,7 @@ def layer_tensor_dict(
         # write qwen2_moe tensor names to match its own config.json.
         layout = _MOE_LAYOUTS[
             "qwen2_moe"
-            if config.model_type in ("qwen2_moe", "qwen3_moe")
+            if config.model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe")
             else "mixtral"
         ]
         for key in layout["experts"]:

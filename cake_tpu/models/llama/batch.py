@@ -102,17 +102,23 @@ def prompt_bucket(longest: int, max_seq_len: int) -> int:
 
 
 def layout_prompts(
-    ids_list: list[list[int]], max_seq_len: int
+    ids_list: list[list[int]], max_seq_len: int, block: int = 0
 ) -> tuple[np.ndarray, np.ndarray, int]:
-    """Left-pad prompts into one shared bucket: (tokens [B, bucket], pads [B], bucket)."""
+    """Left-pad prompts into one shared bucket: (tokens [B, bucket], pads [B], bucket).
+
+    ``block`` (a block-diffusion model's ``block_length``): every row is whole
+    blocks and so is the bucket, hence every pad: a lane's block boundaries
+    fall on the same SLOTS as every other lane's, which is what lets the
+    block-causal mask compare slots (asserted here, where pads are set)."""
     longest = max(len(i) for i in ids_list)
-    bucket = prompt_bucket(longest, max_seq_len)
+    bucket = prompt_bucket(max(longest, 1) if block else longest, max_seq_len)
     b = len(ids_list)
     tokens = np.zeros((b, bucket), np.int32)
     pads = np.zeros((b,), np.int32)
     for r, ids in enumerate(ids_list):
         pads[r] = bucket - len(ids)
         tokens[r, pads[r] :] = ids
+    assert not block or not (pads % block).any(), (pads, block)
     return tokens, pads, bucket
 
 
@@ -449,6 +455,16 @@ def batched_blocks_forward(
         scale=config.attn_scale,
         softcap=config.attn_logit_softcap,
     )
+    if config.block_length:
+        # Generation by diffusion over blocks: the block-causal mask. The
+        # kernels compare SLOTS, the XLA twins positions (``slot - pad``):
+        # the same blocks, because a lane's pad, the bucket and every chunk
+        # are whole blocks (``layout_prompts`` asserts the first).
+        assert not decode, "a block-diffusion model has no one-token step"
+        # (``--kv-mode dense`` is refused for this generation, so the dense
+        # chunk kernel has no such mask: ``capability.REFUSED_BY_GENERATION``)
+        assert paged or not use_pallas, "block-causal: paged, or the XLA twins"
+        attn_kw["block"] = config.block_length
     # Cached chunks start their queries at the write slot (the kernel prunes
     # cache blocks causally from there); fresh prefills start at slot 0.
     q_starts = (
@@ -474,6 +490,11 @@ def batched_blocks_forward(
         return M.block_qkv(
             lp, x, cos, sin, q_pos, config, k_positions=k_pos, fusion=fusion,
         )
+
+    # One pass over ONE block of a block-diffusion model: every query of the
+    # block sees the same keys (``block_pass_attention``).
+    block_pass = bool(config.block_length) and cached_chunk and (
+        x.shape[1] == config.block_length)
 
     def paged_layer(carry, per_layer):
         # The pool rides in the carry and is only ever written in place and
@@ -514,6 +535,11 @@ def batched_blocks_forward(
                         q, k_pool, v_pool, q_pos, k_pos, block_tables,
                         window_flag=lp.get("win_flag"), layer=li, **kw,
                     )
+            elif kernel_ok and block_pass:
+                attn = block_pass_attention(
+                    q, k_pool, v_pool, lengths, block_tables, pads, layer=li,
+                    **{k: v for k, v in kw.items() if k != "block"},
+                )
             elif kernel_ok:
                 # Every paged prefill under pallas is one call. A cached chunk
                 # at slot ``write_pos`` — the prefix-cache suffix prefill AND
@@ -1327,3 +1353,36 @@ class BatchGenerator:
                 )
             )
         return results
+
+
+def block_pass_attention(
+    q: jnp.ndarray,  # [b, B, n_q, d]: ONE block's queries a row
+    k_pool: jnp.ndarray,
+    v_pool: jnp.ndarray,
+    lengths: jnp.ndarray,  # [b] one past the block's last slot
+    block_tables: jnp.ndarray,
+    starts: jnp.ndarray,  # [b] first live slot per row (the left pads)
+    **kw,
+) -> jnp.ndarray:
+    """A denoising or commit pass's attention under the Pallas kernels: the
+    queries of ONE block of a block-diffusion model (``config.block_length``
+    slots from the shared slot on) all see the same keys, every slot of
+    [start, the block's end): no query of the block is masked from another's
+    key. So the block's ``B`` queries a head are ``B`` more heads of the same
+    KV head, and the call is the paged DECODE kernel's at a group of ``B x
+    group`` rows (``ops/pallas/paged_attention.py``: one grid step a lane and
+    KV head, the live pages in a loop of its own), where the chunk kernel's
+    grid walks lanes x query heads x table pages for 4 rows of a 16-row tile
+    (24.7 ms a call at the benchmark cell's shape for the decode kernel's
+    0.1: PERF.md, PR 57). ``kw``: the decode kernel's (``layer``, ``scale``,
+    ``softcap``, ``window``). Returns [b, B, n_q, d]."""
+    b, width, n_q, d = q.shape
+    n_kv = k_pool.shape[-3]
+    group = n_q // n_kv
+    folded = q.reshape(b, width, n_kv, group, d).transpose(0, 2, 1, 3, 4)
+    out = paged_decode_attention(
+        folded.reshape(b, 1, n_kv * width * group, d), k_pool, v_pool, lengths,
+        block_tables, starts, None, **kw,
+    )
+    out = out.reshape(b, n_kv, width, group, d).transpose(0, 2, 1, 3, 4)
+    return out.reshape(b, width, n_q, d)

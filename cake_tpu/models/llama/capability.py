@@ -14,7 +14,10 @@ own paged leaf
 (``runtime/batch_backend.paged_backend``) on one chip, and everything else is
 refused HERE, with one message, before a weight is read: over such a cache
 each would serve wrong tokens silently, and there is no fallback to serve
-instead. Every caller fills in the facts it knows (``cli.main``, the engine
+instead. A model that GENERATES otherwise (``config.generation``: by
+diffusion over blocks, ``sdar_moe``) keeps plain K and V and is refused the
+same features, and a penalty ring beside them, for its step's sake: each
+restores, rewinds, verifies or shards a ONE-TOKEN step. Every caller fills in the facts it knows (``cli.main``, the engine
 for programmatic use, the loader, the splitter, the single-stream step) and
 the message names the feature as a user would have written it.
 """
@@ -22,8 +25,8 @@ the message names the feature as a user would have written it.
 from __future__ import annotations
 
 from cake_tpu.models.llama.config import (
-    CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT_INDEX, SLIDING, STATE,
-    LlamaConfig,
+    BLOCK_DIFFUSION, CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT_INDEX,
+    SLIDING, STATE, LlamaConfig,
 )
 
 
@@ -56,7 +59,22 @@ REFUSED_BY_KIND = {
 }
 
 
+# Refused over one generation kind only: a repeat penalty is a ring of the
+# last tokens SAMPLED one by one, which a block's passes do not keep.
+REFUSED_BY_GENERATION = {
+    BLOCK_DIFFUSION: {"repeat_penalty": "--repeat-penalty other than 1.0"},
+}
+
+
 def _why(config: LlamaConfig) -> str:
+    if config.generation == BLOCK_DIFFUSION:
+        return (
+            f"it generates by diffusion over blocks of {config.block_length} "
+            f"slots (several passes over a block under a mask that is "
+            "bidirectional inside it, then a commit of its K and V), and this "
+            "feature restores, rewinds, verifies, penalises or shards a "
+            "one-token step"
+        )
     if config.cache_kind == CACHE_KV_STATE and config.state_shape is None:
         kept, channels = config.conv_window
         return (
@@ -105,15 +123,17 @@ def refuse_unsupported(config: LlamaConfig, **facts: bool) -> None:
     """``facts`` maps names of ``REFUSED`` to whether the caller was asked
     for that feature; the first that was raises, for a model whose cache is
     not plain K and V (a fact of ``REFUSED_BY_KIND`` for its kind alone)."""
-    if config.cache_kind == CACHE_KV:
+    if config.cache_kind == CACHE_KV and config.generation != BLOCK_DIFFUSION:
         return
-    refused = {**REFUSED, **REFUSED_BY_KIND.get(config.cache_kind, {})}
+    refused = {**REFUSED, **REFUSED_BY_KIND.get(config.cache_kind, {}),
+               **REFUSED_BY_GENERATION.get(config.generation, {})}
     for fact, on in facts.items():
         if on and fact in refused:
             raise UnsupportedForCacheKind(
                 f"{refused[fact]} is not supported for model_type "
                 f"{config.model_type!r}: {_why(config)}. Serve it with --api "
                 "HOST:PORT --api-batch N (N > 1) --kv-mode paged "
-                "--prefix-cache off on one chip, unquantized."
+                "--prefix-cache off on one chip, unquantized"
+                + (", --repeat-penalty 1.0." if config.generation == BLOCK_DIFFUSION else ".")
             )
 

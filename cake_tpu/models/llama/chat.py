@@ -389,6 +389,8 @@ DIALOG_ENCODERS = {
     "lfm2_moe": encode_dialog_lfm2,
     # Qwen3-Next-Instruct (ASSUMED, from memory): ChatML, no default system turn
     "qwen3_next": encode_dialog_chatml_no_default_system,
+    # SDAR-Chat (ASSUMED, from memory): Qwen3's ChatML, no default system turn
+    "sdar_moe": encode_dialog_chatml_no_default_system,
 }
 
 

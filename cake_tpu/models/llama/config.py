@@ -24,7 +24,7 @@ SUPPORTED_MODEL_TYPES = (
     "llama", "qwen2", "mistral", "mixtral", "qwen2_moe",
     "gemma", "gemma2", "phi3", "qwen3", "qwen3_moe", "gemma3_text", "jamba",
     "pangu_ultra_moe", "olmo_hybrid", "laguna", "deepseek_v32", "lfm2_moe",
-    "qwen3_next",
+    "qwen3_next", "sdar_moe",
 )
 
 # The two kinds a decoder layer's token mixer can be (``layer_kinds``).
@@ -47,9 +47,20 @@ FULL, SLIDING = "full", "sliding"
 # behind the window).
 CACHE_KV, CACHE_KV_STATE, CACHE_LATENT = "kv", "kv+state", "latent"
 CACHE_KV_KINDS = "kv+kinds"
+# Prompts are laid out in buckets of this many slots (``batch.BUCKET_MULTIPLE``
+# is this number): a diffusion block's length has to divide it.
+BLOCK_MULTIPLE = 16
 # A latent a token and, behind the SAME block table, the key of a learned
 # index that chooses which cached tokens a query attends (``deepseek_v32``).
 CACHE_LATENT_INDEX = "latent+index"
+# How a model generates (``generation``): one token a step from the last
+# one's logits, or a BLOCK of ``block_length`` slots at a time by diffusion:
+# the block starts as ``mask_token_id``, each denoising pass runs the model
+# over the block (bidirectional inside it, causal over the earlier blocks) and
+# reveals some slots, and a last pass commits the finished block's K and V.
+AUTOREGRESSIVE, BLOCK_DIFFUSION = "autoregressive", "block_diffusion"
+# Which masked slots a denoising pass reveals (``remask``).
+REMASK_RULES = ("sequential", "low_confidence_static", "low_confidence_dynamic")
 
 
 def _expert_share(d: dict, held_key: str, first_key: str, default: int) -> tuple[int, int, int]:
@@ -292,6 +303,18 @@ class LlamaConfig:
     # The feed-forward of every layer as a list (``mlp_layer_types``); None =
     # by ``first_k_dense_replace``.
     ff_types: tuple[str, ...] | None = None
+    # How the model generates (``AUTOREGRESSIVE`` / ``BLOCK_DIFFUSION``): a
+    # fact of the checkpoint, never a caller's to set against it. For block
+    # diffusion: the block's length, the id a masked slot holds, and the
+    # serving defaults a flag may override (``--denoise-steps``, ``--remask``,
+    # ``--confidence-threshold``): passes a block before its commit, and which
+    # slots a pass reveals (``REMASK_RULES``).
+    generation: str = AUTOREGRESSIVE
+    block_length: int = 0
+    mask_token_id: int = -1
+    denoising_steps: int = 0
+    remask: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
     # Chat-template override (--chat-template; not an HF field). None = pick
     # by model_type. Needed for Llama-2-chat checkpoints, whose config.json
     # is indistinguishable from base Llama (chat.DIALOG_ENCODERS keys).
@@ -576,6 +599,8 @@ class LlamaConfig:
             return cls._lfm2_moe_from_hf_dict(d, eos_ids)
         if model_type == "qwen3_next":
             return cls._qwen3_next_from_hf_dict(d, eos_ids)
+        if model_type == "sdar_moe":
+            return cls._sdar_moe_from_hf_dict(d)
         if model_type == "phi3" and d.get("rope_scaling"):
             # Phi-3 128k variants use longrope (per-dim su-scaled factors);
             # only the base-rope variants (4k/8k) are supported.
@@ -1328,6 +1353,48 @@ class LlamaConfig:
         )
 
     @classmethod
+    def _sdar_moe_from_hf_dict(cls, d: dict[str, Any]) -> "LlamaConfig":
+        """``model_type: sdar_moe`` (JetLM's SDAR-30B-A3B-Chat): ``qwen3_moe``'s
+        block, layer for layer (q/k norm a head before the rope, softmax
+        scores over every expert, the chosen ones renormalised, no shared
+        expert, plain K and V), generating by diffusion over blocks. The
+        catalog row gives neither the block length nor the mask id: both are
+        ASSUMED defaults here (4, the family's released setting; 151669) that
+        the file's own ``block_length`` / ``mask_token_id`` override. What
+        the parser cannot serve is refused by name."""
+        for key, want in (("rope_scaling", None), ("attention_bias", False),
+                          ("use_sliding_window", False)):
+            if d.get(key, want) not in (want, None):
+                raise ValueError(
+                    f"sdar_moe with {key}={d[key]!r} is not supported: the "
+                    f"block-diffusion path serves {key}={want!r} only"
+                )
+        base = cls.from_hf_dict({**d, "model_type": "qwen3_moe"})
+        block = int(d.get("block_length", 4))
+        mask_id = int(d.get("mask_token_id", 151669))
+        if block < 1 or BLOCK_MULTIPLE % block:
+            raise ValueError(
+                f"sdar_moe block_length={block} must divide {BLOCK_MULTIPLE}: "
+                "prompts are laid out in buckets of that many slots, and a "
+                "block may not straddle a lane's left pad"
+            )
+        if not 0 <= mask_id < base.vocab_size:
+            raise ValueError(
+                f"sdar_moe mask_token_id={mask_id} lies outside the "
+                f"vocabulary of {base.vocab_size}"
+            )
+        steps = int(d.get("denoising_steps", block))
+        if not 1 <= steps <= block:
+            raise ValueError(
+                f"sdar_moe denoising_steps={steps} must lie in 1..block_length "
+                f"({block}): a pass reveals at least one slot"
+            )
+        return dataclasses.replace(
+            base, model_type="sdar_moe", generation=BLOCK_DIFFUSION,
+            block_length=block, mask_token_id=mask_id, denoising_steps=steps,
+        )
+
+    @classmethod
     def from_model_dir(
         cls, model_dir: str | Path, *, attention_impl: str | None = None
     ) -> "LlamaConfig":
@@ -1405,6 +1472,7 @@ class LlamaConfig:
             "deepseek_v32": "DeepseekV32ForCausalLM",
             "lfm2_moe": "Lfm2MoeForCausalLM",
             "qwen3_next": "Qwen3NextForCausalLM",
+            "sdar_moe": "SDARMoeForCausalLM",
         }[self.model_type]
         d: dict[str, Any] = {
             "architectures": [arch],
@@ -1553,7 +1621,7 @@ class LlamaConfig:
                     self.shared_expert_intermediate_size or 0),
             )
         elif self.num_local_experts:
-            if self.model_type in ("qwen2_moe", "qwen3_moe"):
+            if self.model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe"):
                 d["num_experts"] = self.num_local_experts
                 d["norm_topk_prob"] = self.norm_topk_prob
                 if self.moe_intermediate_size is not None:
@@ -1565,6 +1633,9 @@ class LlamaConfig:
             else:
                 d["num_local_experts"] = self.num_local_experts
             d["num_experts_per_tok"] = self.num_experts_per_tok
+        if self.generation == BLOCK_DIFFUSION:
+            d.update(block_length=self.block_length, mask_token_id=self.mask_token_id,
+                     head_dim=self.head_dim)
         if self.model_type in ("gemma", "gemma2"):
             d["hidden_activation"] = "gelu_pytorch_tanh"
             d["head_dim"] = self.head_dim

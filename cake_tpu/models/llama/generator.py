@@ -191,9 +191,10 @@ class LocalForwardStep(FusedDecodeCapability):
         return self._max_seq
 
     def reset(self) -> None:
-        if self.config.cache_kind != CACHE_KV:
+        if self.config.cache_kind != CACHE_KV or self.config.block_length:
             # The weights' holder for the batch engine only: this step's
-            # dense cache and M.forward know K and V a KV head alone.
+            # dense cache and M.forward know K and V a KV head alone, one
+            # token a step.
             self._kv = None
             return
         self._kv = init_cache(

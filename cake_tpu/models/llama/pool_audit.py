@@ -118,7 +118,9 @@ def audit_programs(
     weight_dims = {
         ",".join(map(str, a.shape)) for a in jax.tree.leaves(params["layers"])
     }
-    watched = [run[key].shape for run in params["layers"] for key in watch if key in run]
+    # (a list of runs' trees, or one stack's tree: plain K and V's)
+    runs = params["layers"] if isinstance(params["layers"], (list, tuple)) else [params["layers"]]
+    watched = [run[key].shape for run in runs for key in watch if key in run]
 
     def nbytes(arrays):
         return sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in arrays)

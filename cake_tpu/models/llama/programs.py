@@ -32,13 +32,14 @@ import jax
 import jax.numpy as jnp
 
 from cake_tpu.models.llama import batch as B
+from cake_tpu.models.llama import diffusion as D
 from cake_tpu.models.llama import hybrid as H
 from cake_tpu.models.llama import kinds as K
 from cake_tpu.models.llama import latent as L
 from cake_tpu.models.llama import latent_index as LI
 from cake_tpu.models.llama import model as M
 from cake_tpu.models.llama.config import (
-    ATTENTION, CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT,
+    ATTENTION, BLOCK_DIFFUSION, CACHE_KV, CACHE_KV_KINDS, CACHE_KV_STATE, CACHE_LATENT,
     CACHE_LATENT_INDEX, SPARSE, LlamaConfig,
 )
 from cake_tpu.models.llama.fused import sampled_decode_scan
@@ -232,6 +233,27 @@ _ALL = (
     ),
 )
 KINDS: dict[str, CacheKind] = {kind.name: kind for kind in _ALL}
+# Plain K and V under a model that generates by diffusion over blocks
+# (``config.generation``; models/llama/diffusion.py): the same cache, pool,
+# pages and strings, and the generation's own programs over them: every
+# window under the block-causal mask from a start slot (the closed shapes'
+# windows), a decode dispatch of whole blocks (``block_decode_program``) that
+# masks dead lanes out of the experts' rows, and the two accounts riding back.
+# No cache kind of its own: ``kind_of`` hands it out for ``CACHE_KV``.
+_KV_BLOCKS = dataclasses.replace(
+    KINDS[CACHE_KV], window=D.block_window, forward_one=None,
+    window_operands=("start",), masks_lanes=True, capped_windows=False,
+    accounts=(L.ExpertAccount, D.DiffusionAccount), more_programs={},
+)
+
+
+def kind_of(config: LlamaConfig) -> CacheKind:
+    """The record a model's served programs are made by: its cache kind's,
+    and for plain K and V also its generation kind's say."""
+    if config.generation == BLOCK_DIFFUSION:
+        assert config.cache_kind == CACHE_KV, config.cache_kind
+        return _KV_BLOCKS
+    return KINDS[config.cache_kind]
 
 
 def pool_pages(config: LlamaConfig, max_pages: int, page_size: int, lanes: int):
@@ -376,7 +398,8 @@ def served_programs(
     ``pool_pages`` sizes them, where ``pools_by_kind``); ``sharding`` names
     the device to compile for (one that is described and not attached will
     do). ``programs[name]()`` is ``jit(...).trace(...)``'s result."""
-    kind = KINDS[config.cache_kind]
+    kind = kind_of(config)
+    blocks = config.generation == BLOCK_DIFFUSION
 
     def spec(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
@@ -394,7 +417,8 @@ def served_programs(
         table = spec((rows, table_pages))
         have = {
             "params": params, "cache": cache, "config": config,
-            "tokens": spec((rows, width)), "tok": spec((rows,)), "slot": spec(()),
+            "tokens": spec((rows, width)), "slot": spec(()),
+            "tok": spec((rows, config.block_length) if blocks else (rows,)),
             "pads": spec((rows,)), "ends": spec((rows,)),
             "write_starts": spec((rows,)), "start": spec(()), "lane": spec(()), "lanes": spec((rows,)),
             "tables": tuple(table for _ in n_pages) if kind.pools_by_kind else table,
@@ -404,14 +428,17 @@ def served_programs(
         return [have[role] for role in roles]
 
     window = ("pads", "ends", "tables")
-    decode = decode_program(
+    decode = block_decode_program(
+        kind, config, n_steps, 0.0, None, None, allow_pallas
+    ) if blocks else decode_program(
         kind, config, table_pages * page_size if kind.closes_over_capacity else None,
         n_steps, 0.0, None, None, 1.0, allow_pallas,
     )
     programs = {
         "decode": (decode, (
             "params", "cache", "tok", "slot", "pads", "tables",
-            *(("valid",) if kind.masks_lanes else ()), "key", "ring", "ring_idx")),
+            *(("valid",) if kind.masks_lanes else ()), "key",
+            *(() if blocks else ("ring", "ring_idx")))),
         "join": (join_program(kind, config, width, allow_pallas),
                  ("params", "cache", "tokens", *window, *kind.window_operands)),
         "prefill": (prefill_program(kind),
@@ -465,3 +492,42 @@ def _rows_programs(kind, config, rows, width, allow_pallas) -> dict:
         join_rows_program(kind, config, rows, width, allow_pallas),
         ("params", "cache", "tokens", "pads", "ends", "tables", "start", "lanes"),
     )}
+
+
+# --------------------------------------------- a dispatch of whole blocks
+
+
+@functools.lru_cache(maxsize=16)
+def block_decode_program(
+    kind: CacheKind,
+    config: LlamaConfig,
+    n_steps: int,  # slots a dispatch advances: whole blocks
+    temperature: float,
+    top_k,
+    top_p,
+    allow_pallas: bool = True,
+):
+    """``decode_program`` for a model that generates by diffusion over blocks
+    (``diffusion.block_decode``): ``n_steps // block_length`` blocks of
+    ``denoising_steps`` passes and a commit, the cache donated, the block
+    table a traced operand. Operands: the first block's known tokens
+    ``[lanes, B]`` in the last token's place, ``valid`` [lanes], the rows'
+    keys; no penalty ring (``--repeat-penalty`` is refused). Returns (tokens
+    [lanes, n_steps], cache, keys, the two accounts' counts as one vector)."""
+
+    def run(params, cache, known, slot, pads, block_tables, valid, keys):
+        return D.block_decode(
+            params, cache, known, slot, pads, block_tables, valid, keys, config,
+            n_steps=n_steps, temperature=temperature, top_k=top_k, top_p=top_p,
+            allow_pallas=allow_pallas,
+        )
+
+    return tracked_jit(
+        run,
+        name=(
+            f"batch.{kind.label}_decode[n={n_steps},t={temperature},k={top_k},"
+            f"p={top_p},steps={config.denoising_steps},remask={config.remask}]"
+        ),
+        module=f"decode_chunk_{kind.module}",
+        donate_argnums=(1,),
+    )

@@ -166,3 +166,7 @@ PROGRAM_PARTS = (
 # (its products and its gate), so that a device trace tells it from the
 # routed experts beside it (``feed_forward/shared_expert``).
 SHARED_EXPERT = "shared_expert"
+# A scope INSIDE ``sample``: a block-diffusion pass's reveal (the choice of
+# masked slots by rank or confidence and the write of the revealed tokens:
+# ``models/llama/diffusion.reveal``), ``sample/unmask`` in a device trace.
+UNMASK = "unmask"

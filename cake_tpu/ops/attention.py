@@ -45,6 +45,14 @@ def widen_qkv(q, k, v):
     return q.astype(wide), k.astype(wide), v.astype(wide)
 
 
+def block_query_end(positions, block: int):
+    """The last position of the block of ``block`` positions that each of
+    ``positions`` lies in: the query term of the block-causal mask (``k <=
+    block_query_end(q)``), in position space or, for rows whose left pads are
+    whole blocks, in slot space alike (the Pallas chunk kernels')."""
+    return (positions // block + 1) * block - 1
+
+
 def gqa_attention_hm(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -55,6 +63,7 @@ def gqa_attention_hm(
     window_flag: jnp.ndarray | None = None,
     scale: float | None = None,
     softcap: float | None = None,
+    block: int | None = None,
 ) -> jnp.ndarray:
     """Causal grouped-query attention, K/V head-major (the cache layout).
 
@@ -72,6 +81,11 @@ def gqa_attention_hm(
         None = head_dim**-0.5.
       softcap: tanh soft-capping of scores BEFORE masking (Gemma-2
         attn_logit_softcapping).
+      block: STATIC — the BLOCK-CAUSAL mask of a model that generates by
+        diffusion over blocks of this many positions (``block_query_end``):
+        a query sees every key below the END of its own block, so attention
+        is bidirectional inside a block and causal over the earlier ones.
+        None = causal (every other model; nothing is traced for it).
 
     Returns:
       [batch, q_len, n_q_heads, head_dim] in q's dtype.
@@ -93,6 +107,8 @@ def gqa_attention_hm(
     if softcap is not None:
         scores = softcap * jnp.tanh(scores / softcap)
 
+    if block is not None:
+        q_positions = block_query_end(q_positions, block)
     causal = k_positions[:, None, :] <= q_positions[:, :, None]  # [b, q_len, kv_len]
     if window is not None:
         # HF convention: position p attends to [p - window + 1, p].
@@ -126,12 +142,14 @@ def gqa_attention(
     window_flag: jnp.ndarray | None = None,
     scale: float | None = None,
     softcap: float | None = None,
+    block: int | None = None,
 ) -> jnp.ndarray:
     """``gqa_attention_hm`` for fresh seq-major K/V [batch, kv_len, n_kv, head_dim]
     (projection outputs during prefill)."""
     return gqa_attention_hm(
         q, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2), q_positions, k_positions,
         window=window, window_flag=window_flag, scale=scale, softcap=softcap,
+        **({} if block is None else {"block": block}),
     )
 
 

@@ -45,7 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from cake_tpu.models.llama.paged_cache import gather_pages
-from cake_tpu.ops.attention import gqa_attention_hm, widen_qkv
+from cake_tpu.ops.attention import block_query_end, gqa_attention_hm, widen_qkv
 from cake_tpu.ops.pallas.paged_attention import as_pool
 
 _LANES = 128
@@ -79,11 +79,17 @@ def _paged_chunk_kernel(
     page_size,
     window,
     softcap,
+    block=None,
 ):
     bi = pl.program_id(0)
     qi = pl.program_id(2)
     pi = pl.program_id(3)  # LOGICAL page; k_ref/v_ref hold the physical page
     q0 = qs_ref[bi] + qi * block_q  # absolute slot of this q block's row 0
+    # The last key slot any query of this q block may see: its own last slot,
+    # or under the block-causal mask the end of that slot's block.
+    q_hi = q0 + block_q - 1
+    if block is not None:
+        q_hi = block_query_end(q_hi, block)
     k_start = pi * page_size
     length = lens_ref[bi]
     row_first = ks_ref[bi]  # first live key slot (left-padded batch rows)
@@ -98,12 +104,12 @@ def _paged_chunk_kernel(
         first_block = jnp.maximum(first_block, jnp.where(flag, wfirst, 0))
         win_live = ~flag | (k_start + page_size > q0 - window + 1)
     executed = (
-        (k_start <= q0 + block_q - 1) & (k_start < length) & front_live & win_live
+        (k_start <= q_hi) & (k_start < length) & front_live & win_live
     )
     # Largest pi satisfying the causal+length terms of `executed` (the window
     # only prunes the FRONT) — the epilogue runs exactly once, there.
     last_block = jnp.minimum(
-        (q0 + block_q - 1) // page_size,
+        q_hi // page_size,
         jnp.maximum(length - 1, 0) // page_size,
     )
     # Clamp into the visited grid range so _init ALWAYS runs for every q
@@ -137,6 +143,10 @@ def _paged_chunk_kernel(
         # region and need the explicit >= row_first mask. Queries below the
         # row's own pad (suffix windows can start before a warm row's pad)
         # end up all-masked and zero out through m_safe.
+        if block is not None:
+            # Block-causal: the whole of the query's own block. Slot space
+            # serves because a row's left pad is whole blocks (batch.py).
+            qpos = block_query_end(qpos, block)
         mask = (kpos <= qpos) & (kpos >= row_first)
         if window is not None:
             mask &= (kpos > qpos - window) | ~flag
@@ -170,7 +180,7 @@ def _paged_chunk_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("window", "scale", "softcap", "block_q", "interpret"),
+    static_argnames=("window", "scale", "softcap", "block_q", "interpret", "block"),
 )
 def paged_chunk_attention(
     q: jnp.ndarray,
@@ -188,6 +198,7 @@ def paged_chunk_attention(
     softcap: float | None = None,
     block_q: int = 128,
     interpret: bool | None = None,
+    block: int | None = None,
 ) -> jnp.ndarray:
     """Chunk-of-queries GQA attention against the page pool.
 
@@ -216,6 +227,10 @@ def paged_chunk_attention(
         grid: callers slice the table to the epoch's bounded capacity
         (runtime/serving.py) so dead pages cost no grid steps at all.
       window/window_flag/scale/softcap: the dense chunk kernel's knobs.
+      block: STATIC — the block-causal mask (ops/attention.py ``block``), in
+        SLOT space: the caller's ``k_starts`` and ``q_starts`` are multiples
+        of it, so a slot's block is its position's. Pruning keeps the pages
+        up to the end of a q block's last block. None = causal.
 
     Returns [batch, chunk, n_q_heads, head_dim] in q's dtype.
     """
@@ -259,7 +274,10 @@ def paged_chunk_attention(
         last_live = jnp.maximum(
             (lens[bi] + page_size - 1) // page_size - 1, 0
         )
-        last_needed = jnp.minimum((q0 + block_q - 1) // page_size, last_live)
+        q_hi = q0 + block_q - 1
+        if block is not None:
+            q_hi = block_query_end(q_hi, block)
+        last_needed = jnp.minimum(q_hi // page_size, last_live)
         first_needed = ks[bi] // page_size
         if window is not None:
             wfirst = jnp.maximum(0, (q0 - window + 1) // page_size)
@@ -297,6 +315,7 @@ def paged_chunk_attention(
             page_size=page_size,
             window=window,
             softcap=softcap,
+            **({} if block is None else {"block": block}),
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_q, sq, d), q.dtype),
@@ -327,6 +346,7 @@ def paged_chunk_attention_xla(
     scale: float | None = None,
     softcap: float | None = None,
     layer: jnp.ndarray | None = None,
+    block: int | None = None,
 ) -> jnp.ndarray:
     """Gather-based twin: the dense XLA cached-chunk arithmetic over a
     gathered view of each row's pages (of layer ``layer`` where ``k_pages``
@@ -349,4 +369,5 @@ def paged_chunk_attention_xla(
     return gqa_attention_hm(
         q, k, v, q_positions, k_positions,
         window=window, window_flag=window_flag, scale=scale, softcap=softcap,
+        **({} if block is None else {"block": block}),
     )

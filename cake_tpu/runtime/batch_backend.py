@@ -356,7 +356,7 @@ class _PagedBackend:
         from cake_tpu.ops.fuse import fuse_params, resolve_fusion
 
         self.config = config
-        self.kind = programs.KINDS[config.cache_kind]
+        self.kind = programs.kind_of(config)
         self.cache_kind = self.kind.name  # what the cache holds
         self._prefill_program = programs.prefill_program(self.kind)
         self._join_program = functools.partial(programs.join_program, self.kind)
@@ -398,6 +398,8 @@ class _PagedBackend:
         # whose programs count neither reports neither.
         self._accounts = {a.section: a(config) for a in self.kind.accounts_of(config)}
         self._traced = any(a.keeps_traced for a in self._accounts.values())
+        # (the engine adds its own two counts: ``DiffusionAccount.note``)
+        self.diffusion = self._accounts.get("diffusion")
         self._chunk_counters = None
         for section in self._accounts:
             setattr(self, f"{section}_facts",
@@ -721,6 +723,11 @@ class _PagedBackend:
                         allow_pallas=self.allow_pallas,
                     )
             logits.append(out)
+        if self.config.block_length:
+            # nobody samples from a prefill of a model that generates by blocks
+            # (a block's tokens come from its own passes): no logits are put
+            # together, for a spare row or a real one
+            return None, kv
         if spare is not None:
             ran = next(out for out in logits if out is not None)
             logits = [
@@ -732,6 +739,8 @@ class _PagedBackend:
 
     def decode(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s):
         self._kernel_note("decode", int(slot) + n)
+        if self.config.block_length:
+            return _decode_blocks(self, kv, tok, slot, pads, keys, ring, ring_idx, n, s)
         if self._fusions:
             _note_fusion_kernels(self, s)
         # Position grids size to the epoch capacity, not the padded max_seq
@@ -829,8 +838,23 @@ class _PagedBackend:
         programs = self.shapes.programs(lanes)
         cache = self.init_kv(lanes)
         zeros = jnp.zeros((lanes,), jnp.int32)
+        # the decode chunk's token operand: a block's known tokens, all masked,
+        # where the model generates by blocks
+        tok = zeros if not self.config.block_length else jnp.full(
+            (lanes, self.config.block_length), self.config.mask_token_id, jnp.int32)
         keys = jnp.stack([jax.random.PRNGKey(0)] * lanes)
         ring = jnp.zeros((lanes, sampling.repeat_last_n), jnp.int32)
+        if self.shapes.whole_batch:
+            # An epoch's prefill cuts its groups' operands with eager slices,
+            # which compile once a shape; a warmed epoch's groups are all
+            # spare and skipped before the cut. Cut a group a width here, or
+            # a width's first live epoch compiles while it serves
+            # (``compile.untracked``).
+            for width in self.shapes.widths:
+                *cut, groups = self._epoch_groups(
+                    np.zeros((lanes, width), np.int32), zeros, None)
+                for group in (groups[0], groups[-1]):
+                    jax.tree.map(lambda t: t[group], cut)
         for op, rows, slots in programs:
             blank = np.zeros((rows, slots), np.int32)
             if op == "prefill":
@@ -846,7 +870,7 @@ class _PagedBackend:
                 n = self.shapes.decode_steps(n_steps, slots, slots - n_steps) if tail else n_steps
                 self.set_epoch_capacity(slots)
                 cache = self.decode(
-                    cache, zeros, 0, zeros, keys, ring, zeros, n, sampling
+                    cache, tok, 0, zeros, keys, ring, zeros, n, sampling
                 )[1]
         jax.block_until_ready(cache)
         self.set_epoch_capacity(None)
@@ -1013,8 +1037,11 @@ def _recording() -> bool:
 def paged_backend(config: LlamaConfig, params: M.Params, **kw) -> _PagedBackend:
     """The paged backend of this model's cache kind: the one place it is
     chosen (the shapes are chosen beside it, ``ProgramShapes.for_model``).
-    Plain K and V alone has a subclass, for what only it can do."""
-    leaf = PagedLocalBackend if config.cache_kind == CACHE_KV else _PagedBackend
+    Plain K and V alone has a subclass, for what only it can do over a
+    one-token step (a model that generates by diffusion over blocks is the
+    driver's own: ``programs.kind_of``)."""
+    plain = config.cache_kind == CACHE_KV and not config.block_length
+    leaf = PagedLocalBackend if plain else _PagedBackend
     return leaf(config, params, **kw)
 
 
@@ -2289,3 +2316,25 @@ def _count_stepped(self, lanes: int, live: int) -> None:
 
     self.state_decode_lanes += lanes
     self.state_decode_rows += live if steps_live_rows(self.config, self.allow_pallas) else lanes
+
+
+def _decode_blocks(self, kv, known, slot, pads, keys, ring, ring_idx, n, s):
+    """``_PagedBackend.decode`` for a model that generates by diffusion over
+    blocks (``programs.block_decode_program``): ``n`` slots, whole blocks,
+    from ``slot``; ``known`` [lanes, B] the first block's known tokens in the
+    last token's place. The penalty ring passes through untouched. A lane is
+    live while it holds pages, as for every kind that masks lanes."""
+    from cake_tpu.models.llama import programs
+
+    fn = programs.block_decode_program(
+        self.kind, self.config, n, s.temperature, s.top_k, s.top_p, self.allow_pallas
+    )
+    b = int(jnp.shape(known)[0])
+    live = (self.allocator.block_tables[:b] >= 0).any(axis=1)
+    toks, kv, keys, counts = fn(
+        self.params, kv, known, jnp.int32(slot), pads, self._tables(),
+        jnp.asarray(live), keys,
+    )
+    # an expert dispatch is a PASS's rows: lanes x block_length
+    self._chunk_counters = (counts, b * self.config.block_length)
+    return toks, kv, keys, ring, ring_idx

@@ -101,7 +101,7 @@ from cake_tpu.obs import memwatch
 from cake_tpu.obs.jitwatch import tracked_jit
 from cake_tpu.obs.period import PeriodAccount
 from cake_tpu.obs.timeline import PROFILED_TRACK, timeline
-from cake_tpu.runtime import faults
+from cake_tpu.runtime import faults, generation
 from cake_tpu.runtime.admission import (
     DEFAULT_TENANT,
     FairQueue,
@@ -637,6 +637,8 @@ class BatchEngine:
             other_backend=backend is not None
             and getattr(backend, "cache_kind", "kv") != config.cache_kind,
         )
+        if config.block_length and max_batch < 2:
+            refuse_unsupported(config, single_stream=True)
         if backend is None:
             if params is None:
                 # Fail here, not later inside a jitted prefill with an opaque
@@ -752,6 +754,12 @@ class BatchEngine:
         # clears the cache (chains must never outlive their bytes).
         self._epoch_kv_retained = False
         self.decode_chunk_size = max(1, decode_chunk_size)
+        # How a lane's tokens come out of a step (``config.generation``):
+        # everything the step loop does differently for a model that generates
+        # by diffusion over blocks is asked of this object
+        # (runtime/generation.py); a dispatch is then whole blocks.
+        self._gen = generation.of(config)
+        self.decode_chunk_size = self._gen.chunk(self.decode_chunk_size)
         self.max_batch = max(1, max_batch)
         self.admission_window = admission_window
         # > 0 enables batched prompt-lookup speculative decoding: every row
@@ -1359,6 +1367,7 @@ class BatchEngine:
         ids = self.tokenizer.encode(
             encode_dialog(messages, self.config.dialog_template)
         )
+        self._gen.check(sampling)
         # Left-pad bucket rounding can add slots ahead of the prompt; require
         # room for the bucket plus at least one generated token. Same helper
         # as the actual layout (models/llama/batch.py) so they cannot drift.
@@ -1985,7 +1994,7 @@ class BatchEngine:
                 if entry.n:
                     self._emit_chunk(rows, entry, host)
                 else:
-                    self._emit_first(rows, entry, int(host[entry.row]))
+                    self._emit_first(rows, entry, self._gen.first_token(host, entry))
 
     def _emit_chunk(self, rows: list, entry: "_Unread", toks_np) -> None:
         """A decode chunk's tokens reach their streams: the rows are the
@@ -2000,9 +2009,13 @@ class BatchEngine:
         self._t_chunk_read = entry.t_read
         self._step_budget.observe_chunk(dt_chunk)
         n = entry.n
+        # (a first block's known prompt tokens are nobody's to stream)
+        known = entry.known
         consumed = {
-            lane: row.peek_consumed(toks_np[lane]) for lane, row in entry.rows
+            lane: row.peek_consumed(toks_np[lane][known.get(lane, 0):])
+            for lane, row in entry.rows
         }
+        self._gen.note(self.backend, consumed, known)
         # Hardware ledger: the chunk computed B x n positions — consumed
         # ones are decode goodput, live-but-unconsumed tails are convoy,
         # dead lanes are pad. Noted BEFORE the pushes for the same
@@ -2017,9 +2030,9 @@ class BatchEngine:
             # chunk's decode share (and its unconsumed-tail convoy — the
             # very number the convoy meter exists for) must already be on
             # the row by then.
-            row.inflight -= n
+            row.inflight -= n - known.get(lane, 0)
             row.account_decode(dt_chunk, n, consumed[lane])
-            for t in toks_np[lane]:
+            for t in toks_np[lane][known.get(lane, 0):]:
                 row.push(int(t))
                 if row.done:
                     break
@@ -2027,8 +2040,10 @@ class BatchEngine:
                 rows[lane] = None
         self._release_finished(rows)
 
-    def _emit_first(self, rows: list, entry: "_Unread", first: int) -> None:
-        """A joiner's first token, read with its boundary's other values."""
+    def _emit_first(self, rows: list, entry: "_Unread", first: int | None) -> None:
+        """A joiner's first token, read with its boundary's other values
+        (None: a block-diffusion joiner has none, its join is accounted
+        alone and its first tokens come with its first block)."""
         (lane, row), = entry.rows
         dt_join = entry.t_read - entry.t0
         row.account_join(dt_join)
@@ -2038,6 +2053,8 @@ class BatchEngine:
             dt_join, 1, entry.width,
             min(len(row.req.prompt_ids), entry.width),
         )
+        if first is None:
+            return
         row.inflight -= 1
         row.push(first)
         if row.done and rows[lane] is row:
@@ -2718,7 +2735,7 @@ class BatchEngine:
         else:
             reqs = list(batch) + [None] * (B - len(batch))
             ids_list = [
-                r.prompt_ids if r is not None else [self.config.bos_token_id]
+                self._gen.prefilled(r) if r is not None else self._gen.dead_row()
                 for r in reqs
             ]
             rows.extend(
@@ -2735,7 +2752,7 @@ class BatchEngine:
                 row.open_span(slot=None)
         from cake_tpu.runtime.batch_backend import BackendWorkerError
 
-        tokens, pads, bucket = layout_prompts(ids_list, self.max_seq_len)
+        tokens, pads, bucket = layout_prompts(ids_list, self.max_seq_len, self._gen.block)
         # ONE bounded attention capacity for the whole epoch (paged backends
         # only; why, in their class docstring): enough slots for every
         # admitted row's full token budget. ``cap`` (the epoch's slot
@@ -2750,7 +2767,7 @@ class BatchEngine:
                 [max(1, sp.row.req.max_tokens - sp.row.n)
                  for sp in seed_spills]
                 if seed_spills
-                else [r.max_tokens for r in batch]
+                else [self._gen.slots_for(r) for r in batch]
             )
             reach = bucket + max(
                 min(t, self.max_seq_len - bucket) for t in budgets
@@ -2882,6 +2899,9 @@ class BatchEngine:
             for sp in seed_spills:
                 sp.row.n_at_restore = sp.row.n
                 self._note_restore(sp.row)
+        elif self._gen.block:
+            # No first token: a block's tokens come from its own passes.
+            first, keys = self._gen.seat(reqs, rows)
         else:
             # The ``prefill`` span above closed after the ENQUEUE: the wait
             # for the device is here, where ``first_sample`` reads the
@@ -2940,7 +2960,7 @@ class BatchEngine:
         self._serial_why = "segment-start"
         self._t_chunk_read = 0.0
         ended = "capacity"  # the loop's own end: the slot reached the cap
-        while slot < cap - 1:
+        while self.shapes.more(cap, slot):
             if self._stop:
                 # stop() must not wait out a long epoch: close every live
                 # stream now (consumers see the error, not a hang).
@@ -3122,7 +3142,8 @@ class BatchEngine:
                         period.update(dispatched=True, live=live, order="spec", steps=self.speculative_k + 1)
                         continue
                 if self._alloc is not None and not self._extend_pages(
-                    rows, slot, n, spill_ctx=(keys, ring_j, ring_idx_j)
+                    rows, slot, n,
+                    spill_ctx=self._gen.spill_ctx(keys, ring_j, ring_idx_j),
                 ):
                     ended = "pages"
                     break  # every remaining row was truncated or spilled
@@ -3141,7 +3162,7 @@ class BatchEngine:
                         args={
                             "lanes": B, "capacity": int(cap),
                             "slot": int(slot), "n": int(n), "live": live,
-                            "ahead": ahead,
+                            "ahead": ahead, **self._gen.phase_args(n),
                         },
                     ):
                         t0 = time.perf_counter()
@@ -3170,16 +3191,16 @@ class BatchEngine:
                         cached = sum(
                             len(row.history) - 1 + row.inflight
                             for _, row in chunk.rows
-                        )
+                        ) + self._gen.cached_more(chunk)
                         for lane, row in chunk.rows:
                             # What the host can count does not lag: a
                             # budget that ends inside this chunk frees the
                             # lane at the next boundary, read or not. The
                             # row takes its last tokens from ``_unread``.
-                            row.inflight += n
+                            row.inflight += n - chunk.known.get(lane, 0)
                             if row.n + row.inflight >= row.req.max_tokens:
                                 rows[lane] = None
-                        tok = toks[:, -1]
+                        tok = self._gen.next_operand(toks, B)
                         slot += n
                         if not look or self._unread[0] is not chunk:
                             self._read(self._unread[0])
@@ -4017,7 +4038,7 @@ class BatchEngine:
             # truncates below what waiting would deliver. A joiner gets
             # cap - slot tokens: 1 at the join + cap - 1 - slot decoded.
             solo_budget = min(
-                req.max_tokens,
+                self._gen.slots_for(req),
                 self.max_seq_len
                 - self.shapes.prompt_width(n_ids, self.max_seq_len),
             )
@@ -4149,7 +4170,7 @@ class BatchEngine:
     ):
         from cake_tpu.models.llama.batch import _first_sample_fn, seed_rings
 
-        ids = req.prompt_ids
+        ids = self._gen.prefilled(req)
         with self._phase(
             "join", rid=req.rid,
             args={
@@ -4188,31 +4209,43 @@ class BatchEngine:
                     self._pool_counter()
                     return tok, kv, keys, ring_j, ring_idx_j
             logits, kv, W = self._row_prefill(kv, lane, ids, pad, slot, fork)
-
-            # Same first-token arithmetic as every entry point (batch.py's
-            # ``first_sample``), left ON THE DEVICE: the sample, the ring's
-            # update and the lane's writes are programs enqueued behind the
-            # prefill, and the token itself is read with this boundary's
-            # other values (``_settle``), after the next decode chunk has
-            # been enqueued behind them.
-            row_ring, row_ring_idx = seed_rings([ids], s.repeat_last_n)
-            key0 = jax.random.PRNGKey(req.sampling.seed)
-            first, key_next = _first_sample_fn(
-                s.temperature, s.top_k, s.top_p, s.repeat_penalty, True
-            )(logits, jnp.asarray(row_ring), key0[None])
-            tok, keys, ring_j, ring_idx_j = _seat_joiner(
-                tok, keys, ring_j, ring_idx_j, lane, first, key_next,
-                row_ring, row_ring_idx,
-            )
+            if self._gen.block:
+                # No first token: the joiner's first block and its key.
+                tok, keys = _set_lane(
+                    (tok, keys), lane,
+                    (self._gen.known_block(req), jax.random.PRNGKey(req.sampling.seed)),
+                )
+            else:
+                # Same first-token arithmetic as every entry point (batch.py's
+                # ``first_sample``), left ON THE DEVICE: the sample, the ring's
+                # update and the lane's writes are programs enqueued behind the
+                # prefill, and the token itself is read with this boundary's
+                # other values (``_settle``), after the next decode chunk has
+                # been enqueued behind them.
+                row_ring, row_ring_idx = seed_rings([ids], s.repeat_last_n)
+                key0 = jax.random.PRNGKey(req.sampling.seed)
+                first, key_next = _first_sample_fn(
+                    s.temperature, s.top_k, s.top_p, s.repeat_penalty, True
+                )(logits, jnp.asarray(row_ring), key0[None])
+                tok, keys, ring_j, ring_idx_j = _seat_joiner(
+                    tok, keys, ring_j, ring_idx_j, lane, first, key_next,
+                    row_ring, row_ring_idx,
+                )
         self.periods.note_join(join.seconds)
-        row.inflight = 1
+        counters = self._take_counters()
+        if self._gen.block:
+            # nothing of the row's is in flight; the entry carries the join's
+            # account (its counts, ready when the join is: what is read)
+            row.known = self._gen.tail(req)
+            first = counters[0] if counters is not None else tok
+        else:
+            row.inflight = 1
         self._unread.append(_Unread(
-            first, [(lane, row)], slot, 0, t_join, W,
-            counters=self._take_counters(),
+            first, [(lane, row)], slot, 0, t_join, W, counters=counters,
         ))
         # A budget of one token ends with the token in flight: the lane
         # never becomes the row's (its pages go at the next release).
-        rows[lane] = row if req.max_tokens > 1 else None
+        rows[lane] = row if self._gen.keeps_lane(req) else None
         self._record_admissions([req], "joined", lane=lane, slot=slot)
         metrics.registry.counter(
             "cake_engine_joins_total",
@@ -4246,7 +4279,9 @@ class BatchEngine:
 
     def _set_pads(self, pads_j, group: list, slot: int):
         """The joiners' pads into their lanes' rows of ``pads_j``."""
-        pads = [slot - len(req.prompt_ids) for _, req in group]
+        pads = [slot - len(self._gen.prefilled(req)) for _, req in group]
+        # (whole blocks: what lets the block-causal mask compare slots)
+        assert not any(p % (self._gen.block or 1) for p in pads), pads
         if len(group) == 1:
             return _set_lane((pads_j,), group[0][0], (pads[0],))[0]
         lanes = self._row_lanes(group, dead=int(pads_j.shape[0]))
@@ -4386,7 +4421,7 @@ class BatchEngine:
         the engine's."""
         out = dict(self.stats)
         out.update(self.periods.snapshot(now))
-        for key in ("cache", "moe", "sparse", "state"):
+        for key in ("cache", "moe", "sparse", "state", "diffusion"):
             facts = getattr(self.backend, f"{key}_facts", None)
             if facts is not None:
                 out[key] = facts()
@@ -4501,6 +4536,9 @@ class _Unread:
     t_read: float = 0.0
     counters: jax.Array | None = None
     row: int = 0  # a joiner's row of ``value``: a group's joiners share one
+    # lane -> prompt tokens its FIRST block carries unmasked (a block-diffusion
+    # chunk's; they lead the lane's tokens and are not streamed)
+    known: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -4560,6 +4598,9 @@ class _RowState:
         # its next chunk can never map on this pool, so re-parking would
         # livelock (the respill doom check in _spill_lane).
         self.n_at_restore = -1
+        # Prompt tokens this row's first block carries unmasked, until the
+        # chunk that holds that block is enqueued (a block-diffusion row).
+        self.known = 0
 
     # ---- lane-track timeline span (admission -> finish) ------------------
 

@@ -119,6 +119,16 @@ _DEAR_DEAD_SLOTS = 512
 
 
 def _dear_programs(config) -> bool:
+    """State layers beside routed experts (above), or plain K and V under
+    generation by diffusion over blocks (``sdar_moe``): a decode program of
+    that kind holds two pass bodies (a denoising pass and the commit) of a
+    layer scan with the grouped experts' sort and three grouped products, a
+    join reads whole expert layers, and a dead lane takes no expert's rows:
+    six widths, a row a program, every epoch ``max_batch`` wide, the tails
+    warmed. Its joiners go one a program (no window of rows with a lane each
+    is written for it)."""
+    if getattr(config, "block_length", 0):
+        return True
     return config.cache_kind == CACHE_KV_STATE and SPARSE in config.ff_kinds
 
 
@@ -197,6 +207,9 @@ class ProgramShapes:
     join_rows: int = 1
     join_widths: tuple[int, ...] = ()
     dead_slots: int = 0
+    # A model that generates by diffusion over blocks: slots a block (every
+    # width, capacity and decode dispatch is whole blocks); 0: one token a step.
+    block: int = 0
 
     @classmethod
     def for_model(cls, config, page_size: int = 0, pages_per_seq: int = 0):
@@ -208,10 +221,12 @@ class ProgramShapes:
         ``olmo-hybrid-7b-chat-closed``'s, and at 64 lanes
         ``pangu-ultra-ep16-chat-closed``'s); at any other geometry they are
         as closed."""
-        if config.cache_kind == CACHE_KV:
+        block = getattr(config, "block_length", 0)  # slots a block; 0: a token a step
+        if config.cache_kind == CACHE_KV and not block:
             return cls()
         slots = page_size * pages_per_seq
         dear = _dear_programs(config)
+        grouped = dear and not block  # a step's joiners as one program
         shares = _DEAR_WIDTH_64THS if dear else (
             _WIDTH_64THS_BY_KIND.get(config.cache_kind, _WIDTH_64THS))
         def in_slots(shares):
@@ -224,9 +239,10 @@ class ProgramShapes:
             prefill_tokens=1 if dear else _prefill_tokens(config),
             one_row_prefill_is_join=dear or config.cache_kind == CACHE_LATENT_INDEX,
             whole_batch=dear,
-            join_rows=_DEAR_JOIN_ROWS if dear else 1,
-            join_widths=in_slots(_DEAR_JOIN_64THS) if dear else (),
-            dead_slots=_DEAR_DEAD_SLOTS if dear else 0,
+            join_rows=_DEAR_JOIN_ROWS if grouped else 1,
+            join_widths=in_slots(_DEAR_JOIN_64THS) if grouped else (),
+            dead_slots=_DEAR_DEAD_SLOTS if grouped else 0,
+            block=block,
         )
 
     def lanes(self, n_seed: int, max_batch: int) -> int:
@@ -317,8 +333,16 @@ class ProgramShapes:
         return sorted(out)  # by each program's first row
 
     def decode_steps(self, chunk: int, cap: int, slot: int) -> int:
-        """A chunk, or what is left under the epoch's slot ceiling."""
+        """A chunk, or what is left under the epoch's slot ceiling; whole
+        blocks from ``slot`` on, the first unwritten slot, where the model
+        generates by blocks (0 when no block is left: ``more``)."""
+        if self.block:
+            return min(chunk, cap - slot) // self.block * self.block
         return min(chunk, cap - 1 - slot)
+
+    def more(self, cap: int, slot: int) -> bool:
+        """Whether a lane at ``slot`` can take another step under ``cap``."""
+        return slot + self.block <= cap if self.block else slot < cap - 1
 
     def prefill_group(self, rows: int, width: int) -> int:
         """Rows (a power of two) one prefill program takes of an epoch's."""

@@ -61,6 +61,16 @@ every phase runs in a child that has exited before the next one starts.
            step's 64 and a join's 1,024 rows, and the cell's decode chunk,
            join and three-row group compiled: no copy of the pool or of the
            float32 state
+  M        a model that generates by diffusion over blocks of 4 over 128
+           softmax-routed experts a layer, all held, at
+           sdar-30b-a3b-chat-closed's geometry: the paged chunk kernel at 4
+           queries a lane over 64 lanes under the block-causal mask against
+           its XLA twin and alone on the clock, a sparse layer at 128 held of
+           128 at a pass's 256 rows and a join's 1,024, two layers of the cut
+           at the published widths through a prefill and a dispatch of two
+           blocks for real (kernels' programs against their twins'), and the
+           cell's decode dispatch and join compiled: no copy of the pool, no
+           weight laid out again a pass
   D        four chips: the phase-A server under --tp 4 and as a four-stage
            pipeline, with per-device memory (skipped below four devices)
 
@@ -88,7 +98,7 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, ".chip_smoke")  # listed in .gitignore
-PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "O", "L", "S", "F", "J", "Q", "D")
+PHASES = ("probe", "native", "setup", "A", "B", "Bf", "C", "P", "H", "O", "L", "S", "F", "J", "Q", "M", "D")
 
 MISTRAL_7B = dict(  # Mistral-7B-v0.1 config.json, depth aside
     model_type="mistral", hidden_size=4096, intermediate_size=14336,
@@ -113,6 +123,7 @@ OLMO_HYBRID_D16 = _benchmark_model("olmo-hybrid-7b-d16")
 DEEPSEEK_V32_EP16 = _benchmark_model("deepseek-v3.2-exp-ep16-d5")
 LFM2_D16 = _benchmark_model("lfm2-8b-a1b-d16")
 QWEN3_NEXT_EP4 = _benchmark_model("qwen3-next-80b-a3b-ep4-d12")
+SDAR_PP8 = _benchmark_model("sdar-30b-a3b-chat-pp8-d6")
 PRESETS = {
     # Prompt lengths are in characters: without a tokenizer file the byte
     # tokenizer serves, one token a byte.
@@ -174,6 +185,14 @@ PRESETS = {
             model=dict(QWEN3_NEXT_EP4), pages=1024, lanes=64, table_pages=32,
             steps=8, join_width=512, prompts=(300, 190), block_lanes=64,
             expert_tokens=(64, 1024),
+        ),
+        # sdar-30b-a3b-chat-closed (bench/configs/sdar-30b-a3b-chat-pp8-d6.json):
+        # a dispatch of two blocks at the cell's 64 lanes, the experts at a
+        # pass's rows (64 x 4) and a join's
+        sdar=dict(
+            model=dict(SDAR_PP8), pages=1024, lanes=64, table_pages=32,
+            steps=8, join_width=512, prompts=(301, 190, 450), block_lanes=64,
+            block_layers=2, cached=450, expert_tokens=(256, 1024),
         ),
     ),
     # The rehearsal: same family and head layout rules (tp 4 divides the
@@ -279,6 +298,17 @@ PRESETS = {
             pages=16, lanes=4, table_pages=2, steps=4, join_width=64,
             prompts=(37, 21), block_lanes=4,
             expert_tokens=(8, 32), timed=dict(calls=2, repeats=1),
+        ),
+        sdar=dict(
+            model=dict(
+                SDAR_PP8, hidden_size=128, moe_intermediate_size=64,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+                num_experts=8, num_experts_per_tok=2, vocab_size=512,
+                mask_token_id=300, bos_token_id=3, eos_token_id=4, pad_token_id=3,
+            ),
+            pages=16, lanes=4, table_pages=2, steps=8, join_width=64,
+            prompts=(37, 21), block_lanes=4, block_layers=2, cached=40,
+            expert_tokens=(16, 32), timed=dict(calls=2, repeats=1),
         ),
     ),
 }
@@ -789,6 +819,230 @@ def child_qwen3next(preset: dict) -> None:
         programs=("decode", "join", "join_rows"))
 
 
+def child_sdar(preset: dict) -> None:
+    """Generation by diffusion over blocks at the benchmark cell's widths
+    (sdar-30b-a3b-chat-closed): the paged chunk kernel at ``B`` queries a
+    lane under the block-causal mask against its gather twin, and alone on
+    the clock (microseconds a call, twelve calls a program over the pool's
+    layers); one sparse layer's 128 held of 128 at a pass's rows and a
+    join's; ``block_layers`` layers of the cut through an epoch's prefill
+    and a dispatch of two blocks FOR REAL, by the kernels' programs and by
+    their twins'; then the cell's decode dispatch and a join compiled for the
+    device this process holds, from shapes alone: the pool neither sliced nor
+    copied (ten passes write the same slots), no stacked weight written out
+    again a pass (``pool_audit.weight_ops_in_hlo``)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cake_tpu.models.llama import model as M
+    from cake_tpu.models.llama import pool_audit
+    from cake_tpu.models.llama.batch import block_pass_attention
+    from cake_tpu.models.llama.config import LlamaConfig
+    from cake_tpu.models.llama.generator import SamplingConfig
+    from cake_tpu.ops.pallas.check import timed_expert_layer
+    from cake_tpu.ops.pallas.paged_prefill import (
+        paged_chunk_attention, paged_chunk_attention_xla,
+    )
+    from cake_tpu.runtime.batch_backend import paged_backend
+    from cake_tpu.utils.device import describe_devices, setup_compile_cache
+
+    setup_compile_cache()
+    emit({"kind": "summary", **describe_devices()})
+    g = preset["sdar"]
+    on_chip = jax.default_backend() != "cpu"
+    dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[preset["dtype"]]
+    page, lanes = preset["page_size"], g["block_lanes"]
+    # (as the cell serves it: the one rule whose states the reference rebuilds)
+    config = dataclasses.replace(
+        LlamaConfig.from_hf_dict(g["model"]), attention_impl="pallas", remask="sequential")
+    width, n_q, n_kv, hd = (config.block_length, config.num_attention_heads,
+                            config.num_key_value_heads, config.head_dim)
+
+    # (1) the chunk kernel at a pass's shape: every lane ``cached`` tokens
+    # behind a left pad of whole blocks, the block's own slots the last
+    rng = np.random.default_rng(0)
+    table_pages, layers = g["table_pages"], 4
+    per_lane = -(-(g["cached"] + 64) // page)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)  # noqa: E731
+    k_pool, v_pool = (draw(layers, lanes * per_lane, n_kv, page, hd) for _ in range(2))
+    q = draw(lanes, width, n_q, hd)
+    tables = np.full((lanes, table_pages), -1, np.int32)
+    tables[:, :per_lane] = np.arange(lanes * per_lane).reshape(lanes, per_lane)
+    pads = jnp.asarray(rng.integers(0, 8, lanes) * width, jnp.int32)
+    slot = g["cached"] // width * width
+    starts = jnp.full((lanes,), slot, jnp.int32)
+    lengths = starts + width
+    grid = jnp.arange(table_pages * page, dtype=jnp.int32)[None, :] - pads[:, None]
+    k_pos = jnp.where(grid < 0, 2**30, grid)
+    q_pos = slot + jnp.arange(width, dtype=jnp.int32)[None, :] - pads[:, None]
+    # a pass's call: the block's queries as more heads of the paged DECODE kernel
+    kernel = jax.jit(lambda q, li: block_pass_attention(
+        q, k_pool, v_pool, lengths, jnp.asarray(tables), pads, layer=li,
+        interpret=not on_chip))
+    # the chunk kernel itself under the block-causal mask, at the same shape
+    chunk = np.asarray(jax.jit(lambda q, li: paged_chunk_attention(
+        q, k_pool, v_pool, starts, lengths, pads, jnp.asarray(tables), layer=li,
+        block=width, interpret=not on_chip))(q, jnp.int32(1)), np.float32)
+    twin = jax.jit(lambda q, li: paged_chunk_attention_xla(
+        q, k_pool, v_pool, q_pos, k_pos, jnp.asarray(tables), layer=li, block=width))
+    got = np.asarray(kernel(q, jnp.int32(1)), np.float32)
+    want = np.asarray(twin(q, jnp.int32(1)), np.float32)
+    causal = np.asarray(jax.jit(lambda q, li: paged_chunk_attention_xla(
+        q, k_pool, v_pool, q_pos, k_pos, jnp.asarray(tables), layer=li))(q, jnp.int32(1)), np.float32)
+
+    @jax.jit
+    def chain(q):
+        def call(i, q):
+            out = block_pass_attention(
+                q, k_pool, v_pool, lengths, jnp.asarray(tables), pads,
+                layer=i % layers, interpret=not on_chip)
+            return (q + out * 1e-3).astype(q.dtype)
+        return jax.lax.fori_loop(0, 12, call, q)
+
+    jax.block_until_ready(chain(q))
+    t0 = time.perf_counter()
+    for _ in range(5):
+        out = chain(q)
+    jax.block_until_ready(out)
+    emit({"kind": "attention", "lanes": lanes, "cached": g["cached"], "queries": width,
+          "err_in_spreads": float(np.abs(got - want).max() / want.std()),
+          "chunk_err_in_spreads": float(np.abs(chunk - want).max() / want.std()),
+          "causal_differs_by": float(np.abs(causal - want).max() / want.std()),
+          "us_a_call": round(1e6 * (time.perf_counter() - t0) / (5 * 12), 1),
+          "kv_bytes": int(2 * lanes * g["cached"] * n_kv * hd * jnp.dtype(dtype).itemsize)})
+    del k_pool, v_pool, kernel, twin, chain
+
+    # (2) a sparse layer's experts alone on the clock
+    emit({"kind": "experts", "rows": timed_expert_layer(
+        config.hidden_size, config.moe_intermediate_size,
+        config.num_local_experts, config.n_router_experts,
+        config.num_experts_per_tok, tuple(g["expert_tokens"]),
+        dtype=preset["dtype"], dead_every=3, **g.get("timed", {}),
+    )})
+
+    # (3) a prefill and a dispatch of two blocks for real, kernels and twins
+    cut = dataclasses.replace(config, num_hidden_layers=g["block_layers"])
+    params = M.init_params(cut, jax.random.PRNGKey(0), dtype)
+    # (the special ids' head rows zero, as the benchmark's checkpoint has them)
+    params["lm_head"] = params["lm_head"].at[:, cut.mask_token_id].set(0)
+    rows = [rng.integers(8, min(cut.vocab_size, 100_000), n).tolist() for n in g["prompts"]]
+    heads = [p[: len(p) // width * width] for p in rows]
+    bucket = -(-max(len(h) for h in heads) // page) * page
+    greedy = SamplingConfig(temperature=0.0, repeat_penalty=1.0)
+    steps = g["steps"]
+
+    def served(allow_pallas: bool):
+        t0 = time.perf_counter()
+        be = paged_backend(
+            cut, params, max_seq_len=bucket + page, cache_dtype=dtype, page_size=page,
+            max_pages=lanes * (bucket // page + 1), allow_pallas=allow_pallas, lanes=lanes,
+        )
+        cache = be.init_kv(lanes)
+        tokens = np.zeros((lanes, bucket), np.int32)
+        pads = np.full((lanes,), bucket, np.int32)
+        known = np.full((lanes, width), cut.mask_token_id, np.int32)
+        for r, (p, h) in enumerate(zip(rows, heads)):
+            pads[r] = bucket - len(h)
+            tokens[r, pads[r]:] = h
+            known[r, : len(p) - len(h)] = p[len(h):]
+            be.allocator.map_range(r, int(pads[r]), bucket + steps)
+        _, cache = be.prefill(tokens, cache, jnp.asarray(pads))
+        keys = jnp.stack([jax.random.PRNGKey(0)] * lanes)
+        toks, cache, *_ = be.decode(
+            cache, jnp.asarray(known), bucket, jnp.asarray(pads), keys,
+            jnp.zeros((lanes, 0), jnp.int32), jnp.zeros((lanes,), jnp.int32),
+            steps, greedy,
+        )
+        counts = be.absorb_chunk_counters(be.take_chunk_counters())
+        toks = np.asarray(toks)[:len(rows)]
+        first_s = round(time.perf_counter() - t0, 1)
+        be.allocator.reset(batch=lanes)  # a second dispatch, dead lanes all: its wall alone
+        t0 = time.perf_counter()
+        jax.block_until_ready(be.decode(
+            cache, jnp.asarray(known), bucket, jnp.asarray(pads), keys,
+            jnp.zeros((lanes, 0), jnp.int32), jnp.zeros((lanes,), jnp.int32),
+            steps, greedy)[0])
+        be.take_chunk_counters()
+        return toks, counts, {**be.diffusion_facts()}, first_s, round(
+            1e3 * (time.perf_counter() - t0), 1)
+
+    kernel_toks, counts, facts, kernel_s, idle_ms = served(on_chip)
+    twin_toks, *_ = served(False)
+    tails = [len(p) - len(h) for p, h in zip(rows, heads)]
+    # The benchmark's plain reference (float32, full forward passes over the
+    # judged STATES, nothing of this program) over the same weights, on what
+    # each program served: the judge's reading (``bench/reference.py``).
+    from bench.manifest import architecture
+
+    hf = cut.to_hf_dict()
+    hf.update(pad_token_id=hf["bos_token_id"])
+    arch = architecture(REPO, hf)
+    layers = params["layers"]
+    names = {"wq": "self_attn.q_proj", "wk": "self_attn.k_proj", "wv": "self_attn.v_proj",
+             "wo": "self_attn.o_proj", "router": "mlp.gate"}
+    experts = {"w_gate": "gate_proj", "w_up": "up_proj", "w_down": "down_proj"}
+    norms = {"ln_attn": "input_layernorm", "ln_mlp": "post_attention_layernorm",
+             "q_norm": "self_attn.q_norm", "k_norm": "self_attn.k_norm"}
+
+    def reader(name: str):
+        """The tree's tensors under HF's names (a plain model's: [in, out]
+        matrices transposed, fused projections split by the loader's rule)."""
+        if name == "model.embed_tokens.weight":
+            return params["embed"]
+        if name == "model.norm.weight":
+            return params["ln_f"]
+        if name == "lm_head.weight":
+            return params["lm_head"].T
+        _, _, i, rest = name.split(".", 3)
+        rest = rest.removesuffix(".weight")
+        for key, hf_name in norms.items():
+            if rest == hf_name:
+                return layers[key][int(i)]
+        for key, hf_name in names.items():
+            if rest == hf_name:
+                return layers[key][int(i)].T
+        _, _, e, which = rest.split(".")
+        key = next(k for k, v in experts.items() if v == which)
+        return layers[key][int(i), int(e)].T
+
+    def deficit(toks):
+        served_ids = [toks[r, t:].tolist() for r, t in enumerate(tails)]
+        got = arch.state_logits(
+            reader, hf, [p + s for p, s in zip(rows, served_ids)], [len(p) - 1 for p in rows])
+        each = np.concatenate([arch.deficits(g, s) for g, s in zip(got, served_ids)])
+        return float(each.max()), float(each.mean())
+
+    missing = [k for k in ("wq", "wk", "wv", "wo") if k not in layers]
+    kernel_deficit = twin_deficit = (None, None)
+    if not missing:  # (a fused tree would need the loader's split: not a plain one's)
+        kernel_deficit, twin_deficit = deficit(kernel_toks), deficit(twin_toks)
+    emit({"kind": "block", "rows": len(rows), "lanes": lanes, "layers": g["block_layers"],
+          "tokens_agree": float(np.mean([
+              (kernel_toks[r, t:] == twin_toks[r, t:]).mean() for r, t in enumerate(tails)])),
+          "known_kept": all(kernel_toks[r, :t].tolist() == rows[r][len(heads[r]):]
+                            for r, t in enumerate(tails)),
+          "unmasked": bool((kernel_toks != cut.mask_token_id).all()),
+          "kernel_deficit": kernel_deficit, "twin_deficit": twin_deficit,
+          "counts": counts, "diffusion": {k: facts[k] for k in (
+              "blocks", "passes", "commit_passes", "lane_passes", "revealed")},
+          "top_k": cut.num_experts_per_tok, "first_pass_s": kernel_s,
+          "dead_dispatch_ms": idle_ms})
+    del params
+
+    # (4) the cell's programs compiled from shapes
+    reports = pool_audit.audit_programs(
+        config, n_pages=g["pages"], page_size=page, lanes=g["lanes"],
+        table_pages=g["table_pages"], n_steps=steps, width=g["join_width"],
+        dtype=dtype, allow_pallas=on_chip, only=("decode", "join"),
+        watch=("wq", "wk", "wv", "wo", "w_qkv", "w_gate", "w_up", "w_down", "router"),
+    )
+    for name, report in reports.items():
+        emit({"kind": "program", "program": name, **report})
+
+
 def _sparse_hybrid_child(preset: dict, g: dict, period_hf: dict, *, sparse_layers: int,
                          programs: tuple[str, ...] = ("decode", "join")) -> None:
     """A hybrid stack with routed experts at a benchmark cell's widths: one
@@ -1030,7 +1284,7 @@ def child_joins(preset: dict) -> None:
 
 
 CHILDREN = {"probe": child_probe, "joins": child_joins, "sparse": child_sparse, "setup": child_setup,
-            "lfm2": child_lfm2, "qwen3next": child_qwen3next,
+            "lfm2": child_lfm2, "qwen3next": child_qwen3next, "sdar": child_sdar,
             "kernels": child_kernels, "pool": child_pool,
             "hybrid": child_hybrid, "olmo": child_olmo,
             "latent": child_latent}
@@ -1829,6 +2083,78 @@ def _phase_sparse_hybrid(phase: str, key: str, args, preset) -> dict:
     return out
 
 
+def phase_sdar(args, preset) -> dict:
+    """Phase M: generation by diffusion over blocks at the benchmark cell's
+    geometry (sdar-30b-a3b-chat-closed): ``child_sdar``'s four readings."""
+    records = run_child("sdar", args, timeout=2400)
+    problems = []
+    a = next(r for r in records if r["kind"] == "attention")
+    say(f"phase=M the paged chunk kernel at {a['queries']} queries a lane, {a['lanes']} lanes x "
+        f"{a['cached']} cached tokens, block-causal: against its twin "
+        f"err_in_spreads={a['err_in_spreads']:.3g}, the chunk kernel's "
+        f"{a['chunk_err_in_spreads']:.3g} (the causal mask differs by "
+        f"{a['causal_differs_by']:.3g}); us_a_call={a['us_a_call']} kv_bytes={a['kv_bytes']}")
+    if not max(a["err_in_spreads"], a["chunk_err_in_spreads"]) <= 0.08 or not (
+            a["causal_differs_by"] > 0.2):
+        problems.append(
+            f"the chunk kernel under the block-causal mask differs from its twin by "
+            f"{a['err_in_spreads']:.3g} of a spread (from the causal mask by "
+            f"{a['causal_differs_by']:.3g})")
+    where = "" if not args.rehearse_cpu else " (cpu rehearsal: no device time)"
+    problems += say_experts("M", records, where)
+    b = next(r for r in records if r["kind"] == "block")
+    c, d = b["counts"], b["diffusion"]
+    say(f"phase=M {b['layers']} layers at the cell's widths, {b['rows']} rows on {b['lanes']} "
+        f"lanes, a dispatch of two blocks: kernels against twins tokens_agree="
+        f"{b['tokens_agree']:.3f} known_kept={b['known_kept']} unmasked={b['unmasked']}; "
+        f"against the plain float32 reference's states (worst, mean deficit) kernels "
+        f"{b['kernel_deficit']} twins {b['twin_deficit']}; "
+        f"passes={d['passes']} commit_passes={d['commit_passes']} blocks={d['blocks']} "
+        f"lane_passes={d['lane_passes']} revealed={d['revealed']} held={c['held']} "
+        f"touched={c['touched']} first_pass_s={b['first_pass_s']} "
+        f"dead_dispatch_ms={b['dead_dispatch_ms']}")
+    passes = 2 * (preset["sdar"]["model"].get("denoising_steps", 4) + 1)
+    want = passes * b["layers"] * b["rows"] * 4 * b["top_k"]
+    if c["held"] != want or c["routed"] != want:
+        problems.append(f"the dispatch counted {c['held']} held assignments of {c['routed']} "
+                        f"routed; {want} live ones were made (dead lanes take none)")
+    if (d["passes"], d["commit_passes"], d["blocks"], d["lane_passes"]) != (
+            passes, 2, 2 * b["rows"], passes * b["rows"]):
+        problems.append(f"the dispatch's account is {d}")
+    # Two programs in the served type need not choose the same token where two
+    # logits lie within their rounding; each must lie near the float32
+    # reference's best (a fault in the mask, the fold of a block's queries into
+    # heads or the commit moves a position by whole spreads).
+    if not (b["known_kept"] and b["unmasked"]):
+        problems.append(f"known_kept={b['known_kept']} unmasked={b['unmasked']}")
+    for who in ("kernel_deficit", "twin_deficit"):
+        if b[who][0] is not None and not b[who][0] <= 0.5:
+            problems.append(f"{who}: {b[who][0]:.3g} of a logit spread from the plain "
+                            "reference at its worst position")
+    out = {"tokens_agree": b["tokens_agree"], "chunk_kernel_us": a["us_a_call"]}
+    for r in (r for r in records if r["kind"] == "program"):
+        moved = r["scans"] + ([] if args.rehearse_cpu else r["pool_ops"])
+        say(f"phase=M program={r['program']} temp_bytes={r['temp_bytes']} "
+            f"argument_bytes={r['argument_bytes']} pool_bytes={r['pool_bytes']} "
+            f"code_bytes={r['code_bytes']} kernels={r['kernels']} "
+            f"grouped_products={r['grouped_products']} pool_writes={r['pool_writes']} "
+            f"moving_ops={len(moved)} weight_ops={len(r['weight_ops'])} compile_s={r['seconds']}")
+        for m in moved:
+            say(f"phase=M   {r['program']} moves the pool: {m}")
+        if moved:
+            problems.append(f"{r['program']}: {len(moved)} op(s) move the pool")
+        if not args.rehearse_cpu:
+            for f in r["weight_ops"]:
+                say(f"phase=M   {r['program']} writes a weight out again: {f['op']}")
+            if r["program"] == "decode" and r["weight_ops"]:
+                say(r["weight_ops"][0]["text"])
+                problems.append(f"decode: {len(r['weight_ops'])} op(s) lay a weight out again a pass")
+        out[f"{r['program']}_temp_bytes"] = r["temp_bytes"]
+    if problems:
+        raise PhaseFailed("; ".join(problems))
+    return out
+
+
 def phase_joins(args, preset) -> dict:
     """Phase J: LFM2's join programs alone on the clock (``child_joins``): a
     line a case, the group beside its live rows one a program (each at its
@@ -1953,6 +2279,7 @@ def main() -> int:
         "S": lambda: phase_sparse(args, preset),
         "F": lambda: phase_lfm2(args, preset),
         "Q": lambda: phase_qwen3next(args, preset),
+        "M": lambda: phase_sdar(args, preset),
         "J": lambda: phase_joins(args, preset),
         "D": lambda: phase_four_chips(args, preset),
     }

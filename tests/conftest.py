@@ -111,7 +111,7 @@ def pytest_collection_modifyitems(items):
     other_models = ("jamba2-3b-chat-closed", "pangu-ultra-ep16-chat-closed",
                     "olmo-hybrid-7b-chat-closed", "laguna-s-ep8-code-closed",
                     "deepseek-v32-ep16-longdoc-closed", "lfm2-8b-a1b-chat-closed",
-                    "qwen3-next-ep4-chat-closed")
+                    "qwen3-next-ep4-chat-closed", "sdar-30b-a3b-chat-closed")
     for item in items:
         if item.nodeid.endswith(
             tuple(f"test_cell_loads[{cell}]" for cell in other_models)

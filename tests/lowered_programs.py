@@ -55,7 +55,7 @@ def families() -> dict:
         # (the families the recording holds: a later family's programs have
         # no parent text to be held to)
         **{"pangu" if name == "latent_moe" else name: (config, GEOMETRY)
-           for name, config in FAMILIES.items() if name not in ("lfm2_moe", "qwen3_next")},
+           for name, config in FAMILIES.items() if name not in ("lfm2_moe", "qwen3_next", "sdar")},
         # the windowed pool as the first recording held it (12 pages; a
         # server's would be 9 at 3 lanes)
         "laguna": (LlamaConfig.from_hf_dict(HF), {**GEOMETRY, "n_pages": (32, 12)}),
@@ -96,9 +96,26 @@ def dear_digests() -> dict[str, str]:
         thunk().lower().as_text().encode()).hexdigest() for name, thunk in sorted(served.items())}
 
 
+def block_digests() -> dict[str, str]:
+    """The family PR 57 adds (``sdar_moe``: plain K and V, generating by
+    diffusion over blocks): its three served programs as that tree lowers them
+    (``python tests/lowered_programs.py blocks`` there recorded ``tests/data/
+    lowered_programs_pr57.json``; no parent had them)."""
+    from cake_tpu.models.llama import programs
+
+    from test_program_parts import FAMILIES
+
+    served = programs.served_programs(FAMILIES["sdar"], **GEOMETRY, allow_pallas=False).programs
+    return {f"sdar.{name}": hashlib.sha256(
+        thunk().lower().as_text().encode()).hexdigest() for name, thunk in sorted(served.items())}
+
+
 if __name__ == "__main__":
     if sys.argv[1:] == ["dear"]:
         json.dump(dear_digests(), sys.stdout, indent=1)
+        sys.exit(0)
+    if sys.argv[1:] == ["blocks"]:
+        json.dump(block_digests(), sys.stdout, indent=1)
         sys.exit(0)
     if "grouped" in sys.argv[1:]:
         from cake_tpu.ops import moe

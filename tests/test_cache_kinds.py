@@ -65,11 +65,17 @@ PARENT_FACTS = {
     "lfm2_moe": {"cache": CACHE, "state": STATE, "moe": MOE, "sparse": None},
     # PR 53's own: grouped delta-rule heads beside a share of routed experts
     "qwen3_next": {"cache": CACHE, "state": STATE | DELTA_HEADS, "moe": MOE, "sparse": None},
+    # PR 57's own: plain K and V under generation by diffusion over blocks: the
+    # expert account and the dispatches' own (``diffusion.DiffusionAccount``)
+    "sdar": {"cache": CACHE, "state": STATE, "moe": MOE, "sparse": None, "diffusion": {
+        "block_length", "denoising_steps", "remask", "mask_token_id", "confidence_threshold",
+        "dispatches", "blocks", "passes", "commit_passes", "lane_passes", "revealed",
+        "emitted", "known"}},
 }
 
 
 def tiny_backend(config):
-    kind = programs.KINDS[config.cache_kind]
+    kind = programs.kind_of(config)
     # zeros in the tree's shapes: no fact of the construction reads a weight
     params = jax.tree.map(
         lambda a: jnp.zeros(a.shape, a.dtype),

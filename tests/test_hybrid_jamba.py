@@ -708,7 +708,11 @@ def test_the_other_families_parse_as_they_did(family):
         # PR 48: a short convolution's taps, read where it is the mixer
         "short_conv_taps": 3,
         # PR 53: the rotary term turns the whole head
-        "partial_rotary_factor": 1.0}
+        "partial_rotary_factor": 1.0,
+        # PR 57: one token a step (the block-diffusion fields at their rest)
+        "generation": "autoregressive", "block_length": 0, "mask_token_id": -1,
+        "denoising_steps": 0, "remask": "low_confidence_dynamic",
+        "confidence_threshold": 0.9}
     assert config.attention_kinds == ("full",)
     assert set(config.layer_kinds) == {"attention"} and not config.has_state_layers
     assert config.cache_kind == "kv" and len(set(config.ff_kinds)) == 1
@@ -718,7 +722,7 @@ def test_the_other_families_parse_as_they_did(family):
 
 def test_unsupported_message_is_built_from_the_tuple():
     assert sorted([*GOLDEN, "jamba", "pangu_ultra_moe", "olmo_hybrid", "laguna",
-                   "deepseek_v32", "lfm2_moe", "qwen3_next"]) == sorted(SUPPORTED_MODEL_TYPES)
+                   "deepseek_v32", "lfm2_moe", "qwen3_next", "sdar_moe"]) == sorted(SUPPORTED_MODEL_TYPES)
     with pytest.raises(ValueError) as e:
         LlamaConfig.from_hf_dict({"model_type": "mamba2"})
     assert f"(supported: {', '.join(SUPPORTED_MODEL_TYPES)})" in str(e.value)

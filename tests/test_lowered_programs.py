@@ -115,6 +115,24 @@ def test_the_dear_kinds_programs_lower_to_their_recording(dear_digests, program)
         f"lfm2_moe.{p}" for p in ("decode", "join", "join_rows", "prefill")]
 
 
+# PR 57: the family that generates by diffusion over blocks (``sdar_moe``),
+# its three served programs held to their first lowering.
+BLOCKS = json.loads(
+    (Path(__file__).parent / "data" / "lowered_programs_pr57.json").read_text())
+
+
+@pytest.fixture(scope="module")
+def block_digests():
+    return _digests("blocks")
+
+
+@pytest.mark.parametrize("program", sorted(BLOCKS))
+def test_the_block_programs_lower_to_their_recording(block_digests, program):
+    assert block_digests[program] == BLOCKS[program]
+    assert sorted(block_digests) == sorted(BLOCKS) == [
+        f"sdar.{p}" for p in ("decode", "join", "prefill")]
+
+
 def test_every_program_is_held(digests):
     held = sorted([f"{m}.{p}" for m in FAMILIES for p in ("decode", "join", "prefill")]
                   + ["dense.join_plain", "dense.prefill_plain"])

@@ -809,7 +809,8 @@ def _cell_dispatches():
         page, lanes = flag("--page-size"), flag("--api-batch")
         shapes = ProgramShapes.for_model(config, page, flag("--max-seq-len") // page)
         epochs = sorted({shapes.lanes(seeds, lanes) for seeds in range(1, lanes + 1)})
-        dispatches = {("decode", b, 1) for b in epochs}
+        # (a pass of a block-diffusion model is a block's rows a lane)
+        dispatches = {("decode", b * max(1, config.block_length), 1) for b in epochs}
         for width in shapes.widths:
             dispatches.add(("join", width, width))
             for b in epochs:
@@ -845,7 +846,8 @@ _RULE = {
 
 def test_the_cells_dispatches_are_all_there():
     families = {name.split(".")[0] for name in _CELLS}
-    assert families == {"lfm2_moe", "pangu_ultra_moe", "laguna", "deepseek_v32", "qwen3_next"}
+    assert families == {"lfm2_moe", "pangu_ultra_moe", "laguna", "deepseek_v32", "qwen3_next",
+                        "sdar_moe"}
     assert [n for n, c in _CELLS.items() if c[-1] == "dense"] == ["lfm2_moe.decode.64"]
     # the narrowest and the widest join of each, and Pangu's 128-slot one
     for name in ("lfm2_moe.join.256", "lfm2_moe.join.4096", "pangu_ultra_moe.join.64",
@@ -854,7 +856,10 @@ def test_the_cells_dispatches_are_all_there():
                  "laguna.join.24576", "deepseek_v32.decode.16", "deepseek_v32.join.2688",
                  "deepseek_v32.join.21504",
                  # 64 rows that choose 10 of 512 leave 28% untouched: GROUPED (PR 53)
-                 "qwen3_next.decode.64", "qwen3_next.join.256", "qwen3_next.join.4096"):
+                 "qwen3_next.decode.64", "qwen3_next.join.256", "qwen3_next.join.4096",
+                 # a pass's 256 rows that choose 8 of 128 touch every expert and
+                 # are wider than a tile: GROUPED (PR 57)
+                 "sdar_moe.decode.256", "sdar_moe.join.256", "sdar_moe.join.4096"):
         assert name in _CELLS, sorted(_CELLS)
 
 

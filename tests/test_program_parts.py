@@ -40,6 +40,7 @@ from test_deepseek_v32 import TINY as LATENT_INDEX
 from test_latent_pangu import SHARE as LATENT_MOE
 from test_lfm2_moe import HF as LFM2_MOE
 from test_qwen3_next import HF as QWEN3_NEXT
+from test_sdar import HF as SDAR
 
 FAMILIES = {
     "dense": LlamaConfig.tiny(),
@@ -49,6 +50,9 @@ FAMILIES = {
     "latent_index": LlamaConfig.from_hf_dict(LATENT_INDEX),
     "lfm2_moe": LlamaConfig.from_hf_dict(LFM2_MOE),
     "qwen3_next": LlamaConfig.from_hf_dict(QWEN3_NEXT),
+    # plain K and V, generating by diffusion over blocks of 4: the decode
+    # program is one block of four denoising passes and a commit
+    "sdar": LlamaConfig.from_hf_dict(SDAR),
 }
 PROGRAMS = ("decode", "join", "prefill")
 # A tiny server's shapes: lanes, pages of 16 slots, a table of 8 pages a
@@ -88,6 +92,7 @@ HOLDS = {
     **{("lfm2_moe", p): {experts, "short_conv"} for p, experts in _EXPERTS.items()},
     **{("qwen3_next", p): {experts, "gated_delta_step" if p == "decode" else "gated_delta_rule"}
        for p, experts in _EXPERTS.items()},
+    **{("sdar", p): {experts} for p, experts in _EXPERTS.items()},
 }
 WEIGHTY = ("dot_general", "convolution", "custom_call", "scatter")
 
@@ -234,6 +239,30 @@ def test_qwen3_nexts_programs_leave_nothing_new_unscoped():
     # Laguna's and Pangu's shared experts (no gate) take the scope too
     names = operation_names(lowered("latent_moe", "decode").compiler_ir())
     assert [n for _, n in names if SHARED_EXPERT in n.split("/")]
+
+
+def test_the_block_programs_leave_no_more_unscoped_than_the_plain_kinds():
+    """A decode dispatch of blocks (``diffusion.block_decode``): the reveal
+    under ``sample/unmask`` and nowhere else, the head in the denoising pass
+    alone (the commit runs none), every weighty operation under a part, and
+    under no part only the loops' plumbing: no more kinds of it than the
+    dense family's decode chunk (Mistral's cell's) and LFM2's (the expert
+    stacks outside the scanned tree and their account) leave."""
+    from cake_tpu.obs.taxonomy import HEAD, UNMASK
+
+    names = operation_names(lowered("sdar", "decode").compiler_ir())
+    unmask = [name for _, name in names if UNMASK in name.split("/")]
+    assert unmask and {tuple(parts_of(n)) for n in unmask} == {(SAMPLE,)}
+    assert all(n.split("/").index(SAMPLE) < n.split("/").index(UNMASK) for n in unmask)
+    heads = [kind for kind, name in names if parts_of(name) == [HEAD] and kind.endswith("dot_general")]
+    assert len(heads) == 1  # the denoising pass's body; the commit's has none
+    ours = collections.Counter(unscoped("sdar", "decode"))
+    known = collections.Counter(unscoped("dense", "decode")) + collections.Counter(
+        unscoped("lfm2_moe", "decode"))
+    assert set(ours) <= set(known), set(ours) - set(known)
+    for program in ("join", "prefill"):
+        ours = collections.Counter(unscoped("sdar", program))
+        assert set(ours) <= set(collections.Counter(unscoped("lfm2_moe", program))), program
 
 
 def test_the_in_place_step_sits_inside_mixer():
